@@ -1,20 +1,16 @@
-"""Version-compatibility shims for the jax API surface this framework uses.
+"""The ONE home of the version-sensitive jax surface this framework uses.
 
-The framework targets the modern jax surface (``jax.shard_map``,
-``jax.lax.axis_size``, Explicit/Auto mesh axis types); older runtimes
-(0.4.x) spell the same machinery differently.  Every module that touches a
-version-sensitive symbol goes through this file, so the compatibility
-policy lives in ONE place instead of scattered ``hasattr`` probes:
+The framework targets jax 0.9 (``jax.shard_map``, ``jax.lax.axis_size``,
+Explicit/Auto mesh axis types).  Every module that touches one of those
+symbols goes through this file (lint rule BLT102), so the next toolchain
+change is made in ONE place instead of at scattered call sites:
 
-* :func:`shard_map` — ``jax.shard_map`` when present, else the
-  ``jax.experimental.shard_map`` spelling; the new ``check_vma`` flag maps
-  onto the old ``check_rep``.
-* :func:`axis_size` — ``jax.lax.axis_size`` when present, else
-  ``psum(1, name)`` (static under shard_map: mesh extents are trace-time
-  constants, so permutation schedules can still be built from it).
-* :func:`make_mesh` / :func:`ensure_auto_mesh` — Auto axis-typing where
-  the runtime has typed mesh axes; a plain mesh (implicitly Auto — typed
-  axes do not exist) otherwise.
+* :func:`shard_map` — ``jax.shard_map`` with this framework's keyword
+  order.
+* :func:`axis_size` — ``jax.lax.axis_size``.
+* :func:`make_mesh` / :func:`ensure_auto_mesh` — Auto axis-typing (this
+  framework drives sharding through constraints and lets GSPMD
+  propagate, which requires Auto).
 * the **survivable distributed runtime** block
   (:func:`distributed_initialize` / :func:`distributed_teardown` /
   :func:`distributed_client` / :func:`clear_backends`) — the pod
@@ -32,62 +28,37 @@ policy lives in ONE place instead of scattered ``hasattr`` probes:
   fall back to the stock (fatal) ``jax.distributed.initialize``.
 """
 
-import numpy as np
-
 import jax
-
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
-# The version watershed the suites key xfails on: runtimes predating the
-# jax.shard_map promotion (0.4.x/0.5.x) also carry the GSPMD and
-# jnp.ufunc behavior gaps documented per-test.  True = OLD runtime.
-OLD_JAX = not hasattr(jax, "shard_map")
 
 
 def make_mesh(shape, axis_names):
-    """An n-d mesh with Auto-typed axes on runtimes that type mesh axes
-    (this framework drives sharding through constraints and lets GSPMD
-    propagate, which requires Auto); on older runtimes every mesh is
-    implicitly Auto already."""
-    if HAS_AXIS_TYPES:
-        auto = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-        return jax.make_mesh(tuple(shape), tuple(axis_names),
-                             axis_types=auto)
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    """An n-d mesh with Auto-typed axes (this framework drives sharding
+    through constraints and lets GSPMD propagate, which requires
+    Auto)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(tuple(shape), tuple(axis_names), axis_types=auto)
 
 
 def ensure_auto_mesh(mesh):
-    """An Auto-axis-typed twin of ``mesh`` (identity where the runtime has
-    no axis types, or where the mesh is Auto-typed already)."""
-    if not HAS_AXIS_TYPES:
-        return mesh
-    types = getattr(mesh, "axis_types", None)
-    if types is None or all(t == jax.sharding.AxisType.Auto for t in types):
+    """An Auto-axis-typed twin of ``mesh`` (identity where the mesh is
+    Auto-typed already)."""
+    if all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types):
         return mesh
     return jax.sharding.Mesh(mesh.devices, mesh.axis_names)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` with the cross-version replication-check flag
-    (``check_vma`` new / ``check_rep`` old).  Defaults to True — the
-    same default as both jax spellings — so call sites migrated from a
-    bare ``jax.shard_map`` keep the replication checker."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` (``check_vma`` defaults to True, as jax's
+    does)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis_name):
-    """Extent of a mapped mesh axis inside a shard_map body.  The psum
-    fallback is a trace-time constant (mesh extents are static), so
-    callers may use it to build Python-level schedules (ppermute pair
-    lists) on either runtime."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return int(np.asarray(jax.lax.psum(1, axis_name)))
+    """Extent of a mapped mesh axis inside a shard_map body — a
+    trace-time constant, so callers may build Python-level schedules
+    (ppermute pair lists) from it."""
+    return jax.lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------

@@ -19,16 +19,6 @@ class HostFallbackWarning(UserWarning):
     locate (or forbid) fallback sites."""
 
 
-class HBMPressureWarning(UserWarning):
-    """An operation's estimated device-memory demand exceeds the ASSUMED
-    accelerator memory (the device did not report its capacity, so the
-    smallest-current-TPU default applies).  The op may still succeed on
-    larger chips — set ``BOLT_HBM_BYTES`` (or
-    ``bolt_tpu.tpu.array._HBM_LIMIT_OVERRIDE``) to your chip's HBM size
-    to turn this into an accurate up-front ``MemoryError`` instead of a
-    mid-program XLA OOM."""
-
-
 class BoltArray(metaclass=ABCMeta):
     """An n-dimensional array whose axes split into *key axes* (the
     distributed / parallel domain) and *value axes* (the local block each

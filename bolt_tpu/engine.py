@@ -15,8 +15,9 @@ give:
    Dispatch then goes straight to the compiled executable.
 
 2. **Cross-process persistence.**  :func:`persistent_cache` opts in to
-   JAX's on-disk compilation cache (``jax_compilation_cache_dir`` with
-   the min-time/min-size floors dropped to zero), so a warm process
+   JAX's on-disk compilation cache (placed by
+   ``$JAX_COMPILATION_CACHE_DIR``, else beside the checkout, with the
+   min-time/min-size floors dropped to zero), so a warm process
    re-lowers but skips XLA compilation entirely: the second run of an
    identical pipeline in a fresh process shows ``compile_seconds ≈ 0``.
 
@@ -307,47 +308,60 @@ def cache_len():
 
 _PERSISTENT_DIR = None
 
+# where the cache lives when nobody places it: beside the package, so a
+# checkout carries its cache with it (the path is part of jax's cache
+# key — a directory that moves never hits)
+_CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
 
 def persistent_cache(cache_dir=None, enable=True):
     """Opt in to JAX's persistent on-disk XLA compilation cache.
 
     ::
 
-        bolt_tpu.engine.persistent_cache("/var/cache/bolt-xla")
+        bolt_tpu.engine.persistent_cache()
 
-    Compiled programs are written under ``cache_dir`` (default
-    ``~/.cache/bolt_tpu/xla``); a fresh process running the same pipeline
-    re-lowers but loads the executable from disk instead of invoking XLA
-    — the engine's ``compile_seconds`` counter stays ≈ 0 on the warm run.
-    The min-compile-time and min-entry-size floors are dropped to zero so
-    EVERY program persists (this framework's programs are many and
-    individually cheap; the default floors would skip most of them).
+    Compiled programs persist on disk; a fresh process running the same
+    pipeline re-lowers but loads the executable from disk instead of
+    invoking XLA — the engine's ``compile_seconds`` counter stays ≈ 0 on
+    the warm run.  The min-compile-time and min-entry-size floors are
+    dropped to zero so EVERY program persists (this framework's programs
+    are many and individually cheap; the default floors would skip most
+    of them).
 
-    ``enable=False`` detaches the directory (in-memory caching only).
-    Returns the resolved directory (or ``None`` when disabling).  Any
-    explicit call here also DISARMS a prior :func:`warm_start` — hits
-    against a re-attached ordinary cache must not keep counting as
-    warm-start hits (``warm_start`` re-arms after delegating)."""
+    Where the cache lives: ``$JAX_COMPILATION_CACHE_DIR`` when the
+    variable is set — whoever runs the process placed the cache, so it
+    wins over ``cache_dir`` and ``jax_compilation_cache_dir`` is left
+    exactly as jax read it from the environment; else ``cache_dir``;
+    else ``.jax_cache`` beside the package (the checkout).
+
+    ``enable=False`` detaches a directory this function attached
+    (in-memory caching only); a cache placed by the environment is not
+    this module's to detach.  Returns the resolved directory (or
+    ``None`` when disabling).  Any explicit call here also DISARMS a
+    prior :func:`warm_start` — hits against a re-attached ordinary
+    cache must not keep counting as warm-start hits (``warm_start``
+    re-arms after delegating)."""
     global _PERSISTENT_DIR, _WARM_ARMED
     _hook_persistent_monitoring()
     _WARM_ARMED = False
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not enable:
-        jax.config.update("jax_compilation_cache_dir", None)
-        _reset_jax_cache_singleton()
+        if not placed:
+            jax.config.update("jax_compilation_cache_dir", None)
+            _reset_jax_cache_singleton()
         _PERSISTENT_DIR = None
         return None
-    if cache_dir is None:
-        cache_dir = os.path.join(os.path.expanduser("~"), ".cache",
-                                 "bolt_tpu", "xla")
-    cache_dir = os.path.abspath(cache_dir)
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    try:
-        jax.config.update("jax_enable_compilation_cache", True)
-    except AttributeError:      # flag spelling varies across versions
-        pass
+    if placed:
+        _PERSISTENT_DIR = os.path.abspath(placed)
+        return _PERSISTENT_DIR
+    cache_dir = os.path.abspath(cache_dir or _CHECKOUT_CACHE_DIR)
+    os.makedirs(cache_dir, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     _reset_jax_cache_singleton()
     _PERSISTENT_DIR = cache_dir
     return cache_dir
@@ -357,11 +371,8 @@ def _reset_jax_cache_singleton():
     """jax initialises its compilation-cache object once per process;
     flipping the directory afterwards needs an explicit reset or the old
     (absent) cache keeps being consulted."""
-    try:
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+    from jax.experimental.compilation_cache import compilation_cache
+    compilation_cache.reset_cache()
 
 
 def persistent_cache_dir():
@@ -379,7 +390,8 @@ _WARM_ARMED = False
 def warm_start(cache_dir):
     """Arm the fleet-warm start: attach the on-disk XLA cache at
     ``cache_dir`` (pre-seeded by an earlier process running the fleet's
-    pipeline shapes) and count every compile it serves as a
+    pipeline shapes; ``$JAX_COMPILATION_CACHE_DIR`` wins when set — see
+    :func:`persistent_cache`) and count every compile it serves as a
     ``persistent_warm_hits`` — a warmed process's first request then
     re-lowers but runs ZERO fresh XLA compiles (``persistent_misses``
     stays flat).  Returns the resolved cache directory.

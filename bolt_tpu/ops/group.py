@@ -715,7 +715,7 @@ def bincount(b, minlength=0):
         # elements (its int32 per-bin partial cannot wrap); partials
         # combine exactly in host int64.  Chunk starts stay STATIC —
         # dynamic-start slices of sharded operands make GSPMD all-gather
-        # the whole array (BASELINE.md) — so it is one program per chunk;
+        # the whole array — so it is one program per chunk;
         # at the default ~2**31 chunk a 16 GB chip holds at most a
         # handful of chunks.
         total = np.zeros(length, np.int64)

@@ -485,14 +485,13 @@ def pca(b, k=None, center=False, axis=None, return_mean=False,
     ``fetch=False`` (TPU mode) returns components/singular values/mean
     as DEVICE-resident ``jax.Array``s instead of host ndarrays: the call
     then syncs nothing — back-to-back pca calls (or downstream jnp use
-    of the components) pipeline without paying a host round-trip each,
-    which on a remote attach is the dominant per-call cost.
+    of the components) pipeline without paying a host round-trip each.
 
     ``precision=None`` resolves through the scoped policy
     (``bolt.precision``), pinned at ``"highest"`` — the Gram and
     projection matmuls are the measured ~2x of this op's cost;
-    ``"default"`` trades ~1e-2 relative score accuracy for it
-    (BASELINE round-4 MFU table).  The local oracle always computes in
+    ``"default"`` trades ~1e-2 relative score accuracy for it.  The
+    local oracle always computes in
     f64.
     """
     from bolt_tpu._precision import resolve
@@ -569,8 +568,7 @@ def pca(b, k=None, center=False, axis=None, return_mean=False,
         # async path: nothing syncs — small results stay on device
         return (wrapped, vec, sv, mu) if return_mean else (wrapped, vec, sv)
     # ONE batched host fetch for the small results: separate device_gets
-    # cost a full host round-trip EACH (2x the per-call latency of the
-    # whole API on a remote attach; measured in the pca perf family)
+    # cost a full host round-trip EACH
     if return_mean:
         vec, sv, mu = jax.device_get((vec, sv, mu))
         return wrapped, np.asarray(vec), np.asarray(sv), np.asarray(mu)

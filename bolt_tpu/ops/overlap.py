@@ -129,11 +129,9 @@ def _separable_filter(b, taps_list, axes, size, mode, shard=None,
     whole-array program whose per-axis correlations are Pallas window
     kernels where the plan allows — each block reads HBM once and
     windows in VMEM, where the XLA shifted-slice form re-reads the
-    operand once per tap (measured 112 → ~40 ms for a 9-tap 2-axis
-    gaussian on 2.1 GB; round-3).  Anything the kernel can't serve
-    (unplannable geometry, non-float dtype, a failed compile on this
-    toolchain) falls back to the halo-chunked machinery, which also
-    serves ``shard=`` (sequence-parallel) and the local oracle."""
+    operand once per tap.  Anything the kernel can't serve (unplannable
+    geometry, non-float dtype) takes the halo-chunked machinery, which
+    also serves ``shard=`` (sequence-parallel) and the local oracle."""
     from bolt_tpu._precision import resolve
     pr = resolve(precision)
     mode = _canon_mode(mode)
@@ -151,12 +149,16 @@ def _separable_filter(b, taps_list, axes, size, mode, shard=None,
 def _whole_array_sepfilter(b, taps_key, axes, mode, precision="highest"):
     """ONE compiled program filtering every requested axis of the full
     (sharded) array — Pallas window kernel per axis, shifted-slice for
-    any axis the plan can't serve.  Returns None (caller takes the
-    chunked path) when no axis can use the kernel or the compile fails
-    on this toolchain (the kernel's Mosaic surface varies by version;
-    a flaky remote-compile must degrade, not crash)."""
+    any axis the plan can't serve.  The filtered axes are VALUE axes,
+    whole on every device, so the kernels run per shard under
+    ``shard_map`` with no communication (Mosaic kernels cannot be
+    partitioned by GSPMD).  Returns None (caller takes the chunked
+    path) when no axis can use the kernel.  A geometry the plan admits
+    and Mosaic refuses is a bug to see: the compile error propagates."""
     import numpy as _np
+    from bolt_tpu._compat import shard_map
     from bolt_tpu.ops import kernels
+    from bolt_tpu.parallel.sharding import key_sharding
     from bolt_tpu.tpu.array import (_cached_jit, _chain_apply, _check_live,
                                     _constrain)
     split = b.split
@@ -169,44 +171,36 @@ def _whole_array_sepfilter(b, taps_key, axes, mode, precision="highest"):
     itemsize = _np.dtype(b.dtype).itemsize
     if not _np.issubdtype(_np.dtype(b.dtype), _np.floating):
         return None
-    if not any(kernels.sepfilter_capable(b.shape, itemsize, g, len(t),
+    mesh = b.mesh
+    sharding = key_sharding(mesh, b.shape, split)
+    local = sharding.shard_shape(tuple(b.shape))   # what a kernel sees
+    if not any(kernels.sepfilter_capable(local, itemsize, g, len(t),
                                          mode=mode)
                for g, t in active):
         return None
-    mesh = b.mesh
     base, funcs = b._chain_parts()
     key = ("sepfilter", taps_key, axes, mode, funcs, base.shape,
            str(base.dtype), split, mesh, precision)
-    if key in _SEPFILTER_FAILED:
-        return None                        # this toolchain said no once
 
     def build():
-        def run(d):
-            x = _chain_apply(funcs, split, d)
+        def per_shard(x):
             for g, taps in active:
                 y = kernels.sepfilter1d(x, taps, g, mode=mode,
                                         precision=precision)
                 x = y if y is not None else _filter1d(x, g, taps, mode, jnp)
-            return _constrain(x, mesh, split)
+            return x
+
+        # check_vma=False: the pallas out_shape carries no vma annotation
+        filt = shard_map(per_shard, mesh, in_specs=sharding.spec,
+                         out_specs=sharding.spec, check_vma=False)
+
+        def run(d):
+            x = _chain_apply(funcs, split, d)
+            return _constrain(filt(x), mesh, split)
         return jax.jit(run)
 
-    try:
-        fn = _cached_jit(key, build)
-        out = fn(_check_live(base))
-    except Exception:
-        # a Mosaic/remote-compile failure: remember it (retrying would
-        # pay the failed compile EVERY call), purge the cached program,
-        # and let the chunked path serve this geometry from now on
-        from bolt_tpu.tpu.array import _JIT_CACHE
-        _JIT_CACHE.pop(key, None)
-        _SEPFILTER_FAILED.add(key)
-        return None
+    out = _cached_jit(key, build)(_check_live(base))
     return b._wrap(out, split)
-
-
-# geometries whose kernel program failed to compile on this toolchain —
-# they take the chunked path without re-paying the failed compile
-_SEPFILTER_FAILED = set()
 
 
 def _filter_axes(b, axis):

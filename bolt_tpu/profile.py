@@ -8,10 +8,10 @@ numbers are meaningful.  This module packages that:
 
 * :func:`trace` — context manager writing a device trace to a log dir.
 * :func:`annotate` — names a region so it shows up in the trace timeline.
-* :func:`timeit` — robust wall-clock of a function over device arrays,
-  fetching results to force completion (NOTE: fetching, not
-  ``block_until_ready``, is the reliable barrier on remote-attached
-  devices).
+* :func:`timeit` — wall-clock of a function over device arrays: blocks
+  on the result (``block_until_ready`` does block on the chip's own
+  host — PERF.md, PR 21) and then fetches it, so the figure includes
+  the device→host copy of the result.
 * :func:`throughput` — GB/s given bytes touched, the BASELINE "GB/s/chip"
   metric.
 * :func:`debug_nans` — toggles jax NaN checking (the race-detector slot in
@@ -49,8 +49,8 @@ def timeit(fn, iters=5, warmup=1):
     ``jax.block_until_ready`` (tuples/dicts/dataclasses of arrays, and
     non-array leaves, all handled — not just objects exposing a
     ``.block_until_ready`` method), then pulls it to the host
-    (``jax.device_get``) so the timing includes real completion — on
-    remote-attached devices the fetch is the reliable barrier.
+    (``jax.device_get``), so the timing includes the result's
+    device→host copy.
 
     ``iters`` must be >= 1 (a "best of zero runs" has no answer);
     negative ``warmup`` counts as zero.

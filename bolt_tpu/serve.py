@@ -59,7 +59,10 @@ that lets N tenants share one process and one device mesh safely:
   standalone terminal body runs vmapped (the ``StackedArray`` batched-
   execution idea applied to the request queue), and each lane's
   results scatter back to its request's ``Future`` — BIT-IDENTICAL to
-  the standalone dispatch.  Partial batches pad to bucketed widths
+  the standalone dispatch on XLA's CPU backend, where the tests run;
+  on a v5e a float32 reduction inside the stacked program rounded
+  differently from the standalone one (1.9e-7 relative; PERF.md,
+  PR 21).  Partial batches pad to bucketed widths
   (powers of two up to ``max_batch``) so steady state compiles a small
   fixed executable set and then runs zero fresh XLA compiles
   (``bolt_tpu.tpu.batched.warm`` pre-compiles the buckets for a

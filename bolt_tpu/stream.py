@@ -1245,9 +1245,12 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
     mesh = source.mesh
     raw_dtype = source.dtype
     delta_ok = split < len(source.shape)
+    # one device only: GSPMD cannot partition a Mosaic kernel, and this
+    # program is not under shard_map off pods
     use_kernel = (codec_obj is not None and codec_obj.name == "int8"
                   and terminal == "sum" and not stages and pred is None
                   and not sharded and split == 1
+                  and mesh.devices.size == 1
                   and _codec_registry().kernel_enabled())
     key = ("stream-slab-acc" if fused else "stream-slab", terminal,
            stages, pred, slab_shape, str(source.dtype), split, ddof,

@@ -36,6 +36,15 @@ name       wire     ratio*  contract
 
 \\* ratio = wire bytes / raw bytes for a float32 source.
 
+What "bit-identical" covers, as measured on a v5e (PERF.md, PR 21): the
+``delta-f32`` DECODE is bit-exact (a streamed swap under it equals the
+raw swap element for element) and exact reductions — integer-valued
+sums, ``min``, ``max`` — match the raw pass exactly; a float-rounded
+moment (``var``) differed in its last bits, because XLA:TPU orders the
+reduction differently once the decode is fused into the slab program.
+Bit-identity of every reduction holds on XLA's CPU backend, where the
+tests run.
+
 Accuracy follows the ``_precision.resolve_accumulate`` contract
 template: the default (no codec) is bit-exact; lossy codecs are an
 explicit opt-in with parity bounds documented in

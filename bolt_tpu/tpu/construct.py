@@ -104,11 +104,8 @@ class ConstructTPU:
             data = jax.make_array_from_callback(
                 a.shape, sharding, lambda idx: a[idx])
         else:
-            # complex hosts upload as real/imag pairs — some attach
-            # transports have no complex DMA and one failed transfer
-            # poisons the session (see array._complex_safe_put)
-            from bolt_tpu.tpu.array import _complex_safe_put
-            data = _complex_safe_put(a, sharding)
+            from bolt_tpu import stream as _streamlib
+            data = _streamlib.transfer(a, sharding)
         return BoltArrayTPU(data, split, mesh)
 
     @staticmethod

@@ -272,13 +272,16 @@ class _StatGroup:
         shape = tuple(self.in_aval.shape)
         n = prod(shape[:split])
         vshape = shape[split:]
-        keepdims = m.keepdims
+        # cached programs close over GEOMETRY only: a closure holding
+        # the member handle would pin group -> base, i.e. the engine
+        # cache would keep the device buffer alive
+        keepdims, new_split = m.keepdims, m.new_split
 
         def build():
             def reducer(data):
                 out = _reduce_tree_expr(data, func, funcs, split, n,
                                         vshape, keepdims)
-                return _constrain(out, mesh, m.new_split)
+                return _constrain(out, mesh, new_split)
             return jax.jit(reducer)
 
         fn = _cached_jit(("reduce", func, funcs, base.shape,
@@ -298,16 +301,17 @@ class _StatGroup:
             # standalone resolution: the EXACT pre-fusion terminal —
             # same engine key, same traced expressions
             m = members[0]
+            # geometry only in the cached closure (see _resolve_reduce)
+            op = _OPS[m.name]
+            axes, keepdims, new_split = m.axes, m.keepdims, m.new_split
+            kwargs = {} if m.ddof is None else {"ddof": m.ddof}
 
             def build():
-                op = _OPS[m.name]
-                kwargs = {} if m.ddof is None else {"ddof": m.ddof}
-
                 def stat(data):
                     mapped = _chain_apply(funcs, split, data)
-                    out = op(mapped, axis=m.axes, keepdims=m.keepdims,
+                    out = op(mapped, axis=axes, keepdims=keepdims,
                              **kwargs)
-                    return _constrain(out, mesh, m.new_split)
+                    return _constrain(out, mesh, new_split)
                 return jax.jit(stat,
                                donate_argnums=(0,) if donate else ())
 
@@ -364,6 +368,9 @@ class _StatGroup:
             # the eager path (same key, same expressions; never
             # needs_count — min/max handles are not lazy here)
             m = members[0]
+            # geometry only in the cached closure (see _resolve_reduce)
+            name, axes, keepdims, ddof, new_split = (
+                m.name, m.axes, m.keepdims, m.ddof, m.new_split)
 
             def build():
                 def stat(data):
@@ -372,9 +379,9 @@ class _StatGroup:
                     mask = _pred_mask(pred, flat)
                     mfull = mask.reshape((n,) + (1,) * len(vshape))
                     out = _masked_stat_expr(
-                        m.name, flat, mask, mfull, m.axes, m.keepdims,
-                        m.ddof, vshape, vdtype)
-                    return _constrain(out, mesh, m.new_split)
+                        name, flat, mask, mfull, axes, keepdims,
+                        ddof, vshape, vdtype)
+                    return _constrain(out, mesh, new_split)
                 return jax.jit(stat,
                                donate_argnums=(0,) if donate else ())
 

@@ -25,25 +25,17 @@ from bolt_tpu.tpu.array import _cached_jit
 from bolt_tpu.utils import inshape, prod, tupleize
 
 
-def _kernel_gate(axes, ndim, dtype):
-    """ONE predicate for "the fused_welford kernel can engage here" —
-    shared by the traced body and welford's compile-failure fallback
-    arming, so they cannot disagree (jnp.issubdtype: bf16 IS floating,
-    where np.issubdtype says no)."""
-    return (axes == tuple(range(len(axes))) and len(axes) < ndim
-            and jnp.issubdtype(dtype, jnp.floating))
-
-
-def _shard_moments(x, axes, use_kernel=True):
+def _shard_moments(x, axes):
     """Per-shard ``(mu, m2, min, max)`` over ``axes`` (traced inside the
     shard_map body).  When the reduced axes are the leading contiguous
     ones — the ``stats()`` default — and the shard geometry tiles cleanly,
-    the single-HBM-pass pallas kernel computes them (measured 1.52× over
-    the fused-XLA two-pass at 10.7 GB on a v5e chip: XLA cannot fuse the
-    mean with the centred second moment, so it reads HBM twice;
-    BASELINE.md).  Everything else takes the jnp path — identical
-    semantics, allclose-level numerics."""
-    if use_kernel and _kernel_gate(axes, x.ndim, x.dtype):
+    the single-HBM-pass pallas kernel computes them (XLA cannot fuse the
+    mean with the centred second moment, so it reads HBM twice).
+    Everything else takes the jnp path — identical semantics,
+    allclose-level numerics.  A geometry the plan admits and Mosaic
+    refuses is a bug to see: the compile error propagates."""
+    if (axes == tuple(range(len(axes))) and len(axes) < x.ndim
+            and jnp.issubdtype(x.dtype, jnp.floating)):
         from bolt_tpu.ops.kernels import fused_welford
         r = fused_welford(x)
         if r is not None:
@@ -97,13 +89,12 @@ def welford(barray, requested=("mean", "var", "std", "min", "max"),
 
     key = ("welford", shape, str(barray.dtype), axes, spec, mesh)
 
-    def build(use_kernel=True):
+    def build():
         def local_moments(x):
             # x is the per-device shard; reduced dims may be divided across
             # the mesh, so this count is the LOCAL n.
             n_local = prod(tuple(x.shape[a] for a in axes))
-            moments = _shard_moments(x, axes, use_kernel)
-            mu, m2, mn, mx = moments
+            mu, m2, mn, mx = _shard_moments(x, axes)
             if reduce_names:
                 n_loc = jnp.asarray(n_local, dtype=mu.dtype)
                 n_tot = jax.lax.psum(n_loc, reduce_names)
@@ -125,37 +116,8 @@ def welford(barray, requested=("mean", "var", "std", "min", "max"),
             out_specs=(out_spec, out_spec, out_spec, out_spec),
             check_vma=False))
 
-    # shares the bounded LRU executable cache with every other op family.
-    # The compile-failure fallback arms ONLY when the pallas kernel can
-    # actually engage (leading contiguous axes, floating dtype — the
-    # _shard_moments gate); other geometries compile one jnp program and
-    # their errors surface undisturbed (the sepfilter precedent: gate
-    # eligibility BEFORE arming the fallback).
-    data = barray._data
-    kernel_possible = _kernel_gate(axes, len(shape), barray.dtype)
-    out = None
-    if not kernel_possible:
-        out = _cached_jit(key, build)(data)
-    elif key not in _KERNEL_FAILED:
-        try:
-            out = _cached_jit(key, build)(data)
-        except Exception:
-            # the DEFAULT stats() path must survive a flaky pallas
-            # toolchain (remote-compile hiccups / Mosaic geometry
-            # surprises): fall back to the jnp two-pass body, memoise so
-            # the failed compile is never re-paid
-            from bolt_tpu.tpu.array import _JIT_CACHE
-            _JIT_CACHE.pop(key, None)
-            _KERNEL_FAILED.add(key)
-    if out is None:
-        out = _cached_jit(key + ("nokernel",),
-                          lambda: build(use_kernel=False))(data)
+    # shares the bounded LRU executable cache with every other op family
+    out = _cached_jit(key, build)(barray._data)
     mu, m2, mn, mx = (np.asarray(jax.device_get(o)) for o in out)
     return StatCounter.from_moments(n_total, mu, m2, minValue=mn, maxValue=mx,
                                     stats=requested)
-
-
-# welford geometries whose pallas-backed program failed to compile on
-# this toolchain — they run the jnp two-pass body without re-paying the
-# failed compile
-_KERNEL_FAILED = set()

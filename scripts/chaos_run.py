@@ -38,8 +38,16 @@ Usage::
     python scripts/chaos_run.py --matrix   # the seam x action sweep
     python scripts/chaos_run.py --child .. # internal: one streamed run
 
-``bench_all.py`` config 10 (``stream_resume``) and the ``perf_regress``
-``stream_resume`` family reuse :func:`run_resume_bench`.
+Every child is pinned to the CPU backend (:func:`_child_env`): the
+parent may hold the chip (``main`` runs its thread variant in process,
+``bench_all.py`` runs nine configs first), a chip belongs to one
+process, and a ``kill -9`` must never land on the process that holds
+it.  What the children prove — checkpoint, fence and resume control
+flow, bit-identity — does not depend on the backend; their wall
+seconds are CPU seconds and are labelled so.
+
+``bench_all.py`` config 10 (``stream_resume``) reuses
+:func:`run_resume_bench`.
 """
 
 import json
@@ -116,10 +124,17 @@ def child_main(argv):
     return 0
 
 
-def _run_child(ck_dir, out, chaos=None):
-    env = dict(os.environ)
-    env["BOLT_STREAM_UPLOAD_THREADS"] = "1"   # deterministic watermark
+def _child_env(**extra):
+    """Environment of a kill-target child: this process's, pinned to
+    the CPU backend (see the module docstring), chaos disarmed."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", **extra)
     env.pop("BOLT_CHAOS", None)
+    return env
+
+
+def _run_child(ck_dir, out, chaos=None):
+    env = _child_env(BOLT_STREAM_UPLOAD_THREADS="1")  # deterministic
+    #                                                   watermark
     if chaos:
         env["BOLT_CHAOS"] = chaos
     proc = subprocess.run(
@@ -253,9 +268,7 @@ _POD_NTH = {"podwatch.heartbeat": 3, "multihost.barrier": 1,
 
 
 def _run_stream_child(ck_dir, out, arm="", codec=None):
-    env = dict(os.environ)
-    env["BOLT_STREAM_UPLOAD_THREADS"] = "1"
-    env.pop("BOLT_CHAOS", None)
+    env = _child_env(BOLT_STREAM_UPLOAD_THREADS="1")
     cmd = [sys.executable, os.path.abspath(__file__), "--child",
            "--dir", ck_dir, "--out", out, "--arm", arm]
     if codec:
@@ -382,10 +395,8 @@ def _pod_cell(seam, mode, workdir):
                                              mode))
 
     def run(arm):
-        env = dict(os.environ)
-        env.pop("BOLT_CHAOS", None)
-        env["BOLT_MATRIX_HB"] = hb
-        env["BOLT_MATRIX_ARM"] = "1" if arm else "0"
+        env = _child_env(BOLT_MATRIX_HB=hb,
+                         BOLT_MATRIX_ARM="1" if arm else "0")
         return subprocess.run(
             [sys.executable, os.path.abspath(__file__), "--pod-child",
              seam, mode], env=env, capture_output=True, text=True,
@@ -512,9 +523,7 @@ def shuffle_child_main(argv):
 
 
 def _run_shuffle_child(spill_dir, out, arm=""):
-    env = dict(os.environ)
-    env["BOLT_STREAM_UPLOAD_THREADS"] = "1"
-    env.pop("BOLT_CHAOS", None)
+    env = _child_env(BOLT_STREAM_UPLOAD_THREADS="1")
     return subprocess.run(
         [sys.executable, os.path.abspath(__file__), "--shuffle-child",
          "--dir", spill_dir, "--out", out, "--arm", arm],
@@ -654,7 +663,7 @@ def main():
           and not tv["stale_checkpoint"])
     print("   -> %s" % ("OK" if ok else "MISMATCH"))
 
-    print("== subprocess kill -9 variant")
+    print("== subprocess kill -9 variant (children on the CPU backend)")
     kv = run_resume_bench()
     print("   %s" % json.dumps(kv))
     bounded = kv["recovery_s"] < 1.5 * kv["clean_s"]

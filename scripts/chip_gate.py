@@ -4,13 +4,13 @@
 Runs the ``-m chip`` parity subset (``tests/test_chip.py``) against the
 REAL TPU with production numerics — x64 OFF, the actual XLA:TPU/Mosaic
 lowering — the configuration the CPU-mesh suite structurally cannot
-exercise.  Appends a one-line record to ``docs/STATUS.md`` so each
-round's run is auditable.
+exercise.  Prints a one-line record as its last line; whoever ran it
+copies that line where the round's records are kept (CHANGES.md).  This
+process stays off JAX: the chip belongs to the pytest child.
 
 Usage::
 
-    python scripts/chip_gate.py            # run + record
-    python scripts/chip_gate.py --no-record
+    python scripts/chip_gate.py
 """
 
 import datetime
@@ -37,35 +37,7 @@ def main():
     line = "- %s chip gate: %s (rc=%d)" % (
         datetime.date.today().isoformat(), tail, proc.returncode)
     print(line)
-    if "--no-record" not in sys.argv:
-        _record(line)
     return proc.returncode
-
-
-HEADING = "## Chip gate runs"
-
-
-def _record(line):
-    """Append under a dedicated STATUS.md section (created on first
-    run) — a blind file append would land the record inside whatever
-    list happens to end the document."""
-    path = os.path.join(ROOT, "docs", "STATUS.md")
-    with open(path) as f:
-        text = f.read()
-    if HEADING not in text:
-        text = text.rstrip("\n") + "\n\n%s\n\n%s\n" % (HEADING, line)
-    else:
-        head, _, rest = text.partition(HEADING)
-        # insert before the NEXT section heading, not at end-of-file —
-        # sections added below the gate log must not swallow records
-        nxt = rest.find("\n## ")
-        if nxt == -1:
-            text = head + HEADING + rest.rstrip("\n") + "\n" + line + "\n"
-        else:
-            text = (head + HEADING + rest[:nxt].rstrip("\n") + "\n" + line
-                    + "\n" + rest[nxt:])
-    with open(path, "w") as f:
-        f.write(text)
 
 
 if __name__ == "__main__":

@@ -459,18 +459,6 @@ TAIL_CASES = [
 @pytest.mark.parametrize("name,call", TAIL_CASES,
                          ids=[c[0] for c in TAIL_CASES])
 def test_dispatch_tail_parity(request, layout, name, call):
-    if name == "append-flat" and layout == "keys2d":
-        # known old-jax residual (seed-present): 0.4.x GSPMD
-        # mis-replicates the flatten of a 2-d-sharded key layout inside
-        # the fused flat-append program, so the appended values appear
-        # once per device group (x4 on the (4, 2) mesh).  Marker-based
-        # (not imperative pytest.xfail) so a fix shows up as XPASS.
-        from bolt_tpu._compat import OLD_JAX
-        request.node.add_marker(pytest.mark.xfail(
-            condition=OLD_JAX, strict=False,
-            reason="jax 0.4.x GSPMD replicates the keys2d flatten in "
-                   "the flat np.append program (values x4); fixed on "
-                   "runtimes with jax.shard_map"))
     if layout == "keys1d":
         m, axis = request.getfixturevalue("mesh"), (0,)
     else:

@@ -89,7 +89,8 @@ def test_cached_entries_stay_inspectable(mesh):
 # persistent on-disk compilation cache
 # ----------------------------------------------------------------------
 
-def test_persistent_cache_roundtrip(tmp_path, mesh):
+def test_persistent_cache_roundtrip(tmp_path, mesh, monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     d = str(tmp_path / "xla-cache")
     try:
         got = engine.persistent_cache(d)

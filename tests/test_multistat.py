@@ -718,3 +718,23 @@ def test_fused_counter_snapshots_are_lock_consistent(mesh):
     c1 = engine.counters()
     assert c1["fused_stat_groups"] - c0["fused_stat_groups"] == 30
     assert c1["fused_stat_terminals"] - c0["fused_stat_terminals"] == 60
+
+
+@pytest.mark.parametrize("terminal", [
+    lambda b: b.map(lambda v: v + 1).sum().toarray(),
+    lambda b: b.filter(lambda v: v.mean() > 0).sum().toarray(),
+    lambda b: b.map(lambda v: v + 1).reduce(np.add).toarray(),
+    lambda b: bolt.compute(b.sum(), b.var()),
+], ids=["stat", "filter-stat", "reduce", "multi"])
+def test_cached_programs_do_not_pin_the_base_buffer(mesh, terminal):
+    # engine-cached closures hold geometry only: one that captured the
+    # member handle kept group -> base alive in the cache, i.e. a
+    # dropped 10 GB input stayed in HBM (seen on the chip, PR 21)
+    import gc
+    import weakref
+    b = bolt.array(np.random.RandomState(7).randn(16, 4, 8), mesh)
+    ref = weakref.ref(b._data)
+    terminal(b)
+    del b
+    gc.collect()
+    assert ref() is None

@@ -668,12 +668,13 @@ def test_weight_turn_forfeited_when_queue_drains():
 # ---------------------------------------------------------------------
 
 def test_start_warm_serves_first_request_without_fresh_compiles(
-        mesh, tmp_path):
+        mesh, tmp_path, monkeypatch):
     """A pre-seeded persistent cache + Server(start_warm=dir): the
     warmed server's first request re-lowers but runs ZERO fresh XLA
     compiles (persistent_misses flat), and every disk-served compile is
     counted as a persistent_warm_hits."""
     import os
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     cache = str(tmp_path / "warm-xla")
     x = _x((32, 8, 4))
 

@@ -5,7 +5,6 @@ import numpy as np
 import pytest
 
 import bolt_tpu as bolt
-from bolt_tpu._compat import OLD_JAX
 from bolt_tpu.utils import allclose
 
 
@@ -309,13 +308,6 @@ def test_ufunc_reduce_parity(mesh):
                                           axis=1))
 
 
-@pytest.mark.xfail(
-    condition=OLD_JAX,
-    strict=False,
-    reason="known old-jax residual (seed-present): 0.4.x jnp lacks the "
-           "jnp.ufunc accumulate/reduceat surface this dispatch lowers "
-           "to (np.maximum.accumulate raises in the fused program); "
-           "fixed on runtimes with jax.shard_map")
 def test_ufunc_accumulate_reduceat_parity(mesh):
     x = _x()
     lo, tp = bolt.array(x), bolt.array(x, mesh)
