@@ -23,6 +23,7 @@ from bolt_tpu.tpu.array import BoltArrayTPU
 from bolt_tpu.tpu.multistat import compute
 from bolt_tpu._precision import precision
 from bolt_tpu.utils import allclose
+from bolt_tpu import profile as _profile   # arms the obs->profiler bridge
 
 __all__ = ["array", "ones", "zeros", "full", "rand", "randn",
            "fromcallback", "fromiter", "concatenate", "compute",

@@ -770,6 +770,8 @@ class _Dispatch:
         #                              held-lock-across-collective
         #                              hazard; DISPATCH_SAFE excepted)
         sp = _obs.begin("engine.dispatch")
+        if sp is not None:
+            sp.set(family=_family(self.key))
         t0 = _clock()
         try:
             out = self._dispatch(args)
@@ -780,17 +782,30 @@ class _Dispatch:
             _obs.end(sp)
         return out
 
+    def _enqueue(self, fn, args):
+        """Hand ``fn(*args)`` to the device queues, in the process-wide
+        program order (the order lock; the schedule digest folds the key
+        here)."""
+        with _ORDER_LOCK:
+            _schedule_note(self.key)
+            sp = _obs.begin("engine.enqueue")
+            try:
+                return fn(*args)
+            finally:
+                _obs.end(sp)
+
     def _dispatch(self, args):
         if not _AOT:
             _COUNTERS.add("fallbacks")
-            with _ORDER_LOCK:
-                _schedule_note(self.key)
-                return self.jitted(*args)
+            return self._enqueue(self.jitted, args)
+        sp = _obs.begin("engine.signature")
         try:
             leaves, treedef = jax.tree_util.tree_flatten(args)
             sig = (treedef, tuple(_leaf_sig(x) for x in leaves))
         except Exception:
             sig = None
+        finally:
+            _obs.end(sp)
         if sig is not None:
             fn = self.compiled.get(sig)
             if fn is None:
@@ -825,9 +840,7 @@ class _Dispatch:
                             fn = None
             if fn is not None:
                 try:
-                    with _ORDER_LOCK:
-                        _schedule_note(self.key)
-                        return fn(*args)
+                    return self._enqueue(fn, args)
                 except (TypeError, ValueError):
                     # argument-validation drift the leaf model missed
                     # (layouts, committed-device nuances) — raised BEFORE
@@ -844,9 +857,42 @@ class _Dispatch:
         # construction (unhashable leaves, argument-validation drift) and
         # BOLT_ENGINE_AOT=0 is an explicit single-user debug mode; the
         # hot AOT path above compiles OUTSIDE the lock.
-        with _ORDER_LOCK:
-            _schedule_note(self.key)
-            return self.jitted(*args)
+        return self._enqueue(self.jitted, args)
+
+
+def _family(key):
+    """The op family of an engine key (its first element), as the spans'
+    ``family=`` attribute."""
+    return str(key[0]) if isinstance(key, tuple) and key else str(key)
+
+
+def _lookup(key):
+    """The keyed lookup of :func:`get`: ``(entry, None)`` on a hit (or
+    after waiting out another thread's build of the same key), else
+    ``(None, event)`` with this thread owning the build that ``event``
+    marks.  Each lookup counts exactly ONCE: hit, miss, or coalesced
+    wait."""
+    waited = False
+    while True:
+        with _LOCK:
+            entry = _CACHE.get(key)
+            if entry is not None:
+                if not waited:
+                    _COUNTERS.add("hits")
+                _CACHE.move_to_end(key)
+                return entry, None
+            ev = _BUILDING.get(key)
+            if ev is None:
+                ev = _BUILDING[key] = threading.Event()
+                if not waited:
+                    _COUNTERS.add("misses")
+                return None, ev         # this thread owns the build
+            if not waited:
+                _COUNTERS.add("coalesced_builds")
+                waited = True
+        ev.wait()
+        # the owner either inserted the entry (the re-check above finds
+        # it) or failed (loop again: this thread may become the owner)
 
 
 def get(key, builder):
@@ -867,31 +913,19 @@ def get(key, builder):
     identical cold pipeline trace and compile it exactly once.  A failed
     build wakes the waiters, which then build for themselves (the
     original exception propagates to the owner alone)."""
-    waited = False                      # each lookup counts exactly ONCE:
-    while True:                         # hit, miss, or coalesced wait
-        with _LOCK:
-            entry = _CACHE.get(key)
-            if entry is not None:
-                if not waited:
-                    _COUNTERS.add("hits")
-                _CACHE.move_to_end(key)
-                return entry
-            ev = _BUILDING.get(key)
-            if ev is None:
-                ev = _BUILDING[key] = threading.Event()
-                break                   # this thread owns the build
-            if not waited:
-                _COUNTERS.add("coalesced_builds")
-                waited = True
-        ev.wait()
-        # the owner either inserted the entry (the re-check above finds
-        # it) or failed (loop again: this thread may become the owner)
-    if not waited:
-        _COUNTERS.add("misses")
+    sp = _obs.begin("engine.lookup")
+    entry = None
+    try:
+        entry, ev = _lookup(key)
+    finally:
+        if sp is not None:
+            _obs.end(sp, family=_family(key), hit=entry is not None)
+    if entry is not None:
+        return entry
     # build OUTSIDE the lock: builders may trace (slow) and re-enter
     sp = _obs.begin("engine.build")
-    if sp is not None and isinstance(key, tuple) and key:
-        sp.set(family=str(key[0]))
+    if sp is not None:
+        sp.set(family=_family(key))
     try:
         entry = _Dispatch(builder(), key=key)
     except BaseException:

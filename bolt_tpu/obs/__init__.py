@@ -10,7 +10,11 @@ reading engine internals.
   ``obs.event`` instant marks; ``obs.clock`` THE blessed monotonic
   timer (lint rule BLT106 forbids raw ``time.perf_counter()``
   bookkeeping elsewhere in the package).  Off by default; near-zero
-  cost while off.
+  cost while off.  Spans record while ``obs.enable()`` is in force OR a
+  ``jax.profiler`` session is live, and in a live session each lands in
+  the profiler's trace as ``bolt.<name>`` on the device's clock;
+  ``obs.totals()`` keeps per-name count / seconds / self seconds
+  whatever the ring has dropped.
 * :mod:`bolt_tpu.obs.metrics` — typed registry (counters, gauges,
   log2-bucket histograms, locked counter groups).  The dispatch
   engine's counters are the group named ``"engine"`` here;
@@ -38,9 +42,9 @@ from bolt_tpu.obs.export import report, timeline, to_chrome, trace_arg
 from bolt_tpu.obs.metrics import registry, thread_census
 from bolt_tpu.obs.trace import (Span, active_count, begin, cancel, clear,
                                 clock, current, disable, enable, enabled,
-                                end, event, span, spans)
+                                end, event, span, spans, totals)
 
 __all__ = ["Span", "active_count", "begin", "cancel", "clear", "clock",
            "current", "disable", "enable", "enabled", "end", "event",
            "metrics", "registry", "report", "span", "spans",
-           "thread_census", "timeline", "to_chrome", "trace_arg"]
+           "thread_census", "timeline", "to_chrome", "totals", "trace_arg"]

@@ -7,7 +7,7 @@
   the visual twin of ``profile.overlap_efficiency()``.
 * :func:`report` — an aggregated plain-text tree (span name -> calls,
   total/self seconds, bytes, XLA compiles beneath it) for terminals
-  without a trace viewer.
+  without a trace viewer, drawn from the tracer's running totals.
 * :func:`timeline` — the one-shot scope: arm tracing, run, write the
   file::
 
@@ -42,6 +42,7 @@ def _events(spans):
         ts = (s.t0 - origin) * 1e6
         args = {k: v for k, v in s.attrs.items()
                 if isinstance(v, (int, float, str, bool))}
+        args["rid"] = s.rid
         if s.kind == "I":
             evs.append((ts, 1, s.sid,
                         {"name": s.name, "ph": "i", "s": "t", "ts": ts,
@@ -75,57 +76,6 @@ def to_chrome(spans=None, path=None):
     return doc
 
 
-class _Agg:
-    __slots__ = ("count", "total", "self_s", "nbytes", "compiles",
-                 "children")
-
-    def __init__(self):
-        self.count = 0
-        self.total = 0.0
-        self.self_s = 0.0
-        self.nbytes = 0
-        self.compiles = 0
-        self.children = {}
-
-
-def _aggregate(spans):
-    idx = {s.sid: s for s in spans}
-    kids = {}
-    roots = []
-    for s in spans:
-        if s.pid and s.pid in idx:
-            kids.setdefault(s.pid, []).append(s)
-        else:
-            roots.append(s)
-
-    def visit(s, node_map):
-        agg = node_map.get(s.name)
-        if agg is None:
-            agg = node_map[s.name] = _Agg()
-        d = s.duration or 0.0
-        agg.count += 1
-        agg.total += d
-        ch = kids.get(s.sid, ())
-        # self time subtracts only SAME-thread children: spans handed
-        # off to another thread (prefetch ingest under a stream run)
-        # overlap their parent's own work rather than displacing it
-        agg.self_s += d - sum(c.duration or 0.0 for c in ch
-                              if c.tid == s.tid)
-        b = s.attrs.get("bytes")
-        if isinstance(b, (int, float)):
-            agg.nbytes += int(b)
-        n_comp = 1 if s.name == "engine.compile" else 0
-        for c in ch:
-            n_comp += visit(c, agg.children)
-        agg.compiles += n_comp
-        return n_comp
-
-    top = {}
-    for r in roots:
-        visit(r, top)
-    return top
-
-
 def _human_bytes(n):
     if not n:
         return ""
@@ -137,30 +87,37 @@ def _human_bytes(n):
     return ""
 
 
-def report(spans=None):
-    """Aggregated text tree over the completed spans: per name (within
-    its parent) the call count, total and self wall seconds, summed
-    ``bytes`` attrs, and the number of XLA compiles
+def report():
+    """Aggregated text tree over every span ended since ``obs.clear()``
+    (the tracer's running totals, so a ring that wrapped loses nothing):
+    per name (within its parent) the call count, total and self wall
+    seconds, summed ``bytes`` attrs, and the number of XLA compiles
     (``engine.compile`` spans) at or beneath it."""
-    sp = _trace.spans() if spans is None else spans
-    if not sp:
+    rows = _trace.path_totals()
+    if not rows:
         return "(no spans recorded — arm tracing with bolt_tpu.obs." \
-               "enable() or the obs.timeline(path) scope)"
-    top = _aggregate(sp)
+               "enable() or the obs.timeline(path) scope, or take a " \
+               "jax.profiler trace)"
     lines = ["%-44s %7s %10s %10s %10s %8s"
              % ("span", "calls", "total_s", "self_s", "bytes",
                 "compiles")]
 
-    def render(node_map, depth):
-        for name, agg in sorted(node_map.items(),
-                                key=lambda kv: -kv[1].total):
-            label = "  " * depth + name
+    def render(parent):
+        depth = len(parent)
+        kids = [p for p in rows if len(p) == depth + 1
+                and p[:depth] == parent]
+        for path in sorted(kids, key=lambda p: -rows[p][1]):
+            count, seconds, self_s, nbytes = rows[path]
+            compiles = sum(r[0] for p, r in rows.items()
+                           if p[:len(path)] == path
+                           and p[-1] == "engine.compile")
+            label = "  " * depth + path[-1]
             lines.append("%-44s %7d %10.4f %10.4f %10s %8d"
-                         % (label[:44], agg.count, agg.total, agg.self_s,
-                            _human_bytes(agg.nbytes), agg.compiles))
-            render(agg.children, depth + 1)
+                         % (label[:44], count, seconds, self_s,
+                            _human_bytes(nbytes), compiles))
+            render(path)
 
-    render(top, 0)
+    render(())
     return "\n".join(lines)
 
 

@@ -28,9 +28,19 @@ dispatch was XLA compilation.  This module adds the structure:
   system comes from the same clock and can be correlated on one
   timeline.
 
+* the profiler bridge: spans are recorded while the tracer is armed OR
+  while a ``jax.profiler`` session is live, and in a live session each
+  span also opens a ``TraceAnnotation`` named ``bolt.<span name>``
+  carrying its attributes and ``rid``, so bolt's spans lie on the device
+  trace's own clock in any profile anyone takes.  The jax side is handed
+  in by the package (:func:`set_bridge`, called from ``profile.py``).
+* :func:`totals` — running per-name count / seconds / self seconds kept
+  beside the ring, so an aggregate survives a ring that wrapped.
+
 Tracing is OFF by default.  :func:`enable` arms it process-wide;
 :func:`bolt_tpu.obs.timeline` scopes it around one run and writes a
-Chrome trace-event file.  This module imports ONLY the standard library.
+Chrome trace-event file; a live profiler session arms it for as long as
+the session lasts.  This module imports ONLY the standard library.
 """
 
 import functools
@@ -65,9 +75,12 @@ def _lockdep():
 
 _RING_DEFAULT = 4096
 
-_ON = False                      # the one hot-path check
-_LOCK = _lockdep().lock("obs.trace")   # guards ring + active count
+_ON = False                      # the hot-path flag ...
+_LIVE = None                     # ... and probe: is a profiler session live?
+_ANNOTATE = None                 # (name, **stats) -> an entered annotation
+_LOCK = _lockdep().lock("obs.trace")   # guards ring, totals, active count
 _RING = deque(maxlen=_RING_DEFAULT)
+_TOTALS = {}                     # path -> [count, seconds, self s, bytes]
 _ACTIVE = 0                      # begun-but-not-ended spans (leak gate)
 _IDS = itertools.count(1)
 _TLS = threading.local()         # per-thread open-span stack
@@ -76,24 +89,33 @@ _TLS = threading.local()         # per-thread open-span stack
 class Span:
     """One recorded interval: ``name``, ``attrs``, ids and timestamps.
 
-    ``sid`` is the span's id, ``pid`` its parent span's id (0 = root);
+    ``sid`` is the span's id, ``pid`` its parent span's id (0 = root),
+    ``rid`` the ``sid`` of its root — the request it belongs to,
+    inherited through ``parent`` and so across the explicit cross-thread
+    hand-off; ``path`` the names from that root down to this span;
     ``tid``/``tname`` identify the recording thread; ``t0``/``t1`` are
     :func:`clock` seconds (``t1`` is ``None`` while open).  ``kind`` is
     ``"S"`` for spans, ``"I"`` for instant events."""
 
-    __slots__ = ("name", "attrs", "sid", "pid", "tid", "tname", "t0",
-                 "t1", "kind")
+    __slots__ = ("name", "attrs", "sid", "pid", "rid", "path", "tid",
+                 "tname", "t0", "t1", "kind", "_kids", "_ann")
 
-    def __init__(self, name, attrs, sid, pid, tid, tname, t0, kind="S"):
+    def __init__(self, name, attrs, sid, parent, tid, tname, kind="S"):
         self.name = name
         self.attrs = attrs
         self.sid = sid
-        self.pid = pid
+        if parent is None:
+            self.pid, self.rid, self.path = 0, sid, (name,)
+        else:
+            self.pid, self.rid = parent.sid, parent.rid
+            self.path = parent.path + (name,)
         self.tid = tid
         self.tname = tname
-        self.t0 = t0
         self.t1 = None
         self.kind = kind
+        self._kids = 0.0             # seconds in same-thread children
+        self._ann = None             # the profiler's annotation, if live
+        self.t0 = clock()
 
     def set(self, **attrs):
         """Attach attributes to an open span; chainable."""
@@ -104,6 +126,13 @@ class Span:
     def duration(self):
         """Seconds from begin to end (``None`` while still open)."""
         return None if self.t1 is None else self.t1 - self.t0
+
+    @property
+    def self_seconds(self):
+        """``duration`` less the direct children that ran on the same
+        thread (a child handed to another thread overlaps its parent's
+        own work rather than displacing it)."""
+        return None if self.t1 is None else self.t1 - self.t0 - self._kids
 
     def __repr__(self):
         dur = "open" if self.t1 is None else "%.6fs" % (self.t1 - self.t0)
@@ -135,8 +164,20 @@ def _stack():
 
 
 def enabled():
-    """Is the tracer armed?"""
+    """Is the tracer armed by :func:`enable`?  (A live profiler session
+    records too, armed or not.)"""
     return _ON
+
+
+def set_bridge(is_live, annotate):
+    """Hand the tracer the profiler's side (``profile.py`` does, so this
+    module stays standard-library only).  ``is_live()`` says whether a
+    profiler session is recording; ``annotate(name, **stats)`` returns
+    an ENTERED annotation with ``set_metadata(**stats)`` and
+    ``__exit__(None, None, None)``.  ``set_bridge(None, None)`` takes
+    the bridge out."""
+    global _LIVE, _ANNOTATE
+    _LIVE, _ANNOTATE = is_live, annotate
 
 
 def enable(ring=None):
@@ -161,12 +202,13 @@ def disable():
 
 
 def clear():
-    """Drop every completed span and zero the leak counter (open spans
-    begun before ``clear`` still end cleanly — ``end`` tolerates an
-    already-cleared ring)."""
+    """Drop every completed span, zero the totals and the leak counter
+    (open spans begun before ``clear`` still end cleanly — ``end``
+    tolerates an already-cleared ring)."""
     global _ACTIVE
     with _LOCK:
         _RING.clear()
+        _TOTALS.clear()
         _ACTIVE = 0
 
 
@@ -175,6 +217,34 @@ def spans():
     first)."""
     with _LOCK:
         return list(_RING)
+
+
+def path_totals():
+    """``{path: (count, seconds, self_seconds, bytes)}`` of every span
+    ended since :func:`clear`, ``path`` the tuple of names from the
+    root span down; a consistent snapshot.  What :func:`report` draws
+    its tree from."""
+    with _LOCK:
+        return {path: tuple(row) for path, row in _TOTALS.items()}
+
+
+def totals():
+    """``{name: {"count", "seconds", "self_seconds", "bytes"}}`` of
+    every span ended since :func:`clear`, whatever the ring still
+    holds; a consistent snapshot.  ``self_seconds`` leaves out a span's
+    direct children on the same thread; ``bytes`` sums the ``bytes``
+    attributes."""
+    out = {}
+    for path, (count, seconds, self_s, nbytes) in path_totals().items():
+        row = out.get(path[-1])
+        if row is None:
+            row = out[path[-1]] = {"count": 0, "seconds": 0.0,
+                                   "self_seconds": 0.0, "bytes": 0}
+        row["count"] += count
+        row["seconds"] += seconds
+        row["self_seconds"] += self_s
+        row["bytes"] += nbytes
+    return out
 
 
 def active_count():
@@ -186,24 +256,42 @@ def active_count():
 
 
 def begin(name, parent=None, **attrs):
-    """Open a span; the hot-path primitive.  Returns ``None`` when
-    tracing is disabled — one module-global check, NO allocation — so
-    per-dispatch instrumentation costs nothing until someone arms the
-    tracer.  ``parent`` overrides the calling thread's current span (the
-    explicit cross-thread handoff; see the streaming executor)."""
+    """Open a span; the hot-path primitive.  Returns ``None`` unless the
+    tracer is armed or a profiler session is live — one flag test and
+    one probe call, NO allocation — so per-dispatch instrumentation
+    costs nothing until someone is looking.  In a live session the span
+    also opens the profiler's annotation ``bolt.<name>``.  ``parent``
+    overrides the calling thread's current span (the explicit
+    cross-thread handoff; see the streaming executor)."""
     global _ACTIVE
-    if not _ON:
+    live = _LIVE is not None and _LIVE()
+    if not (_ON or live):
         return None
     st = _stack()
     if parent is None and st:
         parent = st[-1]
     th = threading.current_thread()
-    sp = Span(name, attrs, next(_IDS), parent.sid if parent else 0,
-              th.ident, th.name, clock())
+    sp = Span(name, attrs, next(_IDS), parent, th.ident, th.name)
+    if live:
+        sp._ann = _ANNOTATE("bolt." + name, rid=sp.rid)
     st.append(sp)
     with _LOCK:
         _ACTIVE += 1
     return sp
+
+
+def _leave(sp):
+    """Take ``sp`` off its thread's stack; returns the span then on top
+    (its same-thread encloser) or ``None``."""
+    st = getattr(_TLS, "stack", None)
+    if st and sp in st:
+        # pop through: defensive against misordered ends so the stack
+        # can never grow without bound
+        while st and st[-1] is not sp:
+            st.pop()
+        st.pop()
+        return st[-1] if st else None
+    return None
 
 
 def end(sp, **attrs):
@@ -212,34 +300,47 @@ def end(sp, **attrs):
     if sp is None:
         return
     sp.t1 = clock()
+    ann, sp._ann = sp._ann, None
     if attrs:
         sp.attrs.update(attrs)
-    st = getattr(_TLS, "stack", None)
-    if st and sp in st:
-        # pop through: defensive against misordered ends so the stack
-        # can never grow without bound
-        while st and st[-1] is not sp:
-            st.pop()
-        st.pop()
+    if ann is not None:
+        # the attributes go on at the end, so those set while the span
+        # was open reach the profiler's event too
+        if sp.attrs:
+            ann.set_metadata(**sp.attrs)
+        ann.__exit__(None, None, None)
+    d = sp.t1 - sp.t0
+    top = _leave(sp)
+    if top is not None and top.sid == sp.pid:
+        top._kids += d
+    nbytes = sp.attrs.get("bytes")
     with _LOCK:
         if _ACTIVE > 0:
             _ACTIVE -= 1
         _RING.append(sp)
+        row = _TOTALS.get(sp.path)
+        if row is None:
+            row = _TOTALS[sp.path] = [0, 0.0, 0.0, 0]
+        row[0] += 1
+        row[1] += d
+        row[2] += d - sp._kids
+        if isinstance(nbytes, (int, float)):
+            row[3] += int(nbytes)
 
 
 def cancel(sp):
     """Abandon an open span: it leaves the thread stack and the leak
-    counter but never lands in the ring.  For probes that turn out to
+    counter but never lands in the ring or the totals (the profiler's
+    annotation, once opened, does close).  For probes that turn out to
     have observed nothing (e.g. the streaming executor's ingest probe
     that hits end-of-source)."""
     global _ACTIVE
     if sp is None:
         return
-    st = getattr(_TLS, "stack", None)
-    if st and sp in st:
-        while st and st[-1] is not sp:
-            st.pop()
-        st.pop()
+    ann, sp._ann = sp._ann, None
+    if ann is not None:
+        ann.__exit__(None, None, None)
+    _leave(sp)
     with _LOCK:
         if _ACTIVE > 0:
             _ACTIVE -= 1
@@ -309,8 +410,3 @@ class span:
             with span(name, **attrs):
                 return fn(*args, **kwargs)
         return wrapper
-
-
-def origin():
-    """Process identity for exporters: ``(pid, clock-epoch note)``."""
-    return os.getpid()

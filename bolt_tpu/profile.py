@@ -7,7 +7,10 @@ and XLA programs have precise completion semantics, so wall-clock and GB/s
 numbers are meaningful.  This module packages that:
 
 * :func:`trace` — context manager writing a device trace to a log dir.
-* :func:`annotate` — names a region so it shows up in the trace timeline.
+  Every ``bolt_tpu.obs`` span open inside it (the engine's, the streamed
+  executor's, the array terminals', a caller's own ``obs.span``) lands
+  in that trace as a ``bolt.<name>`` event on the device's clock: this
+  module hands the tracer the profiler's side (``obs.trace.set_bridge``).
 * :func:`timeit` — wall-clock of a function over device arrays: blocks
   on the result (``block_until_ready`` does block on the chip's own
   host — PERF.md, PR 21) and then fetches it, so the figure includes
@@ -26,6 +29,19 @@ import numpy as np
 
 import jax
 
+from bolt_tpu.obs import trace as _trace
+
+
+def _annotate(name, **stats):
+    ann = jax.profiler.TraceAnnotation(name, **stats)
+    ann.__enter__()
+    return ann
+
+
+# the jax side of the obs bridge: spans record while a profiler session
+# is live and open a TraceAnnotation each (obs/trace.py stays stdlib-only)
+_trace.set_bridge(jax.profiler.TraceAnnotation.is_enabled, _annotate)
+
 
 def trace(logdir):
     """Device-trace context manager::
@@ -33,13 +49,9 @@ def trace(logdir):
         with bolt_tpu.profile.trace("/tmp/trace"):
             b.map(f).sum().toarray()
 
-    View with TensorBoard's profile plugin or Perfetto."""
+    View with TensorBoard's profile plugin or Perfetto; name a region
+    of your own with ``bolt_tpu.obs.span("my.region")``."""
     return jax.profiler.trace(logdir)
-
-
-def annotate(name):
-    """Name a region in the device trace timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 def timeit(fn, iters=5, warmup=1):

@@ -690,7 +690,6 @@ class BoltArrayTPU(BoltArray):
         if self._donated:
             op = self._donated if isinstance(self._donated, str) \
                 else "a donating pipeline terminal"
-            _obs.event("array.donated_read", op=op)
             raise RuntimeError(
                 "this array's device buffer was donated to %s and can no "
                 "longer be read (donation-aware terminals consume a "
@@ -3015,7 +3014,8 @@ class BoltArrayTPU(BoltArray):
                 return _constrain(out, mesh, new_split)
             return jax.jit(get)
 
-        out = _cached_jit(key, build)(self._data, arrays)
+        with _obs.span("array.getitem", advanced=len(adv)):
+            out = _cached_jit(key, build)(self._data, arrays)
         return self._wrap(out, new_split)
 
     def __len__(self):
@@ -3069,7 +3069,42 @@ class BoltArrayTPU(BoltArray):
         of a count sync followed by a data fetch; the fetched count then
         resolves the device side for free.  Large padded buffers skip the
         fast path — when few records survive, shipping the full buffer
-        would cost more than the extra count round-trip saves."""
+        would cost more than the extra count round-trip saves.
+
+        Three phases, each a span under the root ``array.fetch`` (the
+        same calls whether or not anyone records them):
+        ``array.fetch.force`` until the last program this fetch needs
+        and the copy back are enqueued (a streamed operand runs whole in
+        here), ``array.fetch.wait`` until the device has the answer, and
+        ``array.fetch.copy`` for the rest of the way to a host ndarray
+        (``bytes=``)."""
+        root = _obs.begin("array.fetch")
+        sp = _obs.begin("array.fetch.force")
+        try:
+            arrays, finish = self._fetch_plan(out)
+            for a in arrays:
+                # asked for now, so that the copy follows the program
+                # down the device's queue and not the host's wake-up
+                a.copy_to_host_async()
+            _obs.end(sp)
+            sp = _obs.begin("array.fetch.wait")
+            # the device_get below would wait here anyway: this only
+            # tells the wait from the copy
+            jax.block_until_ready(arrays)  # lint: allow(BLT107 toarray is the sync point)
+            _obs.end(sp)
+            sp = _obs.begin("array.fetch.copy")
+            res = finish()
+            if sp is not None:
+                sp.set(bytes=int(res.nbytes))
+            return res
+        finally:
+            _obs.end(sp)
+            _obs.end(root)
+
+    def _fetch_plan(self, out):
+        """The force phase of :meth:`toarray`: ``(arrays, finish)`` with
+        every program enqueued, ``arrays`` what is to be waited for and
+        copied back, ``finish()`` the copy and the host assembly."""
         if self._fpending is not None:
             self._resolve_fpending()   # one fused pass → (padded, count)
         if self._pending is not None:
@@ -3077,12 +3112,14 @@ class BoltArrayTPU(BoltArray):
             if (padded.is_fully_addressable
                     and padded.size * padded.dtype.itemsize
                     <= _PENDING_FETCH_MAX_BYTES):
-                p, c = jax.device_get((padded, cnt))
-                c = int(c)
-                # the count is on host now: resolve device-side without a
-                # second sync, releasing the padded buffer
-                self._resolve_pending(count=c)
-                if out is not None:
+                def finish_pending():
+                    p, c = jax.device_get((padded, cnt))
+                    c = int(c)
+                    # the count is on host now: resolve device-side
+                    # without a second sync, releasing the padded buffer
+                    self._resolve_pending(count=c)
+                    if out is None:
+                        return np.asarray(p)[:c].copy()
                     # out= keeps the single batched round-trip: validate
                     # against the now-known filtered shape, copy the
                     # survivor slice in
@@ -3090,22 +3127,26 @@ class BoltArrayTPU(BoltArray):
                         out, (c,) + tuple(padded.shape[1:]), padded.dtype)
                     out[...] = np.asarray(p)[:c]
                     return out
-                return np.asarray(p)[:c].copy()
+                return [padded, cnt], finish_pending
         data = self._data
         if out is not None:
             BoltArray._check_out(out, data.shape, data.dtype)
         if not data.is_fully_addressable:
-            return self._gather_multihost(data, out=out)
-        if out is not None:
-            # shard-wise writes into the caller's target (which may be a
-            # memmap) — fetched in ONE batched device_get (per-shard
-            # gets would pay a host round-trip EACH)
-            shards = data.addressable_shards
-            blocks = jax.device_get([sh.data for sh in shards])
-            for sh, blk in zip(shards, blocks):
+            return ([sh.data for sh in data.addressable_shards],
+                    lambda: self._gather_multihost(data, out=out))
+        if out is None:
+            return [data], lambda: np.asarray(jax.device_get(data))
+        # shard-wise writes into the caller's target (which may be a
+        # memmap) — fetched in ONE batched device_get (per-shard gets
+        # would pay a host round-trip EACH)
+        shards = data.addressable_shards
+        blocks = [sh.data for sh in shards]
+
+        def finish_out():
+            for sh, blk in zip(shards, jax.device_get(blocks)):
                 out[sh.index] = np.asarray(blk)
             return out
-        return np.asarray(jax.device_get(data))
+        return blocks, finish_out
 
     def iter_shards(self):
         """Yield ``(index, block)`` for every shard THIS process can
@@ -3333,8 +3374,16 @@ class BoltArrayTPU(BoltArray):
     def cache(self):
         """Force materialisation of a deferred chain and keep the result
         resident (reference: ``BoltArraySpark.cache`` pins the
-        lazily-computed RDD)."""
-        self._data
+        lazily-computed RDD).  As a synchronous exit it is a fetch of
+        one phase: ``array.fetch`` with ``array.fetch.force`` beneath
+        (every program enqueued; the caller waits on the jax.Array)."""
+        root = _obs.begin("array.fetch")
+        sp = _obs.begin("array.fetch.force")
+        try:
+            self._data
+        finally:
+            _obs.end(sp)
+            _obs.end(root)
         return self
 
     def unpersist(self):

@@ -1,5 +1,6 @@
 """Profiling/timing instrumentation tests (the tracing slot of SURVEY §5)."""
 
+import glob
 import os
 
 import numpy as np
@@ -24,12 +25,25 @@ def test_array_bytes(mesh):
 
 
 def test_annotate_and_trace(tmp_path, mesh):
-    with profile.annotate("bolt-test-region"):
+    """A region is named with ``obs.span`` (``profile.annotate`` is gone):
+    outside a profile it records nothing and costs nothing, inside one it
+    lands in the device trace as ``bolt.<name>``."""
+    from jax.profiler import ProfileData
+    from bolt_tpu import obs
+    with obs.span("bolt-test-region"):
         bolt.ones((8, 2), mesh).sum().toarray()
+    assert obs.spans() == []
     logdir = str(tmp_path / "trace")
     with profile.trace(logdir):
-        bolt.ones((8, 2), mesh).sum().toarray()
+        with obs.span("bolt-test-region"):
+            bolt.ones((8, 2), mesh).sum().toarray()
+    obs.clear()
     assert os.path.isdir(logdir)
+    path, = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert "bolt.bolt-test-region" in names
 
 
 def test_debug_nans_toggle():
