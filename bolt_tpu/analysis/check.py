@@ -49,10 +49,20 @@ def _name(func):
 
 
 def _func_label(func):
-    from bolt_tpu.tpu.array import _WithKeysFunc
+    from bolt_tpu.tpu.array import _Window, _WithKeysFunc
+    if type(func) is _Window:
+        return "getitem(starts=%s, sizes=%s%s)" % (
+            func.starts, func.sizes,
+            ", squeezed=%s" % (func.squeezed,) if func.squeezed else "")
     if isinstance(func, _WithKeysFunc):
         return "map(%s, with_keys)" % _name(func.func)
     return "map(%s)" % _name(func)
+
+
+def _kdrop(funcs):
+    """Key axes the getitem windows among ``funcs`` remove."""
+    from bolt_tpu.tpu.array import _windows
+    return sum(w.kdrop for w in _windows(funcs))
 
 
 def _stage_eval(func, split, aval):
@@ -659,6 +669,9 @@ def _check_impl(obj):
         engine.record_diagnostics(len(diags))
         return rep
 
+    # the recorded split is that of the chain's RESULT: a getitem window
+    # that took an integer on a key axis lowers it on the way
+    walk_split += _kdrop(funcs)
     aval = jax.ShapeDtypeStruct(tuple(base.shape), base.dtype)
     stages.append(Stage(0, "base", aval.shape, np.dtype(aval.dtype),
                         walk_split, _spec(mesh, aval.shape, walk_split)))
@@ -669,6 +682,7 @@ def _check_impl(obj):
     failed = False
     for i, func in enumerate(funcs):
         label = _func_label(func)
+        walk_split -= _kdrop((func,))
         try:
             nxt = _stage_eval(func, walk_split, aval)
         except Exception as exc:

@@ -148,6 +148,9 @@ _SCHEMA = {
     "fused_stat_groups": 0,       # multi-terminal fused dispatches
     "fused_stat_terminals": 0,    # terminals served by those dispatches
                                   # (terminals - groups = dispatches saved)
+    "getitems_fused": 0,          # deferred getitem windows traced inside
+                                  # a consumer's program (no slice program
+                                  # or launch of their own)
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -474,6 +477,13 @@ def record_fused_stats(n_terminals):
     the timeline carries it as the ``array.multi_stat`` span."""
     _COUNTERS.update(fused_stat_groups=1,
                      fused_stat_terminals=int(n_terminals))
+
+
+def record_getitems_fused(n):
+    """``n`` deferred ``getitem`` windows were handed to a consumer that
+    traces them inside its own program (no slice program, no launch of
+    their own); the eager ``getitem`` program is span ``array.getitem``."""
+    _COUNTERS.add("getitems_fused", n)
 
 
 def donation_granted():

@@ -41,6 +41,7 @@ import threading
 import warnings
 from collections import OrderedDict
 from functools import lru_cache
+from typing import NamedTuple
 
 import numpy as np
 
@@ -299,7 +300,14 @@ def _chain_donate_ok(chain):
     ``owner._fpending`` directly as the argument (the calibrated shape)
     and before binding their own local to the base — an extra reference
     fails safe: no donation.  tests/test_engine.py pins both the shared
-    and the unshared side."""
+    and the unshared side.
+
+    A chain that holds a :class:`_Window` is never donated, whatever the
+    counts say: it is a view of a base that the array it was sliced from
+    still owns (``chain[1]`` is the ``funcs`` of a ``_chain`` and of an
+    ``_fpending`` alike)."""
+    if _windows(chain[1]):
+        return False            # a view of a base somebody else holds
     base = chain[0]
     floor = _engine.donation_min_bytes()
     if floor is None or base.nbytes < floor:
@@ -411,6 +419,76 @@ class _WithKeysFunc:
         return type(other) is _WithKeysFunc and self.func == other.func
 
 
+class _Window(NamedTuple):
+    """Deferred-chain entry for a basic-slice ``getitem`` (slices of step
+    1 and integers): ``starts``/``sizes`` cover the leading axes up to
+    the last one the index touches (the axes after it pass whole),
+    ``squeezed`` lists the axes an integer removed, and ``split`` is the
+    number of key axes of what the window is applied TO — an integer on
+    a key axis lowers the split for every entry after it (``kdrop``).
+    :func:`_chain_apply` applies it as ``lax.slice`` plus the reshape
+    for squeezed axes, with no sharding constraint, so whatever reads
+    the chain traces the slice inside its own program.
+
+    All static (tuples of ints) and hashed by value: the entry is part
+    of every engine key that holds the chain's ``funcs``.  A chain that
+    holds a window is a VIEW of a base somebody else holds and is never
+    donated (:func:`_chain_donate_ok`)."""
+
+    starts: tuple
+    sizes: tuple
+    squeezed: tuple
+    split: int
+
+    @property
+    def kdrop(self):
+        """Key axes this window removes."""
+        return sum(1 for a in self.squeezed if a < self.split)
+
+    def out_shape(self, shape):
+        return tuple(s for a, s in enumerate(
+            self.sizes + tuple(shape[len(self.sizes):]))
+            if a not in self.squeezed)
+
+    def apply(self, x):
+        m = len(self.starts)
+        out = jax.lax.slice(
+            x, self.starts + (0,) * (x.ndim - m),
+            tuple(a + z for a, z in zip(self.starts, self.sizes))
+            + tuple(x.shape[m:]))
+        if self.squeezed:
+            out = out.reshape(self.out_shape(x.shape))
+        return out
+
+    def then(self, nxt):
+        """This window followed by ``nxt`` (which indexes this one's
+        output), as ONE window of this one's input."""
+        starts, sizes, squeezed = [], [], list(self.squeezed)
+        m1, m2 = len(self.starts), len(nxt.starts)
+        a = out = 0
+        while a < m1 or out < m2:
+            s1, z1 = (self.starts[a], self.sizes[a]) if a < m1 else (0, None)
+            if a in self.squeezed:
+                starts.append(s1)
+                sizes.append(1)
+            else:
+                if out < m2:
+                    s1, z1 = s1 + nxt.starts[out], nxt.sizes[out]
+                    if out in nxt.squeezed:
+                        squeezed.append(a)
+                starts.append(s1)
+                sizes.append(z1)
+                out += 1
+            a += 1
+        return _Window(tuple(starts), tuple(sizes), tuple(sorted(squeezed)),
+                       self.split)
+
+
+def _windows(funcs):
+    """The ``getitem`` windows among a chain's entries."""
+    return [f for f in funcs if type(f) is _Window]
+
+
 def _reduce_tree_expr(data, func, funcs, split, n, vshape, keepdims):
     """The fixed-order pairwise-tree reduction expression — ONE traced
     body shared by the eager ``reduce`` program, the lazy reduce
@@ -443,9 +521,16 @@ def _chain_apply(funcs, split, data):
     """Apply a deferred map chain: each func nested-vmapped over the
     ``split`` leading key axes, in order; ``with_keys`` entries vmap
     over flattened records zipped with their (traced, int32 — matching
-    the shape-inference avals) key tuples."""
+    the shape-inference avals) key tuples; :class:`_Window` entries
+    slice in place.  ``split`` is the split of the chain's RESULT: a
+    window that takes an integer on a key axis lowers it on the way."""
     out = data
+    split += sum(w.kdrop for w in _windows(funcs))
     for func in funcs:
+        if type(func) is _Window:
+            out = func.apply(out)
+            split -= func.kdrop
+            continue
         if isinstance(func, _WithKeysFunc):
             kshape = out.shape[:split]
             n = prod(kshape)
@@ -827,11 +912,19 @@ class BoltArrayTPU(BoltArray):
                 _engine.donation_granted()
         return _check_live(self._concrete)
 
-    def _chain_parts(self):
+    def _chain_parts(self, consume=True):
         """``(base jax.Array, funcs)`` for fusing this array into a bigger
         program: the unmaterialised chain if deferred, else the concrete
-        data with an empty chain."""
-        return self._chain if self.deferred else (self._data, ())
+        data with an empty chain.  A consumer that takes a chain with
+        windows in it traces those slices inside its own program: they
+        count as ``getitems_fused`` (``consume=False``: the chain is
+        only being extended)."""
+        if not self.deferred:
+            return self._data, ()
+        wins = consume and _windows(self._chain[1])
+        if wins:
+            _engine.record_getitems_fused(len(wins))
+        return self._chain
 
     def _adopt_materialised(self, data):
         """Adopt ``data`` as this deferred chain's materialised result —
@@ -2990,8 +3083,38 @@ class BoltArrayTPU(BoltArray):
     # ------------------------------------------------------------------
 
     def __getitem__(self, index):
+        """Index per axis with an integer, a slice, a list or a boolean
+        mask (advanced indices apply orthogonally, axis by axis).
+
+        A BASIC index — slices of step 1 and integers only — launches
+        nothing: it is recorded as a window on the deferred chain
+        (:class:`_Window`) and traced inside the program of whatever
+        reads it, so ``b[t:t+16].mean()`` is one program and one launch.
+        Like a NumPy view the result keeps the array it was cut from
+        alive (``small = big[:16]; del big`` holds ``big``'s HBM until
+        ``small`` is ``.cache()``d or otherwise materialised); unlike a
+        chain of maps it is never donated.  A window of a window is one
+        window; a window over key axes alone moves in front of the
+        per-record maps before it, which it commutes with.
+
+        Today's eager program (one launch, the slice materialised) still
+        serves what a window in the chain cannot mean the same for: an
+        array or boolean index, a step other than 1; a filtered, streamed
+        or pending-statistic source; and a window that touches a value
+        axis after maps that changed the value shape."""
         from bolt_tpu.utils import normalize_index
+        # decided on what the array is when it is indexed: reading the
+        # shape below resolves a filter
+        plain = not (self._donated or self._stream is not None
+                     or self._fpending is not None
+                     or self._pending is not None
+                     or self._spending is not None)
         norm, squeezed = normalize_index(index, self.shape)
+        if plain and all(isinstance(s, slice) and s.step == 1
+                         for s in norm):
+            out = self._defer_window(norm, squeezed)
+            if out is not None:
+                return out
 
         mesh = self._mesh
         adv = tuple(ax for ax, s in enumerate(norm) if isinstance(s, np.ndarray))
@@ -3017,6 +3140,43 @@ class BoltArrayTPU(BoltArray):
         with _obs.span("array.getitem", advanced=len(adv)):
             out = _cached_jit(key, build)(self._data, arrays)
         return self._wrap(out, new_split)
+
+    def _defer_window(self, norm, squeezed):
+        """``self[index]`` as a deferred chain with the index as a
+        :class:`_Window` entry, or None where only the eager program
+        will do (see :meth:`__getitem__`).  ``norm`` holds step-1 slices
+        only, already clipped to the shape."""
+        shape, split = self.shape, self._split
+        starts = [s.start for s in norm]
+        sizes = [max(0, s.stop - s.start) for s in norm]
+        m = len(shape)
+        while m and sizes[m - 1] == shape[m - 1] and m - 1 not in squeezed:
+            m -= 1                      # trailing whole axes pass untouched
+        win = _Window(tuple(starts[:m]), tuple(sizes[:m]), tuple(squeezed),
+                      split)
+        base, funcs = self._chain_parts(consume=False)
+        at = len(funcs)
+        if m <= split:
+            # key axes alone: commutes with the per-record maps before
+            # it (not with a with_keys map, whose keys it would shift)
+            while at and type(funcs[at - 1]) is not _Window \
+                    and not isinstance(funcs[at - 1], _WithKeysFunc):
+                at -= 1
+        elif at and type(funcs[at - 1]) is not _Window:
+            # a value axis, after maps: in place while the value shape
+            # is still the one the maps were given
+            before = base.shape
+            for w in _windows(funcs):
+                before = w.out_shape(before)
+            if tuple(before[split:]) != tuple(shape[split:]):
+                return None
+        if at and type(funcs[at - 1]) is _Window:
+            funcs = funcs[:at - 1] + (funcs[at - 1].then(win),) + funcs[at:]
+        else:
+            funcs = funcs[:at] + (win,) + funcs[at:]
+        aval = jax.ShapeDtypeStruct(win.out_shape(shape), self._aval.dtype)
+        return BoltArrayTPU._deferred(base, funcs, split - win.kdrop,
+                                      self._mesh, aval)
 
     def __len__(self):
         if self.ndim == 0:
@@ -3303,6 +3463,10 @@ class BoltArrayTPU(BoltArray):
         program — the chain runs on the first block only, never
         materialising the full mapped array (the reference's
         one-record-job economy, VERDICT r2 weak-5)."""
+        if self.deferred and _windows(self._chain[1]):
+            # the one-record slice below is a slice of the BASE: over a
+            # windowed chain ask for the first record as one more window
+            return self[(0,) * self._split].toarray()
         if self.deferred:
             base, funcs = self._chain
             mesh, split = self._mesh, self._split

@@ -246,3 +246,39 @@ def test_quantile_lowers_to_sorted_collective_program(mesh):
     assert fns
     txt = fns[-1].lower(b._data, 0.5).compile().as_text()  # q is an ARG
     assert "sort" in txt
+
+
+# ---------------------------------------------------------------------
+# a basic-slice getitem in front of a statistic (ISSUE 25): the slice is
+# an entry of the chain, traced inside the statistic's own program
+# ---------------------------------------------------------------------
+
+def test_window_and_statistic_are_one_program(mesh):
+    from bolt_tpu import engine
+    from bolt_tpu.tpu import array as array_mod
+    # geometry unique to this test so every engine key is fresh
+    x = np.random.RandomState(25).randn(32, 6, 7)
+    b = bolt.array(x, mesh)
+    before = set(array_mod._JIT_CACHE)
+    c0 = engine.counters()
+    got = b[5:21].mean(axis=(0, 1, 2)).toarray()
+    c1 = engine.counters()
+    assert np.allclose(got, x[5:21].mean())
+    new = [k for k in array_mod._JIT_CACHE if k not in before]
+    assert [k[0] for k in new] == ["stat"]        # and no "getitem"
+    assert c1["dispatches"] - c0["dispatches"] == 1
+    assert c1["getitems_fused"] - c0["getitems_fused"] == 1
+    (key,) = new
+    assert any(type(f) is array_mod._Window for f in key[2])
+    lowered = array_mod._JIT_CACHE[key].lower(b._data)
+    # the whole base is the program's one parameter, the scalar its result
+    (arg,), _ = lowered.args_info
+    assert tuple(arg.shape) == x.shape
+    assert tuple(lowered.out_info.shape) == ()
+    text = lowered.compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    assert entry.count(" parameter(") == 1
+    assert "f64[] " in entry.splitlines()[0].split("->")[1]
+    # on the sharded key axis the slice is partitioned inside that one
+    # program and its partial sums combined there
+    assert "all-reduce" in text

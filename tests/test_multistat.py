@@ -738,3 +738,99 @@ def test_cached_programs_do_not_pin_the_base_buffer(mesh, terminal):
     del b
     gc.collect()
     assert ref() is None
+
+
+# ---------------------------------------------------------------------
+# a deferred getitem window in front of the lazy statistics (ISSUE 25):
+# the slice is part of the group's chain, traced inside its one program
+# ---------------------------------------------------------------------
+
+_WINDOWS = {
+    "key_slice": lambda a: a[3:11],
+    "value_slice": lambda a: a[:, 1:4, 1:],
+    "int_on_value_axis": lambda a: a[:, 2],
+    "slice_of_slice_after_map": lambda a: a.map(lambda v: v + 1)[2:14][1:9],
+}
+
+
+@pytest.mark.parametrize("name", STATS + ("ptp",))
+@pytest.mark.parametrize("form", sorted(_WINDOWS))
+def test_window_fused_bit_identical_to_standalone(mesh, form, name):
+    x = np.abs(_x(seed=11)) * 0.25 + 0.5      # prod-safe magnitudes
+    index = _WINDOWS[form]
+    names = STATS + ("ptp",)
+    want = np.asarray(getattr(index(bolt.array(x, mesh)), name)().toarray())
+    oracle = np.asarray(getattr(index(bolt.array(x)), name)(axis=0))
+    assert np.allclose(want, oracle)
+    w = index(bolt.array(x, mesh))
+    handles = {n: getattr(w, n)() for n in names}
+    c0 = engine.counters()
+    bolt.compute(*handles.values())
+    c1 = engine.counters()
+    # every statistic of the window from ONE program over the base (ptp
+    # subtracts its pair in a second tiny one); the window counted when
+    # the group took the chain, once
+    assert c1["dispatches"] - c0["dispatches"] == 2
+    assert c1["fused_stat_groups"] - c0["fused_stat_groups"] == 1
+    assert _bits(handles[name].toarray(), want)
+
+
+def test_compute_of_one_window_is_one_program(mesh):
+    # geometry UNIQUE to this test so every engine key is fresh
+    x = _x(shape=(24, 5, 7), seed=12)
+    b = bolt.array(x, mesh)
+    c0 = engine.counters()
+    w = b[5:21]
+    mean, std, lo, hi = bolt.compute(w.mean(), w.std(), w.min(), w.max())
+    c1 = engine.counters()
+    d = {k: c1[k] - c0[k] for k in c1}
+    assert d["misses"] == 1 and d["aot_compiles"] == 1
+    assert d["dispatches"] == 1                 # no getitem program
+    assert d["getitems_fused"] == 1
+    assert d["fused_stat_terminals"] == 4
+    assert d["donations"] == 0
+    for got, want in ((mean, x[5:21].mean(axis=0)), (std, x[5:21].std(axis=0)),
+                      (lo, x[5:21].min(axis=0)), (hi, x[5:21].max(axis=0))):
+        assert np.allclose(np.asarray(got.toarray()), want)
+    # the same question at another position: a program of its own (the
+    # start is static), still one launch
+    c2 = engine.counters()
+    bolt.compute(b[6:22].mean(), b[6:22].std())
+    c3 = engine.counters()
+    assert c3["dispatches"] - c2["dispatches"] == 2   # two windows, two groups
+    w2 = b[6:22]
+    c4 = engine.counters()
+    bolt.compute(w2.mean(), w2.std())
+    c5 = engine.counters()
+    assert c5["dispatches"] - c4["dispatches"] == 1
+    assert np.allclose(b.toarray(), x)          # the base stays readable
+
+
+def test_fluent_stats_of_a_window_is_one_pass(mesh):
+    x = _x(shape=(16, 3, 5), seed=13)
+    b, lo = bolt.array(x, mesh), bolt.array(x)
+    c0 = engine.counters()
+    got = b[:, 1:].stats("sum", "var", "min")
+    c1 = engine.counters()
+    assert c1["dispatches"] - c0["dispatches"] == 1
+    assert c1["getitems_fused"] - c0["getitems_fused"] == 1
+    want = lo[:, 1:].stats("sum", "var", "min")
+    for n in ("sum", "var", "min"):
+        assert np.allclose(np.asarray(got[n].toarray()),
+                           np.asarray(want[n]))
+
+
+def test_check_forecasts_a_windowed_group_with_zero_compiles(mesh):
+    x = _x(seed=14)
+    w = bolt.array(x, mesh)[2:9].map(lambda v: v * 2)
+    s = w.sum()
+    c0 = engine.counters()
+    rep = analysis.check(s)
+    assert not [d for d in rep.diagnostics if d.severity == "error"]
+    assert "getitem" in str(analysis.check(w))
+    c1 = engine.counters()
+    assert c1["aot_compiles"] == c0["aot_compiles"]
+    assert c1["dispatches"] == c0["dispatches"]
+    with analysis.strict():
+        assert np.allclose(np.asarray(w.mean().toarray()),
+                           (x[2:9] * 2).mean(axis=0))

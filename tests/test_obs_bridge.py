@@ -405,7 +405,8 @@ def test_a_streamed_operand_runs_whole_inside_force(mesh):
 def test_getitem_has_a_span_like_its_sibling_terminals(mesh):
     b = bolt.array(X, mesh)
     obs.enable()
-    b[2:9]
+    b[2:9]                  # basic: deferred as a window, nothing launched
+    b[::2]
     b[[1, 3], :]
     got = [s for s in obs.spans() if s.name == "array.getitem"]
     assert [s.attrs["advanced"] for s in got] == [0, 1]

@@ -340,3 +340,53 @@ def test_kernel_compiles_for_v5e(v5e_device, name, fn, avals):
     with jax.enable_x64(False):     # the chip's numerics; Mosaic has no i64
         text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text, name    # the plan engaged
+
+
+# ---------------------------------------------------------------------
+# compile-only: a getitem window traced inside the statistic that reads
+# it (ISSUE 25) must stay ONE fusion over the resident base on the chip
+# — no window-sized slice or relayout copy written to HBM on the way
+# ---------------------------------------------------------------------
+
+_STACK = (3200, 200, 64, 64)          # the benchmark's resident stack
+_WINDOW_CASES = [
+    # name, (starts, sizes) of the window, statistic, its axes
+    ("slice_mean", ((97,), (16,)), jnp.mean, (0, 1, 2, 3)),
+    ("slice_std", ((41,), (16,)), jnp.std, (0, 1, 2, 3)),
+    ("slice_max", ((13,), (16,)), jnp.max, (0,)),
+    ("roi_trace", ((0, 11, 5, 3), (3200, 8, 8, 8)), jnp.mean, (1, 2, 3)),
+]
+
+
+@pytest.mark.parametrize("name,window,op,axes", _WINDOW_CASES,
+                         ids=[c[0] for c in _WINDOW_CASES])
+def test_window_statistic_is_one_fusion_on_v5e(v5e_device, name, window,
+                                               op, axes):
+    import re
+    import jax
+    from bolt_tpu.tpu.array import _Window, _chain_apply
+    starts, sizes = window
+    funcs = (_Window(starts, sizes, (), 1),)
+    win_shape = funcs[0].out_shape(_STACK)
+    win_bytes = 4 * int(np.prod(win_shape))
+
+    def stat(data):                    # the stat program's traced body
+        return op(_chain_apply(funcs, 1, data), axis=axes)
+
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    with jax.enable_x64(False):
+        compiled = jax.jit(stat).lower(jax.ShapeDtypeStruct(
+            _STACK, _F32, sharding=where)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    # the whole base comes in, the small answer goes out
+    assert "f32[%s]" % ",".join(map(str, _STACK)) in entry.splitlines()[0]
+    # nothing of the window's size is written outside a fusion: no
+    # standalone slice, no relayout copy of it, no temporary to hold it
+    shaped = "f32[%s]" % ",".join(map(str, win_shape))
+    outside = [ln for ln in entry.splitlines()[1:]
+               if re.search(r"= %s\S* (slice|copy|fusion)\(" %
+                            re.escape(shaped), ln)]
+    assert not outside, outside
+    assert not re.search(r"= \S+ slice\(", entry), name
+    assert compiled.memory_analysis().temp_size_in_bytes < win_bytes
