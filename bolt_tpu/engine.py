@@ -151,6 +151,9 @@ _SCHEMA = {
     "getitems_fused": 0,          # deferred getitem windows traced inside
                                   # a consumer's program (no slice program
                                   # or launch of their own)
+    "resplit_views": 0,           # re-splits (swap/_align with an identity
+                                  # permutation and unchanged sharding)
+                                  # served as a view: no program, no buffer
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -484,6 +487,14 @@ def record_getitems_fused(n):
     traces them inside its own program (no slice program, no launch of
     their own); the eager ``getitem`` program is span ``array.getitem``."""
     _COUNTERS.add("getitems_fused", n)
+
+
+def record_resplit_view():
+    """One re-split was served as a view of the buffer it was asked of:
+    the permutation was the identity and the sharding did not change, so
+    no program ran and no second buffer exists (``tpu/array.py ::
+    _do_swap``)."""
+    _COUNTERS.add("resplit_views")
 
 
 def donation_granted():

@@ -11,11 +11,9 @@ almost entirely serial.  This module takes the TPU-native route instead:
   Jacobi with the parallel (round-robin) ordering.  Every step applies
   n/2 disjoint rotations to the whole batch at once as two permutation
   gathers plus elementwise math — no matmuls, no data-dependent control
-  flow, one fixed-length ``lax.scan``.  On a (1024, 16, 16) batch on a
-  v5e chip: 29 ms for ``jnp.linalg.eigvalsh`` vs 7.7 ms standalone
-  (~4x; ~2 ms marginal once fused into the Gram pipeline — the rest is
-  this environment's per-dispatch floor), exact to f32 machine
-  precision.
+  flow, one fixed-length ``lax.scan``.  What it costs on the chip is
+  ``eigh_ms.scan`` of the benchmark's ``series64-1chip.pca`` cell
+  (``PERF.md``, section 5; the ledger's lines of that cell).
 * :func:`svdvals` / :func:`tallskinny_pca` — singular values / principal
   components of tall-skinny blocks via the Gram matrix: the (n, d) data
   is touched once by an MXU matmul and the eigenproblem is only (d, d),
@@ -39,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from bolt_tpu._precision import resolve as _resolve
+from bolt_tpu.obs import trace as _obs
 from bolt_tpu.utils import prod
 
 
@@ -79,11 +78,16 @@ def _round_robin(n):
 
 
 def _default_sweeps(n, dtype):
-    """Cyclic Jacobi converges quadratically once sweeps ~ log2(n); the +4
-    (+6 for f64's longer mantissa) lands at machine precision with margin —
-    measured ≤ 2e-6 rel. error (f32) for n up to 64 on random Gram
-    matrices."""
-    extra = 6 if jnp.finfo(dtype).bits >= 64 else 4
+    """Sweeps until a Gram matrix has settled.  The parallel ordering
+    converges quadratically only at the very end: a random Gram matrix of
+    n = 64 is at f32 machine precision after log2(n) + 4 sweeps, a
+    planted PCA's (eight strong components over a noise floor, six decades
+    in all) after log2(n) + 7; stopped at + 4, as this was, that one read
+    1e-4 of the largest eigenvalue, on the chip and off it (PERF.md, PR
+    26).  So + 8, and + 12 for f64's longer mantissa.  A spectrum graded
+    EVENLY over six decades takes log2(n) + 12 in f32: not served by the
+    default."""
+    extra = 12 if jnp.finfo(dtype).bits >= 64 else 8
     return max(6, int(math.ceil(math.log2(max(n, 2)))) + extra)
 
 
@@ -98,9 +102,13 @@ def jacobi_eigh(a, vectors=False, sweeps=None):
     A fixed-iteration cyclic Jacobi with parallel ordering: ``sweeps *
     (n - 1)`` scan steps, each applying ``n // 2`` disjoint rotations to
     every matrix in the batch via two permutation gathers + elementwise
-    arithmetic.  Best for large batches of small ``n`` (the per-chunk
-    PCA regime); for a single big matrix prefer ``jnp.linalg.eigh``.
-    Complex input falls back to ``jnp.linalg``.
+    arithmetic; ``sweeps`` defaults to :func:`_default_sweeps`, and a
+    spectrum graded evenly over many decades wants more (pass it).  The
+    count is fixed on purpose: the time does not depend on the data, and
+    plain-lax iteration needs no differentiation rule of its own.  Best
+    for large batches of small ``n`` (the per-chunk PCA regime); for a
+    single big matrix prefer ``jnp.linalg.eigh``.  Complex input falls
+    back to ``jnp.linalg``.
     """
     a = jnp.asarray(a)
     if a.ndim < 2 or a.shape[-1] != a.shape[-2]:
@@ -179,12 +187,14 @@ def jacobi_eigh(a, vectors=False, sweeps=None):
     return jnp.take_along_axis(w, order, axis=-1), V
 
 
-# Jacobi-vs-QDWH routing, measured on a v5e chip (batched Gram matrices,
-# steady state): jacobi wins 2.5-4.5x for d <= 64 at batch >= 1024 and
-# still ~1.2-1.4x at batch 64 for d in [32, 64], but LOSES for small
-# batch*d (the sequential sweep chain is launch-bound: d=16/batch=64 ->
-# 0.6x) and for d = 128 (0.3x — per-step O(B d^2) gathers outgrow QDWH's
-# matmuls).  Hence: small dims AND enough total work.
+# Jacobi-vs-QDWH routing: the batched sweep pays for small matrices in
+# large batches and loses for a small batch*d (the sequential sweep
+# chain is launch-bound) and for d > 64 (per-step O(B d^2) gathers
+# outgrow QDWH's matmuls).  Hence: small dims AND enough total work.
+# The thresholds were set on another host and toolchain and have no
+# ledger line of their own; the one routed case the benchmark measures
+# is ``series64-1chip.pca`` (80 Grams of 64 x 64 through Jacobi, one
+# through QDWH: ``eigh_ms.scan``, PERF.md section 5).
 _JACOBI_MAX_DIM = 64
 _JACOBI_MIN_WORK = 2048          # batch * d below this -> QDWH
 
@@ -248,8 +258,7 @@ def svdvals(x, gram_ratio=4):
     x = _widen(jnp.asarray(x), jnp)
     rows, cols = x.shape[-2], x.shape[-1]
     if rows >= gram_ratio * cols:
-        g = jnp.matmul(_adjoint(x), x, precision=_resolve("highest"),
-                       preferred_element_type=_acc_dtype(x.dtype))
+        g = _gram(x, jnp, _resolve("highest"))
         ev = _gram_eigvalsh(g)                         # ascending, real
         ev = jnp.maximum(ev[..., ::-1], 0.0)           # descending, clamped
         return jnp.sqrt(ev).astype(_real_dtype(x.dtype))
@@ -267,15 +276,99 @@ def _check_k(k, d):
 
 
 def _gram(x, xp, precision="highest"):
-    """The Gram matrix ``X^H X`` of ``(..., n, d)`` data — one MXU matmul
-    on TPU ("highest" precision, f32 accumulation, unless the caller
-    resolved a cheaper mode through the scoped policy)."""
-    xt = xp.swapaxes(x, -1, -2)
-    if xp.iscomplexobj(x):
-        xt = xp.conj(xt)
-    return xp.matmul(xt, x) if xp is np else \
-        xp.matmul(xt, x, precision=precision,
-                  preferred_element_type=_acc_dtype(x.dtype))
+    """The Gram matrix ``X^H X`` of one ``(..., n, d)`` block — an MXU
+    matmul on TPU ("highest" precision, f32 accumulation, unless the
+    caller resolved a cheaper mode through the scoped policy).  ONE
+    contraction over the block's rows: a block is what a caller's
+    ``vmap`` (a chunked map) has cut from a longer axis already, and on
+    the chip's tiled layout a second cut of that axis is a relayout copy
+    of the whole array where the first was a bitcast (the same deployment
+    at 32 time points, blocks of 2**20 rows cut once more, is refused at
+    compile: "Used 20.00G of 15.75G hbm").  The price is the matrix
+    unit's own running-sum bias over the block (see ``_GRAM_RUN``); a
+    whole bolt array goes through :func:`_sample_gram` instead."""
+    if xp is np:
+        xt = np.swapaxes(x, -1, -2)
+        return np.matmul(np.conj(xt) if np.iscomplexobj(x) else xt, x)
+    return _products(jnp.conj(x), x, precision)
+
+
+def _products(u, v, precision):
+    """``(.., len, d) x 2 -> (.., d, d)``: every leading axis a batch
+    axis, the rows contracted on the matrix unit."""
+    k = u.ndim - 2
+    acc = _acc_dtype(jnp.promote_types(u.dtype, v.dtype))
+    return jax.lax.dot_general(
+        u, v, (((k,), (k,)), (tuple(range(k)),) * 2),
+        precision=precision, preferred_element_type=acc)
+
+
+# rows of a whole array contracted in one go on the matrix unit.  At
+# "high"/"highest" the unit's float32 running sum loses a little with every
+# tile it adds, always downwards: on a v5e one contraction over 8,192 rows
+# came out 1.0e-7 under the true Gram matrix's diagonal, over 65,536 rows
+# 3.9e-6, over 524,288 rows 6.2e-6, and over 41.9 M rows 3.2e-3, worse than
+# a single bfloat16 pass (PERF.md, PR 26; splitting costs no time there).
+# So a whole array's sample axis is contracted in runs and the runs'
+# matrices are added outside the unit, by a reduction that rounds to
+# nearest.
+_GRAM_RUN = 1 << 14
+
+
+def _run_gram(a, b, precision):
+    """``sum over the sample axes of a[.., i] * b[.., j]`` for operands
+    shaped ``samples + (d,)`` (every axis but the last contracted):
+    ``(d, d)``.
+
+    The samples are contracted where they lie, never flattened to
+    ``(n, d)`` first: on the chip's tiled layout that reshape is a
+    relayout copy of the whole array wherever the sample axes are not
+    adjacent in memory (``f32[40,1048576,64]`` would not compile beside
+    its own copy on a 16 GB chip).  The last sample axis is cut into runs
+    of ``_GRAM_RUN`` rows (and a shorter tail where it does not divide);
+    every run of every leading sample index gives one ``(d, d)`` product
+    on the matrix unit, and those are summed outside it."""
+    n, d = a.shape[-2], a.shape[-1]
+    count, tail = divmod(n, _GRAM_RUN)
+    parts = []
+    if count:
+        cut = count * _GRAM_RUN
+        shape = a.shape[:-2] + (count, _GRAM_RUN, d)
+        parts.append(_products(a[..., :cut, :].reshape(shape),
+                               b[..., :cut, :].reshape(shape), precision))
+    if tail:
+        parts.append(_products(a[..., n - tail:, :], b[..., n - tail:, :],
+                               precision))
+    # the barrier keeps XLA from folding the sums back into one long
+    # contraction (reduce-of-batched-dot is a rewrite it knows)
+    parts = jax.lax.optimization_barrier(parts)
+    return sum(jnp.sum(part, axis=tuple(range(part.ndim - 2)))
+               for part in parts)
+
+
+def _sample_gram(x, precision, second_conj=False):
+    """The Gram matrix of ``sample_shape + (d,)`` data over all its
+    leading axes: ``sum conj(x[.., i]) * x[.., j]``, or with
+    ``second_conj`` ``sum x[.., i] * conj(x[.., j])`` (``np.cov``'s
+    convention).  See :func:`_run_gram`."""
+    a, b = (x, jnp.conj(x)) if second_conj else (jnp.conj(x), x)
+    return _run_gram(a, b, precision)
+
+
+def _project(x, vec, precision):
+    """``x @ vec`` over the trailing feature axis of ``sample_shape +
+    (d,)`` data, the sample axes left as they are."""
+    return jax.lax.dot_general(
+        x, vec, (((x.ndim - 1,), (0,)), ((), ())), precision=precision)
+
+
+def _features_last(mapped, kshape, d):
+    """``sample_shape + (d,)``: the feature axes merged into one (no
+    reshape at all for a single feature axis), widened for the
+    decomposition.  The sample axes are NOT merged."""
+    if mapped.shape != kshape + (d,):
+        mapped = mapped.reshape(kshape + (d,))
+    return _widen(mapped, jnp)
 
 
 def _decompose_gram(g, k, xp, eigh_fn):
@@ -421,8 +514,7 @@ def tsqr(x):
     eye = jnp.eye(d, dtype=x.dtype)
 
     def _chol_qr(a):
-        g = jnp.matmul(_adjoint(a), a, precision=_resolve("highest"),
-                       preferred_element_type=_acc_dtype(a.dtype))
+        g = _gram(a, jnp, _resolve("highest"))
         l = jnp.linalg.cholesky(g)                       # g = l @ l^H
         r = _adjoint(l)
         # invert only the small (d, d) triangle, then apply by matmul so
@@ -488,92 +580,127 @@ def pca(b, k=None, center=False, axis=None, return_mean=False,
     of the components) pipeline without paying a host round-trip each.
 
     ``precision=None`` resolves through the scoped policy
-    (``bolt.precision``), pinned at ``"highest"`` — the Gram and
-    projection matmuls are the measured ~2x of this op's cost;
-    ``"default"`` trades ~1e-2 relative score accuracy for it.  The
-    local oracle always computes in
-    f64.
+    (``bolt.precision``), pinned at ``"highest"`` — what the Gram and
+    projection matmuls cost at it is ``gram_roofline`` of the
+    benchmark's ``series64-1chip.pca`` cell (``PERF.md``, section 5);
+    ``"default"`` trades ~1e-2 relative score accuracy for one bf16
+    pass.  The local oracle always computes in f64.
+
+    The sample axes are contracted where they lie (``_sample_gram``,
+    ``_project``): a ``(K, N, d)`` array keyed on axis 0 with
+    ``axis=(0, 1)`` is never flattened to ``(K*N, d)``, which on the
+    chip's tiled layout is a relayout copy as large as the data.
+
+    Spans: ``linalg.pca`` from entry to the results in hand, with
+    ``linalg.pca.launch`` (alignment and the one program enqueued) and
+    ``linalg.pca.fetch`` (the small results brought to the host;
+    absent with ``fetch=False``) beneath it.
     """
     from bolt_tpu._precision import resolve
     pr = resolve(precision)
-    mode, b, x_full, split, shape, n, d = _samples_features(
-        b, axis, "pca", hint="; for plain matrices use tallskinny_pca")
-    kshape = shape[:split]
+    if getattr(b, "mode", None) != "tpu":
+        return _pca_local(b, k, center, axis, return_mean)
+    with _obs.span("linalg.pca", k=k, center=bool(center)):
+        with _obs.span("linalg.pca.launch"):
+            scores, vec, sv, mu = _pca_launch(b, k, center, axis, pr)
+        if fetch:
+            # ONE batched host fetch for the small results: separate
+            # device_gets cost a full host round-trip EACH.  With
+            # fetch=False nothing syncs: they stay on the device
+            with _obs.span("linalg.pca.fetch"):
+                vec, sv, mu = (np.asarray(a) for a in
+                               jax.device_get((vec, sv, mu)))
+        return (scores, vec, sv, mu) if return_mean else (scores, vec, sv)
+
+
+def _pca_sizes(k, n, d):
     if n < d:
         raise ValueError(
             "pca requires #samples >= #features (got %d x %d); swap your "
             "key/value axes or use jnp.linalg.svd" % (n, d))
-    k = _check_k(k, d)
+    return _check_k(k, d)
 
-    if mode == "local":
-        # the NumPy oracle: same sequence, host-side
-        x = _widen(x_full.reshape(n, d), np)
-        mu = x.mean(axis=0) if center else np.zeros(d, x.dtype)
-        if center:
-            x = x - mu
-        vec, ev = _gram_decompose(x, k, np, np.linalg.eigh)
-        vec = np.ascontiguousarray(vec)
-        scores = (x @ vec).reshape(kshape + (k,))
-        out = (type(b)(scores), vec,
-               np.sqrt(ev).astype(_real_dtype(x.dtype)))
-        return out + (mu,) if return_mean else out
 
+def _pca_local(b, k, center, axis, return_mean):
+    """The NumPy oracle of :func:`pca`: same sequence, host-side."""
+    _, b, x_full, split, shape, n, d = _samples_features(
+        b, axis, "pca", hint="; for plain matrices use tallskinny_pca")
+    k = _pca_sizes(k, n, d)
+    x = _widen(x_full.reshape(n, d), np)
+    mu = x.mean(axis=0) if center else np.zeros(d, x.dtype)
+    if center:
+        x = x - mu
+    vec, ev = _gram_decompose(x, k, np, np.linalg.eigh)
+    vec = np.ascontiguousarray(vec)
+    scores = (x @ vec).reshape(shape[:split] + (k,))
+    out = (type(b)(scores), vec, np.sqrt(ev).astype(_real_dtype(x.dtype)))
+    return out + (mu,) if return_mean else out
+
+
+def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
+    """The traced body of :func:`pca`'s one program over the base buffer
+    (``funcs``: the deferred map chain fused in front).  Module-level so
+    that a compile-only test can lower exactly what ships."""
     from bolt_tpu.parallel.sharding import key_sharding
-    from bolt_tpu.tpu.array import _cached_jit, _chain_apply
+    from bolt_tpu.tpu.array import _chain_apply
+    samples = tuple(range(split))
+    n = prod(kshape)
+
+    def program(data):
+        x = _features_last(_chain_apply(funcs, split, data), kshape, d)
+        # Centering folds into the Gram algebraically (round-4 fusion):
+        #   (X - mu)^T (X - mu) = X^T X - n mu mu^T
+        # so the centred matrix is NEVER materialised — the raw X is
+        # read by exactly two MXU matmuls (Gram + projection) plus the
+        # mean's fused reduction, instead of a mean pass, a centred
+        # copy (read+write), and two matmuls over the copy.  The
+        # projection offset is applied to the (k,)-sized result:
+        #   (X - mu) @ V = X @ V - mu @ V.
+        # Conditioning: the fold loses the centred formulation's
+        # guard against cancellation when ||mu|| >> sigma — the Gram
+        # loses ~eps_f32 * (mu/sigma)^2 relative accuracy (measured:
+        # ~1e-4 at 20 sigma, ~1e-2 at 200 sigma — see
+        # test_pca_centering_fold_large_offset).  Pre-shift data with
+        # larger offsets.
+        mu = jnp.mean(x, axis=samples) if center \
+            else jnp.zeros(d, x.dtype)
+        g = _sample_gram(x, pr)
+        if center:
+            g = g - n * jnp.outer(jnp.conj(mu), mu)
+        vec, ev = _decompose_gram(g, k, jnp, _tpu_eigh)
+        # pinned "highest": the MXU's bf16 default costs ~3 decimal
+        # digits on f32 data — visible in scores at PCA scale; the
+        # scoped policy buys it back where the user accepts that
+        scores = _project(x, vec, pr)
+        if center:
+            scores = scores - jnp.matmul(mu, vec, precision=pr)
+        scores = jax.lax.with_sharding_constraint(
+            scores, key_sharding(mesh, kshape + (k,), split))
+        return scores, vec, jnp.sqrt(ev), mu
+    return program
+
+
+def _pca_launch(b, k, center, axis, pr):
+    """Align, build (once per key) and enqueue :func:`pca`'s one program;
+    returns ``(scores bolt array, vec, sv, mu)`` with the small results
+    still on the device."""
+    from bolt_tpu.tpu.array import _cached_jit
+    _, b, _, split, shape, n, d = _samples_features(b, axis, "pca")
+    k = _pca_sizes(k, n, d)
+    kshape = shape[:split]
     # a deferred map chain fuses INTO the PCA program (one XLA program,
     # no materialised intermediate), same as map/filter/reduce consumers
     base, funcs = b._chain_parts()
     mesh = b._mesh
 
     def build():
-        def program(data):
-            mapped = _chain_apply(funcs, split, data)
-            x = _widen(mapped.reshape((n, d)), jnp)
-            # Centering folds into the Gram algebraically (round-4 fusion):
-            #   (X - mu)^T (X - mu) = X^T X - n mu mu^T
-            # so the centred matrix is NEVER materialised — the raw X is
-            # read by exactly two MXU matmuls (Gram + projection) plus the
-            # mean's fused reduction, instead of a mean pass, a centred
-            # copy (read+write), and two matmuls over the copy.  The
-            # projection offset is applied to the (k,)-sized result:
-            #   (X - mu) @ V = X @ V - mu @ V.
-            # Conditioning: the fold loses the centred formulation's
-            # guard against cancellation when ||mu|| >> sigma — the Gram
-            # loses ~eps_f32 * (mu/sigma)^2 relative accuracy (measured:
-            # ~1e-4 at 20 sigma, ~1e-2 at 200 sigma — see
-            # test_pca_centering_fold_large_offset).  Pre-shift data with
-            # larger offsets.
-            mu = jnp.mean(x, axis=0) if center else jnp.zeros(d, x.dtype)
-            g = _gram(x, jnp, pr)
-            if center:
-                g = g - n * jnp.outer(jnp.conj(mu), mu)
-            vec, ev = _decompose_gram(g, k, jnp, _tpu_eigh)
-            # pinned "highest": the MXU's bf16 default costs ~3 decimal
-            # digits on f32 data — visible in scores at PCA scale; the
-            # scoped policy buys it back where the user accepts that
-            scores = jnp.matmul(x, vec, precision=pr)
-            if center:
-                scores = scores - jnp.matmul(mu, vec, precision=pr)
-            scores = scores.reshape(kshape + (k,))
-            scores = jax.lax.with_sharding_constraint(
-                scores, key_sharding(mesh, kshape + (k,), split))
-            return scores, vec, jnp.sqrt(ev), mu
-        return jax.jit(program)
+        return jax.jit(_pca_program(funcs, split, kshape, d, k, center, pr,
+                                    mesh))
 
     fn = _cached_jit(("ops-pca", funcs, base.shape, str(base.dtype), split,
                       mesh, k, center, pr), build)
     scores, vec, sv, mu = fn(base)
-    wrapped = type(b)(scores, split, mesh)
-    if not fetch:
-        # async path: nothing syncs — small results stay on device
-        return (wrapped, vec, sv, mu) if return_mean else (wrapped, vec, sv)
-    # ONE batched host fetch for the small results: separate device_gets
-    # cost a full host round-trip EACH
-    if return_mean:
-        vec, sv, mu = jax.device_get((vec, sv, mu))
-        return wrapped, np.asarray(vec), np.asarray(sv), np.asarray(mu)
-    vec, sv = jax.device_get((vec, sv))
-    return wrapped, np.asarray(vec), np.asarray(sv)
+    return type(b)(scores, split, mesh), vec, sv, mu
 
 
 def tallskinny_pca(x, k=None):
@@ -666,17 +793,16 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
 
     def build():
         def program(data):
-            mapped = _chain_apply(funcs, split, data)
-            x = _widen(mapped.reshape((n, d)), jnp)
+            x = _features_last(_chain_apply(funcs, split, data),
+                               shape[:split], d)
             # same centering fold as pca (round 4): the centred copy is
             # never materialised — (X-mu)^T conj(X-mu) = X^T conj(X) -
             # n mu conj(mu)^T; same second-factor conjugation as np.cov.
             # Same conditioning envelope as pca's fold (~eps_f32 *
             # (mu/sigma)^2 relative error in the entries).
-            mu = jnp.mean(x, axis=0) if center else jnp.zeros(d, x.dtype)
-            c = jnp.matmul(jnp.swapaxes(x, -1, -2), jnp.conj(x),
-                           precision=pr,
-                           preferred_element_type=_acc_dtype(x.dtype))
+            mu = jnp.mean(x, axis=tuple(range(split))) if center \
+                else jnp.zeros(d, x.dtype)
+            c = _sample_gram(x, pr, second_conj=True)
             if center:
                 c = c - n * jnp.outer(mu, jnp.conj(mu))
                 # the explicit-centering path this fold replaced computed

@@ -2900,7 +2900,8 @@ class BoltArrayTPU(BoltArray):
         perm = (keys_rest + [split + v for v in vaxes]
                 + list(kaxes) + [split + v for v in values_rest])
         new_split = len(keys_rest) + len(vaxes)
-        if perm == list(range(self.ndim)) and new_split == split:
+        identity = perm == list(range(self.ndim))
+        if identity and new_split == split:
             return self
         if self._stream is not None:
             # a STREAMED source records the swap as a lazy stage instead
@@ -2915,6 +2916,22 @@ class BoltArrayTPU(BoltArray):
             if out is not NotImplemented:
                 return out
         mesh = self._mesh
+        if identity and not self.deferred:
+            # only ``split`` changes.  Where the key sharding of the new
+            # split places the data exactly as it lies, the re-split is a
+            # VIEW: no program, no second buffer (at HBM-filling sizes
+            # the copy would not fit beside its source).  Both wrappers
+            # then hold the one jax.Array, which is what the donation
+            # rule counts (:func:`_chain_donate_ok`: a base with two
+            # owners is never donated), so neither can be consumed while
+            # the other lives.  ``donate=True`` has nothing to hand over.
+            # (A deferred chain has a program to run anyway and fuses
+            # into the one below, as it always did.)
+            data = self._data
+            if data.sharding.is_equivalent_to(
+                    key_sharding(mesh, data.shape, new_split), data.ndim):
+                _engine.record_resplit_view()
+                return self._wrap(data, new_split)
 
         if not donate:
             # a deferred chain fuses into the transpose program (donation

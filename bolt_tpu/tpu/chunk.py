@@ -92,25 +92,45 @@ def _uniform_map_body(data, func, split, plan, canon=None):
     vshape = data.shape[split:]
     nv = len(vshape)
     grid = tuple(v // c for v, c in zip(vshape, plan))
+    # only an axis that is really cut (more than one chunk) is split into
+    # a (grid, block) pair.  An uncut axis keeps its place: a size-1 grid
+    # axis beside it says nothing, and on the chip's tiled layout it made
+    # the reshape a relayout copy of the whole array (a 10.74 GB
+    # temporary for f32[40,1048576,64] cut to (524288, 64) blocks, which
+    # does not fit beside its source; without it the reshape stays
+    # inside the consumer's fusion)
+    cut = [i for i in range(nv) if grid[i] > 1]
     newshape = kshape + tuple(
-        x for v, c in zip(vshape, plan) for x in (v // c, c))
+        x for i in range(nv)
+        for x in ((grid[i], plan[i]) if i in cut else (vshape[i],)))
     r = data.reshape(newshape)
-    g_axes = [split + 2 * i for i in range(nv)]
-    c_axes = [split + 2 * i + 1 for i in range(nv)]
+    g_axes, c_axes, pos = [], [], split
+    for i in range(nv):
+        if i in cut:
+            g_axes.append(pos)
+            pos += 1
+        c_axes.append(pos)
+        pos += 1
     r = jnp.transpose(
         r, tuple(range(split)) + tuple(g_axes) + tuple(c_axes))
+    ncut = len(cut)
     f = func
-    for _ in range(split + nv):
+    for _ in range(split + ncut):
         f = jax.vmap(f)
     out = f(r)
-    ob = out.shape[split + nv:]
+    ob = out.shape[split + ncut:]
     if len(ob) != nv:
         raise ValueError(
             "chunked map must preserve block rank: block %s "
             "-> %s" % (str(tuple(plan)), str(tuple(ob))))
-    perm = tuple(range(split)) + tuple(
-        x for i in range(nv) for x in (split + i, split + nv + i))
-    out = jnp.transpose(out, perm)
+    # each cut axis' grid goes back in front of its own block axis
+    perm, g = list(range(split)), 0
+    for i in range(nv):
+        if i in cut:
+            perm.append(split + g)
+            g += 1
+        perm.append(split + ncut + i)
+    out = jnp.transpose(out, tuple(perm))
     merged = kshape + tuple(g * o for g, o in zip(grid, ob))
     out = out.reshape(merged)
     if canon is not None:

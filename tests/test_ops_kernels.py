@@ -390,3 +390,66 @@ def test_window_statistic_is_one_fusion_on_v5e(v5e_device, name, window,
     assert not outside, outside
     assert not re.search(r"= \S+ slice\(", entry), name
     assert compiled.memory_analysis().temp_size_in_bytes < win_bytes
+
+
+# ---------------------------------------------------------------------
+# compile-only: BASELINE config 5 at HBM size (ISSUE 26).  Both programs
+# of the series64-1chip configuration have to fit one 16 GB chip beside
+# their 10.74 GB argument: no temporary as large as the data (a relayout
+# of the sample axes, the chunk grid or the Gram matrix's runs), which
+# the compiler refuses outright ("Used 20.00G of 15.75G hbm")
+# ---------------------------------------------------------------------
+
+_SERIES = (40, 1048576, 64)           # planes x voxels x time points
+
+
+def _series_mesh(v5e_device):
+    import jax
+    return jax.sharding.Mesh(np.asarray([v5e_device]), ("k",))
+
+
+def _compile_series(fn, v5e_device, shape=_SERIES):
+    import jax
+    mesh = _series_mesh(v5e_device)
+    where = jax.sharding.NamedSharding(mesh,
+                                       jax.sharding.PartitionSpec("k"))
+    with jax.enable_x64(False):
+        return jax.jit(fn).lower(jax.ShapeDtypeStruct(
+            shape, _F32, sharding=where)).compile().memory_analysis()
+
+
+def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device):
+    from bolt_tpu.ops import linalg
+    program = linalg._pca_program((), 2, _SERIES[:2], _SERIES[2], 8, True,
+                                  "highest", _series_mesh(v5e_device))
+    mem = _compile_series(program, v5e_device)
+    assert mem.argument_size_in_bytes == 4 * int(np.prod(_SERIES))
+    assert mem.temp_size_in_bytes < 0.1e9
+    # the scores and nothing else of any size come out
+    assert mem.output_size_in_bytes < 4 * int(np.prod(_SERIES[:2])) * 8 * 1.01
+
+
+# the cell's own shape, and the same deployment at 32 time points: its
+# blocks are 2**20 rows, longer than any run a Gram matrix was ever cut
+# into, and a block that svdvals cut a second time was a 10 GB relayout
+@pytest.mark.parametrize("shape,block", [
+    (_SERIES, (524288, 64)), ((40, 2097152, 32), (1048576, 32))],
+    ids=["64-time-points", "32-time-points"])
+def test_per_chunk_svd_fits_beside_its_argument_on_v5e(v5e_device, shape,
+                                                       block):
+    from bolt_tpu.ops import svdvals
+    from bolt_tpu.tpu.chunk import _constrain_chunked, _uniform_map_body
+    from bolt_tpu.utils import chunk_align, chunk_plan
+    axes, size, padding = chunk_align(shape[1:], (0,), "150", None)
+    plan = chunk_plan(shape[1:], 4, size, axes, padding=padding)
+    assert tuple(plan) == block               # upstream's default budget
+    mesh = _series_mesh(v5e_device)
+
+    def run(data):                    # ChunkedArray.map's uniform program
+        out = _uniform_map_body(data, lambda blk: svdvals(blk)[None, :], 1,
+                                tuple(plan))
+        return _constrain_chunked(out, mesh, 1, {})
+
+    mem = _compile_series(run, v5e_device, shape)
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert mem.output_size_in_bytes < 1e6

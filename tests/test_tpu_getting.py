@@ -234,13 +234,15 @@ def test_window_fuses_into_the_terminal_that_reads_it(mesh, form, terminal):
         assert np.array_equal(got, want, equal_nan=True)
     assert np.allclose(got, want, equal_nan=True)
     # one launch fewer than with the slice materialised, and the window
-    # counted once
+    # counted once (reduce over axis 0 of a keyless result re-splits
+    # first: of the materialised slice that is a view since PR 26, so
+    # there the two ways launch the same number)
+    resplit = (form, terminal) == ("int_on_key_axis", "reduce_add")
     assert n2["dispatches"] - n1["dispatches"] \
-        == n1["dispatches"] - n0["dispatches"] - 1
+        == n1["dispatches"] - n0["dispatches"] - (0 if resplit else 1)
     # ... which is ONE launch (ptp subtracts its two extrema in a second
-    # tiny program; reduce over axis 0 of a keyless result swaps first)
-    two_step = terminal == "ptp" or (form, terminal) == (
-        "int_on_key_axis", "reduce_add")
+    # tiny program; the re-split of a deferred window is a program)
+    two_step = terminal == "ptp" or resplit
     assert n2["dispatches"] - n1["dispatches"] == (2 if two_step else 1)
     assert n2["getitems_fused"] - n1["getitems_fused"] == 1
     assert n1["getitems_fused"] == n0["getitems_fused"]
