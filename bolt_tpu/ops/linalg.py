@@ -8,12 +8,19 @@ are built for one big matrix and leave a large batch of tiny problems
 almost entirely serial.  This module takes the TPU-native route instead:
 
 * :func:`jacobi_eigh` — batched symmetric eigendecomposition by cyclic
-  Jacobi with the parallel (round-robin) ordering.  Every step applies
-  n/2 disjoint rotations to the whole batch at once as two permutation
-  gathers plus elementwise math — no matmuls, no data-dependent control
-  flow, one fixed-length ``lax.scan``.  What it costs on the chip is
-  ``eigh_ms.scan`` of the benchmark's ``series64-1chip.pca`` cell
-  (``PERF.md``, section 5; the ledger's lines of that cell).
+  Jacobi with the parallel (round-robin) ordering.  Every round applies
+  n/2 disjoint rotations to the whole batch at once: elementwise math,
+  no matmuls, no data-dependent control flow, a fixed number of rounds.
+  Real float32 in a program compiled for one TPU device runs all the
+  rounds as ONE Mosaic kernel that keeps the batch in VMEM
+  (``_jacobi_kernel``: 0.84 ms for 80 matrices of 64 x 64 on a v5e);
+  every other dtype, backend and program runs them as one ``lax.scan``
+  of two permutation gathers plus elementwise math a round
+  (``_scan_sweeps``: 29.2 ms for the same, fifteen small XLA kernels a
+  round).  What either costs on the chip is ``eigh_ms.scan`` of the
+  benchmark's ``series64-1chip.pca`` cell (``PERF.md``, section 5; the
+  ledger's lines of that cell, PR 26 for the scan and PR 27 for the
+  kernel).
 * :func:`svdvals` / :func:`tallskinny_pca` — singular values / principal
   components of tall-skinny blocks via the Gram matrix: the (n, d) data
   is touched once by an MXU matmul and the eigenproblem is only (d, d),
@@ -23,13 +30,14 @@ almost entirely serial.  This module takes the TPU-native route instead:
 Rotation angles use ``0.5 * atan2(2*a_pq, a_qq - a_pp)`` — no divisions,
 no overflow for any input scale (the textbook ``tau = (a_qq - a_pp) /
 (2*a_pq)`` route overflows f32 near convergence and, on TPU, turns into
-NaN through the rsqrt lowering).  The row/column updates are pure
-elementwise f32, so results do not depend on the MXU's bf16 default the
-way a rotation-by-matmul formulation would.
+NaN through the rsqrt lowering); in the kernel the same formula, with
+``_atan2`` for the ``atan2`` that Mosaic does not lower.  The row/column
+updates are pure elementwise f32, so results do not depend on the MXU's
+bf16 default the way a rotation-by-matmul formulation would.
 """
 
 import math
-from functools import lru_cache
+from functools import lru_cache, partial
 
 import numpy as np
 
@@ -100,15 +108,18 @@ def jacobi_eigh(a, vectors=False, sweeps=None):
     when ``vectors=True``.
 
     A fixed-iteration cyclic Jacobi with parallel ordering: ``sweeps *
-    (n - 1)`` scan steps, each applying ``n // 2`` disjoint rotations to
-    every matrix in the batch via two permutation gathers + elementwise
-    arithmetic; ``sweeps`` defaults to :func:`_default_sweeps`, and a
-    spectrum graded evenly over many decades wants more (pass it).  The
-    count is fixed on purpose: the time does not depend on the data, and
-    plain-lax iteration needs no differentiation rule of its own.  Best
-    for large batches of small ``n`` (the per-chunk PCA regime); for a
-    single big matrix prefer ``jnp.linalg.eigh``.  Complex input falls
-    back to ``jnp.linalg``.
+    (n - 1)`` rounds, each applying ``n // 2`` disjoint rotations to
+    every matrix in the batch; ``sweeps`` defaults to
+    :func:`_default_sweeps`, and a spectrum graded evenly over many
+    decades wants more (pass it).  The count is fixed on purpose: the
+    time does not depend on the data.  Where the rounds run is chosen
+    when the program is lowered (:func:`_sweep_chain`): float32 up to
+    64 x 64 in a program for one TPU device is one Mosaic kernel with
+    the batch on the lanes (``vmap`` folds into that batch), anything
+    else one ``lax.scan``; both differentiate, by the scan's plain-lax
+    rules.  Best for large batches of small ``n`` (the per-chunk PCA
+    regime); for a single big matrix prefer ``jnp.linalg.eigh``.
+    Complex input falls back to ``jnp.linalg``.
     """
     a = jnp.asarray(a)
     if a.ndim < 2 or a.shape[-1] != a.shape[-2]:
@@ -135,6 +146,38 @@ def jacobi_eigh(a, vectors=False, sweeps=None):
         big = 1.0 + m * jnp.max(jnp.abs(a), axis=(-2, -1))
         a = a.at[..., n, n].set(big)
 
+    w, V = _sweep_chain(a, sweeps, vectors)
+    if odd:
+        w = w[..., :n]   # dummy never swaps, so it is still at index n
+    order = jnp.argsort(w, axis=-1)
+    if not vectors:
+        return jnp.take_along_axis(w, order, axis=-1)
+    if odd:
+        V = V[..., :n, :n]
+    V = jnp.take_along_axis(V, order[..., None, :], axis=-1)
+    return jnp.take_along_axis(w, order, axis=-1), V
+
+
+def _sweep_chain(a, sweeps, vectors):
+    """``sweeps`` sweeps of the parallel ordering over ``(..., m, m)``,
+    ``m`` even: the diagonal left and, with ``vectors``, the accumulated
+    rotations (else ``None``), both in the input's index order.  One
+    algorithm and one schedule, two executors.  Real float32 of at most
+    ``_JACOBI_MAX_DIM`` goes through :func:`_chain_entry`, which chooses
+    when the program is lowered: ONE Mosaic kernel where it is compiled
+    for a TPU, the ``lax.scan`` of :func:`_scan_sweeps` elsewhere.
+    Float64 and the half precisions are the scan's on every backend."""
+    if a.dtype == jnp.float32 and a.shape[-1] <= _JACOBI_MAX_DIM:
+        return _chain_entry(sweeps, vectors)(a)
+    return _scan_sweeps(a, sweeps, vectors)
+
+
+def _scan_sweeps(a, sweeps, vectors):
+    """:func:`_sweep_chain` in plain ``lax``: one ``lax.scan`` of
+    ``sweeps * (m - 1)`` rounds, each two permutation gathers plus
+    elementwise math (fifteen small XLA kernels a round on a TPU: 33 us
+    for 80 matrices of 64 x 64, ledger PR 26, ``eigh_ms.scan`` 14.7)."""
+    m = a.shape[-1]
     sched = np.tile(_round_robin(m), (sweeps, 1, 1))      # (S, m//2, 2)
     P = sched[..., 0]
     Q = sched[..., 1]
@@ -175,25 +218,323 @@ def jacobi_eigh(a, vectors=False, sweeps=None):
     V0 = jnp.broadcast_to(jnp.eye(m, dtype=a.dtype),
                           a.shape) if vectors else None
     (A, V), _ = jax.lax.scan(step, (a, V0), xs)
-    w = jnp.diagonal(A, axis1=-2, axis2=-1)
-    if odd:
-        w = w[..., :n]   # dummy never swaps, so it is still at index n
-    order = jnp.argsort(w, axis=-1)
+    return jnp.diagonal(A, axis1=-2, axis2=-1), V
+
+
+# ---------------------------------------------------------------------
+# the sweep chain as one Mosaic kernel.  The batch lies on the lanes and
+# a matrix's rows on the major axis, so ``(m, m, B)`` is ``m`` slabs of
+# ``(m, 128)``: 64 x 64 x 128 float32 = 2 MiB, resident in VMEM for all
+# ``sweeps * (m - 1)`` rounds.  The circle method is run with the
+# players moving through FIXED seats: seat ``i`` of the top half always
+# pairs with seat ``i`` of the bottom half, and between rounds everyone
+# but ``t0`` moves one seat on (``_seating``), so one loop body serves
+# every round: no index is dynamic but the round's
+# ---------------------------------------------------------------------
+
+_LANES = 128
+# XLA names the kernel's instruction, and so its event on the device
+# trace, after this; the benchmark tells the eigensolver by a name that
+# holds "while" or "custom-call" (benchmark/metrics/eigh_ms.scan.json,
+# gram_roofline.json), so the name is part of what those metrics read
+_KERNEL_NAME = "jacobi-sweeps-custom-call"
+
+
+@lru_cache(maxsize=None)
+def _seating(m):
+    """Who sits where: ``(m - 1, 2, m // 2)`` original indices held by
+    the top and the bottom seats in each round of a sweep.  Round 0 is
+    ``0..h-1`` over ``m-1..h``; the re-seating ``top' = t0, b0, t1 ..
+    t[h-2]``, ``bottom' = b1 .. b[h-1], t[h-1]`` is the circle method's
+    rotation, so each round's ``(top[i], bottom[i])`` are
+    :func:`_round_robin`'s pairs of that round, and after ``m - 1`` rounds
+    everyone is back where the sweep began."""
+    h = m // 2
+    top, bottom = list(range(h)), [m - 1 - i for i in range(h)]
+    rounds = []
+    for _ in range(m - 1):
+        rounds.append((top, bottom))
+        if h > 1:
+            top, bottom = ([top[0], bottom[0]] + top[1:h - 1],
+                           bottom[1:] + [top[h - 1]])
+    return np.asarray(rounds)
+
+
+def _seat_bits(m):
+    """One int32 a round: bit ``i`` says that seat ``i``'s top player is
+    the pair's smaller original index (``_JACOBI_MAX_DIM`` / 2 = 32 seats:
+    one word).  :func:`_scan_sweeps` rotates with ``p < q``; where the bit
+    is clear the kernel's pair is ``(q, p)`` and ``a_qq - a_pp`` and ``s``
+    change sign."""
+    seats = _seating(m)
+    up = (seats[:, 0] < seats[:, 1]).astype(np.uint32)
+    bits = (up << np.arange(m // 2, dtype=np.uint32)).sum(axis=1)
+    return bits.astype(np.uint32).view(np.int32)
+
+
+def _atan2(y, x):
+    """``atan2`` for the kernel: Mosaic (jax 0.9) lowers none.  Cephes'
+    ``atanf`` on ``min/max`` of the magnitudes (so no quotient leaves
+    ``[0, 1]`` whatever the scale), then the octant; ``atan2(0, 0) = 0``
+    like ``lax.atan2``, and ``-0.0`` counts as zero."""
+    ax, ay = jnp.abs(x), jnp.abs(y)
+    hi, lo = jnp.maximum(ax, ay), jnp.minimum(ax, ay)
+    t = lo / jnp.where(hi == 0.0, jnp.ones_like(hi), hi)
+    far = t > 0.4142135623730951                  # tan(pi / 8)
+    u = jnp.where(far, (t - 1.0) / (t + 1.0), t)
+    z = u * u
+    r = (((8.05374449538e-2 * z - 1.38776856032e-1) * z
+          + 1.99777106478e-1) * z - 3.33329491539e-1) * z * u + u
+    r = jnp.where(far, r + 0.25 * math.pi, r)
+    r = jnp.where(ay > ax, 0.5 * math.pi - r, r)
+    r = jnp.where(x < 0.0, math.pi - r, r)
+    return jnp.where(y < 0.0, -r, r)
+
+
+def _jacobi_kernel(bits_ref, a_ref, w_ref, *refs, m, rounds, vectors):
+    """All ``rounds`` of the chain over one block of 128 lanes.
+
+    ``a_ref`` is ``(mp * mp, 128)``: row ``r * mp + c`` holds entry
+    ``(r, c)`` of every matrix, rows and columns in SEAT order, the top
+    seats at ``0 .. h-1`` and the bottom seats at ``hp .. hp+h-1`` (``hp``
+    is ``h`` rounded up to the 8 sublanes of a tile; what lies between is
+    zero and stays zero).  ``w_ref`` ``(mp, 128)`` gets the diagonal,
+    ``v_ref`` ``(m * mp, 128)`` the rotations' product, its rows in index
+    order and its columns in seat order."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    v_ref = refs[0] if vectors else None
+    acc, c_ref, s_ref = refs[-3:]
+    h = m // 2
+    mp = w_ref.shape[0]
+    hp = mp // 2
+    lanes = w_ref.shape[1]
+    seat = jax.lax.broadcasted_iota(jnp.int32, (hp, lanes), 0)
+    real = seat < h
+
+    def slab(ref, r):
+        return ref.at[pl.ds(r * mp, mp), :]
+
+    def reseat(top, bottom):
+        # the column side of the re-seating, on the sublanes: a roll and
+        # a select (the row side is where a result slab is stored)
+        if h == 1:
+            return top, bottom
+        new_top = jnp.where(seat == 0, top, jnp.where(
+            seat == 1, pltpu.roll(bottom, 1, 0), pltpu.roll(top, 1, 0)))
+        new_bottom = jnp.where(seat == h - 1, top,
+                               pltpu.roll(bottom, hp - 1, 0))
+        if h != hp:
+            new_top = jnp.where(real, new_top, jnp.zeros_like(top))
+            new_bottom = jnp.where(real, new_bottom, jnp.zeros_like(top))
+        return new_top, new_bottom
+
+    def columns(x, c, s):
+        # (x J) for the round's h rotations, then the columns re-seated
+        top, bottom = x[:hp], x[hp:]
+        return reseat(c * top - s * bottom, c * bottom + s * top)
+
+    def store(ref, r, halves):
+        ref[pl.ds(r * mp, hp), :] = halves[0]
+        ref[pl.ds(r * mp + hp, hp), :] = halves[1]
+
+    acc[...] = a_ref[...]
+    if vectors:
+        column = jax.lax.broadcasted_iota(jnp.int32, (mp, lanes), 0)
+        for r in range(m):        # identity: index r sits in seat r, or
+            at = r if r < h else hp + (m - 1 - r)     # m-1-r of the bottom
+            slab(v_ref, r)[...] = (column == at).astype(jnp.float32)
+
+    def one_round(r, carry):
+        att = acc[pl.ds(0, hp, stride=mp + 1), :]
+        abb = acc[pl.ds(hp * mp + hp, hp, stride=mp + 1), :]
+        atb = acc[pl.ds(hp, hp, stride=mp + 1), :]
+        bits = bits_ref[jax.lax.rem(r, jnp.int32(m - 1))]
+        up = (jax.lax.shift_right_logical(bits, seat) & 1) == 1
+        theta = 0.5 * _atan2(2.0 * atb,
+                             jnp.where(up, abb - att, att - abb))
+        c = jnp.cos(theta)
+        s = jnp.sin(theta)
+        s = jnp.where(up, s, -s)
+        c_ref[...] = c
+        s_ref[...] = s
+
+        def pair(i):
+            t, b = slab(acc, i)[...], slab(acc, hp + i)[...]
+            ci, si = c_ref[pl.ds(i, 1), :], s_ref[pl.ds(i, 1), :]
+            return (columns(ci * t - si * b, c, s),
+                    columns(ci * b + si * t, c, s))
+
+        # rows: seat i's pair leaves for top[i + 1] and bottom[i - 1]
+        # (t0 stays, b0 goes to top[1], t[h-1] to bottom[h-1]).  Going
+        # down from h - 1, every store lands on a slab already read but
+        # bottom[i - 1]'s, which waits one step in ``held``
+        new_t, held = pair(h - 1)
+        if h == 1:                      # one pair: nobody moves
+            store(acc, 0, new_t)
+            store(acc, hp, held)
+        else:
+            store(acc, hp + h - 1, new_t)
+            for i in range(h - 2, -1, -1):
+                new_t, new_b = pair(i)
+                store(acc, hp + i, held)
+                store(acc, i + (i > 0), new_t)
+                held = new_b
+            store(acc, 1, held)
+        if vectors:
+            for r_ in range(m):
+                store(v_ref, r_, columns(slab(v_ref, r_)[...], c, s))
+        return carry
+
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(rounds), one_round, 0)
+    w_ref[pl.ds(0, hp), :] = acc[pl.ds(0, hp, stride=mp + 1), :]
+    w_ref[pl.ds(hp, hp), :] = acc[pl.ds(hp * mp + hp, hp, stride=mp + 1), :]
+
+
+def _lane_sweeps(a, sweeps, vectors):
+    """:func:`_scan_sweeps` by the kernel, over a flat batch ``(B, m, m)``
+    float32, ``m`` even: ``w (B, m)`` and ``v (B, m, m)`` or ``None``.
+    The batch goes to the lanes, padded to blocks of 128 (a zero matrix
+    rotates by ``atan2(0, 0) = 0``), one grid step a block; the few
+    transposes around the call are XLA's, over B * m * m elements."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    B, m = a.shape[0], a.shape[-1]
+    h = m // 2
+    hp = -(-h // 8) * 8
+    mp = 2 * hp
+    lanes = -(-max(B, 1) // _LANES) * _LANES
+    # index -> seat order (top 0..h-1, bottom m-1..h), the halves padded
+    # apart to hp, the batch last and padded to whole lane blocks
+    seats = _seating(m)[0].reshape(-1)
+    x = a[:, seats][:, :, seats].reshape(B, 2, h, 2, h)
+    x = jnp.pad(x, ((0, lanes - B), (0, 0), (0, hp - h), (0, 0),
+                    (0, hp - h)))
+    x = x.reshape(lanes, mp * mp).T
+    block = lambda rows: pl.BlockSpec((rows, _LANES), lambda g: (0, g))
+    shapes = [jax.ShapeDtypeStruct((mp, lanes), jnp.float32)]
+    if vectors:
+        shapes.append(jax.ShapeDtypeStruct((m * mp, lanes), jnp.float32))
+    out = pl.pallas_call(
+        partial(_jacobi_kernel, m=m, rounds=sweeps * (m - 1),
+                vectors=vectors),
+        out_shape=shapes,
+        grid=(lanes // _LANES,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), block(mp * mp)],
+        out_specs=[block(s.shape[0]) for s in shapes],
+        scratch_shapes=[pltpu.VMEM((mp * mp, _LANES), jnp.float32),
+                        pltpu.VMEM((hp, _LANES), jnp.float32),
+                        pltpu.VMEM((hp, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        name=_KERNEL_NAME,
+    )(jnp.asarray(_seat_bits(m)), x)
+    # seat order back to index order
+    back = np.argsort(seats)
+    w = out[0].reshape(2, hp, lanes)[:, :h, :B].reshape(m, B)[back].T
     if not vectors:
-        return jnp.take_along_axis(w, order, axis=-1)
-    if odd:
-        V = V[..., :n, :n]
-    V = jnp.take_along_axis(V, order[..., None, :], axis=-1)
-    return jnp.take_along_axis(w, order, axis=-1), V
+        return w, None
+    v = out[1].reshape(m, 2, hp, lanes)[:, :, :h, :B].reshape(m, m, B)
+    return w, jnp.transpose(v[:, back], (2, 0, 1))
+
+
+def _mosaic_fits(ctx):
+    """Whether a Mosaic kernel can be placed in the program being lowered
+    (``tpu_custom_call``'s own rule): a program for one device, or the
+    inside of a fully manual ``shard_map``.  GSPMD does not partition a
+    kernel, so a program for several chips outside ``shard_map`` keeps
+    the scan."""
+    axes = ctx.module_context.axis_context
+    if hasattr(axes, "manual_axes"):
+        return (axes.manual_axes | set(axes.mesh.manual_axes)
+                == frozenset(axes.mesh.axis_names))
+    return getattr(axes, "num_devices", 1) == 1
+
+
+def _sweeps_primitive():
+    """``jacobi_sweeps``: the chain over a flat batch ``(B, m, m)``
+    float32, as a primitive because its executor is chosen when a program
+    is LOWERED: by then the target is known (the engine lowers for the
+    mesh it compiles for, a compile-only test for a described chip), at
+    trace time it is not.
+
+    Under ``vmap`` (a chunked map reaches here under two) ``pallas_call``'s
+    own rule would make each mapped axis a grid axis: 80 kernel instances
+    with ONE lane in use.  This rule folds the mapped axis into the flat
+    batch and binds again, so nesting folds twice."""
+    from jax._src import dispatch       # eager calls: jax's own cache
+    from jax.extend.core import Primitive
+    from jax.interpreters import batching, mlir
+    prim = Primitive("jacobi_sweeps")
+    prim.multiple_results = True
+    prim.def_impl(partial(dispatch.apply_primitive, prim))
+
+    @prim.def_abstract_eval
+    def _(a, *, sweeps, vectors):
+        out = [a.update(shape=a.shape[:2])]
+        return out + [a] if vectors else out
+
+    def lower(fits):
+        def rule(ctx, a, *, sweeps, vectors):
+            kernel = fits(ctx)
+            fn = _lane_sweeps if kernel else _scan_sweeps
+            # Mosaic has no 64-bit types; everything the kernel's side
+            # traces is float32 and int32 whatever the session's x64 says
+            with jax.enable_x64(jax.config.jax_enable_x64 and not kernel):
+                return mlir.lower_fun(
+                    lambda x: jax.tree.leaves(fn(x, sweeps, vectors)),
+                    multiple_results=True)(ctx, a)
+        return rule
+
+    mlir.register_lowering(prim, lower(lambda ctx: False))
+    mlir.register_lowering(prim, lower(_mosaic_fits), platform="tpu")
+
+    def fold(args, dims, **params):
+        a = jnp.moveaxis(args[0], dims[0], 0)
+        out = prim.bind(a.reshape((-1,) + a.shape[2:]), **params)
+        return [o.reshape(a.shape[:2] + o.shape[1:]) for o in out], \
+            [0] * len(out)
+
+    batching.primitive_batchers[prim] = fold
+    return prim
+
+
+_sweeps_p = _sweeps_primitive()
+
+
+@lru_cache(maxsize=None)
+def _chain_entry(sweeps, vectors):
+    """:func:`_scan_sweeps` for ``(..., m, m)`` float32: the flattened
+    batch through ``jacobi_sweeps``.  A ``pallas_call`` has no
+    differentiation rule: this one's is the scan's, on the chip too."""
+
+    @jax.custom_jvp
+    def run(a):
+        out = _sweeps_p.bind(a.reshape((-1,) + a.shape[-2:]),
+                             sweeps=sweeps, vectors=vectors)
+        w, v = [o.reshape(a.shape[:-2] + o.shape[1:])
+                for o in out] + [None] * (not vectors)
+        return w, v
+
+    @run.defjvp
+    def _(primals, tangents):
+        return jax.jvp(lambda a: _scan_sweeps(a, sweeps, vectors),
+                       primals, tangents)
+
+    return run
 
 
 # Jacobi-vs-QDWH routing: the batched sweep pays for small matrices in
 # large batches and loses for a small batch*d (the sequential sweep
-# chain is launch-bound) and for d > 64 (per-step O(B d^2) gathers
-# outgrow QDWH's matmuls).  Hence: small dims AND enough total work.
-# The thresholds were set on another host and toolchain and have no
-# ledger line of their own; the one routed case the benchmark measures
-# is ``series64-1chip.pca`` (80 Grams of 64 x 64 through Jacobi, one
+# chain is latency-bound) and for d > 64 (the scan's per-step O(B d^2)
+# gathers outgrow QDWH's matmuls; the kernel seats at most 64 indices).
+# Hence: small dims AND enough total work.  The thresholds were set for
+# the scan on another host and toolchain, have no ledger line of their
+# own and were NOT moved when the kernel came (PR 27), though it changes
+# the trade: on a v5e 80 Grams of 64 x 64 cost 0.84 ms through the
+# kernel, 29.2 ms through the scan and 13.7 ms through XLA's eigvalsh
+# (PERF.md section 6, PR 27).  The one routed case the benchmark
+# measures is ``series64-1chip.pca`` (80 Grams through Jacobi, one
 # through QDWH: ``eigh_ms.scan``, PERF.md section 5).
 _JACOBI_MAX_DIM = 64
 _JACOBI_MIN_WORK = 2048          # batch * d below this -> QDWH

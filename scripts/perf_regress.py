@@ -312,10 +312,12 @@ def fam_jacobi_eigh():
     from bolt_tpu.ops.linalg import jacobi_eigh
     # the batched small-matrix eigensolver (the PCA family's (d, d)
     # kernel, stress-shaped: many matrices).  Neither HBM- nor MXU-bound:
-    # the sweep chain is a fixed-length sequential scan of gather +
-    # elementwise rounds — its wall clock is round-count x per-round
-    # latency, so the family gates regressions in the schedule/rotation
-    # formulation, not a bandwidth number.
+    # the sweep chain is a fixed number of sequential rounds of
+    # elementwise rotations (one Mosaic kernel over 128 lane blocks on a
+    # TPU, a lax.scan of gather + elementwise rounds elsewhere) — its
+    # wall clock is round-count x per-round latency, so the family gates
+    # regressions in the schedule/rotation formulation, not a bandwidth
+    # number.
     batch, n = 16384, 16                          # 67 MB of matrices
     g = bolt.randn((batch, n, n), mode="tpu", seed=13,
                    dtype=np.float32).tojax()

@@ -314,7 +314,20 @@ _MOSAIC_CASES = [
      lambda x, ax=ax, taps=taps: kernels.sepfilter1d(
          x, taps, ax, interpret=False), [((16, 64, 256), _F32)])
     for ax in (0, 1, 2) for taps in (_BOX9, _BOX25)
+] + [
+    # ops/linalg.py's sweep chain (ISSUE 27): 64 is the benchmark's, 8 and
+    # 2 pad the seats' halves to a tile, 5 is padded to 6 by the dummy
+    ("jacobi-%s-%dx%dx%d" % (("vectors" if vec else "values",) + shape),
+     lambda a, vec=vec: _jacobi_eigh(a, vectors=vec), [(shape, _F32)])
+    for shape, vec in [((80, 64, 64), False), ((80, 64, 64), True),
+                       ((300, 8, 8), True), ((300, 5, 5), False),
+                       ((1000, 2, 2), True)]
 ]
+
+
+def _jacobi_eigh(a, vectors=False):
+    from bolt_tpu.ops import jacobi_eigh
+    return jacobi_eigh(a, vectors=vectors)
 
 
 @pytest.fixture(scope="module")
@@ -415,14 +428,14 @@ def _compile_series(fn, v5e_device, shape=_SERIES):
                                        jax.sharding.PartitionSpec("k"))
     with jax.enable_x64(False):
         return jax.jit(fn).lower(jax.ShapeDtypeStruct(
-            shape, _F32, sharding=where)).compile().memory_analysis()
+            shape, _F32, sharding=where)).compile()
 
 
 def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device):
     from bolt_tpu.ops import linalg
     program = linalg._pca_program((), 2, _SERIES[:2], _SERIES[2], 8, True,
                                   "highest", _series_mesh(v5e_device))
-    mem = _compile_series(program, v5e_device)
+    mem = _compile_series(program, v5e_device).memory_analysis()
     assert mem.argument_size_in_bytes == 4 * int(np.prod(_SERIES))
     assert mem.temp_size_in_bytes < 0.1e9
     # the scores and nothing else of any size come out
@@ -450,6 +463,59 @@ def test_per_chunk_svd_fits_beside_its_argument_on_v5e(v5e_device, shape,
                                 tuple(plan))
         return _constrain_chunked(out, mesh, 1, {})
 
-    mem = _compile_series(run, v5e_device, shape)
+    compiled = _compile_series(run, v5e_device, shape)
+    mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.1e9
     assert mem.output_size_in_bytes < 1e6
+    # the 80 blocks' eigenproblems, reached under the map's two vmaps, are
+    # ONE kernel call with the 80 on the lanes of one block (ISSUE 27)
+    _one_jacobi_kernel(compiled.as_text(), block[1], 128)
+
+
+def _one_jacobi_kernel(text, m, lanes):
+    """The compiled program holds the sweep chain as ONE Mosaic call over
+    ``lanes`` lanes of ``m x m`` matrices: no ``while`` holds the rounds,
+    and the call's instruction, and so its event on the device trace, has
+    a name that ``benchmark/metrics/eigh_ms.scan.json`` matches."""
+    import re
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 1, calls
+    assert re.match(r"\s*%\S*custom-call\S* = ", calls[0]), calls[0]
+    half = -(-(m // 2) // 8) * 8
+    assert "f32[%d,%d]" % (4 * half * half, lanes) in calls[0], calls[0]
+    assert " while(" not in text
+
+
+@pytest.mark.parametrize("shape,lanes", [
+    ((80, 64, 64), 128), ((130, 8, 8), 256), ((13, 10, 8, 8), 256)],
+    ids=["80-of-64", "one-vmap-130", "two-vmaps-13x10"])
+def test_jacobi_is_one_kernel_call_on_v5e(v5e_device, shape, lanes):
+    # pallas_call's own batching rule would give a grid step, and ONE lane
+    # in use, to each mapped matrix: the mapped sizes' product has to
+    # arrive on the lanes of one call
+    import jax
+    fn = _jacobi_eigh
+    for _ in shape[:-3]:
+        fn = jax.vmap(fn)
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    with jax.enable_x64(False):
+        text = jax.jit(fn).lower(jax.ShapeDtypeStruct(
+            shape, _F32, sharding=where)).compile().as_text()
+    _one_jacobi_kernel(text, shape[-1], lanes)
+
+
+def test_jacobi_keeps_the_scan_in_a_program_for_four_chips(v5e_device):
+    # GSPMD cannot partition a Mosaic kernel ("Please wrap the call in a
+    # shard_map"): outside shard_map a program for several chips has to
+    # keep the lax.scan, and compile
+    import jax
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("k",))
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
+    with jax.enable_x64(False):
+        text = jax.jit(_jacobi_eigh).lower(jax.ShapeDtypeStruct(
+            (400, 8, 8), _F32, sharding=where)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert " while(" in text

@@ -7,6 +7,7 @@
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from bolt_tpu.ops import jacobi_eigh
@@ -316,3 +317,152 @@ def test_lstsq_dtype_promotion_and_complex_rejection():
     assert np.asarray(x).dtype == np.float64   # promoted, not narrowed
     with pytest.raises(ValueError):
         lstsq(jnp.asarray(a32), jnp.asarray(b64 + 1j * b64))
+
+
+# ---------------------------------------------------------------------
+# the sweep chain as one Mosaic kernel (ISSUE 27).  On this CPU mesh
+# ``jacobi_eigh`` lowers to the ``lax.scan``; the kernel itself runs here
+# in Pallas' TPU interpret mode, against that scan, at small sizes
+# ---------------------------------------------------------------------
+
+@pytest.mark.parametrize("m", range(2, 66, 2))
+def test_fixed_seats_reproduce_round_robin(m):
+    # pure NumPy: seat i of the top half against seat i of the bottom half
+    # gives _round_robin's pairs round for round, the sign bit says which
+    # of the two seats holds the pair's smaller index, and a sweep ends
+    # with everyone back in the seats it began with
+    from bolt_tpu.ops.linalg import _round_robin, _seat_bits, _seating
+    seats, bits = _seating(m), _seat_bits(m)
+    assert seats.shape == (m - 1, 2, m // 2) and bits.shape == (m - 1,)
+    assert bits.dtype == np.int32
+    for r in range(m - 1):
+        top, bottom = seats[r]
+        pairs = sorted(zip(np.minimum(top, bottom).tolist(),
+                           np.maximum(top, bottom).tolist()))
+        assert pairs == [tuple(p) for p in _round_robin(m)[r].tolist()]
+        up = [(int(bits[r]) >> i) & 1 for i in range(m // 2)]
+        assert up == (top < bottom).astype(int).tolist()
+    top, bottom = seats[-1].tolist()
+    if m > 2:       # one more re-seating closes the cycle
+        top, bottom = ([top[0], bottom[0]] + top[1:-1],
+                       bottom[1:] + [top[-1]])
+    assert [top, bottom] == seats[0].tolist()
+
+
+def _sym32(rs, batch, n):
+    x = rs.randn(batch, 3 * n, n).astype(np.float32)
+    return np.einsum("bni,bnj->bij", x, x)
+
+
+@pytest.mark.parametrize("vectors", [False, True], ids=["values", "vectors"])
+@pytest.mark.parametrize("batch", [1, 3, 130])
+@pytest.mark.parametrize("n", [4, 7, 8])
+def test_kernel_matches_the_scan(n, batch, vectors):
+    # a few sweeps, far from converged: the two executors agree only if
+    # they apply the same rotations in the same order.  130 matrices are
+    # two lane blocks (the second 2 matrices and 126 of padding); n = 7 is
+    # padded to 8 with the decoupled dummy, as jacobi_eigh pads it
+    from jax.experimental.pallas import tpu as pltpu
+    from bolt_tpu.ops.linalg import _lane_sweeps, _scan_sweeps
+    g = _sym32(np.random.RandomState(100 * n + batch), batch, n)
+    if n % 2:
+        g = np.pad(g, [(0, 0), (0, 1), (0, 1)])
+        g[:, n, n] = 1.0 + (n + 1) * np.abs(g).max(axis=(-2, -1))
+    g = jnp.asarray(g)
+    want_w, want_v = _scan_sweeps(g, 3, vectors)
+    with pltpu.force_tpu_interpret_mode():
+        got_w, got_v = _lane_sweeps(g, 3, vectors)
+    assert (got_v is None) == (want_v is None)
+    assert got_w.dtype == jnp.float32 and got_w.shape == want_w.shape
+    scale = float(np.abs(np.asarray(g)).max())
+    assert np.max(np.abs(np.asarray(got_w) - np.asarray(want_w))) \
+        < 2e-5 * scale
+    if vectors:
+        assert got_v.shape == want_v.shape
+        assert np.max(np.abs(np.asarray(got_v) - np.asarray(want_v))) < 2e-5
+
+
+def test_kernel_atan2_matches_numpy():
+    from bolt_tpu.ops.linalg import _atan2
+    rs = np.random.RandomState(7)
+    y = np.concatenate([rs.randn(4000) * 10.0 ** rs.randint(-30, 30, 4000),
+                        [0.0, 0.0, 0.0, 1.0, -1.0, 3e38, 2e-38]])
+    x = np.concatenate([rs.randn(4000) * 10.0 ** rs.randint(-30, 30, 4000),
+                        [0.0, 2.0, -2.0, 0.0, 0.0, 3e38, -2e-38]])
+    y, x = y.astype(np.float32), x.astype(np.float32)
+    got = np.asarray(_atan2(jnp.asarray(y), jnp.asarray(x)))
+    want = np.arctan2(y.astype(np.float64), x.astype(np.float64))
+    assert got.dtype == np.float32 and np.all(np.isfinite(got))
+    assert np.max(np.abs(got - want)) < 4e-7
+
+
+def _eqns(jaxpr, name):
+    """Every equation called ``name`` in a jaxpr and the jaxprs it holds."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == name:
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_eqns(sub, name))
+    return found
+
+
+@pytest.mark.parametrize("shape", [(6, 4, 4), (5, 3, 4, 4), (5, 3, 2, 4, 4)],
+                         ids=["one-vmap", "two-vmaps", "two-vmaps-of-a-batch"])
+def test_vmap_folds_into_the_lane_batch(shape):
+    # pallas_call's own batching rule would make every mapped axis a grid
+    # axis, one lane in use each: the mapped axes must fold into the flat
+    # batch of ONE jacobi_sweeps (one kernel call where it lowers for a
+    # TPU; tests/test_ops_kernels.py counts that call's lanes)
+    f = lambda a: jacobi_eigh(a, vectors=True)
+    for _ in range(min(len(shape) - 2, 2)):
+        f = jax.vmap(f)
+    g = jnp.asarray(np.random.RandomState(3).randn(*shape).astype(np.float32))
+    g = g + jnp.swapaxes(g, -1, -2)
+    calls = _eqns(jax.make_jaxpr(f)(g).jaxpr, "jacobi_sweeps")
+    assert len(calls) == 1
+    assert calls[0].invars[0].aval.shape == (int(np.prod(shape[:-2])), 4, 4)
+    w, v = f(g)
+    want_w, want_v = jacobi_eigh(g, vectors=True)
+    assert w.shape == shape[:-1] and v.shape == shape
+    assert np.array_equal(np.asarray(w), np.asarray(want_w))
+    assert np.array_equal(np.asarray(v), np.asarray(want_v))
+
+
+def test_kernel_entry_differentiates_as_the_scan():
+    # a pallas_call has no differentiation rule: the entry's is the scan's
+    from bolt_tpu.ops.linalg import _chain_entry, _scan_sweeps
+    rs = np.random.RandomState(11)
+    g = jnp.asarray(_sym32(rs, 3, 6))
+    t = jnp.asarray(rs.randn(3, 6).astype(np.float32))
+    for vectors in (False, True):
+        def loss(run):
+            def f(a):
+                w, v = run(a)
+                extra = (v * v[..., ::-1]).sum() if vectors else 0.0
+                return (w * t).sum() + extra
+            return f
+        got = jax.grad(loss(_chain_entry(5, vectors)))(g)
+        want = jax.grad(loss(lambda a: _scan_sweeps(a, 5, vectors)))(g)
+        assert got.dtype == jnp.float32
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    # and under vmap of grad (the scan's rule batches like any lax code)
+    per = jax.vmap(jax.grad(lambda a: _chain_entry(5, False)(a)[0].sum()))(g)
+    assert np.allclose(np.asarray(per), np.eye(6), atol=1e-5)
+
+
+@pytest.mark.parametrize("devices,kernel", [(1, True), (4, False)],
+                         ids=["one-device", "four-devices"])
+def test_the_executor_is_chosen_when_the_program_is_lowered(devices, kernel):
+    # lowered FOR a TPU on this CPU host (nothing compiles, nothing runs):
+    # a program for one device holds the Mosaic kernel; GSPMD cannot
+    # partition one, so a program for several devices keeps the scan
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:devices]), ("k",))
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
+    arg = jax.ShapeDtypeStruct((8, 6, 6), jnp.float32, sharding=where)
+    text = jax.jit(jacobi_eigh).trace(arg).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert ("tpu_custom_call" in text) == kernel
+    assert ("stablehlo.while" in text) != kernel
+    cpu = jax.jit(jacobi_eigh).lower(arg).as_text()
+    assert "tpu_custom_call" not in cpu and "stablehlo.while" in cpu
