@@ -521,8 +521,8 @@ def _remap_state_path(path, meta):
 
 def stream_clear(path, multiprocess=None):
     """Remove a directory's stream checkpoint (the success path: a
-    finished run must leave NO stale checkpoint behind — the
-    ``bench_all --check`` gate asserts it).  Meta first, then state —
+    finished run must leave NO stale checkpoint behind —
+    ``tests/test_resilience.py`` asserts it).  Meta first, then state —
     the reverse of the write order, so an interrupted clear also never
     leaves meta pointing at missing state.  Multi-process (same
     ``multiprocess`` contract as :func:`stream_save` — the executor
@@ -728,7 +728,7 @@ def spill_pending(path):
 def spill_clear(path):
     """Remove every shuffle spill working directory under ``path`` (the
     success path: a completed shuffle's phase 2 owns its buckets only
-    until the output is consumed — the ``bench_all --check`` gate
+    until the output is consumed — ``tests/test_stream_swap.py``
     asserts a cleared directory holds no ``bolt-spill-*`` residue,
     half-written ``.tmp`` droppings included)."""
     import shutil

@@ -63,10 +63,6 @@ from bolt_tpu.obs.trace import clock as _clock
 
 CACHE_MAX = 512                      # keyed entries (same bound as before)
 
-# AOT can be turned off (pure jit dispatch, still keyed + counted) for
-# debugging signature mismatches: BOLT_ENGINE_AOT=0
-_AOT = os.environ.get("BOLT_ENGINE_AOT", "1").lower() not in ("0", "false")
-
 # donation floor: terminals donate sole-owned chain bases at or above
 # this size.  The default is deliberately HBM-scale (64 MB): donation's
 # win is one-shot multi-GB chains where input + intermediate cannot
@@ -816,9 +812,6 @@ class _Dispatch:
                 _obs.end(sp)
 
     def _dispatch(self, args):
-        if not _AOT:
-            _COUNTERS.add("fallbacks")
-            return self._enqueue(self.jitted, args)
         sp = _obs.begin("engine.signature")
         try:
             leaves, treedef = jax.tree_util.tree_flatten(args)
@@ -875,9 +868,10 @@ class _Dispatch:
         # NOTE: a COLD fallback traces+compiles inside jit's first call,
         # i.e. under the order lock — unavoidable here because plain jit
         # dispatch fuses compile and enqueue.  Fallbacks are rare by
-        # construction (unhashable leaves, argument-validation drift) and
-        # BOLT_ENGINE_AOT=0 is an explicit single-user debug mode; the
-        # hot AOT path above compiles OUTSIDE the lock.
+        # construction (a leaf with no signature, such as a tracer when
+        # the program is called under somebody's trace; argument-
+        # validation drift); the hot AOT path above compiles OUTSIDE
+        # the lock.
         return self._enqueue(self.jitted, args)
 
 

@@ -38,7 +38,7 @@ property the fast CLI gates rely on); reaching them through the
 """
 
 from bolt_tpu.obs import metrics
-from bolt_tpu.obs.export import report, timeline, to_chrome, trace_arg
+from bolt_tpu.obs.export import report, timeline, to_chrome
 from bolt_tpu.obs.metrics import registry, thread_census
 from bolt_tpu.obs.trace import (Span, active_count, begin, cancel, clear,
                                 clock, current, disable, enable, enabled,
@@ -47,4 +47,4 @@ from bolt_tpu.obs.trace import (Span, active_count, begin, cancel, clear,
 __all__ = ["Span", "active_count", "begin", "cancel", "clear", "clock",
            "current", "disable", "enable", "enabled", "end", "event",
            "metrics", "registry", "report", "span", "spans",
-           "thread_census", "timeline", "to_chrome", "totals", "trace_arg"]
+           "thread_census", "timeline", "to_chrome", "totals"]

@@ -121,18 +121,6 @@ def report():
     return "\n".join(lines)
 
 
-def trace_arg(argv):
-    """Parse the conventional ``--trace out.json`` / ``--trace=out.json``
-    CLI flag (the ONE parser both ``scripts/bench_all.py`` and
-    ``scripts/perf_regress.py`` use); returns the path or ``None``."""
-    for i, a in enumerate(argv):
-        if a == "--trace" and i + 1 < len(argv):
-            return argv[i + 1]
-        if a.startswith("--trace="):
-            return a.split("=", 1)[1]
-    return None
-
-
 @contextlib.contextmanager
 def timeline(path, ring=None):
     """Arm tracing, run the body, write a Chrome trace to ``path`` —

@@ -354,9 +354,10 @@ _THREAD_PREFIXES = (
 def thread_census():
     """Live bolt-owned worker threads, ``{name: count}`` grouped by
     the blessed thread-name prefixes.  Empty when every pool, watch
-    and supervisor has been torn down — the hygiene invariant the
-    bench ``--check`` gate and the test suite assert (a leaked thread
-    here is a server/executor that skipped its shutdown path)."""
+    and supervisor has been torn down — the hygiene invariant
+    ``tests/conftest.py`` asserts after every test module (a leaked
+    thread here is a server/executor that skipped its shutdown
+    path)."""
     import threading
     out = {}
     for t in threading.enumerate():

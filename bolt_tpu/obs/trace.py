@@ -249,8 +249,8 @@ def totals():
 
 def active_count():
     """Spans begun but not yet ended — a nonzero value after a run means
-    an instrumented path leaked a span (``scripts/bench_all.py --check``
-    gates on this)."""
+    an instrumented path leaked a span (the feature suites under
+    ``tests/`` assert zero after a run)."""
     with _LOCK:
         return _ACTIVE
 

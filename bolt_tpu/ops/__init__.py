@@ -1,7 +1,6 @@
 from bolt_tpu.ops.group import bincount, segment_reduce, topk, unique
 from bolt_tpu.ops.hist import histogram
-from bolt_tpu.ops.kernels import (fused_map_reduce, fused_stats,
-                                  fused_welford, sepfilter1d)
+from bolt_tpu.ops.kernels import fused_welford, sepfilter1d
 from bolt_tpu.ops.linalg import (corrcoef, cov, jacobi_eigh, lstsq, pca,
                                  svdvals, tallskinny_pca, tallskinny_svd,
                                  tsqr)
@@ -12,8 +11,7 @@ from bolt_tpu.ops.series import (center, crosscorr, detrend, fourier,
 
 __all__ = ["bincount", "center", "convolve", "corrcoef", "cov",
            "crosscorr", "segment_reduce", "topk", "unique",
-           "detrend", "fourier", "fused_map_reduce", "fused_stats",
-           "fused_welford", "gaussian", "sepfilter1d", "histogram", "jacobi_eigh",
-           "lstsq", "map_overlap",
+           "detrend", "fourier", "fused_welford", "gaussian", "sepfilter1d",
+           "histogram", "jacobi_eigh", "lstsq", "map_overlap",
            "median_filter", "normalize", "pca", "smooth", "svdvals",
            "tallskinny_pca", "tallskinny_svd", "tsqr", "zscore"]

@@ -1,22 +1,23 @@
-"""Pallas TPU kernels for the hot reduction ops.
+"""Pallas TPU kernels for the passes XLA's own fusion cannot make in one.
 
-The reference's hot loops are Python-per-record inside Spark executors
-(``bolt/spark/array.py :: map``/``reduce`` via ``mapValues``/``treeReduce``
-— SURVEY §3.2/3.4); XLA already compiles our lowering to fused HBM-bandwidth
-code, so these kernels exist for the cases where explicit control wins:
+A plain ``sum(fn(x))`` is XLA's fused reduction (the ``reduce`` cell of the
+benchmark measures it); the kernels here are the ones something calls:
 
-* :func:`fused_map_reduce` — ``sum(fn(x))`` in ONE pass over HBM with an
-  on-chip scalar accumulator: the elementwise map, the reduction, and the
-  accumulation never round-trip to HBM.
-* :func:`fused_stats` — sum / sum-of-squares / min / max in one pass (four
-  XLA reductions would read HBM up to four times if fusion declines).
+* :func:`fused_welford` — mean / centred second moment / min / max over
+  axis 0 in ONE pass over HBM (the centred moment needs the finished mean,
+  so XLA reads twice); ``tpu/stats.py`` calls it for ``stats()``.
+* :func:`fused_decode_sum` — an int8 slab decoded and reduced in one pass,
+  behind ``BOLT_CODEC_KERNEL`` (``stream.py``).
+* :func:`sepfilter1d` — a separable filter's 1-d pass with every block read
+  once, and its wide-window lane form :func:`lane_band_pallas` (with the
+  XLA twin :func:`lane_band_conv`); ``ops/overlap.py`` calls them.
 
 Blocks are carved from the array's ORIGINAL shape — no reshape, because on
 TPU a reshape that merges the minor (tiled) dims is a physical relayout
-copy, which would double HBM for a 10 GB input.  Grids tile the one or two
-leading axes; anything that doesn't tile cleanly falls back to plain jnp.
-Off-TPU the kernels run in interpret mode, so the same code paths are
-testable on the CPU mesh.
+copy, which would double HBM for a 10 GB input.  A kernel whose plan does
+not apply returns ``None`` and its caller keeps the XLA path.  Off-TPU the
+kernels run in interpret mode, so the same code paths are testable on the
+CPU mesh.
 """
 
 from functools import partial
@@ -27,10 +28,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from bolt_tpu.utils import prod
-
-# effective per-block VMEM budget (bytes); conservative vs the ~16 MB/core
-# so double buffering and lane padding fit
-_VMEM_BUDGET = 6 * 2 ** 20
 
 
 def _interpret_default():
@@ -60,129 +57,6 @@ def _largest_divisor_fitting(n, unit_bytes, budget):
                     best = cand
         d += 1
     return best
-
-
-def _block_plan(shape, itemsize):
-    """Pick ``(grid, block)`` tiling the leading one or two axes of
-    ``shape``; None when the array can't be tiled cleanly into VMEM.
-
-    Requires a 128-aligned minor dim: feeding a narrower array to a TPU
-    pallas kernel makes XLA relayout-copy the whole operand with padded
-    lanes (observed: a 10 GB input became a 21 GB copy) — worse than just
-    letting XLA fuse the reduction."""
-    if len(shape) == 0:
-        return None
-    if shape[-1] % 128 != 0:
-        return None
-    rest1 = _padded_bytes(shape[1:], itemsize) if len(shape) > 1 else itemsize
-    t0 = _largest_divisor_fitting(shape[0], rest1, _VMEM_BUDGET)
-    if t0 is not None:
-        grid = (shape[0] // t0,)
-        block = (t0,) + tuple(shape[1:])
-        return grid, block
-    if len(shape) > 1:
-        rest2 = _padded_bytes(shape[2:], itemsize) if len(shape) > 2 else itemsize
-        t1 = _largest_divisor_fitting(shape[1], rest2, _VMEM_BUDGET)
-        if t1 is not None:
-            grid = (shape[0], shape[1] // t1)
-            block = (1, t1) + tuple(shape[2:])
-            return grid, block
-    return None
-
-
-def _index_map(grid_rank, block):
-    if grid_rank == 1:
-        return lambda i: (i,) + (0,) * (len(block) - 1)
-    return lambda i, j: (i, j) + (0,) * (len(block) - 2)
-
-
-def _mr_kernel(x_ref, o_ref, *, fn, grid_rank):
-    first = pl.program_id(0) == 0
-    if grid_rank == 2:
-        first = jnp.logical_and(first, pl.program_id(1) == 0)
-
-    @pl.when(first)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-    o_ref[...] += jnp.sum(fn(x_ref[...]).astype(o_ref.dtype))
-
-
-def fused_map_reduce(x, fn=None, interpret=None):
-    """``sum(fn(x))`` over all elements, single HBM pass.
-
-    ``fn`` is any traceable elementwise function (identity when ``None``) —
-    it runs inside the kernel on VMEM-resident tiles.  Returns a scalar of
-    ``x.dtype`` (accumulated in float32 for sub-float32 inputs).
-    """
-    if fn is None:
-        fn = lambda v: v
-    plan = _block_plan(x.shape, x.dtype.itemsize)
-    # integer inputs fall back: jnp.sum promotes its accumulator, and the
-    # kernel's same-dtype accumulation would silently overflow
-    if plan is None or not jnp.issubdtype(x.dtype, jnp.floating):
-        return jnp.sum(fn(x))
-    grid, block = plan
-    if interpret is None:
-        interpret = _interpret_default()
-    acc_dtype = jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float16) else x.dtype
-
-    out = pl.pallas_call(
-        partial(_mr_kernel, fn=fn, grid_rank=len(grid)),
-        grid=grid,
-        in_specs=[pl.BlockSpec(block, _index_map(len(grid), block))],
-        out_specs=pl.BlockSpec((1, 1), (lambda i: (0, 0)) if len(grid) == 1
-                               else (lambda i, j: (0, 0))),
-        out_shape=jax.ShapeDtypeStruct((1, 1), acc_dtype),
-        interpret=interpret,
-    )(x)
-    return out[0, 0].astype(x.dtype)
-
-
-def _stats_kernel(x_ref, s_ref, sq_ref, mn_ref, mx_ref, *, grid_rank):
-    first = pl.program_id(0) == 0
-    if grid_rank == 2:
-        first = jnp.logical_and(first, pl.program_id(1) == 0)
-
-    @pl.when(first)
-    def _init():
-        s_ref[...] = jnp.zeros_like(s_ref)
-        sq_ref[...] = jnp.zeros_like(sq_ref)
-        mn_ref[...] = jnp.full_like(mn_ref, jnp.inf)
-        mx_ref[...] = jnp.full_like(mx_ref, -jnp.inf)
-    blk = x_ref[...]
-    s_ref[...] += jnp.sum(blk)
-    sq_ref[...] += jnp.sum(blk * blk)
-    mn_ref[...] = jnp.minimum(mn_ref[...], jnp.min(blk))
-    mx_ref[...] = jnp.maximum(mx_ref[...], jnp.max(blk))
-
-
-def fused_stats(x, interpret=None):
-    """One-pass ``(sum, sum_sq, min, max)`` over all elements of ``x`` —
-    the moment set behind mean/var/std/min/max (the reference computes these
-    in one pass too, via StatCounter merges; SURVEY §3.4)."""
-    plan = _block_plan(x.shape, x.dtype.itemsize)
-    # integer inputs fall back: +/-inf accumulator init and same-dtype
-    # sum-of-squares are only correct in floating point
-    if plan is None or not jnp.issubdtype(x.dtype, jnp.floating):
-        return (jnp.sum(x), jnp.sum(x * x), jnp.min(x), jnp.max(x))
-    grid, block = plan
-    if interpret is None:
-        interpret = _interpret_default()
-    dt = jnp.float32 if x.dtype in (jnp.bfloat16, jnp.float16) else x.dtype
-    scalar = jax.ShapeDtypeStruct((1, 1), dt)
-    out_spec = pl.BlockSpec((1, 1), (lambda i: (0, 0)) if len(grid) == 1
-                            else (lambda i, j: (0, 0)))
-
-    s, sq, mn, mx = pl.pallas_call(
-        partial(_stats_kernel, grid_rank=len(grid)),
-        grid=grid,
-        in_specs=[pl.BlockSpec(block, _index_map(len(grid), block))],
-        out_specs=[out_spec] * 4,
-        out_shape=[scalar] * 4,
-        interpret=interpret,
-    )(x)
-    return (s[0, 0].astype(x.dtype), sq[0, 0].astype(x.dtype),
-            mn[0, 0].astype(x.dtype), mx[0, 0].astype(x.dtype))
 
 
 def _welford_kernel(x_ref, mu_ref, m2_ref, mn_ref, mx_ref, *, t0):

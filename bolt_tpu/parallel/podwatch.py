@@ -417,7 +417,7 @@ class FileTransport:
 
     def stale_marker_count(self):
         """Markers from epochs before the current one (the hygiene
-        observable the elastic bench gates at zero)."""
+        observable the elastic scenario holds at zero)."""
         n = 0
         for pat in ("hb.e*", "quiesce.e*"):
             for p in glob.glob(os.path.join(self.path, pat)):

@@ -38,7 +38,7 @@ that lets N tenants share one process and one device mesh safely:
   (``coalesced_builds`` / ``coalesced_compiles`` counters), so N
   tenants running the same pipeline shape trace and compile it ONCE —
   provided they share the stage callables (hoist user functions to
-  module level, as every bench does; two bytecode-identical lambdas
+  module level, as the tests do; two bytecode-identical lambdas
   are distinct cache keys);
 * **admission control with backpressure**: the queue is bounded
   (``queue_limit``); ``policy="queue"`` blocks the submitter until

@@ -1,11 +1,11 @@
 """Codec-encoded streaming ingest: move FEWER bytes over the link.
 
-The streaming executor is transfer-bound by design (``stream_sum``'s
-PERF.json traffic model is literally "one host→device pass per byte"),
-and the on-device fused map→sum has sat at the HBM roofline for five
-bench rounds — so the remaining single-chip lever is shrinking the
-bytes themselves (ROADMAP item 5, SURVEY §2.3).  This module is the
-codec registry the executor (``bolt_tpu.stream``) consults: uploader
+The streaming executor is transfer-bound by design: one host→device
+pass per byte, with the device idle 98.9 % of a streamed pass (the
+``stack4d-1chip.stream`` cell, PERF.md section 5) — so the remaining
+single-chip lever is shrinking the bytes themselves (ROADMAP S1 (c),
+SURVEY §2.3).  This module is the codec registry the executor
+(``bolt_tpu.stream``) consults: uploader
 workers ENCODE each slab on host (parallel, per worker, counted as
 ``codec_encode_seconds`` / ``codec_bytes_raw`` / ``codec_bytes_wire``),
 the wire representation plus a tiny sidecar crosses the link, and the

@@ -464,8 +464,8 @@ def load_script(name):
     """Load ``scripts/<name>.py`` from this repo by path, WITHOUT
     importing it as a package module (scripts are not a package, and
     several — the multihost cluster harness, chaos_run — are shared by
-    tests, bench_all, perf_regress and examples alike).  One loader
-    instead of per-caller importlib boilerplate."""
+    tests and examples alike).  One loader instead of per-caller
+    importlib boilerplate."""
     import importlib.util
     import os
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

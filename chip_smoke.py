@@ -430,7 +430,8 @@ def _svals(blk):
 
 
 def northstar(mesh, shape):
-    """``ones.map(v+1).sum()`` as bench.py runs it: bit-exact ``2·N``.
+    """``ones.map(v+1).sum()`` at BASELINE.json's north-star shape:
+    bit-exact ``2·N``.
     Also answers what ROADMAP S0 waits on: does ``block_until_ready``
     block here?  (A log line, not a metric.)"""
     import jax

@@ -39,15 +39,14 @@ Usage::
     python scripts/chaos_run.py --child .. # internal: one streamed run
 
 Every child is pinned to the CPU backend (:func:`_child_env`): the
-parent may hold the chip (``main`` runs its thread variant in process,
-``bench_all.py`` runs nine configs first), a chip belongs to one
-process, and a ``kill -9`` must never land on the process that holds
-it.  What the children prove — checkpoint, fence and resume control
-flow, bit-identity — does not depend on the backend; their wall
-seconds are CPU seconds and are labelled so.
+parent may hold the chip (``main`` runs its thread variant in
+process), a chip belongs to one process, and a ``kill -9`` must never
+land on the process that holds it.  What the children prove —
+checkpoint, fence and resume control flow, bit-identity — does not
+depend on the backend; their wall seconds are CPU seconds and are
+labelled so.
 
-``bench_all.py`` config 10 (``stream_resume``) reuses
-:func:`run_resume_bench`.
+``tests/test_resilience.py`` reuses :func:`run_resume_bench`.
 """
 
 import json
@@ -145,11 +144,11 @@ def _run_child(ck_dir, out, chaos=None):
 
 
 def run_resume_bench(kill_at=6, workdir=None):
-    """The subprocess kill -9 proof, packaged for the bench harness:
-    clean child run, SIGKILLed child (``BOLT_CHAOS`` arms the kill at
-    upload ``kill_at`` of 8), resumed child.  Returns the measurement
-    dict; raises on a child that failed for any reason OTHER than the
-    intended kill."""
+    """The subprocess kill -9 proof, packaged for its readers (``main``
+    and the tests): clean child run, SIGKILLed child (``BOLT_CHAOS``
+    arms the kill at upload ``kill_at`` of 8), resumed child.  Returns
+    the measurement dict; raises on a child that failed for any reason
+    OTHER than the intended kill."""
     from bolt_tpu import checkpoint as ckpt
     workdir = workdir or tempfile.mkdtemp(prefix="bolt-chaos-")
     ck_dir = os.path.join(workdir, "ckpt")

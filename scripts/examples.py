@@ -361,11 +361,10 @@ def main():
         assert _e["victim_rc"] == -9 and _e["survivors"] == 2
         assert _e["rejoined"] == 1 and _e["nproc_final"] == 3
         assert _e["bit_identical"]
-        # the wall ratio is gated against the scenario's OWN clean run
-        # by bench config 13 on a quiet machine; here — examples run
-        # alongside anything — it is REPORTED, with only a loose sanity
-        # bound, so background load cannot flake the drill (the PR 13
-        # known flake: timing/count gates vs committed expectations)
+        # the wall ratio against the scenario's OWN clean run is
+        # REPORTED, with only a loose sanity bound: examples run
+        # alongside anything, and background load must not flake the
+        # drill (tests/test_multihost.py holds it to 2.5x)
         assert _e["scenario_over_clean"] < 10
         assert _e["stale_ckpt"] == [] and _e["stale_markers"] == 0
         assert _e["arbiter_bytes"] == 0 and _e["leaked_spans"] == 0

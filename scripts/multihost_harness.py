@@ -16,10 +16,9 @@ so the monitor terminates them and raises a POINTED ``RuntimeError``
 naming the dead process and its exit code.  ``expect_dead=True``
 (the checkpoint/resume kill tests) instead returns the exit codes.
 
-Used by tests/test_multihost.py, scripts/bench_all.py (config 11) and
-scripts/perf_regress.py (the ``multihost_stream`` family); run
-standalone as ``python scripts/multihost_harness.py`` for a smoke pass
-of the parity payload.
+Used by tests/test_multihost.py and scripts/examples.py; run standalone
+as ``python scripts/multihost_harness.py`` for a smoke pass of the
+parity payload.
 """
 
 import json
@@ -463,51 +462,6 @@ def payload_resume(pid):
     return {"pid": pid,
             "resumes": c1["stream_resumes"] - c0["stream_resumes"],
             "slabs": c1["stream_chunks"] - c0["stream_chunks"]}
-
-
-def payload_bench(pid):
-    """The config-11 / perf-family payload: stream a larger crafted
-    source through the per-process pipeline, recording this process's
-    ingest bytes and wall seconds (per-process GB/s) plus the
-    compile-once counters."""
-    import numpy as np
-    import bolt_tpu as bolt
-    from bolt_tpu import engine, obs
-    from bolt_tpu.obs.trace import clock
-    out = os.environ["BOLT_MH_OUT"]
-    n = int(os.environ.get("BOLT_MH_NKEYS", "4096"))
-    vdim = int(os.environ.get("BOLT_MH_VDIM", "256"))
-    chunks = int(os.environ.get("BOLT_MH_CHUNKS", "512"))
-    x = _crafted(n, vdim)
-    mesh = _mesh()
-    obs.clear()
-    obs.enable()
-
-    def make():
-        return bolt.fromcallback(lambda idx: x[idx], (n, vdim), mesh,
-                                 dtype=np.float32, chunks=chunks,
-                                 per_process=True)
-
-    warm = make().map(ADD1).sum().cache()           # compile + warm
-    _value(warm)
-    c0 = engine.counters()
-    t0 = clock()
-    s = make().map(ADD1).sum().cache()
-    val = _value(s)
-    wall = clock() - t0
-    c1 = engine.counters()
-    np.save(os.path.join(out, "bench_sum.%d.npy" % pid), val)
-    res = {
-        "pid": pid,
-        "wall_s": wall,
-        "transfer_bytes": c1["transfer_bytes"] - c0["transfer_bytes"],
-        "slabs": c1["stream_chunks"] - c0["stream_chunks"],
-        "recompiles_warm": (c1["aot_compiles"] - c0["aot_compiles"]
-                            + c1["misses"] - c0["misses"]),
-        "leaked_spans": obs.active_count(),
-    }
-    obs.disable()
-    return res
 
 
 def payload_reform(pid):
@@ -1098,7 +1052,6 @@ PAYLOADS = {
     "single_ref": payload_single_ref,
     "codec_pod": payload_codec_pod,
     "resume": payload_resume,
-    "bench": payload_bench,
     "reform": payload_reform,
     "serve_pod": payload_serve_pod,
     "supervise": payload_supervise,
@@ -1145,18 +1098,19 @@ def worker_main(pid):
 
 
 # ---------------------------------------------------------------------
-# the elastic bench (bench_all config 13 / perf_regress
-# multihost_elastic): the 3→2→3 self-healing scenario + the
+# the elastic scenario (tests/test_multihost.py's ``elastic`` fixture,
+# scripts/examples.py 8i): the 3→2→3 self-healing pod + the
 # pre-collective death bound
 # ---------------------------------------------------------------------
 
 def run_supervise_bench(nproc=3, pace=0.2, kill_at=4, pod_timeout=2.0,
                         timeout=420, workdir=None):
-    """The ISSUE-12 acceptance scenario, packaged for the bench
-    harness: a CLEAN ``nproc``-process reference run of the supervised
-    workload (pipelines A, B, C — see ``payload_supervise``), then the
-    ELASTIC leg — worker 1 SIGKILLed mid-A (automatic 3→2 shrink with
-    zero caller intervention), a replacement process rejoining mid-B
+    """The ISSUE-12 acceptance scenario, packaged for its readers
+    (tests/test_multihost.py, scripts/examples.py): a CLEAN
+    ``nproc``-process reference run of the supervised workload
+    (pipelines A, B, C — see ``payload_supervise``), then the ELASTIC
+    leg — worker 1 SIGKILLed mid-A (automatic 3→2 shrink with zero
+    caller intervention), a replacement process rejoining mid-B
     (quiesce + 2→3 re-expansion), C clean on the re-expanded pod.
     Every artifact must be bit-identical between legs; the gate is
     scenario-vs-clean wall < 2.5x plus zero leaked arbiter bytes /
@@ -1284,16 +1238,18 @@ def run_precollective_probe(pod_timeout=2.0, timeout=180, workdir=None):
 
 
 # ---------------------------------------------------------------------
-# the reform bench (bench_all config 12 / perf_regress multihost_resume)
+# the reform scenario (tests/test_multihost.py's ``reform`` fixture,
+# scripts/examples.py)
 # ---------------------------------------------------------------------
 
 def run_reform_bench(nproc=3, nkeys=96, chunks=12, vdim=8, pace=0.25,
                      kill_at=7, pod_timeout=2.0, timeout=420,
                      workdir=None):
-    """The ISSUE-11 acceptance scenario, packaged for the bench
-    harness: a CLEAN ``nproc-1``-process run of the reform workload
-    (the unkilled post-shrink baseline), then an ``nproc``-process run
-    with worker 1 SIGKILLed mid-stream — every survivor must raise
+    """The ISSUE-11 acceptance scenario, packaged for its readers
+    (tests/test_multihost.py, scripts/examples.py): a CLEAN
+    ``nproc-1``-process run of the reform workload (the unkilled
+    post-shrink baseline), then an ``nproc``-process run with worker 1
+    SIGKILLed mid-stream — every survivor must raise
     ``PeerLostError`` (watchdog within 2× ``BOLT_POD_TIMEOUT``),
     ``multihost.reform`` onto the survivors, and resume bit-identically
     to the clean run.  ``recovery_s`` is the max survivor wall from
@@ -1308,8 +1264,8 @@ def run_reform_bench(nproc=3, nkeys=96, chunks=12, vdim=8, pace=0.25,
     checkpointed, so peer death surfaces as a fast transport error and
     the resume provably skips retired slabs.  A victim killed before
     the FIRST collective instead costs gloo's own connect timeout
-    (~30s) — bounded and converted, but not the fast path this bench
-    measures."""
+    (~30s) — bounded and converted, but not the fast path this
+    scenario exercises."""
     import shutil
     import numpy as np
     own = workdir is None

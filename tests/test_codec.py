@@ -347,7 +347,7 @@ def test_source_codec_wins_over_scope(mesh):
 def test_wire_bytes_and_codec_counters(mesh):
     x = _posdata()
     c0 = engine.counters()
-    _src(x, mesh, codec="bf16").sum().toarray()
+    first = np.asarray(_src(x, mesh, codec="bf16").sum().toarray())
     c1 = engine.counters()
     wire = c1["transfer_bytes"] - c0["transfer_bytes"]
     # the transfer counters tally the WIRE bytes: half the raw f32
@@ -356,6 +356,12 @@ def test_wire_bytes_and_codec_counters(mesh):
     assert c1["codec_bytes_wire"] - c0["codec_bytes_wire"] \
         == x.nbytes // 2
     assert c1["codec_encode_seconds"] > c0["codec_encode_seconds"]
+    # a second encoded pass compiles nothing and answers the same bits
+    again = np.asarray(_src(x, mesh, codec="bf16").sum().toarray())
+    c2 = engine.counters()
+    assert c2["misses"] == c1["misses"]
+    assert c2["aot_compiles"] == c1["aot_compiles"]
+    assert np.array_equal(first, again)
 
 
 def test_admission_floor_recomputes_via_codec_ratio(mesh):

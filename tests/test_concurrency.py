@@ -67,7 +67,7 @@ def test_blt111_raw_lock_construction():
     # the witness itself, tests and scripts build raw primitives freely
     assert conc.lint_source(src, "bolt_tpu/_lockdep.py") == []
     assert conc.lint_source(src, "tests/test_foo.py") == []
-    assert conc.lint_source(src, "scripts/bench_all.py") == []
+    assert conc.lint_source(src, "scripts/chaos_run.py") == []
     # the pragma escape hatch documents a deliberate exception
     ok = ("import threading\n"
           "L = threading.Lock()  # lint: allow(BLT111 scratch harness)\n")

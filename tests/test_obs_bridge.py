@@ -434,26 +434,31 @@ def test_engine_lookup_on_miss_and_hit():
 
 
 @pytest.mark.parametrize("aot", [True, False])
-def test_dispatch_has_signature_and_enqueue_beneath_it(monkeypatch, aot):
-    monkeypatch.setattr(engine, "_AOT", aot)
+def test_dispatch_has_signature_and_enqueue_beneath_it(aot):
+    """``aot=False`` is the plain-jit fallback, reached the way it is in
+    use: the program is called under somebody's trace, and a tracer has
+    no signature to pick an executable by."""
     key = ("bridge-test", "dispatch", aot, object())
     fn = engine.get(key, lambda: jax.jit(lambda v: v * 2))
+    call = fn if aot else jax.jit(lambda v: fn(v))
     fn(np.ones(4, np.float32))              # compile outside the reading
     before = engine.counters()
     obs.enable()
-    fn(np.ones(4, np.float32))
+    call(np.ones(4, np.float32))
     obs.disable()
     engine.evict(key)
     after = engine.counters()
     sp = obs.spans()
-    want = ["engine.signature", "engine.enqueue"] if aot \
-        else ["engine.enqueue"]
-    assert children(sp, "engine.dispatch") == want
+    # the signature is tried (and its span closed) on both paths
+    assert children(sp, "engine.dispatch") == ["engine.signature",
+                                               "engine.enqueue"]
     disp = [s for s in sp if s.name == "engine.dispatch"][0]
     assert disp.attrs["family"] == "bridge-test"
     # the always-on counters are what they were
     assert after["dispatches"] - before["dispatches"] == 1
     assert after["dispatch_seconds"] > before["dispatch_seconds"]
+    assert after["fallbacks"] - before["fallbacks"] == (0 if aot else 1)
+    assert after["aot_compiles"] == before["aot_compiles"]
 
 
 # ----------------------------------------------------------------------

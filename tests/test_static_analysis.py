@@ -17,7 +17,6 @@ Two halves (ISSUE 2 tentpole):
 Every diagnostic code and every lint rule has a seeded violation here.
 """
 
-import importlib.util
 import os
 import subprocess
 import sys
@@ -30,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 import bolt_tpu as bolt
-from bolt_tpu import analysis, engine
+from bolt_tpu import analysis, engine, obs
 from bolt_tpu.analysis import PipelineError, astlint
 from bolt_tpu.tpu.array import BoltArrayTPU
 
@@ -394,34 +393,80 @@ def test_swap_donation_guard_names_operation(mesh):
 
 
 # ----------------------------------------------------------------------
-# bench configs: the checker predicts every scripts/bench_all.py
-# pipeline with zero XLA compiles (acceptance criterion)
+# every kind of deferred state an array can hold: the checker predicts
+# its shape, dtype and dynamic-ness with zero XLA compiles and leaves no
+# span open
 # ----------------------------------------------------------------------
 
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+ADD1 = lambda v: v + 1
+MEANPOS = lambda v: v.mean() > 0
 
 
-def test_bench_all_configs_check_clean(mesh):
-    bench = _load_script("bench_all")
-    for name, arr in bench.pipelines(mesh=mesh):
+def _streamed(mesh, shape=(16, 8, 4), **kw):
+    x = (np.arange(int(np.prod(shape))) % 7).astype(
+        np.float32).reshape(shape)
+    return bolt.fromcallback(lambda idx: x[idx], shape, mesh,
+                             dtype=np.float32, chunks=4, **kw)
+
+
+def _f32(shape, seed=7):
+    return np.random.RandomState(seed).randn(*shape).astype(np.float32)
+
+
+# the pre-terminal state's builder, and a diagnostic that state's own
+# forecast must carry (or None)
+@pytest.mark.parametrize("build,code", [
+    pytest.param(lambda m: bolt.array(
+        np.ones((16, 8, 4), np.float32), m).map(ADD1), None,
+        id="map-chain"),
+    pytest.param(lambda m: bolt.array(
+        _f32((16, 6, 4)), m).filter(MEANPOS), "BLT008",
+        id="deferred-filter"),
+    pytest.param(lambda m: bolt.array(
+        _f32((8, 32, 4)), m).map(ADD1).chunk(size=(8,), axis=(0,)), None,
+        id="chunked-view-over-chain"),
+    pytest.param(lambda m: _streamed(m).chunk(
+        size=(4,), axis=(0,)).map(ADD1), None,
+        id="streamed-chunked-map"),
+    pytest.param(lambda m: _streamed(m).map(ADD1), None,
+                 id="streamed-map"),
+    pytest.param(lambda m: _streamed(
+        m, shape=(16, 8), per_process=True).map(ADD1), None,
+        id="streamed-map-per-process"),
+    pytest.param(lambda m: _streamed(m, codec="bf16").map(ADD1), "BLT016",
+                 id="streamed-map-codec"),
+    pytest.param(lambda m: _streamed(m).swap((0,), (0,)), "BLT017",
+                 id="streamed-swap"),
+    pytest.param(lambda m: bolt.array(
+        _f32((16, 6, 4)), m)[4:12].map(ADD1), None,
+        id="window-then-map"),
+    pytest.param(lambda m: bolt.array(
+        _f32((16, 6, 4)), m).map(ADD1).sum(), "BLT009",
+        id="lazy-statistic"),
+])
+def test_deferred_states_check_clean(mesh, build, code):
+    arr = build(mesh)
+    obs.clear()
+    obs.enable()
+    try:
         c0 = engine.counters()
         rep = analysis.check(arr)
         _no_new_compiles(c0, engine.counters())
-        assert rep.ok, (name, rep.diagnostics)
+        assert rep.ok, rep.diagnostics
+        assert code is None or rep.has(code), rep.diagnostics
         target = arr.unchunk() if hasattr(arr, "unchunk") else arr
-        got_shape = tuple(target.shape)
+        got_shape = tuple(target.shape)      # resolves/dispatches NOW
         got_dtype = np.dtype(target.dtype)
         if rep.dynamic:
             assert rep.shape[0] is None
-            assert rep.shape[1:] == got_shape[1:], name
+            assert rep.shape[1:] == got_shape[1:]
         else:
-            assert rep.shape == got_shape, name
-        assert np.dtype(rep.dtype) == got_dtype, name
+            assert rep.shape == got_shape
+        assert np.dtype(rep.dtype) == got_dtype
+        assert obs.active_count() == 0
+    finally:
+        obs.disable()
+        obs.clear()
 
 
 # ----------------------------------------------------------------------

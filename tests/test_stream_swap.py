@@ -17,6 +17,7 @@ raises are absorbed in place by the ``stream.retries`` fence, and the
 dict codec + spill-file layer keep their format contracts.
 """
 
+import contextlib
 import glob
 import os
 
@@ -24,7 +25,8 @@ import numpy as np
 import pytest
 
 import bolt_tpu as bolt
-from bolt_tpu import _chaos, analysis, checkpoint, engine, stream
+from bolt_tpu import (_chaos, analysis, checkpoint, engine, obs, serve,
+                      stream)
 from bolt_tpu.tpu import codec as codec_mod
 
 N, V0, V1 = 24, 6, 5
@@ -136,12 +138,29 @@ def test_lossy_codec_swap_falls_back_to_materialise(mesh):
 # the forced-spill path (budget ~ one bucket)
 # ---------------------------------------------------------------------
 
-def test_forced_spill_bitexact_and_cleared(mesh, tmp_path):
+@pytest.mark.parametrize("served", [False, True],
+                         ids=["alone", "under-a-serving-budget"])
+def test_forced_spill_bitexact_and_cleared(mesh, tmp_path, served):
+    # served: the swap leases its slabs from a server's arbiter; every
+    # byte comes back and no span stays open
     data = _data()
     td = str(tmp_path)
     c0 = engine.counters()
-    with stream.spill(dir=td, budget=1):
-        got = np.asarray(_source(data, mesh, 4).swap((0,), (0,))._data)
+    obs.clear()
+    obs.enable()
+    try:
+        server = (serve.serving(workers=1, budget_bytes=64 << 20)
+                  if served else contextlib.nullcontext())
+        with server as sv:
+            with stream.spill(dir=td, budget=1):
+                got = np.asarray(
+                    _source(data, mesh, 4).swap((0,), (0,))._data)
+            if served:
+                assert sv.stats()["arbiter"]["in_use_bytes"] == 0
+        assert obs.active_count() == 0
+    finally:
+        obs.disable()
+        obs.clear()
     c1 = engine.counters()
     assert np.array_equal(got, np.transpose(data, (1, 0, 2)))
     assert c1["spill_bytes"] > c0["spill_bytes"]
