@@ -150,6 +150,11 @@ _SCHEMA = {
     "resplit_views": 0,           # re-splits (swap/_align with an identity
                                   # permutation and unchanged sharding)
                                   # served as a view: no program, no buffer
+    "gram_kernel_programs": 0,    # programs LOWERED with ops/linalg.py's
+                                  # packed_gram kernel in them (the
+                                  # executor is chosen at lowering, so
+                                  # programs can be counted, not calls;
+                                  # 0 on the CPU)
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -491,6 +496,14 @@ def record_resplit_view():
     no program ran and no second buffer exists (``tpu/array.py ::
     _do_swap``)."""
     _COUNTERS.add("resplit_views")
+
+
+def record_gram_kernel_program():
+    """One program was lowered with the ``packed_gram`` Mosaic kernel in
+    it (``ops/linalg.py :: _gram_primitive``): a program for one TPU
+    device with a Gram matrix of real float32 that packs.  Per call the
+    record is the device trace (``packed_gram*`` events)."""
+    _COUNTERS.add("gram_kernel_programs")
 
 
 def donation_granted():

@@ -26,6 +26,12 @@ almost entirely serial.  This module takes the TPU-native route instead:
   is touched once by an MXU matmul and the eigenproblem is only (d, d),
   routed to :func:`jacobi_eigh` when the batch is large enough to
   amortise the sweep chain (see ``_use_jacobi``), else XLA's QDWH.
+  Real float32 of 8, 16, 32 or 64 features in a program for one TPU
+  device takes the Gram matrix by ONE Mosaic kernel that reads the data
+  where it lies and fills the matrix unit (``_packed_gram``: 10.74 GB
+  in 15.1 ms on a v5e, HBM's pace, where the ``dot_general`` fusion
+  took 29.3: ``PERF.md`` section 6, PR 29); every other input keeps
+  ``dot_general``.
 
 Rotation angles use ``0.5 * atan2(2*a_pq, a_qq - a_pp)`` — no divisions,
 no overflow for any input scale (the textbook ``tau = (a_qq - a_pp) /
@@ -590,16 +596,40 @@ def svdvals(x, gram_ratio=4):
     eigendecomposition touches only a (cols, cols) matrix — routed to the
     batched :func:`jacobi_eigh` when cols <= 64 and the batch (or a
     vmapped context) amortises it, else XLA's QDWH — instead of XLA's
-    QR-iteration SVD over the full block.  The trade-off is the classic
+    QR-iteration SVD over the full block.
+
+    The Gram matrix of real float32 with 8, 16, 32 or 64 columns is
+    ``gram_products`` (:func:`_gram`): in a program for one TPU device a
+    Mosaic kernel reads ``x`` once where it is stored, ``64 // cols`` row
+    ranges of a block side by side so that the matrix unit is full, in
+    contractions of ``_GRAM_BLOCK`` rows added outside the unit (no
+    running-sum bias over a long block; rows that do not fill a step are
+    a tail for ``dot_general``).  Batch axes of ``x`` itself and the axes
+    of a bolt ``chunk().map`` fold into that one call; under any OTHER
+    ``vmap``, and for complex, float64, half precisions and integers
+    (widened to float32 inside the ``dot_general``'s fusion, never as a
+    copy), other widths, the CPU, a program for several chips outside
+    ``shard_map`` and under differentiation, it is one ``dot_general`` a
+    block, as it always was: how a mapped axis lies in memory is known to
+    the code that mapped it, not to this function, and the wrong view of
+    a large array is a copy of it.  What this function cannot see is a
+    float32 ``x`` computed in the same program (``svdvals(x * 2)``, the
+    caller's own ``astype``): XLA fuses such a producer into a
+    ``dot_general`` and cannot into a kernel, so ``x`` is written to HBM
+    first (compiled for a v5e at ``(10, 1048576, 64)``: 2.68 GB of temp
+    where there was none; under a chunked map and behind ``pca``'s
+    deferred chain it was written before the kernel too).  Give the
+    stored array.  The trade-off of the route is the classic
     one: forming the Gram matrix squares the condition number, so trailing
     singular values below ``sqrt(eps) * s_max`` lose accuracy — fine for
     PCA-style spectra, not for rank-revealing use.  Wide or near-square
     inputs fall back to ``jnp.linalg.svd``.
     """
-    x = _widen(jnp.asarray(x), jnp)
+    given = jnp.asarray(x)
+    x = _widen(given, jnp)
     rows, cols = x.shape[-2], x.shape[-1]
     if rows >= gram_ratio * cols:
-        g = _gram(x, jnp, _resolve("highest"))
+        g = _gram(x, jnp, _resolve("highest"), widened=x.dtype != given.dtype)
         ev = _gram_eigvalsh(g)                         # ascending, real
         ev = jnp.maximum(ev[..., ::-1], 0.0)           # descending, clamped
         return jnp.sqrt(ev).astype(_real_dtype(x.dtype))
@@ -616,27 +646,49 @@ def _check_k(k, d):
     return k
 
 
-def _gram(x, xp, precision="highest"):
+def _gram(x, xp, precision="highest", widened=False):
     """The Gram matrix ``X^H X`` of one ``(..., n, d)`` block — an MXU
     matmul on TPU ("highest" precision, f32 accumulation, unless the
-    caller resolved a cheaper mode through the scoped policy).  ONE
-    contraction over the block's rows: a block is what a caller's
-    ``vmap`` (a chunked map) has cut from a longer axis already, and on
-    the chip's tiled layout a second cut of that axis is a relayout copy
-    of the whole array where the first was a bitcast (the same deployment
+    caller resolved a cheaper mode through the scoped policy).
+
+    Real float32 of a width that packs into the matrix unit (``d`` of 8,
+    16, 32, 64) goes through ``gram_products`` (:func:`_gram_primitive`):
+    in a program for one TPU device the kernel :func:`_packed_gram` reads
+    the block where it lies, ``64 // d`` row groups side by side, in
+    contractions of ``_GRAM_BLOCK`` rows whose matrices are added outside
+    the unit, so a block loses the unit's running-sum bias too (see
+    ``_GRAM_RUN``).  Leading axes of ``x`` itself, and the axes a chunked
+    map says it mapped, fold into that one call.
+
+    Everything else is ONE ``dot_general`` over the block's rows:
+    complex, float64, other widths, "default" precision, the CPU, a
+    program for several chips outside ``shard_map``, differentiation, a
+    block under a ``vmap`` whose origin nobody named, and ``widened``
+    input: what the caller was GIVEN was bfloat16, float16 or integers
+    and ``x`` is :func:`_widen`'s float32 of it.  XLA fuses that convert
+    into a ``dot_general``, which then reads the narrow array; it cannot
+    fuse it into a Mosaic call, so the kernel would be fed a float32 copy
+    of the whole input written to HBM first (10.7 GB beside a stored
+    ``int16[40,1048576,64]``: a 7.5 GB int16 series that compiled would
+    be refused).  One contraction, never runs: a block is what a
+    caller's ``vmap`` has cut from a longer axis already, and on the
+    chip's tiled layout a second cut of that axis is a relayout copy of
+    the whole array where the first was a bitcast (the same deployment
     at 32 time points, blocks of 2**20 rows cut once more, is refused at
-    compile: "Used 20.00G of 15.75G hbm").  The price is the matrix
-    unit's own running-sum bias over the block (see ``_GRAM_RUN``); a
-    whole bolt array goes through :func:`_sample_gram` instead."""
+    compile: "Used 20.00G of 15.75G hbm").  The price there is the
+    matrix unit's bias over the block; a whole bolt array goes through
+    :func:`_sample_gram` instead."""
     if xp is np:
         xt = np.swapaxes(x, -1, -2)
         return np.matmul(np.conj(xt) if np.iscomplexobj(x) else xt, x)
+    if not widened and _kernel_serves(x, precision):
+        return _gram_entry(precision, False)(x)
     return _products(jnp.conj(x), x, precision)
 
 
 def _products(u, v, precision):
     """``(.., len, d) x 2 -> (.., d, d)``: every leading axis a batch
-    axis, the rows contracted on the matrix unit."""
+    axis, the rows contracted on the matrix unit by one ``dot_general``."""
     k = u.ndim - 2
     acc = _acc_dtype(jnp.promote_types(u.dtype, v.dtype))
     return jax.lax.dot_general(
@@ -652,11 +704,12 @@ def _products(u, v, precision):
 # a single bfloat16 pass (PERF.md, PR 26; splitting costs no time there).
 # So a whole array's sample axis is contracted in runs and the runs'
 # matrices are added outside the unit, by a reduction that rounds to
-# nearest.
+# nearest.  This is the ``dot_general`` path's run; the kernel's is
+# ``_GRAM_BLOCK``, shorter still, for a block under a chunked map too.
 _GRAM_RUN = 1 << 14
 
 
-def _run_gram(a, b, precision):
+def _run_gram(a, b, precision, widened=False):
     """``sum over the sample axes of a[.., i] * b[.., j]`` for operands
     shaped ``samples + (d,)`` (every axis but the last contracted):
     ``(d, d)``.
@@ -665,10 +718,25 @@ def _run_gram(a, b, precision):
     ``(n, d)`` first: on the chip's tiled layout that reshape is a
     relayout copy of the whole array wherever the sample axes are not
     adjacent in memory (``f32[40,1048576,64]`` would not compile beside
-    its own copy on a 16 GB chip).  The last sample axis is cut into runs
-    of ``_GRAM_RUN`` rows (and a shorter tail where it does not divide);
-    every run of every leading sample index gives one ``(d, d)`` product
-    on the matrix unit, and those are summed outside it."""
+    its own copy on a 16 GB chip).
+
+    Real float32 of a width that packs (``a is b`` then), stored as
+    float32 (not ``widened``: see :func:`_gram`), is ``gram_products``
+    with every leading axis a sample axis: in a program
+    for one TPU device the stored array goes to :func:`_packed_gram`
+    where it lies, in contractions of ``_GRAM_BLOCK`` rows, and the
+    planes' matrices are added here.  Every other input, backend and
+    program keeps :func:`_gram_in_runs`, and so does differentiation."""
+    if a is b and not widened and _kernel_serves(a, precision):
+        return _gram_entry(precision, True)(a)
+    return _gram_in_runs(a, b, precision)
+
+
+def _gram_in_runs(a, b, precision):
+    """:func:`_run_gram` by ``dot_general``.  The last sample axis is cut
+    into runs of ``_GRAM_RUN`` rows (and a shorter tail where it does not
+    divide); every run of every leading sample index gives one ``(d, d)``
+    product on the matrix unit, and those are summed outside it."""
     n, d = a.shape[-2], a.shape[-1]
     count, tail = divmod(n, _GRAM_RUN)
     parts = []
@@ -687,11 +755,270 @@ def _run_gram(a, b, precision):
                for part in parts)
 
 
-def _sample_gram(x, precision, second_conj=False):
+# ---------------------------------------------------------------------
+# the Gram pass as one Mosaic kernel.  ``(d, K) x (K, d)`` with d = 64
+# uses every 128-row tile of weights the matrix unit loads for 64
+# streamed rows and fills a quarter of its 128 x 128 result; at "highest"
+# that was 28.5 ms for one read of 10.74 GB on a v5e, 46 % of HBM
+# (PERF.md section 5, PR 27).  "highest" is float32 by bfloat16 pieces,
+# ``x = h + m + l`` of 8 significant bits each, and the six products
+# that matter: ``hh + hm + mh + mm + hl + lh`` (what is dropped is under
+# 2**-24 of a product).  The kernel stacks 64 feature rows' ``h`` and
+# ``m`` on the sublanes, ``C = [H; M]`` of ``(128, T)``: ONE full
+# product ``C C^T`` holds ``HH, HM, MH, MM`` in its four quadrants, every
+# one of them wanted, and a quarter-size ``H L^T`` the rest (``lh`` is
+# its transpose).  A width under 64 fills the 64 rows with ``64 // d``
+# row groups of one batch element, whose Gram matrices are the diagonal
+# blocks of the quadrants; the blocks between two groups are computed and
+# dropped.
+# ---------------------------------------------------------------------
+
+# XLA names the kernel's instruction, and so its event on the device
+# trace, after this.  It must hold neither "while" nor "custom-call":
+# benchmark/metrics/gram_roofline.json and eigh_ms.scan.json take every
+# device operation so named for the EIGENSOLVER and leave it out of
+# gram_roofline's denominator
+_GRAM_KERNEL_NAME = "packed_gram"
+# rows of one row group contracted in one go on the matrix unit; the
+# blocks' matrices are added on the vector unit, which rounds to nearest
+# (see ``_GRAM_RUN``: 1.0e-7 at 8,192 rows).  One constant, from one
+# sweep on the chip (PERF.md section 6, PR 29): 64 x _GRAM_BLOCK float32,
+# double-buffered, with its three bf16 pieces, sits in the default
+# scoped VMEM
+_GRAM_BLOCK = 8192
+_GRAM_ROWS = _LANES // 2        # feature rows a step: half the sublanes
+
+
+def _gram_groups(d):
+    """Row groups of width ``d`` side by side in one step's 64 feature
+    rows (64 -> 1, 32 -> 2, 16 -> 4, 8 -> 8); 0 where ``d`` does not pack
+    (48, 100, anything over 64)."""
+    return _GRAM_ROWS // d if d % 8 == 0 and _GRAM_ROWS % d == 0 else 0
+
+
+def _kernel_serves(x, precision):
+    """Whether ``gram_products`` takes this Gram matrix (what it lowers to
+    is decided later, by the program's target).  Real float32, a width
+    that packs, and "highest", whose products the kernel makes.  Complex,
+    float64, other widths and the cheaper modes ("high", "default") keep
+    ``dot_general``; so does float32 that :func:`_widen` made of a
+    narrower stored array, which the callers test themselves (the dtype
+    here no longer shows it)."""
+    return (x.dtype == jnp.float32 and x.ndim >= 2
+            and _gram_groups(x.shape[-1]) > 0 and precision == "highest")
+
+
+def _gram_kernel(*refs):
+    """One grid step.  ``refs``: the row groups' blocks ``(d / 8, 8, T)``,
+    then the two accumulators of the batch element, ``(128, 128)`` for
+    ``[H; M] [H; M]^T`` and ``(64, 64)`` for ``H L^T``, which stay in VMEM
+    over the last grid axis.  The blocks are stacked on the sublanes to
+    ``(64, T)`` float32 and split into three bfloat16 pieces on the
+    vector unit; each product is ONE contraction of ``T`` rows on the
+    matrix unit (the lanes of both sides), added to its accumulator in
+    float32 on the vector unit."""
+    from jax.experimental import pallas as pl
+    full, quarter = refs[-2:]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        full[...] = jnp.zeros_like(full)
+        quarter[...] = jnp.zeros_like(quarter)
+
+    x = jnp.concatenate([r[...].reshape(-1, r.shape[-1])
+                         for r in refs[:-2]], axis=0)
+    h = x.astype(jnp.bfloat16)
+    x = x - h.astype(jnp.float32)
+    m = x.astype(jnp.bfloat16)
+    low = (x - m.astype(jnp.float32)).astype(jnp.bfloat16)
+    c = jnp.concatenate([h, m], axis=0)
+    dot = partial(jax.lax.dot_general,
+                  dimension_numbers=(((1,), (1,)), ((), ())),
+                  preferred_element_type=jnp.float32)
+    full[...] += dot(c, c)
+    quarter[...] += dot(h, low)
+
+
+def _packed_gram(x, cut):
+    """``x^T x`` over the rows of every ``(n, d)`` of ``lead + (n, d)``
+    float32 by the kernel: ``(lead + (d, d), rows taken)``, or ``(None,
+    0)`` where ``n`` is under one step's ``64 // d`` blocks.  With
+    ``cut`` the last axis of ``lead`` is a cut of the stored row axis (a
+    chunk grid); the axes before it lie outside the rows in memory
+    (planes, keys).
+
+    The operand reaches the kernel as a VIEW of the stored array, and on
+    the chip's tiled layout (rows on the lanes, features on the
+    sublanes: ``f32[40,1048576,64]`` is ``{1,2,0:T(8,128)}``) that takes
+    the order written here: the features split into ``(d / 8, 8)``
+    behind the rows, then ONE transpose to ``(planes, d / 8, grid, 8,
+    rows)``, which is how a cut of the row axis lies in memory (the grid
+    BETWEEN the two halves of the tiled feature axis).  XLA folds that to
+    a bitcast.  The same view written from the other end
+    (``swapaxes(-1, -2)`` first), a flattened ``(planes, d, grid *
+    rows)``, and ``pallas_call``'s own batching under the map's ``vmap``s
+    are each a relayout copy of the whole array: "Used 20.00G of 15.75G
+    hbm" at the benchmark's size (compiled for the described v5e, ISSUE
+    29).
+
+    Row groups pair by row range within one batch element: grid step
+    ``j`` reads blocks ``j, steps + j, ..`` of the same plane or chunk, so
+    an odd number of planes needs nothing.  Rows from ``groups * steps *
+    block`` on are the caller's tail."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    block = _GRAM_BLOCK
+    lead, (n, d) = x.shape[:-2], x.shape[-2:]
+    groups = _gram_groups(d)
+    steps = n // (groups * block)
+    if not steps:
+        return None, 0
+    grid = lead[-1] if cut else 1
+    major = prod(lead) // grid
+    view = x.reshape(major, grid, n, d // 8, 8).transpose(0, 3, 1, 4, 2)
+    sides = (_LANES, _GRAM_ROWS)        # [H; M] [H; M]^T and H L^T
+    full, quarter = pl.pallas_call(
+        _gram_kernel,
+        out_shape=[jax.ShapeDtypeStruct((major, grid, side, side),
+                                        jnp.float32) for side in sides],
+        grid=(major, grid, steps),
+        in_specs=[pl.BlockSpec((None, d // 8, None, 8, block),
+                               lambda p, g, j, k=k: (p, 0, g, 0,
+                                                     k * steps + j))
+                  for k in range(groups)],
+        out_specs=[pl.BlockSpec((None, None, side, side),
+                                lambda p, g, j: (p, g, 0, 0))
+                   for side in sides],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name=_GRAM_KERNEL_NAME,
+    )(*[view] * groups)
+    r = _GRAM_ROWS
+    out = (full[..., :r, :r] + full[..., r:, r:]
+           + (full[..., :r, r:] + full[..., r:, :r])
+           + (quarter + jnp.swapaxes(quarter, -1, -2)))
+    out = sum(out[..., k * d:(k + 1) * d, k * d:(k + 1) * d]
+              for k in range(groups))
+    return out.reshape(lead + (d, d)), groups * steps * block
+
+
+def _kernel_gram(x, precision, cut, samples):
+    """``gram_products`` where the kernel can be placed: the rows
+    :func:`_packed_gram` takes, the tail by :func:`_plain_gram`."""
+    out, done = _packed_gram(x, cut)
+    if samples and done:
+        out = jnp.sum(out, axis=tuple(range(out.ndim - 2)))
+    if done < x.shape[-2]:
+        tail = _plain_gram(x[..., done:, :], precision, samples)
+        out = tail if not done else out + tail
+    return out
+
+
+def _plain_gram(x, precision, samples):
+    """What ``gram_products`` computes, by ``dot_general``: one
+    contraction a block, or a whole array's in runs."""
+    return _gram_in_runs(x, x, precision) if samples \
+        else _products(x, x, precision)
+
+
+def _gram_primitive():
+    """``gram_products``: the Gram matrices of ``lead + (n, d)`` float32,
+    ``lead + (d, d)``, or with ``samples`` their sum ``(d, d)``.  A
+    primitive for :func:`_sweeps_primitive`'s reason: whether a Mosaic
+    kernel can be placed is known when the program is LOWERED.  In a
+    program for one TPU device it is :func:`_packed_gram`; on the CPU,
+    and in a program for several chips outside ``shard_map``, it is
+    :func:`_plain_gram`, exactly what ran before the kernel was.
+
+    Under ``vmap`` the rule folds a mapped axis into ``lead`` only where
+    its NAME says how it lies in memory (``tpu/chunk.py :: MappedAxis``,
+    which a chunked map gives the axes it maps).  A
+    stored ``(80, R, 64)`` under a user's own ``jax.vmap`` and a chunk
+    grid's ``(40, 2, R, 64)`` batch to the same shapes in the OTHER
+    physical order; the wrong view is a copy of the whole array, so a
+    mapped axis of unknown origin keeps ``dot_general``."""
+    from jax._src import dispatch       # eager calls: jax's own cache
+    from jax.extend.core import Primitive
+    from jax.interpreters import mlir
+    from jax._src.interpreters import batching
+    prim = Primitive("gram_products")
+    prim.def_impl(partial(dispatch.apply_primitive, prim))
+
+    @prim.def_abstract_eval
+    def _(x, *, precision, cut, samples):
+        lead = () if samples else x.shape[:-2]
+        return x.update(shape=lead + (x.shape[-1],) * 2)
+
+    def lower(fits):
+        def rule(ctx, x, *, precision, cut, samples):
+            n, d = ctx.avals_in[0].shape[-2:]
+            kernel = fits(ctx) and n >= _gram_groups(d) * _GRAM_BLOCK
+            if kernel:
+                from bolt_tpu import engine
+                engine.record_gram_kernel_program()
+            fn = partial(_plain_gram, precision=precision, samples=samples)
+            if kernel:
+                fn = partial(_kernel_gram, precision=precision, cut=cut,
+                             samples=samples)
+            # Mosaic has no 64-bit types (see ``jacobi_sweeps``)
+            with jax.enable_x64(jax.config.jax_enable_x64 and not kernel):
+                return mlir.lower_fun(fn, multiple_results=False)(ctx, x)
+        return rule
+
+    mlir.register_lowering(prim, lower(lambda ctx: False))
+    mlir.register_lowering(prim, lower(_mosaic_fits), platform="tpu")
+
+    def fold(axis_data, args, dims, *, precision, cut, samples):
+        from bolt_tpu.tpu.chunk import MappedAxis
+        if dims[0] is None:
+            return prim.bind(args[0], precision=precision, cut=cut,
+                             samples=samples), None
+        x = jnp.moveaxis(args[0], dims[0], 0)
+        name = axis_data.name
+        named = isinstance(name, MappedAxis) and not samples
+        # the one row cut folds first, in front of the block itself: keys
+        # lie outside it in memory
+        row_cut = named and name.rows == x.shape[-2] and x.ndim == 3 \
+            and not cut
+        if not (row_cut or named and not name.rows):
+            return jax.vmap(partial(_plain_gram, precision=precision,
+                                    samples=samples))(x), 0
+        return prim.bind(x, precision=precision, cut=cut or row_cut,
+                         samples=False), 0
+
+    batching.fancy_primitive_batchers[prim] = fold
+    return prim
+
+
+_gram_p = _gram_primitive()
+
+
+@lru_cache(maxsize=None)
+def _gram_entry(precision, samples):
+    """:func:`_plain_gram` for real float32 of a width that packs, through
+    ``gram_products``.  A ``pallas_call`` has no differentiation rule:
+    this one's is ``dot_general``'s, on the chip too."""
+
+    @jax.custom_jvp
+    def run(x):
+        return _gram_p.bind(x, precision=precision, cut=False,
+                            samples=samples)
+
+    @run.defjvp
+    def _(primals, tangents):
+        return jax.jvp(partial(_plain_gram, precision=precision,
+                               samples=samples), primals, tangents)
+
+    return run
+
+
+def _sample_gram(x, precision, second_conj=False, widened=False):
     """The Gram matrix of ``sample_shape + (d,)`` data over all its
     leading axes: ``sum conj(x[.., i]) * x[.., j]``, or with
     ``second_conj`` ``sum x[.., i] * conj(x[.., j])`` (``np.cov``'s
-    convention).  See :func:`_run_gram`."""
+    convention; the two are one for real data).  See :func:`_run_gram`."""
+    if not jnp.iscomplexobj(x):
+        return _run_gram(x, x, precision, widened)
     a, b = (x, jnp.conj(x)) if second_conj else (jnp.conj(x), x)
     return _run_gram(a, b, precision)
 
@@ -706,10 +1033,12 @@ def _project(x, vec, precision):
 def _features_last(mapped, kshape, d):
     """``sample_shape + (d,)``: the feature axes merged into one (no
     reshape at all for a single feature axis), widened for the
-    decomposition.  The sample axes are NOT merged."""
+    decomposition, and whether widening converted it (see :func:`_gram`).
+    The sample axes are NOT merged."""
     if mapped.shape != kshape + (d,):
         mapped = mapped.reshape(kshape + (d,))
-    return _widen(mapped, jnp)
+    x = _widen(mapped, jnp)
+    return x, x.dtype != mapped.dtype
 
 
 def _decompose_gram(g, k, xp, eigh_fn):
@@ -721,13 +1050,13 @@ def _decompose_gram(g, k, xp, eigh_fn):
     return vec, ev
 
 
-def _gram_decompose(x, k, xp, eigh_fn):
+def _gram_decompose(x, k, xp, eigh_fn, widened=False):
     """Shared Gram-route core for the PCA family: ``x`` is ``(n, d)``,
     returns ``(vec (d, k), ev (k,))`` in descending order.  ``xp`` is the
     array namespace (numpy for the local oracle, jnp inside jit) so the
     backends run the same sequence (the TPU pca program splices its
     centering fold between :func:`_gram` and :func:`_decompose_gram`)."""
-    return _decompose_gram(_gram(x, xp), k, xp, eigh_fn)
+    return _decompose_gram(_gram(x, xp, widened=widened), k, xp, eigh_fn)
 
 
 def _tpu_eigh(g):
@@ -783,7 +1112,8 @@ def lstsq(a, b):
     elif getattr(b, "mode", None) == "local":
         bl = np.asarray(b)
         b = bl if bl.ndim == 1 else bl.reshape((bl.shape[0], -1))
-    a = _widen(jnp.asarray(a), jnp)
+    given = jnp.asarray(a)
+    a = _widen(given, jnp)
     b = _widen(jnp.asarray(b), jnp)
     if jnp.iscomplexobj(a) or jnp.iscomplexobj(b):
         raise ValueError("lstsq supports real systems; use jnp.linalg.lstsq "
@@ -799,7 +1129,7 @@ def lstsq(a, b):
             "%s and %s" % (a.shape, b.shape))
     if vec:
         b = b[..., None]
-    q, r = tsqr(a)
+    q, r = _tsqr(a, a.dtype != given.dtype)
     y = jnp.matmul(_adjoint(q), b, precision=_resolve("highest"))
     x = jax.scipy.linalg.solve_triangular(r, y, lower=False)
     # one refinement pass: e = y - r x at full precision repairs the
@@ -821,12 +1151,14 @@ def tallskinny_svd(x, k=None):
     than an arbitrary orthonormal completion.  ``k`` truncates to the
     top components.  Descending order, ``numpy.linalg.svd`` conventions.
     """
-    x = _widen(jnp.asarray(x), jnp)
+    given = jnp.asarray(x)
+    x = _widen(given, jnp)
     if x.ndim < 2 or x.shape[-2] < x.shape[-1]:
         raise ValueError("tallskinny_svd requires (..., n, d) with n >= d, "
                          "got %s; use jnp.linalg.svd" % (x.shape,))
     d = x.shape[-1]
-    vec, ev = _gram_decompose(x, _check_k(k, d), jnp, _tpu_eigh)
+    vec, ev = _gram_decompose(x, _check_k(k, d), jnp, _tpu_eigh,
+                              widened=x.dtype != given.dtype)
     s = jnp.sqrt(ev)
     safe = jnp.where(s > 0, s, 1.0)
     u = jnp.matmul(x, vec, precision=_resolve("highest")) / safe[..., None, :]
@@ -846,7 +1178,14 @@ def tsqr(x):
     ~machine-eps for cond(x) up to ~1/sqrt(eps) — beyond that (or rank
     deficient, where the Cholesky NaNs) use ``jnp.linalg.qr``.
     """
-    x = _widen(jnp.asarray(x), jnp)
+    given = jnp.asarray(x)
+    x = _widen(given, jnp)
+    return _tsqr(x, x.dtype != given.dtype)
+
+
+def _tsqr(x, widened):
+    """:func:`tsqr` of a float ``x``; ``widened``: it is a conversion of
+    what the caller was given (see :func:`_gram`)."""
     if x.ndim < 2 or x.shape[-2] < x.shape[-1]:
         raise ValueError("tsqr requires (..., n, d) with n >= d, got %s"
                          % (x.shape,))
@@ -854,8 +1193,8 @@ def tsqr(x):
     d = x.shape[-1]
     eye = jnp.eye(d, dtype=x.dtype)
 
-    def _chol_qr(a):
-        g = _gram(a, jnp, _resolve("highest"))
+    def _chol_qr(a, widened=False):
+        g = _gram(a, jnp, _resolve("highest"), widened=widened)
         l = jnp.linalg.cholesky(g)                       # g = l @ l^H
         r = _adjoint(l)
         # invert only the small (d, d) triangle, then apply by matmul so
@@ -871,7 +1210,7 @@ def tsqr(x):
         q = jnp.matmul(a, r_inv, precision=_resolve("highest"))
         return q, r
 
-    q1, r1 = _chol_qr(x)
+    q1, r1 = _chol_qr(x, widened)
     q, r2 = _chol_qr(q1)                                 # re-orthogonalise
     return q, jnp.matmul(r2, r1, precision=_resolve("highest"))
 
@@ -988,7 +1327,8 @@ def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
     n = prod(kshape)
 
     def program(data):
-        x = _features_last(_chain_apply(funcs, split, data), kshape, d)
+        x, widened = _features_last(_chain_apply(funcs, split, data),
+                                    kshape, d)
         # Centering folds into the Gram algebraically (round-4 fusion):
         #   (X - mu)^T (X - mu) = X^T X - n mu mu^T
         # so the centred matrix is NEVER materialised — the raw X is
@@ -1005,7 +1345,7 @@ def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
         # larger offsets.
         mu = jnp.mean(x, axis=samples) if center \
             else jnp.zeros(d, x.dtype)
-        g = _sample_gram(x, pr)
+        g = _sample_gram(x, pr, widened=widened)
         if center:
             g = g - n * jnp.outer(jnp.conj(mu), mu)
         vec, ev = _decompose_gram(g, k, jnp, _tpu_eigh)
@@ -1058,8 +1398,10 @@ def tallskinny_pca(x, k=None):
             "tallskinny_pca requires n >= d (got %d x %d): the rank-%d Gram "
             "matrix would pad the spectrum with zero eigenvalues whose "
             "eigenvectors are arbitrary; use jnp.linalg.svd" % (n, d, n))
-    x = _widen(jnp.asarray(x), jnp)
-    vec, ev = _gram_decompose(x, _check_k(k, d), jnp, _tpu_eigh)
+    given = jnp.asarray(x)
+    x = _widen(given, jnp)
+    vec, ev = _gram_decompose(x, _check_k(k, d), jnp, _tpu_eigh,
+                              widened=x.dtype != given.dtype)
     return vec.astype(x.dtype), jnp.sqrt(ev).astype(_real_dtype(x.dtype))
 
 
@@ -1134,8 +1476,8 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
 
     def build():
         def program(data):
-            x = _features_last(_chain_apply(funcs, split, data),
-                               shape[:split], d)
+            x, widened = _features_last(_chain_apply(funcs, split, data),
+                                        shape[:split], d)
             # same centering fold as pca (round 4): the centred copy is
             # never materialised — (X-mu)^T conj(X-mu) = X^T conj(X) -
             # n mu conj(mu)^T; same second-factor conjugation as np.cov.
@@ -1143,7 +1485,7 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
             # (mu/sigma)^2 relative error in the entries).
             mu = jnp.mean(x, axis=tuple(range(split))) if center \
                 else jnp.zeros(d, x.dtype)
-            c = _sample_gram(x, pr, second_conj=True)
+            c = _sample_gram(x, pr, second_conj=True, widened=widened)
             if center:
                 c = c - n * jnp.outer(mu, jnp.conj(mu))
                 # the explicit-centering path this fold replaced computed

@@ -20,6 +20,8 @@ recursive concatenate tree the reference's ``unchunk`` uses — all inside
 one jit whose trace cost is independent of the grid size.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
@@ -81,6 +83,20 @@ def _axis_categories(v, c, p, g):
     return cats
 
 
+@dataclasses.dataclass(frozen=True)
+class MappedAxis:
+    """The ``vmap`` ``axis_name`` of an axis the chunked map maps.  Only
+    the code that cut an axis knows how the pieces lie in memory, and a
+    kernel that reads them where they lie has to be told: ``rows`` 0 is a
+    key axis, stored outside the block; otherwise the axis cuts the
+    block's leading (row) axis into pieces of ``rows`` rows, which on the
+    chip's tiled layout lie BETWEEN the two halves of the tiled last
+    axis.  ``ops/linalg.py :: gram_products`` folds both into one call
+    over a view of ``data``; a mapped axis without this name, a user's own
+    ``vmap`` among them, keeps its ``dot_general``."""
+    rows: int = 0
+
+
 def _uniform_map_body(data, func, split, plan, canon=None):
     """The uniform no-padding chunked-map program body: reshape the
     value axes into (grid, block) pairs, nested-vmap ``func`` over
@@ -114,9 +130,13 @@ def _uniform_map_body(data, func, split, plan, canon=None):
     r = jnp.transpose(
         r, tuple(range(split)) + tuple(g_axes) + tuple(c_axes))
     ncut = len(cut)
+    # the map names the axes it maps (see ``MappedAxis``); a cut of a
+    # value axis other than the block's leading one stays anonymous
+    names = [MappedAxis()] * split + [
+        MappedAxis(plan[0]) if i == 0 and nv > 1 else None for i in cut]
     f = func
-    for _ in range(split + ncut):
-        f = jax.vmap(f)
+    for name in reversed(names):
+        f = jax.vmap(f, axis_name=name)
     out = f(r)
     ob = out.shape[split + ncut:]
     if len(ob) != nv:

@@ -248,12 +248,24 @@ _MOSAIC_CASES = [
     for shape, vec in [((80, 64, 64), False), ((80, 64, 64), True),
                        ((300, 8, 8), True), ((300, 5, 5), False),
                        ((1000, 2, 2), True)]
+] + [
+    # ops/linalg.py's Gram pass (ISSUE 29): 64 is the benchmark's width, a
+    # narrower one puts 64 // d row groups side by side; the second 64 has
+    # a tail for dot_general behind the kernel's rows
+    ("packed_gram-%dx%dx%d" % shape, lambda x: _svdvals(x), [(shape, _F32)])
+    for shape in [(6, 16384, 64), (3, 20000, 64), (5, 16384, 32),
+                  (2, 65536, 16), (1, 65536, 8)]
 ]
 
 
 def _jacobi_eigh(a, vectors=False):
     from bolt_tpu.ops import jacobi_eigh
     return jacobi_eigh(a, vectors=vectors)
+
+
+def _svdvals(x):
+    from bolt_tpu.ops import svdvals
+    return svdvals(x)
 
 
 @pytest.fixture(scope="module")
@@ -347,25 +359,32 @@ def _series_mesh(v5e_device):
     return jax.sharding.Mesh(np.asarray([v5e_device]), ("k",))
 
 
-def _compile_series(fn, v5e_device, shape=_SERIES):
+def _compile_series(fn, v5e_device, shape=_SERIES, dtype=_F32):
     import jax
     mesh = _series_mesh(v5e_device)
     where = jax.sharding.NamedSharding(mesh,
                                        jax.sharding.PartitionSpec("k"))
     with jax.enable_x64(False):
         return jax.jit(fn).lower(jax.ShapeDtypeStruct(
-            shape, _F32, sharding=where)).compile()
+            shape, dtype, sharding=where)).compile()
 
 
 def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device):
     from bolt_tpu.ops import linalg
     program = linalg._pca_program((), 2, _SERIES[:2], _SERIES[2], 8, True,
                                   "highest", _series_mesh(v5e_device))
-    mem = _compile_series(program, v5e_device).memory_analysis()
+    compiled = _compile_series(program, v5e_device)
+    mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == 4 * int(np.prod(_SERIES))
     assert mem.temp_size_in_bytes < 0.1e9
     # the scores and nothing else of any size come out
     assert mem.output_size_in_bytes < 4 * int(np.prod(_SERIES[:2])) * 8 * 1.01
+    # the Gram pass is ONE kernel call over a bitcast of the argument
+    # (ISSUE 29): no matrix-unit fusion with a (64, 64) result is left
+    text = compiled.as_text()
+    _one_packed_gram(text, _SERIES[:1] + (1,))
+    assert not [ln for ln in text.splitlines()
+                if "convolution" in ln and "f32[64,64]" in ln.split("=")[1][:40]]
 
 
 # the cell's own shape, and the same deployment at 32 time points: its
@@ -376,19 +395,8 @@ def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device):
     ids=["64-time-points", "32-time-points"])
 def test_per_chunk_svd_fits_beside_its_argument_on_v5e(v5e_device, shape,
                                                        block):
-    from bolt_tpu.ops import svdvals
-    from bolt_tpu.tpu.chunk import _constrain_chunked, _uniform_map_body
-    from bolt_tpu.utils import chunk_align, chunk_plan
-    axes, size, padding = chunk_align(shape[1:], (0,), "150", None)
-    plan = chunk_plan(shape[1:], 4, size, axes, padding=padding)
-    assert tuple(plan) == block               # upstream's default budget
-    mesh = _series_mesh(v5e_device)
-
-    def run(data):                    # ChunkedArray.map's uniform program
-        out = _uniform_map_body(data, lambda blk: svdvals(blk)[None, :], 1,
-                                tuple(plan))
-        return _constrain_chunked(out, mesh, 1, {})
-
+    run, plan = _chunk_svd_program(v5e_device, shape)
+    assert plan == block                      # upstream's default budget
     compiled = _compile_series(run, v5e_device, shape)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 0.1e9
@@ -396,15 +404,75 @@ def test_per_chunk_svd_fits_beside_its_argument_on_v5e(v5e_device, shape,
     # the 80 blocks' eigenproblems, reached under the map's two vmaps, are
     # ONE kernel call with the 80 on the lanes of one block (ISSUE 27)
     _one_jacobi_kernel(compiled.as_text(), block[1], 128)
+    # and their 80 Gram matrices ONE call over the chunk grid's view of
+    # the argument, planes x grid (ISSUE 29)
+    _one_packed_gram(compiled.as_text(), (shape[0], shape[1] // block[0]))
+
+
+def _chunk_svd_program(v5e_device, shape):
+    """``ChunkedArray.map``'s uniform program of ``svdvals`` over
+    upstream's default blocks of a plane-keyed ``shape``, and the block."""
+    from bolt_tpu.ops import svdvals
+    from bolt_tpu.tpu.chunk import _constrain_chunked, _uniform_map_body
+    from bolt_tpu.utils import chunk_align, chunk_plan
+    axes, size, padding = chunk_align(shape[1:], (0,), "150", None)
+    plan = tuple(chunk_plan(shape[1:], 4, size, axes, padding=padding))
+    mesh = _series_mesh(v5e_device)
+
+    def run(data):
+        out = _uniform_map_body(data, lambda blk: svdvals(blk)[None, :], 1,
+                                plan)
+        return _constrain_chunked(out, mesh, 1, {})
+    return run, plan
+
+
+def _kernel_calls(text, name):
+    """The compiled program's Mosaic calls whose instruction is named
+    after the kernel ``name`` (XLA names a kernel's instruction, and so
+    its event on the device trace, after the kernel)."""
+    import re
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+            and re.match(r"\s*%" + re.escape(name) + r"(\.\d+)? = ", ln)]
+
+
+def _one_packed_gram(text, batch):
+    """ONE ``packed_gram`` call whose accumulators are one ``(128, 128)``
+    and one ``(64, 64)`` an element of ``batch``, fed a bitcast (not a
+    copy) of the program's argument, under a name that the benchmark does
+    not take for the eigensolver's."""
+    import json
+    import os
+    import re
+    from bolt_tpu.ops import linalg
+    calls = _kernel_calls(text, linalg._GRAM_KERNEL_NAME)
+    assert len(calls) == 1, calls
+    dims = ",".join(map(str, batch))
+    assert "f32[%s,128,128]" % dims in calls[0], calls[0]
+    assert "f32[%s,64,64]" % dims in calls[0], calls[0]
+    operands = set(re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
+                   .replace(" ", "").split(","))
+    assert len(operands) == 1 and next(iter(operands)).startswith(
+        "%bitcast"), calls[0]
+    name = re.match(r"\s*%(\S+) = ", calls[0]).group(1)
+    here = os.path.dirname(os.path.abspath(__file__))
+    with open(os.path.join(here, "..", "benchmark", "metrics",
+                           "gram_roofline.json")) as f:
+        eigh = json.load(f)["args"]["eigh"]
+    assert eigh and not [pat for pat in eigh if pat in name], (name, eigh)
 
 
 def _one_jacobi_kernel(text, m, lanes):
     """The compiled program holds the sweep chain as ONE Mosaic call over
     ``lanes`` lanes of ``m x m`` matrices: no ``while`` holds the rounds,
     and the call's instruction, and so its event on the device trace, has
-    a name that ``benchmark/metrics/eigh_ms.scan.json`` matches."""
+    a name that ``benchmark/metrics/eigh_ms.scan.json`` matches.  Any
+    other Mosaic call of the program is ``packed_gram``'s."""
     import re
-    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    from bolt_tpu.ops import linalg
+    every = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    calls = _kernel_calls(text, linalg._KERNEL_NAME)
+    assert len(every) == len(calls) + len(
+        _kernel_calls(text, linalg._GRAM_KERNEL_NAME)), every
     assert len(calls) == 1, calls
     assert re.match(r"\s*%\S*custom-call\S* = ", calls[0]), calls[0]
     half = -(-(m // 2) // 8) * 8
@@ -445,3 +513,102 @@ def test_jacobi_keeps_the_scan_in_a_program_for_four_chips(v5e_device):
             (400, 8, 8), _F32, sharding=where)).compile().as_text()
     assert "tpu_custom_call" not in text
     assert " while(" in text
+
+
+# ---------------------------------------------------------------------
+# compile-only: who gets packed_gram and who keeps dot_general (ISSUE 29)
+# ---------------------------------------------------------------------
+
+def test_a_plain_vmap_of_svdvals_keeps_dot_general_and_fits_on_v5e(
+        v5e_device):
+    # a stored (80, R, 64) under the user's own vmap batches to the shapes
+    # the chunk grid's (40, 2, R, 64) does, in the OTHER physical order:
+    # nobody named the axis, so nobody guesses, and the program is what it
+    # was (a wrong guess is a copy of the whole array: refused at this size)
+    import jax
+    compiled = _compile_series(jax.vmap(_svdvals), v5e_device,
+                               (80, 524288, 64))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    from bolt_tpu.ops import linalg
+    assert not _kernel_calls(compiled.as_text(), linalg._GRAM_KERNEL_NAME)
+
+
+@pytest.mark.parametrize("dtype,planes", [
+    ("bfloat16", 40), ("int16", 40), ("int16", 56)],
+    ids=["bf16-5.4GB", "int16-5.4GB", "int16-7.5GB"])
+@pytest.mark.parametrize("program", ["pca", "chunk_svd"])
+def test_a_stored_narrow_series_is_widened_inside_the_fusion_on_v5e(
+        v5e_device, program, dtype, planes):
+    # a stored bfloat16 or integer series is widened to float32 INSIDE the
+    # dot_general's fusion, never as a copy: the kernel, which XLA cannot
+    # fuse a convert into, is not for it (a float32 copy of the 7.5 GB
+    # int16 series is 15 GB: "Used 21.00G of 15.75G hbm")
+    from bolt_tpu.ops import linalg
+    shape = (planes,) + _SERIES[1:]
+    if program == "pca":
+        run = linalg._pca_program((), 2, shape[:2], shape[2], 8, True,
+                                  "highest", _series_mesh(v5e_device))
+    else:
+        run, _ = _chunk_svd_program(v5e_device, shape)
+    compiled = _compile_series(run, v5e_device, shape, jnp.dtype(dtype))
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 2 * int(np.prod(shape))
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert not _kernel_calls(compiled.as_text(), linalg._GRAM_KERNEL_NAME)
+
+
+def test_batched_svdvals_is_one_packed_gram_on_v5e(v5e_device):
+    # leading axes of the operand itself are stored outside the rows
+    compiled = _compile_series(_svdvals, v5e_device, (80, 524288, 64))
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    _one_packed_gram(compiled.as_text(), (80, 1))
+
+
+@pytest.mark.parametrize("shape", [(8, 65536, 48), (8, 65536, 128),
+                                   (8, 4000, 64)],
+                         ids=["d48", "d128", "under-one-block"])
+def test_gram_that_does_not_pack_keeps_dot_general_on_v5e(v5e_device,
+                                                          shape):
+    from bolt_tpu.ops import linalg
+    text = _compile_series(
+        lambda x: linalg._gram(x, jnp, "highest"), v5e_device,
+        shape).as_text()
+    assert "tpu_custom_call" not in text
+    assert "convolution" in text
+
+
+@pytest.mark.parametrize("precision", ["high", "default"])
+def test_cheaper_precisions_keep_dot_general_on_v5e(v5e_device, precision):
+    from bolt_tpu.ops import linalg
+    text = _compile_series(
+        lambda x: linalg._gram(x, jnp, precision), v5e_device,
+        (8, 65536, 64)).as_text()
+    assert "tpu_custom_call" not in text
+
+
+def test_gram_keeps_dot_general_in_a_program_for_four_chips(v5e_device):
+    # GSPMD does not partition a Mosaic kernel: outside shard_map a program
+    # for several chips keeps the dot_general in runs, and compiles
+    import jax
+    from jax.experimental import topologies
+    from bolt_tpu.ops import linalg
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("k",))
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
+    program = linalg._pca_program((), 2, (8, 65536), 64, 8, True, "highest",
+                                  mesh)
+    with jax.enable_x64(False):
+        text = jax.jit(program).lower(jax.ShapeDtypeStruct(
+            (8, 65536, 64), _F32, sharding=where)).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
+
+
+def test_the_lowering_counts_programs_with_the_kernel(v5e_device):
+    from bolt_tpu import engine
+    c0 = engine.counters()["gram_kernel_programs"]
+    _compile_series(_svdvals, v5e_device, (2, 16384, 64))
+    assert engine.counters()["gram_kernel_programs"] == c0 + 1
+    _compile_series(_svdvals, v5e_device, (2, 16384, 48))
+    assert engine.counters()["gram_kernel_programs"] == c0 + 1

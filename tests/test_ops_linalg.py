@@ -10,7 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from bolt_tpu.ops import jacobi_eigh
+from bolt_tpu.ops import jacobi_eigh, svdvals
 
 
 def _gram(rs, b, n):
@@ -466,3 +466,239 @@ def test_the_executor_is_chosen_when_the_program_is_lowered(devices, kernel):
     assert ("stablehlo.while" in text) != kernel
     cpu = jax.jit(jacobi_eigh).lower(arg).as_text()
     assert "tpu_custom_call" not in cpu and "stablehlo.while" in cpu
+
+
+# ---------------------------------------------------------------------
+# the Gram pass as one Mosaic kernel (ISSUE 29).  On this CPU mesh
+# ``gram_products`` lowers to ``dot_general``; the kernel itself runs here
+# in Pallas' TPU interpret mode, with a short block, against float64
+# ---------------------------------------------------------------------
+
+def _kernel_gram_here(x, cut=False, samples=False, block=128):
+    from jax.experimental.pallas import tpu as pltpu
+    from bolt_tpu.ops import linalg
+    old, linalg._GRAM_BLOCK = linalg._GRAM_BLOCK, block
+    try:
+        with pltpu.force_tpu_interpret_mode(), jax.enable_x64(False):
+            return np.asarray(linalg._kernel_gram(
+                jnp.asarray(x, jnp.float32), "highest", cut, samples),
+                np.float64)
+    finally:
+        linalg._GRAM_BLOCK = old
+
+
+def _gram64(x, samples=False):
+    x = np.asarray(x, np.float64)
+    g = np.einsum("...ni,...nj->...ij", x, x)
+    return g.reshape((-1,) + g.shape[-2:]).sum(axis=0) if samples else g
+
+
+@pytest.mark.parametrize("d", [8, 16, 32, 64])
+@pytest.mark.parametrize("lead,cut", [((), False), ((3,), False),
+                                      ((3, 5), True), ((2, 3, 2), True)],
+                         ids=["one", "planes3", "planes3-grid5",
+                              "keys2x3-grid2"])
+def test_packed_gram_is_exact_on_small_integers(d, lead, cut):
+    # integers under 2**6 over at most 2,048 rows: every product and every
+    # partial sum is an integer under 2**24, so float32 holds the TRUTH and
+    # any lost product, group or block shows as a whole number
+    rs = np.random.RandomState(d + len(lead))
+    n = 2 * (64 // d) * 128
+    x = rs.randint(-63, 64, size=lead + (n, d))
+    got = _kernel_gram_here(x, cut)
+    assert got.shape == lead + (d, d)
+    assert np.array_equal(got, _gram64(x))
+    # a whole array's sum over the planes leaves 2**24 behind: to float32
+    whole = _gram64(x, samples=True)
+    assert np.abs(_kernel_gram_here(x, samples=True) - whole).max() \
+        <= 2e-7 * np.diag(whole).max()
+
+
+@pytest.mark.parametrize("d,n", [(64, 3 * 128 + 37), (32, 2 * 256 + 255),
+                                 (16, 4 * 128 + 1), (8, 8 * 128 + 1000),
+                                 (64, 100)],
+                         ids=["d64", "d32", "d16", "d8", "all-tail"])
+def test_packed_gram_with_a_tail_against_float64(d, n):
+    # rows beyond the kernel's whole steps go to dot_general and are added:
+    # 12-bit data as the benchmark's, three planes (an odd count)
+    from bolt_tpu.ops.linalg import _products
+    rs = np.random.RandomState(n)
+    x = rs.randint(-2047, 2048, size=(3, n, d)).astype(np.float32)
+    want = _gram64(x)
+    scale = np.abs(np.diagonal(want, axis1=-2, axis2=-1)).max()
+    got = _kernel_gram_here(x)
+    assert np.abs(got - want).max() < 1e-6 * scale
+    # and the dot_general it replaces, to the same 1e-6 of the diagonal
+    plain = np.asarray(_products(jnp.asarray(x), jnp.asarray(x), "highest"))
+    assert np.abs(got - plain).max() < 1e-6 * scale
+    assert np.abs(_kernel_gram_here(x, samples=True)
+                  - want.sum(axis=0)).max() < 1e-6 * 3 * scale
+
+
+def test_packed_gram_keeps_all_of_float32():
+    # "highest" is float32 by three bfloat16 pieces: data with a full
+    # 24-bit mantissa must come out to float32's own rounding, which two
+    # pieces and three products do not give (1e-5 here)
+    rs = np.random.RandomState(29)
+    x = (rs.randn(2, 1024, 64) * 1000.0).astype(np.float32)
+    want = _gram64(x)
+    scale = np.abs(np.diagonal(want, axis1=-2, axis2=-1)).max()
+    assert np.abs(_kernel_gram_here(x) - want).max() < 5e-7 * scale
+
+
+@pytest.mark.parametrize("d", [32, 8])
+def test_row_groups_do_not_leak_into_each_other(d):
+    # the kernel's product holds group i against group j off its diagonal
+    # blocks; with the second row range a million times the first and the
+    # same rows, that block is 1e-6 of the answer: it must not be in it
+    rs = np.random.RandomState(d)
+    groups, n = 64 // d, (64 // d) * 256
+    a = rs.randint(-2047, 2048, size=(n // groups, d)).astype(np.float64)
+    x = np.concatenate([a] + [a * 1e6] * (groups - 1))
+    want = _gram64(x)
+    got = _kernel_gram_here(x)
+    assert np.abs(got - want).max() < 2e-7 * np.abs(np.diag(want)).max()
+
+
+def test_gram_products_folds_the_axes_a_chunked_map_names():
+    # the chunk grid's axis is a cut of the rows, the key axis lies
+    # outside: both fold into ONE gram_products over (keys, grid, n, d)
+    from bolt_tpu.tpu.chunk import _uniform_map_body
+    rs = np.random.RandomState(4)
+    x = rs.randint(-63, 64, size=(5, 128, 8)).astype(np.float32)
+    run = lambda data: _uniform_map_body(
+        data, lambda blk: svdvals(blk)[None, :], 1, (32, 8))
+    calls = _eqns(jax.make_jaxpr(run)(x).jaxpr, "gram_products")
+    assert len(calls) == 1
+    assert calls[0].invars[0].aval.shape == (5, 4, 32, 8)
+    assert calls[0].params["cut"] is True and not calls[0].params["samples"]
+    want = np.linalg.svd(x.reshape(5, 4, 32, 8).astype(np.float64),
+                         compute_uv=False)
+    assert np.allclose(np.asarray(run(x)), want, rtol=1e-4, atol=1e-3)
+    # two key axes and the grid; a cut of the FEATURE axis is not named
+    run2 = lambda data: _uniform_map_body(
+        data, lambda blk: svdvals(blk)[None, :], 2, (32, 8))
+    call, = _eqns(jax.make_jaxpr(run2)(x.reshape(5, 1, 128, 8)).jaxpr,
+                  "gram_products")
+    assert call.invars[0].aval.shape == (5, 1, 4, 32, 8)
+    assert call.params["cut"] is True
+    run3 = lambda data: _uniform_map_body(
+        data, lambda blk: svdvals(blk)[None, :], 1, (64, 8))
+    wide = jnp.asarray(rs.randn(5, 64, 16).astype(np.float32))
+    jaxpr = jax.make_jaxpr(run3)(wide).jaxpr
+    assert not _eqns(jaxpr, "gram_products") and _eqns(jaxpr, "dot_general")
+
+
+def test_an_unnamed_vmap_keeps_dot_general():
+    # a stored (B, n, d) under the user's own vmap has the chunk grid's
+    # batched shapes in the other physical order: nobody guesses
+    x = jnp.asarray(np.random.RandomState(6).randn(6, 64, 8)
+                    .astype(np.float32))
+    jaxpr = jax.make_jaxpr(jax.vmap(svdvals))(x).jaxpr
+    assert not _eqns(jaxpr, "gram_products")
+    assert _eqns(jaxpr, "dot_general")
+    # leading axes of the operand itself are its own: one call
+    call, = _eqns(jax.make_jaxpr(svdvals)(x).jaxpr, "gram_products")
+    assert call.invars[0].aval.shape == (6, 64, 8)
+    assert call.params["cut"] is False
+    # a named axis OUTSIDE an unnamed one is batched over the dot_general
+    from bolt_tpu.tpu.chunk import MappedAxis
+    both = jax.vmap(jax.vmap(svdvals), axis_name=MappedAxis())
+    jaxpr = jax.make_jaxpr(both)(x.reshape(2, 3, 64, 8)).jaxpr
+    assert not _eqns(jaxpr, "gram_products")
+    got = np.asarray(both(x.reshape(2, 3, 64, 8)))
+    assert np.allclose(got.reshape(6, 8), np.asarray(svdvals(x)), rtol=1e-5)
+
+
+@pytest.mark.parametrize("dtype,d,precision,served", [
+    ("float32", 64, "highest", True), ("float32", 8, "highest", True),
+    ("float32", 48, "highest", False), ("float32", 100, "highest", False),
+    ("float32", 128, "highest", False), ("float32", 64, "high", False),
+    ("float32", 64, "default", False), ("float64", 64, "highest", False),
+    ("bfloat16", 64, "highest", False), ("complex64", 64, "highest", False)])
+def test_who_the_gram_kernel_serves(dtype, d, precision, served):
+    from bolt_tpu.ops.linalg import _gram, _kernel_serves
+    x = jnp.zeros((256, d), dtype)
+    assert _kernel_serves(x, precision) == served
+    jaxpr = jax.make_jaxpr(lambda v: _gram(v, jnp, precision))(x).jaxpr
+    assert bool(_eqns(jaxpr, "gram_products")) == served
+    assert bool(_eqns(jaxpr, "dot_general")) != served
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "int16", "uint8"])
+def test_a_widened_input_keeps_dot_general(dtype):
+    # XLA fuses the widening convert into a dot_general, which then reads
+    # the narrow array; into a Mosaic call it cannot, so the kernel would
+    # be fed a float32 copy of the whole input.  What decides is the array
+    # the caller GAVE, before _widen
+    with jax.enable_x64(False):   # the chip's: integers widen to float32
+        _widened_cases(jnp.ones((4, 256, 8), dtype))
+
+
+def _widened_cases(x):
+    from bolt_tpu.ops import linalg
+    from bolt_tpu.tpu.chunk import _uniform_map_body
+    program = linalg._pca_program((), 2, (4, 256), 8, 3, True, "highest",
+                                  jax.sharding.Mesh(
+                                      np.asarray(jax.devices()[:1]), ("k",)))
+    chunked = lambda data: _uniform_map_body(
+        data, lambda blk: svdvals(blk)[None, :], 1, (64, 8))
+    for fn in (svdvals, chunked, program, linalg.tallskinny_svd,
+               lambda v: linalg.tallskinny_pca(v[0])):
+        jaxpr = jax.make_jaxpr(fn)(x).jaxpr
+        assert not _eqns(jaxpr, "gram_products"), fn
+        assert _eqns(jaxpr, "dot_general"), fn
+        served = jax.make_jaxpr(fn)(x.astype(jnp.float32)).jaxpr
+        assert _eqns(served, "gram_products"), fn
+    # tsqr's second round reads its own float32 q1, which is written to
+    # memory whatever reads it: only the first round's operand is widened
+    rhs = jnp.ones(x.shape[:-1], jnp.float32)
+    for fn in (linalg.tsqr, lambda v: linalg.lstsq(v, rhs)):
+        calls = _eqns(jax.make_jaxpr(fn)(x).jaxpr, "gram_products")
+        assert len(calls) == 1, fn
+        assert len(_eqns(jax.make_jaxpr(fn)(x.astype(jnp.float32)).jaxpr,
+                         "gram_products")) == 2, fn
+
+
+def test_gram_products_differentiates_as_dot_general(monkeypatch):
+    # a pallas_call has no differentiation rule: the entry's is the
+    # dot_general path's, through svdvals and through a whole array's runs
+    from bolt_tpu.ops import linalg
+    rs = np.random.RandomState(12)
+    x = jnp.asarray(rs.randn(3, 96, 8).astype(np.float32))
+    loss = lambda v: (svdvals(v) ** 2).sum() + svdvals(v)[..., 0].sum()
+    runs = lambda v: (linalg._sample_gram(v, "highest") ** 2).sum()
+    got, got_runs = jax.grad(loss)(x), jax.grad(runs)(x)
+    assert _eqns(jax.make_jaxpr(loss)(x).jaxpr, "gram_products")
+    assert not _eqns(jax.make_jaxpr(jax.grad(loss))(x).jaxpr,
+                     "gram_products")
+    monkeypatch.setattr(linalg, "_kernel_serves", lambda x, precision: False)
+    # (make_jaxpr caches a function's trace: a new one)
+    assert not _eqns(jax.make_jaxpr(lambda v: loss(v))(x).jaxpr,
+                     "gram_products")
+    want, want_runs = jax.grad(loss)(x), jax.grad(runs)(x)
+    assert got.dtype == jnp.float32 and np.all(np.isfinite(np.asarray(got)))
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert np.array_equal(np.asarray(got_runs), np.asarray(want_runs))
+
+
+@pytest.mark.parametrize("devices,kernel", [(1, True), (4, False)],
+                         ids=["one-device", "four-devices"])
+def test_the_gram_executor_is_chosen_when_the_program_is_lowered(devices,
+                                                                 kernel):
+    # lowered FOR a TPU on this CPU host (nothing compiles, nothing runs):
+    # one device gets the kernel and is counted; several keep dot_general,
+    # and so does this host's own backend, uncounted
+    from bolt_tpu import engine
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:devices]), ("k",))
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
+    arg = jax.ShapeDtypeStruct((8, 8192, 64), jnp.float32, sharding=where)
+    c0 = engine.counters()["gram_kernel_programs"]
+    with jax.enable_x64(False):
+        text = jax.jit(svdvals).trace(arg).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert ("packed_gram" in text) == kernel
+    assert engine.counters()["gram_kernel_programs"] == c0 + kernel
+    cpu = jax.jit(svdvals).lower(arg).as_text()
+    assert "packed_gram" not in cpu and "dot_general" in cpu
+    assert engine.counters()["gram_kernel_programs"] == c0 + kernel
