@@ -79,6 +79,19 @@ def _stage_eval(func, split, aval):
             jax.ShapeDtypeStruct(tuple(aval.shape), aval.dtype)))
 
 
+def _untraceable(idx, label, aval, exc):
+    """``BLT001`` for the chain stage ``label`` that failed abstract
+    tracing on ``aval``."""
+    first = str(exc).splitlines()[0] if str(exc) else ""
+    return Diagnostic(
+        "BLT001", idx,
+        "%s fails abstract tracing on input %s %s: %s%s"
+        % (label, tuple(aval.shape), np.dtype(aval.dtype),
+           type(exc).__name__, ": " + first if first else ""),
+        hint="the stage would fail identically at compile time; "
+             "fix the callable's shape/dtype contract")
+
+
 def _would_donate(arr):
     """Would the NEXT terminal donate this array's chain base?  Mirrors
     the terminals exactly by delegating to ``_chain_donate_ok`` with the
@@ -627,7 +640,7 @@ def _check_impl(obj):
     dynamic = False
 
     if fp is not None:
-        base, funcs, pred, walk_split, vshape, n, vdtype = fp
+        base, funcs, pred, walk_split, vshape, n, vdtype = fp[:7]
     elif arr._chain is not None:
         base, funcs = arr._chain
         walk_split = arr._split
@@ -686,14 +699,7 @@ def _check_impl(obj):
         try:
             nxt = _stage_eval(func, walk_split, aval)
         except Exception as exc:
-            first = str(exc).splitlines()[0] if str(exc) else ""
-            diags.append(Diagnostic(
-                "BLT001", i + 1,
-                "%s fails abstract tracing on input %s %s: %s%s"
-                % (label, tuple(aval.shape), np.dtype(aval.dtype),
-                   type(exc).__name__, ": " + first if first else ""),
-                hint="the stage would fail identically at compile time; "
-                     "fix the callable's shape/dtype contract"))
+            diags.append(_untraceable(i + 1, label, aval, exc))
             failed = True
             break
         old, new = np.dtype(aval.dtype), np.dtype(nxt.dtype)
@@ -755,6 +761,20 @@ def _check_impl(obj):
             "predicate; reading .shape dispatches the fused compaction "
             "and syncs one scalar" % n))
         dynamic = True
+        # the record-wise maps called on the filter since: they stay
+        # deferred with it and run on the survivors
+        aval = jax.ShapeDtypeStruct(out_shape, vdtype)
+        for j, func in enumerate(fp.post):
+            try:
+                aval = _stage_eval(func, 1, aval)
+            except Exception as exc:
+                diags.append(_untraceable(pidx + j + 1, _func_label(func),
+                                          aval, exc))
+                failed = True
+                break
+            stages.append(Stage(pidx + j + 1, _func_label(func),
+                                aval.shape, np.dtype(aval.dtype), 1,
+                                _spec(mesh, aval.shape, 1), dynamic=True))
 
     if not failed:
         _note_admission(

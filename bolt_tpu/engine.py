@@ -150,6 +150,11 @@ _SCHEMA = {
     "resplit_views": 0,           # re-splits (swap/_align with an identity
                                   # permutation and unchanged sharding)
                                   # served as a view: no program, no buffer
+    "filters_fused": 0,           # deferred filters that ended in a
+                                  # terminal (a statistic, a reduce, the
+                                  # grouped fold) with no buffer built
+    "filter_compactions": 0,      # deferred filters whose survivors were
+                                  # built as an array (either compaction)
     "gram_kernel_programs": 0,    # programs LOWERED with ops/linalg.py's
                                   # packed_gram kernel in them (the
                                   # executor is chosen at lowering, so
@@ -496,6 +501,21 @@ def record_resplit_view():
     no program ran and no second buffer exists (``tpu/array.py ::
     _do_swap``)."""
     _COUNTERS.add("resplit_views")
+
+
+def record_filter_fused():
+    """One deferred filter was folded into the program of the terminal
+    that read it (``tpu/array.py :: _launch_filter_terminal``, span
+    ``array.filter_stat``): one pass, no survivor buffer."""
+    _COUNTERS.add("filters_fused")
+
+
+def record_filter_compaction():
+    """One deferred filter had its survivors built as an array
+    (``tpu/array.py :: _resolve_fpending``, span ``array.filter``): the
+    padded compaction or, above ``_FILTER_FUSED_MAX_BYTES``, the
+    two-phase gather."""
+    _COUNTERS.add("filter_compactions")
 
 
 def record_gram_kernel_program():

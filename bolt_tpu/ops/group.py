@@ -38,39 +38,69 @@ def _label_minmax(labels):
 
 
 def segment_reduce(b, labels, num_segments=None, op="sum", method=None,
-                   precision=None):
+                   precision=None, value=None, return_counts=False):
     """Reduce the records of ``b`` (leading key axis) into groups given by
     ``labels``: record ``i`` joins group ``labels[i]``, and group ``g``'s
     result is the ``op``-combine of its records — the ``reduceByKey``
     analog, one compiled program.
 
-    ``labels``: 1-d integers of length ``b.shape[0]``.  A host sequence /
-    ndarray ships to the device once; a ``jax.Array`` (or a bolt TPU
-    array) STAYS on device — range validation is one two-scalar sync, the
-    label data itself never round-trips through the host.
+    ``labels`` is either the group of every record, as an array, or a
+    FUNCTION of the record that gives it.
+
+    **A label function** (``record -> integer``, jax-traceable like
+    ``filter``'s predicate; ``num_segments`` is then required) is traced
+    into the same program as everything else, so nothing record-sized is
+    made for it: ``b`` may be a deferred ``filter`` (with record-wise
+    maps behind it), whose predicate, maps, labels and fold are then ONE
+    pass that never builds the survivors — SQL's ``WHERE .. GROUP BY ..``
+    with its aggregates::
+
+        sums, counts = segment_reduce(
+            b.filter(shipped_by), labels=flag_and_status, num_segments=6,
+            value=lambda r: (r[QTY], r[PRICE], r[PRICE] * (100 - r[DISC])),
+            return_counts=True)
+
+    ``value`` (label functions only) is what a record adds to its group:
+    a function of the record returning an array or a TUPLE of arrays
+    (one aggregate each; the result is then a tuple of bolt arrays in
+    the same order); the record itself where ``None``.  On a table of
+    thin records give the aggregates as a tuple of scalars and not as
+    one stacked vector: the chip holds such a table with the rows on the
+    lanes, where a stacked value is written out row-sized before it is
+    folded and a tuple of scalars is not (PERF.md, PR 30).  A record
+    whose label falls outside ``[0, num_segments)`` joins no group.
+
+    **A label array**: 1-d integers of length ``b.shape[0]``.  A host
+    sequence / ndarray ships to the device once; a ``jax.Array`` (or a
+    bolt TPU array) STAYS on device — range validation is one two-scalar
+    sync, the label data itself never round-trips through the host.
     ``num_segments``: static group count (defaults to ``labels.max() + 1``
     — free on host labels, part of the same two-scalar sync on device
-    labels); groups with no records get ``0`` for sum/mean and the
-    dtype's identity (∓inf → the op's init) for max/min, matching
+    labels).
+
+    Groups with no records get ``0`` for sum/mean and the dtype's
+    identity (∓inf → the op's init) for max/min, matching
     ``jax.ops.segment_max/min``.  ``op='mean'`` on integer input promotes
     through the canonical float (float64 under x64, float32 on a
     production x64-off TPU) on BOTH backends, so the backends agree under
     either x64 setting.
     Returns a bolt array shaped ``(num_segments, *value_shape)`` with
-    ``split=1`` (``mode='local'`` computes the same thing in NumPy).
+    ``split=1`` (``mode='local'`` computes the same thing in NumPy);
+    with ``return_counts=True`` a pair of it and the exact int32 count of
+    records in every group, ``(num_segments,)`` — what a mean divides by,
+    and ``COUNT(*)``.
 
-    ``method``: ``None``/``"auto"`` (default) picks per a measured cost
-    model; ``"scatter"`` forces the ``jax.ops.segment_*`` scatter
-    combine; ``"matmul"`` forces the one-hot MXU form (sum/mean of
-    floating data only).  The matmul form computes ``onehot(labels) @
+    ``method`` (label arrays only): ``None``/``"auto"`` (default) picks
+    per a cost model; ``"scatter"`` forces the ``jax.ops.segment_*``
+    scatter combine; ``"matmul"`` forces the one-hot MXU form (sum/mean
+    of floating data only).  The matmul form computes ``onehot(labels) @
     X`` — small segment counts turn the memory-latency-bound scatter
-    into one MXU matmul (measured on chip, 2 GB f32, 256 segments:
-    scatter 28 GB/s flat / 153 GB/s in the (8192, 1024, 64) layout;
-    one-hot 321 GB/s at "highest", 449 GB/s under the "default"
-    precision scope — sort+contiguous-scatter measured WORSE than plain
-    scatter, 23 GB/s, and was dropped).  Products against a 0/1 matrix
-    are exact, so "highest" matches the scatter combine to f32
-    round-off (measured 2.4e-7 max rel).  Non-finite records would
+    into one MXU matmul; the (nseg, n) one-hot is a tensor of its own,
+    so the form is capped at the data's size.  Neither form has a cell
+    in the benchmark and no speed is stated for them; the label-function
+    fold is what ``lineitem-1chip.q1q6`` measures (PERF.md).  Products
+    against a 0/1 matrix are exact, so "highest" matches the scatter
+    combine to f32 round-off.  Non-finite records would
     poison whole value columns through ``0 x NaN``, so the program
     guards with one fused ``isfinite`` test and falls back to the
     scatter combine at runtime when any record is non-finite —
@@ -90,6 +120,19 @@ def segment_reduce(b, labels, num_segments=None, op="sum", method=None,
         raise ValueError(
             "method='matmul' serves sum/mean of real floating (or "
             "int-mean) data only, got op=%r dtype=%s" % (op, b.dtype))
+    if callable(labels):
+        if method not in (None, "auto"):
+            raise ValueError("method=%r chooses between the forms that take "
+                             "a label ARRAY; a label function has one form"
+                             % (method,))
+        if num_segments is None:
+            raise ValueError("a label function needs num_segments: the "
+                             "group count is static")
+        out = _fold_by_function(b, labels, value, int(num_segments), op)
+        return out if return_counts else out[0]
+    if value is not None:
+        raise ValueError("value= goes with a label function; with a label "
+                         "array, map the records first (b.map(value))")
     from bolt_tpu._precision import resolve
     pr = resolve(precision)
     from bolt_tpu.base import BoltArray
@@ -147,6 +190,9 @@ def segment_reduce(b, labels, num_segments=None, op="sum", method=None,
             ufunc = np.maximum if op == "max" else np.minimum
             ufunc.at(out, labels, x)
         from bolt_tpu.local.array import BoltArrayLocal
+        if return_counts:
+            return BoltArrayLocal(out), BoltArrayLocal(np.bincount(
+                labels, minlength=num_segments).astype(np.int32))
         return BoltArrayLocal(out)
 
     from bolt_tpu.tpu.array import (BoltArrayTPU, _cached_jit, _chain_apply,
@@ -252,7 +298,12 @@ def segment_reduce(b, labels, num_segments=None, op="sum", method=None,
                     out = mean_divide(out, lab)
             else:
                 out = scatter_out(flat, lab)
-            return _constrain(out, mesh, 1)
+            out = _constrain(out, mesh, 1)
+            if return_counts:
+                return out, _constrain(jax.ops.segment_sum(
+                    jnp.ones((n,), jnp.int32), lab,
+                    num_segments=num_segments), mesh, 1)
+            return out
         return jax.jit(run)
 
     # labels is a traced argument (its length is pinned by base.shape), so
@@ -261,10 +312,55 @@ def segment_reduce(b, labels, num_segments=None, op="sum", method=None,
     # happens inside the program — no host round-trip)
     fn = _cached_jit(("segreduce", op, funcs, base.shape, str(base.dtype),
                       split, num_segments, mesh, use_matmul,
-                      pr if use_matmul else None), build)
+                      pr if use_matmul else None, return_counts), build)
     lab = labels if device_labels else jnp.asarray(labels, dtype=jnp.int32)
     out = fn(_check_live(base), lab)
+    if return_counts:
+        return BoltArrayTPU(out[0], 1, mesh), BoltArrayTPU(out[1], 1, mesh)
     return BoltArrayTPU(out, 1, mesh)
+
+
+def _fold_by_function(b, label, value, num_segments, op):
+    """``segment_reduce`` by a label function: ``(folded, counts)``.  On
+    the TPU backend the terminal ``BoltArrayTPU._grouped_fold`` (one
+    program; a deferred filter folded in); on the local backend the same
+    semantics record by record in NumPy, the oracle."""
+    if num_segments < 0:
+        raise ValueError("num_segments must not be negative")
+    if b.mode == "tpu":
+        from bolt_tpu.tpu.array import _TRACE_ERRORS, _warn_fallback
+        try:
+            return b._grouped_fold(label, value, num_segments, op)
+        except _TRACE_ERRORS as exc:
+            # a label or value function that does not trace: the local
+            # oracle answers, as for a predicate that does not
+            _warn_fallback("segment_reduce", label, exc)
+            folded, counts = _fold_by_function(
+                b.tolocal(), label, value, num_segments, op)
+            totpu = lambda a: b._constructor.array(      # noqa: E731
+                np.asarray(a), context=b.mesh, axis=(0,))
+            return jax.tree_util.tree_map(totpu, folded), totpu(counts)
+    from bolt_tpu.local.array import BoltArrayLocal
+    x = np.asarray(b)
+    recs = list(x)
+    labs = np.asarray([int(np.asarray(label(r)).reshape(())) for r in recs],
+                      dtype=np.int64).reshape((-1,))
+    vals = recs if value is None else [value(r) for r in recs]
+    # the value's structure: from a record, or where there is none from
+    # the function over zeros
+    probe = vals[0] if vals else np.zeros(x.shape[1:], x.dtype) \
+        if value is None else value(np.zeros(x.shape[1:], x.dtype))
+    zeros, tree = jax.tree_util.tree_flatten(probe)
+    keep = (labs >= 0) & (labs < num_segments)
+    # every leaf is a label-ARRAY reduction of the records that have a
+    # group: the oracle above answers it
+    folded = [segment_reduce(BoltArrayLocal(np.asarray(
+        [np.asarray(jax.tree_util.tree_leaves(v)[i]) for v in vals]).reshape(
+            (len(recs),) + np.shape(z))[keep]), labs[keep], num_segments, op)
+        for i, z in enumerate(zeros)]
+    counts = np.bincount(labs[keep], minlength=num_segments).astype(np.int32)
+    return (jax.tree_util.tree_unflatten(tree, folded),
+            BoltArrayLocal(counts))
 
 
 def _topk_desc(xp, moved, k):

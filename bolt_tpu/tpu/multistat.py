@@ -357,10 +357,14 @@ class _StatGroup:
                 m.result = outs[index[_slot(m)[0]]]
 
     def _resolve_fpending(self):
-        from bolt_tpu.tpu.array import _check_live, _chain_apply, \
-            _constrain, _masked_stat_expr, _pred_mask
+        from bolt_tpu.tpu.array import _constrain, \
+            _launch_filter_terminal, _masked_stat_expr
         members = self.members
-        base, funcs, pred, psplit, vshape, n, vdtype = self.fpending
+        fp = self.fpending
+        base, n = fp.base, fp.n
+        vshape, vdtype = tuple(fp.out.shape), fp.out.dtype
+        # geometry only in the cached closures (see _resolve_reduce)
+        geo = fp.geometry()
         mesh = self.mesh
         donate = self.donate
         if len(members) == 1:
@@ -368,15 +372,12 @@ class _StatGroup:
             # the eager path (same key, same expressions; never
             # needs_count — min/max handles are not lazy here)
             m = members[0]
-            # geometry only in the cached closure (see _resolve_reduce)
             name, axes, keepdims, ddof, new_split = (
                 m.name, m.axes, m.keepdims, m.ddof, m.new_split)
 
             def build():
                 def stat(data):
-                    mapped = _chain_apply(funcs, psplit, data)
-                    flat = mapped.reshape((n,) + tuple(vshape))
-                    mask = _pred_mask(pred, flat)
+                    flat, mask = geo.records(data)
                     mfull = mask.reshape((n,) + (1,) * len(vshape))
                     out = _masked_stat_expr(
                         name, flat, mask, mfull, axes, keepdims,
@@ -385,11 +386,10 @@ class _StatGroup:
                 return jax.jit(stat,
                                donate_argnums=(0,) if donate else ())
 
-            fn = _cached_jit(("filter-stat", m.name, pred, funcs,
-                              base.shape, str(base.dtype), psplit,
-                              m.axes, m.keepdims, m.ddof, donate, mesh),
+            fn = _cached_jit(("filter-stat", m.name) + fp.key()
+                             + (m.axes, m.keepdims, m.ddof, donate, mesh),
                              build)
-            m.result = fn(_check_live(base))
+            m.result = _launch_filter_terminal(fn, base, m.name, donate)
             return
 
         slots = sorted({s for m in members for s in _slot(m)}, key=repr)
@@ -397,9 +397,7 @@ class _StatGroup:
 
         def build():
             def stat(data):
-                mapped = _chain_apply(funcs, psplit, data)
-                flat = mapped.reshape((n,) + tuple(vshape))
-                mask = _pred_mask(pred, flat)
+                flat, mask = geo.records(data)
                 mfull = mask.reshape((n,) + (1,) * len(vshape))
                 outs = []
                 for (name, axes, keepdims, ddof) in slots:
@@ -411,12 +409,11 @@ class _StatGroup:
                 return tuple(outs)
             return jax.jit(stat, donate_argnums=(0,) if donate else ())
 
-        fn = _cached_jit(("multi-filter-stat", slots, pred, funcs,
-                          base.shape, str(base.dtype), psplit, donate,
-                          mesh), build)
+        fn = _cached_jit(("multi-filter-stat", slots) + fp.key()
+                         + (donate, mesh), build)
         with _obs.span("array.multi_stat", terminals=len(members),
                        slots=len(slots), filtered=True, donate=donate):
-            outs = fn(_check_live(base))
+            outs = _launch_filter_terminal(fn, base, "multi", donate)
         _engine.record_fused_stats(len(members))
         index = {s: i for i, s in enumerate(slots)}
         for m in members:
@@ -620,7 +617,8 @@ def _chain_member(g, name, axis, keepdims, ddof):
 
 
 def _fpending_member(g, name, axis, keepdims, ddof):
-    _, _, _, _, vshape, n, vdtype = g.fpending
+    n, vshape, vdtype = g.fpending.n, g.fpending.out.shape, \
+        g.fpending.out.dtype
     if name not in _FPENDING_LAZY:
         return NotImplemented
     ndim = 1 + len(vshape)

@@ -107,7 +107,8 @@ def test_filter_both_paths(cmesh, monkeypatch):
     # two-phase eager path with the bucketed gather
     monkeypatch.setattr(mod, "_FILTER_FUSED_MAX_BYTES", 0)
     out2 = b.filter(lambda v: v.mean() > 0)
-    assert not out2.pending
+    assert out2.pending             # deferred at every size (ISSUE 30)
+    assert out2.shape == x[keep].shape and not out2.pending
     _close(out2, x[keep])
 
 

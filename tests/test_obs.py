@@ -310,6 +310,7 @@ _EXPECTED_ENGINE_KEYS = {
     "fused_stat_groups": False, "fused_stat_terminals": False,
     "getitems_fused": False, "resplit_views": False,
     "gram_kernel_programs": False,
+    "filters_fused": False, "filter_compactions": False,
     "coalesced_builds": False, "coalesced_compiles": False,
     "batched_dispatches": False, "batched_requests": False,
     "codec_encode_seconds": True, "codec_bytes_raw": False,

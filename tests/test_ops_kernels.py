@@ -344,6 +344,139 @@ def test_window_statistic_is_one_fusion_on_v5e(v5e_device, name, window,
 
 
 # ---------------------------------------------------------------------
+# compile-only: a deferred filter folded into the terminal that reads it
+# at HBM size (ISSUE 30).  TPC-H Q6 and Q1 over this chip's half of
+# LINEITEM at SF 100, (300018951, 7) float32, which the chip holds with
+# the rows on the lanes (9.60 GB), and BASELINE config 4 on the resident
+# stack: each ONE program beside its argument, nothing row-sized stored
+# (no temporary as large as one column, 1.2 GB; the stack's under 0.1
+# GB), no gather and no sort
+# ---------------------------------------------------------------------
+
+_LINEITEM = (300018951, 7)
+
+
+def _q6_pred(r):
+    return ((r[0] >= 731) & (r[0] < 1096) & (r[3] >= 5) & (r[3] <= 7)
+            & (r[1] < 24))
+
+
+def _q6_value(r):
+    return r[2] * r[3]
+
+
+def _q1_pred(r):
+    return r[0] <= 2436
+
+
+def _q1_group(r):
+    return (3 * r[6] + r[5]).astype(jnp.int32)
+
+
+def _q1_terms(r):
+    disc_price = r[2] * (100 - r[3])
+    return (r[1], r[2], disc_price, disc_price * (100 + r[4]), r[3],
+            jnp.ones_like(r[0]))
+
+
+def _corner(v):
+    return v[0, 0, :8].mean() > 0
+
+
+def _plus_one(v):
+    return v + 1
+
+
+def _filter_of(funcs, pred, shape, post=(), out=None):
+    from bolt_tpu.tpu.array import _Filter
+    import jax
+    out = shape[1:] if out is None else out
+    return _Filter(None, funcs, pred, 1, tuple(shape[1:]), shape[0], _F32,
+                   post, jax.ShapeDtypeStruct(tuple(out), _F32))
+
+
+def _q6_program(data):
+    from bolt_tpu.tpu.array import _masked_stat_expr
+    flat, mask = _filter_of((), _q6_pred, _LINEITEM, (_q6_value,),
+                            ()).records(data)
+    return _masked_stat_expr("sum", flat, mask, mask, (0,), False, None,
+                             (), _F32)
+
+
+def _q1_program(data):
+    from bolt_tpu.tpu.array import _grouped_fold_expr
+    fp = _filter_of((), _q1_pred, _LINEITEM)
+    flat, mask = fp.records(data)
+    return _grouped_fold_expr("sum", flat, mask, _q1_group, _q1_terms, 6,
+                              fp.mapped(data))
+
+
+def _stack_filter_program(data):
+    from bolt_tpu.tpu.array import _masked_stat_expr
+    flat, mask = _filter_of((_plus_one,), _corner, _STACK).records(data)
+    return _masked_stat_expr("sum", flat, mask,
+                             mask.reshape((_STACK[0], 1, 1, 1)), (0,),
+                             False, None, _STACK[1:], _F32)
+
+
+_FOLD_CASES = [
+    # name, the terminal's traced body, argument shape, temp limit (bytes)
+    ("tpch_q6", _q6_program, _LINEITEM, 1.2e9),
+    ("tpch_q1", _q1_program, _LINEITEM, 1.2e9),
+    ("stack_filter_sum", _stack_filter_program, _STACK, 0.1e9),
+]
+
+
+@pytest.mark.parametrize("name,program,shape,limit", _FOLD_CASES,
+                         ids=[c[0] for c in _FOLD_CASES])
+def test_filter_folded_into_its_terminal_is_one_pass_on_v5e(
+        v5e_device, name, program, shape, limit):
+    import re
+    import jax
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    with jax.enable_x64(False):
+        compiled = jax.jit(program).lower(jax.ShapeDtypeStruct(
+            shape, _F32, sharding=where)).compile()
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < limit, name
+    assert not re.search(r"= \S+ (gather|sort)\(", text), name
+    entry = text[text.index("ENTRY"):]
+    if shape == _LINEITEM:
+        # the table is held with the rows on the lanes, the seven columns
+        # on eight sublanes: 9.60 GB for 8.40 logical, and ONE fusion
+        # reads it
+        assert "f32[300018951,7]{0,1:T(8,128)}" in entry.splitlines()[1]
+        assert mem.argument_size_in_bytes == -(-300018951 // 128) * 128 * 8 * 4
+        assert len(re.findall(r" fusion\(", entry)) == 1, name
+    else:
+        assert mem.argument_size_in_bytes == 4 * int(np.prod(shape))
+
+
+def test_a_stacked_value_is_written_out_row_sized_on_v5e(v5e_device):
+    """Why ``segment_reduce``'s docstring asks for a tuple of scalars on a
+    table of thin records: the same six aggregates as one ``jnp.stack``ed
+    vector are a (rows, 6) temporary that the fold then reads, which at
+    this size does not fit beside the table."""
+    import jax
+    from bolt_tpu.tpu.array import _grouped_fold_expr
+    rows = 30_000_001                 # a tenth: what fails must compile
+
+    def program(data):
+        fp = _filter_of((), _q1_pred, (rows, 7))
+        flat, mask = fp.records(data)
+        return _grouped_fold_expr(
+            "sum", flat, mask, _q1_group,
+            lambda r: jnp.stack(_q1_terms(r)), 6)
+
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    with jax.enable_x64(False):
+        compiled = jax.jit(program).lower(jax.ShapeDtypeStruct(
+            (rows, 7), _F32, sharding=where)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > rows * 4 * 6
+
+
+# ---------------------------------------------------------------------
 # compile-only: BASELINE config 5 at HBM size (ISSUE 26).  Both programs
 # of the series64-1chip configuration have to fit one 16 GB chip beside
 # their 10.74 GB argument: no temporary as large as the data (a relayout

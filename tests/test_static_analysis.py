@@ -202,8 +202,10 @@ def test_check_survives_malformed_split_state(mesh):
 def test_blt007_nonscalar_predicate_seeded(mesh):
     b = bolt.array(_x(), mesh)
     bad = BoltArrayTPU(None, 1, mesh)
-    bad._fpending = (b._data, (), lambda v: v > 0, 1, (6, 4), 16,
-                     np.dtype(np.float64))
+    from bolt_tpu.tpu.array import _Filter
+    bad._fpending = _Filter(
+        b._data, (), lambda v: v > 0, 1, (6, 4), 16, np.dtype(np.float64),
+        (), jax.ShapeDtypeStruct((6, 4), np.float64))
     rep = analysis.check(bad)
     assert not rep.ok and rep.has("BLT007")
     assert "scalar" in str(rep)

@@ -237,15 +237,21 @@ def test_filter_toarray_large_buffer_path(mesh, monkeypatch):
 
 
 def test_filter_eager_path_for_large_inputs(mesh, monkeypatch):
-    # above the fused-path size cap, filter takes the memory-safe
-    # two-phase route: eager count sync, survivor-sized gather output
+    # above the fused-path size cap a filter whose survivors are needed
+    # as an array takes the memory-safe two-phase route (count synced to
+    # the host, survivor-sized gather output); the filter itself defers
+    # at every size (ISSUE 30), so the size is asked where the buffer is
+    # built and not in front of the terminals that build none
     import bolt_tpu.tpu.array as mod
     monkeypatch.setattr(mod, "_FILTER_FUSED_MAX_BYTES", 0)
     x = _x()
     b = bolt.array(x, mesh)
     out = b.filter(lambda v: v.sum() > 0)
-    assert not out.pending  # eager path resolves immediately
+    assert out.pending              # nothing dispatched yet
     expected = np.asarray([v for v in x if v.sum() > 0])
+    assert out.shape == expected.shape
+    assert not out.pending          # the two-phase path leaves it concrete
+    assert any(k[0] == "filter-gather" for k in mod._JIT_CACHE)
     assert allclose(out.toarray(), expected)
     assert out.split == 1
     # value-axis filter goes through _align then the same path
