@@ -160,6 +160,12 @@ _SCHEMA = {
                                   # executor is chosen at lowering, so
                                   # programs can be counted, not calls;
                                   # 0 on the CPU)
+    "fold_kernel_programs": 0,    # programs LOWERED with tpu/fold.py's
+                                  # thin_fold kernel in them: a filter
+                                  # (or a map chain) folded into a
+                                  # sum-like terminal over a table of
+                                  # thin records, for one TPU device (0
+                                  # on the CPU)
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -524,6 +530,15 @@ def record_gram_kernel_program():
     device with a Gram matrix of real float32 that packs.  Per call the
     record is the device trace (``packed_gram*`` events)."""
     _COUNTERS.add("gram_kernel_programs")
+
+
+def record_fold_kernel_program():
+    """One program was lowered with the ``thin_fold`` Mosaic kernel in it
+    (``tpu/fold.py :: _fold_primitive``): a program for one TPU device
+    that folds a filter or a map chain into a sum-like terminal over a
+    stored table of at most eight 32-bit values a record.  Per call the
+    record is the device trace (``thin_fold*`` events)."""
+    _COUNTERS.add("fold_kernel_programs")
 
 
 def donation_granted():

@@ -69,6 +69,12 @@ def segment_reduce(b, labels, num_segments=None, op="sum", method=None,
     lanes, where a stacked value is written out row-sized before it is
     folded and a tuple of scalars is not (PERF.md, PR 30).  A record
     whose label falls outside ``[0, num_segments)`` joins no group.
+    Over a stored table of thin records (``(rows, c)`` float32 or int32,
+    ``c <= 8``) with an element-wise predicate, label and value the fold
+    is the Mosaic kernel ``thin_fold`` in a program for one TPU device
+    (``tpu/fold.py``, engine counter ``fold_kernel_programs``): one
+    stream of the table, float sums whose last digits differ from the
+    fusion's (more running sums), every count exact.
 
     **A label array**: 1-d integers of length ``b.shape[0]``.  A host
     sequence / ndarray ships to the device once; a ``jax.Array`` (or a

@@ -54,6 +54,7 @@ from bolt_tpu import stream as _streamlib
 from bolt_tpu.base import BoltArray, HostFallbackWarning
 from bolt_tpu.obs import trace as _obs
 from bolt_tpu.parallel.sharding import key_sharding
+from bolt_tpu.tpu import fold as _fold
 from bolt_tpu.utils import (argpack, check_value_shape as _check_value_shape,
                             inshape, isreshapeable, istransposeable, prod,
                             tupleize)
@@ -629,10 +630,10 @@ class _Filter(NamedTuple):
         return self._replace(base=None)
 
 
-def _fold_identity(name, dtype):
-    """What a record that takes no part is folded onto: the identity of
-    the reduction ``name`` in ``dtype`` (``where(kept, v, identity)``
-    makes a dropped record, NaNs included, inert)."""
+def _fold_identity_value(name, dtype):
+    """The identity of the reduction ``name`` in ``dtype`` as a NumPy
+    scalar (what a Mosaic kernel, which holds no array constants, folds
+    a row onto: ``tpu/fold.py``)."""
     dtype = np.dtype(dtype)
     if name in ("sum", "prod", "any", "all"):
         ident = {"sum": 0, "prod": 1, "any": False, "all": True}[name]
@@ -643,7 +644,26 @@ def _fold_identity(name, dtype):
     else:
         info = np.iinfo(dtype)
         ident = info.min if name == "max" else info.max
-    return jnp.asarray(ident, dtype)
+    return dtype.type(ident)
+
+
+def _fold_identity(name, dtype):
+    """What a record that takes no part is folded onto: the identity of
+    the reduction ``name`` in ``dtype`` (``where(kept, v, identity)``
+    makes a dropped record, NaNs included, inert)."""
+    return jnp.asarray(_fold_identity_value(name, dtype), dtype)
+
+
+def _stat_dtype(name, axes, vshape, vdtype):
+    """The dtype of the statistic ``name`` over ``axes`` of records
+    ``vshape``/``vdtype``: jnp's own promotion rule on a 1-record probe,
+    so fused and eager results always agree on dtype."""
+    ref = {"sum": jnp.sum, "prod": jnp.prod, "any": jnp.any,
+           "all": jnp.all, "max": jnp.max, "min": jnp.min,
+           "mean": jnp.mean, "var": jnp.var, "std": jnp.std}[name]
+    return jax.eval_shape(
+        lambda x: ref(x, axis=axes), jax.ShapeDtypeStruct(
+            (1,) + tuple(vshape), np.dtype(vdtype))).dtype
 
 
 def _masked_stat_expr(name, flat, mask, mfull, axes, keepdims, ddof,
@@ -656,16 +676,9 @@ def _masked_stat_expr(name, flat, mask, mfull, axes, keepdims, ddof,
     masked COUNT computed in the same pass (var as the one-pass moment
     form ``(Σx² − (Σx)²/n)/(n−ddof)``); the rest fold dropped records
     onto their identity."""
-    vdtype = np.dtype(vdtype)
     op = {"sum": jnp.sum, "prod": jnp.prod, "any": jnp.any,
           "all": jnp.all, "max": jnp.max, "min": jnp.min}.get(name)
-    ref = {"mean": jnp.mean, "var": jnp.var, "std": jnp.std}.get(
-        name, op)
-    # output dtype from jnp's own promotion rule on a 1-record probe,
-    # so fused and eager results always agree on dtype
-    out_dt = jax.eval_shape(
-        lambda x: ref(x, axis=axes), jax.ShapeDtypeStruct(
-            (1,) + tuple(vshape), vdtype)).dtype
+    out_dt = _stat_dtype(name, axes, vshape, vdtype)
     if name in ("sum", "prod", "any", "all", "max", "min"):
         v = jnp.where(mfull, flat, _fold_identity(name, flat.dtype))
         out = op(v, axis=axes, keepdims=keepdims)
@@ -679,10 +692,19 @@ def _masked_stat_expr(name, flat, mask, mfull, axes, keepdims, ddof,
     den = (cnt * prodv).astype(out_dt)
     xf = jnp.where(mfull, flat, jnp.zeros((), flat.dtype)).astype(out_dt)
     s1 = jnp.sum(xf, axis=axes, keepdims=keepdims)
+    s2 = None if name == "mean" else jnp.sum(xf * xf, axis=axes,
+                                             keepdims=keepdims)
+    return _moments(name, s1, s2, den, ddof)
+
+
+def _moments(name, s1, s2, den, ddof):
+    """``mean``/``var``/``std`` from the survivors' sum ``s1``, their sum
+    of squares ``s2`` and what each slot divides by, ``den``: the finish
+    of :func:`_masked_stat_expr`, and of the same fold by the kernel
+    (``tpu/fold.py``)."""
     if name == "mean":
         return s1 / den
     dd = 0.0 if ddof is None else ddof
-    s2 = jnp.sum(xf * xf, axis=axes, keepdims=keepdims)
     out = (s2 - s1 * s1 / den) / (den - dd)
     if name == "std":
         out = jnp.sqrt(out)
@@ -1328,7 +1350,13 @@ class BoltArrayTPU(BoltArray):
         (``sum``/``mean``/…, ``reduce``, ``ops.segment_reduce`` by a label
         function) folds the predicate into its own ONE pass and builds no
         buffer, and a record-wise ``map`` in between stays recorded too,
-        so ``b.filter(p).map(f).sum()`` reads the input once.  Only a
+        so ``b.filter(p).map(f).sum()`` reads the input once.  Over a
+        stored table of thin records (``(rows, c)`` float32 or int32,
+        ``c <= 8``) with element-wise functions that pass is the Mosaic
+        kernel ``thin_fold`` in a program for one TPU device
+        (``tpu/fold.py``, engine counter ``fold_kernel_programs``): it
+        keeps more running sums than XLA's fusion, so a float answer's
+        last digits differ from the fusion's.  Only a
         consumer that needs the survivors as an array compacts them
         (:meth:`_resolve_fpending`), and only there is the size asked:
         the padded compaction buffer is a full-size transient copy, so
@@ -1603,23 +1631,22 @@ class BoltArrayTPU(BoltArray):
             return NotImplemented
         donate = _chain_donate_ok(self._fpending)    # [0] is the base
         fp = self._fpending
-        base, geo, n, mesh = fp.base, fp.geometry(), fp.n, self._mesh
+        base, geo, mesh = fp.base, fp.geometry(), self._mesh
         new_split = 1 if keepdims else 0
         needs_count = name in ("max", "min")
 
         def build():
+            # the fold lives in ONE module function, shared with the
+            # fused multi-terminal program (bolt_tpu/tpu/multistat.py)
+            # and the grouped fold: single and fused filter-stats trace
+            # identical arithmetic, by the same executor
+            fold = _fold.Fold(geo, ((name, axes, keepdims, ddof),),
+                              needs_count)
+
             def stat(data):
-                flat, mask = geo.records(data)
-                mfull = mask.reshape((n,) + (1,) * len(vshape))
-                cnt = jnp.sum(mask, dtype=jnp.int32)
-                # the per-terminal masked reduction lives in ONE module
-                # function, shared with the fused multi-terminal
-                # program (bolt_tpu/tpu/multistat.py) — single and
-                # fused filter-stats trace identical arithmetic
-                out = _masked_stat_expr(name, flat, mask, mfull, axes,
-                                        keepdims, ddof, vshape, vdtype)
+                out, *cnt = _fold.fold_records(fold, data)
                 out = _constrain(out, mesh, new_split)
-                return (out, cnt) if needs_count else out
+                return (out, cnt[0]) if needs_count else out
             return jax.jit(stat, donate_argnums=(0,) if donate else ())
 
         fn = _cached_jit(("filter-stat", name) + fp.key()
@@ -1733,23 +1760,12 @@ class BoltArrayTPU(BoltArray):
         _engine.strict_guard(self, "segment_reduce()")
         fp = self._fpending
         if fp is not None:
-            base, geo, key, rec = fp.base, fp.geometry(), fp.key(), fp.out
-
-            def records(data):
-                return geo.records(data) + (geo.mapped(data),)
+            base, source, key, rec = fp.base, fp.geometry(), fp.key(), fp.out
         else:
             base, funcs = self._chain_parts()
-            split = self._split
-            key = (funcs, base.shape, str(base.dtype), split)
+            source = _fold.Chain(funcs, self._split)
+            key = (funcs, base.shape, str(base.dtype), self._split)
             rec = jax.ShapeDtypeStruct(self.shape[1:], self.dtype)
-
-            def records(data):
-                # the label's own application of the maps, as a filter's
-                # readers have theirs (_Filter.mapped)
-                again = jax.lax.optimization_barrier(data) if funcs \
-                    else data
-                return (_chain_apply(funcs, split, data), None,
-                        _chain_apply(funcs, split, again))
         lab = _cached_eval_shape(
             ("segreduce-label", label, tuple(rec.shape), str(rec.dtype)),
             lambda: jax.eval_shape(label, rec))
@@ -1763,10 +1779,10 @@ class BoltArrayTPU(BoltArray):
         mesh = self._mesh
 
         def build():
+            spec = _fold.Fold(source, group=(op, label, value, nseg))
+
             def fold(data):
-                flat, mask, keyed = records(data)
-                folded, counts = _grouped_fold_expr(op, flat, mask, label,
-                                                    value, nseg, keyed)
+                folded, counts = _fold.fold_records(spec, data)
                 return (jax.tree_util.tree_map(
                     lambda o: _constrain(o, mesh, 1), folded),
                     _constrain(counts, mesh, 1))

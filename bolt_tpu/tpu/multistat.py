@@ -65,6 +65,7 @@ from bolt_tpu import engine as _engine
 from bolt_tpu import _precision
 from bolt_tpu import stream as _streamlib
 from bolt_tpu.obs import trace as _obs
+from bolt_tpu.tpu import fold as _fold
 from bolt_tpu.utils import inshape, prod, tupleize
 
 
@@ -357,31 +358,26 @@ class _StatGroup:
                 m.result = outs[index[_slot(m)[0]]]
 
     def _resolve_fpending(self):
-        from bolt_tpu.tpu.array import _constrain, \
-            _launch_filter_terminal, _masked_stat_expr
+        from bolt_tpu.tpu.array import _constrain, _launch_filter_terminal
         members = self.members
         fp = self.fpending
-        base, n = fp.base, fp.n
-        vshape, vdtype = tuple(fp.out.shape), fp.out.dtype
+        base = fp.base
         # geometry only in the cached closures (see _resolve_reduce)
         geo = fp.geometry()
         mesh = self.mesh
         donate = self.donate
         if len(members) == 1:
             # standalone resolution: the exact filter-stat terminal of
-            # the eager path (same key, same expressions; never
-            # needs_count — min/max handles are not lazy here)
+            # the eager path (same key, same fold; never needs_count —
+            # min/max handles are not lazy here)
             m = members[0]
-            name, axes, keepdims, ddof, new_split = (
-                m.name, m.axes, m.keepdims, m.ddof, m.new_split)
+            slot, new_split = _slot(m)[0], m.new_split
 
             def build():
+                fold = _fold.Fold(geo, (slot,))
+
                 def stat(data):
-                    flat, mask = geo.records(data)
-                    mfull = mask.reshape((n,) + (1,) * len(vshape))
-                    out = _masked_stat_expr(
-                        name, flat, mask, mfull, axes, keepdims,
-                        ddof, vshape, vdtype)
+                    out, = _fold.fold_records(fold, data)
                     return _constrain(out, mesh, new_split)
                 return jax.jit(stat,
                                donate_argnums=(0,) if donate else ())
@@ -396,17 +392,13 @@ class _StatGroup:
         slots = tuple(slots)
 
         def build():
+            fold = _fold.Fold(geo, slots)
+
             def stat(data):
-                flat, mask = geo.records(data)
-                mfull = mask.reshape((n,) + (1,) * len(vshape))
-                outs = []
-                for (name, axes, keepdims, ddof) in slots:
-                    outs.append(_constrain(
-                        _masked_stat_expr(name, flat, mask, mfull, axes,
-                                          keepdims, ddof, vshape,
-                                          vdtype),
-                        mesh, 1 if keepdims else 0))
-                return tuple(outs)
+                return tuple(
+                    _constrain(out, mesh, 1 if keepdims else 0)
+                    for out, (_, _, keepdims, _) in zip(
+                        _fold.fold_records(fold, data), slots))
             return jax.jit(stat, donate_argnums=(0,) if donate else ())
 
         fn = _cached_jit(("multi-filter-stat", slots) + fp.key()

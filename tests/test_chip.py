@@ -112,6 +112,42 @@ def test_filter_both_paths(cmesh, monkeypatch):
     _close(out2, x[keep])
 
 
+def test_thin_records_fold_by_the_kernel(cmesh):
+    # tpu/fold.py (ISSUE 31): on one chip the fold of a filter into a sum,
+    # the moments, the extremes and a grouped fold over (rows, c <= 8) is
+    # the Mosaic kernel thin_fold; small integers, so every sum is exact
+    import jax
+    from bolt_tpu import engine, ops
+    rows = 3 * 65536 + 4321
+    x = np.random.RandomState(31).randint(0, 40, size=(rows, 7)).astype(
+        np.float32)
+    b = bolt.array(x, cmesh)
+    keep = x[:, 0] > 10
+    c0 = engine.counters()["fold_kernel_programs"]
+    kept = b.filter(lambda r: r[0] > 10)
+    _close(kept.map(lambda r: r[2] * r[3]).sum(),
+           (x[keep, 2] * x[keep, 3]).sum(dtype=np.float64), rtol=1e-7)
+    total, spread, top = bolt.compute(kept.sum(), kept.var(), kept.max())
+    _close(total, x[keep].sum(axis=0, dtype=np.float64), rtol=1e-7)
+    _close(spread, x[keep].var(axis=0, dtype=np.float64), rtol=1e-3)
+    _close(top, x[keep].max(axis=0))
+    sums, counts = ops.segment_reduce(
+        kept, labels=lambda r: (r[6] % 5).astype(np.int32) - 1,
+        num_segments=3, value=lambda r: (r[1], r[1] * r[2] + 1),
+        return_counts=True)
+    gid = (x[:, 6] % 5).astype(np.int64) - 1
+    for g in range(3):
+        hit = keep & (gid == g)
+        assert int(counts.toarray()[g]) == hit.sum()
+        assert float(sums[0].toarray()[g]) == x[hit, 1].sum(dtype=np.float64)
+        assert float(sums[1].toarray()[g]) == (
+            x[hit, 1] * x[hit, 2] + 1).sum(dtype=np.float64)
+    placed = engine.counters()["fold_kernel_programs"] - c0
+    # the sum, the fused group (max is a program of its own: it syncs the
+    # survivors' count) and the grouped fold, each lowered once
+    assert placed == (4 if len(jax.devices()) == 1 else 0), placed
+
+
 def test_swap_and_chunked_halo_map(cmesh):
     x = _x((8, 6, 32), seed=4)
     b = bolt.array(x, cmesh)

@@ -477,6 +477,206 @@ def test_a_stacked_value_is_written_out_row_sized_on_v5e(v5e_device):
 
 
 # ---------------------------------------------------------------------
+# the fold over a table of thin records as one Mosaic kernel (ISSUE 31,
+# ``tpu/fold.py``).  On this CPU mesh ``thin_fold`` lowers to the
+# expressions (``_masked_stat_expr``, ``_grouped_fold_expr``); the kernel
+# itself runs here in Pallas' TPU interpret mode, with short blocks,
+# against those expressions on the same table.  Every value is a small
+# integer, so each float32 sum is exact in any order and the two must
+# agree to the digit
+# ---------------------------------------------------------------------
+
+def _thin_table(rows, c, dtype, seed=0):
+    rs = np.random.RandomState(seed + 31 * c)
+    return rs.randint(0, 40, size=(rows, c)).astype(dtype)
+
+
+def _thin_filter(pred, shape, dtype, post=(), funcs=()):
+    import jax
+    from bolt_tpu.tpu.array import _Filter
+    rec = jax.ShapeDtypeStruct(tuple(shape[1:]), dtype)
+    for f in funcs:
+        rec = jax.eval_shape(f, rec)
+    out = rec
+    for f in post:
+        out = jax.eval_shape(f, out)
+    return _Filter(None, tuple(funcs), pred, 1, tuple(rec.shape), shape[0],
+                   rec.dtype, tuple(post), out)
+
+
+def _fold_both_ways(fold, x, block=2048, chunk=1024):
+    """``(by the expressions, by the kernel)`` on the CPU, x64 off as on
+    the chip."""
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+    from bolt_tpu.tpu import fold as tf
+    old = tf._FOLD_BLOCK, tf._FOLD_CHUNK
+    tf._FOLD_BLOCK, tf._FOLD_CHUNK = block, chunk
+    try:
+        with jax.enable_x64(False):
+            assert tf._kernel_serves(fold, jax.ShapeDtypeStruct(
+                x.shape, x.dtype))
+            want = jax.jit(lambda d: tf._plain(fold, d))(x)
+            with pltpu.force_tpu_interpret_mode():
+                got = jax.jit(lambda d: tf._kernel_fold(fold, d))(x)
+    finally:
+        tf._FOLD_BLOCK, tf._FOLD_CHUNK = old
+    return jax.tree.leaves(want), jax.tree.leaves(got)
+
+
+def _last(r):
+    return r[-1]
+
+
+def _first_above_10(r):
+    return r[0] > 10
+
+
+def _first_below_0(r):
+    return r[0] < 0
+
+
+def _first_times_last(r):
+    return r[0] * r[-1]
+
+
+def _label_mod5_less_1(r):
+    # -1 and 3 lie outside [0, 3): those rows join no group
+    return (r[-1] % 5).astype(jnp.int32) - 1
+
+
+def _two_terms(r):
+    return (r[0], r[0] * r[-1] + 1)
+
+
+def _stat(name, ddof=None, keepdims=False):
+    return ((name, (0,), keepdims, ddof),)
+
+
+def _thin_cases():
+    from bolt_tpu.tpu.fold import Chain, Fold
+    f32, i32 = np.float32, np.int32
+    cases = []
+
+    def add(name, rows, c, dtype, make, spoil=None):
+        cases.append(pytest.param(rows, c, dtype, make, spoil, id=name))
+
+    q6 = lambda sh, dt: Fold(_thin_filter(                  # noqa: E731
+        _first_above_10, sh, dt, post=(_first_times_last,)), _stat("sum"))
+    q1 = lambda sh, dt: Fold(_thin_filter(_first_above_10, sh, dt),  # noqa
+                             group=("sum", _label_mod5_less_1,
+                                    _two_terms, 3))
+    for c in (1, 4, 7, 8):
+        add("q6-width%d-ragged" % c, 2 * 2048 + 1234 + c, c, f32, q6)
+        add("q1-width%d-ragged" % c, 2 * 2048 + 1234 + c, c, f32, q1)
+    add("q6-whole-blocks", 3 * 2048, 7, f32, q6)
+    add("q6-under-one-block", 1024 + 77, 7, f32, q6)
+    add("q1-under-one-block", 1024 + 77, 7, f32, q1)
+    add("q6-int32", 2048 + 999, 4, i32, q6)
+    add("q1-int32", 2048 + 999, 4, i32, q1)
+
+    def nans(x):        # NaN and inf where the predicate drops the row
+        x[x[:, 0] <= 10, 1:] = np.nan
+        x[::7][x[::7, 0] <= 10, 1:] = np.inf
+        return x
+    add("q6-nan-inf-in-dropped-rows", 2048 + 999, 7, f32, q6, nans)
+    add("q1-nan-inf-in-dropped-rows", 2048 + 999, 7, f32, q1, nans)
+    none = lambda sh, dt: Fold(_thin_filter(                # noqa: E731
+        _first_below_0, sh, dt), _stat("sum") + _stat("max"), True)
+    add("every-row-dropped", 2048 + 999, 7, f32, none)
+    add("every-row-dropped-grouped", 2048 + 999, 7, f32,
+        lambda sh, dt: Fold(_thin_filter(_first_below_0, sh, dt),
+                            group=("mean", _label_mod5_less_1, None, 3)))
+    moments = lambda sh, dt: Fold(                          # noqa: E731
+        _thin_filter(_first_above_10, sh, dt),
+        _stat("mean") + _stat("var", 1) + _stat("std", 0, True)
+        + _stat("min") + _stat("max") + _stat("sum", None, True), True)
+    add("mean-var-std-extremes-as-one-group", 2048 + 999, 7, f32, moments)
+    add("mean-of-int32-divides-by-the-masked-count", 2048 + 999, 4, i32,
+        lambda sh, dt: Fold(_thin_filter(_first_above_10, sh, dt,
+                                         post=(_last,)), _stat("mean")))
+    add("maps-in-front-of-the-filter", 2048 + 999, 7, f32,
+        lambda sh, dt: Fold(_thin_filter(
+            _first_above_10, sh, dt, funcs=(_plus_one, _plus_one)),
+            _stat("sum")))
+    for op in ("mean", "max", "min"):
+        add("grouped-%s" % op, 2048 + 999, 7, f32,
+            lambda sh, dt, op=op: Fold(
+                _thin_filter(_first_above_10, sh, dt),
+                group=(op, _label_mod5_less_1, _two_terms, 3)))
+    add("grouped-chain-no-filter", 2048 + 999, 7, f32,
+        lambda sh, dt: Fold(Chain((_plus_one,), 1),
+                            group=("sum", _label_mod5_less_1, None, 3)))
+    return cases
+
+
+@pytest.mark.parametrize("rows,c,dtype,make,spoil", _thin_cases())
+def test_thin_fold_kernel_matches_the_expressions(rows, c, dtype, make,
+                                                  spoil):
+    x = _thin_table(rows, c, dtype)
+    if spoil is not None:
+        x = spoil(x)
+    want, got = _fold_both_ways(make(x.shape, x.dtype), x)
+    assert len(want) == len(got)
+    for w, g in zip(want, got):
+        assert w.shape == g.shape and w.dtype == g.dtype
+        if np.issubdtype(w.dtype, np.integer):
+            assert np.array_equal(w, g)         # every count is exact
+        else:
+            # sums of small integers are exact in any order; a quotient
+            # of two such sums is the same division
+            np.testing.assert_allclose(g, w, rtol=1e-6, atol=0)
+
+
+def _thin_eligibility_cases():
+    from bolt_tpu.tpu.array import _WithKeysFunc
+    f32 = np.float32
+    sums = _stat("sum")
+    gather = np.array([0, 2])
+    return [
+        # name, shape, dtype, pred, post, stats, served
+        ("tpch_q6", _LINEITEM, f32, _q6_pred, (_q6_value,), sums, True),
+        ("width8", (50000, 8), f32, _first_above_10, (), sums, True),
+        ("width9", (50000, 9), f32, _first_above_10, (), sums, False),
+        ("fat-records", _STACK, f32, _corner, (), sums, False),
+        ("float64", (50000, 7), np.float64, _first_above_10, (), sums,
+         False),
+        ("bfloat16", (50000, 7), jnp.bfloat16, _first_above_10, (), sums,
+         False),
+        ("a-reduction-inside-the-record", (50000, 7), f32,
+         lambda r: r.sum() > 3, (), sums, False),
+        ("a-gather", (50000, 7), f32, lambda r: r[gather].max() > 3, (),
+         sums, False),
+        ("a-sort", (50000, 7), f32, lambda r: jnp.sort(r)[0] > 3, (), sums,
+         False),
+        ("keyed-map", (50000, 7), f32, _first_above_10,
+         (_WithKeysFunc(lambda kv: kv[1]),), sums, False),
+        ("prod", (50000, 7), f32, _first_above_10, (), _stat("prod"),
+         False),
+        ("a-value-axis-reduced", (50000, 7), f32, _first_above_10, (),
+         (("sum", (0, 1), False, None),), False),
+        ("few-rows", (1000, 7), f32, _first_above_10, (), sums, False),
+    ]
+
+
+@pytest.mark.parametrize("name,shape,dtype,pred,post,stats,served",
+                         _thin_eligibility_cases(),
+                         ids=[c[0] for c in _thin_eligibility_cases()])
+def test_which_folds_the_kernel_serves(name, shape, dtype, pred, post,
+                                       stats, served):
+    import jax
+    from bolt_tpu.tpu import fold as tf
+    from bolt_tpu.tpu.array import _Filter
+    fp = _Filter(None, (), pred, 1, tuple(shape[1:]), shape[0], dtype, post,
+                 jax.ShapeDtypeStruct(
+                     () if post == (_q6_value,) else tuple(shape[1:]),
+                     dtype))
+    with jax.enable_x64(name == "float64"):
+        assert tf._kernel_serves(tf.Fold(fp, stats), jax.ShapeDtypeStruct(
+            shape, dtype)) == served
+
+
+# ---------------------------------------------------------------------
 # compile-only: BASELINE config 5 at HBM size (ISSUE 26).  Both programs
 # of the series64-1chip configuration have to fit one 16 GB chip beside
 # their 10.74 GB argument: no temporary as large as the data (a relayout
@@ -745,3 +945,128 @@ def test_the_lowering_counts_programs_with_the_kernel(v5e_device):
     assert engine.counters()["gram_kernel_programs"] == c0 + 1
     _compile_series(_svdvals, v5e_device, (2, 16384, 48))
     assert engine.counters()["gram_kernel_programs"] == c0 + 1
+
+
+# ---------------------------------------------------------------------
+# compile-only: the fold over thin records through its ONE entry
+# (``tpu/fold.py :: fold_records``, ISSUE 31).  TPC-H Q6 and Q1 at the
+# benchmark's size are one ``thin_fold`` call over a bitcast of the table
+# and nothing else reads it; every fold the kernel does not serve, and
+# every program it cannot be placed in, keeps the text it had
+# ---------------------------------------------------------------------
+
+def _q6_fold(shape=_LINEITEM):
+    from bolt_tpu.tpu.fold import Fold
+    return Fold(_filter_of((), _q6_pred, shape, (_q6_value,), ()),
+                (("sum", (0,), False, None),))
+
+
+def _q1_fold(shape=_LINEITEM):
+    from bolt_tpu.tpu.fold import Fold
+    return Fold(_filter_of((), _q1_pred, shape),
+                group=("sum", _q1_group, _q1_terms, 6))
+
+
+def _compile_fold(fold, shape, where, entry=True):
+    """The compiled program of ``fold`` over ``shape`` placed ``where``:
+    through the entry the call sites use, or by the expressions alone."""
+    import jax
+    from bolt_tpu.tpu import fold as tf
+    run = tf.fold_records if entry else tf._plain
+    with jax.enable_x64(False):
+        return jax.jit(lambda data: run(fold, data)).lower(
+            jax.ShapeDtypeStruct(shape, _F32, sharding=where)).compile()
+
+
+@pytest.mark.parametrize("make", [_q6_fold, _q1_fold],
+                         ids=["tpch_q6", "tpch_q1"])
+def test_thin_fold_is_one_kernel_over_a_bitcast_on_v5e(v5e_device, make):
+    import re
+    import jax
+    compiled = _compile_fold(make(), _LINEITEM,
+                             jax.sharding.SingleDeviceSharding(v5e_device))
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert len(_kernel_calls(text, "thin_fold")) == 1
+    assert text.count("tpu_custom_call") == 1
+    # the table as it is held, 9.60 GB, and a few accumulators beside it
+    assert mem.argument_size_in_bytes == -(-300018951 // 128) * 128 * 8 * 4
+    assert mem.temp_size_in_bytes < 16e6
+    entry = text[text.index("ENTRY"):]
+    # the kernel's operand is a VIEW of the table as it is held: a
+    # bitcast, and nothing else takes the table or is of its size
+    table = [ln for ln in entry.splitlines()[1:] if "300018951" in ln]
+    assert len(table) == 3, table
+    assert "= f32[300018951,7]{0,1:T(8,128)} parameter(0)" in table[0]
+    assert re.search(r"= f32\[7,300018951\]\S* bitcast\(", table[1])
+    assert "tpu_custom_call" in table[2]
+    assert not re.search(r"= \S+ (copy|transpose|gather|sort)\(", text)
+
+
+def _reads_whole_record(r):
+    return r.sum() > 100
+
+
+def _kept_texts(fold, shape, where):
+    """``(through the entry, by the expressions)``: the two programs'
+    computations, without what names the Python that traced them (the
+    table of stack frames in front, each instruction's metadata)."""
+    import re
+
+    def computations(compiled):
+        text = compiled.as_text()
+        text = text[text.index("\n\n", text.index("StackFrames")):]
+        return re.sub(r", metadata=\{[^}]*\}", "", text)
+    return (computations(_compile_fold(fold, shape, where)),
+            computations(_compile_fold(fold, shape, where, entry=False)))
+
+
+@pytest.mark.parametrize("name", ["stack_filter_sum", "width9",
+                                  "predicate-reduces-the-record",
+                                  "tpch_q6-four-chips", "tpch_q1-four-chips"])
+def test_what_the_kernel_does_not_serve_keeps_its_text_on_v5e(v5e_device,
+                                                              name):
+    import jax
+    from jax.experimental import topologies
+    from bolt_tpu.tpu.fold import Fold
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    sums = (("sum", (0,), False, None),)
+    if name == "stack_filter_sum":
+        shape = _STACK
+        fold = Fold(_filter_of((_plus_one,), _corner, _STACK), sums)
+    elif name == "width9":
+        shape = (30_000_001, 9)
+        fold = Fold(_filter_of((), _q6_pred, shape, (_q6_value,), ()), sums)
+    elif name == "predicate-reduces-the-record":
+        shape = (30_000_001, 7)
+        fold = Fold(_filter_of((), _reads_whole_record, shape), sums)
+    else:
+        # GSPMD does not partition a Mosaic kernel: outside shard_map a
+        # program for several chips keeps the fusion
+        shape = (300018952, 7)           # a row more: four equal shares
+        fold = (_q6_fold if "q6" in name else _q1_fold)(shape)
+        topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                            platform="tpu")
+        mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("k",))
+        where = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec("k"))
+    got, want = _kept_texts(fold, shape, where)
+    assert "tpu_custom_call" not in got
+    assert got == want
+    if name == "stack_filter_sum":
+        assert "select_reduce_fusion" in got
+
+
+def test_the_lowering_counts_programs_with_the_fold_kernel(v5e_device):
+    import jax
+    from bolt_tpu import engine
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    c0 = engine.counters()["fold_kernel_programs"]
+    _compile_fold(_q6_fold((300_000, 7)), (300_000, 7), where)
+    assert engine.counters()["fold_kernel_programs"] == c0 + 1
+    _compile_fold(_q1_fold((300_000, 7)), (300_000, 7), where)
+    assert engine.counters()["fold_kernel_programs"] == c0 + 2
+    # a width the kernel does not serve, and the CPU: no kernel, no count
+    _compile_fold(_q6_fold((300_000, 9)), (300_000, 9), where)
+    _compile_fold(_q6_fold((300_000, 7)), (300_000, 7), None)
+    assert engine.counters()["fold_kernel_programs"] == c0 + 2

@@ -142,6 +142,38 @@ def test_q1_is_exact_and_matches_local(mesh, mesh1, which, n):
     assert lcounts.dtype == np.int32 and np.array_equal(lcounts, counts)
 
 
+# enough rows for the fold to bind ``thin_fold`` (ISSUE 31, tpu/fold.py):
+# on this CPU mesh, one device or eight, the primitive lowers to the same
+# expressions as before and places no kernel
+@pytest.mark.parametrize("which", ["one", "eight"])
+def test_thin_records_bind_the_fold_primitive_and_the_cpu_keeps_the_fusion(
+        mesh, mesh1, which):
+    from bolt_tpu.tpu import fold as tf
+    n = 8 * 1024 + 8
+    x = table(n, seed=31)
+    b = bolt.array(x, context=mesh if which == "eight" else mesh1,
+                   axis=(0,))
+    fp = b.filter(q6_pred).map(q6_value)._fpending.geometry()
+    spec = tf.Fold(fp, (("sum", (0,), False, None),))
+    assert "thin_fold" in str(jax.make_jaxpr(
+        lambda data: tf.fold_records(spec, data))(x))
+    c0 = engine.counters()["fold_kernel_programs"]
+    got = b.filter(q6_pred).map(q6_value).sum().toarray()
+    assert close(got, q6_exact(x))
+    sums, counts = run_q1(b)
+    want_sums, want_counts = q1_exact(x)
+    assert close(sums, want_sums)
+    assert counts.dtype == np.int32 and np.array_equal(counts, want_counts)
+    mean = b.filter(q1_pred).mean().toarray()
+    assert close(mean, x[x[:, DATE] <= 2436].mean(axis=0, dtype=np.float64))
+    kept = b.filter(q1_pred)
+    total, top = bolt.compute(kept.sum(), kept.var())     # one fused group
+    rows = x[x[:, DATE] <= 2436].astype(np.float64)
+    assert close(total.toarray(), rows.sum(axis=0))
+    assert np.allclose(top.toarray(), rows.var(axis=0), rtol=1e-3)
+    assert engine.counters()["fold_kernel_programs"] == c0
+
+
 def test_q1_empty_group_group_of_one_and_a_predicate_that_keeps_nothing(
         mesh):
     x = table(515, seed=7)
