@@ -471,7 +471,8 @@ def _note_shuffle(src, stage, aval, split, mesh, idx, diags):
     try:
         plan = _shuffle.plan_shuffle(
             tuple(aval.shape), np.dtype(aval.dtype), split, perm,
-            new_split, mesh, src.slab, _stream.swap_budget(), spill_dir)
+            new_split, mesh, src.slab, _stream.swap_budget(mesh),
+            spill_dir, ring=_stream.swap_ring(src))
     except ValueError as exc:
         diags.append(Diagnostic(
             "BLT017", idx,

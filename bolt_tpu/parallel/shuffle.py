@@ -16,10 +16,15 @@ module plans the two-phase pipeline that closes the gap:
   record axis, concatenate at ``j0``), so each slab costs exactly one
   collective; single-process the transpose plus a sharding constraint
   lets GSPMD insert the local permute.
-* **phase 2 (re-assemble)**: transposed slabs either stay RESIDENT
-  (concatenated along ``j0`` into the swapped array when the output
-  fits the budget) or SPILL to encoded bucket files — ``out_block``
-  new-key records per bucket — which a fresh callback
+* **phase 2 (re-assemble)**: RESIDENT, the swapped array is allocated
+  once (:func:`alloc_program`) and each slab's program writes its
+  transposed block INTO it at the slab's offset along ``j0``
+  (:func:`place_program`: re-bucket and ``dynamic_update_slice`` in one
+  program, the output donated and aliased), so the device holds the
+  output, the ring of uploaded slabs and one program's temp — never
+  the parts beside their concatenation.  Past the budget the blocks
+  SPILL to encoded bucket files — ``out_block`` new-key records per
+  bucket — which a fresh callback
   :class:`~bolt_tpu.stream.StreamSource` then streams through the SAME
   slab-program machinery as any other source (Spark's shuffle-spill
   reincarnated on the donation ring).
@@ -34,7 +39,11 @@ spilled, single-process or pod.
 The planner (:func:`plan_shuffle`) is consulted both by the executor
 (``stream.resolve_swaps``) and abstractly by ``analysis.check`` (the
 BLT017 forecast), so the forecast and the measured decision cannot
-drift: both read the same resident/spill rule off the same budget.
+drift: both read the same resident/spill rule off the same budget
+(``stream.swap_budget``: a ``spill`` scope's, the serving arbiter's,
+else the device's own free memory).  The rule counts what the resident
+programs hold: ``resident_bytes`` = the output + ``ring + 1`` slabs
+(the uploaded slabs in flight and one program's transposed temp).
 """
 
 import numpy as np
@@ -51,17 +60,21 @@ from bolt_tpu.utils import prod
 class ShufflePlan:
     """The static description of one streamed-swap resolution.
 
-    ``resident`` is the phase-2 decision: keep every transposed slab in
-    HBM and concatenate (True), or spill encoded bucket files and
-    re-stream them (False).  ``alltoall_bytes`` is the planner's
+    ``resident`` is the phase-2 decision: assemble the swapped array in
+    place in HBM (True), or spill encoded bucket files and re-stream
+    them (False).  ``resident_bytes`` is what the resident leg holds on
+    the device at its peak (output + ``ring`` uploaded slabs + one
+    program's temp) — the figure compared with ``budget``.
+    ``alltoall_bytes`` is the planner's
     cross-device traffic model: the bytes that must cross device
     boundaries during phase 1 (0 when the record axis stays leading —
     a pure local permute)."""
 
     __slots__ = ("in_shape", "dtype", "split", "perm", "new_split",
                  "out_shape", "j0", "slab", "nslabs", "out_block",
-                 "nbuckets", "total_bytes", "slab_bytes", "budget",
-                 "resident", "spill_dir", "alltoall_bytes", "sharded")
+                 "nbuckets", "total_bytes", "slab_bytes", "ring",
+                 "resident_bytes", "budget", "resident", "spill_dir",
+                 "alltoall_bytes", "sharded")
 
     def __init__(self, **kw):
         for name in self.__slots__:
@@ -76,7 +89,7 @@ class ShufflePlan:
                 "budget %s, %d bucket%s x %d records, all-to-all "
                 "~%.1f MiB)"
                 % (self.nslabs, "s" if self.nslabs != 1 else "", mode,
-                   self.total_bytes / mb,
+                   self.resident_bytes / mb,
                    ("%.1f MiB" % (self.budget / mb))
                    if self.budget is not None else "unbounded",
                    self.nbuckets, "s" if self.nbuckets != 1 else "",
@@ -110,7 +123,7 @@ def _pick_out_block(extent, target_rows, mult):
 
 
 def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
-                 slab, budget, spill_dir):
+                 slab, budget, spill_dir, ring=1):
     """Plan one streamed-swap resolution over the POST-pre-stage
     geometry.
 
@@ -120,7 +133,9 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     exactly as ``tpu/array.py :: _do_swap`` builds them; ``slab`` is
     the input records per slab; ``budget`` the resident ceiling in
     bytes (``None`` = unbounded → always resident); ``spill_dir``
-    where bucket files would land.  Raises the pointed pod-geometry
+    where bucket files would land; ``ring`` the uploaded slabs the
+    executor keeps in flight (``stream.swap_ring``).  Raises the
+    pointed pod-geometry
     errors HERE, before any thread starts, mirroring BLT012."""
     staged_shape = tuple(int(s) for s in staged_shape)
     perm = tuple(int(p) for p in perm)
@@ -168,13 +183,19 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     alltoall_bytes = 0 if perm[0] == 0 or d_in <= 1 else int(
         round(total_bytes * (d_in - 1) / d_in))
 
-    resident = budget is None or total_bytes + slab_bytes <= budget
+    # what the resident leg holds at its peak: the output (allocated
+    # once, every place program aliases it), the ring of uploaded slabs
+    # and ONE place program's temp (the slab's transposed block)
+    ring = max(1, int(ring))
+    resident_bytes = total_bytes + (ring + 1) * slab_bytes
+    resident = budget is None or resident_bytes <= budget
     return ShufflePlan(
         in_shape=staged_shape, dtype=np.dtype(dtype), split=int(split),
         perm=perm, new_split=int(new_split), out_shape=out_shape, j0=j0,
         slab=int(slab), nslabs=int(nslabs), out_block=int(out_block),
         nbuckets=int(nbuckets), total_bytes=int(total_bytes),
-        slab_bytes=int(slab_bytes),
+        slab_bytes=int(slab_bytes), ring=ring,
+        resident_bytes=int(resident_bytes),
         budget=None if budget is None else int(budget),
         resident=bool(resident), spill_dir=spill_dir,
         alltoall_bytes=int(alltoall_bytes), sharded=bool(sharded))
@@ -211,108 +232,192 @@ def _pod_axes_or_refuse(mesh, slab_shape, split, perm, out_slab_shape,
     return axes_in
 
 
-def rebucket_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
-                     raw_slab_shape, delta_ok):
-    """The ONE compiled phase-1 program each input slab runs: fused
-    codec decode (when streaming rode a codec), the pre-swap stage
-    chain, and the swap's transpose — the EXACT expression the
+def _program_key(tag, plan, pre_stages, mesh, codec_obj, raw_dtype,
+                 raw_slab_shape):
+    """Engine key of one per-slab program: (stages, slab geometry,
+    perm, codec, topology) — uniform slabs compile exactly once per
+    variant per process, the short last slab once more."""
+    return (tag, pre_stages, tuple(raw_slab_shape), str(raw_dtype),
+            plan.split, plan.perm, plan.new_split, mesh,
+            _multihost.topology_token() if plan.sharded else None,
+            codec_obj.name if codec_obj is not None else None)
+
+
+def _rebucket_body(plan, pre_stages, mesh, codec_obj, raw_dtype,
+                   raw_slab_shape, delta_ok):
+    """The traced phase-1 expression of ONE slab, ``data -> block``:
+    fused codec decode (when streaming rode a codec), the pre-swap
+    stage chain, and the swap's transpose — the EXACT expression the
     materialised ``swap`` compiles, so parity holds by construction.
 
     ``raw_slab_shape`` is the UPLOADED slab's shape (wire dtype under a
-    codec); the program's output is that slab's transposed block: the
-    full new-key extent with the slab's records at axis ``plan.j0``,
-    constrained to the output key sharding.  On pods the body runs
-    under ``shard_map`` with ONE explicit ``lax.all_to_all`` per slab
+    codec); the result is that slab's transposed block: the full
+    new-key extent with the slab's records at axis ``plan.j0``.  On
+    pods the body runs under
+    ``shard_map`` with ONE explicit ``lax.all_to_all`` per slab
     (``split_axis=0`` of the new layout, ``concat_axis=j0``, tiled) —
-    the TPU-native form of the reference's cluster-wide shuffle.
-    Engine-cached per (stages, slab geometry, perm, codec, topology):
-    uniform slabs compile exactly once per variant per process."""
+    the TPU-native form of the reference's cluster-wide shuffle."""
+    from bolt_tpu.stream import _stage_apply
     split = plan.split
     perm = plan.perm
     j0 = plan.j0
     slab_rows = raw_slab_shape[0]
+
+    def body(data):
+        if codec_obj is None:
+            x = data
+        elif codec_obj.sidecar:
+            x = codec_obj.decode(data[0], data[1:], raw_dtype, delta_ok)
+        else:
+            x = codec_obj.decode(data, (), raw_dtype, delta_ok)
+        for stg in pre_stages:
+            x = _stage_apply(stg, split, x)
+        return jnp.transpose(x, perm)
+
+    if not plan.sharded:
+        return body
+
+    from jax.sharding import PartitionSpec
+    from bolt_tpu import _compat
+    from bolt_tpu.parallel.sharding import key_spec
     out_slab_shape = tuple(
         slab_rows if i == j0 else plan.out_shape[i]
         for i in range(len(plan.out_shape)))
-    key = ("stream-shuffle", pre_stages, tuple(raw_slab_shape),
-           str(raw_dtype), split, perm, plan.new_split, mesh,
-           _multihost.topology_token() if plan.sharded else None,
-           codec_obj.name if codec_obj is not None else None)
+    staged_slab = tuple(
+        slab_rows if i == 0 else plan.in_shape[i]
+        for i in range(len(plan.in_shape)))
+    axes = _pod_axes_or_refuse(mesh, staged_slab, split, perm,
+                               out_slab_shape, plan.new_split)
+
+    def shard_body(data):
+        y = body(data)
+        if axes:
+            # one collective per slab: split the (locally full) new
+            # record axis over the devices that held the old one,
+            # concatenating each device's incoming pieces at j0 —
+            # device order equals global record order, so the glued
+            # global equals the global transpose bit-for-bit
+            for name in axes:
+                y = jax.lax.all_to_all(y, name, split_axis=0,
+                                       concat_axis=j0, tiled=True)
+        return y
+
+    in_specs = key_spec(mesh, staged_slab, split)
+    out_entries = [None] * len(out_slab_shape)
+    out_entries[0] = (axes[0] if len(axes) == 1 else tuple(axes)) \
+        if axes else None
+    if not axes:
+        # record axis stays leading: its sharding is unchanged
+        out_entries[j0] = in_specs[0] if len(in_specs) else None
+    return _compat.shard_map(
+        shard_body, mesh, in_specs=in_specs,
+        out_specs=PartitionSpec(*out_entries), check_vma=False)
+
+
+def rebucket_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
+                     raw_slab_shape, delta_ok):
+    """The ONE compiled phase-1 program each input slab of the SPILL
+    leg runs: :func:`_rebucket_body`, its block constrained to the
+    output key sharding and handed back for the bucket files."""
+    key = _program_key("stream-shuffle", plan, pre_stages, mesh,
+                       codec_obj, raw_dtype, raw_slab_shape)
 
     def build():
-        from bolt_tpu.stream import _stage_apply
         from bolt_tpu.tpu.array import _constrain
+        body = _rebucket_body(plan, pre_stages, mesh, codec_obj,
+                              raw_dtype, raw_slab_shape, delta_ok)
+        if plan.sharded:
+            return jax.jit(body, donate_argnums=(0,))
 
-        def body(data):
-            if codec_obj is None:
-                x = data
-            elif codec_obj.sidecar:
-                x = codec_obj.decode(data[0], data[1:], raw_dtype,
-                                     delta_ok)
-            else:
-                x = codec_obj.decode(data, (), raw_dtype, delta_ok)
-            for stg in pre_stages:
-                x = _stage_apply(stg, split, x)
-            return jnp.transpose(x, perm)
-
-        if not plan.sharded:
-            def run(data):
-                return _constrain(body(data), mesh, plan.new_split)
-            return jax.jit(run, donate_argnums=(0,))
-
-        from jax.sharding import PartitionSpec
-        from bolt_tpu import _compat
-        from bolt_tpu.parallel.sharding import key_spec
-        staged_slab = tuple(
-            slab_rows if i == 0 else plan.in_shape[i]
-            for i in range(len(plan.in_shape)))
-        axes = _pod_axes_or_refuse(mesh, staged_slab, split, perm,
-                                   out_slab_shape, plan.new_split)
-
-        def shard_body(data):
-            y = body(data)
-            if axes:
-                # one collective per slab: split the (locally full) new
-                # record axis over the devices that held the old one,
-                # concatenating each device's incoming pieces at j0 —
-                # device order equals global record order, so the glued
-                # global equals the global transpose bit-for-bit
-                for name in axes:
-                    y = jax.lax.all_to_all(y, name, split_axis=0,
-                                           concat_axis=j0, tiled=True)
-            return y
-
-        in_specs = key_spec(mesh, staged_slab, split)
-        out_entries = [None] * len(out_slab_shape)
-        out_entries[0] = (axes[0] if len(axes) == 1 else tuple(axes)) \
-            if axes else None
-        if not axes:
-            # record axis stays leading: its sharding is unchanged
-            out_entries[j0] = in_specs[0] if len(in_specs) else None
-        body_sm = _compat.shard_map(
-            shard_body, mesh, in_specs=in_specs,
-            out_specs=PartitionSpec(*out_entries), check_vma=False)
-        return jax.jit(body_sm, donate_argnums=(0,))
+        def run(data):
+            return _constrain(body(data), mesh, plan.new_split)
+        return jax.jit(run, donate_argnums=(0,))
 
     return _engine.get(key, build)
 
 
-def concat_program(plan, part_shapes, mesh):
-    """Glue phase-1 transposed slabs into the RESIDENT swapped array:
-    one concatenate along ``j0``, inputs donated (the parts are
-    consumed — at HBM-filling sizes the parts and the result cannot
-    coexist twice), output constrained to the new key sharding."""
-    key = ("stream-shuffle-concat", tuple(part_shapes), str(plan.dtype),
-           plan.j0, plan.new_split, mesh,
+def alloc_program(plan, mesh):
+    """The RESIDENT leg's state, made ONCE a resolution: the swapped
+    array, zero-filled under the new key sharding, and the placement
+    cursor (a uint32 zero).  Every slab's :func:`place_program` is
+    handed both and hands both back: the array aliased, the cursor
+    advanced — so no slab's dispatch carries a host operand up the link
+    the uploader is filling."""
+    key = ("stream-shuffle-alloc", plan.out_shape, str(plan.dtype),
+           plan.new_split, mesh,
            _multihost.topology_token() if plan.sharded else None)
 
     def build():
-        from bolt_tpu.tpu.array import _constrain
-
-        def run(*parts):
-            out = parts[0] if len(parts) == 1 \
-                else jnp.concatenate(parts, axis=plan.j0)
-            return _constrain(out, mesh, plan.new_split)
-        return jax.jit(run, donate_argnums=tuple(range(len(part_shapes))))
+        from jax.sharding import NamedSharding, PartitionSpec
+        from bolt_tpu.parallel.sharding import key_sharding
+        return jax.jit(
+            lambda: (jnp.zeros(plan.out_shape, plan.dtype),
+                     jnp.zeros((), jnp.uint32)),
+            out_shardings=(key_sharding(mesh, plan.out_shape,
+                                        plan.new_split),
+                           NamedSharding(mesh, PartitionSpec())))
 
     return _engine.get(key, build)
+
+
+def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
+                  raw_slab_shape, delta_ok, unit):
+    """The ONE compiled program each input slab of the RESIDENT leg
+    runs, ``(out, data, cursor) -> (out, cursor')``:
+    :func:`_rebucket_body` and the placement of its block into the
+    swapped array at record ``cursor * unit`` along ``j0``, in one
+    program.  ``out`` is donated and the result aliases it (the update
+    is in place: the device never holds the parts beside the whole).
+
+    The offset is an OPERAND, so every uniform slab of every pass runs
+    the same executable, and it is spelled ``cursor * unit`` with
+    ``unit`` static (the records a slab: callback sources cut uniform
+    slabs, so slab ``g`` lands at ``g * unit``; ``1`` for iterator
+    sources, whose blocks are what the iterable yields): XLA then knows
+    the offset's low bits, and where ``unit`` is a whole number of lane
+    tiles on the minor axis the update is an aligned copy — half the
+    device time of the same update at an offset it knows nothing about
+    (PERF.md, PR 32).  Unsigned, because a signed index is first
+    wrapped (``select(i < 0, i + n, i)``), which hides those bits."""
+    unit = int(unit)
+    key = _program_key("stream-shuffle-place", plan, pre_stages, mesh,
+                       codec_obj, raw_dtype, raw_slab_shape) + (unit,)
+
+    def build():
+        from bolt_tpu.tpu.array import _constrain
+        body = _rebucket_body(plan, pre_stages, mesh, codec_obj,
+                              raw_dtype, raw_slab_shape, delta_ok)
+        j0 = plan.j0
+        step = -(-int(raw_slab_shape[0]) // unit)
+
+        def run(out, data, cursor):
+            zero = jnp.zeros((), jnp.uint32)
+            at = tuple(cursor * jnp.uint32(unit) if i == j0 else zero
+                       for i in range(out.ndim))
+            out = jax.lax.dynamic_update_slice(out, body(data), at)
+            return (_constrain(out, mesh, plan.new_split),
+                    cursor + jnp.uint32(step))
+        return jax.jit(run, donate_argnums=(0, 1))
+
+    return _engine.get(key, build)
+
+
+LANES = 128          # the minor-axis tile of a TPU array, in elements
+
+
+def lane_slab(slab, records, record_bytes, perm, ceiling):
+    """Records a slab for a streamed swap whose caller chose none: where
+    the old record axis lands MINOR (``perm`` ends in 0) a slab's block
+    is ``slab`` elements wide on the lane axis, and one that is not a
+    whole number of lane tiles is padded to one in every temp and
+    written with masks — so ``slab`` is rounded UP to whole tiles,
+    unless that many records pass ``ceiling`` bytes (records so fat
+    that a tile of them is no slab any more).  On the chip, 512 x 512
+    float32 frames: 128 a slab re-axed 9 % more bytes a second than the
+    default 64, at half the device time (PERF.md, PR 32)."""
+    if perm[-1] != 0 or slab % LANES == 0:
+        return slab
+    whole = -(-slab // LANES) * LANES
+    if whole * record_bytes > ceiling:
+        return slab
+    return min(whole, max(int(records), 1))

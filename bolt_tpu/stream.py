@@ -413,8 +413,10 @@ def spill(dir=None, budget=None):
 
     ``dir`` is where spilled bucket files land (``None`` keeps the
     ``BOLT_STREAM_SPILL_DIR`` default); ``budget`` caps the RESIDENT
-    working set in bytes (``None`` defers to the serving arbiter's
-    budget, else unbounded).  THREAD-LOCAL with the same stack
+    working set in bytes: the swapped array plus the slabs in flight
+    (``None`` defers to the serving arbiter's budget, else to the
+    device's own free memory: :func:`swap_budget`).  THREAD-LOCAL with
+    the same stack
     discipline as :func:`codec`/:func:`resumable`: one serve tenant's
     spill policy must not redirect a neighbour's bucket files."""
     st = _scope_stack("spill")
@@ -437,23 +439,53 @@ def spill_scope():
     return _SPILL_DIR, None
 
 
-def swap_budget():
+def _device_headroom(mesh=None):
+    """Free device memory the platform reports for ``mesh`` (default:
+    this process's devices), in bytes over the WHOLE mesh: the tightest
+    addressable device's ``bytes_limit - bytes_in_use`` times the
+    device count (key sharding spreads a resident array evenly).
+    ``None`` where the platform reports no limit (the CPU backend)."""
+    devs = list(mesh.devices.flat) if mesh is not None \
+        else jax.local_devices()
+    free = []
+    for d in devs:
+        if d.process_index != _multihost.process_index():
+            continue
+        stats = d.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return None
+        free.append(int(stats["bytes_limit"])
+                    - int(stats.get("bytes_in_use", 0)))
+    if not free:
+        return None
+    return max(0, min(free)) * len(devs)
+
+
+def swap_budget(mesh=None):
     """The resident-working-set ceiling a streamed-swap resolution
     plans against: the innermost :func:`spill` scope's explicit
     ``budget``, else the ACTIVE serving arbiter's device budget, else
-    ``None`` (unbounded — always resident).  The checker's BLT017
-    forecast calls this same function, so the forecast and the
-    measured resident/spill decision cannot drift."""
+    what the device itself has free (:func:`_device_headroom` over
+    ``mesh``), else ``None`` (a platform that reports no limit:
+    unbounded — always resident).  The checker's BLT017 forecast calls
+    this same function, so the forecast and the measured resident/spill
+    decision cannot drift."""
     _, b = spill_scope()
     if b is not None:
         return b
     sv = sys.modules.get("bolt_tpu.serve")
-    if sv is None:
-        return None
-    arb = sv.device_arbiter()
-    if arb is None:
-        return None
-    return int(arb.budget)
+    arb = sv.device_arbiter() if sv is not None else None
+    if arb is not None:
+        return int(arb.budget)
+    return _device_headroom(mesh)
+
+
+def swap_ring(source):
+    """Uploaded slabs a streamed-swap resolution over ``source`` keeps
+    in flight: the prefetch depth plus the uploader pool — the ring the
+    resolver's permits bound, and what ``plan_shuffle`` counts beside
+    the output."""
+    return prefetch_depth() + pool_size(source)
 
 
 def pool_size(source):
@@ -628,11 +660,11 @@ class StreamSource:
     fold without ever materialising a compaction buffer."""
 
     __slots__ = ("kind", "produce", "blocks", "shape", "split", "dtype",
-                 "mesh", "slab", "stages", "ckpt", "codec", "_state",
-                 "_consumed")
+                 "mesh", "slab", "stages", "ckpt", "codec", "auto_slab",
+                 "_state", "_consumed")
 
     def __init__(self, kind, produce, blocks, shape, split, dtype, mesh,
-                 slab, stages=(), ckpt=None, codec=None):
+                 slab, stages=(), ckpt=None, codec=None, auto_slab=False):
         self.kind = kind
         self.produce = produce          # callback: fn(index_slices)
         self.blocks = blocks            # iter: the iterable of blocks
@@ -645,6 +677,9 @@ class StreamSource:
         self.ckpt = ckpt                # resumable checkpoint dir (or None)
         self.codec = codec              # ingest codec NAME (or None);
         #                                 wins over the codec() scope
+        self.auto_slab = bool(auto_slab)  # the caller gave no `chunks`:
+        #                                 the slab is the default rule's
+        #                                 and a swap may re-draw it
         self._state = None
         # iter sources stream ONCE per iter() of a one-shot iterable (a
         # generator cannot rewind); the cell is SHARED across derived
@@ -664,7 +699,7 @@ class StreamSource:
             _codec_registry().get(codec)
         slab = _slab_records(shape, dtype, chunks)
         return cls("callback", fn, None, shape, split, dtype, mesh, slab,
-                   ckpt=checkpoint, codec=codec)
+                   ckpt=checkpoint, codec=codec, auto_slab=chunks is None)
 
     @classmethod
     def from_iter(cls, blocks, shape, split, dtype, mesh,
@@ -677,12 +712,14 @@ class StreamSource:
         return cls("iter", None, blocks, shape, split, dtype, mesh, slab,
                    ckpt=checkpoint, codec=codec)
 
-    def with_stage(self, stage):
-        """A new source sharing the host side, one device stage longer."""
+    def with_stage(self, stage, slab=None):
+        """A new source sharing the host side, one device stage longer
+        (``slab``: re-drawn records a slab, for a swap stage)."""
         out = StreamSource(self.kind, self.produce, self.blocks,
                            self.shape, self.split, self.dtype, self.mesh,
-                           self.slab, self.stages + (stage,),
-                           ckpt=self.ckpt, codec=self.codec)
+                           self.slab if slab is None else slab,
+                           self.stages + (stage,), ckpt=self.ckpt,
+                           codec=self.codec, auto_slab=self.auto_slab)
         out._consumed = self._consumed      # same iterator, same budget
         return out
 
@@ -966,7 +1003,12 @@ def swap_stage(arr, perm, new_split):
     cannot stream: a dynamic (post-filter) row count, a lossy ingest
     codec (phase 1 decodes once; a later terminal would quantise
     AGAIN, drifting from the materialised path), or a pod iterator
-    source (per-process bucket ownership needs random access)."""
+    source (per-process bucket ownership needs random access).
+
+    Where the caller gave no ``chunks`` and the record axis lands minor,
+    the slab is re-drawn to whole lane tiles
+    (``parallel.shuffle.lane_slab``: a rule, no knob)."""
+    from bolt_tpu.parallel.shuffle import lane_slab
     from bolt_tpu.tpu.array import BoltArrayTPU
     src = arr._stream
     st = result_state(src)
@@ -978,10 +1020,14 @@ def swap_stage(arr, perm, new_split):
     if _multihost.mesh_process_count(src.mesh) > 1 \
             and src.kind != "callback":
         return NotImplemented
-    out = BoltArrayTPU._streamed(
+    slab = None
+    if src.auto_slab and src.kind == "callback" and not has_swap(src):
+        slab = lane_slab(src.slab, src.shape[0],
+                         prod(src.shape[1:]) * src.dtype.itemsize, perm,
+                         2 * _SLAB_BYTES)
+    return BoltArrayTPU._streamed(
         src.with_stage(("swap", tuple(int(p) for p in perm),
-                        int(new_split))))
-    return out
+                        int(new_split)), slab=slab))
 
 
 def has_swap(source):
@@ -2687,12 +2733,15 @@ def _resolve_one_swap(source):
     streaming shuffle (module docstring of
     ``bolt_tpu.parallel.shuffle``): phase 1 streams input slabs through
     the uploader pool and one re-bucket program each (all-to-all on
-    pods), phase 2 either concatenates RESIDENT parts into the swapped
-    array (post-swap stages replayed concretely) or returns a fresh
-    stream source over SPILLED bucket files carrying the post-swap
-    stages lazily.  Bit-identical to the materialised swap either way —
-    the re-bucket program traces the same transpose and the same stage
-    bodies."""
+    pods), phase 2 either happens IN PLACE (resident: the swapped array
+    is allocated once and each slab's program writes its block into it,
+    post-swap stages replayed concretely) or returns a fresh stream
+    source over SPILLED bucket files carrying the post-swap stages
+    lazily.  Bit-identical to the materialised swap either way — the
+    per-slab program traces the same transpose and the same stage
+    bodies.  Phase 1 is a streamed run like any other: its wall, ingest
+    and overlap seconds land in the ``stream_*`` counters
+    (``engine.record_stream``) beside ``shuffle_bytes/_seconds``."""
     from bolt_tpu import checkpoint as _ckptlib
     from bolt_tpu.parallel import shuffle as _shuffle
     from bolt_tpu.tpu.array import BoltArrayTPU
@@ -2714,7 +2763,8 @@ def _resolve_one_swap(source):
     spill_dir, _ = spill_scope()
     plan = _shuffle.plan_shuffle(st.shape, st.dtype, st.split, perm,
                                  new_split, mesh, base.slab,
-                                 swap_budget(), spill_dir)
+                                 swap_budget(mesh), spill_dir,
+                                 ring=swap_ring(base))
     if not plan.resident and spill_dir is None:
         raise RuntimeError(
             "streamed swap: the re-keyed working set (%.1f MiB) "
@@ -2722,7 +2772,7 @@ def _resolve_one_swap(source):
             "directory is configured — wrap the run in "
             "bolt_tpu.stream.spill(dir) (or raise the budget); "
             "analysis.check forecasts this as BLT017"
-            % (plan.total_bytes / 2**20, (plan.budget or 0) / 2**20))
+            % (plan.resident_bytes / 2**20, (plan.budget or 0) / 2**20))
 
     codec_obj = resolve_codec(base)     # lossless or None (gated at
     delta_ok = split < len(source.shape)  # swap_stage record time)
@@ -2753,7 +2803,8 @@ def _resolve_one_swap(source):
                 "mesh raise the arbiter budget so the buckets stay "
                 "resident, or materialise first (toarray) and swap "
                 "in memory; analysis.check forecasts this as BLT017"
-                % (plan.total_bytes / 2**20, (plan.budget or 0) / 2**20))
+                % (plan.resident_bytes / 2**20,
+                   (plan.budget or 0) / 2**20))
 
     # spill-manifest resume (fingerprinted like stream checkpoints):
     # slabs whose every bucket landed are skipped — their files are
@@ -2780,12 +2831,13 @@ def _resolve_one_swap(source):
                  if codec_obj is not None else source.dtype.itemsize)
     tenant_tag = _engine.current_tenant()
     lease = _tenant_lease()
-    ring = depth + nwork
+    ring = plan.ring                    # == depth + nwork (swap_ring)
     permits = threading.Semaphore(ring)
     stop = threading.Event()
     rsq = _Reseq()
     jobq = queue.Queue()
     run_sp = _obs.begin("stream.shuffle", resident=plan.resident,
+                        inplace=plan.resident, ring=ring,
                         slabs=plan.nslabs, buckets=plan.nbuckets,
                         out_block=plan.out_block,
                         alltoall_bytes=plan.alltoall_bytes)
@@ -2974,11 +3026,21 @@ def _resolve_one_swap(source):
 
     t_start = _clock()
     moved = 0
-    parts = []
-    pshapes = []
+    placed = 0
+    ingest = compute = 0.0
+    pod = mspec is not None
+    out = cursor = None
+    if plan.resident:
+        # phase 2 in place: the swapped array exists ONCE, from here
+        # on; every slab's program is handed it (donated) and hands it
+        # back with its block written at the cursor, which counts
+        # slabs for a callback source (uniform: slab g starts at
+        # g * slab) and records for an iterator's own blocks
+        out, cursor = _shuffle.alloc_program(plan, mesh)()
+        unit = base.slab if base.kind == "callback" else 1
     for th in threads:
         th.start()
-    if mspec is not None:
+    if pod:
         _podwatch.pod_enter()
     ready_done = False
     try:
@@ -2986,10 +3048,12 @@ def _resolve_one_swap(source):
             got = rsq.next(threads, workers=ingesters)
             if got is None:
                 break
-            if mspec is not None and not ready_done:
+            if pod and not ready_done:
                 _podwatch.ready_rendezvous()
                 ready_done = True
             j, (g, buf, bnb, tsec) = got
+            ingest += tsec
+            t0 = _clock()
             wshape = (buf[0].shape if isinstance(buf, tuple)
                       else buf.shape)
             csp = _obs.begin("stream.compute", slab=g, shuffle=True)
@@ -2999,23 +3063,35 @@ def _resolve_one_swap(source):
                 while True:
                     try:
                         # the chaos seam fires BEFORE the dispatch, so
-                        # an injected raise leaves the donated buffer
+                        # an injected raise leaves the donated buffers
                         # intact — the in-place retry (same fence as
-                        # ingest retries) re-dispatches it verbatim
+                        # ingest retries) re-dispatches them verbatim
                         _chaos.hit("stream.shuffle")
-                        prog = _shuffle.rebucket_program(
-                            plan, pre, mesh, codec_obj, source.dtype,
-                            wshape, delta_ok)
+                        if plan.resident:
+                            prog = _shuffle.place_program(
+                                plan, pre, mesh, codec_obj, source.dtype,
+                                wshape, delta_ok, unit)
+                        else:
+                            prog = _shuffle.rebucket_program(
+                                plan, pre, mesh, codec_obj, source.dtype,
+                                wshape, delta_ok)
                         with warnings.catch_warnings():
-                            # CPU dev meshes have no donation: the
+                            # the uploaded slab is donated but never
+                            # aliased (no output has its shape; CPU dev
+                            # meshes have no donation at all): the
                             # per-slab "donated buffers were not
-                            # usable" warning is expected noise there
+                            # usable" warning is expected noise
                             warnings.filterwarnings(
                                 "ignore", message="Some donated "
                                 "buffers were not usable")
-                            part = prog(buf)
-                        _pod_sync(part, mspec is not None,
-                                  "shuffle re-bucket", slab=g)
+                            if plan.resident:
+                                # slabs arrive re-sequenced, in key
+                                # order, and a resident run skips none
+                                out, cursor = prog(out, buf, cursor)
+                                part = out
+                            else:
+                                part = prog(buf)
+                        _pod_sync(part, pod, "shuffle re-bucket", slab=g)
                         break
                     except _podwatch.PeerLostError:
                         raise
@@ -3026,14 +3102,12 @@ def _resolve_one_swap(source):
             finally:
                 _obs.end(csp)
             del buf, got
-            moved += int(prod(part.shape)
-                         * np.dtype(part.dtype).itemsize)
-            if plan.resident:
-                parts.append((g, part))
-                pshapes.append(tuple(part.shape))
-            else:
+            moved += wshape[0] * (plan.total_bytes // plan.in_shape[0])
+            placed += 1
+            if not plan.resident:
                 _spill_part(part, g)
-                del part
+            del part
+            compute += _clock() - t0
             permits.release()
             if lease is not None:
                 lease.release(bnb)
@@ -3044,32 +3118,31 @@ def _resolve_one_swap(source):
         for th in threads:
             th.join()
         rsq.drain()
-        if mspec is not None:
+        if pod:
             _podwatch.pod_exit()
         if lease is not None:
             lease.close()
-        _engine.record_shuffle(moved, _clock() - t_start)
+        wall = _clock() - t_start
+        _engine.record_shuffle(moved, wall)
         if run_sp is not None:
             run_sp.set(bytes=moved)
         _obs.end(run_sp)
+    # phase 1 completed: one streamed run, under the counters every
+    # streamed run reports (a spilled swap's phase 2 adds its own)
+    _engine.record_stream(placed, ingest, compute, wall,
+                          max(0.0, ingest + compute - wall), depth,
+                          uploaders=len(ingesters))
 
     if plan.resident:
-        if not parts:
+        if not placed:
             raise RuntimeError(
                 "streamed swap produced no slabs (empty source?) — "
                 "the materialised path owns empty-input rules")
-        # slab order was re-sequenced, but `done`-skips never happen
-        # resident (no manifest) — parts arrive in slab order already
-        parts = [p for _, p in sorted(parts, key=lambda t: t[0])]
-        prog = _shuffle.concat_program(plan, tuple(pshapes), mesh)
-        with warnings.catch_warnings():
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            data = prog(*parts)
-        _pod_sync(data, mspec is not None, "shuffle concat")
-        del parts
-        b = BoltArrayTPU(data, new_split, mesh)
-        return _replay_stages(b, post)
+        with _obs.span("stream.handover", bytes=plan.total_bytes,
+                       stages=len(post)):
+            _pod_sync(out, pod, "shuffle place")
+            b = BoltArrayTPU(out, new_split, mesh)
+            return _replay_stages(b, post)
 
     # SPILLED: phase 2 is a fresh callback source over the bucket
     # files — it streams through the SAME slab-program machinery as

@@ -1070,3 +1070,59 @@ def test_the_lowering_counts_programs_with_the_fold_kernel(v5e_device):
     _compile_fold(_q6_fold((300_000, 9)), (300_000, 9), where)
     _compile_fold(_q6_fold((300_000, 7)), (300_000, 7), None)
     assert engine.counters()["fold_kernel_programs"] == c0 + 2
+
+
+# ---------------------------------------------------------------------
+# compile-only: the streamed swap's place program at the two-photon
+# session's size (ISSUE 32).  The swapped array is the program's own
+# argument handed back: aliased, with a temp of one transposed slab —
+# where the parts and their concatenation were 2-4 x the output
+# ---------------------------------------------------------------------
+
+_SESSION = (10240, 512, 512)          # frames x rows x columns
+
+
+@pytest.mark.parametrize("frames", [64, 128],
+                         ids=["half-a-lane-tile", "whole-lane-tiles"])
+def test_place_program_aliases_its_output_on_v5e(v5e_device, frames):
+    import warnings
+    import jax
+    from bolt_tpu.parallel import shuffle
+    mesh = _series_mesh(v5e_device)
+    plan = shuffle.plan_shuffle(_SESSION, np.float32, 1, (1, 2, 0), 2,
+                                mesh, frames, None, None, ring=3)
+    assert plan.out_shape == (512, 512, 10240) and plan.j0 == 2
+    slab_shape = (frames,) + _SESSION[1:]
+    program = shuffle.place_program(plan, (), mesh, None, np.float32,
+                                    slab_shape, True, frames)
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not")
+        compiled = program.lower(
+            jax.ShapeDtypeStruct(plan.out_shape, _F32, sharding=where),
+            jax.ShapeDtypeStruct(slab_shape, _F32, sharding=where),
+            jax.ShapeDtypeStruct((), np.uint32, sharding=where)).compile()
+    mem = compiled.memory_analysis()
+    # the compiler knows the offset is a multiple of the slab: the low
+    # bits of the minor-axis index are known zero (cursor * frames)
+    assert '"zeroes":"%d"' % (frames - 1) in compiled.as_text()
+    out_bytes = 4 * int(np.prod(_SESSION))
+    slab_bytes = 4 * int(np.prod(slab_shape))
+    # the array and, beside it, the cursor (a scalar and the tuple)
+    assert out_bytes <= mem.output_size_in_bytes < out_bytes + 4096
+    assert out_bytes <= mem.alias_size_in_bytes          # in place
+    # a slab shorter than a lane tile is padded to one in the temp
+    assert mem.temp_size_in_bytes <= max(slab_bytes, 128 * 2**20) * 1.01
+    assert mem.temp_size_in_bytes < 2 * 128 * 2**20
+    # what the plan says the resident leg holds covers what it does hold:
+    # the output, the ring, and this temp
+    assert plan.resident_bytes >= (out_bytes + 3 * slab_bytes
+                                   + min(mem.temp_size_in_bytes, slab_bytes))
+    assert plan.resident_bytes < 16.9e9
+    # and the session twice as long is refused by the same rule on a chip
+    # that has 16.9 GB
+    double = shuffle.plan_shuffle((20480, 512, 512), np.float32, 1,
+                                  (1, 2, 0), 2, mesh, frames,
+                                  int(16.9e9), None, ring=3)
+    assert not double.resident

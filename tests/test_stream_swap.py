@@ -2,9 +2,10 @@
 
 Parity is the load-bearing half: a ``swap`` recorded on a STREAMED
 source resolves through the two-phase shuffle — phase 1 re-buckets each
-uploaded slab on device, phase 2 concatenates resident buckets or
-re-streams spilled ones — and must equal the materialise-first in-memory
-swap BIT for bit (a transpose moves bytes, it never rounds).  Geometry
+uploaded slab on device, phase 2 writes each block INTO the one resident
+output (ISSUE 32) or re-streams spilled ones — and must equal the
+materialise-first in-memory swap BIT for bit (a transpose moves bytes,
+it never rounds).  Geometry
 edges ride along: uneven last slabs, 1-record slabs, multi-value-axis
 permutations, the key↔value round trip, and the budget≈one-bucket
 forced-spill path.
@@ -132,6 +133,252 @@ def test_lossy_codec_swap_falls_back_to_materialise(mesh):
     assert s._stream is None              # materialised at record time
     got = np.asarray(s._data)
     assert got.shape == (V0, N, V1)
+
+
+# ---------------------------------------------------------------------
+# phase 2 in place (ISSUE 32): the swapped array is allocated once and
+# every slab's program writes its block into it at the slab's offset
+# ---------------------------------------------------------------------
+
+def _double(v):
+    return v * 2.0
+
+
+def _case_j0_last(mesh):
+    data = _data()
+    return (_source(data, mesh, 4).swap((0,), (0, 1)),
+            np.transpose(data, (1, 2, 0)))
+
+
+def _case_j0_middle(mesh):
+    data = _data()
+    return (_source(data, mesh, 4).swap((0,), (0,)),
+            np.transpose(data, (1, 0, 2)))
+
+
+def _case_j0_first(mesh):
+    # two key axes, the SECOND swapped out: the record axis stays
+    # leading (perm (0, 2, 1)), so each block lands at offset lo of axis 0
+    data = _data()
+    src = bolt.fromcallback(lambda idx: data[idx], data.shape, mesh,
+                            axis=(0, 1), dtype=data.dtype, chunks=4)
+    return src.swap((1,), (0,)), np.transpose(data, (0, 2, 1))
+
+
+def _case_short_last_slab(mesh):
+    data = _data()                        # 24 records = 4 x 5 + 4
+    return (_source(data, mesh, 5).swap((0,), (0, 1)),
+            np.transpose(data, (1, 2, 0)))
+
+
+def _case_map_before(mesh):
+    data = _data()
+    return (_source(data, mesh, 4).map(_double).swap((0,), (0, 1)),
+            np.transpose(data * 2.0, (1, 2, 0)).astype(np.float32))
+
+
+def _case_map_and_stat_after(mesh):
+    data = np.round(_data())              # integer-valued: sums exact
+    got = _source(data, mesh, 4).swap((0,), (0,)).map(_double).sum()
+    return got, (np.transpose(data, (1, 0, 2)) * 2.0).sum(axis=0)
+
+
+def _case_dict_codec(mesh):
+    data = _data(np.int32)
+    return (_source(data, mesh, 4, codec="dict").swap((0,), (0, 1)),
+            np.transpose(data, (1, 2, 0)))
+
+
+_INPLACE_CASES = {
+    "j0-last": _case_j0_last, "j0-middle": _case_j0_middle,
+    "j0-first": _case_j0_first, "short-last-slab": _case_short_last_slab,
+    "map-before": _case_map_before,
+    "map-and-stat-after": _case_map_and_stat_after,
+    "dict-codec": _case_dict_codec,
+}
+
+
+@pytest.mark.parametrize("case", sorted(_INPLACE_CASES))
+def test_inplace_assembly_equals_numpy_transpose(mesh, case):
+    """Bit for bit, with the output assembled by the place programs: no
+    list of parts, no concatenate (``concat_program`` is gone), nothing
+    spilled, and phase 1 counted as the streamed run it is."""
+    from bolt_tpu.parallel import shuffle
+    assert not hasattr(shuffle, "concat_program")
+    obs.clear()
+    obs.enable()
+    c0 = engine.counters()
+    try:
+        arr, want = _INPLACE_CASES[case](mesh)
+        got = np.asarray(arr._data if hasattr(arr, "_data") else arr)
+        runs = [sp for sp in obs.spans() if sp.name == "stream.shuffle"]
+        hand = [sp for sp in obs.spans() if sp.name == "stream.handover"]
+        slabs = [sp for sp in obs.spans() if sp.name == "stream.compute"]
+        assert obs.active_count() == 0
+    finally:
+        obs.disable()
+        obs.clear()
+    c1 = engine.counters()
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    nslabs = 5 if case == "short-last-slab" else 6
+    assert len(runs) == 1 and len(hand) == 1 and len(slabs) == nslabs
+    assert runs[0].attrs["inplace"] is True and runs[0].attrs["resident"]
+    assert all(sp.attrs["shuffle"] is True for sp in slabs)
+    assert c1["spill_bytes"] == c0["spill_bytes"]
+    assert c1["shuffle_bytes"] - c0["shuffle_bytes"] == _data().nbytes
+    assert c1["stream_chunks"] - c0["stream_chunks"] == nslabs
+    assert c1["stream_wall_seconds"] > c0["stream_wall_seconds"]
+    assert c1["stream_ingest_seconds"] > c0["stream_ingest_seconds"]
+
+
+def test_uniform_slabs_share_one_place_program(mesh):
+    """The slab's place is an OPERAND (a cursor carried on the device):
+    six slabs, one executable; the short last slab is one more; a second
+    pass compiles nothing and no slab's dispatch carries a host value."""
+    data = _data()
+
+    def run(chunks):
+        c0 = engine.counters()
+        np.asarray(_source(data, mesh, chunks).swap((0,), (0, 1))._data)
+        c1 = engine.counters()
+        return c1["aot_compiles"] - c0["aot_compiles"]
+
+    engine.clear()
+    assert run(4) == 2                    # the allocation and the place
+    assert run(4) == 0
+    assert run(5) == 2                    # uniform (5) and the short (4)
+    assert run(5) == 0
+
+
+def test_iterator_blocks_land_at_their_own_offsets(mesh):
+    """An iterator's blocks are what it yields: the cursor counts records
+    there (unit 1), so uneven blocks still land where they belong."""
+    data = _data()
+    blocks = [data[0:7], data[7:9], data[9:20], data[20:24]]
+    s = bolt.fromiter(blocks, SHAPE, mesh, dtype=np.float32)
+    got = np.asarray(s.swap((0,), (0, 1))._data)
+    assert np.array_equal(got, np.transpose(data, (1, 2, 0)))
+
+
+@pytest.mark.parametrize("case,default,chunks,kv,want_slab", [
+    ("record-axis-minor", 64, None, ((0,), (0, 1)), 128),
+    ("already-whole-tiles", 256, None, ((0,), (0, 1)), 256),
+    ("rounds-up-not-down", 200, None, ((0,), (0, 1)), 256),
+    ("record-axis-in-the-middle", 64, None, ((0,), (0,)), 64),
+    ("the-caller-chose", 64, 64, ((0,), (0, 1)), 64),
+    ("records-too-fat-for-a-tile", 20, None, ((0,), (0, 1)), 20),
+])
+def test_default_slab_is_whole_lane_tiles_where_records_land_minor(
+        mesh, monkeypatch, case, default, chunks, kv, want_slab):
+    """A rule, no knob: with no ``chunks`` given, a swap whose record axis
+    lands minor re-draws the slab to whole lane tiles (128 records), unless
+    a tile of records is over twice the default slab's bytes."""
+    shape = (300, 8, 8)
+    data = (np.arange(np.prod(shape)) % 977).astype(np.float32).reshape(
+        shape)
+    monkeypatch.setattr(stream, "_SLAB_BYTES", default * 8 * 8 * 4)
+    src = bolt.fromcallback(lambda idx: data[idx], shape, mesh,
+                            dtype=np.float32, chunks=chunks)
+    assert src._stream.slab == (chunks or default)
+    s = src.swap(*kv)
+    assert s._stream.slab == want_slab, case
+    assert src._stream.slab == (chunks or default)   # the source keeps its
+    perm = (1, 2, 0) if kv[1] == (0, 1) else (1, 0, 2)
+    assert np.array_equal(np.asarray(s._data), np.transpose(data, perm))
+
+
+# ---------------------------------------------------------------------
+# the plan: true of the program, and aware of the device (ISSUE 32)
+# ---------------------------------------------------------------------
+
+def test_resident_rule_counts_what_the_program_holds(mesh):
+    """output + (ring + 1) slabs: the ring of uploaded slabs and one place
+    program's temp; the planner, BLT017 and the resolver read one rule."""
+    from bolt_tpu.parallel import shuffle
+    data = _data()
+    src = _source(data, mesh, 4)._stream
+    ring = stream.swap_ring(src)
+    assert ring == stream.prefetch_depth() + stream.pool_size(src)
+    slab_bytes = 4 * V0 * V1 * 4
+    holds = data.nbytes + (ring + 1) * slab_bytes
+
+    def plan(budget):
+        return shuffle.plan_shuffle(SHAPE, np.float32, 1, (1, 2, 0), 2,
+                                    mesh, 4, budget, None, ring=ring)
+    assert plan(None).resident and plan(None).resident_bytes == holds
+    assert plan(holds).resident and not plan(holds - 1).resident
+    # the old rule (output + one slab) would have said yes here
+    assert data.nbytes + slab_bytes <= holds - 1
+    # a deeper ring holds more
+    with stream.prefetch(5):
+        assert stream.swap_ring(src) == 5 + stream.pool_size(src)
+    # BLT017 and the run agree at the edge, both ways
+    for budget, resident in ((holds, True), (holds - 1, False)):
+        with stream.spill(budget=budget):
+            s = _source(data, mesh, 4).swap((0,), (0, 1))
+            d, = [d for d in analysis.check(s).diagnostics
+                  if d.code == "BLT017"]
+            assert ("resident" in d.message) is resident, d.message
+            assert (d.severity == "info") is resident
+            if resident:
+                assert np.array_equal(np.asarray(s._data),
+                                      np.transpose(data, (1, 2, 0)))
+            else:
+                with pytest.raises(RuntimeError, match="BLT017"):
+                    s._data
+
+
+def test_no_scope_plans_against_the_devices_own_limit(mesh, monkeypatch):
+    """With no spill scope and no arbiter the budget is what the device
+    has free: a swap that cannot fit and has nowhere to spill refuses in
+    BLT017's words BEFORE a thread starts or the loader is called."""
+    import threading
+    data = _data()
+    calls = []
+
+    def loader(idx):
+        calls.append(idx)
+        return data[idx]
+
+    assert stream.swap_budget(mesh) is None   # the CPU reports no limit
+    monkeypatch.setattr(stream, "_device_headroom",
+                        lambda mesh=None: data.nbytes)  # output fits,
+    assert stream.swap_budget(mesh) == data.nbytes      # ring does not
+    s = bolt.fromcallback(loader, SHAPE, mesh, dtype=np.float32,
+                          chunks=4).swap((0,), (0, 1))
+    d, = [d for d in analysis.check(s).diagnostics if d.code == "BLT017"]
+    assert d.severity == "warning" and "NO spill directory" in d.message
+    before = threading.active_count()
+    with pytest.raises(RuntimeError, match="no spill directory"):
+        s._data
+    assert calls == [] and threading.active_count() == before
+    # an explicit scope still wins over the device, and room is room
+    with stream.spill(budget=1 << 30):
+        assert stream.swap_budget(mesh) == 1 << 30
+    monkeypatch.setattr(stream, "_device_headroom",
+                        lambda mesh=None: 1 << 30)
+    assert np.array_equal(np.asarray(s._data),
+                          np.transpose(data, (1, 2, 0)))
+
+
+def test_device_headroom_reads_the_tightest_device(monkeypatch):
+    class Dev:
+        process_index = 0
+
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    import jax
+    monkeypatch.setattr(jax, "process_index", lambda: 0)
+    monkeypatch.setattr(jax, "local_devices", lambda: [
+        Dev({"bytes_limit": 100, "bytes_in_use": 30}),
+        Dev({"bytes_limit": 100, "bytes_in_use": 45})])
+    assert stream._device_headroom() == 2 * 55
+    monkeypatch.setattr(jax, "local_devices", lambda: [Dev(None)])
+    assert stream._device_headroom() is None
 
 
 # ---------------------------------------------------------------------
