@@ -160,6 +160,10 @@ _SCHEMA = {
                                   # executor is chosen at lowering, so
                                   # programs can be counted, not calls;
                                   # 0 on the CPU)
+    "gram_sums_programs": 0,      # those of them whose kernel also hands
+                                  # back its blocks' row sums: a centred
+                                  # pca or cov that takes its mean from
+                                  # the Gram pass (0 on the CPU)
     "fold_kernel_programs": 0,    # programs LOWERED with tpu/fold.py's
                                   # thin_fold kernel in them: a filter
                                   # (or a map chain) folded into a
@@ -524,12 +528,18 @@ def record_filter_compaction():
     _COUNTERS.add("filter_compactions")
 
 
-def record_gram_kernel_program():
+def record_gram_kernel_program(sums=False):
     """One program was lowered with the ``packed_gram`` Mosaic kernel in
     it (``ops/linalg.py :: _gram_primitive``): a program for one TPU
-    device with a Gram matrix of real float32 that packs.  Per call the
-    record is the device trace (``packed_gram*`` events)."""
+    device with a Gram matrix of real float32 that packs; with ``sums``
+    in the form that also returns the per-feature sums (a centring
+    caller's mean, from the same pass), counted under
+    ``gram_sums_programs`` too.  Per call the record is the device trace
+    (``packed_gram*`` events; the summing form's are
+    ``packed_gram_sums*``)."""
     _COUNTERS.add("gram_kernel_programs")
+    if sums:
+        _COUNTERS.add("gram_sums_programs")
 
 
 def record_fold_kernel_program():

@@ -682,7 +682,7 @@ def _gram(x, xp, precision="highest", widened=False):
         xt = np.swapaxes(x, -1, -2)
         return np.matmul(np.conj(xt) if np.iscomplexobj(x) else xt, x)
     if not widened and _kernel_serves(x, precision):
-        return _gram_entry(precision, False)(x)
+        return _gram_entry(precision, False)(x)[0]
     return _products(jnp.conj(x), x, precision)
 
 
@@ -709,10 +709,11 @@ def _products(u, v, precision):
 _GRAM_RUN = 1 << 14
 
 
-def _run_gram(a, b, precision, widened=False):
+def _run_gram(a, b, precision, widened=False, sums=False):
     """``sum over the sample axes of a[.., i] * b[.., j]`` for operands
     shaped ``samples + (d,)`` (every axis but the last contracted):
-    ``(d, d)``.
+    ``(d, d)``; with ``sums`` (a caller that centres) ``(that, sum over
+    the sample axes of a)``.
 
     The samples are contracted where they lie, never flattened to
     ``(n, d)`` first: on the chip's tiled layout that reshape is a
@@ -725,11 +726,16 @@ def _run_gram(a, b, precision, widened=False):
     with every leading axis a sample axis: in a program
     for one TPU device the stored array goes to :func:`_packed_gram`
     where it lies, in contractions of ``_GRAM_BLOCK`` rows, and the
-    planes' matrices are added here.  Every other input, backend and
-    program keeps :func:`_gram_in_runs`, and so does differentiation."""
+    planes' matrices are added here; the sums are then the row sums of
+    the blocks the kernel holds, and no pass of their own.  Every other
+    input, backend and program keeps :func:`_gram_in_runs` (and
+    ``jnp.sum``), and so does differentiation."""
     if a is b and not widened and _kernel_serves(a, precision):
-        return _gram_entry(precision, True)(a)
-    return _gram_in_runs(a, b, precision)
+        out = _gram_entry(precision, True, sums)(a)
+        return tuple(out) if sums else out[0]
+    total = _column_sums(a, True) if sums else None
+    g = _gram_in_runs(a, b, precision)
+    return (g, total) if sums else g
 
 
 def _gram_in_runs(a, b, precision):
@@ -779,6 +785,9 @@ def _gram_in_runs(a, b, precision):
 # device operation so named for the EIGENSOLVER and leave it out of
 # gram_roofline's denominator
 _GRAM_KERNEL_NAME = "packed_gram"
+# the form that also hands back the blocks' row sums: a name of its own, so
+# that a trace says which form a program ran, under the same two rules
+_GRAM_SUMS_KERNEL_NAME = "packed_gram_sums"
 # rows of one row group contracted in one go on the matrix unit; the
 # blocks' matrices are added on the vector unit, which rounds to nearest
 # (see ``_GRAM_RUN``: 1.0e-7 at 8,192 rows).  One constant, from one
@@ -808,25 +817,34 @@ def _kernel_serves(x, precision):
             and _gram_groups(x.shape[-1]) > 0 and precision == "highest")
 
 
-def _gram_kernel(*refs):
+def _gram_kernel(*refs, sums):
     """One grid step.  ``refs``: the row groups' blocks ``(d / 8, 8, T)``,
-    then the two accumulators of the batch element, ``(128, 128)`` for
+    then the accumulators of the batch element, ``(128, 128)`` for
     ``[H; M] [H; M]^T`` and ``(64, 64)`` for ``H L^T``, which stay in VMEM
     over the last grid axis.  The blocks are stacked on the sublanes to
     ``(64, T)`` float32 and split into three bfloat16 pieces on the
     vector unit; each product is ONE contraction of ``T`` rows on the
     matrix unit (the lanes of both sides), added to its accumulator in
-    float32 on the vector unit."""
+    float32 on the vector unit.
+
+    With ``sums`` a third accumulator ``(64, 128)`` takes the stacked
+    block's row sums, a lane at a time: the float32 values themselves,
+    before the split, added on the vector unit (which rounds to nearest);
+    its 128 lanes are added up outside."""
     from jax.experimental import pallas as pl
-    full, quarter = refs[-2:]
+    blocks, outs = refs[:-2 - sums], refs[-2 - sums:]
+    full, quarter = outs[:2]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
-        full[...] = jnp.zeros_like(full)
-        quarter[...] = jnp.zeros_like(quarter)
+        for out in outs:
+            out[...] = jnp.zeros_like(out)
 
-    x = jnp.concatenate([r[...].reshape(-1, r.shape[-1])
-                         for r in refs[:-2]], axis=0)
+    x = jnp.concatenate([r[...].reshape(-1, r.shape[-1]) for r in blocks],
+                        axis=0)
+    if sums:
+        outs[2][...] += _pairwise([x[:, k:k + _LANES]
+                                   for k in range(0, x.shape[1], _LANES)])
     h = x.astype(jnp.bfloat16)
     x = x - h.astype(jnp.float32)
     m = x.astype(jnp.bfloat16)
@@ -839,10 +857,20 @@ def _gram_kernel(*refs):
     quarter[...] += dot(h, low)
 
 
-def _packed_gram(x, cut):
+def _pairwise(parts):
+    """The sum of ``parts`` as a balanced tree, written depth first (few
+    partial sums alive at a time)."""
+    if len(parts) == 1:
+        return parts[0]
+    half = len(parts) // 2
+    return _pairwise(parts[:half]) + _pairwise(parts[half:])
+
+
+def _packed_gram(x, cut, sums=False):
     """``x^T x`` over the rows of every ``(n, d)`` of ``lead + (n, d)``
-    float32 by the kernel: ``(lead + (d, d), rows taken)``, or ``(None,
-    0)`` where ``n`` is under one step's ``64 // d`` blocks.  With
+    float32 by the kernel: ``([lead + (d, d)], rows taken)``, or ``(None,
+    0)`` where ``n`` is under one step's ``64 // d`` blocks; with ``sums``
+    the list also holds the sums of those rows, ``lead + (d,)``.  With
     ``cut`` the last axis of ``lead`` is a cut of the stored row axis (a
     chunk grid); the axes before it lie outside the rows in memory
     (planes, keys).
@@ -876,59 +904,86 @@ def _packed_gram(x, cut):
     grid = lead[-1] if cut else 1
     major = prod(lead) // grid
     view = x.reshape(major, grid, n, d // 8, 8).transpose(0, 3, 1, 4, 2)
-    sides = (_LANES, _GRAM_ROWS)        # [H; M] [H; M]^T and H L^T
-    full, quarter = pl.pallas_call(
-        _gram_kernel,
-        out_shape=[jax.ShapeDtypeStruct((major, grid, side, side),
-                                        jnp.float32) for side in sides],
+    r = _GRAM_ROWS
+    # [H; M] [H; M]^T and H L^T; with ``sums`` the row sums by lane
+    shapes = [(_LANES, _LANES), (r, r)] + [(r, _LANES)] * sums
+    full, quarter, *lanes = pl.pallas_call(
+        partial(_gram_kernel, sums=sums),
+        out_shape=[jax.ShapeDtypeStruct((major, grid) + shape, jnp.float32)
+                   for shape in shapes],
         grid=(major, grid, steps),
         in_specs=[pl.BlockSpec((None, d // 8, None, 8, block),
                                lambda p, g, j, k=k: (p, 0, g, 0,
                                                      k * steps + j))
                   for k in range(groups)],
-        out_specs=[pl.BlockSpec((None, None, side, side),
+        out_specs=[pl.BlockSpec((None, None) + shape,
                                 lambda p, g, j: (p, g, 0, 0))
-                   for side in sides],
+                   for shape in shapes],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name=_GRAM_KERNEL_NAME,
+        name=_GRAM_SUMS_KERNEL_NAME if sums else _GRAM_KERNEL_NAME,
     )(*[view] * groups)
-    r = _GRAM_ROWS
     out = (full[..., :r, :r] + full[..., r:, r:]
            + (full[..., :r, r:] + full[..., r:, :r])
            + (quarter + jnp.swapaxes(quarter, -1, -2)))
     out = sum(out[..., k * d:(k + 1) * d, k * d:(k + 1) * d]
               for k in range(groups))
-    return out.reshape(lead + (d, d)), groups * steps * block
+    # the lanes first, then the groups: a group's sums are rows k * d to
+    # (k + 1) * d of the 64
+    out = [out.reshape(lead + (d, d))] + [
+        jnp.sum(jnp.sum(rows, axis=-1).reshape(lead + (groups, d)), axis=-2)
+        for rows in lanes]
+    return out, groups * steps * block
 
 
-def _kernel_gram(x, precision, cut, samples):
+def _kernel_gram(x, precision, cut, samples, sums=False):
     """``gram_products`` where the kernel can be placed: the rows
     :func:`_packed_gram` takes, the tail by :func:`_plain_gram`."""
-    out, done = _packed_gram(x, cut)
+    out, done = _packed_gram(x, cut, sums)
+    lead = tuple(range(x.ndim - 2))
     if samples and done:
-        out = jnp.sum(out, axis=tuple(range(out.ndim - 2)))
+        out = [jnp.sum(part, axis=lead) for part in out]
     if done < x.shape[-2]:
-        tail = _plain_gram(x[..., done:, :], precision, samples)
-        out = tail if not done else out + tail
+        tail = _plain_gram(x[..., done:, :], precision, samples, sums)
+        out = tail if not done else [a + b for a, b in zip(out, tail)]
     return out
 
 
-def _plain_gram(x, precision, samples):
+def _column_sums(x, samples):
+    """The sums ``gram_products`` hands a caller that centres, as a pass
+    of their own: over the rows of every block, or with ``samples`` over
+    every axis but the features'."""
+    return jnp.sum(x, axis=tuple(range(x.ndim - 1)) if samples else -2)
+
+
+def _plain_gram(x, precision, samples, sums=False):
     """What ``gram_products`` computes, by ``dot_general``: one
-    contraction a block, or a whole array's in runs."""
-    return _gram_in_runs(x, x, precision) if samples \
-        else _products(x, x, precision)
+    contraction a block, or a whole array's in runs; with ``sums`` the
+    rows' sums by ``jnp.sum`` first, as a centring caller's own mean was
+    before the primitive had them."""
+    total = [_column_sums(x, samples)] if sums else []
+    return [_gram_in_runs(x, x, precision) if samples
+            else _products(x, x, precision)] + total
 
 
 def _gram_primitive():
     """``gram_products``: the Gram matrices of ``lead + (n, d)`` float32,
-    ``lead + (d, d)``, or with ``samples`` their sum ``(d, d)``.  A
+    ``[lead + (d, d)]``, or with ``samples`` their sum ``[(d, d)]``.  A
     primitive for :func:`_sweeps_primitive`'s reason: whether a Mosaic
     kernel can be placed is known when the program is LOWERED.  In a
     program for one TPU device it is :func:`_packed_gram`; on the CPU,
     and in a program for several chips outside ``shard_map``, it is
     :func:`_plain_gram`, exactly what ran before the kernel was.
+
+    ``sums`` is set by a caller that centres (``pca``, ``cov``): the
+    result is then ``[gram, sums]``, the rows' sums ``lead + (d,)`` (with
+    ``samples`` ``(d,)``) beside the matrices.  The kernel has every
+    block in VMEM when it makes its products, so there the sums cost no
+    pass over the data (:func:`_gram_kernel`); every other executor adds
+    them up by ``jnp.sum``, the pass a mean was before.  Without ``sums``
+    the kernel, its name and its outputs are what they were: an output
+    nobody reads is not dropped from a Mosaic call, so nobody is handed
+    one.
 
     Under ``vmap`` the rule folds a mapped axis into ``lead`` only where
     its NAME says how it lies in memory (``tpu/chunk.py :: MappedAxis``,
@@ -942,37 +997,42 @@ def _gram_primitive():
     from jax.interpreters import mlir
     from jax._src.interpreters import batching
     prim = Primitive("gram_products")
+    prim.multiple_results = True
     prim.def_impl(partial(dispatch.apply_primitive, prim))
 
     @prim.def_abstract_eval
-    def _(x, *, precision, cut, samples):
+    def _(x, *, precision, cut, samples, sums):
+        d = x.shape[-1]
         lead = () if samples else x.shape[:-2]
-        return x.update(shape=lead + (x.shape[-1],) * 2)
+        return [x.update(shape=lead + (d, d))] \
+            + [x.update(shape=lead + (d,))] * sums
 
     def lower(fits):
-        def rule(ctx, x, *, precision, cut, samples):
+        def rule(ctx, x, *, precision, cut, samples, sums):
             n, d = ctx.avals_in[0].shape[-2:]
             kernel = fits(ctx) and n >= _gram_groups(d) * _GRAM_BLOCK
             if kernel:
                 from bolt_tpu import engine
-                engine.record_gram_kernel_program()
-            fn = partial(_plain_gram, precision=precision, samples=samples)
+                engine.record_gram_kernel_program(sums)
+            fn = partial(_plain_gram, precision=precision, samples=samples,
+                         sums=sums)
             if kernel:
                 fn = partial(_kernel_gram, precision=precision, cut=cut,
-                             samples=samples)
+                             samples=samples, sums=sums)
             # Mosaic has no 64-bit types (see ``jacobi_sweeps``)
             with jax.enable_x64(jax.config.jax_enable_x64 and not kernel):
-                return mlir.lower_fun(fn, multiple_results=False)(ctx, x)
+                return mlir.lower_fun(fn, multiple_results=True)(ctx, x)
         return rule
 
     mlir.register_lowering(prim, lower(lambda ctx: False))
     mlir.register_lowering(prim, lower(_mosaic_fits), platform="tpu")
 
-    def fold(axis_data, args, dims, *, precision, cut, samples):
+    def fold(axis_data, args, dims, *, precision, cut, samples, sums):
         from bolt_tpu.tpu.chunk import MappedAxis
         if dims[0] is None:
-            return prim.bind(args[0], precision=precision, cut=cut,
-                             samples=samples), None
+            out = prim.bind(args[0], precision=precision, cut=cut,
+                            samples=samples, sums=sums)
+            return out, [None] * len(out)
         x = jnp.moveaxis(args[0], dims[0], 0)
         name = axis_data.name
         named = isinstance(name, MappedAxis) and not samples
@@ -981,10 +1041,12 @@ def _gram_primitive():
         row_cut = named and name.rows == x.shape[-2] and x.ndim == 3 \
             and not cut
         if not (row_cut or named and not name.rows):
-            return jax.vmap(partial(_plain_gram, precision=precision,
-                                    samples=samples))(x), 0
-        return prim.bind(x, precision=precision, cut=cut or row_cut,
-                         samples=False), 0
+            out = jax.vmap(partial(_plain_gram, precision=precision,
+                                   samples=samples, sums=sums))(x)
+        else:
+            out = prim.bind(x, precision=precision, cut=cut or row_cut,
+                            samples=False, sums=sums)
+        return out, [0] * len(out)
 
     batching.fancy_primitive_batchers[prim] = fold
     return prim
@@ -994,33 +1056,41 @@ _gram_p = _gram_primitive()
 
 
 @lru_cache(maxsize=None)
-def _gram_entry(precision, samples):
+def _gram_entry(precision, samples, sums=False):
     """:func:`_plain_gram` for real float32 of a width that packs, through
     ``gram_products``.  A ``pallas_call`` has no differentiation rule:
-    this one's is ``dot_general``'s, on the chip too."""
+    this one's is ``dot_general``'s (and ``jnp.sum``'s), on the chip
+    too."""
 
     @jax.custom_jvp
     def run(x):
         return _gram_p.bind(x, precision=precision, cut=False,
-                            samples=samples)
+                            samples=samples, sums=sums)
 
     @run.defjvp
     def _(primals, tangents):
         return jax.jvp(partial(_plain_gram, precision=precision,
-                               samples=samples), primals, tangents)
+                               samples=samples, sums=sums),
+                       primals, tangents)
 
     return run
 
 
-def _sample_gram(x, precision, second_conj=False, widened=False):
+def _sample_gram(x, precision, second_conj=False, widened=False,
+                 sums=False):
     """The Gram matrix of ``sample_shape + (d,)`` data over all its
     leading axes: ``sum conj(x[.., i]) * x[.., j]``, or with
     ``second_conj`` ``sum x[.., i] * conj(x[.., j])`` (``np.cov``'s
-    convention; the two are one for real data).  See :func:`_run_gram`."""
+    convention; the two are one for real data).  With ``sums`` (a caller
+    that centres) ``(that, sum x[.., i])``: the mean's numerator from
+    the pass that is made anyway, where the kernel makes it.  See
+    :func:`_run_gram`."""
     if not jnp.iscomplexobj(x):
-        return _run_gram(x, x, precision, widened)
+        return _run_gram(x, x, precision, widened, sums)
+    total = _column_sums(x, True) if sums else None
     a, b = (x, jnp.conj(x)) if second_conj else (jnp.conj(x), x)
-    return _run_gram(a, b, precision)
+    g = _run_gram(a, b, precision)
+    return (g, total) if sums else g
 
 
 def _project(x, vec, precision):
@@ -1240,8 +1310,12 @@ def pca(b, k=None, center=False, axis=None, return_mean=False,
     ~``eps_f32 * (||mu||/sigma)^2`` relative — exact for mean-zero data,
     ~1e-2 at a 200-sigma offset; pre-shift data with larger offsets);
     ``k`` — number of components (default: all
-    ``d``); ``center`` — subtract per-feature means first (adds one
-    fused pass + a tiny psum); ``axis`` — the sample axes, like
+    ``d``); ``center`` — subtract per-feature means first: the sums
+    come out of the Gram pass where the ``packed_gram`` kernel makes it
+    (stored float32 of 8, 16, 32 or 64 features in a program for one
+    TPU device: no pass of their own, engine counter
+    ``gram_sums_programs``), elsewhere one fused pass + a tiny psum;
+    ``axis`` — the sample axes, like
     ``map``'s (default: the TPU array's key axes / axis 0 locally;
     a TPU array aligns by swapping when they differ, reference
     ``_align`` semantics).
@@ -1323,7 +1397,6 @@ def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
     that a compile-only test can lower exactly what ships."""
     from bolt_tpu.parallel.sharding import key_sharding
     from bolt_tpu.tpu.array import _chain_apply
-    samples = tuple(range(split))
     n = prod(kshape)
 
     def program(data):
@@ -1332,9 +1405,12 @@ def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
         # Centering folds into the Gram algebraically (round-4 fusion):
         #   (X - mu)^T (X - mu) = X^T X - n mu mu^T
         # so the centred matrix is NEVER materialised — the raw X is
-        # read by exactly two MXU matmuls (Gram + projection) plus the
-        # mean's fused reduction, instead of a mean pass, a centred
-        # copy (read+write), and two matmuls over the copy.  The
+        # read by exactly two MXU matmuls (Gram + projection), instead
+        # of a mean pass, a centred copy (read+write), and two matmuls
+        # over the copy.  The mean's sums come back with the Gram
+        # matrix: the packed_gram kernel adds up the rows of the blocks
+        # it holds (ISSUE 33), every other executor makes them a fused
+        # reduction of its own.  The
         # projection offset is applied to the (k,)-sized result:
         #   (X - mu) @ V = X @ V - mu @ V.
         # Conditioning: the fold loses the centred formulation's
@@ -1343,10 +1419,12 @@ def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
         # ~1e-4 at 20 sigma, ~1e-2 at 200 sigma — see
         # test_pca_centering_fold_large_offset).  Pre-shift data with
         # larger offsets.
-        mu = jnp.mean(x, axis=samples) if center \
-            else jnp.zeros(d, x.dtype)
-        g = _sample_gram(x, pr, widened=widened)
-        if center:
+        if not center:
+            mu = jnp.zeros(d, x.dtype)
+            g = _sample_gram(x, pr, widened=widened)
+        else:
+            g, total = _sample_gram(x, pr, widened=widened, sums=True)
+            mu = total / n
             g = g - n * jnp.outer(jnp.conj(mu), mu)
         vec, ev = _decompose_gram(g, k, jnp, _tpu_eigh)
         # pinned "highest": the MXU's bf16 default costs ~3 decimal
@@ -1448,7 +1526,10 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
     program folds the centering into the Gram algebraically (the local
     oracle subtracts the mean explicitly) — entries lose
     ~``eps_f32 * (||mu||/sigma)^2`` relative accuracy at large mean
-    offsets.  Returns a (d, d) NumPy array;
+    offsets.  With ``center`` the mean's sums come out of the Gram pass
+    where the ``packed_gram`` kernel makes it (see :func:`pca`), so the
+    data are read once; elsewhere they are a fused reduction of their
+    own.  Returns a (d, d) NumPy array;
     ``return_mean=True`` appends the per-feature mean.  Superset of the
     reference (its ecosystem computes this via per-chunk jobs).
     ``precision=None`` resolves through the scoped policy like
@@ -1470,35 +1551,13 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
         c = (x.T @ np.conj(x)) / (n - ddof)
         return (c, mu) if return_mean else c
 
-    from bolt_tpu.tpu.array import _cached_jit, _chain_apply
+    from bolt_tpu.tpu.array import _cached_jit
     base, funcs = b._chain_parts()
     mesh = b._mesh
 
     def build():
-        def program(data):
-            x, widened = _features_last(_chain_apply(funcs, split, data),
-                                        shape[:split], d)
-            # same centering fold as pca (round 4): the centred copy is
-            # never materialised — (X-mu)^T conj(X-mu) = X^T conj(X) -
-            # n mu conj(mu)^T; same second-factor conjugation as np.cov.
-            # Same conditioning envelope as pca's fold (~eps_f32 *
-            # (mu/sigma)^2 relative error in the entries).
-            mu = jnp.mean(x, axis=tuple(range(split))) if center \
-                else jnp.zeros(d, x.dtype)
-            c = _sample_gram(x, pr, second_conj=True, widened=widened)
-            if center:
-                c = c - n * jnp.outer(mu, jnp.conj(mu))
-                # the explicit-centering path this fold replaced computed
-                # Xc^H Xc, whose diagonal (sum of squared moduli) cannot
-                # go negative; the fold can cancel past f32 precision for
-                # tiny-variance features on a large offset, so restore
-                # the invariant (mirrors _decompose_gram's eigenvalue
-                # clamp) — corrcoef's sqrt(diag) depends on it
-                idx = jnp.arange(d)
-                diag = jnp.maximum(jnp.real(c[idx, idx]), 0.0)
-                c = c.at[idx, idx].set(diag.astype(c.dtype))
-            return c / (n - ddof), mu
-        return jax.jit(program)
+        return jax.jit(_cov_program(funcs, split, shape[:split], d, center,
+                                    ddof, pr))
 
     fn = _cached_jit(("ops-cov", funcs, base.shape, str(base.dtype), split,
                       mesh, center, ddof, pr), build)
@@ -1507,6 +1566,42 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
         c, mu = jax.device_get((c, mu))    # one batched round-trip
         return np.asarray(c), np.asarray(mu)
     return np.asarray(jax.device_get(c))
+
+
+def _cov_program(funcs, split, kshape, d, center, ddof, pr):
+    """The traced body of :func:`cov`'s one program over the base buffer,
+    module-level for :func:`_pca_program`'s reason."""
+    from bolt_tpu.tpu.array import _chain_apply
+    n = prod(kshape)
+
+    def program(data):
+        x, widened = _features_last(_chain_apply(funcs, split, data),
+                                    kshape, d)
+        # same centering fold as pca (round 4): the centred copy is
+        # never materialised — (X-mu)^T conj(X-mu) = X^T conj(X) -
+        # n mu conj(mu)^T; same second-factor conjugation as np.cov.
+        # Same conditioning envelope as pca's fold (~eps_f32 *
+        # (mu/sigma)^2 relative error in the entries).  The mean's sums
+        # come with the Gram matrix (see _pca_program)
+        if not center:
+            mu = jnp.zeros(d, x.dtype)
+            c = _sample_gram(x, pr, second_conj=True, widened=widened)
+        else:
+            c, total = _sample_gram(x, pr, second_conj=True,
+                                    widened=widened, sums=True)
+            mu = total / n
+            c = c - n * jnp.outer(mu, jnp.conj(mu))
+            # the explicit-centering path this fold replaced computed
+            # Xc^H Xc, whose diagonal (sum of squared moduli) cannot
+            # go negative; the fold can cancel past f32 precision for
+            # tiny-variance features on a large offset, so restore
+            # the invariant (mirrors _decompose_gram's eigenvalue
+            # clamp) — corrcoef's sqrt(diag) depends on it
+            idx = jnp.arange(d)
+            diag = jnp.maximum(jnp.real(c[idx, idx]), 0.0)
+            c = c.at[idx, idx].set(diag.astype(c.dtype))
+        return c / (n - ddof), mu
+    return program
 
 
 def corrcoef(b, axis=None, precision=None):

@@ -148,6 +148,41 @@ def test_thin_records_fold_by_the_kernel(cmesh):
     assert placed == (4 if len(jax.devices()) == 1 else 0), placed
 
 
+@pytest.mark.parametrize("d", [64, 16], ids=["one-group", "four-groups"])
+def test_centred_pca_and_cov_take_the_mean_from_the_gram_pass(cmesh, d):
+    # ops/linalg.py (ISSUE 33): on one chip a centred pca or cov over
+    # stored float32 of a width that packs runs the summing form of the
+    # packed_gram kernel, whose row sums are the mean's numerator; whole
+    # steps of 64 // d row groups and a tail, against float64
+    import jax
+    from bolt_tpu import engine, ops
+    planes, rows, k = 4, 2 * (64 // d) * 8192 + 1234, 3
+    rs = np.random.RandomState(33 + d)
+    x = (rs.randint(-30, 31, size=(planes, rows, d)) * (d - np.arange(d))
+         + 7 * np.arange(d) - 100).astype(np.float32)
+    b = bolt.array(x, cmesh)
+    flat = x.reshape(-1, d).astype(np.float64)
+    mean = flat.mean(axis=0)
+    gram = (flat - mean).T @ (flat - mean)
+    w, v = np.linalg.eigh(gram)
+    c0 = engine.counters()["gram_sums_programs"]
+    scores, comps, svals, mu = ops.pca(b, k=k, center=True, axis=(0, 1),
+                                       return_mean=True)
+    cov, cov_mu = ops.cov(b, axis=(0, 1), return_mean=True)
+    placed = engine.counters()["gram_sums_programs"] - c0
+    assert placed == (2 if len(jax.devices()) == 1 else 0), placed
+    for got in (mu, cov_mu):
+        assert got.dtype == np.float32
+        assert np.abs(got - mean).max() < 2e-4, np.abs(got - mean).max()
+    assert np.allclose(svals, np.sqrt(w[::-1][:k]), rtol=1e-4)
+    top = v[:, ::-1][:, :k]
+    assert np.linalg.norm(comps @ comps.T - top @ top.T) < 1e-2
+    assert np.allclose(cov, gram / (len(flat) - 1), rtol=1e-4,
+                       atol=1e-5 * np.diag(gram).max() / len(flat))
+    _close(scores[:, :512], (flat.reshape(x.shape)[:, :512] - mean) @ comps,
+           rtol=1e-3, atol=5e-2)
+
+
 def test_swap_and_chunked_halo_map(cmesh):
     x = _x((8, 6, 32), seed=4)
     b = bolt.array(x, cmesh)

@@ -309,7 +309,8 @@ _EXPECTED_ENGINE_KEYS = {
     "checkpoint_bytes": False, "checkpoint_seconds": True,
     "fused_stat_groups": False, "fused_stat_terminals": False,
     "getitems_fused": False, "resplit_views": False,
-    "gram_kernel_programs": False, "fold_kernel_programs": False,
+    "gram_kernel_programs": False, "gram_sums_programs": False,
+    "fold_kernel_programs": False,
     "filters_fused": False, "filter_compactions": False,
     "coalesced_builds": False, "coalesced_compiles": False,
     "batched_dispatches": False, "batched_requests": False,

@@ -702,9 +702,14 @@ def _compile_series(fn, v5e_device, shape=_SERIES, dtype=_F32):
             shape, dtype, sharding=where)).compile()
 
 
-def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device):
+@pytest.mark.parametrize("center", [True, False],
+                         ids=["centred", "uncentred"])
+def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device, center):
+    from bolt_tpu import engine
     from bolt_tpu.ops import linalg
-    program = linalg._pca_program((), 2, _SERIES[:2], _SERIES[2], 8, True,
+    names = ("gram_kernel_programs", "gram_sums_programs")
+    c0 = [engine.counters()[k] for k in names]
+    program = linalg._pca_program((), 2, _SERIES[:2], _SERIES[2], 8, center,
                                   "highest", _series_mesh(v5e_device))
     compiled = _compile_series(program, v5e_device)
     mem = compiled.memory_analysis()
@@ -715,9 +720,18 @@ def test_whole_data_pca_fits_beside_its_argument_on_v5e(v5e_device):
     # the Gram pass is ONE kernel call over a bitcast of the argument
     # (ISSUE 29): no matrix-unit fusion with a (64, 64) result is left
     text = compiled.as_text()
-    _one_packed_gram(text, _SERIES[:1] + (1,))
+    _one_packed_gram(text, _SERIES[:1] + (1,), sums=center)
     assert not [ln for ln in text.splitlines()
                 if "convolution" in ln and "f32[64,64]" in ln.split("=")[1][:40]]
+    # and a centred program's mean comes out of that call (ISSUE 33): the
+    # 10.74 GB are read by the kernel and by the projection, and by no
+    # reduction of the mean's own
+    readers = _argument_readers(text)
+    assert len(readers) == 2, readers
+    assert sum("tpu_custom_call" in ln for ln in readers) == 1, readers
+    assert sum("convolution" in ln for ln in readers) == 1, readers
+    assert [engine.counters()[k] for k in names] == [c0[0] + 1,
+                                                     c0[1] + center]
 
 
 # the cell's own shape, and the same deployment at 32 time points: its
@@ -768,20 +782,46 @@ def _kernel_calls(text, name):
             and re.match(r"\s*%" + re.escape(name) + r"(\.\d+)? = ", ln)]
 
 
-def _one_packed_gram(text, batch):
+def _argument_readers(text):
+    """The instructions of the compiled program's entry computation that
+    take its one argument as an operand, a bitcast of it counted as the
+    argument itself."""
+    import re
+    entry = text[text.index("ENTRY "):].splitlines()[1:]
+    held = set(re.findall(r"%(\S+) = \S+ parameter\(0\)", "\n".join(entry)))
+    assert len(held) == 1, held
+    readers = []
+    for ln in entry:
+        made = re.match(r"\s*(?:ROOT )?%(\S+) = ", ln)
+        if not made or not held & set(
+                re.findall(r"%([^\s,()]+)", ln.split(" = ", 1)[1])):
+            continue
+        if re.search(r" bitcast\(", ln):
+            held.add(made.group(1))
+        else:
+            readers.append(ln)
+    return readers
+
+
+def _one_packed_gram(text, batch, sums=False):
     """ONE ``packed_gram`` call whose accumulators are one ``(128, 128)``
-    and one ``(64, 64)`` an element of ``batch``, fed a bitcast (not a
-    copy) of the program's argument, under a name that the benchmark does
-    not take for the eigensolver's."""
+    and one ``(64, 64)`` an element of ``batch`` and nothing else, fed a
+    bitcast (not a copy) of the program's argument, under a name that the
+    benchmark does not take for the eigensolver's.  With ``sums`` it is
+    the summing form: its own name, and a third result, the row sums by
+    lane ``(64, 128)``."""
     import json
     import os
     import re
     from bolt_tpu.ops import linalg
     calls = _kernel_calls(text, linalg._GRAM_KERNEL_NAME)
-    assert len(calls) == 1, calls
+    summing = _kernel_calls(text, linalg._GRAM_SUMS_KERNEL_NAME)
+    assert len(summing) == sums and len(calls) == (not sums), (calls, summing)
+    calls += summing
     dims = ",".join(map(str, batch))
-    assert "f32[%s,128,128]" % dims in calls[0], calls[0]
-    assert "f32[%s,64,64]" % dims in calls[0], calls[0]
+    results = re.findall(r"f32\[[\d,]+\]", calls[0].split(" custom-call(")[0])
+    assert results == ["f32[%s,128,128]" % dims, "f32[%s,64,64]" % dims] \
+        + ["f32[%s,64,128]" % dims] * sums, calls[0]
     operands = set(re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
                    .replace(" ", "").split(","))
     assert len(operands) == 1 and next(iter(operands)).startswith(
@@ -887,7 +927,16 @@ def test_a_stored_narrow_series_is_widened_inside_the_fusion_on_v5e(
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes == 2 * int(np.prod(shape))
     assert mem.temp_size_in_bytes < 0.1e9
-    assert not _kernel_calls(compiled.as_text(), linalg._GRAM_KERNEL_NAME)
+    text = compiled.as_text()
+    assert not _kernel_calls(text, linalg._GRAM_KERNEL_NAME) \
+        + _kernel_calls(text, linalg._GRAM_SUMS_KERNEL_NAME)
+    if program == "pca":
+        # the centred program keeps its own mean: a reduction reads the
+        # narrow argument beside the Gram pass and the projection
+        readers = _argument_readers(text)
+        assert len(readers) == 3, readers
+        assert sum("reduce" in ln.split("=")[0] for ln in readers) == 1, \
+            readers
 
 
 def test_batched_svdvals_is_one_packed_gram_on_v5e(v5e_device):
@@ -936,6 +985,26 @@ def test_gram_keeps_dot_general_in_a_program_for_four_chips(v5e_device):
             (8, 65536, 64), _F32, sharding=where)).compile().as_text()
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text
+    # the centred program's mean is its own reduction there (ISSUE 33)
+    readers = _argument_readers(text)
+    assert sum("reduce" in ln.split("=")[0] for ln in readers) == 1, readers
+
+
+@pytest.mark.parametrize("center", [True, False],
+                         ids=["centred", "uncentred"])
+def test_cov_reads_the_series_once_on_v5e(v5e_device, center):
+    # cov's one program at the cell's shape: ONE kernel call reads the
+    # argument and nothing else does, centred (the summing form: the mean
+    # from the same pass, ISSUE 33) or not (the form it had)
+    from bolt_tpu.ops import linalg
+    program = linalg._cov_program((), 2, _SERIES[:2], _SERIES[2], center, 1,
+                                  "highest")
+    compiled = _compile_series(program, v5e_device)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+    text = compiled.as_text()
+    _one_packed_gram(text, _SERIES[:1] + (1,), sums=center)
+    readers = _argument_readers(text)
+    assert len(readers) == 1 and "tpu_custom_call" in readers[0], readers
 
 
 def test_the_lowering_counts_programs_with_the_kernel(v5e_device):

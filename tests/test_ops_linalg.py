@@ -474,17 +474,20 @@ def test_the_executor_is_chosen_when_the_program_is_lowered(devices, kernel):
 # in Pallas' TPU interpret mode, with a short block, against float64
 # ---------------------------------------------------------------------
 
-def _kernel_gram_here(x, cut=False, samples=False, block=128):
+def _kernel_gram_here(x, cut=False, samples=False, block=128, sums=False):
+    """The Gram matrices by the kernel; with ``sums`` the summing form's
+    ``(matrices, sums)``."""
     from jax.experimental.pallas import tpu as pltpu
     from bolt_tpu.ops import linalg
     old, linalg._GRAM_BLOCK = linalg._GRAM_BLOCK, block
     try:
         with pltpu.force_tpu_interpret_mode(), jax.enable_x64(False):
-            return np.asarray(linalg._kernel_gram(
-                jnp.asarray(x, jnp.float32), "highest", cut, samples),
-                np.float64)
+            out = [np.asarray(part, np.float64) for part in
+                   linalg._kernel_gram(jnp.asarray(x, jnp.float32),
+                                       "highest", cut, samples, sums)]
     finally:
         linalg._GRAM_BLOCK = old
+    return tuple(out) if sums else out[0]
 
 
 def _gram64(x, samples=False):
@@ -498,41 +501,57 @@ def _gram64(x, samples=False):
                                       ((3, 5), True), ((2, 3, 2), True)],
                          ids=["one", "planes3", "planes3-grid5",
                               "keys2x3-grid2"])
-def test_packed_gram_is_exact_on_small_integers(d, lead, cut):
+@pytest.mark.parametrize("sums", [False, True], ids=["gram", "gram+sums"])
+def test_packed_gram_is_exact_on_small_integers(d, lead, cut, sums):
     # integers under 2**6 over at most 2,048 rows: every product and every
     # partial sum is an integer under 2**24, so float32 holds the TRUTH and
-    # any lost product, group or block shows as a whole number
+    # any lost product, group or block shows as a whole number.  The
+    # summing form (ISSUE 33) hands back the same matrices and the rows'
+    # sums, a row group's from its own rows of the stacked block
     rs = np.random.RandomState(d + len(lead))
     n = 2 * (64 // d) * 128
     x = rs.randint(-63, 64, size=lead + (n, d))
-    got = _kernel_gram_here(x, cut)
+    got = _kernel_gram_here(x, cut, sums=sums)
+    whole = _gram64(x, samples=True)
+    summed = _kernel_gram_here(x, samples=True, sums=sums)
+    if sums:
+        assert got[1].shape == lead + (d,)
+        assert np.array_equal(got[1], x.sum(axis=-2))
+        assert np.array_equal(summed[1], x.reshape(-1, d).sum(axis=0))
+        got, summed = got[0], summed[0]
     assert got.shape == lead + (d, d)
     assert np.array_equal(got, _gram64(x))
     # a whole array's sum over the planes leaves 2**24 behind: to float32
-    whole = _gram64(x, samples=True)
-    assert np.abs(_kernel_gram_here(x, samples=True) - whole).max() \
-        <= 2e-7 * np.diag(whole).max()
+    assert np.abs(summed - whole).max() <= 2e-7 * np.diag(whole).max()
 
 
 @pytest.mark.parametrize("d,n", [(64, 3 * 128 + 37), (32, 2 * 256 + 255),
                                  (16, 4 * 128 + 1), (8, 8 * 128 + 1000),
                                  (64, 100)],
                          ids=["d64", "d32", "d16", "d8", "all-tail"])
-def test_packed_gram_with_a_tail_against_float64(d, n):
+@pytest.mark.parametrize("sums", [False, True], ids=["gram", "gram+sums"])
+def test_packed_gram_with_a_tail_against_float64(d, n, sums):
     # rows beyond the kernel's whole steps go to dot_general and are added:
-    # 12-bit data as the benchmark's, three planes (an odd count)
+    # 12-bit data as the benchmark's, three planes (an odd count).  The
+    # tail's rows add their jnp.sum to the kernel's sums: integers whose
+    # column sums stay under 2**24, so every sum is the truth
     from bolt_tpu.ops.linalg import _products
     rs = np.random.RandomState(n)
     x = rs.randint(-2047, 2048, size=(3, n, d)).astype(np.float32)
     want = _gram64(x)
     scale = np.abs(np.diagonal(want, axis1=-2, axis2=-1)).max()
-    got = _kernel_gram_here(x)
+    got = _kernel_gram_here(x, sums=sums)
+    summed = _kernel_gram_here(x, samples=True, sums=sums)
+    if sums:
+        assert np.array_equal(got[1], x.sum(axis=-2, dtype=np.float64))
+        assert np.array_equal(summed[1], x.sum(axis=(0, 1),
+                                               dtype=np.float64))
+        got, summed = got[0], summed[0]
     assert np.abs(got - want).max() < 1e-6 * scale
     # and the dot_general it replaces, to the same 1e-6 of the diagonal
     plain = np.asarray(_products(jnp.asarray(x), jnp.asarray(x), "highest"))
     assert np.abs(got - plain).max() < 1e-6 * scale
-    assert np.abs(_kernel_gram_here(x, samples=True)
-                  - want.sum(axis=0)).max() < 1e-6 * 3 * scale
+    assert np.abs(summed - want.sum(axis=0)).max() < 1e-6 * 3 * scale
 
 
 def test_packed_gram_keeps_all_of_float32():
@@ -544,6 +563,37 @@ def test_packed_gram_keeps_all_of_float32():
     want = _gram64(x)
     scale = np.abs(np.diagonal(want, axis1=-2, axis2=-1)).max()
     assert np.abs(_kernel_gram_here(x) - want).max() < 5e-7 * scale
+
+
+@pytest.mark.parametrize("d,block", [(64, 128), (64, 1024), (32, 512),
+                                     (16, 256), (8, 128)])
+def test_packed_gram_sums_are_of_the_float32_values(d, block):
+    # the sums are of x itself, not of a rounded piece of it.  Full-mantissa
+    # data on an offset (no value fits 16 bits) against float64, held to
+    # twice what jnp.sum's own float32 order of summation errs by; a block
+    # of several lane tiles takes the pairwise tree inside a step.  And the
+    # summing form's Gram matrices are the plain form's, bit for bit
+    rs = np.random.RandomState(d + block)
+    n = 2 * (64 // d) * block + 77
+    x = (rs.randn(3, n, d) * 1000.0 + 5000.0).astype(np.float32)
+    gram, sums = _kernel_gram_here(x, block=block, sums=True)
+    assert np.array_equal(gram, _kernel_gram_here(x, block=block))
+    whole = _kernel_gram_here(x, samples=True, block=block, sums=True)[1]
+    for got, axes in ((sums, -2), (whole, (0, 1))):
+        want = x.sum(axis=axes, dtype=np.float64)
+        plain = np.abs(np.asarray(jnp.sum(jnp.asarray(x), axis=axes),
+                                  np.float64) - want).max()
+        assert np.abs(got - want).max() <= 2 * max(
+            plain, np.spacing(np.float32(want.max())))
+    # a few odd 17-bit integers among zeros, in every row group's rows:
+    # their third bfloat16 piece is not zero and their sums are exact in
+    # float32, so a dropped piece is a wrong integer
+    x = np.zeros((3, n, d), np.float32)
+    rows = rs.choice(n, size=3 * (64 // d), replace=False)
+    x[:, rows] = 2 * rs.randint(1 << 15, 1 << 16, size=(3, len(rows), d)) + 1
+    assert np.array_equal(
+        _kernel_gram_here(x, block=block, sums=True)[1],
+        x.sum(axis=-2, dtype=np.float64))
 
 
 @pytest.mark.parametrize("d", [32, 8])
@@ -672,6 +722,18 @@ def test_gram_products_differentiates_as_dot_general(monkeypatch):
     assert _eqns(jax.make_jaxpr(loss)(x).jaxpr, "gram_products")
     assert not _eqns(jax.make_jaxpr(jax.grad(loss))(x).jaxpr,
                      "gram_products")
+    # with the sums a centring caller asks for (ISSUE 33): the rule is
+    # dot_general's and jnp.sum's
+    def centred(v):
+        g, total = linalg._sample_gram(v, "highest", sums=True)
+        return ((g - jnp.outer(total, total) / 288) ** 2).sum() \
+            + (total ** 3).sum()
+    got_sums = jax.grad(centred)(x)
+    call, = _eqns(jax.make_jaxpr(centred)(x).jaxpr, "gram_products")
+    assert call.params["sums"] and call.params["samples"]
+    assert [v.aval.shape for v in call.outvars] == [(8, 8), (8,)]
+    assert not _eqns(jax.make_jaxpr(jax.grad(centred))(x).jaxpr,
+                     "gram_products")
     monkeypatch.setattr(linalg, "_kernel_serves", lambda x, precision: False)
     # (make_jaxpr caches a function's trace: a new one)
     assert not _eqns(jax.make_jaxpr(lambda v: loss(v))(x).jaxpr,
@@ -680,25 +742,81 @@ def test_gram_products_differentiates_as_dot_general(monkeypatch):
     assert got.dtype == jnp.float32 and np.all(np.isfinite(np.asarray(got)))
     assert np.array_equal(np.asarray(got), np.asarray(want))
     assert np.array_equal(np.asarray(got_runs), np.asarray(want_runs))
+    assert np.array_equal(np.asarray(got_sums),
+                          np.asarray(jax.grad(lambda v: centred(v))(x)))
+
+
+@pytest.mark.parametrize("dtype,d", [("float32", 8), ("float32", 5),
+                                     ("complex64", 8), ("int16", 8)])
+def test_centred_pca_and_cov_are_the_mean_pass_and_the_gram_pass_here(
+        dtype, d):
+    # where the kernel is not placed (this backend) a centring caller's
+    # sums are jnp.sum beside the dot_general: the numbers of the program
+    # that took jnp.mean itself, spelled out here as it was, to the bit
+    import bolt_tpu as bolt
+    from bolt_tpu import ops
+    from bolt_tpu.ops import linalg
+    rs = np.random.RandomState(d)
+    x = (rs.randn(8, 96, d) * 50 + 300)
+    x = (x + 1j * rs.randn(8, 96, d)) if dtype == "complex64" else x
+    x = x.astype(dtype)
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:8]), ("k",))
+    b = bolt.array(x, mesh, axis=(0,))
+    n, k = 8 * 96, 3
+
+    @jax.jit
+    def before(data):
+        v, widened = linalg._features_last(data, (8, 96), d)
+        mu = jnp.mean(v, axis=(0, 1))
+        g = linalg._sample_gram(v, "highest", widened=widened)
+        g = g - n * jnp.outer(jnp.conj(mu), mu)
+        vec, ev = linalg._decompose_gram(g, k, jnp, linalg._tpu_eigh)
+        c = linalg._sample_gram(v, "highest", second_conj=True,
+                                widened=widened)
+        c = c - n * jnp.outer(mu, jnp.conj(mu))
+        idx = jnp.arange(d)
+        c = c.at[idx, idx].set(jnp.maximum(jnp.real(c[idx, idx]), 0.0)
+                               .astype(c.dtype))
+        return vec, jnp.sqrt(ev), mu, c / (n - 1)
+
+    vec, sv, mu, c = (np.asarray(a) for a in before(b.tojax()))
+    _, got_vec, got_sv, got_mu = ops.pca(b, k=k, center=True, axis=(0, 1),
+                                         return_mean=True)
+    got_c, cov_mu = ops.cov(b, axis=(0, 1), return_mean=True)
+    for got, want in ((got_mu, mu), (cov_mu, mu), (got_sv, sv),
+                      (got_vec, vec), (got_c, c)):
+        assert got.dtype == want.dtype
+        assert np.array_equal(got, want)
 
 
 @pytest.mark.parametrize("devices,kernel", [(1, True), (4, False)],
                          ids=["one-device", "four-devices"])
+@pytest.mark.parametrize("sums", [False, True], ids=["gram", "gram+sums"])
 def test_the_gram_executor_is_chosen_when_the_program_is_lowered(devices,
-                                                                 kernel):
+                                                                 kernel,
+                                                                 sums):
     # lowered FOR a TPU on this CPU host (nothing compiles, nothing runs):
     # one device gets the kernel and is counted; several keep dot_general,
-    # and so does this host's own backend, uncounted
+    # and so does this host's own backend, uncounted.  A caller that
+    # centres gets the summing form (its own name, its own count beside
+    # the other) and, where the kernel is not placed, its own reduction
     from bolt_tpu import engine
+    from bolt_tpu.ops import linalg
     mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:devices]), ("k",))
     where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
     arg = jax.ShapeDtypeStruct((8, 8192, 64), jnp.float32, sharding=where)
-    c0 = engine.counters()["gram_kernel_programs"]
+    fn = (lambda v: linalg._sample_gram(v, "highest", sums=True)) if sums \
+        else svdvals
+    names = ("gram_kernel_programs", "gram_sums_programs")
+    c0 = [engine.counters()[k] for k in names]
+    want = [c0[0] + kernel, c0[1] + (kernel and sums)]
     with jax.enable_x64(False):
-        text = jax.jit(svdvals).trace(arg).lower(
+        text = jax.jit(fn).trace(arg).lower(
             lowering_platforms=("tpu",)).as_text()
     assert ("packed_gram" in text) == kernel
-    assert engine.counters()["gram_kernel_programs"] == c0 + kernel
-    cpu = jax.jit(svdvals).lower(arg).as_text()
+    assert ("packed_gram_sums" in text) == (kernel and sums)
+    assert ("stablehlo.reduce(%arg0" in text) == (sums and not kernel)
+    assert [engine.counters()[k] for k in names] == want
+    cpu = jax.jit(fn).lower(arg).as_text()
     assert "packed_gram" not in cpu and "dot_general" in cpu
-    assert engine.counters()["gram_kernel_programs"] == c0 + kernel
+    assert [engine.counters()[k] for k in names] == want

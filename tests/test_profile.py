@@ -30,6 +30,9 @@ def test_annotate_and_trace(tmp_path, mesh):
     lands in the device trace as ``bolt.<name>``."""
     from jax.profiler import ProfileData
     from bolt_tpu import obs
+    # the ring keeps what an earlier file of this worker recorded and
+    # disabled without clearing: start from an empty one
+    obs.clear()
     with obs.span("bolt-test-region"):
         bolt.ones((8, 2), mesh).sum().toarray()
     assert obs.spans() == []
