@@ -13,6 +13,9 @@ symbol-level citation, SURVEY.md §0):
 >>> b.map(lambda x: x + 1).sum().toarray()
 """
 
+from bolt_tpu.obs.trace import clock as _clock     # standard library only
+_T0 = _clock()                  # engine counter import_seconds, from here
+
 __version__ = "0.5.0"
 
 from bolt_tpu.factory import (array, concatenate, fromcallback, fromiter,
@@ -24,6 +27,7 @@ from bolt_tpu.tpu.multistat import compute
 from bolt_tpu._precision import precision
 from bolt_tpu.utils import allclose
 from bolt_tpu import profile as _profile   # arms the obs->profiler bridge
+from bolt_tpu import engine as _engine
 
 __all__ = ["array", "ones", "zeros", "full", "rand", "randn",
            "fromcallback", "fromiter", "concatenate", "compute",
@@ -42,3 +46,6 @@ def __getattr__(name):
         import importlib
         return importlib.import_module("bolt_tpu." + name)
     raise AttributeError("module 'bolt_tpu' has no attribute %r" % (name,))
+
+
+_engine.record_import(_clock() - _T0)       # ... to here

@@ -101,6 +101,18 @@ _SCHEMA = {
     "persistent_warm_hits": 0,  # persistent hits while a warm_start()
                                 # fleet-preload is armed (serve.Server
                                 # start_warm= — the no-compile-storm proof)
+    # start-up accounting, PROCESS-WIDE (jax.monitoring's duration
+    # events, so the fallbacks path and every jax.jit outside the engine
+    # are in them, which lower_seconds / compile_seconds are not).  Each
+    # of the four is SELF time on its thread: an event's seconds less the
+    # events nested in it, so no second is in two of them
+    "import_seconds": 0.0,    # bolt_tpu/__init__.py, first line to last
+    "trace_seconds": 0.0,     # tracing to jaxprs   } the two halves
+    "mlir_seconds": 0.0,      # jaxprs to StableHLO } of lowering
+    "persistent_read_seconds": 0.0,  # on-disk cache: read, deserialize
+    "backend_compile_seconds": 0.0,  # backend compiles less the cache
+                                     # reads inside them: XLA's own work
+    "compile_requests": 0,    # backend compiles asked for, hit or miss
     "diagnostics": 0,         # findings emitted by bolt_tpu.analysis.check
     "strict_checks": 0,       # pre-dispatch checks forced by analysis.strict
     "strict_rejections": 0,   # dispatches refused on error-severity findings
@@ -121,6 +133,11 @@ _SCHEMA = {
                                    # ingest can exceed wall time)
     "stream_compute_seconds": 0.0,  # main-thread dispatch + sync time
     "stream_wall_seconds": 0.0,    # end-to-end streamed-run wall time
+    "stream_compile_seconds": 0.0,  # of that wall, the run's own
+                                    # thread inside the start-up phases
+                                    # above (a first pass lowers and
+                                    # compiles its slab program): wall
+                                    # less this is the passes themselves
     "stream_overlap_seconds": 0.0,  # ingest hidden behind compute
     "stream_prefetch_depth": 0,    # high-water configured prefetch depth
     "stream_upload_threads": 0,    # high-water CONCURRENT uploader
@@ -260,41 +277,108 @@ def tenant_counters(name):
     :func:`tenant` scope for that name does counted work)."""
     return _metrics.registry().group("engine/%s" % name, _SCHEMA).snapshot()
 
-# latency/size distributions riding on the same registry lock: the
-# counters above give totals, these give shape (log2 buckets — see
-# bolt_tpu.obs.metrics.Histogram).  The ".hist" suffix keeps them off
-# the group's flattened "engine.<key>" snapshot namespace.
-_DISPATCH_HIST = _metrics.registry().histogram(
-    "engine.dispatch_seconds.hist", lo=-20, hi=8)
+# size distribution riding on the same registry lock: the counters above
+# give totals, this gives shape (log2 buckets — see
+# bolt_tpu.obs.metrics.Histogram).  The ".hist" suffix keeps it off the
+# group's flattened "engine.<key>" snapshot namespace.
 _TRANSFER_HIST = _metrics.registry().histogram(
     "engine.transfer_bytes.hist", lo=6, hi=36)
 
-_MONITORING_HOOKED = False
+# jax.monitoring's duration events, by the start-up counter each feeds
+_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "mlir_seconds",
+    "/jax/compilation_cache/cache_retrieval_time_sec":
+        "persistent_read_seconds",
+    "/jax/core/compile/backend_compile_duration":
+        "backend_compile_seconds",
+}
+_PHASE_SLACK = 1e-4      # seconds an event may reach its listener late
 
 
-def _hook_persistent_monitoring():
-    """Count the on-disk cache's hits/misses via jax's monitoring events
-    (the only public signal of whether ``.compile()`` loaded from disk)."""
-    global _MONITORING_HOOKED
-    if _MONITORING_HOOKED:
+class _Tally(threading.local):
+    """What the calling thread's compiles have done so far: the on-disk
+    cache's answers (the hit/miss event fires on the compiling thread,
+    inside ``lowered.compile()``, so a reading before and after a compile
+    tells which it got) and ``phases``, the newest ``(start, seconds)`` of
+    the :data:`_PHASES` events that no later event of the thread holds
+    inside it, in :func:`_clock` seconds."""
+
+    def __init__(self):
+        self.hits = self.misses = 0
+        self.read = 0.0
+        self.phases = deque(maxlen=1024)
+
+
+_TALLY = _Tally()
+
+
+def _on_event(event, **kwargs):
+    if event == "/jax/compilation_cache/cache_hits":
+        _TALLY.hits += 1
+        if _WARM_ARMED:
+            _COUNTERS.update(persistent_hits=1, persistent_warm_hits=1)
+        else:
+            _COUNTERS.add("persistent_hits")
+    elif event == "/jax/compilation_cache/cache_misses":
+        _TALLY.misses += 1
+        _COUNTERS.add("persistent_misses")
+
+
+def _on_duration(event, seconds, **kwargs):
+    key = _PHASES.get(event)
+    if key is None:
         return
+    # an event fires as its interval ENDS, the innermost first (a jitted
+    # function traced inside another's trace, the cache's read inside the
+    # backend compile): whatever this thread recorded since this one began
+    # ran inside it, and is taken out so that the counters add up
+    start = _clock() - seconds
+    phases = _TALLY.phases
+    own = seconds
+    while phases and phases[-1][0] >= start - _PHASE_SLACK:
+        own -= phases.pop()[1]
+    phases.append((start, seconds))
+    own = max(own, 0.0)
+    if key == "backend_compile_seconds":
+        _COUNTERS.update(backend_compile_seconds=own, compile_requests=1)
+    else:
+        if key == "persistent_read_seconds":
+            _TALLY.read += seconds
+        _COUNTERS.add(key, own)
+
+
+def _phases_since(start):
+    """Seconds the calling thread spent inside the start-up phases since
+    ``start`` (whole outermost events; :func:`_clock` seconds)."""
+    total = 0.0
+    for begun, seconds in reversed(_TALLY.phases):
+        if begun < start:
+            break
+        total += seconds
+    return total
+
+
+def _hook_monitoring():
+    """Listen to jax's monitoring events: the only public signal of
+    whether ``.compile()`` loaded from disk, and of what tracing, lowering
+    and compiling cost OUTSIDE this module's AOT path."""
     try:
         from jax import monitoring
-
-        def listen(event, **kwargs):
-            if event == "/jax/compilation_cache/cache_hits":
-                if _WARM_ARMED:
-                    _COUNTERS.update(persistent_hits=1,
-                                     persistent_warm_hits=1)
-                else:
-                    _COUNTERS.add("persistent_hits")
-            elif event == "/jax/compilation_cache/cache_misses":
-                _COUNTERS.add("persistent_misses")
-
-        monitoring.register_event_listener(listen)
-        _MONITORING_HOOKED = True
+        monitoring.register_event_listener(_on_event)
+        monitoring.register_event_duration_secs_listener(_on_duration)
     except Exception:
         pass
+
+
+_hook_monitoring()
+
+
+def record_import(seconds):
+    """The package's own import, first line of ``bolt_tpu/__init__.py`` to
+    its last (with whatever it was the first to import: jax, if the
+    caller had not)."""
+    _COUNTERS.add("import_seconds", seconds)
 
 
 def counters():
@@ -310,7 +394,68 @@ def counters():
 
 
 def reset_counters():
+    """Zero the counters, and drop the compile log with them (its rows
+    are stamped with the ``dispatches`` count)."""
     _COUNTERS.reset()
+    with _LOCK:
+        _COMPILE_LOG.clear()
+
+
+# ---------------------------------------------------------------------
+# the compile log: which program compiled, and did the cache serve it
+# ---------------------------------------------------------------------
+
+_COMPILE_LOG = deque(maxlen=512)     # newest rows; a compile costs
+#                                      milliseconds at least, a row nothing
+
+
+def compile_log():
+    """The newest (at most 512) lower+compile runs of the AOT path, oldest
+    first, a dict each:
+
+    ``family``      the op family of the program's engine key
+    ``program``     a short digest of that key and of the argument
+                    signature it was compiled for: the same program reads
+                    the same in every process (addresses are left out,
+                    so two functions of one qualified name read alike),
+                    and a cold run's rows pair with a warm run's
+    ``lower_s``     seconds tracing and lowering (host work, every time)
+    ``compile_s``   seconds inside ``lowered.compile()``
+    ``cache``       ``"hit"``: the on-disk cache served it; ``"miss"``:
+                    XLA compiled it (and the cache kept it); ``"off"``:
+                    no on-disk cache was asked
+    ``read_s``      seconds of ``compile_s`` reading the on-disk cache
+    ``t0``          when lowering began, in ``obs.clock`` seconds: the
+                    clock of every span, so rows lie on ``obs.to_chrome``'s
+                    timeline and on a profiler trace
+    ``dispatches``  the ``dispatches`` counter at that moment
+
+    The operator's answer to "which step recompiled": a row stamped past
+    warm-up names it.  Compiles outside the AOT path (``fallbacks``, any
+    ``jax.jit`` of the caller's own) are in ``compile_requests`` and the
+    process-wide seconds, not here."""
+    with _LOCK:
+        return [dict(row) for row in _COMPILE_LOG]
+
+
+def _log_compile(key, sig, t0, t1, t2, before):
+    """One row for the compile of engine key ``key`` at signature ``sig``
+    that ran ``t0 .. t1 .. t2``; ``before`` is the thread's ``(hits,
+    misses, read)`` tally as lowering began."""
+    hits, misses, read = before
+    row = {
+        "family": _family(key),
+        "program": hashlib.sha256(
+            _stable_key((key, sig)).encode()).hexdigest()[:12],
+        "lower_s": t1 - t0, "compile_s": t2 - t1,
+        "cache": ("hit" if _TALLY.hits > hits
+                  else "miss" if _TALLY.misses > misses else "off"),
+        "read_s": _TALLY.read - read,
+        "t0": t0, "dispatches": _COUNTERS["dispatches"],
+    }
+    with _LOCK:
+        _COMPILE_LOG.append(row)
+    return row
 
 
 def clear():
@@ -367,7 +512,6 @@ def persistent_cache(cache_dir=None, enable=True):
     cache must not keep counting as warm-start hits (``warm_start``
     re-arms after delegating)."""
     global _PERSISTENT_DIR, _WARM_ARMED
-    _hook_persistent_monitoring()
     _WARM_ARMED = False
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not enable:
@@ -666,9 +810,12 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                   uploaders=1, inflight=1):
     """Tally one completed streamed run (bolt_tpu.stream executor); the
     keys apply atomically — a snapshot can never see a run's wall time
-    without its overlap.  ``uploaders`` is the run's observed concurrent
-    uploader high-water, ``inflight`` its dispatched-but-unconfirmed
-    slab-program high-water; both (and the depth) keep process maxima."""
+    without its overlap.  Called by the run's own thread as the run ends,
+    so what that thread spent lowering and compiling since ``wall_s`` ago
+    is the run's (``stream_compile_seconds``).  ``uploaders`` is the run's
+    observed concurrent uploader high-water, ``inflight`` its
+    dispatched-but-unconfirmed slab-program high-water; both (and the
+    depth) keep process maxima."""
     _COUNTERS.update(_maxima={"stream_prefetch_depth": int(depth),
                               "stream_upload_threads": int(uploaders),
                               "stream_inflight_high_water": int(inflight)},
@@ -676,6 +823,8 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                      stream_ingest_seconds=ingest_s,
                      stream_compute_seconds=compute_s,
                      stream_wall_seconds=wall_s,
+                     stream_compile_seconds=_phases_since(
+                         _clock() - wall_s),
                      stream_overlap_seconds=overlap_s)
 
 
@@ -853,7 +1002,6 @@ class _Dispatch:
         finally:
             dt = _clock() - t0
             _COUNTERS.update(dispatches=1, dispatch_seconds=dt)
-            _DISPATCH_HIST.observe(dt)
             _obs.end(sp)
         return out
 
@@ -890,18 +1038,25 @@ class _Dispatch:
                     if fn is not None:
                         _COUNTERS.add("coalesced_compiles")
                     else:
+                        family = _family(self.key)
+                        before = (_TALLY.hits, _TALLY.misses, _TALLY.read)
                         try:
-                            lsp = _obs.begin("engine.lower")
+                            lsp = _obs.begin("engine.lower", family=family)
                             try:
                                 t0 = _clock()
                                 lowered = self.jitted.lower(*args)
                                 t1 = _clock()
                             finally:
                                 _obs.end(lsp)
-                            csp = _obs.begin("engine.compile")
+                            csp = _obs.begin("engine.compile",
+                                             family=family)
                             try:
                                 fn = lowered.compile()
                                 t2 = _clock()
+                                row = _log_compile(self.key, sig, t0, t1,
+                                                   t2, before)
+                                if csp is not None:
+                                    csp.set(cache=row["cache"])
                             finally:
                                 _obs.end(csp)
                             _COUNTERS.update(aot_compiles=1,

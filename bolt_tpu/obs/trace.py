@@ -78,11 +78,17 @@ _RING_DEFAULT = 4096
 _ON = False                      # the hot-path flag ...
 _LIVE = None                     # ... and probe: is a profiler session live?
 _ANNOTATE = None                 # (name, **stats) -> an entered annotation
-_LOCK = _lockdep().lock("obs.trace")   # guards ring, totals, active count
+_LOCK = _lockdep().lock("obs.trace")   # guards ring, totals, the leak gate
 _RING = deque(maxlen=_RING_DEFAULT)
 _TOTALS = {}                     # path -> [count, seconds, self s, bytes]
-_ACTIVE = 0                      # begun-but-not-ended spans (leak gate)
+# the leak gate (active_count) costs begin() nothing: a span's id is drawn
+# from _IDS, whose next() needs no lock, and spans open are ids drawn
+# since _BASE (the id clear() drew) less the ids active_count() drew
+# itself to look (_PEEKS) and the spans closed since (_CLOSED)
 _IDS = itertools.count(1)
+_BASE = 0
+_PEEKS = 0
+_CLOSED = 0
 _TLS = threading.local()         # per-thread open-span stack
 
 
@@ -205,11 +211,11 @@ def clear():
     """Drop every completed span, zero the totals and the leak counter
     (open spans begun before ``clear`` still end cleanly — ``end``
     tolerates an already-cleared ring)."""
-    global _ACTIVE
+    global _BASE, _PEEKS, _CLOSED
     with _LOCK:
         _RING.clear()
         _TOTALS.clear()
-        _ACTIVE = 0
+        _BASE, _PEEKS, _CLOSED = next(_IDS), 0, 0
 
 
 def spans():
@@ -251,8 +257,11 @@ def active_count():
     """Spans begun but not yet ended — a nonzero value after a run means
     an instrumented path leaked a span (the feature suites under
     ``tests/`` assert zero after a run)."""
+    global _PEEKS
     with _LOCK:
-        return _ACTIVE
+        begun = next(_IDS) - _BASE - 1 - _PEEKS
+        _PEEKS += 1
+        return max(0, begun - _CLOSED)
 
 
 def begin(name, parent=None, **attrs):
@@ -263,7 +272,6 @@ def begin(name, parent=None, **attrs):
     also opens the profiler's annotation ``bolt.<name>``.  ``parent``
     overrides the calling thread's current span (the explicit
     cross-thread handoff; see the streaming executor)."""
-    global _ACTIVE
     live = _LIVE is not None and _LIVE()
     if not (_ON or live):
         return None
@@ -275,8 +283,6 @@ def begin(name, parent=None, **attrs):
     if live:
         sp._ann = _ANNOTATE("bolt." + name, rid=sp.rid)
     st.append(sp)
-    with _LOCK:
-        _ACTIVE += 1
     return sp
 
 
@@ -296,7 +302,7 @@ def _leave(sp):
 
 def end(sp, **attrs):
     """Close a span returned by :func:`begin` (no-op on ``None``)."""
-    global _ACTIVE
+    global _CLOSED
     if sp is None:
         return
     sp.t1 = clock()
@@ -315,8 +321,8 @@ def end(sp, **attrs):
         top._kids += d
     nbytes = sp.attrs.get("bytes")
     with _LOCK:
-        if _ACTIVE > 0:
-            _ACTIVE -= 1
+        if sp.sid > _BASE:
+            _CLOSED += 1
         _RING.append(sp)
         row = _TOTALS.get(sp.path)
         if row is None:
@@ -334,7 +340,7 @@ def cancel(sp):
     annotation, once opened, does close).  For probes that turn out to
     have observed nothing (e.g. the streaming executor's ingest probe
     that hits end-of-source)."""
-    global _ACTIVE
+    global _CLOSED
     if sp is None:
         return
     ann, sp._ann = sp._ann, None
@@ -342,8 +348,8 @@ def cancel(sp):
         ann.__exit__(None, None, None)
     _leave(sp)
     with _LOCK:
-        if _ACTIVE > 0:
-            _ACTIVE -= 1
+        if sp.sid > _BASE:
+            _CLOSED += 1
 
 
 def current():

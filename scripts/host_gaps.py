@@ -25,7 +25,10 @@ traced window and per request:
   after the ``bolt.engine.enqueue`` that launched it and ends before the
   ``bolt.array.fetch.wait`` (or ``bench.fetch``) that awaited it; and the last two tables again at either
   end of that range.  The profiler aligns the two clocks to a millisecond
-  or so, which is the size of the gaps being split.
+  or so, which is the size of the gaps being split;
+* the programs that compiled inside the window, if any did, by family
+  with their lowering and compile seconds and whether the on-disk cache
+  served them (``engine.compile_log()``).
 
 The last line of standard output is the same as one JSON object.
 """
@@ -236,6 +239,16 @@ def show(raw, count, out=print):
                                                (s + d - f0) * 1e-3, name))
 
 
+def window_compiles():
+    """The programs that compiled inside the traced window: the tracer
+    records only while the profiler session is live, so its count of
+    ``engine.compile`` spans is the window's, and that many of the engine's
+    newest compile-log rows name them."""
+    from bolt_tpu import engine, obs
+    count = obs.totals().get("engine.compile", {}).get("count", 0)
+    return engine.compile_log()[-count:] if count else []
+
+
 def table(title, rows, requests):
     print(title)
     for name, seconds in sorted(rows.items(), key=lambda kv: -kv[1]):
@@ -271,6 +284,12 @@ def main(argv=None):
              out["idle_s"], out["bolt_events"]))
     if args.show:
         show(kept[0], args.show)
+    out["compiled_in_window"] = window_compiles()
+    for row in out["compiled_in_window"]:
+        print("compiled in the window: %s %s, lower %.3f s, compile %.3f s "
+              "(%s, read %.3f s)"
+              % (row["family"], row["program"], row["lower_s"],
+                 row["compile_s"], row["cache"], row["read_s"]))
     table("idle by bench.* span (the ledger's breakdown.idle_gaps):",
           out["idle_by_bench"], requests)
     table("idle by innermost bolt.* span, whole window:",

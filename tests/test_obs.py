@@ -298,6 +298,9 @@ _EXPECTED_ENGINE_KEYS = {
     "dispatches": False, "dispatch_seconds": True, "fallbacks": False,
     "donations": False, "persistent_hits": False,
     "persistent_misses": False, "persistent_warm_hits": False,
+    "import_seconds": True, "trace_seconds": True, "mlir_seconds": True,
+    "persistent_read_seconds": True, "backend_compile_seconds": True,
+    "compile_requests": False, "stream_compile_seconds": True,
     "diagnostics": False,
     "strict_checks": False, "strict_rejections": False,
     "transfer_bytes": False, "transfer_seconds": True,
@@ -347,14 +350,6 @@ def test_engine_counters_snapshot_unchanged_post_migration(mesh):
     bolt.ones((8, 4), mesh).sum().toarray()
     assert obs.registry().snapshot()["engine.dispatches"] \
         == engine.counters()["dispatches"] >= d0 + 1
-
-
-def test_dispatch_histogram_rides_along(mesh):
-    h = obs.registry().get("engine.dispatch_seconds.hist")
-    n0 = h.count
-    bolt.ones((8, 3), mesh).map(lambda v: v + 5).sum().toarray()
-    assert h.count > n0                         # every dispatch observed
-    assert h.sum >= 0.0
 
 
 # ----------------------------------------------------------------------

@@ -575,3 +575,29 @@ def test_host_gaps_gives_no_bound_without_enqueue_spans(host_gaps):
                  host=[("bench.window", 0, 10), ("bench.fetch", 0, 4)])
     out = host_gaps.split(raw)
     assert out["device_clock_offset_s"] is None and "at_offset" not in out
+
+
+def test_host_gaps_names_what_compiled_while_the_tracer_looked(host_gaps,
+                                                               mesh):
+    arr = bolt.ones((8, 5), mesh)
+    arr.toarray()
+
+    def warm(v):
+        return v * 34.09
+
+    def cold(v):
+        return v * 34.10
+
+    arr.map(warm).sum().toarray()
+    obs.clear()
+    assert host_gaps.window_compiles() == []
+    obs.enable()
+    try:
+        arr.map(warm).sum().toarray()
+        assert host_gaps.window_compiles() == []
+        arr.map(cold).sum().toarray()
+    finally:
+        obs.disable()
+    row, = host_gaps.window_compiles()
+    assert row == engine.compile_log()[-1] and row["family"] == "stat"
+    obs.clear()
