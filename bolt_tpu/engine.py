@@ -119,10 +119,15 @@ _SCHEMA = {
     # host<->device traffic accounting (fed by bolt_tpu.stream.transfer —
     # the ONE device_put wrapper, enforced by lint rule BLT105)
     "transfer_bytes": 0,      # host bytes shipped to device
-    "transfer_seconds": 0.0,  # seconds inside counted transfers, summed
-                              # across uploader-pool workers (concurrent
-                              # uploads can exceed wall time, so derive
-                              # per-worker link rate, not absolute GB/s)
+    "transfer_seconds": 0.0,  # seconds during which at least ONE counted
+                              # transfer was in flight: the union of the
+                              # copies' intervals, so bytes over it is
+                              # the link's rate however many uploader
+                              # workers overlap (never above wall time)
+    "transfer_copy_seconds": 0.0,  # each copy's own seconds, summed
+                                   # across workers; over the counter
+                                   # above it is the mean number of
+                                   # copies in flight while any was
     # streaming-executor accounting (bolt_tpu.stream: the out-of-core
     # double-buffered pipeline).  overlap_seconds is ingest time hidden
     # behind device compute: max(0, ingest + compute - wall) per run;
@@ -399,6 +404,7 @@ def reset_counters():
     _COUNTERS.reset()
     with _LOCK:
         _COMPILE_LOG.clear()
+        del _LINK_BUSY[:]
 
 
 # ---------------------------------------------------------------------
@@ -753,11 +759,44 @@ def strict_rejected():
 # transfer / streaming accounting (fed by bolt_tpu.stream)
 # ---------------------------------------------------------------------
 
+# the stretches of _clock time already counted into transfer_seconds:
+# disjoint, oldest first, guarded by _LOCK.  A copy reports as it ENDS,
+# so a new one reaches back over the newest few only
+_LINK_BUSY = []
+_LINK_KEEP = 16
+
+
+def _link_fresh(start, end):
+    """Count ``[start, end)`` as link-busy and return the seconds of it
+    that no earlier transfer had counted (caller holds ``_LOCK``)."""
+    fresh = end - start
+    kept = []
+    for a, b in _LINK_BUSY:
+        if b < start or a > end:
+            kept.append((a, b))
+            continue
+        fresh -= min(b, end) - max(a, start)
+        start, end = min(start, a), max(end, b)
+    kept.append((start, end))
+    kept.sort()
+    _LINK_BUSY[:] = kept[-_LINK_KEEP:]
+    return max(fresh, 0.0)
+
+
 def record_transfer(nbytes, seconds):
-    """Tally one counted host->device transfer (bolt_tpu.stream.transfer
-    is the only caller — lint rule BLT105 keeps it that way)."""
+    """Tally one counted host->device transfer that took ``seconds`` and
+    ended now (bolt_tpu.stream.transfer is the only caller — lint rule
+    BLT105 keeps it that way).  ``transfer_copy_seconds`` gets the copy's
+    own seconds; ``transfer_seconds`` only the part of them during which
+    no other counted copy was in flight, so it is the link's busy time:
+    the same number wherever copies never overlap.  The link is the
+    process's: a tenant's mirror gets the part its copy added."""
+    end = _clock()
+    with _LOCK:
+        busy = _link_fresh(end - seconds, end)
     _COUNTERS.update(transfer_bytes=int(nbytes),
-                     transfer_seconds=seconds)
+                     transfer_seconds=busy,
+                     transfer_copy_seconds=seconds)
     _TRANSFER_HIST.observe(int(nbytes))
 
 

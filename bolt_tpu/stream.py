@@ -10,7 +10,8 @@ of device-side stages (per-record maps, chunked maps, stacked maps, a
 trailing filter predicate), and :func:`execute` runs a reduction terminal
 over it as a pipelined fan-in:
 
-* an **N-way uploader pool** (default ``min(mesh devices, 4)``;
+* an **N-way uploader pool** (default ``min(max(mesh devices, 2), 4)``:
+  two copies in flight even on ONE device's link;
   ``BOLT_STREAM_UPLOAD_THREADS`` / the :func:`uploaders` scope) ingests
   slabs concurrently — for random-access ``fromcallback`` sources each
   worker produces AND uploads its own slab (per-device sub-blocks via
@@ -45,7 +46,9 @@ materialised results cannot drift semantically — the out-of-core parity
 suite (``tests/test_stream.py``) bit-compares them.
 
 Accounting lands in the engine counters (``transfer_bytes`` /
-``transfer_seconds`` for every counted upload, the ``stream_*`` family
+``transfer_seconds`` for every counted upload — the seconds are the
+link's busy time, counted once where the pool's copies overlap, with
+each copy's own in ``transfer_copy_seconds`` — and the ``stream_*`` family
 for the executor — including ``stream_upload_threads``, the observed
 concurrent-uploader high-water, and ``stream_inflight_high_water``, the
 async dispatch window's peak).  Ingest/compute seconds are attributed
@@ -142,12 +145,23 @@ from bolt_tpu.utils import iter_record_blocks, prod
 _DEPTH = max(1, int(os.environ.get("BOLT_STREAM_DEPTH", "2")))
 
 # uploader pool size: concurrent ingest workers.  0 = auto, resolved per
-# run as min(mesh device count, 4) — one host thread cannot saturate the
-# link feeding many chips, but past ~4 workers the host memory bus is
-# the limit, not thread count.  Sequential (fromiter) sources always
-# stream through ONE produce+upload prefetch thread regardless.
+# run as min(max(mesh device count, _LINK_COPIES), 4) — one host thread
+# cannot saturate the link feeding many chips, but past ~4 workers the
+# host memory bus is the limit, not thread count.  Sequential (fromiter)
+# sources always stream through ONE produce+upload prefetch thread
+# regardless.
 _UPLOADERS = max(0, int(os.environ.get("BOLT_STREAM_UPLOAD_THREADS",
                                        "0")))
+
+# copies in flight ONE device's link wants: the auto rule's floor, so a
+# one-device mesh does not run its link one copy at a time, with none in
+# flight while its only worker is in Python between two.  MEASURED, not
+# assumed (PERF.md section 5, PR 35; scripts/h2d_probe.py on a v5e host,
+# raw device_put + block_until_ready of 64 MiB views, GB/s in aggregate,
+# medians of six): 1 in flight 9.04, 2: 14.07, 3: 14.09, 4: 14.10 (128
+# MiB: 9.44, 14.03, 13.98, 14.05; one thread keeping N puts issued reads
+# the same as N threads).  2 is the smallest count within 2 % of the best.
+_LINK_COPIES = 2
 
 # the prefetch()/uploaders() SCOPES are thread-local (like
 # engine.donation and bolt.precision): under the multi-tenant serving
@@ -209,7 +223,7 @@ def prefetch(depth):
 def upload_threads():
     """The configured uploader-pool size for the calling thread
     (innermost :func:`uploaders` scope, else the process default;
-    0 = auto: resolved per run as ``min(mesh devices, 4)``)."""
+    0 = auto: resolved per run by :func:`pool_size`)."""
     st = _scope_stack("uploaders")
     if st:
         return st[-1]
@@ -218,7 +232,8 @@ def upload_threads():
 
 def set_upload_threads(n):
     """Set the process-wide DEFAULT uploader-pool size (0 restores
-    auto); per-thread :func:`uploaders` scopes override it."""
+    auto, :func:`pool_size`'s ``min(max(mesh devices, 2), 4)``);
+    per-thread :func:`uploaders` scopes override it."""
     global _UPLOADERS
     _UPLOADERS = max(0, int(n))
 
@@ -490,16 +505,18 @@ def swap_ring(source):
 
 def pool_size(source):
     """The uploader-pool size a run over ``source`` will use: the
-    calling thread's configured count (scope/env), else ``min(mesh
-    devices, 4)``; sequential ``fromiter`` sources always use ONE
-    prefetch thread (their iterator cannot be consumed concurrently)."""
+    calling thread's configured count (scope/env), else ``min(max(mesh
+    devices, _LINK_COPIES), 4)``: a worker a device as before, and never
+    fewer than the copies one link wants in flight; sequential
+    ``fromiter`` sources always use ONE prefetch thread (their iterator
+    cannot be consumed concurrently)."""
     if source.kind != "callback":
         return 1
     n = upload_threads()
     if n >= 1:
         return n
     ndev = int(source.mesh.devices.size) if source.mesh is not None else 1
-    return min(max(ndev, 1), 4)
+    return min(max(ndev, _LINK_COPIES), 4)
 
 
 def _cached_jit(key, builder):
@@ -1895,7 +1912,13 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     # (the in-flight window sync) — so ring memory stays capped even
     # though dispatch is async.
     ring = depth + nwork
-    window = ring - 1          # one slot always free for the dispenser
+    # the consumer confirms (and hands permits back) once MORE than
+    # `depth` slabs are dispatched and unconfirmed, so a slot stays free
+    # for EVERY worker's hand.  `ring - 1`, the same number for one
+    # worker, gave permits back a pair at a time and only with the ring
+    # full: a pool of any size then ran two workers, started together
+    # (PERF.md section 5, PR 35)
+    window = ring - nwork
     permits = threading.Semaphore(ring)
     stop = threading.Event()
     rsq = _Reseq()

@@ -304,6 +304,7 @@ _EXPECTED_ENGINE_KEYS = {
     "diagnostics": False,
     "strict_checks": False, "strict_rejections": False,
     "transfer_bytes": False, "transfer_seconds": True,
+    "transfer_copy_seconds": True,
     "stream_chunks": False, "stream_ingest_seconds": True,
     "stream_compute_seconds": True, "stream_wall_seconds": True,
     "stream_overlap_seconds": True, "stream_prefetch_depth": False,

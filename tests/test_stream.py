@@ -568,16 +568,32 @@ def test_blt105_device_put_rule():
 # re-sequencing, the async in-flight window, and pool fault paths
 # ---------------------------------------------------------------------
 
-def test_uploaders_scope_and_pool_size(mesh):
+def _submesh(ndev):
+    return jax.sharding.Mesh(np.array(jax.devices()[:ndev]), ("k",))
+
+
+@pytest.mark.parametrize("ndev", [1, 2, 4, 8])
+def test_uploaders_scope_and_pool_size(ndev):
+    # the auto rule: a worker a device up to four, and never fewer than
+    # the copies ONE device's link wants in flight (stream._LINK_COPIES,
+    # measured: PERF.md section 5, PR 35), a one-device mesh included
+    mesh = _submesh(ndev)
     data = _intdata()
     src = _source(data, mesh, 4)._stream
     before = stream.upload_threads()
     try:
         stream.set_upload_threads(0)            # auto
-        assert stream.pool_size(src) == min(len(mesh.devices.ravel()), 4)
+        assert 2 <= stream._LINK_COPIES <= 4
+        assert stream.pool_size(src) == min(max(ndev, stream._LINK_COPIES),
+                                            4)
+        # the ring and the resident/spill plan read the same count
+        assert stream.swap_ring(src) == (stream.prefetch_depth()
+                                         + stream.pool_size(src))
         with stream.uploaders(7):
             assert stream.upload_threads() == 7
             assert stream.pool_size(src) == 7
+        with stream.uploaders(1):               # the override keeps
+            assert stream.pool_size(src) == 1   # its meaning
         assert stream.upload_threads() == 0
         stream.set_upload_threads(2)
         assert stream.pool_size(src) == 2
@@ -585,8 +601,193 @@ def test_uploaders_scope_and_pool_size(mesh):
         it = bolt.fromiter([data], SHAPE, mesh, dtype=np.float64)._stream
         with stream.uploaders(6):
             assert stream.pool_size(it) == 1
+        stream.set_upload_threads(0)
+        assert stream.pool_size(it) == 1
     finally:
         stream.set_upload_threads(before)
+
+
+def _meeting_loader(data, parties):
+    """A loader that returns only once ``parties`` callers are inside it
+    together (or 20 s have passed: an odd tail proceeds alone)."""
+    bar = threading.Barrier(parties, timeout=20)
+
+    def loader(idx):
+        try:
+            bar.wait()
+        except threading.BrokenBarrierError:
+            pass
+        return data[idx]
+    return loader
+
+
+@pytest.mark.parametrize("terminal", ["fold", "swap"])
+def test_one_device_auto_pool_engages_and_is_bit_identical(terminal):
+    # on a ONE-device mesh the auto rule runs _LINK_COPIES workers (the
+    # counter says so) and the answer is the one-worker answer bit for
+    # bit: slabs are re-sequenced, so the fold order does not change
+    mesh = _submesh(1)
+    k = stream._LINK_COPIES
+    rng = np.random.default_rng(35)
+    data = rng.standard_normal((4 * k,) + SHAPE[1:])    # 4k slabs of 1:
+    #                                  float sums would tell a reordering
+
+    def run(loader):
+        src = bolt.fromcallback(loader, data.shape, mesh,
+                                dtype=data.dtype, chunks=1)
+        if terminal == "fold":
+            return np.asarray(src.map(ADD1).sum().toarray())
+        return np.asarray(src.swap((0,), (0,)).toarray())
+
+    before = stream.upload_threads()
+    try:
+        stream.set_upload_threads(0)
+        with stream.uploaders(1):
+            engine.reset_counters()
+            want = run(lambda idx: data[idx])
+            c1 = engine.counters()
+        assert c1["stream_upload_threads"] == 1
+        assert c1["transfer_copy_seconds"] == pytest.approx(
+            c1["transfer_seconds"], rel=1e-9)
+        engine.reset_counters()
+        got = run(_meeting_loader(data, k))     # k workers must meet
+        ck = engine.counters()
+    finally:
+        stream.set_upload_threads(before)
+    assert ck["stream_upload_threads"] == k
+    assert ck["stream_chunks"] == c1["stream_chunks"] == 4 * k
+    assert ck["transfer_bytes"] == c1["transfer_bytes"]
+    assert ck["transfer_seconds"] <= ck["transfer_copy_seconds"]
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+    if terminal == "fold":
+        assert np.allclose(got, (data + 1.0).sum(axis=0))
+    else:
+        assert np.array_equal(got, np.moveaxis(data, 0, 1))
+
+
+def test_record_transfer_counts_the_link_once(monkeypatch):
+    # transfer_seconds is the time at least one counted copy was in
+    # flight (the union of the copies' intervals, each reported as it
+    # ends); transfer_copy_seconds is their sum.  Disjoint copies add the
+    # same to both, which is every run with one uploader
+    now = [0.0]
+    monkeypatch.setattr(engine, "_clock", lambda: now[0])
+    engine.reset_counters()
+
+    def copy(end, seconds, nbytes=8):
+        now[0] = end
+        engine.record_transfer(nbytes, seconds)
+        c = engine.counters()
+        return c["transfer_seconds"], c["transfer_copy_seconds"]
+
+    assert copy(10.0, 4.0) == (4.0, 4.0)            # [6, 10)
+    assert copy(12.0, 5.0) == (6.0, 9.0)            # [7, 12): 2 fresh
+    assert copy(20.0, 3.0) == (9.0, 12.0)           # [17, 20): disjoint
+    assert copy(25.0, 2.0) == (11.0, 14.0)          # [23, 25): disjoint
+    # a short copy that ends first, then the long one that held it
+    assert copy(31.0, 1.0) == (12.0, 15.0)          # [30, 31)
+    assert copy(33.0, 5.0) == (16.0, 20.0)          # [28, 33): 4 fresh
+    # one that bridges two counted stretches and the gap between them
+    assert copy(34.0, 10.0) == (20.0, 30.0)         # [24, 34): 25-28, 33-34
+    assert engine.counters()["transfer_bytes"] == 56
+    engine.reset_counters()                         # forgets the stretches
+    assert copy(34.0, 10.0) == (10.0, 10.0)
+    engine.reset_counters()
+
+
+def test_ring_keeps_the_whole_pool_at_work():
+    # the consumer hands permits back once MORE than the prefetch depth
+    # are dispatched and unconfirmed, so every worker finds a slab to
+    # take; a window of ring - 1 gave them back two at a time with the
+    # ring full, and a pool of any size ran two workers (PR 35).  A
+    # count, not a timing: the loader notes how many callers are inside
+    # it together, past the first ring's worth of slabs
+    mesh = _submesh(1)
+    nwork, nslabs = 4, 32
+    data = np.arange(nslabs * 8, dtype=np.float64).reshape(nslabs, 4, 2)
+    lock = threading.Lock()
+    inside = {"n": 0, "late_hw": 0}
+
+    def loader(idx):
+        lo = idx[0].start
+        with lock:
+            inside["n"] += 1
+            if lo >= 2 + nwork:                 # past the first ring
+                inside["late_hw"] = max(inside["late_hw"], inside["n"])
+        time.sleep(0.02)                        # an upload's worth
+        with lock:
+            inside["n"] -= 1
+        return data[idx]
+
+    with stream.prefetch(2), stream.uploaders(nwork):
+        got = np.asarray(bolt.fromcallback(
+            loader, data.shape, mesh, dtype=data.dtype,
+            chunks=1).sum().toarray())
+    assert np.array_equal(got, data.sum(axis=0))
+    assert inside["late_hw"] >= 3
+
+
+def test_record_transfer_union_under_many_reporters():
+    # more reporters than cores, a short switch interval: the link's busy
+    # time can never pass the wall the reports were made in, whatever
+    # order they land in, and no copy's bytes or seconds are lost
+    import sys
+    nthreads, each, span = 32, 200, 0.004
+    engine.reset_counters()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        gate = threading.Barrier(nthreads + 1, timeout=20)
+
+        def report():
+            gate.wait()
+            for _ in range(each):
+                engine.record_transfer(3, span)
+
+        pool = [threading.Thread(target=report, daemon=True)
+                for _ in range(nthreads)]
+        for t in pool:
+            t.start()
+        t0 = engine._clock() - span         # the first report reaches back
+        gate.wait()
+        for t in pool:
+            t.join(30)
+        wall = engine._clock() - t0
+        assert not any(t.is_alive() for t in pool)
+    finally:
+        sys.setswitchinterval(old)
+    c = engine.counters()
+    engine.reset_counters()
+    assert c["transfer_bytes"] == 3 * nthreads * each
+    assert c["transfer_copy_seconds"] == pytest.approx(
+        span * nthreads * each, rel=1e-9)
+    assert span <= c["transfer_seconds"] <= wall + 1e-6
+
+
+def test_upload_workers_busy_reads_counters_every_engine_keeps():
+    # the benchmark's metric of the pool (PR 35) is plain arithmetic on
+    # two counters that were there before it, so a program without the
+    # wider pool reads it too (at most 1.0 with one worker)
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics",
+        "upload_workers_busy.json")
+    with open(path) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "counter_ratio"
+    assert spec["args"] == {"num": ["stream_ingest_seconds"],
+                            "den": ["stream_wall_seconds"]}
+    mesh = _submesh(1)
+    data = _intdata()
+    with stream.uploaders(1):
+        c0 = engine.counters()
+        _source(data, mesh, 2).map(ADD1).sum().toarray()
+        c1 = engine.counters()
+    busy = ((c1["stream_ingest_seconds"] - c0["stream_ingest_seconds"])
+            / (c1["stream_wall_seconds"] - c0["stream_wall_seconds"]))
+    assert 0.0 < busy <= 1.0
 
 
 def test_stream_concurrent_uploaders_counted(mesh):
@@ -594,16 +795,8 @@ def test_stream_concurrent_uploaders_counted(mesh):
     # a 2-party barrier, so two pool threads must be mid-ingest together
     # before either can finish — the counter records that high-water
     data = _intdata()
-    bar = threading.Barrier(2, timeout=20)
-
-    def loader(idx):
-        try:
-            bar.wait()
-        except threading.BrokenBarrierError:
-            pass                                # odd tail: proceed alone
-        return data[idx]
-
-    src = bolt.fromcallback(loader, SHAPE, mesh, dtype=np.float64,
+    src = bolt.fromcallback(_meeting_loader(data, 2), SHAPE, mesh,
+                            dtype=np.float64,
                             chunks=4)           # 4 slabs, pool >= 2
     c0 = engine.counters()
     with stream.uploaders(2):
