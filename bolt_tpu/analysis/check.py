@@ -216,6 +216,33 @@ def _admission_budget():
     return arb.budget if arb is not None else None
 
 
+def _note_blocks(arr, base, funcs, idx, diags):
+    """``BLT018`` / ``BLT019``: what the rule of ``tpu/blocks.py`` says
+    of this chain on this device, from the SAME function the lowering
+    asks (``BoltArrayTPU._block_plan``), so the forecast and the program
+    cannot disagree: a run of maps with record-sized temporaries (a
+    sort, an FFT, a scan) that the device cannot hold for every record
+    at once is lowered over blocks of whole records; one whose single
+    record does not fit is refused here, in words, before XLA is asked."""
+    try:
+        marked = arr._block_plan(base, funcs)
+    except MemoryError as exc:
+        diags.append(Diagnostic(
+            "BLT019", idx, str(exc),
+            hint="shorten the records (chunk the value axis) or free "
+                 "device memory; no block of whole records can fit"))
+        return
+    if marked is not funcs:
+        plan = marked[-1]
+        diags.append(Diagnostic(
+            "BLT018", idx,
+            "blocked: %d blocks of %d records (a record of this chain "
+            "keeps record-sized temporaries, and all %s records at once "
+            "would not fit what the device has left)"
+            % (plan.blocks, plan.block_records,
+               " + ".join(str(r[0]) for r in plan.runs if r))))
+
+
 def _note_admission(est, idx, diags):
     """``BLT010``: the pipeline's MINIMUM device working set — the
     floor it can degrade to under budget pressure (one slab for
@@ -778,6 +805,7 @@ def _check_impl(obj):
                                 _spec(mesh, aval.shape, 1), dynamic=True))
 
     if not failed:
+        _note_blocks(arr, base, funcs, len(funcs), diags)
         _note_admission(
             int(base.nbytes)
             + prod(tuple(stages[-1].shape))

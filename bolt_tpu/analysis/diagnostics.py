@@ -50,6 +50,12 @@ CODES = {
     "BLT017": ("info",
                "streamed shuffle plan: the swap re-buckets slab by "
                "slab, resident in HBM or spilled past the budget"),
+    "BLT018": ("info",
+               "record-blocked map chain: a record function with "
+               "record-sized temporaries runs over blocks of records"),
+    "BLT019": ("error",
+               "one record of the map chain holds more live than the "
+               "device has left"),
 }
 
 SEVERITIES = ("error", "warning", "info")

@@ -192,6 +192,14 @@ _SCHEMA = {
                                   # sum-like terminal over a table of
                                   # thin records, for one TPU device (0
                                   # on the CPU)
+    # the record-blocked lowering of a deferred map chain (tpu/blocks.py:
+    # a record function with record-sized temporaries, a sort, an FFT,
+    # over an array too large to hold them for every record at once)
+    "map_blocks": 0,              # blocks of records RUN: every dispatch
+                                  # of a blocked program adds its count
+    "blocked_chains": 0,          # programs LOWERED with a run of maps
+                                  # over blocks (traced, so counted once
+                                  # a program, not once a call)
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -400,8 +408,11 @@ def counters():
 
 def reset_counters():
     """Zero the counters, and drop the compile log with them (its rows
-    are stamped with the ``dispatches`` count)."""
-    _COUNTERS.reset()
+    are stamped with the ``dispatches`` count).  ``import_seconds`` is a
+    fact of the process (the package is imported once, before anything
+    can be reset) and not a tally of work since the last reset: it
+    stays."""
+    _COUNTERS.reset(keep=("import_seconds",))
     with _LOCK:
         _COMPILE_LOG.clear()
         del _LINK_BUSY[:]
@@ -699,6 +710,19 @@ def record_fold_kernel_program():
     stored table of at most eight 32-bit values a record.  Per call the
     record is the device trace (``thin_fold*`` events)."""
     _COUNTERS.add("fold_kernel_programs")
+
+
+def record_map_blocks(n):
+    """A program with a record-blocked run of maps in it is about to be
+    dispatched and runs ``n`` blocks (``tpu/array.py ::
+    BoltArrayTPU._blocked``)."""
+    _COUNTERS.add("map_blocks", n)
+
+
+def record_blocked_chain():
+    """A program was traced with a run of maps lowered over blocks of
+    records (``tpu/array.py :: _chain_apply_blocked``)."""
+    _COUNTERS.add("blocked_chains")
 
 
 def donation_granted():

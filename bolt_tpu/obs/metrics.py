@@ -265,9 +265,12 @@ class CounterGroup:
         with self._lock:
             return dict(self._vals)
 
-    def reset(self):
+    def reset(self, keep=()):
+        """Back to the schema's initial values, but for the keys of
+        ``keep`` (facts of the process, which no reset undoes)."""
         with self._lock:
-            self._vals = dict(self._schema)
+            kept = {k: self._vals[k] for k in keep}
+            self._vals = dict(self._schema, **kept)
 
 
 class Registry:

@@ -1,11 +1,31 @@
-"""Per-record time-series transforms: detrend, z-score.
+"""Per-record time-series transforms: normalize (dF/F), detrend, z-score,
+center, cross-correlation, the Fourier tuning map.
 
 The reference ecosystem's TimeSeries workloads (Thunder: records keyed by
 pixel/channel, values = a time axis) detrend and standardise every record
 before analysis.  Here each transform is a traceable per-record ``map`` —
-it DEFERS like any map and fuses into the next action, so
-``zscore(detrend(b)).stats()`` is one compiled pass over HBM.  Both
-backends run the same math (NumPy locally — the oracle).
+it DEFERS like any map and fuses into the next action.  Both backends run
+the same math (NumPy locally — the oracle).
+
+What that costs on the device depends on what a record's function keeps:
+
+* ``detrend``, ``zscore``, ``center``, ``crosscorr`` and
+  ``normalize(baseline="mean")`` are element-wise work and reductions
+  within a record: XLA fuses them, so ``zscore(detrend(b)).stats()`` is
+  one compiled pass over HBM with no temporary (compiled for the v5e,
+  ``detrend -> sum`` over 10.74 GB takes none).
+* ``normalize(baseline="percentile")`` sorts each record and ``fourier``
+  transforms it: a sort and an FFT keep record-sized temporaries that XLA
+  does not fuse away.  Over a small array that changes nothing; over an
+  array too large to hold them for every record at once (a resident
+  series array of HBM size asks for 20 and 40 GB) the consuming program
+  runs the chain over BLOCKS of whole records, chosen by a rule and not
+  by the caller (``bolt_tpu/tpu/blocks.py``; ``analysis.explain`` says
+  "blocked: n blocks of r records").  Still ONE program an action, but
+  not one pass over HBM: the sort alone is many (PERF.md, PR 36).
+* ``fourier`` returns two deferred arrays over one deferred parent, so
+  fetching both runs the parent's chain twice (two programs): known, and
+  measured by the ``pixelseries512-1chip.tuning`` cell, not repaired.
 
 Polynomial detrending is two thin matmuls per record against the
 precomputed Vandermonde ``A`` and its pseudo-inverse (``v - A @

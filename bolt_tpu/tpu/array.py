@@ -53,7 +53,9 @@ from bolt_tpu import engine as _engine
 from bolt_tpu import stream as _streamlib
 from bolt_tpu.base import BoltArray, HostFallbackWarning
 from bolt_tpu.obs import trace as _obs
-from bolt_tpu.parallel.sharding import key_sharding
+from bolt_tpu._compat import shard_map as _shard_map
+from bolt_tpu.parallel.sharding import key_sharding, key_spec, spec_names
+from bolt_tpu.tpu import blocks as _blocks
 from bolt_tpu.tpu import fold as _fold
 from bolt_tpu.utils import (argpack, check_value_shape as _check_value_shape,
                             inshape, isreshapeable, istransposeable, prod,
@@ -520,13 +522,153 @@ def _reduce_tree_expr(data, func, funcs, split, n, vshape, keepdims):
     return out
 
 
+class _Blocked(NamedTuple):
+    """The LAST entry of a chain's ``funcs`` as a consumer holds them
+    (:meth:`BoltArrayTPU._chain_parts`), when the rule of
+    ``bolt_tpu/tpu/blocks.py`` says that a run of its maps cannot be
+    lowered over the whole array at once: ``runs`` has one entry for
+    each run of maps (the stretches between getitem windows), ``None``
+    for a run that lowers as ever and ``(records, block)`` for one that
+    :func:`_chain_apply` lowers over blocks of ``block`` whole records,
+    inside the consumer's own program.  Static and hashed by value, so
+    every engine key that holds the ``funcs`` tells a blocked program
+    from an unblocked one; a chain whose record functions fuse carries
+    no such entry and its keys and programs are what they always were.
+
+    On a mesh of several devices ``mesh`` is that mesh: the blocks are
+    taken inside each device's shard of the keys (the loop under
+    ``shard_map``, no communication), and ``records`` counts the records
+    of ONE shard.  ``None`` on one device."""
+
+    runs: tuple
+    mesh: object = None
+
+    @property
+    def blocks(self):
+        """Blocks one dispatch runs."""
+        return sum(-(-r[0] // r[1]) for r in self.runs if r)
+
+    @property
+    def block_records(self):
+        """Records of the largest block."""
+        return max(r[1] for r in self.runs if r)
+
+
+def _chain_runs(funcs):
+    """``funcs`` (no :class:`_Blocked` among them) as the sequence
+    :func:`_chain_apply` walks: each :class:`_Window` alone, each stretch
+    of maps between windows as one tuple."""
+    out, run = [], []
+    for func in funcs:
+        if type(func) is _Window:
+            if run:
+                out.append(tuple(run))
+                run = []
+            out.append(func)
+        else:
+            run.append(func)
+    if run:
+        out.append(tuple(run))
+    return out
+
+
+def _record_fn(run):
+    """The maps of one run as ONE function of a record (and its key
+    indices, which only ``with_keys`` entries read)."""
+    def one(v, *k):
+        for func in run:
+            if isinstance(func, _WithKeysFunc):
+                v = func.func((tuple(k), v))
+            else:
+                v = func(v)
+        return v
+    return one
+
+
+def _key_shards(mesh, shape, split):
+    """Into how many shards the key sharding cuts each key axis of an
+    array of ``shape``, and the mesh axes that do it: ``[(count,
+    names)]``."""
+    out = []
+    for entry in tuple(key_spec(mesh, shape, split))[:split]:
+        names = spec_names(entry)
+        out.append((prod([mesh.shape[n] for n in names]), names))
+    return out
+
+
+def _sharded_blocked_run(run, split, x, block, mesh):
+    """:func:`_blocked_run` inside each device's shard of the keys: the
+    loop under ``shard_map`` over the key sharding, so a block is a
+    block of one shard and nothing crosses between devices.  A
+    ``with_keys`` map is handed the GLOBAL key indices (the shard's
+    place along each mesh axis, times the shard's extent)."""
+    shards = _key_shards(mesh, x.shape, split)
+    local = tuple(k // c for k, (c, _) in zip(x.shape[:split], shards))
+
+    def per_shard(part):
+        offsets = []
+        for extent, (_, names) in zip(local, shards):
+            at = jnp.int32(0)
+            for name in names:
+                at = at * mesh.shape[name] + jax.lax.axis_index(name)
+            offsets.append(at * extent)
+        return _blocked_run(run, split, part, block, tuple(offsets))
+
+    spec = key_spec(mesh, x.shape, split)
+    keys = jax.sharding.PartitionSpec(*tuple(spec)[:split])
+    return _shard_map(per_shard, mesh, in_specs=spec, out_specs=keys,
+                      check_vma=False)(x)
+
+
+def _blocked_run(run, split, x, block, offsets=None):
+    """The maps of ``run`` over ``x`` in blocks of ``block`` whole
+    records: a loop inside the program, whose temporaries are a block's
+    and not the array's.  Each record's value is what the nested ``vmap``
+    gives it (a record's arithmetic does not read its neighbours); where
+    the count does not divide, the last block starts early and rewrites a
+    few records with the values they already have.  ``offsets``: what to
+    add to each key index a ``with_keys`` map is handed (a shard's place
+    in the whole array)."""
+    kshape = x.shape[:split]
+    n = prod(kshape)
+    block = min(block, n)
+    flat = x.reshape((n,) + x.shape[split:])
+    one = _record_fn(run)
+    keyed = any(isinstance(f, _WithKeysFunc) for f in run)
+
+    def piece(start):
+        rows = jax.lax.dynamic_slice_in_dim(flat, start, block, axis=0)
+        if not keyed:
+            return jax.vmap(one)(rows)
+        keys = jnp.unravel_index(
+            start + jnp.arange(block, dtype=jnp.int32), kshape)
+        if offsets is not None:
+            keys = tuple(k + o for k, o in zip(keys, offsets))
+        return jax.vmap(one)(rows, *keys)
+
+    aval = jax.eval_shape(piece, jax.ShapeDtypeStruct((), jnp.int32))
+
+    def body(i, out):
+        start = jnp.minimum(i.astype(jnp.int32) * block, n - block)
+        return jax.lax.dynamic_update_slice_in_dim(out, piece(start), start,
+                                                   axis=0)
+
+    out = jax.lax.fori_loop(0, -(-n // block), body,
+                            jnp.zeros((n,) + aval.shape[1:], aval.dtype))
+    return out.reshape(kshape + out.shape[1:])
+
+
 def _chain_apply(funcs, split, data):
     """Apply a deferred map chain: each func nested-vmapped over the
     ``split`` leading key axes, in order; ``with_keys`` entries vmap
     over flattened records zipped with their (traced, int32 — matching
     the shape-inference avals) key tuples; :class:`_Window` entries
     slice in place.  ``split`` is the split of the chain's RESULT: a
-    window that takes an integer on a key axis lowers it on the way."""
+    window that takes an integer on a key axis lowers it on the way.
+    A trailing :class:`_Blocked` names the runs of maps that are lowered
+    over blocks of records instead (:func:`_blocked_run`)."""
+    if funcs and type(funcs[-1]) is _Blocked:
+        return _chain_apply_blocked(funcs[:-1], split, data, funcs[-1])
     out = data
     split += sum(w.kdrop for w in _windows(funcs))
     for func in funcs:
@@ -552,6 +694,70 @@ def _chain_apply(funcs, split, data):
             f = jax.vmap(f)
         out = f(out)
     return out
+
+
+def _chain_apply_blocked(funcs, split, data, marker):
+    """:func:`_chain_apply` for a chain some of whose runs of maps are
+    lowered over blocks, as its :class:`_Blocked` ``marker`` says."""
+    _engine.record_blocked_chain()
+    out = data
+    split += sum(w.kdrop for w in _windows(funcs))
+    plan = iter(marker.runs)
+    for part in _chain_runs(funcs):
+        if type(part) is _Window:
+            out = part.apply(out)
+            split -= part.kdrop
+            continue
+        blocked = next(plan)
+        if blocked is None:
+            out = _chain_apply(part, split, out)
+        elif marker.mesh is None:
+            out = _blocked_run(part, split, out, blocked[1])
+        else:
+            out = _sharded_blocked_run(part, split, out, blocked[1],
+                                       marker.mesh)
+    return out
+
+
+def _plan_blocks(funcs, split, shape, dtype, free, mesh=None):
+    """The rule of ``bolt_tpu/tpu/blocks.py`` over a chain: ``funcs``
+    with a trailing :class:`_Blocked` where a run of its maps has to be
+    lowered over blocks on a device with ``free`` bytes left beside (its
+    shard of) the base and the result, else ``funcs`` as they are.
+    ``mesh``: the mesh of several devices the keys are sharded over (a
+    device then holds, and blocks, its shard's records).  Raises
+    :class:`MemoryError` where one record's temporaries do not fit."""
+    runs = []
+    aval = jax.ShapeDtypeStruct(tuple(shape), dtype)
+    at = split + sum(w.kdrop for w in _windows(funcs))
+    for part in _chain_runs(funcs):
+        if type(part) is _Window:
+            aval = jax.eval_shape(part.apply, aval)
+            at -= part.kdrop
+            continue
+        records = prod(aval.shape[:at])
+        if mesh is not None:
+            records //= prod([c for c, _ in
+                              _key_shards(mesh, aval.shape, at)])
+        rec = (jax.ShapeDtypeStruct(aval.shape[at:], aval.dtype),) \
+            + (jax.ShapeDtypeStruct((), jnp.int32),) * at
+        is_heavy, live, out = _blocks.record_live_bytes(_record_fn(part),
+                                                        rec)
+        block = _blocks.block_records(records, live, free) \
+            if is_heavy else None
+        runs.append(None if block is None else (records, block))
+        aval = jax.ShapeDtypeStruct(aval.shape[:at] + out.shape, out.dtype)
+    return funcs + (_Blocked(tuple(runs), mesh),) if any(runs) else funcs
+
+
+def _span_funcs(funcs):
+    """What a span says of the chain its program lowers: the count of
+    its maps and windows and, where :class:`_Blocked` rides with them,
+    the blocks a dispatch runs and the records of a block."""
+    if funcs and type(funcs[-1]) is _Blocked:
+        return {"funcs": len(funcs) - 1, "blocks": funcs[-1].blocks,
+                "block_records": funcs[-1].block_records}
+    return {"funcs": len(funcs)}
 
 
 def _pred_mask(pred, flat):
@@ -1140,6 +1346,7 @@ class BoltArrayTPU(BoltArray):
             # XLA aliases them — one buffer instead of two)
             donate = _chain_donate_ok(self._chain)
             base, funcs = self._chain
+            funcs = self._blocked(base, funcs)
             mesh, split = self._mesh, self._split
 
             def build():
@@ -1149,8 +1356,8 @@ class BoltArrayTPU(BoltArray):
 
             fn = _cached_jit(("chain", funcs, base.shape, str(base.dtype),
                               split, donate, mesh), build)
-            with _obs.span("array.chain", funcs=len(funcs),
-                           donate=donate, bytes=int(base.nbytes)):
+            with _obs.span("array.chain", donate=donate,
+                           bytes=int(base.nbytes), **_span_funcs(funcs)):
                 self._concrete = fn(_check_live(base))
             self._chain = None
             if donate:
@@ -1169,7 +1376,49 @@ class BoltArrayTPU(BoltArray):
         wins = consume and _windows(self._chain[1])
         if wins:
             _engine.record_getitems_fused(len(wins))
-        return self._chain
+        if not consume:
+            return self._chain
+        base, funcs = self._chain
+        return base, self._blocked(base, funcs)
+
+    def _blocked(self, base, funcs):
+        """:meth:`_block_plan` for a program that is about to be
+        dispatched: its blocks are counted (``map_blocks``)."""
+        marked = self._block_plan(base, funcs)
+        if marked is not funcs:
+            _engine.record_map_blocks(marked[-1].blocks)
+        return marked
+
+    def _block_plan(self, base, funcs):
+        """``funcs`` as the program that lowers them takes them: with a
+        trailing :class:`_Blocked` where the rule of ``tpu/blocks.py``
+        says that a run of these maps holds more live than the device
+        has left beside ``base`` and the chain's result (each counted
+        whole: a blocked run writes its result out).  Where there is no
+        limit (off the TPU) and for a chain that fuses it is ``funcs``
+        itself, and nothing about the program changes.  On a mesh of
+        several devices the bytes are a device's shard's.  Judged once
+        per chain, geometry and limit (the eval cache, whose lock makes
+        it safe under ``bolt_tpu.serve``'s threads): a dispatch pays a
+        dictionary look-up."""
+        limit = _hbm_limit()
+        if limit is None or not funcs:
+            return funcs
+        aval, mesh, split = self._aval, self._mesh, self._split
+
+        def judge():
+            held = prod(base.sharding.shard_shape(base.shape)) \
+                * base.dtype.itemsize
+            made = prod(key_sharding(mesh, aval.shape, split).shard_shape(
+                tuple(aval.shape))) * np.dtype(aval.dtype).itemsize
+            planned = _plan_blocks(funcs, split, base.shape, base.dtype,
+                                   int(limit - held - made),
+                                   mesh if mesh.size > 1 else None)
+            return planned[-1] if planned is not funcs else False
+        marker = _cached_eval_shape(
+            ("blocks", funcs, split, base.shape, base.dtype, base.sharding,
+             aval.shape, aval.dtype, limit, mesh), judge)
+        return funcs + (marker,) if marker else funcs
 
     def _adopt_materialised(self, data):
         """Adopt ``data`` as this deferred chain's materialised result —
@@ -1510,7 +1759,7 @@ class BoltArrayTPU(BoltArray):
 
         fn = _cached_jit(("reduce", func, funcs, base.shape, str(base.dtype),
                           split, keepdims, donate, mesh), build)
-        with _obs.span("array.reduce", funcs=len(funcs), donate=donate):
+        with _obs.span("array.reduce", donate=donate, **_span_funcs(funcs)):
             out = self._wrap(fn(_check_live(base)), new_split)
         if donate:
             aligned._consume_donated("reduce()")
@@ -1580,8 +1829,8 @@ class BoltArrayTPU(BoltArray):
 
         fn = _cached_jit(("stat", name, funcs, base.shape, str(base.dtype),
                           split, axes, keepdims, ddof, donate, mesh), build)
-        with _obs.span("array.stat", op=name, funcs=len(funcs),
-                       donate=donate):
+        with _obs.span("array.stat", op=name, donate=donate,
+                       **_span_funcs(funcs)):
             out = self._wrap(fn(_check_live(base)), new_split)
         if donate:
             self._consume_donated("%s()" % name)

@@ -265,7 +265,7 @@ class _StatGroup:
         the lazy door refuses donating chains), same traced pairwise
         tree (`array._reduce_tree_expr`)."""
         from bolt_tpu.tpu.array import _check_live, _constrain, \
-            _reduce_tree_expr
+            _reduce_tree_expr, _span_funcs
         m = self.members[0]
         func = self.rfunc
         base, funcs, split, mesh = (self.base, self.funcs, self.split,
@@ -288,12 +288,12 @@ class _StatGroup:
         fn = _cached_jit(("reduce", func, funcs, base.shape,
                           str(base.dtype), split, keepdims, False, mesh),
                          build)
-        with _obs.span("array.reduce", funcs=len(funcs), donate=False):
+        with _obs.span("array.reduce", donate=False, **_span_funcs(funcs)):
             m.result = fn(_check_live(base))
 
     def _resolve_chain(self, mode):
         from bolt_tpu.tpu.array import _check_live, _chain_apply, \
-            _constrain
+            _constrain, _span_funcs
         members = self.members
         base, funcs, split, mesh = (self.base, self.funcs, self.split,
                                     self.mesh)
@@ -319,8 +319,8 @@ class _StatGroup:
             fn = _cached_jit(("stat", m.name, funcs, base.shape,
                               str(base.dtype), split, m.axes, m.keepdims,
                               m.ddof, donate, mesh), build)
-            with _obs.span("array.stat", op=m.name, funcs=len(funcs),
-                           donate=donate):
+            with _obs.span("array.stat", op=m.name, donate=donate,
+                           **_span_funcs(funcs)):
                 m.result = fn(_check_live(base))
             return
 
@@ -343,8 +343,8 @@ class _StatGroup:
                           str(base.dtype), split, donate, mode, mesh),
                          build)
         with _obs.span("array.multi_stat", terminals=len(members),
-                       slots=len(slots), funcs=len(funcs),
-                       donate=donate, accumulate=mode or "exact"):
+                       slots=len(slots), donate=donate,
+                       accumulate=mode or "exact", **_span_funcs(funcs)):
             outs = fn(_check_live(base))
         if len(members) > 1:
             _engine.record_fused_stats(len(members))

@@ -315,6 +315,7 @@ _EXPECTED_ENGINE_KEYS = {
     "getitems_fused": False, "resplit_views": False,
     "gram_kernel_programs": False, "gram_sums_programs": False,
     "fold_kernel_programs": False,
+    "map_blocks": False, "blocked_chains": False,
     "filters_fused": False, "filter_compactions": False,
     "coalesced_builds": False, "coalesced_compiles": False,
     "batched_dispatches": False, "batched_requests": False,

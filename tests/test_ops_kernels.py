@@ -1195,3 +1195,65 @@ def test_place_program_aliases_its_output_on_v5e(v5e_device, frames):
                                   (1, 2, 0), 2, mesh, frames,
                                   int(16.9e9), None, ring=3)
     assert not double.resident
+
+
+# ---------------------------------------------------------------------
+# compile-only: the per-pixel series analysis that follows toseries
+# (configuration pixelseries512-1chip, PR 36): ops.normalize -> detrend
+# -> fourier over the resident (512, 512, 10240) float32 series array.
+# A sort and an FFT keep record-sized temporaries; over the whole array
+# at once they are array-sized and the chip refuses the program, which
+# is why tpu/blocks.py exists.  Lowered over the blocks the rule gives,
+# the temporaries are a block's
+# ---------------------------------------------------------------------
+
+_PIXELS = (512, 512, 10240)
+_V5E_HBM = int(15.75 * 2 ** 30)        # the chip's memory_stats() limit
+
+
+def _tuning_chain():
+    from bolt_tpu.ops import series
+    return (series._normalize_fn("percentile", 20.0, 0, 0.0),
+            series._detrend_fn(10240, 5, 0), series._fourier_fn(16, 0, 0.0),
+            series._pick_fn(0, 0))
+
+
+def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
+        v5e_device):
+    import jax
+    from bolt_tpu.tpu.array import _Blocked, _chain_apply, _plan_blocks
+    funcs = _tuning_chain()
+    base = 4 * int(np.prod(_PIXELS))
+    free = _V5E_HBM - base - 4 * 512 * 512
+    planned = _plan_blocks(funcs, 2, _PIXELS, np.float32, free)
+    marker = planned[-1]
+    assert type(marker) is _Blocked and planned[:-1] == funcs
+    (records, block), = marker.runs
+    assert records == 512 * 512 and 1024 <= block <= 16384
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    arg = jax.ShapeDtypeStruct(_PIXELS, _F32, sharding=where)
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda d: _chain_apply(planned, 2, d)).lower(arg).compile()
+        mem = compiled.memory_analysis()
+        # the base, a 1 MB map, and a block's temporaries: under the
+        # quarter of what is left that the rule allows them
+        assert mem.argument_size_in_bytes == base
+        assert mem.temp_size_in_bytes < 2e9
+        assert mem.temp_size_in_bytes < 0.25 * free
+        # and the rule's own estimate covers what the compiler needs
+        assert mem.temp_size_in_bytes < _blocked_estimate(funcs, block)
+        with pytest.raises(Exception, match="Exceeded hbm capacity|hbm"):
+            jax.jit(lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
+
+
+def _blocked_estimate(funcs, block):
+    import jax
+    from bolt_tpu.tpu import blocks
+    from bolt_tpu.tpu.array import _record_fn
+    rec = (jax.ShapeDtypeStruct(_PIXELS[2:], np.float32),
+           jax.ShapeDtypeStruct((), np.int32),
+           jax.ShapeDtypeStruct((), np.int32))
+    heavy, live, _ = blocks.record_live_bytes(_record_fn(funcs), rec)
+    assert heavy
+    return live * block
