@@ -59,6 +59,25 @@ def _func_label(func):
     return "map(%s)" % _name(func)
 
 
+def _percentile_note(func, split, aval):
+    """How an ``ops.normalize(baseline="percentile")`` stage takes its
+    baseline over records of ``aval``: from the SAME function the
+    lowering asks (``ops/select.py :: regime``), so the forecast and the
+    program cannot disagree.  Empty for any other stage."""
+    ax = getattr(func, "percentile_axis", None)
+    if ax is None:
+        return ""
+    from bolt_tpu.ops import select
+    length = aval.shape[split + ax]
+    # the dtype the stage itself promotes to
+    dtype = jax.numpy.promote_types(aval.dtype, np.float32)
+    if select.regime(length, dtype) == "select":
+        return ("percentile by selection: two exact order statistics of "
+                "%d values found bit by bit, no sort" % length)
+    return "percentile by sort: %d values a record is under the %d " \
+        "from which it is selected" % (length, select.select_from(dtype))
+
+
 def _kdrop(funcs):
     """Key axes the getitem windows among ``funcs`` remove."""
     from bolt_tpu.tpu.array import _windows
@@ -740,10 +759,11 @@ def _check_impl(obj):
                 hint="keep constants in the input dtype or cast back "
                      "with astype/map(dtype=...) if the widening is "
                      "unintended"))
+        note = _percentile_note(func, walk_split, aval)
         aval = nxt
         stages.append(Stage(i + 1, label, aval.shape, np.dtype(aval.dtype),
                             walk_split, _spec(mesh, aval.shape,
-                                              walk_split)))
+                                              walk_split), note=note))
         idle_seen = _idle_device_check(mesh, aval.shape, walk_split,
                                        i + 1, diags, idle_seen)
 

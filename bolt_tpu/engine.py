@@ -200,6 +200,13 @@ _SCHEMA = {
     "blocked_chains": 0,          # programs LOWERED with a run of maps
                                   # over blocks (traced, so counted once
                                   # a program, not once a call)
+    # how a per-record percentile was taken (ops/select.py): by exact
+    # selection at and above a length, by jnp.percentile's sort below it.
+    # Bumped where the record function is TRACED, so a count says which
+    # regime the programs of a chain took, not how often they ran (the
+    # blocks rule and the shape inference trace the function too)
+    "percentile_select_lowerings": 0,
+    "percentile_sort_lowerings": 0,
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -723,6 +730,13 @@ def record_blocked_chain():
     """A program was traced with a run of maps lowered over blocks of
     records (``tpu/array.py :: _chain_apply_blocked``)."""
     _COUNTERS.add("blocked_chains")
+
+
+def record_percentile_lowering(regime):
+    """A record function was traced with a percentile taken by
+    ``regime`` (``"select"`` or ``"sort"``: ``ops/select.py ::
+    percentile``)."""
+    _COUNTERS.add("percentile_%s_lowerings" % regime)
 
 
 def donation_granted():

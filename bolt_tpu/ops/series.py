@@ -14,15 +14,22 @@ What that costs on the device depends on what a record's function keeps:
   within a record: XLA fuses them, so ``zscore(detrend(b)).stats()`` is
   one compiled pass over HBM with no temporary (compiled for the v5e,
   ``detrend -> sum`` over 10.74 GB takes none).
-* ``normalize(baseline="percentile")`` sorts each record and ``fourier``
-  transforms it: a sort and an FFT keep record-sized temporaries that XLA
-  does not fuse away.  Over a small array that changes nothing; over an
-  array too large to hold them for every record at once (a resident
-  series array of HBM size asks for 20 and 40 GB) the consuming program
-  runs the chain over BLOCKS of whole records, chosen by a rule and not
-  by the caller (``bolt_tpu/tpu/blocks.py``; ``analysis.explain`` says
-  "blocked: n blocks of r records").  Still ONE program an action, but
-  not one pass over HBM: the sort alone is many (PERF.md, PR 36).
+* ``normalize(baseline="percentile")`` takes two order statistics of each
+  record and ``fourier`` transforms it.  The order statistics are SELECTED
+  (``ops/select.py``: the k-th smallest built bit by bit, some eighteen
+  counting passes over the record and its image of integer keys, exact)
+  from a length on; a shorter record is sorted by ``jnp.percentile``, to
+  the same answer to the bit.  A loop of passes, a sort and an FFT keep
+  record-sized temporaries that XLA does not fuse away.  Over a small
+  array that changes nothing; over an array too large to hold them for
+  every record at once (a resident series array of HBM size asks for 20
+  and 40 GB) the consuming program runs the chain over BLOCKS of whole
+  records, chosen by a rule and not by the caller
+  (``bolt_tpu/tpu/blocks.py``; ``analysis.explain`` says "blocked: n
+  blocks of r records", and of a ``normalize`` stage "percentile by
+  selection" or "by sort").  Still ONE program an action, but not one
+  pass over HBM: the selection alone reads a block some twenty times
+  (PERF.md, PR 36 and PR 37).
 * ``fourier`` returns two deferred arrays over one deferred parent, so
   fetching both runs the parent's chain twice (two programs): known, and
   measured by the ``pixelseries512-1chip.tuning`` cell, not repaired.
@@ -39,6 +46,7 @@ import numpy as np
 import jax.numpy as jnp
 
 from bolt_tpu._precision import resolve as _resolve
+from bolt_tpu.ops import select as _select
 
 
 def _value_axis(b, axis):
@@ -280,7 +288,13 @@ def normalize(b, baseline="percentile", perc=20.0, axis=0, epsilon=0.0):
 
     ``baseline``: ``'percentile'`` (the ``perc``-th per-record
     percentile, default 20 — a robust resting level) or ``'mean'``.
-    A deferred map on either backend.
+    A deferred map on either backend.  The percentile is NumPy's: linear
+    interpolation between two exact order statistics.  Locally
+    ``np.percentile``; on the device the two are selected without
+    sorting the record (``ops/select.py``) where it is long enough for
+    that to pay, and ``jnp.percentile`` sorts a shorter one: the same
+    value to the bit either way (engine counters
+    ``percentile_select_lowerings`` / ``percentile_sort_lowerings``).
     """
     if baseline not in ("percentile", "mean"):
         raise ValueError(
@@ -299,7 +313,9 @@ def _normalize_fn(baseline, perc, ax, epsilon):
         dt = xp.promote_types(v.dtype, xp.float32)
         vf = v.astype(dt)
         if baseline == "percentile":
-            base = xp.percentile(vf, perc, axis=ax, keepdims=True)
+            # NumPy's own locally (the oracle); selected on the device
+            take = np.percentile if xp is np else _select.percentile
+            base = take(vf, perc, axis=ax, keepdims=True)
         else:
             base = xp.mean(vf, axis=ax, keepdims=True)
         # sign-aware guard: the baseline is SIGNED (e.g. after detrend),
@@ -307,4 +323,7 @@ def _normalize_fn(baseline, perc, ax, epsilon):
         # push it away from zero instead (zero itself goes to +epsilon)
         denom = xp.where(base >= 0, base + epsilon, base - epsilon)
         return (vf - base) / denom
+    if baseline == "percentile":
+        # what analysis.explain reads to say how the baseline is taken
+        f.percentile_axis = ax
     return f

@@ -4,11 +4,14 @@
 program.  A record function that XLA fuses into an element-wise loop (every
 ``v + 1``, a product of two columns, a corner mean) then costs no
 temporaries at all; one with a record-sized temporary that is NOT fused away
-(a sort behind ``jnp.percentile``, an FFT, a cumulative sum) costs
-temporaries as large as the ARRAY, and a resident array over a third of HBM
-can take no such function.  Compiled for the v5e at ``(512, 512, 10240)``
-float32 (10.74 GB): ``normalize(percentile)`` asks for 20.00 G of 15.75 G,
-``fourier`` for 40.00 G (PERF.md, PR 36).
+(a sort, an FFT, a cumulative sum, the loop of counting passes by which
+``ops.normalize`` selects a percentile) costs temporaries as large as the
+ARRAY, and a resident array over a third of HBM can take no such function.
+Compiled for the v5e at ``(512, 512, 10240)`` float32 (10.74 GB): a
+percentile by ``jnp.percentile``'s sort asks for 20.00 G of 15.75 G,
+``fourier`` for 40.00 G (PERF.md, PR 36; since PR 37 the percentile of a
+record that long is selected, and holds the record's image of keys where
+the sort held its copy).
 
 The rule, static and with no knob.  The runs of maps of a chain (between
 its getitem windows) are traced ONCE, on ONE record's aval, to a jaxpr:
@@ -45,8 +48,9 @@ from jax.extend.core import Literal
 
 # primitives that end a loop fusion and keep a result (and scratch) as
 # large as their operand: sorts (jnp.sort/argsort/percentile/median),
-# FFTs, cumulative scans, top-k, loops that carry per-record state, and
-# the dense decompositions
+# FFTs, cumulative scans, top-k, loops that carry per-record state (the
+# passes of ops/select.py's percentile among them), and the dense
+# decompositions
 HEAVY = frozenset([
     "sort", "fft", "cumsum", "cumprod", "cummax", "cummin", "cumlogsumexp",
     "top_k", "approx_top_k", "while", "scan",

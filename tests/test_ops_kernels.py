@@ -1,6 +1,8 @@
 """Pallas kernel tests (interpret mode on the CPU mesh, and compile-only
 for the described v5e): correctness against NumPy and the fallbacks."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -1243,6 +1245,15 @@ def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
         assert mem.temp_size_in_bytes < 0.25 * free
         # and the rule's own estimate covers what the compiler needs
         assert mem.temp_size_in_bytes < _blocked_estimate(funcs, block)
+        # the percentile is selected, not sorted (PR 37): no sort in the
+        # optimised program, and the passes are a loop of their own
+        # inside the loop over blocks
+        text = compiled.as_text()
+        assert not re.search(r"\bsort(\.\d+)? = |= \S+ sort\(", text)
+        loops = re.findall(r" while\(.*?op_name=\"([^\"]*)\"", text)
+        assert len(loops) == 2
+        assert any(name.endswith("percentile_select)/while")
+                   and name.count("while") == 2 for name in loops)
         with pytest.raises(Exception, match="Exceeded hbm capacity|hbm"):
             jax.jit(lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
 

@@ -514,3 +514,129 @@ def test_the_chain_span_carries_blocks_and_the_counters_reset(tight,
     assert c["map_blocks"] == 0 and c["blocked_chains"] == 0
     # a fact of the process, not a tally since the last reset
     assert c["import_seconds"] == imported
+
+
+# ---------------------------------------------------------------------
+# (d) the percentile by SELECTION (ops/select.py, PR 37) through the
+# lowerings that carry it: series long enough to be selected and not
+# sorted (T raised over the crossover for these cases only)
+# ---------------------------------------------------------------------
+
+from bolt_tpu.ops import select  # noqa: E402
+
+LONG = 2 * select._SELECT_FROM
+_LONG_OPS = [
+    ("normalize-percentile", lambda b: ops.normalize(b, "percentile", PERC)),
+    ("tuning-coherence", lambda b: tuning(b)[0]),
+]
+
+
+def percentile_lowerings():
+    c = engine.counters()
+    return (c["percentile_select_lowerings"], c["percentile_sort_lowerings"])
+
+
+def record_live(arr):
+    """What the rule says one record of the deferred ``arr``'s chain
+    (maps alone) holds live."""
+    base, funcs = arr._chain
+    rec = (jax.ShapeDtypeStruct(base.shape[arr.split:], base.dtype),) \
+        + (jax.ShapeDtypeStruct((), np.int32),) * arr.split
+    return blocks.record_live_bytes(tpu_array._record_fn(funcs), rec)[1]
+
+
+@pytest.mark.parametrize("name,call", _LONG_OPS,
+                         ids=[c[0] for c in _LONG_OPS])
+@pytest.mark.parametrize("shape,fits,blocks_are", [
+    ((6, 4, LONG), 4, "even"),          # 24 records in blocks of 4
+    ((7, 5, LONG), 4, "tail"),          # 35 records: a tail block of 3
+    ((3, 4, LONG), 1, "ones")],         # blocks of one record
+    ids=["even", "tail", "ones"])
+def test_a_selected_percentile_is_the_same_blocked(tight, one_device, name,
+                                                   call, shape, fits,
+                                                   blocks_are):
+    x = sessions(shape)
+    make = lambda: call(bolt.array(x, one_device, axis=(0, 1)))
+    arr = make()
+    # what the device has "left": a quarter of it holds ``fits`` records
+    free = int((4 * fits + 2) * record_live(arr))
+    tight(arr, free)
+    records, block = arr._block_plan(*arr._chain)[-1].runs[0]
+    tpu_array._HBM_LIMIT_OVERRIDE = None
+    assert records == prod(shape[:2]) and block == fits
+    assert bool(records % block) == (blocks_are == "tail")
+    select_before, sort_before = percentile_lowerings()
+    whole, blocked, ran = both_ways(tight, make, lambda a: a.toarray(),
+                                    free=free)
+    assert ran == -(-records // block) and ran > 1
+    # selection on both lowerings, and never the sort
+    select_after, sort_after = percentile_lowerings()
+    assert select_after > select_before and sort_after == sort_before
+    assert whole.dtype == blocked.dtype
+    if blocks_are == "ones" and name != "normalize-percentile":
+        # one-row matrix products (the detrend) take another kernel on
+        # the CPU: float32 rounding, as PR 36's item 6 found
+        assert np.allclose(whole, blocked, rtol=0, atol=4e-6)
+    else:
+        assert np.array_equal(whole, blocked)
+    if name == "normalize-percentile":
+        x64 = x.astype(np.float64)
+        base = np.percentile(x64, PERC, axis=-1, keepdims=True)
+        assert np.max(np.abs(blocked - (x64 - base) / base)) < 1e-6
+
+
+@pytest.mark.parametrize("axis", [(0,), (0, 1)], ids=["split1", "split2"])
+def test_a_selected_percentile_on_four_devices(tight, four_devices, axis):
+    x = sessions((16, 4, LONG))
+    ax = 1 if len(axis) == 1 else 0
+
+    def make():
+        b = bolt.array(x, four_devices, axis=axis)
+        dff = ops.detrend(ops.normalize(b, "percentile", PERC, axis=ax),
+                          order=ORDER, axis=ax)
+        return ops.fourier(dff, freq=FREQ, axis=ax)[0]
+    # a shard's 16 records (split1: 4) in blocks of two
+    free = int(10 * record_live(make()))
+    whole, blocked, ran = both_ways(tight, make, lambda a: a.toarray(),
+                                    free=free)
+    assert ran > 1 and np.array_equal(whole, blocked)
+    want_coh, _ = tuning_reference(x)
+    assert np.max(np.abs(blocked - want_coh)) < 2e-5
+
+
+def test_the_selection_is_heavy_and_its_estimate_covers_the_compiler():
+    fn = ops.series._normalize_fn("percentile", PERC, 0, 0.0)
+    aval = jax.ShapeDtypeStruct((LONG,), np.float32)
+    heavy, live, out = blocks.record_live_bytes(fn, (aval,))
+    # the loop of passes keeps the chain among those lowered over blocks
+    assert heavy and out.shape == (LONG,)
+    # the record, its image of keys, and the result at the least
+    assert live >= 3 * LONG * 4
+    rows = 64
+    compiled = jax.jit(jax.vmap(fn)).lower(
+        jax.ShapeDtypeStruct((rows, LONG), np.float32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes <= rows * live
+    text = compiled.as_text()
+    assert "percentile_select" in text and " sort(" not in text
+
+
+@pytest.mark.parametrize("t,took", [(select._SELECT_FROM - 1, "sort"),
+                                    (select._SELECT_FROM, "select")],
+                         ids=["under", "at"])
+def test_the_counters_and_explain_name_the_regime(one_device, t, took):
+    x = sessions((4, 3, t))
+    arr = ops.normalize(bolt.array(x, one_device, axis=(0, 1)),
+                        "percentile", PERC)
+    said = analysis.explain(arr)
+    assert ("percentile by selection" in said) == (took == "select")
+    assert ("percentile by sort" in said) == (took == "sort")
+    select_before, sort_before = percentile_lowerings()
+    arr.toarray()
+    select_after, sort_after = percentile_lowerings()
+    assert (select_after > select_before) == (took == "select")
+    assert (sort_after > sort_before) == (took == "sort")
+    # a mean baseline takes no percentile at all
+    mean = ops.normalize(bolt.array(x, one_device, axis=(0, 1)), "mean")
+    assert "percentile" not in analysis.explain(mean)
+    mean.toarray()
+    assert percentile_lowerings() == (select_after, sort_after)
