@@ -89,8 +89,9 @@ def _stage_eval(func, split, aval):
     ``_chain_apply`` the compiled program runs, so the prediction cannot
     drift from execution.  Results are memoised in the array module's
     eval cache (keyed on func identity + input aval)."""
-    from bolt_tpu.tpu.array import _cached_eval_shape, _chain_apply
-    key = ("analysis-stage", func, split, tuple(aval.shape),
+    from bolt_tpu.tpu.array import (_cached_eval_shape, _chain_apply,
+                                    _func_key)
+    key = ("analysis-stage", _func_key(func), split, tuple(aval.shape),
            str(aval.dtype))
     return _cached_eval_shape(
         key, lambda: jax.eval_shape(
@@ -502,7 +503,50 @@ def _note_codec(src, idx, diags, members=()):
              "passes, and the arbiter leases the wire bytes"))
 
 
-def _note_shuffle(src, stage, aval, split, mesh, idx, diags):
+def _note_collect(src, idx, diags):
+    """``BLT020``: what taking this mapped streamed result WHOLE
+    (``toarray`` / ``tojax`` / ``cache``) will do, by the rules the run
+    itself decides by (``stream.collect_refusal``, then
+    ``stream.materialize_bytes`` against the device's memory): collected
+    slab by slab, materialised with the base uploaded whole, or refused.
+    Reduction terminals stream whatever this says."""
+    from bolt_tpu import stream as _stream
+    from bolt_tpu.tpu.array import _hbm_limit
+    why = _stream.collect_refusal(src)
+    if why is None:
+        plan = _stream.collect_plan(src)
+        diags.append(Diagnostic(
+            "BLT020", idx,
+            "taken whole, this result is collected slab by slab: %d "
+            "slab%s through the uploader pool, the stages in the slab "
+            "program, each slab's records placed into the %s result (%s "
+            "working set with %d slabs in flight, budget %s)"
+            % (plan.nslabs, "s" if plan.nslabs != 1 else "",
+               _fmt_bytes(plan.total_bytes),
+               _fmt_bytes(plan.resident_bytes), plan.ring,
+               _fmt_bytes(plan.budget) if plan.budget is not None
+               else "unbounded"),
+            hint="the base never lives whole on the device; bit-identical "
+                 "to materialising it (stream_collect_slabs / "
+                 "stream_collect_bytes engine counters)"))
+        return
+    need, limit = _stream.materialize_bytes(src), _hbm_limit()
+    refused = limit is not None and need > limit
+    diags.append(Diagnostic(
+        "BLT020", idx,
+        "taken whole, this result materialises, %s a device: the base "
+        "uploads whole, outside the uploader pool, and the stages replay "
+        "on the resident copy (not collected slab by slab: %s)%s"
+        % (_fmt_bytes(need), why,
+           " — the device holds %s: toarray / cache will refuse it at "
+           "dispatch with these words" % _fmt_bytes(limit)
+           if refused else ""),
+        severity="warning" if refused else "info",
+        hint="reduction terminals (sum/mean/var/std/reduce) stream it "
+             "slab by slab whatever its size"))
+
+
+def _note_shuffle(src, stage, aval, split, mesh, idx, diags, keyed=False):
     """``BLT017``: forecast the streamed shuffle (ISSUE 18) — the SAME
     planner the executor runs (``parallel.shuffle.plan_shuffle`` fed by
     ``stream.swap_budget()``/``spill_scope()``), so the forecast and
@@ -518,7 +562,8 @@ def _note_shuffle(src, stage, aval, split, mesh, idx, diags):
         plan = _shuffle.plan_shuffle(
             tuple(aval.shape), np.dtype(aval.dtype), split, perm,
             new_split, mesh, src.slab, _stream.swap_budget(mesh),
-            spill_dir, ring=_stream.swap_ring(src))
+            spill_dir, ring=_stream.swap_ring(src),
+            raw_slab_bytes=_stream._raw_slab_bytes(src))
     except ValueError as exc:
         diags.append(Diagnostic(
             "BLT017", idx,
@@ -539,6 +584,18 @@ def _note_shuffle(src, stage, aval, split, mesh, idx, diags):
             hint="raise the arbiter budget so the re-keyed buckets "
                  "stay resident, or materialise first (toarray) and "
                  "swap in memory"))
+        return
+    if not plan.resident and keyed:
+        diags.append(Diagnostic(
+            "BLT017", idx,
+            plan.describe() + " — but a with_keys map rides in front of "
+            "the swap, and the spill leg's program is not handed a "
+            "slab's first key: the executor will refuse this swap at "
+            "dispatch",
+            severity="warning",
+            hint="raise the budget so the re-keyed array stays "
+                 "resident, or materialise first (toarray) and swap in "
+                 "memory"))
         return
     if not plan.resident and plan.spill_dir is None:
         diags.append(Diagnostic(
@@ -1094,7 +1151,8 @@ def _check_stream(arr, target, stages, diags):
             # the shuffle forecast anchors on the PRE-swap geometry
             # (the planner's input), then the walk adopts the swapped
             # split for every later stage
-            _note_shuffle(src, stage, aval, walk_split, mesh, idx, diags)
+            _note_shuffle(src, stage, aval, walk_split, mesh, idx, diags,
+                          keyed=_stream.stage_extras(src.stages[:i])[0])
             walk_split = stage[2]
         old, new = np.dtype(aval.dtype), np.dtype(nxt.dtype)
         if new.itemsize > old.itemsize:
@@ -1111,6 +1169,10 @@ def _check_stream(arr, target, stages, diags):
                                               walk_split)))
         idle_seen = _idle_device_check(mesh, aval.shape, walk_split, idx,
                                        diags, idle_seen)
+    else:
+        # every stage traced: what taking the mapped result whole does
+        if src.stages and not _stream.has_swap(src):
+            _note_collect(src, len(src.stages), diags)
     return Report(target + ", streaming (out-of-core)", stages, diags,
                   dynamic=dynamic)
 

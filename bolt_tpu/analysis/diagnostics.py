@@ -56,6 +56,9 @@ CODES = {
     "BLT019": ("error",
                "one record of the map chain holds more live than the "
                "device has left"),
+    "BLT020": ("info",
+               "a mapped streamed result taken whole: collected slab by "
+               "slab, or materialised with its base uploaded whole"),
 }
 
 SEVERITIES = ("error", "warning", "info")

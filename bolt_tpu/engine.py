@@ -238,6 +238,14 @@ _SCHEMA = {
     "shuffle_bytes": 0,
     "spill_bytes": 0,
     "shuffle_seconds": 0.0,
+    # a mapped result COLLECTED slab by slab (stream.collect: the
+    # resolver's resident leg with no re-axis) and keyed stages
+    "stream_collect_slabs": 0,    # slabs placed into a collected result
+    "stream_collect_bytes": 0,    # bytes of result those slabs placed
+    "stream_keyed_slabs": 0,      # slabs whose program took the slab's
+                                  # first key as an operand (a with_keys
+                                  # map on a streamed source; 0 where it
+                                  # fell back to materialising)
 }
 
 _COUNTERS = _metrics.registry().group("engine", _SCHEMA)
@@ -858,6 +866,16 @@ def record_shuffle(nbytes, seconds):
     _COUNTERS.update(shuffle_bytes=int(nbytes), shuffle_seconds=seconds)
 
 
+def record_collect(slabs, nbytes):
+    """Tally one streamed collect (bolt_tpu.stream's resident leg run
+    with no re-axis): the slabs it placed and the bytes of result they
+    made.  One update a collect, at its end; the timeline carries it as
+    the ``stream.collect`` span and a ``stream.collect.place`` span a
+    slab."""
+    _COUNTERS.update(stream_collect_slabs=int(slabs),
+                     stream_collect_bytes=int(nbytes))
+
+
 def record_spill(nbytes):
     """Tally one spilled shuffle bucket's wire bytes
     (checkpoint.spill_save's return — dict-encoded when the slab's
@@ -884,7 +902,7 @@ def record_checkpoint(nbytes, seconds):
 
 
 def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
-                  uploaders=1, inflight=1):
+                  uploaders=1, inflight=1, keyed=0):
     """Tally one completed streamed run (bolt_tpu.stream executor); the
     keys apply atomically — a snapshot can never see a run's wall time
     without its overlap.  Called by the run's own thread as the run ends,
@@ -892,11 +910,13 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
     is the run's (``stream_compile_seconds``).  ``uploaders`` is the run's
     observed concurrent uploader high-water, ``inflight`` its
     dispatched-but-unconfirmed slab-program high-water; both (and the
-    depth) keep process maxima."""
+    depth) keep process maxima.  ``keyed``: of ``chunks``, the slabs
+    whose program took the slab's first key as an operand."""
     _COUNTERS.update(_maxima={"stream_prefetch_depth": int(depth),
                               "stream_upload_threads": int(uploaders),
                               "stream_inflight_high_water": int(inflight)},
                      stream_chunks=int(chunks),
+                     stream_keyed_slabs=int(keyed),
                      stream_ingest_seconds=ingest_s,
                      stream_compute_seconds=compute_s,
                      stream_wall_seconds=wall_s,

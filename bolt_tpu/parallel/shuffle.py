@@ -123,7 +123,7 @@ def _pick_out_block(extent, target_rows, mult):
 
 
 def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
-                 slab, budget, spill_dir, ring=1):
+                 slab, budget, spill_dir, ring=1, raw_slab_bytes=None):
     """Plan one streamed-swap resolution over the POST-pre-stage
     geometry.
 
@@ -134,8 +134,11 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     the input records per slab; ``budget`` the resident ceiling in
     bytes (``None`` = unbounded → always resident); ``spill_dir``
     where bucket files would land; ``ring`` the uploaded slabs the
-    executor keeps in flight (``stream.swap_ring``).  Raises the
-    pointed pod-geometry
+    executor keeps in flight (``stream.swap_ring``);
+    ``raw_slab_bytes`` what ONE of them holds as uploaded, where the
+    stages before the re-axis change a slab's size (a collect of small
+    mapped records rings slabs far larger than what it places).  Raises
+    the pointed pod-geometry
     errors HERE, before any thread starts, mirroring BLT012."""
     staged_shape = tuple(int(s) for s in staged_shape)
     perm = tuple(int(p) for p in perm)
@@ -187,7 +190,8 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     # once, every place program aliases it), the ring of uploaded slabs
     # and ONE place program's temp (the slab's transposed block)
     ring = max(1, int(ring))
-    resident_bytes = total_bytes + (ring + 1) * slab_bytes
+    resident_bytes = total_bytes + slab_bytes \
+        + ring * max(slab_bytes, int(raw_slab_bytes or 0))
     resident = budget is None or resident_bytes <= budget
     return ShufflePlan(
         in_shape=staged_shape, dtype=np.dtype(dtype), split=int(split),
@@ -237,7 +241,9 @@ def _program_key(tag, plan, pre_stages, mesh, codec_obj, raw_dtype,
     """Engine key of one per-slab program: (stages, slab geometry,
     perm, codec, topology) — uniform slabs compile exactly once per
     variant per process, the short last slab once more."""
-    return (tag, pre_stages, tuple(raw_slab_shape), str(raw_dtype),
+    from bolt_tpu.stream import stage_keys
+    return (tag, stage_keys(pre_stages), tuple(raw_slab_shape),
+            str(raw_dtype),
             plan.split, plan.perm, plan.new_split, mesh,
             _multihost.topology_token() if plan.sharded else None,
             codec_obj.name if codec_obj is not None else None)
@@ -252,7 +258,10 @@ def _rebucket_body(plan, pre_stages, mesh, codec_obj, raw_dtype,
 
     ``raw_slab_shape`` is the UPLOADED slab's shape (wire dtype under a
     codec); the result is that slab's transposed block: the full
-    new-key extent with the slab's records at axis ``plan.j0``.  On
+    new-key extent with the slab's records at axis ``plan.j0``.  The
+    body also takes the slab's first key (``key0``, for a keyed stage:
+    ``None`` where the caller has none to give) and the chain's side
+    operands (``stream.stage_extras`` order).  On
     pods the body runs under
     ``shard_map`` with ONE explicit ``lax.all_to_all`` per slab
     (``split_axis=0`` of the new layout, ``concat_axis=j0``, tiled) —
@@ -263,15 +272,16 @@ def _rebucket_body(plan, pre_stages, mesh, codec_obj, raw_dtype,
     j0 = plan.j0
     slab_rows = raw_slab_shape[0]
 
-    def body(data):
+    def body(data, key0=None, operands=()):
         if codec_obj is None:
             x = data
         elif codec_obj.sidecar:
             x = codec_obj.decode(data[0], data[1:], raw_dtype, delta_ok)
         else:
             x = codec_obj.decode(data, (), raw_dtype, delta_ok)
+        operands = iter(operands)
         for stg in pre_stages:
-            x = _stage_apply(stg, split, x)
+            x = _stage_apply(stg, split, x, key0, operands)
         return jnp.transpose(x, perm)
 
     if not plan.sharded:
@@ -309,9 +319,12 @@ def _rebucket_body(plan, pre_stages, mesh, codec_obj, raw_dtype,
     if not axes:
         # record axis stays leading: its sharding is unchanged
         out_entries[j0] = in_specs[0] if len(in_specs) else None
-    return _compat.shard_map(
+    mapped = _compat.shard_map(
         shard_body, mesh, in_specs=in_specs,
         out_specs=PartitionSpec(*out_entries), check_vma=False)
+    # a pod's stages carry neither a key nor operands (stream.map_stage
+    # records none there): the body's other two arguments are empty
+    return lambda data, key0=None, operands=(): mapped(data)
 
 
 def rebucket_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
@@ -329,8 +342,9 @@ def rebucket_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
         if plan.sharded:
             return jax.jit(body, donate_argnums=(0,))
 
-        def run(data):
-            return _constrain(body(data), mesh, plan.new_split)
+        def run(data, *operands):
+            return _constrain(body(data, None, operands), mesh,
+                              plan.new_split)
         return jax.jit(run, donate_argnums=(0,))
 
     return _engine.get(key, build)
@@ -363,7 +377,10 @@ def alloc_program(plan, mesh):
 def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
                   raw_slab_shape, delta_ok, unit):
     """The ONE compiled program each input slab of the RESIDENT leg
-    runs, ``(out, data, cursor) -> (out, cursor')``:
+    runs, ``(out, data, cursor, *operands) -> (out, cursor')``
+    (``operands``: the side operands of the stages before the re-axis,
+    none for most chains; a KEYED stage among them reads the slab's
+    first key off the same cursor the placement does):
     :func:`_rebucket_body` and the placement of its block into the
     swapped array at record ``cursor * unit`` along ``j0``, in one
     program.  ``out`` is donated and the result aliases it (the update
@@ -390,11 +407,17 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
         j0 = plan.j0
         step = -(-int(raw_slab_shape[0]) // unit)
 
-        def run(out, data, cursor):
+        from bolt_tpu.stream import stage_extras
+        keyed, _ = stage_extras(pre_stages)
+
+        def run(out, data, cursor, *operands):
             zero = jnp.zeros((), jnp.uint32)
-            at = tuple(cursor * jnp.uint32(unit) if i == j0 else zero
+            first = cursor * jnp.uint32(unit)
+            at = tuple(first if i == j0 else zero
                        for i in range(out.ndim))
-            out = jax.lax.dynamic_update_slice(out, body(data), at)
+            block = body(data, first.astype(jnp.int32) if keyed else None,
+                         operands)
+            out = jax.lax.dynamic_update_slice(out, block, at)
             return (_constrain(out, mesh, plan.new_split),
                     cursor + jnp.uint32(step))
         return jax.jit(run, donate_argnums=(0, 1))

@@ -808,14 +808,24 @@ def _slab_records(shape, dtype, chunks):
 # abstract stage interpretation (shared with bolt_tpu.analysis.check)
 # ---------------------------------------------------------------------
 
-def _stage_apply(stage, split, x):
+def _stage_apply(stage, split, x, key0=None, operands=None):
     """Apply ONE device-side stage to traced value ``x`` — the same
     bodies the materialised paths compile, so streamed and materialised
-    semantics cannot drift."""
+    semantics cannot drift.  ``key0``: the first key of the slab ``x``
+    is (an int32 scalar the program takes as an operand), which a KEYED
+    stage (a ``with_keys`` map) adds to its slab-local keys.
+    ``operands``: an iterator over the program's side operands
+    (:func:`stage_extras` order), of which a ``with_operands`` map takes
+    its share; without one the map's own arrays are bound."""
     kind = stage[0]
     if kind == "map":
-        from bolt_tpu.tpu.array import _chain_apply
-        return _chain_apply((stage[1],), split, x)
+        from bolt_tpu.tpu.array import (_bind_operands, _chain_apply,
+                                        _operands_of)
+        funcs = (stage[1],)
+        if operands is not None:
+            funcs = _bind_operands(
+                funcs, [next(operands) for _ in _operands_of(funcs)])
+        return _chain_apply(funcs, split, x, key0=key0)
     if kind == "chunk":
         from bolt_tpu.tpu.chunk import _general_map_body, _uniform_map_body
         _, func, plan, pad, canon = stage
@@ -846,6 +856,9 @@ def stage_label(stage):
         return getattr(f, "__name__", None) or type(f).__name__
     kind = stage[0]
     if kind == "map":
+        from bolt_tpu.tpu.array import _WithKeysFunc
+        if isinstance(stage[1], _WithKeysFunc):
+            return "map(%s, with_keys)" % _name(stage[1].func)
         return "map(%s)" % _name(stage[1])
     if kind == "chunk":
         return "chunk(plan=%s).map(%s)" % (tuple(stage[2]), _name(stage[1]))
@@ -866,12 +879,32 @@ def stage_aval(stage, split, aval):
         # pure axis permutation: the abstract result needs no trace
         return jax.ShapeDtypeStruct(
             tuple(aval.shape[p] for p in stage[1]), aval.dtype)
-    key = ("stream-stage", stage, split, tuple(aval.shape),
+    key = ("stream-stage", stage_keys((stage,)), split, tuple(aval.shape),
            str(aval.dtype))
     return _cached_eval_shape(
         key, lambda: jax.eval_shape(
             lambda d: _stage_apply(stage, split, d),
             jax.ShapeDtypeStruct(tuple(aval.shape), aval.dtype)))
+
+
+def stage_keys(stages):
+    """``stages`` as a program's engine key takes them: a map of a
+    ``utils.with_operands`` by its function and its operands' avals (the
+    program takes the arrays as arguments), every other stage itself."""
+    from bolt_tpu.tpu.array import _func_key
+    return tuple(("map", _func_key(s[1])) if s[0] == "map" else s
+                 for s in stages)
+
+
+def stage_extras(stages):
+    """``(keyed, operands)`` of a stage chain: whether a ``with_keys``
+    map rides in it (its slab program takes the slab's first key), and
+    the arrays its ``with_operands`` maps name, flat, in stage order
+    (the program's side operands)."""
+    from bolt_tpu.tpu.array import _WithKeysFunc, _operands_of
+    maps = tuple(s[1] for s in stages if s[0] == "map")
+    return (any(isinstance(f, _WithKeysFunc) for f in maps),
+            _operands_of(maps))
 
 
 class _ResultState:
@@ -925,9 +958,18 @@ def result_state(source):
 # ---------------------------------------------------------------------
 
 def map_stage(arr, func):
-    """Record a per-record map on a stream-backed array (lazy)."""
+    """Record a per-record map on a stream-backed array (lazy).  A
+    ``with_keys`` entry or one with side operands makes the slab program
+    take more than the slab; on a mesh of several processes, where that
+    program runs under ``shard_map`` per shard, such a stage is not
+    recorded (NotImplemented: the caller materialises, as before)."""
     from bolt_tpu.tpu.array import BoltArrayTPU
-    return BoltArrayTPU._streamed(arr._stream.with_stage(("map", func)))
+    src = arr._stream
+    stage = ("map", func)
+    if _multihost.mesh_process_count(src.mesh) > 1 \
+            and stage_extras((stage,)) != (False, ()):
+        return NotImplemented
+    return BoltArrayTPU._streamed(src.with_stage(stage))
 
 
 def filter_stage(arr, pred):
@@ -1315,8 +1357,10 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
                   and not sharded and split == 1
                   and mesh.devices.size == 1
                   and _codec_registry().kernel_enabled())
+    keyed, side = stage_extras(stages)
     key = ("stream-slab-acc" if fused else "stream-slab", terminal,
-           stages, pred, slab_shape, str(source.dtype), split, ddof,
+           stage_keys(stages), pred, slab_shape, str(source.dtype), split,
+           ddof,
            rfunc, comps, mesh,
            _multihost.topology_token() if sharded else None,
            codec_obj.name if codec_obj is not None else None,
@@ -1326,11 +1370,16 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
         axes = _multihost.key_collective_axes(mesh, slab_shape, split) \
             if sharded else None
 
-        def partial(data):
+        def partial(data, *extra):
             # under shard_map ``data`` is ONE device shard; standalone it
             # is the whole slab — the body is shape-polymorphic and the
-            # collective points in _terminal_partial close the gap
+            # collective points in _terminal_partial close the gap.
+            # ``extra``: the slab's first key (a keyed stage chain) then
+            # the side operands; empty for every other chain, whose
+            # program is the one it always was
             from bolt_tpu.tpu.array import _pred_mask
+            key0 = extra[0] if keyed else None
+            operands = iter(extra[1:] if keyed else extra)
             if codec_obj is None:
                 x = data
             else:
@@ -1350,7 +1399,7 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
                 else:
                     x = codec_obj.decode(data, (), raw_dtype, delta_ok)
             for stg in stages:
-                x = _stage_apply(stg, split, x)
+                x = _stage_apply(stg, split, x, key0, operands)
             vshape = x.shape[split:]
             n = prod(x.shape[:split])
             flat = x.reshape((n,) + vshape)
@@ -1385,10 +1434,10 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
         if not fused:
             return jax.jit(body, donate_argnums=(0,))
 
-        def run(data, acc):
+        def run(data, acc, *extra):
             # level-0 fold fused in: acc (the EVEN slab's partial) merges
             # with this (ODD) slab's partial inside one dispatch
-            return _combine(terminal, rfunc, acc, body(data),
+            return _combine(terminal, rfunc, acc, body(data, *extra),
                             comps=comps)
         return jax.jit(run, donate_argnums=(0, 1))
 
@@ -2194,6 +2243,9 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     _LAST_THREAD = lead
     _LAST_POOL = tuple(threads)
 
+    from bolt_tpu.tpu.array import _place_operands
+    keyed, side = stage_extras(source.stages)
+    side = _place_operands(side, mesh)      # once a run, not once a slab
     t_start = _clock()
     ingest = 0.0
     compute = 0.0
@@ -2375,13 +2427,20 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                               slab=start_slab + slab_i)
                                    if codec_obj is not None else None)
                             try:
+                                # a keyed chain's program takes the
+                                # slab's first key, then the side
+                                # operands; any other chain's nothing
+                                extra = side
+                                if keyed:
+                                    extra = (np.int32(slab_hi
+                                                      - wshape[0]),) + side
                                 if pend is None:
                                     prog = _slab_program(
                                         source, terminal, wshape, ddof,
                                         rfunc, comps=comps,
                                         sharded=mspec is not None,
                                         codec_obj=codec_obj)
-                                    pend = prog(buf)
+                                    pend = prog(buf, *extra)
                                     pend_bytes = slab_bytes
                                     pairp = None
                                 else:
@@ -2391,7 +2450,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                         rfunc, fused=True, comps=comps,
                                         sharded=mspec is not None,
                                         codec_obj=codec_obj)
-                                    pairp = prog(buf, pend)
+                                    pairp = prog(buf, pend, *extra)
                             finally:
                                 _obs.end(dsp)
                             if pairp is not None:
@@ -2535,7 +2594,8 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         overlap = max(0.0, ingest + compute - wall)
         _engine.record_stream(nslabs, ingest, compute, wall, overlap,
                               depth, uploaders=max(act["hw"], 1),
-                              inflight=max(inflight_hw, 1))
+                              inflight=max(inflight_hw, 1),
+                              keyed=nslabs if keyed else 0)
         if run_sp is not None:
             run_sp.set(slabs=nslabs, ingest_s=round(ingest, 6),
                        compute_s=round(compute, 6),
@@ -2595,13 +2655,10 @@ def materialize(source):
     recorded stage replays through the normal deferred/chunked/stacked
     paths — so a materialised stream is bit-identical to having never
     streamed at all.  Needs the full array to fit; streaming terminals
-    exist so it usually never runs."""
-    with _obs.span("stream.materialize", kind=source.kind,
-                   stages=len(source.stages)):
-        return _materialize_spans(source)
-
-
-def _materialize_spans(source):
+    exist so it usually never runs, and a mapped result that fits is
+    COLLECTED slab by slab instead (:func:`collect`), as a swap is
+    re-axed slab by slab: the ``stream.materialize`` span covers only
+    what uploads whole."""
     if has_swap(source):
         # the two-phase shuffle resolves the re-keying SLAB-WISE (the
         # input never lives whole next to the output); a resident
@@ -2611,8 +2668,117 @@ def _materialize_spans(source):
         if b._stream is None:
             return b
         source = b._stream
-    b = _materialize_base(source)
-    return _replay_stages(b, source.stages)
+    if collect_refusal(source) is None:
+        # a mapped result: slab by slab through the pool, the stages in
+        # the slab program, each slab's records placed into the result —
+        # the base never lives whole on the device
+        return _resolve_one_swap(source, collect=True)
+    with _obs.span("stream.materialize", kind=source.kind,
+                   stages=len(source.stages)):
+        materialize_check(source)
+        b = _materialize_base(source)
+        return _replay_stages(b, source.stages)
+
+
+def materialize_bytes(source):
+    """What :func:`materialize` holds on ONE device at its least: its
+    shard of the base uploaded whole, and of the staged result beside it
+    where stages replay (their temporaries are not counted)."""
+    st = result_state(source)
+    need = prod(source.shape) * source.dtype.itemsize
+    if source.stages:
+        need += st.n * prod(st.vshape) * st.dtype.itemsize
+    return need // max(int(source.mesh.devices.size), 1)
+
+
+def materialize_check(source):
+    """Refuse in bolt's words (BLT020, ``MemoryError``) a materialisation
+    the device cannot hold, before XLA is asked for the memory."""
+    from bolt_tpu.tpu.array import hbm_check
+    hbm_check("BLT020: materialising this streamed source",
+              materialize_bytes(source),
+              "the base uploaded whole%s; what cannot be collected slab "
+              "by slab has no other sink: reduce it (sum/mean/reduce "
+              "stream at any size) or map to smaller records"
+              % (" and the staged result beside it"
+                 if source.stages else ""))
+
+
+def collect_refusal(source):
+    """Why :func:`collect` cannot take ``source`` (the words it raises
+    with), or ``None`` where it can.  What it cannot take materialises:
+    a source with no stage (nothing is mapped: the array IS the base), a
+    dynamic (post-filter) row count, a lossy ingest codec (the
+    materialised path uploads the base unencoded), a mesh of several
+    processes, an empty result, a result that does not fit the resident
+    budget beside the slabs in flight (:func:`collect_plan`)."""
+    if has_swap(source):
+        return ("the source carries an unresolved swap: resolve_swaps "
+                "re-axes it slab by slab first")
+    if not source.stages:
+        return "the source has no stage: nothing is mapped"
+    st = result_state(source)
+    if st.dynamic:
+        return ("the result's row count is dynamic (a filter's survivor "
+                "count is not known until the predicate has run): a "
+                "slab's place in the result cannot be planned; reduce "
+                "the filtered source, or materialise it (toarray)")
+    if st.n == 0 or source.shape[0] == 0:
+        return "the result is empty"
+    if _multihost.mesh_process_count(source.mesh) > 1:
+        return "the mesh spans several processes"
+    codec_obj = resolve_codec(source)
+    if codec_obj is not None and not codec_obj.lossless:
+        return "the ingest codec %r is lossy" % (codec_obj.name,)
+    plan = collect_plan(source)
+    if not plan.resident:
+        return ("the mapped result and the slabs in flight (%.1f MiB) "
+                "exceed the resident budget (%.1f MiB)"
+                % (plan.resident_bytes / 2**20,
+                   (plan.budget or 0) / 2**20))
+    return None
+
+
+def collect_plan(source):
+    """The plan of :func:`collect` over ``source``: the resolver's own
+    (``parallel.shuffle.plan_shuffle``) with the identity for a re-axis,
+    judged against :func:`swap_budget` — the ONE rule ``analysis.check``
+    forecasts by (BLT020) and the run decides by."""
+    from bolt_tpu.parallel import shuffle as _shuffle
+    st = result_state(source)
+    nslabs = max(1, -(-source.shape[0] // max(source.slab, 1)))
+    return _shuffle.plan_shuffle(
+        st.shape, st.dtype, st.split, tuple(range(len(st.shape))),
+        st.split, source.mesh, source.slab, swap_budget(source.mesh),
+        None, ring=min(swap_ring(source), nslabs),
+        raw_slab_bytes=_raw_slab_bytes(source))
+
+
+def _raw_slab_bytes(source):
+    """Bytes of one slab of ``source`` as it is uploaded."""
+    codec_obj = resolve_codec(source)
+    item = (codec_obj.wire_dtype(source.dtype).itemsize
+            if codec_obj is not None else source.dtype.itemsize)
+    return min(source.slab, source.shape[0]) * prod(source.shape[1:]) * item
+
+
+def collect(source):
+    """The CONCRETE array of a mapped stream source, assembled slab by
+    slab: every slab goes up through the uploader pool, ONE program a
+    slab applies the recorded stages and places the slab's records into
+    the result, which exists once (the resolver's resident leg, run with
+    the identity for a re-axis: no second loop).  The base never lives
+    whole on the device, so a map whose RESULT fits is taken however
+    large its source.  Bit-identical to :func:`materialize`'s upload and
+    replay (the slab program traces the same stage bodies).  Refuses in
+    words what it cannot take (:func:`collect_refusal`), a result past
+    the resident budget among them (it has no other sink:
+    :func:`materialize` then says whether the device can hold the source
+    whole, BLT020)."""
+    why = collect_refusal(source)
+    if why is not None:
+        raise ValueError("stream.collect: %s" % why)
+    return _resolve_one_swap(source, collect=True)
 
 
 def _replay_stages(b, stages):
@@ -2623,7 +2789,10 @@ def _replay_stages(b, stages):
     for stage in stages:
         kind = stage[0]
         if kind == "map":
-            b = b.map(stage[1], axis=tuple(range(b.split)))
+            from bolt_tpu.tpu.array import _WithKeysFunc
+            keyed = isinstance(stage[1], _WithKeysFunc)
+            b = b.map(stage[1].func if keyed else stage[1],
+                      axis=tuple(range(b.split)), with_keys=keyed)
         elif kind == "chunk":
             from bolt_tpu.tpu.chunk import ChunkedArray
             _, func, plan, pad, canon = stage
@@ -2751,7 +2920,7 @@ def _owned_buckets(part, out_block):
     return sorted(owned)
 
 
-def _resolve_one_swap(source):
+def _resolve_one_swap(source, collect=False):
     """Resolve the FIRST recorded swap of ``source`` via the two-phase
     streaming shuffle (module docstring of
     ``bolt_tpu.parallel.shuffle``): phase 1 streams input slabs through
@@ -2764,17 +2933,26 @@ def _resolve_one_swap(source):
     per-slab program traces the same transpose and the same stage
     bodies.  Phase 1 is a streamed run like any other: its wall, ingest
     and overlap seconds land in the ``stream_*`` counters
-    (``engine.record_stream``) beside ``shuffle_bytes/_seconds``."""
+    (``engine.record_stream``) beside ``shuffle_bytes/_seconds``.
+
+    ``collect=True`` (:func:`collect`) runs the resident leg over a
+    source with NO swap: every stage is a stage before the re-axis, the
+    re-axis is the identity, and what the place programs assemble is the
+    mapped result itself (``stream_collect_*`` counters in place of the
+    shuffle's; the plan is :func:`collect_plan`'s and never spills)."""
     from bolt_tpu import checkpoint as _ckptlib
     from bolt_tpu.parallel import shuffle as _shuffle
-    from bolt_tpu.tpu.array import BoltArrayTPU
+    from bolt_tpu.tpu.array import BoltArrayTPU, _place_operands
     from bolt_tpu.utils import chain_retry_step
 
-    cut = next(k for k, s in enumerate(source.stages)
-               if s[0] == "swap")
-    pre = source.stages[:cut]
-    _, perm, new_split = source.stages[cut]
-    post = source.stages[cut + 1:]
+    if collect:
+        pre, post = source.stages, ()
+    else:
+        cut = next(k for k, s in enumerate(source.stages)
+                   if s[0] == "swap")
+        pre = source.stages[:cut]
+        _, perm, new_split = source.stages[cut]
+        post = source.stages[cut + 1:]
     base = StreamSource(source.kind, source.produce, source.blocks,
                         source.shape, source.split, source.dtype,
                         source.mesh, source.slab, pre,
@@ -2784,10 +2962,25 @@ def _resolve_one_swap(source):
     mesh = source.mesh
     split = source.split
     spill_dir, _ = spill_scope()
-    plan = _shuffle.plan_shuffle(st.shape, st.dtype, st.split, perm,
-                                 new_split, mesh, base.slab,
-                                 swap_budget(mesh), spill_dir,
-                                 ring=swap_ring(base))
+    keyed, side = stage_extras(pre)
+    if collect:
+        plan = collect_plan(base)         # resident: collect() asked
+        perm, new_split = plan.perm, plan.new_split
+    else:
+        plan = _shuffle.plan_shuffle(st.shape, st.dtype, st.split, perm,
+                                     new_split, mesh, base.slab,
+                                     swap_budget(mesh), spill_dir,
+                                     ring=swap_ring(base),
+                                     raw_slab_bytes=_raw_slab_bytes(base))
+    if keyed and not plan.resident:
+        raise RuntimeError(
+            "streamed swap: a with_keys map in front of a SPILLED "
+            "re-axis is not supported (the spill leg's program is not "
+            "handed a slab's first key) — raise the resident budget so "
+            "the re-keyed array stays resident (%.1f MiB needed, %.1f "
+            "MiB budget), or materialise first (toarray) and swap in "
+            "memory" % (plan.resident_bytes / 2**20,
+                        (plan.budget or 0) / 2**20))
     if not plan.resident and spill_dir is None:
         raise RuntimeError(
             "streamed swap: the re-keyed working set (%.1f MiB) "
@@ -2859,7 +3052,9 @@ def _resolve_one_swap(source):
     stop = threading.Event()
     rsq = _Reseq()
     jobq = queue.Queue()
-    run_sp = _obs.begin("stream.shuffle", resident=plan.resident,
+    side = _place_operands(side, mesh)      # once a run, not once a slab
+    run_sp = _obs.begin("stream.collect" if collect else "stream.shuffle",
+                        resident=plan.resident,
                         inplace=plan.resident, ring=ring,
                         slabs=plan.nslabs, buckets=plan.nbuckets,
                         out_block=plan.out_block,
@@ -3110,10 +3305,17 @@ def _resolve_one_swap(source):
                             if plan.resident:
                                 # slabs arrive re-sequenced, in key
                                 # order, and a resident run skips none
-                                out, cursor = prog(out, buf, cursor)
+                                psp = _obs.begin("stream.collect.place",
+                                                 slab=g) \
+                                    if collect else None
+                                try:
+                                    out, cursor = prog(out, buf, cursor,
+                                                       *side)
+                                finally:
+                                    _obs.end(psp)
                                 part = out
                             else:
-                                part = prog(buf)
+                                part = prog(buf, *side)
                         _pod_sync(part, pod, "shuffle re-bucket", slab=g)
                         break
                     except _podwatch.PeerLostError:
@@ -3146,7 +3348,10 @@ def _resolve_one_swap(source):
         if lease is not None:
             lease.close()
         wall = _clock() - t_start
-        _engine.record_shuffle(moved, wall)
+        if collect:
+            _engine.record_collect(placed, moved)
+        else:
+            _engine.record_shuffle(moved, wall)
         if run_sp is not None:
             run_sp.set(bytes=moved)
         _obs.end(run_sp)
@@ -3154,7 +3359,8 @@ def _resolve_one_swap(source):
     # streamed run reports (a spilled swap's phase 2 adds its own)
     _engine.record_stream(placed, ingest, compute, wall,
                           max(0.0, ingest + compute - wall), depth,
-                          uploaders=len(ingesters))
+                          uploaders=len(ingesters),
+                          keyed=placed if keyed else 0)
 
     if plan.resident:
         if not placed:

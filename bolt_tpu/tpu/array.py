@@ -59,7 +59,7 @@ from bolt_tpu.tpu import blocks as _blocks
 from bolt_tpu.tpu import fold as _fold
 from bolt_tpu.utils import (argpack, check_value_shape as _check_value_shape,
                             inshape, isreshapeable, istransposeable, prod,
-                            tupleize)
+                            tupleize, with_operands)
 
 # Compiled-executable cache keyed on (operation, user function, static
 # geometry): repeated calls with the same func/shape reuse the executable
@@ -424,6 +424,65 @@ class _WithKeysFunc:
         return type(other) is _WithKeysFunc and self.func == other.func
 
 
+def _func_key(func):
+    """What a program is keyed by for one chain entry: the entry itself,
+    but a :class:`~bolt_tpu.utils.with_operands` by its function and its
+    operands' avals (the program takes the arrays as arguments, so their
+    values are no part of it)."""
+    if isinstance(func, _WithKeysFunc):
+        inner = _func_key(func.func)
+        return func if inner is func.func else ("with_keys", inner)
+    if isinstance(func, with_operands):
+        return func.key()
+    return func
+
+
+def _funcs_key(funcs):
+    return tuple(_func_key(f) for f in funcs)
+
+
+def _operands_of(funcs):
+    """Every array the ``with_operands`` entries of ``funcs`` name, flat,
+    in chain order: the extra arguments of a program that lowers them."""
+    out = []
+    for func in funcs:
+        if isinstance(func, _WithKeysFunc):
+            func = func.func
+        if isinstance(func, with_operands):
+            out.extend(func.operands)
+    return tuple(out)
+
+
+def _bind_operands(funcs, flat):
+    """``funcs`` with each ``with_operands`` entry replaced by a plain
+    function over ITS share of ``flat`` (the program's traced arguments,
+    in :func:`_operands_of` order) — called while the program is traced,
+    so nothing of the entry's own arrays enters it."""
+    flat = iter(flat)
+
+    def bind(func):
+        if isinstance(func, _WithKeysFunc):
+            inner = bind(func.func)
+            return func if inner is func.func else _WithKeysFunc(inner)
+        if not isinstance(func, with_operands):
+            return func
+        mine = tuple(next(flat) for _ in func.operands)
+        return lambda v, _f=func.func, _ops=mine: _f(v, *_ops)
+    return tuple(bind(f) for f in funcs)
+
+
+def _place_operands(flat, mesh):
+    """The operands as the program is handed them: on the device,
+    replicated over ``mesh`` (one placement a session whatever the count
+    of slabs or blocks; an array that is there already passes through)."""
+    if not flat:
+        return ()
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    return tuple(
+        x if isinstance(x, jax.Array) and x.sharding == rep
+        else _streamlib.transfer(np.asarray(x), rep) for x in flat)
+
+
 class _Window(NamedTuple):
     """Deferred-chain entry for a basic-slice ``getitem`` (slices of step
     1 and integers): ``starts``/``sizes`` cover the leading axes up to
@@ -658,7 +717,7 @@ def _blocked_run(run, split, x, block, offsets=None):
     return out.reshape(kshape + out.shape[1:])
 
 
-def _chain_apply(funcs, split, data):
+def _chain_apply(funcs, split, data, key0=None):
     """Apply a deferred map chain: each func nested-vmapped over the
     ``split`` leading key axes, in order; ``with_keys`` entries vmap
     over flattened records zipped with their (traced, int32 — matching
@@ -666,7 +725,10 @@ def _chain_apply(funcs, split, data):
     slice in place.  ``split`` is the split of the chain's RESULT: a
     window that takes an integer on a key axis lowers it on the way.
     A trailing :class:`_Blocked` names the runs of maps that are lowered
-    over blocks of records instead (:func:`_blocked_run`)."""
+    over blocks of records instead (:func:`_blocked_run`).  ``key0``:
+    where ``data`` is a slab of a longer array, the first key of the slab
+    on axis 0 (an int32 scalar, traced: one program for every slab),
+    added to the keys a ``with_keys`` entry is handed."""
     if funcs and type(funcs[-1]) is _Blocked:
         return _chain_apply_blocked(funcs[:-1], split, data, funcs[-1])
     out = data
@@ -682,6 +744,8 @@ def _chain_apply(funcs, split, data):
             flat = out.reshape((n,) + out.shape[split:])
             keys = jnp.unravel_index(jnp.arange(n, dtype=jnp.int32),
                                      kshape)
+            if key0 is not None:
+                keys = (keys[0] + key0,) + tuple(keys[1:])
 
             def one(v, *k, _f=func.func):
                 return _f((tuple(k), v))
@@ -1350,15 +1414,18 @@ class BoltArrayTPU(BoltArray):
             mesh, split = self._mesh, self._split
 
             def build():
-                def run(d):
-                    return _constrain(_chain_apply(funcs, split, d), mesh, split)
+                def run(d, *operands):
+                    bound = _bind_operands(funcs, operands)
+                    return _constrain(_chain_apply(bound, split, d), mesh, split)
                 return jax.jit(run, donate_argnums=(0,) if donate else ())
 
-            fn = _cached_jit(("chain", funcs, base.shape, str(base.dtype),
-                              split, donate, mesh), build)
+            fn = _cached_jit(("chain", _funcs_key(funcs), base.shape,
+                              str(base.dtype), split, donate, mesh), build)
             with _obs.span("array.chain", donate=donate,
                            bytes=int(base.nbytes), **_span_funcs(funcs)):
-                self._concrete = fn(_check_live(base))
+                self._concrete = fn(
+                    _check_live(base),
+                    *_place_operands(_operands_of(funcs), mesh))
             self._chain = None
             if donate:
                 _engine.donation_granted()
@@ -1416,7 +1483,8 @@ class BoltArrayTPU(BoltArray):
                                    mesh if mesh.size > 1 else None)
             return planned[-1] if planned is not funcs else False
         marker = _cached_eval_shape(
-            ("blocks", funcs, split, base.shape, base.dtype, base.sharding,
+            ("blocks", _funcs_key(funcs), split, base.shape, base.dtype,
+             base.sharding,
              aval.shape, aval.dtype, limit, mesh), judge)
         return funcs + (marker,) if marker else funcs
 
@@ -1527,11 +1595,12 @@ class BoltArrayTPU(BoltArray):
                         lambda k, v: func((k, v)), kavals,
                         jax.ShapeDtypeStruct(vshape, aligned._aval.dtype))
                 out_aval = _cached_eval_shape(
-                    ("map-wk", func, split, vshape,
+                    ("map-wk", _func_key(func), split, vshape,
                      str(aligned._aval.dtype)), infer_wk)
             else:
                 out_aval = _cached_eval_shape(
-                    ("map", func, vshape, str(aligned._aval.dtype)),
+                    ("map", _func_key(func), vshape,
+                     str(aligned._aval.dtype)),
                     lambda: jax.eval_shape(
                         func,
                         jax.ShapeDtypeStruct(vshape, aligned._aval.dtype)))
@@ -1550,16 +1619,21 @@ class BoltArrayTPU(BoltArray):
         full_aval = jax.ShapeDtypeStruct(kshape + tuple(out_aval.shape),
                                          out_aval.dtype)
 
-        if aligned._stream is not None and not with_keys:
+        if aligned._stream is not None:
             # streaming source (out-of-core): record the map as a
             # device-side stage — it fuses into the per-slab program.
-            # (with_keys maps need GLOBAL key indices, which a slab-local
-            # program cannot produce; they materialise below.)
-            out = _streamlib.map_stage(aligned, func)
-            if dtype is not None and np.dtype(dtype) != np.dtype(
-                    full_aval.dtype):
-                out = _streamlib.map_stage(out, _cast_fn(_canon(dtype)))
-            return out
+            # A with_keys map is a KEYED stage: the slab program is
+            # handed the slab's first key as an operand and adds it to
+            # the slab-local keys.  (Where that cannot be, on a mesh of
+            # several processes, it materialises below.)
+            out = _streamlib.map_stage(
+                aligned, _WithKeysFunc(func) if with_keys else func)
+            if out is not NotImplemented:
+                if dtype is not None and np.dtype(dtype) != np.dtype(
+                        full_aval.dtype):
+                    out = _streamlib.map_stage(out,
+                                               _cast_fn(_canon(dtype)))
+                return out
 
         # defer: extend the chain (or start one) without executing —
         # with_keys maps defer too (as _WithKeysFunc entries), so

@@ -440,6 +440,56 @@ def code_token(func):
     return "%s#%s" % (name, h.hexdigest()[:12])
 
 
+class with_operands:
+    """``func`` together with the arrays it reads beside each record:
+    ``b.map(with_operands(f, ref))`` calls ``f(value, ref)`` at every key
+    (``f((keys, value), ref)`` under ``with_keys=True``).
+
+    A map body that CLOSES over an array bakes it into the compiled
+    program as a constant, and a fresh closure is a fresh trace, lowering
+    and compile.  The arrays named here travel as OPERANDS of the program
+    instead: the lowerings that know this class (the deferred chain's
+    program and the streamed slab and place programs) key the program by
+    :meth:`key`, ``func`` and the operands' shapes and dtypes and never
+    their values, so a second call with other arrays of the same shape
+    runs the same executable.  Give ``func`` a stable identity (a
+    module-level function, or one memoised as ``ops/series.py`` does).
+
+    To everything else it is a plain callable with its arrays bound
+    (``mode='local'``, shape inference, a lowering that does not know
+    the class), with the identity of the object: correct, and compiled
+    afresh, as any closure is."""
+
+    __slots__ = ("func", "operands", "__name__", "__weakref__")
+
+    def __init__(self, func, *operands):
+        if not callable(func):
+            raise TypeError("with_operands needs a callable, got %r"
+                            % (func,))
+        for x in operands:
+            if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+                raise TypeError("an operand is an array (NumPy or jax), "
+                                "got %r" % (type(x).__name__,))
+        self.func = func
+        self.operands = tuple(operands)
+        self.__name__ = getattr(func, "__name__", type(func).__name__)
+
+    @property
+    def __code__(self):
+        # what utils.code_token fingerprints: the wrapped function's
+        return self.func.__code__
+
+    def __call__(self, value):
+        return self.func(value, *self.operands)
+
+    def key(self):
+        """What a program that takes the operands as arguments is keyed
+        by: the function and each operand's shape and dtype."""
+        return ("with_operands", self.func,
+                tuple((tuple(x.shape), str(x.dtype))
+                      for x in self.operands))
+
+
 def chain_retry_step(exc, prev, attempt, allowed, what, knob):
     """The ONE retry-chaining policy, shared by the streaming
     executor's per-slab ingest retries and the serve scheduler's

@@ -325,6 +325,8 @@ _EXPECTED_ENGINE_KEYS = {
     "codec_bytes_wire": False,
     "shuffle_bytes": False, "spill_bytes": False,
     "shuffle_seconds": True,
+    "stream_collect_slabs": False, "stream_collect_bytes": False,
+    "stream_keyed_slabs": False,
 }
 
 
