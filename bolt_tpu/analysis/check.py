@@ -78,6 +78,17 @@ def _percentile_note(func, split, aval):
         "from which it is selected" % (length, select.select_from(dtype))
 
 
+def _shared_note(shared):
+    """What :meth:`BoltArrayTPU._shared_parent` answered, on the stage
+    that is the shared parent's last: its chain is one program for all
+    of its consumers, and the stages after it read the kept result."""
+    _, node, live = shared
+    if node.kept is None:
+        return "shared parent: materialised once for %d consumers" % live
+    return "shared parent: materialised once, its result kept for the " \
+        "%d still deferred" % live
+
+
 def _kdrop(funcs):
     """Key axes the getitem windows among ``funcs`` remove."""
     from bolt_tpu.tpu.array import _windows
@@ -733,9 +744,13 @@ def _check_impl(obj):
         engine.record_diagnostics(len(diags))
         return rep
 
+    # as the lowering asks it (BoltArrayTPU._lower_from_shared), and
+    # before it decides donation: a chain that will read a kept result
+    # leaves the base alone
+    shared = arr._links and arr._shared_parent()
     # donation forecast BEFORE binding any base/chain local (the
     # ownership test is refcount-based; an extra local would mask it)
-    will_donate = _would_donate(arr)
+    will_donate = not shared and _would_donate(arr)
 
     mesh = arr._mesh
     fp = arr._fpending
@@ -817,6 +832,8 @@ def _check_impl(obj):
                      "with astype/map(dtype=...) if the widening is "
                      "unintended"))
         note = _percentile_note(func, walk_split, aval)
+        if shared and i + 1 == shared[1].nfuncs:
+            note = "; ".join(filter(None, (note, _shared_note(shared))))
         aval = nxt
         stages.append(Stage(i + 1, label, aval.shape, np.dtype(aval.dtype),
                             walk_split, _spec(mesh, aval.shape,
@@ -882,7 +899,14 @@ def _check_impl(obj):
                                 _spec(mesh, aval.shape, 1), dynamic=True))
 
     if not failed:
-        _note_blocks(arr, base, funcs, len(funcs), diags)
+        if not shared:
+            _note_blocks(arr, base, funcs, len(funcs), diags)
+        elif shared[1].kept is None:
+            # the parent's chain is the program over the base; this
+            # chain's own reads the parent's result
+            at, node, _ = shared
+            _note_blocks(arr._parent_of(at), base, funcs[:node.nfuncs],
+                         node.nfuncs, diags)
         _note_admission(
             int(base.nbytes)
             + prod(tuple(stages[-1].shape))

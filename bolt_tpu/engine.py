@@ -200,6 +200,13 @@ _SCHEMA = {
     "blocked_chains": 0,          # programs LOWERED with a run of maps
                                   # over blocks (traced, so counted once
                                   # a program, not once a call)
+    # a deferred array with more than one deferred consumer (ops.fourier's
+    # pair over one map): its chain is run once and the result kept on
+    # the node (tpu/array.py :: _lower_from_shared)
+    "shared_parent_runs": 0,      # shared parents' chains RUN
+    "shared_parent_hits": 0,      # consumers lowered from a kept result
+                                  # and not from the base (the one whose
+                                  # force ran the parent among them)
     # how a per-record percentile was taken (ops/select.py): by exact
     # selection at and above a length, by jnp.percentile's sort below it.
     # Bumped where the record function is TRACED, so a count says which
@@ -732,6 +739,16 @@ def record_map_blocks(n):
     dispatched and runs ``n`` blocks (``tpu/array.py ::
     BoltArrayTPU._blocked``)."""
     _COUNTERS.add("map_blocks", n)
+
+
+def record_shared_parent(ran):
+    """A consumer of a shared deferred parent was lowered from the
+    parent's kept result (``tpu/array.py ::
+    BoltArrayTPU._lower_from_shared``); ``ran``: its force is the one
+    that ran the parent's chain."""
+    if ran:
+        _COUNTERS.add("shared_parent_runs")
+    _COUNTERS.add("shared_parent_hits")
 
 
 def record_blocked_chain():

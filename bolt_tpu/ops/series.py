@@ -238,7 +238,15 @@ def fourier(b, freq, axis=0, epsilon=0.0):
 
     Returns ``(coherence, phase)`` as bolt arrays with the axis removed —
     both still DEFERRED maps (the selection is itself a per-record map,
-    so the contract of this module holds and downstream ops fuse).
+    so the contract of this module holds and downstream ops fuse).  On
+    the TPU backend the pair SHARES ONE RUN of everything behind it: the
+    two are consumers of one deferred map, so whichever is forced first
+    runs the chain once, from the base through the FFT, its
+    ``(..., 2)`` result is kept on the device while the other handle
+    lives, and both are slices of that
+    (``BoltArrayTPU._lower_from_shared``; engine counters
+    ``shared_parent_runs`` / ``shared_parent_hits``).  A caller who
+    keeps only one of the two pays for one program, as before.
     ``epsilon`` guards constant records, which otherwise divide 0/0 to
     NaN (same convention as ``zscore``/``crosscorr``).  XLA lowers the
     FFT natively on TPU.

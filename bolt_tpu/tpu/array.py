@@ -824,6 +824,52 @@ def _span_funcs(funcs):
     return {"funcs": len(funcs)}
 
 
+class _SharedNode:
+    """What the deferred consumers of ONE deferred array hold in common.
+
+    ``map`` on a deferred array copies its chain and appends, so two
+    consumers of one deferred parent (``ops.fourier``'s coherence and
+    phase over one map) are two chains from the same base, and forcing
+    both would run the parent's maps twice.  The parent gets one of
+    these where its first consumer is hung on it: ``nfuncs`` is how
+    many of a consumer's ``funcs`` are the parent's own, ``split`` and
+    ``aval`` are the parent's, ``consumers`` the live
+    :class:`_Consumer` edges (their ids: a set, so that an edge coming
+    or going on another thread is one atomic step), and ``kept`` the
+    parent's result once a consumer's force has run it
+    (:meth:`BoltArrayTPU._lower_from_shared`).  Nothing here holds the
+    base, so the refcounts :func:`_chain_donate_ok` reads are what they
+    were, and ``kept`` dies with the last handle that can reach the
+    node: the parent array and its consumers."""
+
+    __slots__ = ("nfuncs", "split", "aval", "consumers", "kept")
+
+    def __init__(self, nfuncs, split, aval):
+        self.nfuncs, self.split, self.aval = nfuncs, split, aval
+        self.consumers = set()
+        self.kept = None
+
+    @property
+    def nbytes(self):
+        return prod(self.aval.shape) * np.dtype(self.aval.dtype).itemsize
+
+
+class _Consumer:
+    """One consumer hung on a deferred parent: held by the consumer and
+    by every chain extended from it (``_links``), and counted by the
+    parent's :class:`_SharedNode` while any of them lives, so the node
+    counts the consumers that can still be forced and no others."""
+
+    __slots__ = ("node",)
+
+    def __init__(self, node):
+        self.node = node
+        node.consumers.add(id(self))
+
+    def __del__(self):
+        self.node.consumers.discard(id(self))
+
+
 def _pred_mask(pred, flat):
     """Filter predicate as a bool mask over flattened records — the ONE
     coercion rule (`asarray(...,bool).reshape(())` per record) shared by
@@ -1108,6 +1154,12 @@ class BoltArrayTPU(BoltArray):
         # on one source fuse into one pass (and one donate)
         self._stat_group = None
         self._donated = False
+        # a deferred chain's place among the deferred arrays it was
+        # mapped from: the :class:`_Consumer` edges up to each deferred
+        # ancestor (outermost first), and the :class:`_SharedNode` that
+        # this array's own consumers hang on (see map)
+        self._links = ()
+        self._node = None
         self._aval = None if data is None else jax.ShapeDtypeStruct(
             data.shape, data.dtype)
 
@@ -1215,7 +1267,7 @@ class BoltArrayTPU(BoltArray):
         says WHICH terminal consumed the buffer).  ``granted=False``
         records the donation without counting it as an engine-policy
         grant (``swap(donate=True)`` is user-explicit, not granted)."""
-        self._chain = None
+        self._retire_chain()
         self._concrete = None
         self._fpending = None
         self._donated = op
@@ -1405,10 +1457,16 @@ class BoltArrayTPU(BoltArray):
             self._resolve_pending()
         if self._concrete is None:
             _engine.strict_guard(self, "map-chain materialisation")
+            node = self._node
+            if node is not None and node.kept is not None:
+                # a consumer's force ran this chain already
+                self._concrete = node.kept
+                self._retire_chain()
+                return _check_live(self._concrete)
             # chained-map terminal: a sole-owned base donates its buffer
             # to the materialising program (the output is input-sized, so
             # XLA aliases them — one buffer instead of two)
-            donate = _chain_donate_ok(self._chain)
+            donate = self._donatable()
             base, funcs = self._chain
             funcs = self._blocked(base, funcs)
             mesh, split = self._mesh, self._split
@@ -1426,10 +1484,93 @@ class BoltArrayTPU(BoltArray):
                 self._concrete = fn(
                     _check_live(base),
                     *_place_operands(_operands_of(funcs), mesh))
-            self._chain = None
+            if node is not None and node.nbytes < base.nbytes:
+                node.kept = self._concrete      # its consumers' base now
+            self._retire_chain()
             if donate:
                 _engine.donation_granted()
         return _check_live(self._concrete)
+
+    def _retire_chain(self):
+        """This array is a deferred chain no longer (it was materialised,
+        consumed, or re-seated on another representation): it lets go of
+        its place among the deferred arrays with the chain."""
+        self._chain = None
+        self._links = ()
+        self._node = None
+
+    def _shared_parent(self):
+        """``(index into _links, node, consumers)`` of the deferred
+        ancestor this chain is lowered FROM rather than through, or
+        ``None``: the nearest one whose result is kept already, or that
+        has more than one live consumer and a result smaller than the
+        base (kept beside the base it never raises the peak past what a
+        consumer's own result would; a map whose result is as large as
+        its input is cheap to run again and dear to keep).  The ONE rule:
+        the lowering asks it (:meth:`_lower_from_shared`) and
+        ``analysis.explain`` says what it answers."""
+        base = self._chain[0]
+        for at in range(len(self._links) - 1, -1, -1):
+            node = self._links[at].node
+            kept = node.kept
+            if kept is base:
+                return None             # lowered from it already
+            if kept is not None and kept.is_deleted():
+                # the ancestor was forced and then gave its buffer away
+                # (swap(donate=True)): the base still has everything
+                kept = node.kept = None
+            live = len(node.consumers)
+            if kept is not None or (live > 1
+                                    and node.nbytes < base.nbytes):
+                return at, node, live
+        return None
+
+    def _parent_of(self, at):
+        """The deferred ancestor behind ``_links[at]`` as an array of its
+        own (the ancestor itself may be gone: ``ops.fourier`` drops the
+        map its pair is picked from)."""
+        node = self._links[at].node
+        base, funcs = self._chain
+        parent = BoltArrayTPU._deferred(base, funcs[:node.nfuncs],
+                                        node.split, self._mesh, node.aval)
+        parent._links = self._links[:at]
+        return parent
+
+    def _lower_from_shared(self):
+        """Re-seat this deferred chain on the kept result of a shared
+        ancestor (:meth:`_shared_parent`), running the ancestor's chain
+        first where no consumer has yet: ONE program over the base for
+        all of them, and this chain's own program reads the result.  The
+        ancestor's program is the ``("chain", ...)`` program its own
+        materialisation would run, never donating (its consumers hold
+        the base); the kept result is never donated either, because this
+        array keeps the edge to the node that holds it.  Called BEFORE a
+        terminal decides whether to donate (:meth:`_donatable`): a chain
+        that is the base's sole owner by then has no shared ancestor
+        still to run (every other live consumer holds the base too), so
+        it is lowered whole and donates, as ever, unless an ancestor's
+        result is kept, and then it reads that and lets the base go."""
+        found = self._links and self._shared_parent()
+        if not found:
+            return
+        at, node, _ = found
+        ran = node.kept is None
+        if ran:
+            node.kept = self._parent_of(at)._data
+        _engine.record_shared_parent(ran)
+        self._chain = (node.kept, self._chain[1][node.nfuncs:])
+        # the edge to the node stays (it holds the kept result); a
+        # consumer hung on this array from now on extends the new chain
+        self._links = self._links[at:at + 1]
+        self._node = None
+
+    def _donatable(self):
+        """:func:`_chain_donate_ok` of this deferred chain as the terminal
+        about to consume it will find it: re-seated first on a shared
+        ancestor's kept result where there is one
+        (:meth:`_lower_from_shared`)."""
+        self._lower_from_shared()
+        return _chain_donate_ok(self._chain)
 
     def _chain_parts(self, consume=True):
         """``(base jax.Array, funcs)`` for fusing this array into a bigger
@@ -1440,6 +1581,8 @@ class BoltArrayTPU(BoltArray):
         only being extended)."""
         if not self.deferred:
             return self._data, ()
+        if consume:
+            self._lower_from_shared()
         wins = consume and _windows(self._chain[1])
         if wins:
             _engine.record_getitems_fused(len(wins))
@@ -1496,7 +1639,7 @@ class BoltArrayTPU(BoltArray):
         so the chain is simply retired."""
         self._concrete = data
         self._aval = jax.ShapeDtypeStruct(tuple(data.shape), data.dtype)
-        self._chain = None
+        self._retire_chain()
 
     def _adopt_resolved(self, res):
         """Adopt the result of resolving this array's swap stages
@@ -1514,6 +1657,7 @@ class BoltArrayTPU(BoltArray):
             self._stream = None
             self._concrete = res._concrete
             self._chain = res._chain
+            self._links, self._node = res._links, res._node
         self._split = res._split
         self._aval = res._aval
 
@@ -1644,6 +1788,13 @@ class BoltArrayTPU(BoltArray):
             base, funcs = aligned._chain
             out = BoltArrayTPU._deferred(base, funcs + (entry,), split,
                                          mesh, full_aval)
+            # one more consumer of a deferred parent: counted on the
+            # parent's node, which a force asks (_shared_parent)
+            node = aligned._node
+            if node is None:
+                node = aligned._node = _SharedNode(len(funcs), split,
+                                                   aligned._aval)
+            out._links = aligned._links + (_Consumer(node),)
         else:
             out = BoltArrayTPU._deferred(aligned._data, (entry,), split,
                                          mesh, full_aval)
@@ -1821,7 +1972,7 @@ class BoltArrayTPU(BoltArray):
         # donation-aware terminal: consuming a sole-owned deferred chain
         # frees the parent buffer inside the reduction program (checked
         # BEFORE binding the base local — see _chain_donate_ok)
-        donate = aligned.deferred and _chain_donate_ok(aligned._chain)
+        donate = aligned.deferred and aligned._donatable()
         base, funcs = aligned._chain_parts()
 
         def build():
@@ -1885,7 +2036,7 @@ class BoltArrayTPU(BoltArray):
 
         # donation-aware terminal (see _chain_donate_ok: checked before
         # the base local exists)
-        donate = self.deferred and _chain_donate_ok(self._chain)
+        donate = self.deferred and self._donatable()
         base, funcs = self._chain_parts()
 
         def build():
@@ -3012,7 +3163,7 @@ class BoltArrayTPU(BoltArray):
         out = _cached_jit(("sort", funcs, base.shape, str(base.dtype),
                            split, axis, mesh), build)(_check_live(base))
         self._concrete = out
-        self._chain = None
+        self._retire_chain()
         self._aval = jax.ShapeDtypeStruct(out.shape, out.dtype)
         return None
 
@@ -4047,6 +4198,7 @@ class BoltArrayTPU(BoltArray):
         (``np.sort``)."""
         b = BoltArrayTPU(self._concrete, self._split, self._mesh)
         b._chain = self._chain
+        b._links, b._node = self._links, self._node
         b._pending = self._pending
         b._fpending = self._fpending
         # a lazy stream source is shared, not forked: callback sources

@@ -31,9 +31,8 @@ from bolt_tpu import stream as _streamlib
 from bolt_tpu.obs import trace as _obs
 from bolt_tpu.parallel.sharding import combined_spec
 from bolt_tpu.tpu.array import (BoltArrayTPU, _TRACE_ERRORS, _cached_jit,
-                                _canon, _chain_apply, _chain_donate_ok,
-                                _check_live, _check_value_shape, _constrain,
-                                _traceable)
+                                _canon, _chain_apply, _check_live,
+                                _check_value_shape, _constrain, _traceable)
 from bolt_tpu.utils import (chunk_align, chunk_pad, chunk_plan, iterexpand,
                             tupleize)
 
@@ -390,7 +389,7 @@ class ChunkedArray:
         # a sole-owned chain base additionally DONATES its buffer to the
         # program (the chunked output is input-sized, so XLA aliases the
         # two — the chunk→map→unchunk pipeline's donation-aware terminal)
-        donate = b.deferred and _chain_donate_ok(b._chain)
+        donate = b.deferred and b._donatable()
         base, funcs = b._chain_parts()
         canon = None if dtype is None else _canon(dtype)
 

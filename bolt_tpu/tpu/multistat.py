@@ -572,7 +572,7 @@ def _new_group(arr, axis, name, keepdims, ddof):
     # the standalone terminals' exact reference pattern (attribute
     # access straight into the call — the ownership test is
     # refcount-based)
-    donate = arr.deferred and _chain_donate_ok(arr._chain)
+    donate = arr.deferred and arr._donatable()
     base, funcs = arr._chain_parts()
     g = _StatGroup("chain", mesh, arr._split, base=base, funcs=funcs,
                    donate=donate,
@@ -690,8 +690,7 @@ def defer_reduce(arr, func, axes, keepdims):
         return NotImplemented          # eager empty-reduce raise contract
     vshape = shape[split:]
     dtype = arr._aval.dtype
-    from bolt_tpu.tpu.array import _TRACE_ERRORS, _cached_eval_shape, \
-        _chain_donate_ok
+    from bolt_tpu.tpu.array import _TRACE_ERRORS, _cached_eval_shape
     vaval = jax.ShapeDtypeStruct(vshape, dtype)
     try:
         oav = _cached_eval_shape(
@@ -701,7 +700,7 @@ def defer_reduce(arr, func, axes, keepdims):
         return NotImplemented          # host-fallback path resolves
     if tuple(oav.shape) != tuple(vshape):
         return NotImplemented          # eager call-time ValueError
-    if arr.deferred and _chain_donate_ok(arr._chain):
+    if arr.deferred and arr._donatable():
         return NotImplemented          # keep the donating eager terminal
     base, funcs = arr._chain_parts()
     g = _StatGroup("chain", arr._mesh, split, base=base, funcs=funcs,

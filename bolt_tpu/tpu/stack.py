@@ -20,9 +20,8 @@ from bolt_tpu import engine as _engine
 from bolt_tpu import stream as _streamlib
 from bolt_tpu.obs import trace as _obs
 from bolt_tpu.tpu.array import (BoltArrayTPU, _TRACE_ERRORS, _cached_jit,
-                                _canon, _chain_apply, _chain_donate_ok,
-                                _check_live, _check_value_shape, _constrain,
-                                _traceable)
+                                _canon, _chain_apply, _check_live,
+                                _check_value_shape, _constrain, _traceable)
 from bolt_tpu.utils import prod
 
 
@@ -136,7 +135,7 @@ class StackedArray:
         size = self._size
         # donation-aware terminal: a sole-owned deferred chain donates its
         # base into the block-batched program (input-sized output)
-        donate = b.deferred and _chain_donate_ok(b._chain)
+        donate = b.deferred and b._donatable()
         base, funcs = b._chain_parts()
         canon = None if dtype is None else _canon(dtype)
         if value_shape is not None:

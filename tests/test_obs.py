@@ -316,6 +316,7 @@ _EXPECTED_ENGINE_KEYS = {
     "gram_kernel_programs": False, "gram_sums_programs": False,
     "fold_kernel_programs": False,
     "map_blocks": False, "blocked_chains": False,
+    "shared_parent_runs": False, "shared_parent_hits": False,
     "percentile_select_lowerings": False,
     "percentile_sort_lowerings": False,
     "filters_fused": False, "filter_compactions": False,

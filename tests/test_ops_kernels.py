@@ -1220,13 +1220,18 @@ def _tuning_chain():
             series._pick_fn(0, 0))
 
 
+# one of fourier's handles alone takes the whole chain and its pick; the
+# pair takes the chain up to fourier's (512, 512, 2) map ONCE, as the
+# shared parent's program (BoltArrayTPU._lower_from_shared, PR 39)
+@pytest.mark.parametrize("picked", [1, 0], ids=["one-handle",
+                                                "the-shared-parent"])
 def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
-        v5e_device):
+        v5e_device, picked):
     import jax
     from bolt_tpu.tpu.array import _Blocked, _chain_apply, _plan_blocks
-    funcs = _tuning_chain()
+    funcs = _tuning_chain()[:3 + picked]
     base = 4 * int(np.prod(_PIXELS))
-    free = _V5E_HBM - base - 4 * 512 * 512
+    free = _V5E_HBM - base - 4 * 512 * 512 * (2 - picked)
     planned = _plan_blocks(funcs, 2, _PIXELS, np.float32, free)
     marker = planned[-1]
     assert type(marker) is _Blocked and planned[:-1] == funcs
@@ -1238,7 +1243,7 @@ def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
         compiled = jax.jit(
             lambda d: _chain_apply(planned, 2, d)).lower(arg).compile()
         mem = compiled.memory_analysis()
-        # the base, a 1 MB map, and a block's temporaries: under the
+        # the base, a 1 or 2 MB map, and a block's temporaries: under the
         # quarter of what is left that the rule allows them
         assert mem.argument_size_in_bytes == base
         assert mem.temp_size_in_bytes < 2e9
