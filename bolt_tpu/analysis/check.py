@@ -71,7 +71,13 @@ def _percentile_note(func, split, aval):
     length = aval.shape[split + ax]
     # the dtype the stage itself promotes to
     dtype = jax.numpy.promote_types(aval.dtype, np.float32)
-    if select.regime(length, dtype) == "select":
+    how = select.regime(length, dtype)
+    if how == "kernel":
+        return ("percentile by selection, one read of a block: two exact "
+                "order statistics of %d values found bit by bit on a tile "
+                "held in VMEM (a program for one TPU device; counting "
+                "passes over the block elsewhere), no sort" % length)
+    if how == "select":
         return ("percentile by selection: two exact order statistics of "
                 "%d values found bit by bit, no sort" % length)
     return "percentile by sort: %d values a record is under the %d " \

@@ -214,6 +214,10 @@ _SCHEMA = {
     # blocks rule and the shape inference trace the function too)
     "percentile_select_lowerings": 0,
     "percentile_sort_lowerings": 0,
+    # selections LOWERED as the Mosaic kernel (one read of a block: a
+    # program for one TPU device); every other selection lowers to the
+    # counting passes and counts nothing here
+    "percentile_kernel_lowerings": 0,
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -760,7 +764,8 @@ def record_blocked_chain():
 def record_percentile_lowering(regime):
     """A record function was traced with a percentile taken by
     ``regime`` (``"select"`` or ``"sort"``: ``ops/select.py ::
-    percentile``)."""
+    percentile``), or a selection was lowered as the Mosaic kernel
+    (``"kernel"``: the ``percentile_select`` primitive's TPU rule)."""
     _COUNTERS.add("percentile_%s_lowerings" % regime)
 
 

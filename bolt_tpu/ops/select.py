@@ -27,10 +27,40 @@ first, and the helper takes floats alone.
 
 Which of the two a length takes is a rule on the length and the key's
 width alone (:func:`regime`): a short record sorts inside one fusion and
-the passes of a selection would only slow it down.  The two constants
-below were read on the chip with ``scripts/select_probe.py``; PERF.md
-(section 6, PR 37) has the table.
+the passes of a selection would only slow it down.
+
+The selection has TWO executors of the one algorithm (PR 40).  Spelled in
+``jax.numpy`` every pass is a trip to HBM: a block of records is read
+nineteen times (sixteen counting passes, the image's read and its write,
+the neighbour, the NaN verdict) for 4 bytes a record.  The Mosaic kernel
+(:func:`_select_kernel`) brings a tile of whole records into VMEM ONCE
+and runs the image, every pass, the neighbour and the NaN verdict on the
+tile where it lies: records on the sublanes, time on the lanes, a count
+an element-wise accumulate over a record's groups of 128 lanes and one
+cross-lane sum a record a pass; it writes two keys and the verdict a
+record, and the interpolation after it is the same ``jax.numpy`` lines.
+What the kernel holds: float32 records of a length :func:`regime` calls
+``"kernel"`` (whole groups of 128 lanes, at least ``_KERNEL_FROM``, a
+tile of 8 of them inside ``_TILE_BYTES``), in a program lowered for ONE
+TPU device (or the inside of a fully manual ``shard_map``).  What the
+fallback holds is everything else, by the ``jax.numpy`` passes as they
+were: the CPU, a program GSPMD partitions, float64 and 16-bit keys, a
+length that is not whole lane-groups, a record too long for VMEM.  The
+choice is made when the program is LOWERED (the ``percentile_select``
+primitive, as ``ops/linalg.py``'s ``jacobi_sweeps``): by then the target
+is known, at trace time it is not.  Under ``vmap`` the primitive's rule
+takes the mapped axis as one more leading axis of ONE bind over the
+whole batch (``pallas_call``'s own rule would make each record a grid
+step of one sublane); the kernel's lowering flattens the batch, a
+bitcast, and the fallback's maps ``_select`` over it again, so that off
+the TPU the program's text is what it was before the kernel came.
+
+The constants below were read on the chip with
+``scripts/select_probe.py``; PERF.md (section 6, PR 37 and PR 40) has the
+tables.
 """
+
+from functools import partial, reduce
 
 import numpy as np
 
@@ -58,6 +88,40 @@ _BITS_A_PASS = 2
 # it in proportion (the passes are the width's)
 _SELECT_FROM = 256
 
+# the kernel's side of the rule (PR 40; scripts/select_probe.py, the
+# kernel's columns, 1 GiB of rows on the v5e).  Bits a pass in VMEM: a
+# pass costs compares and no read, and a compare-and-count is three
+# vector operations an element (compare, select, add), so one bit is 96
+# of them an element, two 144, four 360.  At 10,240 values a record the
+# kernel read 7.4 / 8.9 ms at one and two bits (19.0 at four, groups of
+# 32), the passes over HBM 29.8; one bit is the fastest from 4,096 up
+# (9.2 / 9.9), two bits under it (2,048: 13.7 / 12.9; 1,024: 22.4 / 18.4)
+_KERNEL_BITS = 1
+# the shortest record the kernel takes (whole groups of 128 lanes from
+# here up).  A pass ends in a cross-lane sum a record and the broadcast
+# of the next candidate, which a short record does not hide.  The same
+# probe, ms a GiB, kernel / passes over HBM: 512 values 39.1 / 30.4, 768
+# 28.0 / 30.3, 1,024 22.4 / 29.9, 1,536 15.7 / 28.6, 2,048 13.7 / 29.9,
+# 4,096 9.2 / 28.3, 10,240 7.4 / 29.8
+_KERNEL_FROM = 1024
+# what one buffer of a tile of records may hold in VMEM: the pipeline
+# keeps two, and a group's image of keys lies beside them, all inside
+# Mosaic's 16 MiB scoped default.  A tile is at least 8 records (the
+# sublanes of one vreg), so a record of more than _TILE_BYTES / 32
+# values (98,304) keeps the passes over HBM
+_TILE_BYTES = 3 << 20
+_LANES = 128
+# copies of a walk's running sums, taken in turn by the lane-groups, so
+# that an add does not wait for the one before it (ms a GiB at 10,240:
+# one copy 7.7, two 7.3, four 7.3)
+_WAYS = 2
+# records a group, the rows one pass runs over at once: the wait for a
+# pass's cross-lane sums is shared by that many records (ms a GiB at
+# 10,240: 8 records 18.5, 16 11.6, 32 8.8, 64 7.3; 128 do not fit VMEM
+# beside two buffers of a tile)
+_GROUP = 64
+
+
 def select_from(dtype):
     """The shortest record of the float ``dtype`` whose percentile is
     selected."""
@@ -65,10 +129,17 @@ def select_from(dtype):
 
 
 def regime(length, dtype):
-    """``"select"`` or ``"sort"``: how the percentile of records of
-    ``length`` values of the float ``dtype`` is taken.  The lowering and
-    ``analysis.explain`` both ask this."""
-    return "select" if length >= select_from(dtype) else "sort"
+    """``"kernel"``, ``"select"`` or ``"sort"``: how the percentile of
+    records of ``length`` values of the float ``dtype`` is taken.
+    ``"kernel"`` is a selection too, and says that a program lowered for
+    one TPU device runs it as the Mosaic kernel (one read of a block);
+    everywhere else it lowers to ``"select"``'s passes.  The lowering
+    and ``analysis.explain`` both ask this."""
+    if length < select_from(dtype):
+        return "sort"
+    tiles = np.dtype(dtype) == np.float32 and length % _LANES == 0 \
+        and _KERNEL_FROM <= length <= _TILE_BYTES // 32
+    return "kernel" if tiles else "select"
 
 
 def _keys(x):
@@ -144,19 +215,22 @@ def percentile(a, perc, axis, keepdims=False):
                         % a.dtype)
     axis = axis % a.ndim
     how = regime(a.shape[axis], a.dtype)
-    _engine.record_percentile_lowering(how)
+    # which executor a selection gets is the lowering's to say (and to
+    # count: percentile_kernel_lowerings); traced, it is a selection
+    _engine.record_percentile_lowering("sort" if how == "sort" else "select")
     if how == "sort":
         return jnp.percentile(a, perc, axis=axis, keepdims=keepdims)
-    return _select(a, perc, axis, keepdims)
+    if how == "select":
+        return _select(a, perc, axis, keepdims)
+    return _select_p.bind(a, perc=perc, axis=axis, keepdims=keepdims, lead=0)
 
 
-def _select(a, perc, axis, keepdims, bits=_BITS_A_PASS):
-    """:func:`percentile` by selection, whatever the length (``a`` a
-    float array, ``axis`` not negative)."""
-    n = a.shape[axis]
-    # jnp.quantile's own arithmetic, in the dtype it gives a Python
-    # float: q * (n - 1), floor and ceil, the weights, the clamp.  On
-    # the host: XLA folds the same operations on the same constants
+def _ranks(n, perc):
+    """The two indices ``jnp.quantile`` interpolates between in a record
+    of ``n`` values and their weights: its own arithmetic, in the dtype
+    it gives a Python float (``q * (n - 1)``, floor and ceil, the
+    weights, the clamp).  On the host: XLA folds the same operations on
+    the same constants."""
     real = np.dtype(jnp.result_type(float)).type
     at = real(perc) / real(100) * (real(n) - real(1))
     low, high = np.floor(at), np.ceil(at)
@@ -164,7 +238,32 @@ def _select(a, perc, axis, keepdims, bits=_BITS_A_PASS):
     low_weight = real(1) - high_weight
     low = int(np.clip(low, 0, n - 1))
     high = int(np.clip(high, 0, n - 1))
+    return low, high, low_weight, high_weight
 
+
+def _blend(low_key, high_key, nan, low_weight, high_weight, dtype):
+    """``jnp.quantile``'s answer from the two keys of each record and its
+    NaN verdict: two products and a sum, as ``_quantile`` writes them."""
+    low_value = jnp.where(nan, np.nan, _values(low_key, dtype))
+    high_value = jnp.where(nan, np.nan, _values(high_key, dtype))
+    # the weights are kept from XLA's sight as literals: it rewrites
+    # a * c + b * c with a literal c = 0.5 (a median between two
+    # elements) into (a + b) * c, which overflows where the products do
+    # not (values past half the largest float); jnp.quantile's weights
+    # reach it as expressions folded later, and its products stay
+    low_weight, high_weight = lax.optimization_barrier(
+        (jnp.asarray(low_weight), jnp.asarray(high_weight)))
+    real = low_weight.dtype
+    return (low_value.astype(real) * low_weight
+            + high_value.astype(real) * high_weight).astype(dtype)
+
+
+def _select(a, perc, axis, keepdims, bits=_BITS_A_PASS):
+    """:func:`percentile` by selection, whatever the length (``a`` a
+    float array, ``axis`` not negative), by passes of ``jax.numpy``: the
+    fallback of the ``percentile_select`` primitive, and the selection of
+    every record :func:`regime` does not give the kernel."""
+    low, high, low_weight, high_weight = _ranks(a.shape[axis], perc)
     # the image is taken ONCE, ahead of the passes, and held: written
     # inside a pass, XLA's loop-invariant code motion lifts half of it
     # out (the flipped bits) and every pass then reads the record twice
@@ -173,16 +272,254 @@ def _select(a, perc, axis, keepdims, bits=_BITS_A_PASS):
     high_key = low_key if high == low \
         else _next_key(keys, low_key, low, axis)
     nan = jnp.any(jnp.isnan(a), axis=axis, keepdims=True)
-    low_value = jnp.where(nan, np.nan, _values(low_key, a.dtype))
-    high_value = jnp.where(nan, np.nan, _values(high_key, a.dtype))
-    # two products and a sum, as _quantile writes them.  The weights are
-    # kept from XLA's sight as literals: it rewrites a * c + b * c with a
-    # literal c = 0.5 (a median between two elements) into (a + b) * c,
-    # which overflows where the products do not (values past half the
-    # largest float); jnp.quantile's weights reach it as expressions
-    # folded later, and its products stay
-    low_weight, high_weight = lax.optimization_barrier(
-        (jnp.asarray(low_weight), jnp.asarray(high_weight)))
-    out = (low_value.astype(real) * low_weight
-           + high_value.astype(real) * high_weight).astype(a.dtype)
+    out = _blend(low_key, high_key, nan, low_weight, high_weight, a.dtype)
     return out if keepdims else jnp.squeeze(out, axis)
+
+
+# ---------------------------------------------------------------------
+# the same selection on a tile of records held in VMEM
+# ---------------------------------------------------------------------
+
+_TOP = np.int32(-2 ** 31)
+_MOST = np.int32(2 ** 31 - 1)
+
+
+def _select_kernel(x_ref, out_ref, keys_ref, *, records, low, high, bits):
+    """Both order statistics of every record of one tile.
+
+    ``x_ref`` ``(tile, length)`` float32, a record a sublane; ``keys_ref``
+    ``(group, length)`` int32, the image of the group of records at work;
+    ``out_ref`` ``(tile, 128)`` int32: lane 0 the ``low``-th smallest key
+    of the record, lane 1 the ``high``-th, lane 2 whether it holds a NaN.
+
+    Keys are :func:`_keys`' with the top bit flipped, so that SIGNED
+    compares order them (Mosaic's unsigned compares are not relied on):
+    a negative value's low 31 bits flipped, a positive one's bits as
+    they are, -0.0's key (-1) made +0.0's (0).  ``found`` is built as
+    the unsigned key's bit pattern, as :func:`_kth_key` builds it, and a
+    candidate crosses to the signed image by the same flip."""
+    from jax.experimental import pallas as pl
+    tile, length = x_ref.shape
+    group = keys_ref.shape[0]
+    chunks = length // _LANES
+    # the last tile of a batch that does not divide: its rows past the
+    # batch hold whatever the buffer held, and groups of them are skipped
+    # (rows of a group that straddles the end are selected and dropped
+    # by the masked write-back)
+    valid = jnp.minimum(tile, records - pl.program_id(0) * tile)
+    lane = lax.broadcasted_iota(jnp.int32, (group, _LANES), 1)
+    zeros = jnp.zeros((group, _LANES), jnp.int32)
+
+    # a walk over a record's lane-groups: unrolled as far as 128 of them
+    # (the cell's 80 are straight-line code), a loop of unrolled spans
+    # beyond (Mosaic unrolls a loop whole or not at all)
+    span = max(d for d in range(1, 129) if chunks % d == 0)
+
+    def over_lanes(body, init, merge):
+        # _WAYS copies of the carry take the lane-groups in turn, so that
+        # a step's add does not wait for the step before it; ``merge``
+        # folds them at the end
+        def spans(i, turn):
+            for j in range(span):
+                turn = turn[1:] + (body(pl.ds(pl.multiple_of(
+                    (i * span + j) * _LANES, _LANES), _LANES), turn[0]),)
+            return turn
+        turn = (init,) * _WAYS
+        turn = spans(0, turn) if span == chunks \
+            else lax.fori_loop(0, chunks // span, spans, turn)
+        return reduce(merge, turn)
+
+    def one_group(g, carry):
+        rows = pl.ds(pl.multiple_of(g * group, group), group)
+
+        def image(lanes, nan):
+            x = x_ref[rows, lanes]
+            bits_ = lax.bitcast_convert_type(x, jnp.int32)
+            keys = jnp.where(bits_ < 0, bits_ ^ _MOST, bits_)
+            keys_ref[:, lanes] = jnp.where(keys == -1, 0, keys)
+            return jnp.where(x != x, 1, nan)
+
+        nan = jnp.max(over_lanes(image, zeros, jnp.maximum), axis=1,
+                      keepdims=True)
+
+        def one_pass(i, found):
+            shift = 32 - bits * (i + 1)
+            cands = [jnp.broadcast_to(
+                (found | lax.shift_left(jnp.int32(j), shift)) ^ _TOP,
+                (group, _LANES)) for j in range(1, 2 ** bits)]
+
+            def count(lanes, below):
+                keys = keys_ref[:, lanes]
+                return tuple(b + jnp.where(keys < c, 1, 0)
+                             for b, c in zip(below, cands))
+
+            digit = jnp.zeros((group, 1), jnp.int32)
+            for below in over_lanes(
+                    count, (zeros,) * len(cands),
+                    lambda a, b: tuple(x + y for x, y in zip(a, b))):
+                below = jnp.sum(below, axis=1, keepdims=True)
+                digit += jnp.where(below <= low, 1, 0)
+            return found | lax.shift_left(digit, shift)
+
+        found = lax.fori_loop(0, 32 // bits, one_pass,
+                              jnp.zeros((group, 1), jnp.int32))
+        above = found
+        if high != low:
+            # _next_key: the count of keys up to the k-th and the least
+            # key above it, one walk over the image
+            key = jnp.broadcast_to(found ^ _TOP, (group, _LANES))
+
+            def neighbour(lanes, carry):
+                upto, least = carry
+                keys = keys_ref[:, lanes]
+                within = keys <= key
+                return (upto + jnp.where(within, 1, 0),
+                        jnp.minimum(least, jnp.where(within, _MOST, keys)))
+
+            upto, least = over_lanes(
+                neighbour, (zeros, jnp.full((group, _LANES), _MOST)),
+                lambda a, b: (a[0] + b[0], jnp.minimum(a[1], b[1])))
+            upto = jnp.sum(upto, axis=1, keepdims=True)
+            least = jnp.min(least, axis=1, keepdims=True) ^ _TOP
+            above = jnp.where(upto > low + 1, found, least)
+        out_ref[rows, :] = jnp.where(
+            lane == 0, found, jnp.where(
+                lane == 1, above, jnp.where(lane == 2, nan, 0)))
+        return carry
+
+    lax.fori_loop(0, pl.cdiv(valid, group), one_group, 0)
+
+
+def _tile(records, length):
+    """``(tile, group)``: records a grid step brings into VMEM and
+    records a pass runs over at once (a power of two from 8 to
+    ``_GROUP``, as many as the tile's budget and the batch hold);
+    ``records`` at least 8."""
+    fit = _TILE_BYTES // (4 * length)
+    group = 8
+    while group * 2 <= min(_GROUP, fit, records):
+        group *= 2
+    return max(group, min(fit, records) // group * group), group
+
+
+def _kernel_keys(x, low, high, bits=_KERNEL_BITS):
+    """``(low key, high key, NaN verdict)`` of every record of the flat
+    float32 batch ``x (records, length)``, each ``(records, 1)`` (the
+    keys uint32 as :func:`_keys` has them), by :func:`_select_kernel`."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    records, length = x.shape
+    if records < 8:
+        # one vreg's sublanes at the least; a batch this small is a copy
+        # of a few records
+        x = jnp.pad(x, ((0, 8 - records), (0, 0)))
+    tile, group = _tile(x.shape[0], length)
+    # Mosaic has no 64-bit types: whatever the session's x64 says, the
+    # kernel's side traces int32 and float32
+    with jax.enable_x64(False):
+        out = pl.pallas_call(
+            partial(_select_kernel, records=x.shape[0], low=low, high=high,
+                    bits=bits),
+            out_shape=jax.ShapeDtypeStruct((x.shape[0], _LANES), jnp.int32),
+            grid=(pl.cdiv(x.shape[0], tile),),
+            in_specs=[pl.BlockSpec((tile, length), lambda g: (g, 0))],
+            out_specs=pl.BlockSpec((tile, _LANES), lambda g: (g, 0)),
+            scratch_shapes=[pltpu.VMEM((group, length), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel",)),
+            name="percentile_select",
+        )(x)
+    out = lax.bitcast_convert_type(out[:records, :3], jnp.uint32)
+    return out[:, 0:1], out[:, 1:2], out[:, 2:3] != 0
+
+
+def _select_flat(x, perc, bits=_KERNEL_BITS):
+    """``_select(x, perc, 1, True)`` of a flat float32 batch ``(records,
+    length)`` by the kernel."""
+    low, high, low_weight, high_weight = _ranks(x.shape[1], perc)
+    with jax.named_scope("percentile_select"):
+        low_key, high_key, nan = _kernel_keys(x, low, high, bits)
+    return _blend(low_key, high_key, nan, low_weight, high_weight, x.dtype)
+
+
+def _by_passes(a, perc, axis, keepdims, lead):
+    """The primitive's fallback: :func:`_select` mapped over the ``lead``
+    batch axes of ``a``, which is what the nested ``vmap`` traced before
+    the primitive stood in its way."""
+    fn = partial(_select, perc=perc, axis=axis, keepdims=keepdims)
+    for _ in range(lead):
+        fn = jax.vmap(fn)
+    return fn(a)
+
+
+def _by_kernel(a, perc, axis, keepdims, lead):
+    """The same by the kernel: the batch flattened to ``(records,
+    length)``, a bitcast where ``axis`` is the record's last (a series)."""
+    at = lead + axis
+    rows = jnp.moveaxis(a, at, -1)
+    out = _select_flat(rows.reshape(-1, a.shape[at]), perc)
+    out = jnp.moveaxis(out.reshape(rows.shape[:-1] + (1,)), -1, at)
+    return out if keepdims else jnp.squeeze(out, at)
+
+
+def _takes_kernel(ctx, tpu):
+    """Whether the program being lowered runs a ``"kernel"`` selection as
+    the kernel: lowered for a TPU (``tpu``: which rule asks), and for one
+    device of it or the inside of a fully manual ``shard_map``
+    (``ops/linalg.py :: _mosaic_fits``: GSPMD partitions no kernel)."""
+    from bolt_tpu.ops.linalg import _mosaic_fits
+    return tpu and _mosaic_fits(ctx)
+
+
+def _select_primitive():
+    """``percentile_select``: :func:`_select` of every record of a batch,
+    as a primitive because its executor is chosen when a program is
+    LOWERED (see the module's text, and ``ops/linalg.py``'s
+    ``jacobi_sweeps``).  The operand is ``lead`` batch axes in front of
+    one record's axes, ``axis`` the record's; the result keeps the batch.
+
+    Under ``vmap`` (``tpu/array.py :: _blocked_run`` maps the record
+    function over a block, an unblocked chain over each key axis in
+    turn) the rule moves the mapped axis to the front and binds again
+    with one more leading axis: ONE bind over the whole batch, however
+    deep the nesting.  The kernel's lowering flattens the batch to
+    ``(records, length)``; the fallback maps :func:`_select` over the
+    leading axes again (a reshape of key axes that GSPMD shards is not a
+    bitcast, so the bind itself folds nothing)."""
+    from jax._src import dispatch       # eager calls: jax's own cache
+    from jax.extend.core import Primitive
+    from jax.interpreters import batching, mlir
+    prim = Primitive("percentile_select")
+    prim.def_impl(partial(dispatch.apply_primitive, prim))
+
+    @prim.def_abstract_eval
+    def _(a, *, perc, axis, keepdims, lead):
+        at = lead + axis
+        return a.update(shape=a.shape[:at] + (1,) * keepdims
+                        + a.shape[at + 1:])
+
+    def lower(tpu):
+        def rule(ctx, a, **params):
+            fn = _by_passes
+            if _takes_kernel(ctx, tpu):
+                _engine.record_percentile_lowering("kernel")
+                fn = _by_kernel
+            return mlir.lower_fun(partial(fn, **params),
+                                  multiple_results=False)(ctx, a)
+        return rule
+
+    mlir.register_lowering(prim, lower(False))
+    mlir.register_lowering(prim, lower(True), platform="tpu")
+
+    def fold(args, dims, *, lead, **params):
+        a = jnp.moveaxis(args[0], dims[0], 0)
+        return prim.bind(a, lead=lead + 1, **params), 0
+
+    batching.primitive_batchers[prim] = fold
+    # what the blocks rule counts a record's selection as holding
+    # (tpu/blocks.py :: _sub_jaxprs): the passes and their image of keys
+    prim.fallback = _by_passes
+    return prim
+
+
+_select_p = _select_primitive()

@@ -16,23 +16,28 @@ What that costs on the device depends on what a record's function keeps:
   ``detrend -> sum`` over 10.74 GB takes none).
 * ``normalize(baseline="percentile")`` takes two order statistics of each
   record and ``fourier`` transforms it.  The order statistics are SELECTED
-  (``ops/select.py``: the k-th smallest built bit by bit, some eighteen
-  counting passes over the record and its image of integer keys, exact)
-  from a length on; a shorter record is sorted by ``jnp.percentile``, to
-  the same answer to the bit.  A loop of passes, a sort and an FFT keep
-  record-sized temporaries that XLA does not fuse away.  Over a small
-  array that changes nothing; over an array too large to hold them for
-  every record at once (a resident series array of HBM size asks for 20
-  and 40 GB) the consuming program runs the chain over BLOCKS of whole
+  (``ops/select.py``: the k-th smallest built bit by bit by counting
+  passes over the record's image of integer keys, exact) from a length
+  on; a shorter record is sorted by ``jnp.percentile``, to the same
+  answer to the bit.  In a program for one TPU device the passes run on
+  a tile of records held in VMEM, one Mosaic kernel that reads a block
+  ONCE (PR 40); everywhere else they are a loop of ``jax.numpy`` passes,
+  nineteen reads of a block.  That loop, the kernel, a sort and an FFT
+  keep record-sized temporaries that XLA does not fuse away.  Over a
+  small array that changes nothing; over an array too large to hold them
+  for every record at once (a resident series array of HBM size asks for
+  20 and 40 GB) the consuming program runs the chain over BLOCKS of whole
   records, chosen by a rule and not by the caller
   (``bolt_tpu/tpu/blocks.py``; ``analysis.explain`` says "blocked: n
   blocks of r records", and of a ``normalize`` stage "percentile by
-  selection" or "by sort").  Still ONE program an action, but not one
-  pass over HBM: the selection alone reads a block some twenty times
-  (PERF.md, PR 36 and PR 37).
-* ``fourier`` returns two deferred arrays over one deferred parent, so
-  fetching both runs the parent's chain twice (two programs): known, and
-  measured by the ``pixelseries512-1chip.tuning`` cell, not repaired.
+  selection, one read of a block", "by selection" or "by sort").  Still
+  ONE program an action, but not one pass over HBM (PERF.md, PRs 36, 37
+  and 40).
+* ``fourier`` returns two deferred arrays over one deferred parent.
+  Fetching both runs the parent's chain ONCE: the first force keeps the
+  parent's small result and each handle is a slice of it
+  (``tpu/array.py :: _lower_from_shared``, PR 39; measured by the
+  ``pixelseries512-1chip.tuning`` cell).
 
 Polynomial detrending is two thin matmuls per record against the
 precomputed Vandermonde ``A`` and its pseudo-inverse (``v - A @
@@ -302,7 +307,9 @@ def normalize(b, baseline="percentile", perc=20.0, axis=0, epsilon=0.0):
     sorting the record (``ops/select.py``) where it is long enough for
     that to pay, and ``jnp.percentile`` sorts a shorter one: the same
     value to the bit either way (engine counters
-    ``percentile_select_lowerings`` / ``percentile_sort_lowerings``).
+    ``percentile_select_lowerings`` / ``percentile_sort_lowerings``, and
+    ``percentile_kernel_lowerings`` where a selection was lowered as the
+    kernel that reads a block once).
     """
     if baseline not in ("percentile", "mean"):
         raise ValueError(

@@ -10,8 +10,13 @@ ARRAY, and a resident array over a third of HBM can take no such function.
 Compiled for the v5e at ``(512, 512, 10240)`` float32 (10.74 GB): a
 percentile by ``jnp.percentile``'s sort asks for 20.00 G of 15.75 G,
 ``fourier`` for 40.00 G (PERF.md, PR 36; since PR 37 the percentile of a
-record that long is selected, and holds the record's image of keys where
-the sort held its copy).
+record that long is selected, and held the record's image of keys where
+the sort held its copy; since PR 40 the selection is the primitive
+``percentile_select``, whose Mosaic kernel holds a tile's image in VMEM
+and needs its block of records written out as a buffer, and whose
+fallback is the loop of passes with the image as before: the record's
+jaxpr shows neither, only the primitive and its 4 bytes a record, so
+the rule walks the fallback the primitive names, as a call of it).
 
 The rule, static and with no knob.  The runs of maps of a chain (between
 its getitem windows) are traced ONCE, on ONE record's aval, to a jaxpr:
@@ -41,6 +46,8 @@ it always did: same HLO, same engine keys.  Off the TPU there is no limit
 (:func:`bolt_tpu.tpu.array._hbm_limit` is ``None``) and nothing is blocked.
 """
 
+from functools import partial
+
 import numpy as np
 
 import jax
@@ -48,12 +55,13 @@ from jax.extend.core import Literal
 
 # primitives that end a loop fusion and keep a result (and scratch) as
 # large as their operand: sorts (jnp.sort/argsort/percentile/median),
-# FFTs, cumulative scans, top-k, loops that carry per-record state (the
-# passes of ops/select.py's percentile among them), and the dense
-# decompositions
+# FFTs, cumulative scans, top-k, loops that carry per-record state, the
+# selection of ops/select.py's percentile (a custom call that XLA fuses
+# nothing into, or off the TPU the ``while`` of passes it stands for),
+# and the dense decompositions
 HEAVY = frozenset([
     "sort", "fft", "cumsum", "cumprod", "cummax", "cummin", "cumlogsumexp",
-    "top_k", "approx_top_k", "while", "scan",
+    "top_k", "approx_top_k", "while", "scan", "percentile_select",
     "cholesky", "lu", "qr", "eigh", "eig", "svd", "schur",
     "triangular_solve", "tridiagonal", "tridiagonal_solve"])
 
@@ -83,6 +91,13 @@ def _sub_jaxprs(eqn):
             inner = getattr(v, "jaxpr", v)      # ClosedJaxpr or Jaxpr
             if hasattr(inner, "eqns"):
                 yield inner
+    # a primitive whose executor is chosen at lowering shows nothing of
+    # what it holds; one that names its ``fallback`` (ops/select.py's
+    # ``percentile_select``) is counted as a call of it
+    fallback = getattr(eqn.primitive, "fallback", None)
+    if fallback is not None:
+        yield jax.make_jaxpr(partial(fallback, **eqn.params))(
+            *(v.aval for v in eqn.invars)).jaxpr
 
 
 def _walk(jaxpr):

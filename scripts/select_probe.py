@@ -1,21 +1,26 @@
 #!/usr/bin/env python3
-"""A percentile by XLA's sort against the same percentile by selection.
+"""A percentile by XLA's sort against the same percentile by selection,
+by passes over HBM and by the kernel that holds a tile in VMEM.
 
     python3 scripts/select_probe.py [--lengths 64 256 1024 4096 10240]
-        [--bits 1 2 4] [--gib 1.0] [--runs 5] [--perc 20] [--out file]
+        [--bits 1 2 4] [--kernel-bits 1 2 4] [--gib 1.0] [--runs 5]
+        [--perc 20] [--out file]
 
-The raw measurement under ``bolt_tpu/ops/select.py``'s two constants: the
+The raw measurement under ``bolt_tpu/ops/select.py``'s constants: the
 length from which a record's percentile is selected and not sorted
-(``_SELECT_FROM``), and how many bits of the key one pass decides
-(``_BITS_A_PASS``).  For each length, ``--gib`` GiB of float32 rows of that
+(``_SELECT_FROM``), how many bits of the key one pass over HBM decides
+(``_BITS_A_PASS``), how many a pass over a tile in VMEM decides
+(``_KERNEL_BITS``) and the length from which the kernel engages
+(``_KERNEL_FROM``).  For each length, ``--gib`` GiB of float32 rows of that
 length (14-bit integer counts with heavy ties, the kind the
 ``pixelseries512-1chip`` session holds, made on the device from a seed) go
-through ``jnp.percentile`` (XLA's sort) and through ``select._select`` at
-each ``--bits``, each as ONE jitted program over the whole array, ``--runs``
-timed calls after a warm-up; a reading is the median wall of a call,
-``block_until_ready`` inside it, as rows a second and as GB/s of rows.
-Every selection is also compared with the sort's answer bit for bit, on the
-device that ran both.  Needs a device that is not the CPU (refuses one: a
+through ``jnp.percentile`` (XLA's sort), through ``select._select`` at
+each ``--bits`` and, where the length is whole groups of 128 lanes, through
+``select._select_flat`` (the Mosaic kernel) at each ``--kernel-bits``, each
+as ONE jitted program over the whole array, ``--runs`` timed calls after a
+warm-up; a reading is the median wall of a call, ``block_until_ready``
+inside it, as rows a second and as GB/s of rows.  Every selection is also
+compared with the sort's answer bit for bit, on the device that ran both.  Needs a device that is not the CPU (refuses one: a
 CPU sort says nothing of the chip's).  Runs in no cell of the benchmark.
 
 The last line of standard output is the table as one JSON object; ``--out``
@@ -51,6 +56,7 @@ def main(argv=None):
     ap.add_argument("--lengths", type=int, nargs="+",
                     default=[64, 256, 1024, 4096, 10240])
     ap.add_argument("--bits", type=int, nargs="+", default=[1, 2, 4])
+    ap.add_argument("--kernel-bits", type=int, nargs="*", default=[1, 2, 4])
     ap.add_argument("--gib", type=float, default=1.0)
     ap.add_argument("--runs", type=int, default=5)
     ap.add_argument("--perc", type=float, default=20.0)
@@ -91,6 +97,13 @@ def main(argv=None):
                 v, args.perc, 1, False, b))
             wall, got = timed(by_select, x, args.runs)
             line["select%d" % bits] = dict(
+                reading(wall), equal_to_sort=bool(np.array_equal(
+                    np.asarray(got).view(np.int32), want)))
+        for bits in args.kernel_bits if n % select._LANES == 0 else ():
+            by_kernel = jax.jit(lambda v, b=bits: select._select_flat(
+                v, args.perc, b)[:, 0])
+            wall, got = timed(by_kernel, x, args.runs)
+            line["kernel%d" % bits] = dict(
                 reading(wall), equal_to_sort=bool(np.array_equal(
                     np.asarray(got).view(np.int32), want)))
         print(json.dumps(line), flush=True)

@@ -257,7 +257,24 @@ _MOSAIC_CASES = [
     ("packed_gram-%dx%dx%d" % shape, lambda x: _svdvals(x), [(shape, _F32)])
     for shape in [(6, 16384, 64), (3, 20000, 64), (5, 16384, 32),
                   (2, 65536, 16), (1, 65536, 8)]
+] + [
+    # ops/select.py's selection on a tile held in VMEM (ISSUE 40): a block
+    # of the benchmark's cell (its tiles of 64 records leave an edge of
+    # 40), the shortest record it takes, one of 130 lane-groups (a loop of
+    # unrolled spans), the longest a tile of 8 fits, a batch under 8, and
+    # the percentile that needs no neighbour
+    ("percentile_select-%dx%d-p%g" % (shape + (perc,)),
+     lambda x, perc=perc: _percentile(x, perc), [(shape, _F32)])
+    for shape, perc in [((5352, 10240), 20.0), ((5352, 10240), 100.0),
+                        ((4096, 1024), 20.0), ((100, 16640), 20.0),
+                        ((21, 98304), 50.0), ((5, 1280), 99.9)]
 ]
+
+
+def _percentile(x, perc):
+    import jax
+    from bolt_tpu.ops import select
+    return jax.vmap(lambda v: select.percentile(v, perc, 0, True))(x)
 
 
 def _jacobi_eigh(a, vectors=False):
@@ -1250,17 +1267,76 @@ def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
         assert mem.temp_size_in_bytes < 0.25 * free
         # and the rule's own estimate covers what the compiler needs
         assert mem.temp_size_in_bytes < _blocked_estimate(funcs, block)
-        # the percentile is selected, not sorted (PR 37): no sort in the
-        # optimised program, and the passes are a loop of their own
-        # inside the loop over blocks
+        # the percentile is selected, not sorted (PR 37), and on a tile
+        # held in VMEM (PR 40): no sort in the optimised program, ONE
+        # Mosaic call under the selection's scope inside the loop over
+        # blocks, and no loop of passes beside that loop
         text = compiled.as_text()
         assert not re.search(r"\bsort(\.\d+)? = |= \S+ sort\(", text)
         loops = re.findall(r" while\(.*?op_name=\"([^\"]*)\"", text)
-        assert len(loops) == 2
-        assert any(name.endswith("percentile_select)/while")
-                   and name.count("while") == 2 for name in loops)
+        assert len(loops) == 1 and "percentile_select" not in loops[0]
+        calls = re.findall(
+            r"custom_call_target=\"tpu_custom_call\".*?op_name=\"([^\"]*)\"",
+            text)
+        assert len(calls) == 1 and "while/body" in calls[0]
+        assert calls[0].endswith("percentile_select/pallas_call")
         with pytest.raises(Exception, match="Exceeded hbm capacity|hbm"):
             jax.jit(lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
+
+
+# which executor a selection gets is the lowering's to say, from the
+# record's length, the key's width and what the program is lowered for
+# (ISSUE 40): the kernel in a program for one v5e device; the passes for a
+# key Mosaic has no type for, a record of which 8 do not fit a tile, one
+# too short to hide a pass's cross-lane sums (select._KERNEL_FROM), a
+# length that is not whole lane-groups, and a program GSPMD partitions
+@pytest.mark.parametrize("name,shape,dtype,x64,kernel", [
+    ("the-cell's-block", (5352, 10240), "float32", False, True),
+    ("float32-under-x64", (64, 2048), "float32", True, True),
+    ("float64-under-x64", (64, 2048), "float64", True, False),
+    ("too-short-to-hide-a-pass", (64, 512), "float32", False, False),
+    ("too-long-for-a-tile", (16, 98304 + 128), "float32", False, False),
+    ("not-whole-lane-groups", (64, 2000), "float32", False, False),
+], ids=lambda v: v if isinstance(v, str) and "-" in v else None)
+def test_the_selections_executor_is_chosen_at_lowering_on_v5e(
+        v5e_device, name, shape, dtype, x64, kernel):
+    import jax
+    from bolt_tpu import engine
+    from bolt_tpu.ops import series
+    fn = series._normalize_fn("percentile", 20.0, 0, 0.0)
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    count = lambda: engine.counters()["percentile_kernel_lowerings"]
+    before = count()
+    with jax.enable_x64(x64):
+        arg = jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=where)
+        lowered = jax.jit(jax.vmap(fn)).lower(arg)
+        text = lowered.as_text()
+        if dtype != "float64":          # XLA's TPU compiler has no u64
+            lowered.compile()           # keys; the lowering is the point
+    assert count() == before + kernel, name
+    assert ("tpu_custom_call" in text) == kernel
+    # the passes are a ``while``; the kernel leaves none
+    assert ("stablehlo.while" in text) == (not kernel), name
+    assert "stablehlo.sort" not in text
+
+
+def test_the_selection_keeps_its_passes_in_a_program_for_four_chips(
+        v5e_device):
+    import jax
+    from jax.experimental import topologies
+    from bolt_tpu import engine
+    from bolt_tpu.ops import series
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("k",))
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
+    fn = series._normalize_fn("percentile", 20.0, 0, 0.0)
+    before = engine.counters()["percentile_kernel_lowerings"]
+    with jax.enable_x64(False):
+        text = jax.jit(jax.vmap(fn)).lower(jax.ShapeDtypeStruct(
+            (64, 2048), _F32, sharding=where)).compile().as_text()
+    assert engine.counters()["percentile_kernel_lowerings"] == before
+    assert "tpu_custom_call" not in text and " while(" in text
 
 
 def _blocked_estimate(funcs, block):
