@@ -1,42 +1,47 @@
-"""Streaming out-of-core executor: a parallel-ingest, async-dispatch
-host↔device pipeline.
+"""Streaming out-of-core executor: host data larger than device memory,
+run slab by slab through one ingest pool.
 
-Every other execution path in this backend materialises its operand fully
-in device memory before a terminal runs, which caps the workload class at
-HBM.  This module opens datasets LARGER than device memory: a lazy
-:class:`StreamSource` describes host-resident data as a sequence of
-record *slabs* (consecutive blocks along the first key axis) plus a chain
-of device-side stages (per-record maps, chunked maps, stacked maps, a
-trailing filter predicate), and :func:`execute` runs a reduction terminal
-over it as a pipelined fan-in:
+A lazy :class:`StreamSource` describes host-resident data as a sequence
+of record *slabs* (consecutive blocks along the first key axis) plus a
+chain of device-side stages (per-record maps, chunked maps, stacked
+maps, a trailing filter predicate, a recorded swap).  Every streamed
+run is the same four pieces::
 
-* an **N-way uploader pool** (default ``min(max(mesh devices, 2), 4)``:
-  two copies in flight even on ONE device's link;
-  ``BOLT_STREAM_UPLOAD_THREADS`` / the :func:`uploaders` scope) ingests
-  slabs concurrently — for random-access ``fromcallback`` sources each
-  worker produces AND uploads its own slab (per-device sub-blocks via
+    StreamSource -> _Run -> _IngestPool -> consumer: fold | place | spill
+
+* the **run set-up** (:class:`_Run`) resolves once, on the calling
+  thread, what the run is held to: prefetch depth and pool size
+  (default ``min(max(mesh devices, 2), 4)``: two copies in flight even
+  on ONE device's link; ``BOLT_STREAM_UPLOAD_THREADS`` / the
+  :func:`uploaders` scope), the ingest codec, the pod slice, the tenant
+  and its arbiter lease, the retry budget;
+* the **ingest pool** (:class:`_IngestPool`) turns host blocks into
+  uploaded slabs.  For random-access ``fromcallback`` sources each of N
+  workers produces AND uploads its own slab (per-device sub-blocks via
   ``parallel.sharding.device_placements``), so one CPU thread is never
   the bottleneck feeding many chips; sequential ``fromiter`` sources
-  keep one produce+upload prefetch thread.  A **re-sequencer** hands
-  completed slabs to the consumer strictly in slab order, so the fold
-  is deterministic and bit-exact regardless of upload completion order;
-* slab buffers form a **ring** bounded by ``prefetch depth + pool
-  size``, and each is **donated** into its per-slab program
-  (``donate_argnums``), so XLA recycles the ring's device memory
-  instead of allocating per slab;
-* slab programs **dispatch asynchronously** into a bounded in-flight
-  window — no per-slab ``block_until_ready``; the consumer syncs only
-  on window overflow (an already-retired old partial, ~free) and on the
-  final result, so device compute and host ingest overlap fully;
-* reduction terminals fold per-slab partials ON DEVICE — the **level-0
+  keep one produce+upload thread.  A **re-sequencer** (:class:`_Reseq`)
+  hands slabs to the consumer strictly in slab order whatever order the
+  uploads finish in, so every consumer is deterministic.  Slab buffers
+  form a **ring** bounded by ``prefetch depth + pool size``: a permit
+  (and, under ``bolt_tpu.serve``, the slab's wire bytes from the
+  device-memory arbiter) is taken per slab in slab order and comes back
+  when the consumer says the slab's program retired;
+* the **consumer** is the calling thread.  :func:`execute` FOLDS a
+  reduction terminal: slab programs **dispatch asynchronously** into a
+  bounded in-flight window — no per-slab ``block_until_ready``; it
+  syncs only on window overflow (an already-retired old partial, ~free)
+  and on the final result, so device compute and host ingest overlap.
+  Each ring buffer is **donated** into its slab program, the **level-0
   fold is fused into the slab program** (odd slabs run ``prog(buf,
-  acc)``, merging with the preceding slab's partial in the same
-  dispatch — half the fold dispatches), and a pairwise tree of
-  ``add``/``func`` merges for ``sum``/``reduce``, a Welford/Chan
-  statcounter-moment merge (``n, μ, M2``) for ``mean``/``var``/``std``,
-  combines pair-partials above level 0 — so host traffic is one slab
-  in, one value-block out, and power-of-two slab counts keep the Chan
-  denominators exact.
+  acc)``: half the fold dispatches), and pair-partials above level 0
+  combine as a pairwise tree (``add``/``func`` for ``sum``/``reduce``,
+  a Welford/Chan ``n, μ, M2`` merge for ``mean``/``var``/``std``:
+  power-of-two slab counts keep the Chan denominators exact).
+  :func:`_resolve_one_swap` PLACES each slab into a resident re-keyed
+  array (a recorded swap, or :func:`collect`'s mapped result) or SPILLS
+  its buckets to files that stream again as a fresh source
+  (``bolt_tpu.parallel.shuffle``).
 
 The per-slab program applies the SAME traced bodies the materialised
 paths compile (``tpu/chunk.py :: _uniform_map_body`` /
@@ -45,73 +50,56 @@ paths compile (``tpu/chunk.py :: _uniform_map_body`` /
 materialised results cannot drift semantically — the out-of-core parity
 suite (``tests/test_stream.py``) bit-compares them.
 
-Accounting lands in the engine counters (``transfer_bytes`` /
-``transfer_seconds`` for every counted upload — the seconds are the
-link's busy time, counted once where the pool's copies overlap, with
-each copy's own in ``transfer_copy_seconds`` — and the ``stream_*`` family
-for the executor — including ``stream_upload_threads``, the observed
-concurrent-uploader high-water, and ``stream_inflight_high_water``, the
-async dispatch window's peak).  Ingest/compute seconds are attributed
-from the same instrumented regions the obs spans cover (worker
-``stream.ingest`` spans, consumer ``stream.compute`` dispatch +
-``stream.sync`` windows), NOT from wall-clock around a per-slab sync;
-:func:`bolt_tpu.profile.overlap_efficiency` reports the fraction of
-ingest time hidden behind device compute — ``max(0, ingest + compute -
-wall) / ingest`` per run.
+Accounting lands in the engine counters: ``transfer_bytes`` /
+``transfer_seconds`` for every counted upload (the link's busy time,
+counted once where the pool's copies overlap; each copy's own in
+``transfer_copy_seconds``) and the ``stream_*`` family for a run, whose
+ingest/compute seconds come from the regions the obs spans cover (pool
+``stream.ingest``, consumer ``stream.compute`` + ``stream.sync``), NOT
+from wall-clock around a per-slab sync.
 
 Fault model (ISSUE 9 made it three-tiered):
 
-* **fail-fast** (the default): a source callback or uploader worker that
-  raises mid-stream aborts cleanly — the whole pool is joined, queued
-  ring buffers are released, the partial reduction state is discarded,
-  and the ORIGINAL exception is re-raised to the caller.  A pool thread
-  that dies WITHOUT delivering (interpreter teardown, a killed thread)
-  is detected by the consumer's liveness poll, which raises a pointed
-  ``RuntimeError`` naming the dead thread instead of blocking forever;
+* **fail-fast** (the default): a source callback or pool thread that
+  raises mid-stream aborts cleanly — the pool is joined, queued ring
+  buffers are released, and the ORIGINAL exception is re-raised to the
+  caller.  A pool thread that dies WITHOUT delivering is named by the
+  consumer's liveness poll instead of blocking it forever;
 * **in-run retry** (``stream.retries(n)`` / ``BOLT_STREAM_RETRIES``): a
-  failed slab ingest is re-attempted up to *n* times before poisoning
-  the run — the slab re-runs in place on its worker, fenced through the
-  re-sequencer so a late duplicate of an earlier attempt can never
-  double-fold, and when the budget exhausts the final error chains every
-  attempt's exception back to the original failure;
-* **resume** (``stream.resumable(dir)`` / ``fromcallback``/``fromiter``
-  ``checkpoint=dir``): every ``BOLT_CHECKPOINT_EVERY`` retired slabs the
-  executor drains its async window and persists the retired-slab
-  watermark plus the folded partial accumulator (pairwise-tree levels +
-  the unpaired pair partial — moment triples and fused multi-stat
-  tuples included) via ``bolt_tpu.checkpoint.stream_save``.  A killed
-  run (preemption, ``kill -9``) restarted over the same source skips the
-  already-retired slabs, reloads the exact fold state, and produces a
-  result BIT-IDENTICAL to the uninterrupted run — the fold is a
-  deterministic function of (slab order, accumulator state), both of
-  which the checkpoint captures exactly.  A finished run clears its
-  checkpoint (no stale files).  Deterministic fault points for all of
-  this live in ``bolt_tpu._chaos`` (seams: ``stream.upload``,
-  ``stream.dispatch``, ``stream.fold``, ``stream.checkpoint``).
+  failed slab ingest re-runs in place up to *n* times, fenced through
+  the re-sequencer so a late duplicate can never be consumed twice; the
+  final error chains every attempt back to the original;
+* **resume** (``stream.resumable(dir)`` / a source's ``checkpoint=``;
+  :func:`execute` only): every ``BOLT_CHECKPOINT_EVERY`` retired slabs
+  the fold drains its window and persists the retired-slab watermark
+  plus the folded accumulator (``checkpoint.stream_save``).  A killed
+  run restarted over the same source skips the retired slabs, reloads
+  the exact fold state, and is BIT-IDENTICAL to the uninterrupted run —
+  the fold is a deterministic function of (slab order, accumulator
+  state).  A finished run clears its checkpoint; a spilled swap resumes
+  from its bucket manifest the same way.  Deterministic fault points
+  live in ``bolt_tpu._chaos`` (seams ``stream.upload``, ``.dispatch``,
+  ``.fold``, ``.checkpoint``, ``.shuffle``, ``.spill``).
 
 POD SCALE (``bolt_tpu.parallel.multihost``): on a mesh spanning
-PROCESSES this same executor runs as N peers over one deterministic
-slab schedule.  Each process produces and uploads ONLY its own
-contiguous shard of every slab (``multihost.local_slab_spec`` — the
-``fromcallback(..., per_process=True)`` contract; ``fromiter``
-re-iterable sources slice their shard out of each global block), the
-global slab array is glued from local parts with zero cross-host
-motion, and the slab program runs under ``shard_map`` with the
-cross-host fold as mesh-axis collectives (``psum`` for sum and the
-moment components, ``pmin``/``pmax`` for order statistics) — so one
-streamed slab costs one collective (two for moments) and every fold
-partial comes back replicated.  Slabs dispatch in slab order on every
-process, so the collective rendezvous can never cross; uneven slabs
-refuse with the pointed BLT012 error before any thread starts; and
-checkpoints become per-process shard files with a
-rendezvous-consistent watermark (``checkpoint.stream_save``).  On even
-splits the hierarchical sums equal the flat sums whenever the data
-keeps the reduction exact, so results stay bit-identical to the
-single-process run (tests/test_multihost.py proves it on a REAL
-2-process ``jax.distributed`` localhost cluster).
+PROCESSES the same run is N peers over one deterministic slab schedule.
+Each process produces and uploads ONLY its own contiguous shard of
+every slab (``multihost.local_slab_spec`` — the ``fromcallback(...,
+per_process=True)`` contract; ``fromiter`` re-iterable sources slice
+their shard out of each global block), the global slab is glued from
+local parts with zero cross-host motion, and the slab program runs under
+``shard_map`` with the cross-host combine as mesh collectives
+(``psum``/``pmin``/``pmax`` for a fold, one ``all_to_all`` for a
+re-bucket).  Slabs dispatch in slab order on every process, so the
+rendezvous can never cross; uneven slabs refuse (BLT012) before any
+thread starts; checkpoints become per-process shard files with a
+rendezvous-consistent watermark.  On even splits results stay
+bit-identical to the single-process run (tests/test_multihost.py, on a
+REAL 2-process ``jax.distributed`` localhost cluster).
 """
 
 import contextlib
+import functools
 import os
 import queue
 import sys
@@ -131,7 +119,7 @@ from bolt_tpu.obs import trace as _obs
 from bolt_tpu.obs.trace import clock as _clock
 from bolt_tpu.parallel import multihost as _multihost
 from bolt_tpu.parallel import podwatch as _podwatch
-from bolt_tpu.utils import iter_record_blocks, prod
+from bolt_tpu.utils import chain_retry_step, iter_record_blocks, prod
 
 # ---------------------------------------------------------------------
 # configuration
@@ -141,8 +129,9 @@ from bolt_tpu.utils import iter_record_blocks, prod
 # consumer beyond the uploader pool's own hands-on slabs (the ring is
 # bounded at depth + pool size).  2 = classic double buffering: one slab
 # in compute, one in flight.  Deeper rings only help when per-slab
-# ingest time is noisy; they cost one slab of HBM each.
-_DEPTH = max(1, int(os.environ.get("BOLT_STREAM_DEPTH", "2")))
+# ingest time is noisy (0.3 % on the chip: PERF.md section 5, PR 35);
+# they cost one slab of HBM each.
+_DEPTH = 2
 
 # uploader pool size: concurrent ingest workers.  0 = auto, resolved per
 # run as min(max(mesh device count, _LINK_COPIES), 4) — one host thread
@@ -181,8 +170,9 @@ def _scope_stack(name):
 
 # default slab budget when the caller gives no explicit record count:
 # big enough to amortise per-dispatch overhead, small enough that
-# depth+1 slabs stay far below any device's HBM
-_SLAB_BYTES = int(os.environ.get("BOLT_STREAM_SLAB_BYTES", str(64 << 20)))
+# depth+1 slabs stay far below any device's HBM (64 and 128 MiB read
+# alike on the chip: PERF.md section 5, PR 35)
+_SLAB_BYTES = 64 << 20
 
 
 def prefetch_depth():
@@ -523,23 +513,6 @@ def _cached_jit(key, builder):
     """Engine-routed executable dispatch (same contract as the op
     modules'; ``bolt_tpu.profile.instrument`` patches this name)."""
     return _engine.get(key, builder)
-
-
-def _tenant_lease():
-    """A device-memory lease from the ACTIVE serving arbiter
-    (``bolt_tpu.serve``), attributed to the calling thread's tenant —
-    or ``None`` when no serving layer is running.  Consulted through
-    ``sys.modules`` so merely streaming never imports (or starts) the
-    serving layer; with a lease in hand the executor's slab uploads
-    charge the process-wide bytes budget instead of assuming sole
-    ownership of device memory."""
-    sv = sys.modules.get("bolt_tpu.serve")
-    if sv is None:
-        return None
-    arb = sv.device_arbiter()
-    if arb is None:
-        return None
-    return arb.lease(_engine.current_tenant() or "default")
 
 
 # ---------------------------------------------------------------------
@@ -1760,6 +1733,344 @@ def _acquire(sem, stop):
     return False
 
 
+class _Run:
+    """What one streamed run is held to, resolved ONCE on the calling
+    thread (the scopes are per-thread: a pool thread could not), for
+    both consumers of the ingest pool alike."""
+
+    __slots__ = ("depth", "nwork", "codec", "delta_ok", "wire_item",
+                 "mspec", "tenant", "lease", "nretry")
+
+    def __init__(self, source):
+        self.depth = prefetch_depth()
+        self.nwork = pool_size(source)
+        # the source's own codec= wins over the scope; integer/bool
+        # pipelines refuse lossy codecs pointedly (Codec.wire_dtype)
+        codec_obj = self.codec = resolve_codec(source)
+        self.delta_ok = source.split < len(source.shape)
+        # the arbiter leases WIRE bytes, what occupies the ring and
+        # crossed the link (analysis.admission_floor_bytes: same ratio)
+        self.wire_item = (codec_obj.wire_dtype(source.dtype).itemsize
+                          if codec_obj is not None
+                          else source.dtype.itemsize)
+        # a mesh spanning processes: this run is one of N peers over the
+        # SAME slab schedule, each ingesting its own shard of every slab
+        self.mspec = None
+        if _multihost.mesh_process_count(source.mesh) > 1:
+            err = _multihost.slab_divisibility_error(
+                source.mesh, source.shape, source.split,
+                source.slab_ranges() if source.kind == "callback" else [])
+            if err is not None:
+                raise ValueError(err)       # BLT012 — check() forecasts it
+            err = _multihost.sidecar_codec_error(codec_obj, source.mesh)
+            if err is not None:
+                raise ValueError(err)       # per-process sidecars cannot
+                #                             feed a shard_map slab program
+            self.mspec = _multihost.local_slab_spec(source)
+        # the tenant tag rides into the pool threads, so their transfers
+        # land in the submitter's counters, and under an ACTIVE serving
+        # arbiter the run leases its slab bytes from the process-wide
+        # budget instead of assuming sole ownership of device memory
+        # (through sys.modules: streaming never imports bolt_tpu.serve)
+        self.tenant = _engine.current_tenant()
+        sv = sys.modules.get("bolt_tpu.serve")
+        arb = sv.device_arbiter() if sv is not None else None
+        self.lease = (arb.lease(self.tenant or "default")
+                      if arb is not None else None)
+        self.nretry = retry_limit()
+
+
+class _IngestPool:
+    """The uploader pool of one streamed run: host blocks in, uploaded
+    slabs out STRICTLY in slab order, never more than ``ring`` of them
+    dispensed and not yet given back.
+
+    A callback source is driven by ``jobs``, a list of ``(index, lo,
+    hi)``: the lead thread takes a ring permit AND the run's arbiter
+    bytes per job in list order (a tenant's own slabs can then never
+    deadlock each other by acquiring out of order) and ``run.nwork``
+    workers produce AND upload their own slabs concurrently.  An
+    iterator source (``jobs=None``) is driven by ``blocks``, a callable
+    giving the iterator of ``(lo, hi, block)``: ONE thread pulls and
+    uploads, its slabs indexed from ``first``.  The consumer calls
+    :meth:`start`, takes ``(index, buf, nbytes, seconds, hi)`` from
+    :meth:`next` until ``None``, gives slots and bytes back as its
+    programs retire, and ALWAYS calls :meth:`close`.  ``noun`` names a
+    slab in the retry errors; ``parent`` is the run's span the
+    ``stream.ingest`` spans nest under (nesting does not cross
+    threads)."""
+
+    def __init__(self, run, source, ring, jobs=None, blocks=None, first=0,
+                 noun="slab", parent=None):
+        self._run = run
+        self._source = source
+        self._jobs = jobs
+        self._blocks = blocks or source.slabs
+        self._first = first
+        self._noun = noun
+        self._parent = parent
+        self._permits = threading.Semaphore(ring)
+        self._stop = threading.Event()
+        self._rsq = _Reseq()
+        self._jobq = queue.Queue()
+        # concurrent uploaders at once, and the most there ever were
+        self._hw_lock = _lockdep.lock("stream.uploader_hw")
+        self._active = 0
+        self.high_water = 0
+        lead = threading.Thread(
+            target=self._prefetch if jobs is None else self._dispense,
+            name="bolt-stream-prefetch", daemon=True)
+        workers = () if jobs is None else tuple(
+            threading.Thread(target=self._work, args=(w,),
+                             name="bolt-stream-upload-%d" % w, daemon=True)
+            for w in range(run.nwork))
+        self.threads = (lead,) + workers
+        self._ingesters = workers or (lead,)    # who delivers slabs
+
+    # -- the consumer's surface ---------------------------------------
+
+    def start(self):
+        global _LAST_THREAD, _LAST_POOL
+        _LAST_THREAD, _LAST_POOL = self.threads[0], self.threads
+        for th in self.threads:
+            th.start()
+
+    def next(self, idle=None):
+        """The next slab in order, ``None`` at end-of-stream; re-raises
+        a pool fault and names dead threads (:meth:`_Reseq.next`, which
+        says what ``idle`` is for)."""
+        got = self._rsq.next(self.threads, workers=self._ingesters,
+                             idle=idle)
+        return None if got is None else got[1]
+
+    def give_back(self, slabs, nbytes):
+        """Return ``slabs`` ring permits and ``nbytes`` lease bytes (the
+        ``nbytes`` each slab came with: releases must mirror acquires or
+        the serve budget drifts)."""
+        if slabs:
+            self._permits.release(slabs)
+        if self._run.lease is not None:
+            self._run.lease.release(nbytes)
+
+    def retry(self, index, attempt, prev, exc, what=None):
+        """One failed attempt at slab ``index``: burn a retry (record +
+        chain the attempt's exception) or raise the run-poisoning final
+        error, by the policy stream AND serve share
+        (``utils.chain_retry_step``: at budget 0 the ORIGINAL exception
+        propagates untouched)."""
+        allowed = attempt < self._run.nretry and not self._stop.is_set()
+        if allowed:
+            _engine.record_stream_retry()
+            _obs.event("stream.retry", slab=index, attempt=attempt + 1,
+                       error=type(exc).__name__)
+        return chain_retry_step(
+            exc, prev, attempt, allowed,
+            "%s %d" % (what or self._noun, index),
+            "stream.retries / BOLT_STREAM_RETRIES")
+
+    def close(self):
+        """Stop, join every thread, release the queued ring buffers."""
+        self._stop.set()
+        # the consumer's OWN poison pills: a dispenser killed before its
+        # finally ran leaves workers blocked in jobq.get(), and the joins
+        # below would be the very hang the liveness guard reports
+        for _ in self.threads:
+            self._jobq.put(None)
+        for th in self.threads:
+            th.join()
+        self._rsq.drain()
+
+    # -- the pool threads ---------------------------------------------
+
+    def _enter(self):
+        with self._hw_lock:
+            self._active += 1
+            if self._active > self.high_water:
+                self.high_water = self._active
+
+    def _exit(self):
+        with self._hw_lock:
+            self._active -= 1
+
+    def _local(self, lo, hi):
+        """The records of slab ``[lo, hi)`` THIS process ingests: all of
+        them, or on a pod its own shard, in global coordinates (an
+        indivisible slab raises the pointed BLT012 error)."""
+        mspec = self._run.mspec
+        return (lo, hi) if mspec is None else mspec.local_range(lo, hi)
+
+    def _encode_upload(self, block, lo, hi, llo):
+        """Encode (when a codec is armed) + upload the host block that
+        starts at record ``llo`` of slab ``[lo, hi)``; returns ``(buf,
+        wire_nbytes)``.  ``buf`` is the bare sharded array, or for
+        sidecar codecs a ``(wire, *sidecar)`` tuple whose every leaf the
+        slab program donates.  Codecs change only the dtype, so the
+        per-device placement math is untouched."""
+        run, source = self._run, self._source
+        side = ()
+        if run.codec is None:
+            payload = block
+        else:
+            payload, side = _encode_slab(run.codec, block, run.delta_ok)
+        if run.mspec is None:
+            # through the module-level name: the tests' patch point
+            buf = _upload_slab(payload, source.mesh, source.split)
+        else:
+            buf = _upload_slab_mh(payload, source.mesh, source.split,
+                                  run.mspec.slab_shape(lo, hi), llo - lo)
+        if side:
+            # int8's scale/zero point, counted through the ONE door
+            buf = (buf,) + tuple(transfer(np.asarray(s)) for s in side)
+        return buf, int(payload.nbytes)
+
+    def _dispense(self):
+        run, stop = self._run, self._stop
+        rec_bytes = prod(self._source.shape[1:]) * run.wire_item
+        try:
+            for j, (g, lo, hi) in enumerate(self._jobs):
+                if not _acquire(self._permits, stop):
+                    return
+                if run.lease is not None:
+                    llo, lhi = self._local(lo, hi)
+                    if not run.lease.acquire((lhi - llo) * rec_bytes,
+                                             stop=stop):
+                        return
+                self._jobq.put((j, g, lo, hi))
+            self._rsq.finish(len(self._jobs))
+        except BaseException as exc:        # noqa: BLE001 — re-raised in
+            self._rsq.fault(exc)            # the consumer thread
+        finally:
+            for _ in self._ingesters:
+                self._jobq.put(None)        # poison pills: pool drains
+
+    def _work(self, wid):
+        run, source, stop = self._run, self._source, self._stop
+        try:
+            with _engine.tenant(run.tenant):
+                while True:
+                    job = self._jobq.get()
+                    if job is None or stop.is_set():
+                        return
+                    j, g, lo, hi = job
+                    attempt = 0
+                    prev = None
+                    while True:
+                        self._enter()
+                        sp = _obs.begin("stream.ingest",
+                                        parent=self._parent, slab=g,
+                                        worker=wid, attempt=attempt)
+                        t0 = _clock()
+                        try:
+                            llo, lhi = self._local(lo, hi)
+                            block = source.produce_slab(llo, lhi)
+                            buf, bnb = self._encode_upload(block, lo, hi,
+                                                           llo)
+                            tsec = _clock() - t0
+                            if sp is not None:
+                                sp.set(bytes=bnb, lo=lo, hi=hi)
+                        except BaseException as exc:  # noqa: BLE001
+                            _obs.end(sp, error=type(exc).__name__)
+                            self._exit()
+                            # retry IN PLACE on this worker (the job
+                            # keeps its ring permit and arbiter bytes);
+                            # the re-sequencer fences any duplicate
+                            prev = self.retry(g, attempt, prev, exc)
+                            attempt += 1
+                            continue
+                        _obs.end(sp)
+                        self._exit()
+                        break
+                    del block          # bnb = the LOCAL WIRE bytes this
+                    #                    process acquired and uploaded
+                    self._rsq.put(j, (g, buf, bnb, tsec, hi))
+        except BaseException as exc:        # noqa: BLE001 — re-raised in
+            self._rsq.fault(exc)            # the consumer thread
+
+    def _prefetch(self):
+        """The ingest span/time covers produce AND upload, like a
+        worker's; the ring permit is taken BEFORE the pull (one host
+        block in hand, never two), the arbiter bytes after it (the size
+        of an iterator's slab is known only with the block in hand)."""
+        run, stop = self._run, self._stop
+        j = 0
+        try:
+            with _engine.tenant(run.tenant):
+                it = self._blocks()
+                while True:
+                    if stop.is_set() or not _acquire(self._permits, stop):
+                        return
+                    g = self._first + j
+                    self._enter()
+                    sp = _obs.begin("stream.ingest", parent=self._parent,
+                                    slab=g)
+                    t0 = _clock()
+                    try:
+                        try:
+                            lo, hi, block = next(it)
+                        except StopIteration:
+                            _obs.cancel(sp)   # probe saw end-of-source
+                            sp = None
+                            self._permits.release()  # unused hand slot
+                            break
+                        # on a pod every process walks the SAME
+                        # re-iterable block sequence and keeps its slice
+                        llo, lhi = self._local(lo, hi)
+                        block = block[llo - lo:lhi - lo]
+                        if run.lease is not None and not run.lease.acquire(
+                                int(block.size) * run.wire_item, stop=stop):
+                            return
+                        attempt = 0
+                        prev = None
+                        while True:
+                            try:
+                                buf, bnb = self._encode_upload(
+                                    block, lo, hi, llo)
+                                break
+                            except BaseException as exc:  # noqa: BLE001
+                                # the block is in hand (an iterator
+                                # cannot re-produce it), so the retry
+                                # budget covers the ENCODE + UPLOAD here
+                                prev = self.retry(g, attempt, prev, exc)
+                                attempt += 1
+                        tsec = _clock() - t0
+                        if sp is not None:
+                            sp.set(bytes=bnb, lo=lo, hi=hi)
+                    finally:
+                        _obs.end(sp)
+                        self._exit()
+                    del block
+                    self._rsq.put(j, (g, buf, bnb, tsec, hi))
+                    j += 1
+                self._rsq.finish(j)
+        except BaseException as exc:        # noqa: BLE001
+            self._rsq.fault(exc)
+
+
+def _skip_retired(source, nslabs, nrecords):
+    """The block iterator of an iterator source with the ``nslabs`` slabs
+    a resume checkpoint already retired drained off it, checking that
+    the block layout still cuts at the checkpointed record (a drifted
+    iterator would silently corrupt the fold — refuse instead)."""
+    it = source.slabs()
+    skipped_hi = 0
+    for k in range(nslabs):
+        try:
+            _, skipped_hi, blk = next(it)
+        except StopIteration:
+            raise RuntimeError(
+                "resume checkpoint covers %d slabs but this iterator "
+                "ended after %d; the source is not the one the "
+                "checkpoint was cut from" % (nslabs, k))
+        del blk
+    if skipped_hi != nrecords:
+        raise RuntimeError(
+            "resume checkpoint was cut at record %d but this iterator's "
+            "first %d slab(s) cover %d records — the block layout "
+            "drifted; delete the checkpoint or restore the original "
+            "source" % (nrecords, nslabs, skipped_hi))
+    return it
+
+
 def _pod_sync(x, pod, phase, slab=None):
     """``block_until_ready`` with the pod watchdog armed (ISSUE 11).
 
@@ -1815,7 +2126,6 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     terminal would be.  ``source`` overrides ``arr._stream`` for
     callers resolving already-detached pending handles (``arr=None``
     skips the strict gate — the handle was gated at creation)."""
-    global _LAST_THREAD, _LAST_POOL
     from bolt_tpu.tpu.array import BoltArrayTPU
     comps = _multi_comps(specs) if terminal == "multi" else None
     if source is None:
@@ -1829,16 +2139,11 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
             "internal: execute() received a source with an unresolved "
             "swap stage — the terminal doors resolve swaps first "
             "(stream.resolve_swaps)")
-    mesh = source.mesh
-    split = source.split
-    depth = prefetch_depth()
-    nwork = pool_size(source)
-    # codec-encoded ingest (ISSUE 14): resolved ONCE per run (scopes
-    # are per-thread; the source's own codec= wins), validated against
-    # the dtype (integer/bool pipelines refuse lossy codecs pointedly
-    # in Codec.wire_dtype) and against the terminal: order statistics
-    # are bit-exactness-sensitive, so lossy codecs refuse them.
-    codec_obj = resolve_codec(source)
+    run = _Run(source)
+    mesh, depth, nwork = source.mesh, run.depth, run.nwork
+    codec_obj, mspec, lease = run.codec, run.mspec, run.lease
+    # order statistics are bit-exactness-sensitive, so lossy codecs
+    # refuse them
     if codec_obj is not None and not codec_obj.lossless:
         order = terminal in ("min", "max") or (
             terminal == "multi"
@@ -1851,45 +2156,6 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 "a quantised extremum is never the answer the caller "
                 "meant.  Use the lossless 'delta-f32' codec, or stream "
                 "this terminal uncompressed" % (codec_obj.name, names))
-    delta_ok = split < len(source.shape)
-    wire_rec_bytes = prod(source.shape[1:]) * (
-        codec_obj.wire_dtype(source.dtype).itemsize
-        if codec_obj is not None else source.dtype.itemsize)
-    # POD-SCALE run (parallel.multihost): the mesh spans processes, so
-    # this executor instance is one of N peers running the SAME slab
-    # schedule — each process produces and uploads only its own shard
-    # of each slab (mspec.local_range), the slab programs are
-    # shard_map'd with mesh-axis collectives doing the cross-host fold,
-    # and every fold partial comes back replicated.  Slab order is
-    # deterministic (the re-sequencer delivers strictly in order), so
-    # every process enqueues the collective programs identically — the
-    # rendezvous can never cross.
-    mspec = None
-    if _multihost.mesh_process_count(mesh) > 1:
-        err = _multihost.slab_divisibility_error(
-            mesh, source.shape, source.split,
-            source.slab_ranges() if source.kind == "callback" else [])
-        if err is not None:
-            raise ValueError(err)       # BLT012 — check() forecasts it
-        err = _multihost.sidecar_codec_error(codec_obj, mesh)
-        if err is not None:
-            raise ValueError(err)       # per-process sidecars cannot
-            #                             feed a shard_map slab program
-        mspec = _multihost.local_slab_spec(source)
-    # multi-tenant serving (bolt_tpu.serve): the run charges its slab
-    # bytes to the process-wide device-memory arbiter — the ring's local
-    # permit bound still applies, but N concurrent tenants now share one
-    # HBM budget instead of each assuming sole ownership.  The tenant
-    # tag rides into the pool threads so their transfer accounting lands
-    # in the submitting tenant's scoped counters.
-    tenant_tag = _engine.current_tenant()
-    lease = _tenant_lease()
-    nretry = retry_limit()          # resolved HERE: scopes are per-thread
-    # the arbiter leases COMPRESSED slab bytes: what actually occupies
-    # the ring and crossed the link is the WIRE representation, so a
-    # codec-encoded tenant's admission floor shrinks by the wire ratio
-    # (analysis.admission_floor_bytes applies the same ratio)
-    rec_bytes = wire_rec_bytes
     # resumable checkpointing (ISSUE 9): a per-source checkpoint dir
     # (fromcallback/fromiter checkpoint=) wins over the thread's
     # resumable() scope.  A matching checkpoint from a killed run is
@@ -1951,297 +2217,30 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                        records=resume_records,
                        **({"remapped_from": ck_remap}
                           if ck_remap is not None else {}))
-    ranges = source.slab_ranges()[start_slab:] \
-        if source.kind == "callback" else None
-    total_slabs = len(ranges) if ranges is not None else None
-    # the donated ring: at most depth + pool-size slab buffers exist at
-    # once (each worker holds one in hand, depth more may wait uploaded
-    # or dispatched-unconfirmed).  A permit is acquired per dispensed
-    # slab and released when the consumer CONFIRMS its program retired
-    # (the in-flight window sync) — so ring memory stays capped even
-    # though dispatch is async.
+    # the retired slabs never reach the pool: a callback's jobs start
+    # past them, an iterator's prefix is drained before its first pull
+    jobs = blocks = None
+    if source.kind == "callback":
+        jobs = [(start_slab + k, lo, hi) for k, (lo, hi)
+                in enumerate(source.slab_ranges()[start_slab:])]
+    else:
+        blocks = functools.partial(_skip_retired, source, start_slab,
+                                   resume_records)
+    total_slabs = len(jobs) if jobs is not None else None
+    # each worker holds one slab in hand, `depth` more may wait uploaded
+    # or dispatched-unconfirmed
     ring = depth + nwork
     # the consumer confirms (and hands permits back) once MORE than
     # `depth` slabs are dispatched and unconfirmed, so a slot stays free
-    # for EVERY worker's hand.  `ring - 1`, the same number for one
-    # worker, gave permits back a pair at a time and only with the ring
-    # full: a pool of any size then ran two workers, started together
-    # (PERF.md section 5, PR 35)
+    # for EVERY worker's hand (at `ring - 1` a pool of any size ran two
+    # workers, started together: PERF.md section 5, PR 35)
     window = ring - nwork
-    permits = threading.Semaphore(ring)
-    stop = threading.Event()
-    rsq = _Reseq()
-    # concurrent-uploader accounting (the parallel-ingest proof in the
-    # engine counters: stream_upload_threads records the high-water)
-    act_lock = _lockdep.lock("stream.uploader_hw")
-    act = {"n": 0, "hw": 0}
-
-    def _act_enter():
-        with act_lock:
-            act["n"] += 1
-            if act["n"] > act["hw"]:
-                act["hw"] = act["n"]
-
-    def _act_exit():
-        with act_lock:
-            act["n"] -= 1
-
-    # spans the pool threads begin parent under THIS run's span by
-    # explicit handoff (thread-local nesting does not cross threads):
-    # the exported timeline then shows ingest slabs under the run that
-    # caused them, overlapping the main thread's compute slabs
     run_sp = _obs.begin("stream.run", terminal=terminal, depth=depth,
                         uploaders=nwork, kind=source.kind,
                         **({"codec": codec_obj.name}
                            if codec_obj is not None else {}))
-
-    jobq = queue.Queue()
-
-    def _encode_upload(block, slab_shape, axis0_off):
-        """Encode (when a codec is armed) + upload ONE host block;
-        returns ``(buf, wire_nbytes)``.  ``buf`` is the bare sharded
-        wire/raw array, or — for sidecar codecs — a ``(wire, *sidecar)``
-        tuple whose every leaf the slab program donates.  The wire
-        block keeps the raw block's SHAPE (codecs change only the
-        dtype), so the per-device placement math is untouched."""
-        side = ()
-        if codec_obj is None:
-            payload = block
-        else:
-            payload, side = _encode_slab(codec_obj, block, delta_ok)
-        if mspec is None:
-            # through the module-level name so the single-process
-            # upload seam stays patchable (the fault/ordering tests'
-            # contract)
-            buf = _upload_slab(payload, mesh, split)
-        else:
-            buf = _upload_slab_mh(payload, mesh, split, slab_shape,
-                                  axis0_off)
-        if side:
-            # tiny per-slab sidecar (int8's scale/zero point): counted
-            # honest through the ONE transfer door like everything else
-            buf = (buf,) + tuple(transfer(np.asarray(s)) for s in side)
-        return buf, int(payload.nbytes)
-
-    def dispenser():
-        """Callback sources: hand (slab_i, lo, hi) index jobs to the
-        uploader pool in slab order; workers produce AND upload their
-        own slabs concurrently (random access makes that safe).  Ring
-        permits AND arbiter bytes are acquired HERE, in slab order —
-        per-stream in-order budget delivery, so a tenant's own slabs can
-        never deadlock each other by acquiring out of order."""
-        try:
-            i = 0
-            for lo, hi in ranges:
-                if not _acquire(permits, stop):
-                    return
-                if lease is not None:
-                    nrec = hi - lo
-                    if mspec is not None:
-                        llo, lhi = mspec.local_range(lo, hi)
-                        nrec = lhi - llo    # this process uploads only
-                        #                     its own shard's bytes
-                    if not lease.acquire(nrec * rec_bytes, stop=stop):
-                        return
-                jobq.put((i, lo, hi))
-                i += 1
-            rsq.finish(i)
-        except BaseException as exc:        # noqa: BLE001 — re-raised in
-            rsq.fault(exc)                  # the consumer thread
-        finally:
-            for _ in range(nwork):
-                jobq.put(None)              # poison pills: pool drains
-
-    def _retry_or_raise(i, attempt, prev, exc):
-        """One failed ingest attempt: burn a retry (record + chain the
-        attempt's exception) or raise the run-poisoning final error —
-        the chaining policy itself is the shared
-        ``utils.chain_retry_step`` (one policy for stream AND serve).
-        At budget 0 the ORIGINAL exception propagates untouched — the
-        historical fail-fast contract."""
-        from bolt_tpu.utils import chain_retry_step
-        allowed = attempt < nretry and not stop.is_set()
-        if allowed:
-            _engine.record_stream_retry()
-            _obs.event("stream.retry", slab=start_slab + i,
-                       attempt=attempt + 1, error=type(exc).__name__)
-        return chain_retry_step(
-            exc, prev, attempt, allowed, "slab %d" % (start_slab + i),
-            "stream.retries / BOLT_STREAM_RETRIES")
-
-    def worker(wid):
-        try:
-            with _engine.tenant(tenant_tag):
-                while True:
-                    job = jobq.get()
-                    if job is None or stop.is_set():
-                        return
-                    i, lo, hi = job
-                    attempt = 0
-                    prev = None
-                    while True:
-                        _act_enter()
-                        sp = _obs.begin("stream.ingest", parent=run_sp,
-                                        slab=start_slab + i, worker=wid,
-                                        attempt=attempt)
-                        t0 = _clock()
-                        try:
-                            if mspec is None:
-                                block = source.produce_slab(lo, hi)
-                                buf, bnb = _encode_upload(
-                                    block, block.shape, 0)
-                            else:
-                                # per-process ingest contract: produce
-                                # and upload ONLY this host's shard of
-                                # the slab (global coordinates); with a
-                                # codec armed the LOCAL shard encodes,
-                                # so DCN/gloo ingest bytes shrink too
-                                llo, lhi = mspec.local_range(lo, hi)
-                                block = source.produce_slab(llo, lhi)
-                                buf, bnb = _encode_upload(
-                                    block, mspec.slab_shape(lo, hi),
-                                    llo - lo)
-                            tsec = _clock() - t0
-                            if sp is not None:
-                                sp.set(bytes=bnb, lo=lo, hi=hi)
-                        except BaseException as exc:  # noqa: BLE001
-                            _obs.end(sp, error=type(exc).__name__)
-                            _act_exit()
-                            # retry IN PLACE on this worker (the job
-                            # keeps its ring permit and arbiter bytes);
-                            # the re-sequencer fences any duplicate
-                            prev = _retry_or_raise(i, attempt, prev, exc)
-                            attempt += 1
-                            continue
-                        _obs.end(sp)
-                        _act_exit()
-                        break
-                    del block          # bnb = the LOCAL WIRE bytes this
-                    #                    process acquired and uploaded
-                    rsq.put(i, (buf, bnb, tsec, hi))
-        except BaseException as exc:        # noqa: BLE001 — re-raised in
-            rsq.fault(exc)                  # the consumer thread
-
-    def prefetch():
-        """Iterator sources: ONE produce+upload thread (the iterable is
-        sequential; concurrent ``next()`` would corrupt it).  The ingest
-        span/time covers produce AND upload, like a worker's; arbiter
-        bytes are acquired between produce and upload (an iterator
-        slab's size is only known once the block is in hand)."""
-        i = 0
-        try:
-            with _engine.tenant(tenant_tag):
-                it = source.slabs()
-                if start_slab:
-                    # resume: drain the already-retired prefix, checking
-                    # the block layout still cuts at the checkpointed
-                    # record (a drifted iterator would silently corrupt
-                    # the fold — refuse instead)
-                    skipped_hi = 0
-                    for k in range(start_slab):
-                        try:
-                            _, skipped_hi, blk = next(it)
-                        except StopIteration:
-                            raise RuntimeError(
-                                "resume checkpoint covers %d slabs but "
-                                "this iterator ended after %d; the "
-                                "source is not the one the checkpoint "
-                                "was cut from" % (start_slab, k))
-                        del blk
-                    if skipped_hi != resume_records:
-                        raise RuntimeError(
-                            "resume checkpoint was cut at record %d but "
-                            "this iterator's first %d slab(s) cover %d "
-                            "records — the block layout drifted; delete "
-                            "the checkpoint or restore the original "
-                            "source" % (resume_records, start_slab,
-                                        skipped_hi))
-                while True:
-                    if stop.is_set():
-                        return
-                    if not _acquire(permits, stop):
-                        return
-                    _act_enter()
-                    sp = _obs.begin("stream.ingest", parent=run_sp,
-                                    slab=start_slab + i)
-                    t0 = _clock()
-                    try:
-                        try:
-                            lo, hi, block = next(it)
-                        except StopIteration:
-                            _obs.cancel(sp)   # probe saw end-of-source
-                            sp = None
-                            permits.release()  # unused hand-slot permit
-                            break
-                        axis0_off = 0
-                        if mspec is not None:
-                            # per-process contract for iterator sources:
-                            # every process walks the SAME re-iterable
-                            # block sequence and uploads only its shard
-                            # slice of each global block (validated per
-                            # block — an indivisible slab raises the
-                            # pointed BLT012 error here)
-                            llo, lhi = mspec.local_range(lo, hi)
-                            axis0_off = llo - lo
-                            block = block[llo - lo:lhi - lo]
-                        # acquire the WIRE bytes (exact: codecs keep the
-                        # raw shape, only the itemsize changes) — the
-                        # arbiter budgets what will actually occupy the
-                        # ring, and the release below mirrors it
-                        want = (int(block.size)
-                                * codec_obj.wire_dtype(
-                                    source.dtype).itemsize
-                                if codec_obj is not None
-                                else int(block.nbytes))
-                        if lease is not None and not lease.acquire(
-                                want, stop=stop):
-                            return
-                        attempt = 0
-                        prev = None
-                        while True:
-                            try:
-                                buf, bnb = _encode_upload(
-                                    block,
-                                    block.shape if mspec is None
-                                    else mspec.slab_shape(lo, hi),
-                                    axis0_off)
-                                break
-                            except BaseException as exc:  # noqa: BLE001
-                                # the block is in hand (an iterator
-                                # cannot re-produce it), so the retry
-                                # budget covers the ENCODE + UPLOAD here
-                                prev = _retry_or_raise(i, attempt, prev,
-                                                       exc)
-                                attempt += 1
-                        tsec = _clock() - t0
-                        if sp is not None:
-                            sp.set(bytes=bnb, lo=lo, hi=hi)
-                    finally:
-                        _obs.end(sp)
-                        _act_exit()
-                    del block
-                    rsq.put(i, (buf, bnb, tsec, hi))
-                    i += 1
-                rsq.finish(i)
-        except BaseException as exc:        # noqa: BLE001
-            rsq.fault(exc)
-
-    if source.kind == "callback":
-        lead = threading.Thread(target=dispenser,
-                                name="bolt-stream-prefetch", daemon=True)
-        pool = [threading.Thread(target=worker, args=(w,),
-                                 name="bolt-stream-upload-%d" % w,
-                                 daemon=True)
-                for w in range(nwork)]
-        threads = [lead] + pool
-        ingesters = pool               # only workers deliver slabs
-    else:
-        lead = threading.Thread(target=prefetch,
-                                name="bolt-stream-prefetch", daemon=True)
-        threads = [lead]
-        ingesters = threads
-    _LAST_THREAD = lead
-    _LAST_POOL = tuple(threads)
+    pool = _IngestPool(run, source, ring, jobs=jobs, blocks=blocks,
+                       first=start_slab, parent=run_sp)
 
     from bolt_tpu.tpu.array import _place_operands
     keyed, side = stage_extras(source.stages)
@@ -2287,12 +2286,10 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
             _obs.end(ssp)
         compute += _clock() - t0
         confirmed += cov
-        permits.release(cov)
-        if lease is not None:
-            lease.release(nb)
+        pool.give_back(cov, nb)
 
     def _starved():
-        """The arbiter-backed starvation valve (rsq.next's ``idle``):
+        """The arbiter-backed starvation valve (pool.next's ``idle``):
         with the feeder possibly blocked on budget bytes, confirm one
         retired window per empty poll so its bytes recycle — a budget
         smaller than the full ring then runs a shallower pipeline
@@ -2311,7 +2308,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
             _confirm_oldest()
         elif pend is not None and pend_bytes:
             _pod_sync(pend, mspec is not None, "unpaired-partial sync")
-            lease.release(pend_bytes)
+            pool.give_back(0, pend_bytes)
             pend_bytes = 0
 
     def _fold_push(part):
@@ -2362,8 +2359,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         finally:
             _obs.end(csp)
 
-    for th in threads:
-        th.start()
+    pool.start()
     if mspec is not None:
         # the supervisor must not reform the pod UP under a live
         # collective schedule — this counter is what its quiesce
@@ -2373,9 +2369,8 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     try:
         try:
             while True:
-                got = rsq.next(threads, workers=ingesters,
-                               idle=_starved if lease is not None
-                               else None)
+                got = pool.next(idle=_starved if lease is not None
+                                else None)
                 if got is None:
                     break
                 if mspec is not None and not ready_done:
@@ -2388,17 +2383,15 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                     # blocking ~30s in gloo's connect
                     _podwatch.ready_rendezvous()
                     ready_done = True
-                slab_i, (buf, slab_bytes, tsec, slab_hi) = got
-                # slab_bytes is the PROCESS-LOCAL upload size the worker
-                # acquired from the arbiter (== buf.nbytes single-process;
-                # this process's shard of it on a pod) — releases must
-                # mirror acquires or the serve budget drifts
+                slab_g, buf, slab_bytes, tsec, slab_hi = got
+                # slab_bytes: the PROCESS-LOCAL wire bytes the pool
+                # acquired for the slab, which give_back must mirror
                 ingest += tsec
                 t0 = _clock()
                 wshape = (buf[0].shape if isinstance(buf, tuple)
                           else buf.shape)
                 csp = _obs.begin("stream.compute",
-                                 slab=start_slab + slab_i,
+                                 slab=slab_g,
                                  **({"codec": codec_obj.name}
                                     if codec_obj is not None else {}))
                 _chaos.hit("stream.dispatch")
@@ -2424,7 +2417,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                             # leaks it)
                             dsp = (_obs.begin("stream.decode",
                                               codec=codec_obj.name,
-                                              slab=start_slab + slab_i)
+                                              slab=slab_g)
                                    if codec_obj is not None else None)
                             try:
                                 # a keyed chain's program takes the
@@ -2469,7 +2462,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                             # classify into the pointed PeerLostError
                             # naming the peer and the in-flight slab
                             _podwatch.reraise(exc, phase="slab program",
-                                              slab=start_slab + slab_i)
+                                              slab=slab_g)
                     # counted INSIDE the try, right after the fold state
                     # absorbed the slab: the abort-path checkpoint below
                     # keys its watermark off nslabs, and a watermark
@@ -2541,17 +2534,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 #                         checkpoint)
             raise
         finally:
-            stop.set()
-            # the consumer's OWN poison pills: if the dispenser was
-            # killed before its finally could enqueue them, workers sit
-            # blocked in jobq.get() forever and the joins below would
-            # reproduce the very hang the liveness guard reports —
-            # extra pills are harmless (workers exit on the first one)
-            for _ in range(len(threads)):
-                jobq.put(None)
-            for th in threads:
-                th.join()
-            rsq.drain()                   # release queued ring buffers
+            pool.close()
             pending_sync.clear()
 
         if fold is None:
@@ -2593,14 +2576,14 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         wall = _clock() - t_start
         overlap = max(0.0, ingest + compute - wall)
         _engine.record_stream(nslabs, ingest, compute, wall, overlap,
-                              depth, uploaders=max(act["hw"], 1),
+                              depth, uploaders=max(pool.high_water, 1),
                               inflight=max(inflight_hw, 1),
                               keyed=nslabs if keyed else 0)
         if run_sp is not None:
             run_sp.set(slabs=nslabs, ingest_s=round(ingest, 6),
                        compute_s=round(compute, 6),
                        overlap_s=round(overlap, 6),
-                       concurrent_uploaders=max(act["hw"], 1),
+                       concurrent_uploaders=max(pool.high_water, 1),
                        inflight_high_water=max(inflight_hw, 1))
         if terminal == "multi":
             return list(out)              # one jax array per member spec
@@ -2943,7 +2926,6 @@ def _resolve_one_swap(source, collect=False):
     from bolt_tpu import checkpoint as _ckptlib
     from bolt_tpu.parallel import shuffle as _shuffle
     from bolt_tpu.tpu.array import BoltArrayTPU, _place_operands
-    from bolt_tpu.utils import chain_retry_step
 
     if collect:
         pre, post = source.stages, ()
@@ -2990,37 +2972,24 @@ def _resolve_one_swap(source, collect=False):
             "analysis.check forecasts this as BLT017"
             % (plan.resident_bytes / 2**20, (plan.budget or 0) / 2**20))
 
-    codec_obj = resolve_codec(base)     # lossless or None (gated at
-    delta_ok = split < len(source.shape)  # swap_stage record time)
-    nretry = retry_limit()
-    depth = prefetch_depth()
-    nwork = pool_size(base)
-    mspec = None
-    if _multihost.mesh_process_count(mesh) > 1:
-        err = _multihost.slab_divisibility_error(
-            mesh, source.shape, split,
-            base.slab_ranges() if base.kind == "callback" else [])
-        if err is not None:
-            raise ValueError(err)       # BLT012 — check() forecasts it
-        err = _multihost.sidecar_codec_error(codec_obj, mesh)
-        if err is not None:
-            raise ValueError(err)
-        mspec = _multihost.local_slab_spec(base)
-        if not plan.resident:
-            # pod spill is refused, not attempted: phase 1 spills each
-            # bucket whole on the one process that owns its rows, but
-            # re-streaming those buckets as pod slabs needs every slab
-            # SPLIT across processes (the BLT012 divisibility
-            # contract) — two ownership models that cannot both hold.
-            raise RuntimeError(
-                "streamed swap: the re-keyed working set (%.1f MiB) "
-                "exceeds the resident budget (%.1f MiB) and disk "
-                "spill is single-process only — on a multi-process "
-                "mesh raise the arbiter budget so the buckets stay "
-                "resident, or materialise first (toarray) and swap "
-                "in memory; analysis.check forecasts this as BLT017"
-                % (plan.resident_bytes / 2**20,
-                   (plan.budget or 0) / 2**20))
+    # the codec is lossless or None (gated when the swap was recorded)
+    run = _Run(base)
+    pod = run.mspec is not None
+    if pod and not plan.resident:
+        # pod spill is refused, not attempted: phase 1 spills each
+        # bucket whole on the one process that owns its rows, but
+        # re-streaming those buckets as pod slabs needs every slab
+        # SPLIT across processes (the BLT012 divisibility
+        # contract) — two ownership models that cannot both hold.
+        raise RuntimeError(
+            "streamed swap: the re-keyed working set (%.1f MiB) "
+            "exceeds the resident budget (%.1f MiB) and disk "
+            "spill is single-process only — on a multi-process "
+            "mesh raise the arbiter budget so the buckets stay "
+            "resident, or materialise first (toarray) and swap "
+            "in memory; analysis.check forecasts this as BLT017"
+            % (plan.resident_bytes / 2**20,
+               (plan.budget or 0) / 2**20))
 
     # spill-manifest resume (fingerprinted like stream checkpoints):
     # slabs whose every bucket landed are skipped — their files are
@@ -3032,186 +3001,26 @@ def _resolve_one_swap(source, collect=False):
     fp = _shuffle_fingerprint(base, pre, perm, new_split,
                               plan.out_block)
     done = set()
-    if not plan.resident and base.kind == "callback" and mspec is None:
+    if not plan.resident and base.kind == "callback" and not pod:
         done = _ckptlib.spill_manifest(spill_dir, fp)
         if done:
             _engine.record_stream_resume()
             _obs.event("stream.spill_resume", slabs=len(done))
 
-    ranges = base.slab_ranges() if base.kind == "callback" else None
     jobs = None
-    if ranges is not None:
-        jobs = [(g, lo, hi) for g, (lo, hi) in enumerate(ranges)
-                if g not in done]
-    wire_item = (codec_obj.wire_dtype(source.dtype).itemsize
-                 if codec_obj is not None else source.dtype.itemsize)
-    tenant_tag = _engine.current_tenant()
-    lease = _tenant_lease()
-    ring = plan.ring                    # == depth + nwork (swap_ring)
-    permits = threading.Semaphore(ring)
-    stop = threading.Event()
-    rsq = _Reseq()
-    jobq = queue.Queue()
+    if base.kind == "callback":
+        jobs = [(g, lo, hi) for g, (lo, hi)
+                in enumerate(base.slab_ranges()) if g not in done]
     side = _place_operands(side, mesh)      # once a run, not once a slab
     run_sp = _obs.begin("stream.collect" if collect else "stream.shuffle",
                         resident=plan.resident,
-                        inplace=plan.resident, ring=ring,
+                        inplace=plan.resident, ring=plan.ring,
                         slabs=plan.nslabs, buckets=plan.nbuckets,
                         out_block=plan.out_block,
                         alltoall_bytes=plan.alltoall_bytes)
-
-    def _encode_upload(block, slab_shape, axis0_off):
-        side = ()
-        if codec_obj is None:
-            payload = block
-        else:
-            payload, side = _encode_slab(codec_obj, block, delta_ok)
-        if mspec is None:
-            buf = _upload_slab(payload, mesh, split)
-        else:
-            buf = _upload_slab_mh(payload, mesh, split, slab_shape,
-                                  axis0_off)
-        if side:
-            buf = (buf,) + tuple(transfer(np.asarray(s)) for s in side)
-        return buf, int(payload.nbytes)
-
-    def _retry_or_raise(g, attempt, prev, exc, what):
-        allowed = attempt < nretry and not stop.is_set()
-        if allowed:
-            _engine.record_stream_retry()
-            _obs.event("stream.retry", slab=g, attempt=attempt + 1,
-                       error=type(exc).__name__)
-        return chain_retry_step(exc, prev, attempt, allowed,
-                                "%s %d" % (what, g),
-                                "stream.retries / BOLT_STREAM_RETRIES")
-
-    def dispenser():
-        try:
-            for j, (g, lo, hi) in enumerate(jobs):
-                if not _acquire(permits, stop):
-                    return
-                if lease is not None:
-                    nrec = hi - lo
-                    if mspec is not None:
-                        llo, lhi = mspec.local_range(lo, hi)
-                        nrec = lhi - llo
-                    if not lease.acquire(
-                            nrec * prod(source.shape[1:]) * wire_item,
-                            stop=stop):
-                        return
-                jobq.put((j, g, lo, hi))
-            rsq.finish(len(jobs))
-        except BaseException as exc:        # noqa: BLE001 — re-raised
-            rsq.fault(exc)                  # in the consumer
-        finally:
-            for _ in range(nwork):
-                jobq.put(None)
-
-    def worker(wid):
-        try:
-            with _engine.tenant(tenant_tag):
-                while True:
-                    job = jobq.get()
-                    if job is None or stop.is_set():
-                        return
-                    j, g, lo, hi = job
-                    attempt = 0
-                    prev = None
-                    while True:
-                        sp = _obs.begin("stream.ingest", parent=run_sp,
-                                        slab=g, worker=wid,
-                                        attempt=attempt)
-                        t0 = _clock()
-                        try:
-                            if mspec is None:
-                                block = base.produce_slab(lo, hi)
-                                buf, bnb = _encode_upload(
-                                    block, block.shape, 0)
-                            else:
-                                llo, lhi = mspec.local_range(lo, hi)
-                                block = base.produce_slab(llo, lhi)
-                                buf, bnb = _encode_upload(
-                                    block, mspec.slab_shape(lo, hi),
-                                    llo - lo)
-                            tsec = _clock() - t0
-                            if sp is not None:
-                                sp.set(bytes=bnb, lo=lo, hi=hi)
-                        except BaseException as exc:  # noqa: BLE001
-                            _obs.end(sp, error=type(exc).__name__)
-                            prev = _retry_or_raise(g, attempt, prev, exc,
-                                                   "shuffle slab")
-                            attempt += 1
-                            continue
-                        _obs.end(sp)
-                        break
-                    del block
-                    rsq.put(j, (g, buf, bnb, tsec))
-        except BaseException as exc:        # noqa: BLE001
-            rsq.fault(exc)
-
-    def prefetch():
-        # iterator sources: ONE sequential produce+upload thread; a
-        # one-shot iterable cannot resume, so `done` is always empty
-        j = 0
-        try:
-            with _engine.tenant(tenant_tag):
-                for g, (lo, hi, block) in enumerate(
-                        iter_record_blocks_indexed(base)):
-                    if stop.is_set():
-                        return
-                    if not _acquire(permits, stop):
-                        return
-                    sp = _obs.begin("stream.ingest", parent=run_sp,
-                                    slab=g)
-                    t0 = _clock()
-                    try:
-                        if lease is not None and not lease.acquire(
-                                int(block.size) * wire_item, stop=stop):
-                            return
-                        attempt = 0
-                        prev = None
-                        while True:
-                            try:
-                                buf, bnb = _encode_upload(
-                                    block, block.shape, 0)
-                                break
-                            except BaseException as exc:  # noqa: BLE001
-                                prev = _retry_or_raise(
-                                    g, attempt, prev, exc,
-                                    "shuffle slab")
-                                attempt += 1
-                        tsec = _clock() - t0
-                        if sp is not None:
-                            sp.set(bytes=bnb, lo=lo, hi=hi)
-                    finally:
-                        _obs.end(sp)
-                    del block
-                    rsq.put(j, (g, buf, bnb, tsec))
-                    j += 1
-                rsq.finish(j)
-        except BaseException as exc:        # noqa: BLE001
-            rsq.fault(exc)
-
-    def iter_record_blocks_indexed(src):
-        for lo, hi, block in src.slabs():
-            yield lo, hi, block
-
-    if base.kind == "callback":
-        lead = threading.Thread(target=dispenser,
-                                name="bolt-shuffle-prefetch",
-                                daemon=True)
-        pool = [threading.Thread(target=worker, args=(w,),
-                                 name="bolt-shuffle-upload-%d" % w,
-                                 daemon=True)
-                for w in range(nwork)]
-        threads = [lead] + pool
-        ingesters = pool
-    else:
-        lead = threading.Thread(target=prefetch,
-                                name="bolt-shuffle-prefetch",
-                                daemon=True)
-        threads = [lead]
-        ingesters = threads
+    # a one-shot iterable cannot resume, so `done` is empty without jobs
+    pool = _IngestPool(run, base, plan.ring, jobs=jobs,
+                       noun="shuffle slab", parent=run_sp)
 
     def _spill_part(part, g):
         """Extract and persist every LOCALLY-OWNED bucket of slab
@@ -3237,8 +3046,8 @@ def _resolve_one_swap(source, collect=False):
                     break
                 except BaseException as exc:  # noqa: BLE001
                     _obs.end(ssp, error=type(exc).__name__)
-                    prev = _retry_or_raise(g, attempt, prev, exc,
-                                           "spill slab")
+                    prev = pool.retry(g, attempt, prev, exc,
+                                      "spill slab")
                     attempt += 1
         _ckptlib.spill_slab_done(spill_dir, fp, g)
 
@@ -3246,7 +3055,6 @@ def _resolve_one_swap(source, collect=False):
     moved = 0
     placed = 0
     ingest = compute = 0.0
-    pod = mspec is not None
     out = cursor = None
     if plan.resident:
         # phase 2 in place: the swapped array exists ONCE, from here
@@ -3256,20 +3064,19 @@ def _resolve_one_swap(source, collect=False):
         # g * slab) and records for an iterator's own blocks
         out, cursor = _shuffle.alloc_program(plan, mesh)()
         unit = base.slab if base.kind == "callback" else 1
-    for th in threads:
-        th.start()
+    pool.start()
     if pod:
         _podwatch.pod_enter()
     ready_done = False
     try:
         while True:
-            got = rsq.next(threads, workers=ingesters)
+            got = pool.next()
             if got is None:
                 break
             if pod and not ready_done:
                 _podwatch.ready_rendezvous()
                 ready_done = True
-            j, (g, buf, bnb, tsec) = got
+            g, buf, bnb, tsec, _ = got
             ingest += tsec
             t0 = _clock()
             wshape = (buf[0].shape if isinstance(buf, tuple)
@@ -3287,12 +3094,12 @@ def _resolve_one_swap(source, collect=False):
                         _chaos.hit("stream.shuffle")
                         if plan.resident:
                             prog = _shuffle.place_program(
-                                plan, pre, mesh, codec_obj, source.dtype,
-                                wshape, delta_ok, unit)
+                                plan, pre, mesh, run.codec, source.dtype,
+                                wshape, run.delta_ok, unit)
                         else:
                             prog = _shuffle.rebucket_program(
-                                plan, pre, mesh, codec_obj, source.dtype,
-                                wshape, delta_ok)
+                                plan, pre, mesh, run.codec, source.dtype,
+                                wshape, run.delta_ok)
                         with warnings.catch_warnings():
                             # the uploaded slab is donated but never
                             # aliased (no output has its shape; CPU dev
@@ -3321,8 +3128,8 @@ def _resolve_one_swap(source, collect=False):
                     except _podwatch.PeerLostError:
                         raise
                     except BaseException as exc:  # noqa: BLE001
-                        prev = _retry_or_raise(g, attempt, prev, exc,
-                                               "shuffle dispatch")
+                        prev = pool.retry(g, attempt, prev, exc,
+                                          "shuffle dispatch")
                         attempt += 1
             finally:
                 _obs.end(csp)
@@ -3333,20 +3140,13 @@ def _resolve_one_swap(source, collect=False):
                 _spill_part(part, g)
             del part
             compute += _clock() - t0
-            permits.release()
-            if lease is not None:
-                lease.release(bnb)
+            pool.give_back(1, bnb)
     finally:
-        stop.set()
-        for _ in range(len(threads)):
-            jobq.put(None)
-        for th in threads:
-            th.join()
-        rsq.drain()
+        pool.close()
         if pod:
             _podwatch.pod_exit()
-        if lease is not None:
-            lease.close()
+        if run.lease is not None:
+            run.lease.close()
         wall = _clock() - t_start
         if collect:
             _engine.record_collect(placed, moved)
@@ -3358,8 +3158,8 @@ def _resolve_one_swap(source, collect=False):
     # phase 1 completed: one streamed run, under the counters every
     # streamed run reports (a spilled swap's phase 2 adds its own)
     _engine.record_stream(placed, ingest, compute, wall,
-                          max(0.0, ingest + compute - wall), depth,
-                          uploaders=len(ingesters),
+                          max(0.0, ingest + compute - wall), run.depth,
+                          uploaders=run.nwork,
                           keyed=placed if keyed else 0)
 
     if plan.resident:

@@ -215,8 +215,8 @@ class ConstructTPU:
         (:mod:`bolt_tpu.stream`), so datasets LARGER than device memory
         reduce in one pass; any other consumer materialises it with one
         callback call per device shard, exactly as before.  ``chunks``
-        sets the records per streamed slab (default: a
-        ``BOLT_STREAM_SLAB_BYTES`` budget, 64 MB).  ``dtype=None`` means
+        sets the records per streamed slab (default: a 64 MiB
+        budget).  ``dtype=None`` means
         "whatever the callback produces" and stays eager (the element
         type cannot be known without calling the loader).
 

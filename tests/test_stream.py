@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 import bolt_tpu as bolt
-from bolt_tpu import analysis, engine, profile, stream
+from bolt_tpu import analysis, engine, obs, profile, stream
 from bolt_tpu.tpu.array import BoltArrayTPU
 
 
@@ -834,10 +834,45 @@ def test_stream_sharded_multidevice_parity_bitexact(mesh):
                 assert np.array_equal(got, want), (name, chunks)
 
 
-def test_stream_out_of_order_upload_folds_in_slab_order(mesh, monkeypatch):
+# ---------------------------------------------------------------------
+# the three consumers of the one ingest pool (ISSUE 41): the fold of
+# execute(), a streamed swap's place programs, collect()'s.  The pool's
+# ordering and fault contracts hold whoever consumes its slabs.
+# ---------------------------------------------------------------------
+
+def _run_fold(b, mesh):
+    return np.asarray(b.mean().toarray())
+
+
+def _run_swap(b, mesh):
+    return np.asarray(b.swap((0,), (0,))._data)
+
+
+def _run_collect(b, mesh):
+    return np.asarray(b.map(ADD1).toarray())
+
+
+def _run_spilled_swap(b, mesh, tmp):
+    with stream.spill(dir=str(tmp), budget=1):
+        return np.asarray(b.swap((0,), (0,))._data)
+
+
+CONSUMERS = {
+    # name: (drive the source to its result, the result wanted of data)
+    "fold": (_run_fold, lambda data, mesh: np.asarray(
+        bolt.array(data, mesh).mean().toarray())),
+    "swap": (_run_swap, lambda data, mesh: np.transpose(data, (1, 0, 2))),
+    "collect": (_run_collect, lambda data, mesh: data + 1.0),
+}
+
+
+@pytest.mark.parametrize("consumer", sorted(CONSUMERS))
+def test_stream_out_of_order_upload_folds_in_slab_order(mesh, monkeypatch,
+                                                        consumer):
     # slab 0's upload is HELD BACK until another slab has finished: the
-    # re-sequencer must still hand slabs to the fold in slab order, so
-    # the result stays bit-identical to the materialised path
+    # re-sequencer must still hand slabs to the consumer in slab order,
+    # so the result stays bit-identical to the materialised path
+    run, want = CONSUMERS[consumer]
     data = _intdata()
     orig = stream._upload_slab
     done = []
@@ -855,18 +890,19 @@ def test_stream_out_of_order_upload_folds_in_slab_order(mesh, monkeypatch):
 
     monkeypatch.setattr(stream, "_upload_slab", held_back)
     with stream.uploaders(3):
-        got = np.asarray(_source(data, mesh, 4).mean().toarray())
+        got = run(_source(data, mesh, 4), mesh)
     assert done and done[0] == 0                # slab 0 finished LATE
     assert 1 in done
-    want = np.asarray(bolt.array(data, mesh).mean().toarray())
-    assert np.array_equal(got, want)            # fold order unaffected
+    assert np.array_equal(got, want(data, mesh))    # order unaffected
 
 
-def test_stream_fault_in_uploader_worker_aborts_cleanly(mesh,
-                                                        monkeypatch):
+@pytest.mark.parametrize("consumer", sorted(CONSUMERS))
+def test_stream_fault_in_uploader_worker_aborts_cleanly(mesh, monkeypatch,
+                                                        consumer):
     # a raise inside ONE pool worker (not the source callback): the
     # whole pool is joined, ring permits are released, and the ORIGINAL
     # exception re-raises in the consumer
+    run, want = CONSUMERS[consumer]
     data = _intdata()
     boom = RuntimeError("device link dropped")
     orig = stream._upload_slab
@@ -879,46 +915,246 @@ def test_stream_fault_in_uploader_worker_aborts_cleanly(mesh,
         return orig(block, mesh_, split)
 
     monkeypatch.setattr(stream, "_upload_slab", flaky_upload)
-    src = _source(data, mesh, 4)
     with stream.uploaders(2):
         with pytest.raises(RuntimeError) as ei:
-            src.sum().cache()
+            run(_source(data, mesh, 4), mesh)
     assert ei.value is boom                     # the ORIGINAL exception
     # the WHOLE pool (dispenser + workers) is joined, nothing leaks
     assert stream._LAST_POOL
     assert all(not t.is_alive() for t in stream._LAST_POOL)
-    # the executor is not poisoned: a healthy stream runs right after
+    assert obs.thread_census() == {}
+    # the executor is not poisoned: a healthy run goes right after
     monkeypatch.setattr(stream, "_upload_slab", orig)
-    ok = np.asarray(_source(data, mesh, 4).sum().toarray())
-    assert np.array_equal(ok, data.sum(axis=0))
+    assert np.array_equal(run(_source(data, mesh, 4), mesh),
+                          want(data, mesh))
 
 
-def test_stream_dead_pool_thread_raises_pointed_error(mesh, monkeypatch):
+@pytest.mark.parametrize("consumer", sorted(CONSUMERS))
+def test_stream_dead_pool_thread_raises_pointed_error(mesh, monkeypatch,
+                                                      consumer):
     # the q.get()-blocks-forever bug: a pool thread that dies WITHOUT
     # enqueueing anything (teardown-killed before its fault handler ran)
     # must surface as a pointed RuntimeError naming the dead thread, not
     # hang the consumer.  Simulated by muting the fault funnel.
-    data = _intdata()
+    run, _ = CONSUMERS[consumer]
     monkeypatch.setattr(stream._Reseq, "fault",
                         lambda self, exc: None)
 
     def dying(idx):
         raise RuntimeError("this error is swallowed by the mute")
 
-    src = bolt.fromcallback(dying, SHAPE, mesh, dtype=np.float64,
-                            chunks=4)
+    def src(chunks):
+        return bolt.fromcallback(dying, SHAPE, mesh, dtype=np.float64,
+                                 chunks=chunks)
+
     with pytest.raises(RuntimeError, match="died without delivering"):
-        src.sum().cache()
+        run(src(4), mesh)
     with pytest.raises(RuntimeError, match="bolt-stream"):
-        bolt.fromcallback(dying, SHAPE, mesh, dtype=np.float64,
-                          chunks=4).sum().cache()
+        run(src(4), mesh)
     # the harder shape: MORE slabs than the ring, so the dispenser is
     # still alive, blocked on ring permits, when every worker dies —
     # dead workers must trip the guard anyway (nothing can ever arrive)
     with stream.uploaders(2), stream.prefetch(1):   # ring 3 << 16 slabs
         with pytest.raises(RuntimeError, match="died without delivering"):
-            bolt.fromcallback(dying, SHAPE, mesh, dtype=np.float64,
-                              chunks=1).sum().cache()
+            run(src(1), mesh)
+    assert obs.thread_census() == {}
+
+
+@pytest.mark.parametrize("consumer", sorted(CONSUMERS) + ["spilled-swap"])
+def test_every_pool_is_in_the_thread_census(mesh, monkeypatch, tmp_path,
+                                            consumer):
+    # a pool held open by a held-back upload is COUNTED by the census
+    # the leak gate reads (tests/conftest.py), whoever consumes it, and
+    # gone from it afterwards
+    if consumer == "spilled-swap":
+        def run(b, mesh_):
+            return _run_spilled_swap(b, mesh_, tmp_path)
+        want = CONSUMERS["swap"][1]
+    else:
+        run, want = CONSUMERS[consumer]
+    data = _intdata()
+    orig = stream._upload_slab
+    full = {"bolt-stream-prefetch": 1, "bolt-stream-upload": 2}
+    seen = []
+
+    def held_back(block, mesh_, split):
+        # the run's FIRST upload waits for the whole pool to be up: with
+        # 16 slabs and a ring of 4 the dispenser cannot have finished
+        t0 = time.time()
+        while not seen and obs.thread_census() != full \
+                and time.time() - t0 < 10:
+            time.sleep(0.002)
+        seen.append(obs.thread_census())
+        return orig(block, mesh_, split)
+
+    monkeypatch.setattr(stream, "_upload_slab", held_back)
+    with stream.uploaders(2):
+        got = run(_source(data, mesh, 1), mesh)
+    assert np.array_equal(got, want(data, mesh))
+    assert seen[0] == full
+    assert all(t.name.startswith("bolt-stream-")
+               for t in stream._LAST_POOL)
+    assert obs.thread_census() == {}
+
+
+# ---------------------------------------------------------------------
+# the ingest pool on its own (ISSUE 41): a fake upload, no device
+# ---------------------------------------------------------------------
+
+class _Blocks:
+    """A re-iterable of the 4-record blocks ``fn`` produces."""
+
+    def __init__(self, fn):
+        self.fn = fn
+
+    def __iter__(self):
+        return (self.fn((slice(lo, lo + 4),)) for lo in range(0, N, 4))
+
+
+def _pool(kind, mesh, ring, data=None, produce=None, uploaders=2,
+          retries=0, noun="slab"):
+    """A pool of ``ring`` slots over a 4-slab source of ``kind`` that
+    reads ``data`` (or calls ``produce``, which raises)."""
+    fn = produce or (lambda idx: data[idx])
+    jobs = None
+    if kind == "callback":
+        src = bolt.fromcallback(fn, SHAPE, mesh, dtype=np.float64,
+                                chunks=4)._stream
+        jobs = [(g, lo, hi) for g, (lo, hi)
+                in enumerate(src.slab_ranges())]
+    else:
+        src = bolt.fromiter(_Blocks(fn), SHAPE, mesh,
+                            dtype=np.float64)._stream
+    with stream.uploaders(uploaders), stream.retries(retries):
+        run = stream._Run(src)          # the scopes are read HERE
+    return stream._IngestPool(run, src, ring, jobs=jobs, noun=noun)
+
+
+def _drain(pool, give_back=True):
+    out = []
+    while True:
+        got = pool.next()
+        if got is None:
+            return out
+        out.append(got)
+        if give_back:
+            pool.give_back(1, got[2])
+
+
+@pytest.mark.parametrize("kind", ["callback", "iter"])
+def test_pool_hands_slabs_out_in_order(mesh, monkeypatch, kind):
+    # whatever order the workers finish in: slab 0's upload waits for
+    # a later slab's wherever more than one thread uploads
+    data = _intdata()
+    finished = []
+
+    def upload(block, mesh_, split):
+        lo = int(np.array_equal(block, data[:4]))
+        t0 = time.time()
+        while lo and kind == "callback" and not finished \
+                and time.time() - t0 < 10:
+            time.sleep(0.002)
+        finished.append(lo)
+        return block.copy()
+
+    monkeypatch.setattr(stream, "_upload_slab", upload)
+    pool = _pool(kind, mesh, 4, data, uploaders=3)
+    pool.start()
+    try:
+        got = _drain(pool)
+    finally:
+        pool.close()
+    assert [g for g, *_ in got] == [0, 1, 2, 3]
+    assert [hi for *_, hi in got] == [4, 8, 12, 16]
+    for g, buf, nbytes, seconds, hi in got:
+        assert np.array_equal(buf, data[hi - 4:hi])
+        assert nbytes == buf.nbytes and seconds >= 0
+    if kind == "callback":
+        assert finished[0] == 0                 # slab 0 finished LATE
+    assert all(not t.is_alive() for t in pool.threads)
+
+
+@pytest.mark.parametrize("kind", ["callback", "iter"])
+def test_pool_never_exceeds_its_ring(mesh, monkeypatch, kind):
+    # never more than `ring` slabs dispensed and not given back: with
+    # the ring full nobody uploads, and ONE permit back is ONE more slab
+    data = _intdata()
+    started = []
+    monkeypatch.setattr(stream, "_upload_slab",
+                        lambda block, m, s: (started.append(1),
+                                             block.copy())[1])
+    pool = _pool(kind, mesh, 2, data)
+    pool.start()
+    try:
+        held = [pool.next(), pool.next()]
+        time.sleep(0.3)
+        assert len(started) == 2                # ring full: all wait
+        pool.give_back(1, held[0][2])
+        third = pool.next()
+        time.sleep(0.3)
+        assert len(started) == 3 and third[0] == 2
+        pool.give_back(2, held[1][2] + third[2])
+        assert [g for g, *_ in _drain(pool)] == [3]
+    finally:
+        pool.close()
+    assert len(started) == 4
+
+
+@pytest.mark.parametrize("kind", ["callback", "iter"])
+def test_pool_retries_in_place_and_chains_the_attempts(mesh, monkeypatch,
+                                                       kind):
+    data = _intdata()
+    errs = [RuntimeError("flake %d" % k) for k in range(3)]
+    fails = []
+
+    def upload(block, mesh_, split):
+        if np.array_equal(block, data[4:8]) and len(fails) < len(errs):
+            fails.append(errs[len(fails)])      # slab 1 fails 3 times
+            raise fails[-1]
+        return block.copy()
+
+    monkeypatch.setattr(stream, "_upload_slab", upload)
+    c0 = engine.counters()["stream_retries"]
+
+    def drained(budget):
+        del fails[:]
+        pool = _pool(kind, mesh, 2, data, uploaders=1, retries=budget,
+                     noun="test slab")
+        pool.start()
+        try:
+            return _drain(pool)
+        finally:
+            pool.close()
+
+    assert [g for g, *_ in drained(3)] == [0, 1, 2, 3]  # the last lands
+    with pytest.raises(RuntimeError,
+                       match="test slab 1 failed after 2 retries") as ei:
+        drained(2)
+    # the final error chains every attempt back to the original failure
+    assert ei.value.__cause__ is errs[2]
+    assert errs[2].__cause__ is errs[1] and errs[1].__cause__ is errs[0]
+    assert engine.counters()["stream_retries"] - c0 == 3 + 2
+
+
+@pytest.mark.parametrize("kind", ["callback", "iter"])
+def test_pool_names_a_dead_thread_and_still_joins(mesh, monkeypatch, kind):
+    # a thread that dies mute surfaces through next(); close() joins all
+    monkeypatch.setattr(stream._Reseq, "fault", lambda self, exc: None)
+
+    def dying(idx):
+        raise RuntimeError("swallowed by the mute")
+
+    pool = _pool(kind, mesh, 3, produce=dying)
+    pool.start()
+    try:
+        with pytest.raises(RuntimeError,
+                           match="bolt-stream-.*died without delivering"):
+            pool.next()
+    finally:
+        pool.close()
+    assert all(not t.is_alive() for t in pool.threads)
+    assert len(pool.threads) == (3 if kind == "callback" else 1)
+    assert obs.thread_census() == {}
 
 
 def test_stream_inflight_window_bounds_and_records(mesh):
