@@ -625,14 +625,19 @@ def _note_shuffle(src, stage, aval, split, mesh, idx, diags, keyed=False):
                  "license disk spill, or raise the arbiter budget so "
                  "the re-keyed buckets stay resident"))
         return
+    exchange = ("one all-to-all per slab across its %d devices"
+                % plan.devices if plan.alltoall_bytes
+                else "a local permute: nothing crosses devices")
     diags.append(Diagnostic(
         "BLT017", idx, plan.describe(),
-        hint="phase 1 re-buckets each uploaded slab on device (one "
-             "all-to-all per slab on pods) and %s; phase 2 streams "
+        hint="phase 1 re-buckets each uploaded slab on device (%s) "
+             "and %s; phase 2 streams "
              "the buckets through the standard slab machinery — "
              "bit-identical to the materialised swap "
-             "(shuffle_bytes/spill_bytes engine counters)"
-             % ("keeps them resident in HBM under the arbiter lease"
+             "(shuffle_bytes/spill_bytes/stream_alltoall_bytes engine "
+             "counters)"
+             % (exchange,
+                "keeps them resident in HBM under the arbiter lease"
                 if plan.resident
                 else "spills them codec-encoded to the fingerprint "
                      "directory")))

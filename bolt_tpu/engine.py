@@ -128,6 +128,10 @@ _SCHEMA = {
                                    # across workers; over the counter
                                    # above it is the mean number of
                                    # copies in flight while any was
+    "stream_upload_parts": 0,     # per-device sub-blocks the uploader
+                                  # pool put (stream._upload_slab_mh: one
+                                  # a local device a slab), so over
+                                  # stream_chunks it is the parts a slab
     # streaming-executor accounting (bolt_tpu.stream: the out-of-core
     # double-buffered pipeline).  overlap_seconds is ingest time hidden
     # behind device compute: max(0, ingest + compute - wall) per run;
@@ -249,6 +253,11 @@ _SCHEMA = {
     "shuffle_bytes": 0,
     "spill_bytes": 0,
     "shuffle_seconds": 0.0,
+    "stream_alltoall_bytes": 0,   # of shuffle_bytes, what the planner's
+                                  # model says crossed devices (ShufflePlan
+                                  # .alltoall_bytes, in proportion to the
+                                  # bytes a run moved; 0 on one device and
+                                  # where the record axis stays leading)
     # a mapped result COLLECTED slab by slab (stream.collect: the
     # resolver's resident leg with no re-axis) and keyed stages
     "stream_collect_slabs": 0,    # slabs placed into a collected result
@@ -851,10 +860,12 @@ def _link_fresh(start, end):
     return max(fresh, 0.0)
 
 
-def record_transfer(nbytes, seconds):
+def record_transfer(nbytes, seconds, parts=0):
     """Tally one counted host->device transfer that took ``seconds`` and
     ended now (bolt_tpu.stream.transfer is the only caller — lint rule
-    BLT105 keeps it that way).  ``transfer_copy_seconds`` gets the copy's
+    BLT105 keeps it that way).  ``parts``: the per-device sub-blocks an
+    uploaded SLAB was put as (``stream_upload_parts``; 0 for any other
+    transfer).  ``transfer_copy_seconds`` gets the copy's
     own seconds; ``transfer_seconds`` only the part of them during which
     no other counted copy was in flight, so it is the link's busy time:
     the same number wherever copies never overlap.  The link is the
@@ -864,7 +875,8 @@ def record_transfer(nbytes, seconds):
         busy = _link_fresh(end - seconds, end)
     _COUNTERS.update(transfer_bytes=int(nbytes),
                      transfer_seconds=busy,
-                     transfer_copy_seconds=seconds)
+                     transfer_copy_seconds=seconds,
+                     stream_upload_parts=int(parts))
     _TRANSFER_HIST.observe(int(nbytes))
 
 
@@ -879,13 +891,15 @@ def record_codec(raw_bytes, wire_bytes, seconds):
                      codec_encode_seconds=seconds)
 
 
-def record_shuffle(nbytes, seconds):
+def record_shuffle(nbytes, seconds, alltoall=0):
     """Tally one streamed shuffle's phase 1 (bolt_tpu.stream's swap
-    resolver): ``nbytes`` moved through the re-bucket programs and the
-    phase's wall clock.  One update per shuffle, applied at the end —
-    a snapshot never sees a half-accounted phase.  The timeline carries
-    it as the ``stream.shuffle`` span."""
-    _COUNTERS.update(shuffle_bytes=int(nbytes), shuffle_seconds=seconds)
+    resolver): ``nbytes`` moved through the re-bucket programs, of them
+    ``alltoall`` across devices by the planner's model, and the phase's
+    wall clock.  One update per shuffle, applied at the end — a snapshot
+    never sees a half-accounted phase.  The timeline carries it as the
+    ``stream.shuffle`` span."""
+    _COUNTERS.update(shuffle_bytes=int(nbytes), shuffle_seconds=seconds,
+                     stream_alltoall_bytes=int(alltoall))
 
 
 def record_collect(slabs, nbytes):

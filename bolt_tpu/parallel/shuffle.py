@@ -11,11 +11,24 @@ module plans the two-phase pipeline that closes the gap:
   pre-swap stage chain and the swap's transpose, producing that slab's
   contribution to the output — the full new-key extent, with the
   slab's input records along the axis the old record axis landed on
-  (``j0 = perm.index(0)``).  On a pod the program runs under
-  ``shard_map`` with an explicit ``lax.all_to_all`` (split the new
-  record axis, concatenate at ``j0``), so each slab costs exactly one
-  collective; single-process the transpose plus a sharding constraint
-  lets GSPMD insert the local permute.
+  (``j0 = perm.index(0)``).  A plan is SHARDED (``ShufflePlan.sharded``)
+  when its mesh spans more than one PROCESS, and that alone picks the
+  form of the exchange.  Sharded, the program runs under ``shard_map``
+  with an explicit ``lax.all_to_all`` (split the new record axis,
+  concatenate at ``j0``), so each slab costs exactly one collective and
+  every process enters it on the same slab.  Not sharded, the transpose
+  and the output's sharding constraint are left to GSPMD, whatever the
+  mesh's DEVICE width (``ShufflePlan.devices``): on one device that is
+  a local permute; on one process driving several chips the compiler
+  emits ONE ``all-to-all`` of the slab as uploaded (the frames a chip
+  holds, split over the chips that shard the new leading axis: whole
+  lane tiles), transposes what arrives locally, and updates the
+  aliased output at an offset whose low bits it knows.  The explicit
+  form compiles to the same operations there (its transposed block is
+  laid out frames second-minor, so nothing a quarter of a lane tile
+  wide is ever exchanged), so the rule stays the process count;
+  ``tests/test_ops_kernels.py`` compiles the place program for a
+  described ``v5e:2x2`` and holds the compiler to it (PERF.md, PR 43).
 * **phase 2 (re-assemble)**: RESIDENT, the swapped array is allocated
   once (:func:`alloc_program`) and each slab's program writes its
   transposed block INTO it at the slab's offset along ``j0``
@@ -68,13 +81,16 @@ class ShufflePlan:
     ``alltoall_bytes`` is the planner's
     cross-device traffic model: the bytes that must cross device
     boundaries during phase 1 (0 when the record axis stays leading —
-    a pure local permute)."""
+    a pure local permute), over the ``devices`` that shard the input
+    record axis — several chips of ONE process count like any others
+    (``sharded``, the process count, says only which form the exchange
+    takes: module docstring)."""
 
     __slots__ = ("in_shape", "dtype", "split", "perm", "new_split",
                  "out_shape", "j0", "slab", "nslabs", "out_block",
                  "nbuckets", "total_bytes", "slab_bytes", "ring",
                  "resident_bytes", "budget", "resident", "spill_dir",
-                 "alltoall_bytes", "sharded")
+                 "alltoall_bytes", "devices", "sharded")
 
     def __init__(self, **kw):
         for name in self.__slots__:
@@ -85,15 +101,18 @@ class ShufflePlan:
         mb = 1024.0 * 1024.0
         mode = "resident" if self.resident else (
             "spill to %s" % (self.spill_dir or "<no spill dir>"))
+        across = "" if self.devices <= 1 else (
+            " across %d devices%s" % (
+                self.devices, "" if self.sharded else " of one process"))
         return ("shuffle plan: %d slab%s -> %s (%.1f MiB working set, "
                 "budget %s, %d bucket%s x %d records, all-to-all "
-                "~%.1f MiB)"
+                "~%.1f MiB%s)"
                 % (self.nslabs, "s" if self.nslabs != 1 else "", mode,
                    self.resident_bytes / mb,
                    ("%.1f MiB" % (self.budget / mb))
                    if self.budget is not None else "unbounded",
                    self.nbuckets, "s" if self.nbuckets != 1 else "",
-                   self.out_block, self.alltoall_bytes / mb))
+                   self.out_block, self.alltoall_bytes / mb, across))
 
 
 def _axis0_device_width(mesh, shape, split):
@@ -137,8 +156,11 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     executor keeps in flight (``stream.swap_ring``);
     ``raw_slab_bytes`` what ONE of them holds as uploaded, where the
     stages before the re-axis change a slab's size (a collect of small
-    mapped records rings slabs far larger than what it places).  Raises
-    the pointed pod-geometry
+    mapped records rings slabs far larger than what it places).  The
+    plan records the mesh's two widths, each read once here:
+    ``sharded`` (more than one process: the form of the exchange, module
+    docstring) and ``devices`` (how many shard the input record axis:
+    the traffic model's divisor).  Raises the pointed pod-geometry
     errors HERE, before any thread starts, mirroring BLT012."""
     staged_shape = tuple(int(s) for s in staged_shape)
     perm = tuple(int(p) for p in perm)
@@ -181,8 +203,12 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     # (perm[0] == 0) every record keeps its device and nothing crosses;
     # otherwise each device keeps 1/d of what it holds and ships the
     # rest — the standard all-to-all volume over the d devices that
-    # shard the input record axis
-    d_in = _axis0_device_width(mesh, staged_shape, split)
+    # shard the record axis of a SLAB, which is what is uploaded and
+    # exchanged (a frame count the devices do not divide still goes up
+    # in slabs they do; a slab they do not divide goes up whole to each
+    # and nothing crosses; a short last slab counts like the others)
+    d_in = _axis0_device_width(
+        mesh, (max(1, min(slab, n)),) + staged_shape[1:], split)
     alltoall_bytes = 0 if perm[0] == 0 or d_in <= 1 else int(
         round(total_bytes * (d_in - 1) / d_in))
 
@@ -202,7 +228,8 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
         resident_bytes=int(resident_bytes),
         budget=None if budget is None else int(budget),
         resident=bool(resident), spill_dir=spill_dir,
-        alltoall_bytes=int(alltoall_bytes), sharded=bool(sharded))
+        alltoall_bytes=int(alltoall_bytes), devices=int(d_in),
+        sharded=bool(sharded))
 
 
 def _pod_axes_or_refuse(mesh, slab_shape, split, perm, out_slab_shape,

@@ -600,7 +600,7 @@ def _upload_slab_mh(block, mesh, split, slab_shape, axis0_off):
             p.block_until_ready()
         out = _sh.assemble_from_parts(slab_shape, sharding, parts)
         nbytes = int(block.nbytes)
-        _engine.record_transfer(nbytes, _clock() - t0)
+        _engine.record_transfer(nbytes, _clock() - t0, parts=len(parts))
         if sp is not None:
             sp.set(bytes=nbytes, parts=len(parts))
     finally:
@@ -1817,6 +1817,7 @@ class _IngestPool:
         self._hw_lock = _lockdep.lock("stream.uploader_hw")
         self._active = 0
         self.high_water = 0
+        self.parts = 0          # per-device sub-blocks the slabs were put as
         lead = threading.Thread(
             target=self._prefetch if jobs is None else self._dispense,
             name="bolt-stream-prefetch", daemon=True)
@@ -1888,9 +1889,14 @@ class _IngestPool:
             if self._active > self.high_water:
                 self.high_water = self._active
 
-    def _exit(self):
+    def _exit(self, buf=None):
+        """One attempt over; ``buf`` is the slab it uploaded, if it did."""
+        if isinstance(buf, tuple):
+            buf = buf[0]            # a sidecar codec's (wire, *sidecar)
         with self._hw_lock:
             self._active -= 1
+            if buf is not None:
+                self.parts += len(buf.sharding.addressable_devices)
 
     def _local(self, lo, hi):
         """The records of slab ``[lo, hi)`` THIS process ingests: all of
@@ -1978,7 +1984,7 @@ class _IngestPool:
                             attempt += 1
                             continue
                         _obs.end(sp)
-                        self._exit()
+                        self._exit(buf)
                         break
                     del block          # bnb = the LOCAL WIRE bytes this
                     #                    process acquired and uploaded
@@ -2000,6 +2006,7 @@ class _IngestPool:
                     if stop.is_set() or not _acquire(self._permits, stop):
                         return
                     g = self._first + j
+                    buf = None
                     self._enter()
                     sp = _obs.begin("stream.ingest", parent=self._parent,
                                     slab=g)
@@ -2037,7 +2044,7 @@ class _IngestPool:
                             sp.set(bytes=bnb, lo=lo, hi=hi)
                     finally:
                         _obs.end(sp)
-                        self._exit()
+                        self._exit(buf)
                     del block
                     self._rsq.put(j, (g, buf, bnb, tsec, hi))
                     j += 1
@@ -3017,7 +3024,8 @@ def _resolve_one_swap(source, collect=False):
                         inplace=plan.resident, ring=plan.ring,
                         slabs=plan.nslabs, buckets=plan.nbuckets,
                         out_block=plan.out_block,
-                        alltoall_bytes=plan.alltoall_bytes)
+                        alltoall_bytes=plan.alltoall_bytes,
+                        devices=plan.devices)
     # a one-shot iterable cannot resume, so `done` is empty without jobs
     pool = _IngestPool(run, base, plan.ring, jobs=jobs,
                        noun="shuffle slab", parent=run_sp)
@@ -3148,18 +3156,22 @@ def _resolve_one_swap(source, collect=False):
         if run.lease is not None:
             run.lease.close()
         wall = _clock() - t_start
+        # what crossed devices, by the planner's model, for the bytes
+        # this run moved (a resumed spill skips slabs)
+        crossed = plan.alltoall_bytes * moved // max(plan.total_bytes, 1)
         if collect:
             _engine.record_collect(placed, moved)
         else:
-            _engine.record_shuffle(moved, wall)
+            _engine.record_shuffle(moved, wall, alltoall=crossed)
         if run_sp is not None:
-            run_sp.set(bytes=moved)
+            run_sp.set(bytes=moved, crossed_bytes=crossed,
+                       upload_parts=pool.parts)
         _obs.end(run_sp)
     # phase 1 completed: one streamed run, under the counters every
     # streamed run reports (a spilled swap's phase 2 adds its own)
     _engine.record_stream(placed, ingest, compute, wall,
                           max(0.0, ingest + compute - wall), run.depth,
-                          uploaders=run.nwork,
+                          uploaders=max(pool.high_water, 1),
                           keyed=placed if keyed else 0)
 
     if plan.resident:

@@ -2,7 +2,7 @@
 """How many host-to-device copies does one device's link want in flight?
 
     python3 scripts/h2d_probe.py [--slab-mib 64 128] [--inflight 1 2 3 4]
-        [--runs 6] [--gib 8] [--tile-gib 2] [--out file]
+        [--runs 6] [--gib 8] [--tile-gib 2] [--devices 1] [--out file]
 
 The raw measurement under ``bolt_tpu.stream.pool_size``'s auto rule
 (ROADMAP S1 (a)), with nothing of bolt in the timed path: ``jax.device_put``
@@ -13,6 +13,12 @@ first device, with N copies in flight in two forms:
 * ``threads``: N threads, each putting one slab and waiting for it before
   its next (what the uploader pool does);
 * ``issuer``: one thread that keeps N puts issued and waits for the oldest.
+
+With ``--devices D`` (a four-chip host: 4) the copies go to the first D
+devices, ``--inflight`` copies in flight A DEVICE: ``threads`` runs N x D
+workers, each bound to one device, ``issuer`` keeps N x D puts issued in
+turn over the devices.  The first line then gives the host's ``MemTotal`` and
+the cores the process may run on.
 
 A reading is ``--gib`` GiB of slabs moved, bytes over the wall from the first
 put to the last buffer ready, in GB/s in aggregate.  ``--runs`` readings a
@@ -55,13 +61,13 @@ def slabs(tile, slab_records, count):
                  (i % per_tile + 1) * slab_records] for i in range(count)]
 
 
-def by_threads(jax, dev, views, n):
-    """N workers, one copy each at a time; the wall of all of them."""
+def by_threads(jax, devs, views, n):
+    """N workers a device, one copy each at a time; the wall of all."""
     todo = collections.deque(views)
-    gate = threading.Barrier(n + 1)
+    gate = threading.Barrier(n * len(devs) + 1)
     errors = []
 
-    def work():
+    def work(dev):
         gate.wait()
         try:
             while True:
@@ -71,7 +77,8 @@ def by_threads(jax, dev, views, n):
         except BaseException as exc:    # noqa: BLE001 - raised by the caller
             errors.append(exc)
 
-    pool = [threading.Thread(target=work, daemon=True) for _ in range(n)]
+    pool = [threading.Thread(target=work, args=(dev,), daemon=True)
+            for dev in devs for _ in range(n)]
     for t in pool:
         t.start()
     gate.wait()
@@ -84,14 +91,14 @@ def by_threads(jax, dev, views, n):
     return wall
 
 
-def by_issuer(jax, dev, views, n):
-    """One thread, N puts issued before it waits for the oldest."""
+def by_issuer(jax, devs, views, n):
+    """One thread, N puts a device issued before it waits for the oldest."""
     flight = collections.deque()
     t0 = time.perf_counter()
-    for view in views:
-        if len(flight) == n:
+    for i, view in enumerate(views):
+        if len(flight) == n * len(devs):
             flight.popleft().block_until_ready()
-        flight.append(jax.device_put(view, dev))
+        flight.append(jax.device_put(view, devs[i % len(devs)]))
     while flight:
         flight.popleft().block_until_ready()
     return time.perf_counter() - t0
@@ -110,11 +117,24 @@ def main(argv=None):
     ap.add_argument("--gib", type=float, default=8.0,
                     help="GiB moved a reading")
     ap.add_argument("--tile-gib", type=float, default=2.0)
+    ap.add_argument("--devices", type=int, default=1,
+                    help="copy to the first D devices, --inflight a device")
     ap.add_argument("--out")
     args = ap.parse_args(argv)
 
     import jax
-    dev = jax.devices()[0]
+    devs = jax.devices()[:args.devices]
+    if len(devs) < max(args.devices, 1):
+        print("h2d_probe: %d devices asked for, %d found"
+              % (args.devices, len(devs)), file=sys.stderr)
+        return 1
+    dev = devs[0]
+    if args.devices > 1:
+        with open("/proc/meminfo") as f:
+            mem = next(line for line in f if line.startswith("MemTotal"))
+        print("host: %s, %d cores for this process, %d devices"
+              % (" ".join(mem.split()), len(os.sched_getaffinity(0)),
+                 len(devs)), flush=True)
     if dev.platform == "cpu":
         print("h2d_probe: the first device is the CPU; nothing to measure",
               file=sys.stderr)
@@ -137,13 +157,13 @@ def main(argv=None):
         views[mib] = slabs(tile, slab_records, count)
         nbytes[mib] = sum(v.nbytes for v in views[mib])
         # the first copy of a size pays the runtime's set-up for it
-        by_issuer(jax, dev, views[mib][:4], 2)
+        by_issuer(jax, devs, views[mib][:4 * len(devs)], 2)
     readings = {s: [] for s in settings}
     for r in range(args.runs):
         k = r % len(settings)
         for s in settings[k:] + settings[:k]:
             mib, form, n = s
-            wall = FORMS[form](jax, dev, views[mib], n)
+            wall = FORMS[form](jax, devs, views[mib], n)
             readings[s].append(nbytes[mib] / wall / 1e9)
     rows = []
     print("%8s %8s %8s  %8s %8s %8s  readings (GB/s)"
@@ -156,7 +176,8 @@ def main(argv=None):
         print("%8d %8s %8d  %8.3f %8.3f %8.3f  %s"
               % (mib, form, n, statistics.median(got), min(got), max(got),
                  " ".join("%.3f" % g for g in got)), flush=True)
-    out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind,
+                      "count": len(devs)},
            "tile_gib": tile.nbytes / (1 << 30), "gib_a_reading": args.gib,
            "runs": args.runs, "rows": rows}
     line = json.dumps(out)

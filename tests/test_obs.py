@@ -329,6 +329,7 @@ _EXPECTED_ENGINE_KEYS = {
     "shuffle_seconds": True,
     "stream_collect_slabs": False, "stream_collect_bytes": False,
     "stream_keyed_slabs": False,
+    "stream_alltoall_bytes": False, "stream_upload_parts": False,
 }
 
 

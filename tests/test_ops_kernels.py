@@ -1349,3 +1349,65 @@ def _blocked_estimate(funcs, block):
     heavy, live, _ = blocks.record_live_bytes(_record_fn(funcs), rec)
     assert heavy
     return live * block
+
+
+# ---------------------------------------------------------------------
+# compile-only: the streamed swap's place program on a ONE-PROCESS 2x2
+# host (ISSUE 43).  ``plan.sharded`` means processes, so the exchange of
+# a slab across the four chips is GSPMD's; what it has to stay is what it
+# was read to be at the ``twophoton512-4chip`` cell's shapes (PERF.md, PR
+# 43): ONE all-to-all of the slab as uploaded (a chip's 32 frames split
+# over the four that shard the rows: whole lane tiles), no slab gathered
+# whole on every chip, and the 10.74 GB a chip of the series array
+# updated in place, at an offset whose low bits the compiler knows
+# ---------------------------------------------------------------------
+
+def test_place_program_on_four_chips_is_one_all_to_all_and_in_place(
+        v5e_device):
+    import re
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec
+    from bolt_tpu.parallel import sharding as sh
+    from bolt_tpu.parallel import shuffle
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("k",))
+    frames, slab, frame = 40960, 128, (512, 512)
+    plan = shuffle.plan_shuffle((frames,) + frame, np.float32, 1, (1, 2, 0),
+                                2, mesh, slab, None, None, ring=6)
+    assert not plan.sharded and plan.devices == 4 and plan.resident
+    assert plan.alltoall_bytes == frames * 512 * 512 * 4 * 3 // 4
+    slab_shape = (slab,) + frame
+    slab_bytes = slab * 512 * 512 * 4
+    with jax.enable_x64(False):
+        prog = shuffle.place_program(plan, (), mesh, None,
+                                     np.dtype(np.float32), slab_shape,
+                                     True, slab)
+        compiled = prog.lower(
+            jax.ShapeDtypeStruct(plan.out_shape, _F32,
+                                 sharding=sh.key_sharding(
+                                     mesh, plan.out_shape, 2)),
+            jax.ShapeDtypeStruct(slab_shape, _F32,
+                                 sharding=sh.key_sharding(mesh, slab_shape,
+                                                          1)),
+            jax.ShapeDtypeStruct((), np.uint32, sharding=NamedSharding(
+                mesh, PartitionSpec()))).compile()
+    text = compiled.as_text()
+    found = re.findall(r"= \S+ (all-to-all|all-gather|all-reduce|"
+                       r"collective-permute|reduce-scatter)(?:-start)?\(",
+                       text)
+    assert found == ["all-to-all"], found
+    # the exchange is of a chip's quarter of the slab, the frames whole
+    # sublane tiles and the 512 of y on the lanes
+    assert re.search(r"f32\[4,128,512,32\]\{2,3,1,0:T\(8,128\)[^}]*\} "
+                     r"all-to-all\(", text), "the exchanged block changed"
+    stats = compiled.memory_analysis()
+    per_chip = frames * 512 * 512 * 4 // 4
+    assert stats.alias_size_in_bytes == per_chip      # updated in place
+    assert "input_output_alias={ {0}: (0, {}, may-alias) }" in text
+    # nothing as large as a slab gathered whole beside the output
+    assert stats.temp_size_in_bytes < slab_bytes
+    assert stats.argument_size_in_bytes < per_chip + slab_bytes
+    # the update is aligned: the offset's low bits are known
+    assert "index_known_bits" in text

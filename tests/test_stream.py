@@ -1055,7 +1055,7 @@ def test_pool_hands_slabs_out_in_order(mesh, monkeypatch, kind):
                 and time.time() - t0 < 10:
             time.sleep(0.002)
         finished.append(lo)
-        return block.copy()
+        return jax.device_put(block)
 
     monkeypatch.setattr(stream, "_upload_slab", upload)
     pool = _pool(kind, mesh, 4, data, uploaders=3)
@@ -1082,7 +1082,7 @@ def test_pool_never_exceeds_its_ring(mesh, monkeypatch, kind):
     started = []
     monkeypatch.setattr(stream, "_upload_slab",
                         lambda block, m, s: (started.append(1),
-                                             block.copy())[1])
+                                             jax.device_put(block))[1])
     pool = _pool(kind, mesh, 2, data)
     pool.start()
     try:
@@ -1111,7 +1111,7 @@ def test_pool_retries_in_place_and_chains_the_attempts(mesh, monkeypatch,
         if np.array_equal(block, data[4:8]) and len(fails) < len(errs):
             fails.append(errs[len(fails)])      # slab 1 fails 3 times
             raise fails[-1]
-        return block.copy()
+        return jax.device_put(block)
 
     monkeypatch.setattr(stream, "_upload_slab", upload)
     c0 = engine.counters()["stream_retries"]
