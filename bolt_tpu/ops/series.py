@@ -4,29 +4,34 @@ center, cross-correlation, the Fourier tuning map.
 The reference ecosystem's TimeSeries workloads (Thunder: records keyed by
 pixel/channel, values = a time axis) detrend and standardise every record
 before analysis.  Here each transform is a traceable per-record ``map`` —
-it DEFERS like any map and fuses into the next action.  Both backends run
-the same math (NumPy locally — the oracle).
+it DEFERS like any map and fuses into the next action.  Both backends
+compute the same thing (NumPy locally — the oracle).
 
 What that costs on the device depends on what a record's function keeps:
 
-* ``detrend``, ``zscore``, ``center``, ``crosscorr`` and
+* ``detrend``, ``zscore``, ``center``, ``crosscorr``, ``fourier`` and
   ``normalize(baseline="mean")`` are element-wise work and reductions
   within a record: XLA fuses them, so ``zscore(detrend(b)).stats()`` is
   one compiled pass over HBM with no temporary (compiled for the v5e,
-  ``detrend -> sum`` over 10.74 GB takes none).
+  ``detrend -> sum`` over 10.74 GB takes none, and ``fourier`` over it
+  is two reads with none: the mean, then five sums in one fusion).
+  ``fourier`` asks for ONE bin of each record's spectrum and for the
+  spectrum's energy, and on the device it computes those and no
+  transform: the bin is the centred record's product with a cosine and a
+  sine, the energy Parseval's identity on the record itself (PR 44).
 * ``normalize(baseline="percentile")`` takes two order statistics of each
-  record and ``fourier`` transforms it.  The order statistics are SELECTED
-  (``ops/select.py``: the k-th smallest built bit by bit by counting
-  passes over the record's image of integer keys, exact) from a length
-  on; a shorter record is sorted by ``jnp.percentile``, to the same
-  answer to the bit.  In a program for one TPU device the passes run on
-  a tile of records held in VMEM, one Mosaic kernel that reads a block
-  ONCE (PR 40); everywhere else they are a loop of ``jax.numpy`` passes,
-  nineteen reads of a block.  That loop, the kernel, a sort and an FFT
+  record.  They are SELECTED (``ops/select.py``: the k-th smallest built
+  bit by bit by counting passes over the record's image of integer keys,
+  exact) from a length on; a shorter record is sorted by
+  ``jnp.percentile``, to the same answer to the bit.  In a program for
+  one TPU device the passes run on a tile of records held in VMEM, one
+  Mosaic kernel that reads a block ONCE (PR 40); everywhere else they are
+  a loop of ``jax.numpy`` passes, nineteen reads of a block.  That loop,
+  the kernel and a sort (and a caller's own FFT or scan in a ``map``)
   keep record-sized temporaries that XLA does not fuse away.  Over a
   small array that changes nothing; over an array too large to hold them
   for every record at once (a resident series array of HBM size asks for
-  20 and 40 GB) the consuming program runs the chain over BLOCKS of whole
+  20 GB) the consuming program runs the chain over BLOCKS of whole
   records, chosen by a rule and not by the caller
   (``bolt_tpu/tpu/blocks.py``; ``analysis.explain`` says "blocked: n
   blocks of r records", and of a ``normalize`` stage "percentile by
@@ -233,7 +238,7 @@ def fourier(b, freq, axis=0, epsilon=0.0):
     workload; semantics stated explicitly here since the reference
     mount was empty — SURVEY.md §0).
 
-    Each record is mean-centred and transformed with a real FFT; at bin
+    Each record is mean-centred; with ``co`` its real DFT, at bin
     ``freq`` (1 ≤ freq ≤ L//2, DC excluded):
 
     * **coherence** = ``|co[freq]| / sqrt(sum_{k>=1} |co[k]|^2)`` — the
@@ -246,15 +251,21 @@ def fourier(b, freq, axis=0, epsilon=0.0):
     so the contract of this module holds and downstream ops fuse).  On
     the TPU backend the pair SHARES ONE RUN of everything behind it: the
     two are consumers of one deferred map, so whichever is forced first
-    runs the chain once, from the base through the FFT, its
+    runs the chain once, from the base through the bin, its
     ``(..., 2)`` result is kept on the device while the other handle
     lives, and both are slices of that
     (``BoltArrayTPU._lower_from_shared``; engine counters
     ``shared_parent_runs`` / ``shared_parent_hits``).  A caller who
     keeps only one of the two pays for one program, as before.
     ``epsilon`` guards constant records, which otherwise divide 0/0 to
-    NaN (same convention as ``zscore``/``crosscorr``).  XLA lowers the
-    FFT natively on TPU.
+    NaN (same convention as ``zscore``/``crosscorr``).
+
+    Locally ``co`` is ``np.fft.rfft``'s (the oracle).  On the device no
+    transform runs: one bin of a DFT is the record's product with a
+    cosine and a sine, and ``sum_{k>=1} |co[k]|^2`` is Parseval's
+    identity on the record, ``(L sum y^2 - (sum y)^2 + nyquist^2) / 2``
+    (``_bin_and_energy``).  Both are exact, so the two routes differ by
+    rounding alone, and the work is ``O(L)`` a record with no temporary.
     """
     freq = int(freq)
     ax, split = _value_axis(b, axis)
@@ -270,12 +281,33 @@ def fourier(b, freq, axis=0, epsilon=0.0):
 
 
 @lru_cache(maxsize=128)
+def _bin_rows(freq, length):
+    """What a series of ``length`` points is multiplied by, by NumPy in
+    float64: ``(cos, -sin)`` of bin ``freq`` of its DFT (the bin is
+    ``sum(y * cos) + 1j * sum(y * -sin)``; the angle is reduced in
+    integers first) and ``(-1)**t``, the Nyquist bin of an even length.
+    Where ``2 * freq == length`` the bin IS the Nyquist bin, real as
+    ``rfft`` gives it, and there is no pair; an odd length has no
+    Nyquist bin."""
+    t = np.arange(length)
+    pair = alt = None
+    if 2 * freq != length:
+        angle = 2.0 * np.pi * ((freq * t) % length) / length
+        pair = np.cos(angle), -np.sin(angle)
+    if length % 2 == 0:
+        alt = 1.0 - 2.0 * (t % 2)
+    return pair, alt
+
+
+@lru_cache(maxsize=128)
 def _fourier_fn(freq, ax, epsilon):
     def f(v):
         xp = np if isinstance(v, np.ndarray) else jnp
         dt = xp.promote_types(v.dtype, xp.float32)
         moved = xp.moveaxis(v.astype(dt), ax, -1)
         y = moved - xp.mean(moved, axis=-1, keepdims=True)
+        if xp is jnp:
+            return jnp.stack(_bin_and_energy(y, freq, epsilon), axis=ax)
         co = xp.fft.rfft(y, axis=-1)
         mag2 = xp.abs(co[..., 1:]) ** 2
         coh = (xp.abs(co[..., freq])
@@ -283,6 +315,42 @@ def _fourier_fn(freq, ax, epsilon):
         ph = xp.angle(co[..., freq])
         return xp.stack([coh, ph], axis=ax)
     return f
+
+
+def _bin_and_energy(y, freq, epsilon):
+    """``(coherence, phase)`` at bin ``freq`` of the centred series ``y``
+    (last axis) with no transform.  The bin is the series' product with
+    a cosine and a sine; the half spectrum's non-DC energy is Parseval's
+    identity on the series itself: ``sum_k |Y_k|^2 = L sum y^2`` over
+    all ``L`` bins, of which ``k`` and ``L - k`` are conjugates, the
+    Nyquist bin of an even ``L`` stands once, and ``|Y_0|^2 = (sum y)^2``
+    is left out as the definition leaves the DC bin out.  Both are exact;
+    what differs from ``rfft`` (the NumPy route, the oracle) is rounding.
+
+    Spelt as multiply-reduces and not as one thin product ``y @ W``:
+    compiled for the v5e the five sums are ONE fusion that reads ``y``
+    once, where the product and ``sum(y * y)`` read it twice (PERF.md, PR
+    44).  float32 products and sums on the vector unit: no pass on the
+    MXU, so no precision to pin."""
+    length = y.shape[-1]
+    pair, alt = _bin_rows(freq, length)
+
+    def dot(row):
+        return jnp.sum(y * jnp.asarray(row, y.dtype), axis=-1)
+
+    total = length * jnp.sum(y * y, axis=-1) - jnp.sum(y, axis=-1) ** 2
+    if alt is not None:
+        nyquist = dot(alt)
+        total = total + nyquist ** 2
+    if pair is None:
+        re, im = nyquist, jnp.zeros_like(nyquist)
+    else:
+        re, im = dot(pair[0]), dot(pair[1])
+    # the bin is one of the bins summed: rounding may not take the
+    # energy under it (nor under zero, where sqrt gives NaN)
+    energy = jnp.maximum(total / 2, re * re + im * im)
+    return (jnp.hypot(re, im) / (jnp.sqrt(energy) + epsilon),
+            jnp.arctan2(im, re))
 
 
 @lru_cache(maxsize=128)

@@ -9,7 +9,8 @@ temporaries at all; one with a record-sized temporary that is NOT fused away
 ARRAY, and a resident array over a third of HBM can take no such function.
 Compiled for the v5e at ``(512, 512, 10240)`` float32 (10.74 GB): a
 percentile by ``jnp.percentile``'s sort asks for 20.00 G of 15.75 G,
-``fourier`` for 40.00 G (PERF.md, PR 36; since PR 37 the percentile of a
+``jnp.fft.rfft`` of every record, as ``ops.fourier`` took it until PR 44,
+for 40.00 G (PERF.md, PR 36; since PR 37 the percentile of a
 record that long is selected, and held the record's image of keys where
 the sort held its copy; since PR 40 the selection is the primitive
 ``percentile_select``, whose Mosaic kernel holds a tile's image in VMEM
@@ -31,9 +32,11 @@ its getitem windows) are traced ONCE, on ONE record's aval, to a jaxpr:
 * a heavy run holds live, per record, the peak of its jaxpr's intermediates
   (:func:`record_live_bytes`: every intermediate counted as written out,
   which errs high for what fuses and LOW for what the jaxpr does not show:
-  compiled for the v5e the cell's whole chain takes 0.6 of the estimate,
-  ``fourier`` alone 1.55 of it, XLA's FFT scratch), and all its records at
-  once hold ``records x`` that;
+  compiled for the v5e the ``tuning`` cell's whole chain took 0.6 of the
+  estimate while its last map was an FFT, and that FFT alone 1.55 of its
+  own, XLA's scratch; since PR 44 ``ops.fourier`` holds no heavy
+  primitive, and the chain, heavy by its selection, takes 0.14), and all
+  its records at once hold ``records x`` that;
 * when that passes :data:`SHARE` of what the device has left after the
   chain's base and its result (``memory_stats()["bytes_limit"]`` less
   both), the run is lowered over blocks of whole records, the largest block

@@ -1280,8 +1280,52 @@ def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
             text)
         assert len(calls) == 1 and "while/body" in calls[0]
         assert calls[0].endswith("percentile_select/pallas_call")
+        # fourier's bin and energy are sums over the series (PR 44): no
+        # transform in the program, and the one temporary is the block
+        # written out for the selection's kernel, a quarter of what the
+        # program held with the FFT in it (9831aad: 879,325,696 and
+        # 877,326,848 bytes)
+        assert not re.search(r"\bfft\b", text)
+        assert mem.temp_size_in_bytes < 1.02 * 4 * block * _PIXELS[2]
         with pytest.raises(Exception, match="Exceeded hbm capacity|hbm"):
             jax.jit(lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
+
+
+# ``fourier`` straight over the resident array holds no primitive of
+# ``blocks.HEAVY`` since PR 44 (it asked 40 G for its FFT before): the
+# rule leaves it whole, and it compiles whole, two reads of the array (the
+# mean, then the five sums in one fusion) with no temporary; float32
+# products and sums on the vector unit, nothing on the MXU
+def test_a_bare_fourier_compiles_whole_and_without_a_transform_on_v5e(
+        v5e_device):
+    import jax
+    from bolt_tpu.tpu.array import _chain_apply, _plan_blocks
+    funcs = _tuning_chain()[2:3]
+    base = 4 * int(np.prod(_PIXELS))
+    free = _V5E_HBM - base - 4 * 512 * 512 * 2
+    assert _plan_blocks(funcs, 2, _PIXELS, np.float32, free) == funcs
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    arg = jax.ShapeDtypeStruct(_PIXELS, _F32, sharding=where)
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == base
+    assert mem.output_size_in_bytes == 4 * 512 * 512 * 2
+    assert mem.temp_size_in_bytes < 64 * 2 ** 20     # of the rule's 1.54 GB
+    text = compiled.as_text()
+    assert not re.search(r"\bfft\b| while\(|convolution|\bdot\(", text)
+    # two instructions read the array: the mean's reduce, and ONE
+    # fusion of the five sums
+    entry = text[text.index("\nENTRY "):]
+    base_name = re.search(r"(%\S+) = f32\[512,512,10240\]\S* parameter\(0\)",
+                          entry).group(1)
+    reads = [line for line in entry.splitlines()
+             if re.search(r"\(%s[,)]" % re.escape(base_name), line)]
+    assert len(reads) == 2, reads
+    assert any(" reduce(" in line for line in reads)
+    sums, = [line for line in reads if " fusion(" in line]
+    assert sums[:sums.index(" fusion(")].count("f32[512,512]") == 5, sums
 
 
 # which executor a selection gets is the lowering's to say, from the

@@ -229,6 +229,160 @@ def test_fourier_parity(mesh):
                     np.asarray(lcoh.toarray()) * 2)
 
 
+# ---------------------------------------------------------------------
+# the device side of ``fourier`` takes ONE bin as a product with a cosine
+# and a sine and the energy from Parseval's identity on the series (PR
+# 44); the NumPy side is ``np.fft.rfft``.  Two independent routes to one
+# definition, compared over what a route could get wrong: the parity of
+# the length, the first, an inner and the Nyquist bin, the dtype, where
+# the series axis lies
+# ---------------------------------------------------------------------
+
+def _turn(a, b):
+    """Angular distance on the circle."""
+    return np.abs(np.angle(np.exp(1j * (np.asarray(a, np.float64) - b))))
+
+
+def _planted(shape, freq, seed, dtype):
+    """Series along the LAST axis: a third sinusoids at ``freq`` in
+    noise, a third sinusoids two bins away on a slope, a third noise,
+    all on a level of 40 (the centring matters)."""
+    rs = np.random.RandomState(seed)
+    length = shape[-1]
+    t = np.arange(length)
+    x = rs.randn(*shape) * 0.5 + 40.0
+    flat = x.reshape(-1, length)
+    for i, row in enumerate(flat):
+        if i % 3 == 0:
+            row += 2.0 * np.cos(2 * np.pi * freq * t / length + 0.1 * i)
+        elif i % 3 == 1:
+            row += np.sin(2 * np.pi * (freq + 2) * t / length) + 0.01 * t
+    return x.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+@pytest.mark.parametrize("length,freq", [
+    (64, 1), (64, 5), (64, 32), (63, 1), (63, 5), (63, 31),
+    (250, 7), (250, 125), (1001, 500)])
+def test_fourier_on_the_device_is_the_rfft_route(mesh, length, freq, dtype):
+    from bolt_tpu.ops import fourier
+    x = _planted((24, length), freq, 100 * length + freq, dtype)
+    # the oracle reads the same values, in float64
+    lcoh, lph = fourier(bolt.array(x.astype(np.float64)), freq=freq)
+    tcoh, tph = fourier(bolt.array(x, mesh), freq=freq)
+    lcoh, lph = lcoh.toarray(), lph.toarray()
+    tcoh, tph = tcoh.toarray(), tph.toarray()
+    assert tcoh.dtype == tph.dtype == np.dtype(dtype)
+    assert tcoh.shape == tph.shape == (24,)
+    f32 = dtype == "float32"
+    assert allclose(lcoh, tcoh, rtol=1e-6, atol=2e-7 if f32 else 1e-12)
+    if 2 * freq == length:
+        # the Nyquist bin is real: 0 or pi, to the bit, with rfft's sign
+        assert set(np.unique(tph)) <= {0.0, np.dtype(dtype).type(np.pi)}
+        assert np.array_equal(tph > 1, lph > 1)
+    else:
+        tuned = lcoh > 0.05             # an empty bin has no angle
+        assert tuned.sum() >= 8
+        assert np.max(_turn(tph, lph)[tuned]) < (1e-5 if f32 else 1e-11)
+
+
+@pytest.mark.parametrize("length,freq", [(64, 4), (64, 32), (63, 4),
+                                         (63, 31), (10240, 16)])
+def test_fourier_of_a_pure_sinusoid_at_the_bin_is_all_of_the_energy(
+        mesh, length, freq):
+    from bolt_tpu.ops import fourier
+    t = np.arange(length)
+    shifts = (0.0, np.pi) if 2 * freq == length else (0.7, -2.1)
+    x = np.stack([3.0 * np.cos(2 * np.pi * freq * t / length + p) + 11.0
+                  for p in shifts]).astype(np.float32)
+    coh, ph = fourier(bolt.array(x, mesh), freq=freq)
+    assert np.max(np.abs(coh.toarray() - 1.0)) < 2e-6
+    # cos(wt + p) has the angle p at its bin
+    assert np.max(_turn(ph.toarray(), np.asarray(shifts))) < 2e-5
+
+
+@pytest.mark.parametrize("length", [16, 15], ids=["even", "odd"])
+@pytest.mark.parametrize("epsilon", [0.0, 1e-9], ids=["bare", "guarded"])
+def test_fourier_of_a_constant_record(mesh, length, epsilon):
+    # no energy at all: 0/0 without the guard (NaN, as the rfft route
+    # gives), 0 with it; never the NaN of a negative square root, and
+    # the angle of an empty bin is 0 on both routes
+    from bolt_tpu.ops import fourier
+    x = np.full((3, length), 7.25)
+    arrays = [bolt.array(x), bolt.array(x, mesh)]
+    if length == 16:                    # whose float32 mean is exact
+        arrays.append(bolt.array(x.astype(np.float32), mesh))
+    for b in arrays:
+        coh, ph = fourier(b, freq=2, epsilon=epsilon)
+        coh, ph = coh.toarray(), ph.toarray()
+        if epsilon:
+            assert np.array_equal(coh, np.zeros(3))
+        else:
+            assert np.isnan(coh).all()
+        assert np.array_equal(ph, np.zeros(3))
+
+
+@pytest.mark.parametrize("length,freq", [(250, 7), (251, 7)],
+                         ids=["even", "odd"])
+def test_fourier_leaves_out_what_the_centring_left_in_the_dc_bin(
+        mesh, length, freq):
+    # float32 on a level of 1000: the mean is rounded to 6e-5 and the
+    # centred series keeps that much of a level, which is the DC bin's
+    # and no other's.  The definition leaves the DC bin out; an energy
+    # that kept it would read the coherence 3e-3 low here
+    from bolt_tpu.ops import fourier
+    rs = np.random.RandomState(3)
+    t = np.arange(length)
+    x = (1000.3 + 0.002 * np.cos(2 * np.pi * freq * t / length
+                                 + 6 * rs.rand(24, 1))
+         + 0.001 * rs.randn(24, length)).astype(np.float32)
+    lcoh, lph = fourier(bolt.array(x.astype(np.float64)), freq=freq)
+    tcoh, tph = fourier(bolt.array(x, mesh), freq=freq)
+    assert tcoh.dtype == np.float32
+    assert np.max(np.abs(tcoh.toarray() - lcoh.toarray())) < 2e-6
+    assert np.max(_turn(tph.toarray(), lph.toarray())) < 2e-6
+
+
+@pytest.mark.parametrize("dtype", ["int16", "int32", "uint8"])
+def test_fourier_promotes_an_integer_record(mesh, dtype):
+    from bolt_tpu.ops import fourier
+    x = np.round(_planted((12, 96), 6, 5, "float64")).astype(dtype)
+    lcoh, lph = fourier(bolt.array(x), freq=6)
+    tcoh, tph = fourier(bolt.array(x, mesh), freq=6)
+    assert tcoh.dtype == np.float32 == tph.dtype
+    assert allclose(lcoh.toarray(), tcoh.toarray(), rtol=1e-6, atol=2e-7)
+    tuned = lcoh.toarray() > 0.05
+    assert np.max(_turn(tph.toarray(), lph.toarray())[tuned]) < 1e-5
+
+
+@pytest.mark.parametrize("name,shape,split,axis", [
+    ("first-of-two", (6, 48, 3), 1, 0),
+    ("last-of-two", (6, 3, 48), 1, 1),
+    ("last-of-two-from-the-end", (6, 3, 48), 1, -1),
+    ("middle-of-three", (4, 2, 48, 3), 1, 1),
+    ("first-of-two-split-2", (3, 4, 48, 2), 2, 0),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_fourier_along_any_value_axis(mesh, name, shape, split, axis):
+    from bolt_tpu.ops import fourier
+    at = split + (axis % (len(shape) - split))
+    moved = shape[:at] + shape[at + 1:] + (shape[at],)
+    x = np.moveaxis(_planted(moved, 5, 9, "float64"), -1, at)
+    assert x.shape == shape
+    co = np.fft.rfft(np.moveaxis(x, at, -1)
+                     - x.mean(axis=at)[..., None], axis=-1)
+    want = np.abs(co[..., 5]) / np.sqrt(np.sum(np.abs(co[..., 1:]) ** 2,
+                                               axis=-1))
+    tcoh, tph = fourier(bolt.array(x, mesh, axis=tuple(range(split))),
+                        freq=5, axis=axis)
+    assert tcoh.shape == tph.shape == want.shape
+    assert allclose(tcoh.toarray(), want, rtol=1e-9)
+    assert np.max(_turn(tph.toarray(), np.angle(co[..., 5]))) < 1e-9
+    if split == 1:                      # the local backend keys axis 0
+        lcoh, lph = fourier(bolt.array(x), freq=5, axis=axis)
+        assert allclose(lcoh.toarray(), want, rtol=1e-12)
+        assert allclose(lph.toarray(), np.angle(co[..., 5]), rtol=1e-12)
+
+
 def test_normalize_parity(mesh):
     from bolt_tpu.ops import normalize
     rs = np.random.RandomState(23)

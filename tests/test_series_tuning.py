@@ -205,8 +205,8 @@ _SERIES_OPS = [
     ("zscore", lambda b: ops.zscore(srt(b))),
     ("center", lambda b: ops.center(srt(b))),
     ("crosscorr", lambda b: ops.crosscorr(srt(b), _SIGNAL, lag=2)),
-    ("fourier-coherence", lambda b: ops.fourier(b, freq=FREQ)[0]),
-    ("fourier-phase", lambda b: ops.fourier(b, freq=FREQ)[1]),
+    ("fourier-coherence", lambda b: ops.fourier(srt(b), freq=FREQ)[0]),
+    ("fourier-phase", lambda b: ops.fourier(srt(b), freq=FREQ)[1]),
     ("tuning-coherence", lambda b: tuning(b)[0]),
     ("user-sort", srt),
     ("user-cumsum", lambda b: keymap(b, lambda v: jnp.cumsum(v) * 0.5)),

@@ -538,14 +538,16 @@ def single_consumer_program(mesh, cell):
 
 
 # what they read as at the parent commit (85d0d52, jax 0.9.0): printed
-# there by ``single_consumer_program`` under ``tests/conftest.py``
+# there by ``single_consumer_program`` under ``tests/conftest.py``.  The
+# tuning chain's text is PR 44's, under the engine key it had: that PR
+# took the FFT out of ``fourier``'s record function, the chain's last map
 PARENT_PROGRAMS = {
     "three-maps": {
         "4e42b4d2a6a0b9d7":
         "6a407a87d54afd055b4c18d333db192d63f5faf22062374a7b509bdef320a1a2"},
     "tuning-one-handle": {
         "3ca6f753dc6fd6aa":
-        "44cd956bf01095f3e650b769c93d8b65ba3fed928d76b0e04dc6682ad5de0231"},
+        "b0cd407b4582c347387673c7c0254a35f86973967eee4a39f9f145fac74a34b9"},
     "v+1": {"ccbde19964e72a4a":
             "26d723e7fcc2eb9405883b047b615105f311451d5688368ffc081acea2001d31"},
     "q1q6-product": {
