@@ -48,6 +48,7 @@ import hashlib
 import os
 import re
 import threading
+import warnings
 from collections import OrderedDict, deque
 
 import jax
@@ -222,6 +223,12 @@ _SCHEMA = {
     # program for one TPU device); every other selection lowers to the
     # counting passes and counts nothing here
     "percentile_kernel_lowerings": 0,
+    # resident swaps across chips LOWERED as an explicit exchange and the
+    # one-pass glue (bolt_tpu/parallel/swapmerge.py: a TPU mesh, the key
+    # axis on the lanes in pieces that are no whole lane tiles); every
+    # other swap keeps the transpose under a constraint and counts
+    # nothing here
+    "swap_merge_lowerings": 0,
     # cross-tenant coalescing proof (bolt_tpu.serve: N tenants running
     # the same pipeline shape must compile ONCE) — lookups that WAITED
     # for a concurrent identical build/compile instead of duplicating it
@@ -630,6 +637,54 @@ def disarm_warm_start():
     _WARM_ARMED = False
 
 
+def exported(tag, parts, make, *specs, out_shardings=None):
+    """The function ``make()`` returns, jitted (``out_shardings``) and
+    exported for the abstract arguments ``specs`` (``jax.export``): read
+    from the on-disk cache where one is attached and holds it, else
+    exported now and kept there.  ``None`` where no cache is attached.
+
+    For a program whose LOWERING costs more than a read: JAX's
+    compilation cache is keyed by the lowered module, so a warm process
+    still traces and lowers every program, and one that holds a Pallas
+    kernel first imports Pallas, 1.2-1.4 s on the chip's host (PERF.md,
+    PR 45).  The exported module is the lowered program itself, the
+    kernel inside it as bytes: calling it traces nothing of ``make``'s.
+    ``parts`` is everything that decides the program beside ``specs``
+    and the jax that lowers it (the caller's own source among it, where
+    that can change between runs of one cache)."""
+    directory = persistent_cache_dir()
+    if directory is None:
+        return None
+    from jax import export
+    import jaxlib
+    said = repr((jax.__version__, jaxlib.__version__,
+                 bool(jax.config.jax_enable_x64), parts,
+                 [(s.shape, str(s.dtype), str(s.sharding)) for s in specs]))
+    path = os.path.join(directory, "bolt_exported", "%s-%s.jaxexport" % (
+        tag, hashlib.sha256(said.encode()).hexdigest()[:40]))
+    try:
+        with open(path, "rb") as fh:
+            return export.deserialize(bytearray(fh.read()))
+    except FileNotFoundError:
+        pass
+    except Exception as exc:    # noqa: BLE001 - whatever a cut or foreign
+        # file makes the reader raise: say so and export again
+        warnings.warn("bolt_tpu: %s is unreadable (%r); exporting %s again"
+                      % (path, exc, tag), RuntimeWarning, stacklevel=2)
+    platform = next(iter(specs[0].sharding.device_set)).platform
+    program = export.export(jax.jit(make(), out_shardings=out_shardings),
+                            platforms=(platform,))(*specs)
+    try:
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        scratch = "%s.%d.tmp" % (path, os.getpid())
+        with open(scratch, "wb") as fh:
+            fh.write(program.serialize())
+        os.replace(scratch, path)       # whole or absent, never cut
+    except OSError:
+        pass                    # a cache that cannot be written is none
+    return program
+
+
 # ---------------------------------------------------------------------
 # donation policy
 # ---------------------------------------------------------------------
@@ -776,6 +831,12 @@ def record_percentile_lowering(regime):
     percentile``), or a selection was lowered as the Mosaic kernel
     (``"kernel"``: the ``percentile_select`` primitive's TPU rule)."""
     _COUNTERS.add("percentile_%s_lowerings" % regime)
+
+
+def record_swap_merge_lowering():
+    """A resident swap was lowered with the one-pass glue
+    (``parallel/swapmerge.py :: program``)."""
+    _COUNTERS.add("swap_merge_lowerings")
 
 
 def donation_granted():

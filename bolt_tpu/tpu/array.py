@@ -40,7 +40,7 @@ import sys
 import threading
 import warnings
 from collections import OrderedDict
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import NamedTuple
 
 import numpy as np
@@ -54,6 +54,7 @@ from bolt_tpu import stream as _streamlib
 from bolt_tpu.base import BoltArray, HostFallbackWarning
 from bolt_tpu.obs import trace as _obs
 from bolt_tpu._compat import shard_map as _shard_map
+from bolt_tpu.parallel import swapmerge as _swapmerge
 from bolt_tpu.parallel.sharding import key_sharding, key_spec, spec_names
 from bolt_tpu.tpu import blocks as _blocks
 from bolt_tpu.tpu import fold as _fold
@@ -340,6 +341,19 @@ def _constrain(out, mesh, split):
     at trace time, so the spec is computable inside jit)."""
     return jax.lax.with_sharding_constraint(
         out, key_sharding(mesh, out.shape, split))
+
+
+def _swap_glue(mesh, data, split, perm, new_split):
+    """What to jit for the swap ``transpose(perm)`` of the resident
+    ``data`` where it is an explicit exchange and a one-pass glue (a
+    function of no arguments that makes it), or ``None`` where the swap
+    keeps the transpose under a constraint (``parallel/swapmerge.py``
+    says which and why).  Holds the array's shape, not the array."""
+    p = _swapmerge.plan(mesh, data.shape, data.dtype, split, perm, new_split)
+    if p is None or not _swapmerge.takes(p, mesh, data):
+        return None
+    return partial(_swapmerge.swapper, p, mesh, perm, data.shape,
+                   data.dtype)
 
 
 def _traceable(func):
@@ -3693,8 +3707,17 @@ class BoltArrayTPU(BoltArray):
             # keeps materialise-first semantics: the chain's BASE buffer
             # may be aliased by other arrays, so it must not be donated)
             base, funcs = self._chain_parts()
+            # a materialised base whose sharded axis moves across chips
+            # is exchanged and glued in one pass where GSPMD's program
+            # takes two (parallel/swapmerge.py); a chain keeps the
+            # transpose it fuses into
+            glue = (None if funcs else
+                    _swap_glue(mesh, base, split, perm, new_split))
 
             def build():
+                if glue is not None:
+                    return jax.jit(glue())
+
                 def swapper(data):
                     mapped = _chain_apply(funcs, split, data)
                     return _constrain(jnp.transpose(mapped, perm), mesh,
@@ -3702,18 +3725,26 @@ class BoltArrayTPU(BoltArray):
                 return jax.jit(swapper)
 
             fn = _cached_jit(("swap", funcs, base.shape, str(base.dtype),
-                              tuple(perm), split, new_split, False, mesh),
+                              tuple(perm), split, new_split, False, mesh)
+                             + (("merge",) if glue is not None else ()),
                              build)
             return self._wrap(fn(_check_live(base)), new_split)
 
+        data = self._data
+        glue = _swap_glue(mesh, data, split, perm, new_split)
+
         def build():
+            if glue is not None:
+                return jax.jit(glue(), donate_argnums=(0,))
+
             def swapper(data):
                 return _constrain(jnp.transpose(data, perm), mesh, new_split)
             return jax.jit(swapper, donate_argnums=(0,))
 
         fn = _cached_jit(("swap", self.shape, str(self.dtype), tuple(perm),
-                          split, new_split, True, mesh), build)
-        out = fn(self._data)
+                          split, new_split, True, mesh)
+                         + (("merge",) if glue is not None else ()), build)
+        out = fn(data)
         # only after a successful dispatch: a compile failure must not
         # brick an array whose buffer was never consumed (granted=False:
         # user-explicit donation, not an engine-policy grant)

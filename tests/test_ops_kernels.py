@@ -1455,3 +1455,156 @@ def test_place_program_on_four_chips_is_one_all_to_all_and_in_place(
     assert stats.argument_size_in_bytes < per_chip + slab_bytes
     # the update is aligned: the offset's low bits are known
     assert "index_known_bits" in text
+
+
+# ---------------------------------------------------------------------
+# compile-only: the RESIDENT swap's program on the 2x2 host (ISSUE 45).
+# GSPMD's program for ``stack4d-4chip.swap``'s shapes glues what a chip
+# received in two passes (a ``copy`` into a staging layout, then a
+# lane-merging ``reshape``: two temporaries of 3.77 GB); the explicit
+# exchange and ``swap_merge`` (``parallel/swapmerge.py``) are ONE
+# all-to-all, ONE kernel and bitcasts, one temporary, and the answer in
+# the layout every later program of a caller expects.  Where the pieces
+# are whole lane tiles GSPMD's own program is one pass already, which is
+# why ``swapmerge.takes`` leaves those swaps to it.
+# ---------------------------------------------------------------------
+
+def _host_mesh():
+    """The described 2x2 host as the one-axis mesh the benchmark's cell
+    runs on."""
+    import jax
+    from jax.experimental import topologies
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    return jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("k",))
+
+
+_SWAP_PERM = [1, 0, 2, 3]
+
+
+def _swap_spec(mesh, records):
+    import jax
+    from bolt_tpu.parallel import sharding as sh
+    shape = (records, 200, 64, 64)
+    return jax.ShapeDtypeStruct(shape, _F32,
+                                sharding=sh.key_sharding(mesh, shape, 1))
+
+
+def _swap_on_the_host(records, glued):
+    """``(compiled, plan)`` of ``swap((0,),(0,))`` of ``(records, 200, 64,
+    64)`` float32 over the described 2x2 host: the glue's program, or the
+    transpose under a constraint as ``_do_swap`` builds it."""
+    import jax
+    import jax.numpy as jnp
+    from bolt_tpu.parallel import swapmerge
+    from bolt_tpu.tpu.array import _constrain
+    mesh = _host_mesh()
+    spec = _swap_spec(mesh, records)
+    plan = swapmerge.plan(mesh, spec.shape, _F32, 1, _SWAP_PERM, 1)
+    fn = jax.jit(
+        swapmerge.swapper(plan, mesh, _SWAP_PERM, spec.shape, _F32) if glued
+        else lambda data: _constrain(jnp.transpose(data, _SWAP_PERM), mesh, 1))
+    with jax.enable_x64(False):
+        return fn.lower(spec).compile(), plan
+
+
+def _after_the_exchange(text, nbytes):
+    """The entry computation's operations from the one collective on that
+    write an array of ``nbytes`` or more (a pass over the pieces), by
+    opcode; bitcasts are views."""
+    import re
+    entry = text[text.index("ENTRY"):]
+    found = re.findall(r"= \S+ (all-to-all|all-gather|all-reduce|"
+                       r"collective-permute|reduce-scatter)(?:-start)?\(",
+                       entry)
+    assert found == ["all-to-all"], found
+    passes = []
+    for line in entry[entry.index(" all-to-all("):].splitlines()[1:]:
+        m = re.search(r"= (\w+)\[([\d,]*)\]\S* ([\w-]+)\(", line)
+        if m is None or m.group(3) in ("bitcast", "get-tuple-element"):
+            continue
+        size = np.dtype(np.float32).itemsize * int(np.prod(
+            [int(d) for d in m.group(2).split(",") if d]))
+        if size >= nbytes:
+            passes.append(m.group(3))
+    return passes
+
+
+def _root_is(text, pattern):
+    """Whether the entry computation's ROOT is an array matching
+    ``pattern`` (shape and layout)."""
+    import re
+    entry = text[text.index("ENTRY"):]
+    root = [line for line in entry.splitlines() if "ROOT" in line][0]
+    return re.search(r"ROOT \S+ = " + pattern, root)
+
+
+@pytest.mark.parametrize("records,glued", [(4400, True), (4096, False)],
+                         ids=["1100-a-chip-glued", "1024-a-chip-gspmd"])
+def test_swap_on_four_chips_is_one_all_to_all_and_one_pass(v5e_device,
+                                                           records, glued):
+    from bolt_tpu import engine
+    from bolt_tpu.parallel import swapmerge
+    before = engine.counters()["swap_merge_lowerings"]
+    compiled, plan = _swap_on_the_host(records, glued)
+    assert engine.counters()["swap_merge_lowerings"] == before + glued
+    assert plan.per_chip == records // 4
+    # the rule reads the same: pieces of whole lane tiles are GSPMD's
+    laid = type("Laid", (), {"format": type("F", (), {"layout": type(
+        "L", (), {"major_to_minor": (1, 2, 3, 0)})})})
+    assert swapmerge.takes(plan, _host_mesh(), laid) == glued
+    text = compiled.as_text()
+    piece = records // 4 * 50 * 64 * 64 * 4
+    passes = _after_the_exchange(text, piece)
+    assert passes == (["custom-call"] if glued else ["copy"]), passes
+    if glued:
+        assert "swap_merge" in text and "tpu_custom_call" in text
+    stats = compiled.memory_analysis()
+    # one temporary of a chip's share as laid out, where GSPMD's program
+    # for 1,100 a chip holds two (7.55 GB)
+    assert stats.temp_size_in_bytes < 4.0e9
+    assert stats.output_size_in_bytes == 50 * 64 * 64 * (
+        -(-records // 128) * 128) * 4
+    # the answer's default layout: the old key axis whole on the lanes
+    assert _root_is(text, r"f32\[50,%d,64,64\]\{1,3,2,0:T\(8,128\)\}"
+                    % records)
+
+
+def test_gspmd_glues_the_cells_swap_in_two_passes(v5e_device):
+    # what the glue replaces, so a compiler that learns the one pass is
+    # noticed: then ``swapmerge.takes`` has nothing left to take
+    compiled, _ = _swap_on_the_host(4400, False)
+    passes = _after_the_exchange(compiled.as_text(), 1100 * 50 * 64 * 64 * 4)
+    assert passes == ["copy", "reshape"], passes
+    assert compiled.memory_analysis().temp_size_in_bytes > 7.0e9
+
+
+def test_the_kept_export_of_the_swap_lowers_without_pallas(
+        v5e_device, tmp_path, monkeypatch):
+    # beside an on-disk cache the glue's program is kept exported
+    # (``engine.exported``): the second lowering, a warm process's,
+    # traces nothing of ``swapmerge.program`` and still holds the kernel
+    import jax
+    from bolt_tpu import engine
+    from bolt_tpu.parallel import swapmerge
+    engine.persistent_cache(str(tmp_path / "cache"))
+    try:
+        first, plan = _swap_on_the_host(4400, True)
+        kept = list((tmp_path / "cache" / "bolt_exported").glob("*"))
+        assert [k.name[:11] for k in kept] == ["swap_merge-"]
+        assert _after_the_exchange(first.as_text(), 1100 * 50 * 64 * 64 * 4) \
+            == ["custom-call"]
+        assert first.memory_analysis().temp_size_in_bytes < 4.0e9
+
+        def no_trace(*args):
+            raise AssertionError("the kept export was not used")
+        monkeypatch.setattr(swapmerge, "program", no_trace)
+        mesh = _host_mesh()
+        spec = _swap_spec(mesh, 4400)
+        warm = jax.jit(swapmerge.swapper(plan, mesh, _SWAP_PERM, spec.shape,
+                                         _F32), donate_argnums=(0,))
+        with jax.enable_x64(False):
+            text = warm.lower(spec).as_text()
+        assert "tpu_custom_call" in text and "swap_merge" in text
+    finally:
+        engine.persistent_cache(enable=False)
