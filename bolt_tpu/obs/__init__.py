@@ -42,9 +42,9 @@ from bolt_tpu.obs.export import report, timeline, to_chrome
 from bolt_tpu.obs.metrics import registry, thread_census
 from bolt_tpu.obs.trace import (Span, active_count, begin, cancel, clear,
                                 clock, current, disable, enable, enabled,
-                                end, event, span, spans, totals)
+                                end, event, record, span, spans, totals)
 
 __all__ = ["Span", "active_count", "begin", "cancel", "clear", "clock",
            "current", "disable", "enable", "enabled", "end", "event",
-           "metrics", "registry", "report", "span", "spans",
+           "metrics", "record", "registry", "report", "span", "spans",
            "thread_census", "timeline", "to_chrome", "totals"]

@@ -302,7 +302,6 @@ def _leave(sp):
 
 def end(sp, **attrs):
     """Close a span returned by :func:`begin` (no-op on ``None``)."""
-    global _CLOSED
     if sp is None:
         return
     sp.t1 = clock()
@@ -315,10 +314,16 @@ def end(sp, **attrs):
         if sp.attrs:
             ann.set_metadata(**sp.attrs)
         ann.__exit__(None, None, None)
-    d = sp.t1 - sp.t0
     top = _leave(sp)
     if top is not None and top.sid == sp.pid:
-        top._kids += d
+        top._kids += sp.t1 - sp.t0
+    _land(sp)
+
+
+def _land(sp):
+    """A closed span into the ring, the totals and the leak gate."""
+    global _CLOSED
+    d = sp.t1 - sp.t0
     nbytes = sp.attrs.get("bytes")
     with _LOCK:
         if sp.sid > _BASE:
@@ -332,6 +337,24 @@ def end(sp, **attrs):
         row[2] += d - sp._kids
         if isinstance(nbytes, (int, float)):
             row[3] += int(nbytes)
+
+
+def record(name, t0, t1, **attrs):
+    """Record an interval whose ends the caller already holds as
+    :func:`clock` seconds: one that opens on one thread and closes on
+    another (a job's wait in the serve queue, accepted by the
+    submitter and popped by a worker), which a span bound to a
+    thread's stack cannot be.  A root span on no stack, under the same
+    gate as :func:`begin` (``None`` while nobody is looking); it lands
+    in the ring and the totals, and opens no profiler annotation (those
+    open and close on one thread, now)."""
+    if not (_ON or (_LIVE is not None and _LIVE())):
+        return None
+    th = threading.current_thread()
+    sp = Span(name, attrs, next(_IDS), None, th.ident, th.name)
+    sp.t0, sp.t1 = t0, t1
+    _land(sp)
+    return sp
 
 
 def cancel(sp):

@@ -138,6 +138,9 @@ _SCHEMA = {
     "submitted": 0,            # jobs accepted into the queue
     "rejected": 0,             # jobs refused (queue full / BLT010)
     "completed": 0,            # jobs finished successfully
+    "leased": 0,               # jobs run under an arbiter lease of their
+                               # own estimate (a callable has none, a
+                               # streamed job leases per slab)
     "failed": 0,               # jobs whose pipeline raised
     "queue_wait_seconds": 0.0,  # total submit->start wait
     "run_seconds": 0.0,        # total start->finish execution time
@@ -833,9 +836,18 @@ class Server:
         still queued past it fails with :class:`DeadlineError` instead
         of running, and an expired deadline also stops further
         retries.  Neither affects other tenants' futures."""
+        tenant = str(tenant)
+        sp = _obs.begin("serve.submit", tenant=tenant)
+        try:
+            return self._admit(pipeline, tenant, retries, deadline)
+        finally:
+            _obs.end(sp)
+
+    def _admit(self, pipeline, tenant, retries, deadline):
+        """:meth:`submit`'s body: the drain gate, the estimate and the
+        BLT010 floor, the batch key, the bounded queue."""
         if self._closing:
             raise RuntimeError("serve.Server is closed")
-        tenant = str(tenant)
         if not self._pod_ok.is_set():
             # admission is drained behind a pod peer loss: reject-policy
             # servers refuse pointedly, queue-policy servers apply
@@ -1086,9 +1098,12 @@ class Server:
         self._counters.add("queue_wait_seconds", wait)
         self._tenant_counters(tenant).add("queue_wait_seconds", wait)
         self._h_wait.observe(wait)
+        _obs.record("serve.queue", fut.submitted_s, fut.started_s,
+                    tenant=tenant)
         sp = _obs.begin("serve.run", tenant=tenant,
                         queued_s=round(wait, 6))
         lease = self.arbiter.lease(tenant) if est else None
+        granted = False
         try:
             with _engine.tenant(tenant):
                 if deadline is not None and wait > deadline:
@@ -1101,11 +1116,16 @@ class Server:
                         "started (queued %.3fs)" % (deadline, wait))
                 # stop on CANCEL only: a close(wait=True) drain must
                 # let queued leased jobs wait out the arbiter and run
-                if lease is not None and not lease.acquire(
-                        est, stop=self._cancel):
-                    raise RuntimeError(
-                        "server cancelled before the job's working "
-                        "set (%d bytes) was granted" % est)
+                if lease is not None:
+                    lsp = _obs.begin("serve.lease", tenant=tenant)
+                    try:
+                        granted = lease.acquire(est, stop=self._cancel)
+                    finally:
+                        _obs.end(lsp)
+                    if not granted:
+                        raise RuntimeError(
+                            "server cancelled before the job's working "
+                            "set (%d bytes) was granted" % est)
                 out = self._run_attempts(job, fut, tenant, nretry,
                                          deadline)
             fut._finish(result=out)
@@ -1117,10 +1137,11 @@ class Server:
             if lease is not None:
                 lease.close()           # leases are ALWAYS returned
             _obs.end(sp)
-        run_s = fut.finished_s - fut.started_s
-        self._counters.update(**{key: 1, "run_seconds": run_s})
-        self._tenant_counters(tenant).update(
-            **{key: 1, "run_seconds": run_s})
+        deltas = {key: 1, "run_seconds": fut.finished_s - fut.started_s}
+        if granted:
+            deltas["leased"] = 1
+        self._counters.update(**deltas)
+        self._tenant_counters(tenant).update(**deltas)
 
     # -- continuous micro-batching (bolt_tpu/tpu/batched.py) -----------
 
@@ -1237,6 +1258,8 @@ class Server:
                 wait = t_start - fut.submitted_s
                 _acc(t, queue_wait_seconds=wait)
                 self._h_wait.observe(wait)
+                _obs.record("serve.queue", fut.submitted_s, t_start,
+                            tenant=t)
                 if dl is not None and wait > dl:
                     _acc(t, expired=1)
                     self._finish_batched(t, fut, None, DeadlineError(
@@ -1257,7 +1280,16 @@ class Server:
                     it[2] or 0 for _, it in live)
             if live and total_est:
                 lease = self.arbiter.lease(live[0][0])
-                if not lease.acquire(total_est, stop=self._cancel):
+                lsp = _obs.begin("serve.lease", tenant=live[0][0])
+                try:
+                    granted = lease.acquire(total_est, stop=self._cancel)
+                finally:
+                    _obs.end(lsp)
+                if granted:
+                    for t, it in live:
+                        if it[2]:
+                            _acc(t, leased=1)
+                else:
                     lease.close()
                     lease = None
                     for t, it in live:

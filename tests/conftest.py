@@ -37,7 +37,8 @@ import pytest  # noqa: E402
 # below — one observed rank inversion, self-deadlock or
 # dispatch-under-lock anywhere in them fails the test that did it
 _LOCKDEP_SUITES = frozenset({
-    "test_serve", "test_serve_batching", "test_stream",
+    "test_serve", "test_serve_batching", "test_serve_streams",
+    "test_stream",
     "test_supervisor", "test_multistat", "test_parity_locks",
     "test_podwatch",
 })

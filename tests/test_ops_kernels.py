@@ -1161,6 +1161,59 @@ def test_the_lowering_counts_programs_with_the_fold_kernel(v5e_device):
 
 
 # ---------------------------------------------------------------------
+# compile-only: the ten programs of the throughput test's five query
+# streams (ISSUE 46; ``benchmark/traffic/streams5.json``).  A parameter
+# set is closed over as Python ints and is its own program: each is the
+# ONE ``thin_fold`` call over the table as it is held that the
+# validation set's is, with a few accumulators beside it
+# ---------------------------------------------------------------------
+
+def _stream_positions(kind):
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "traffic",
+        "streams5.json")
+    with open(path) as fh:
+        kinds = {k["kind"]: k for k in json.load(fh)["requests"]}
+    return kinds[kind]["positions"]
+
+
+def _stream_fold(kind, position):
+    from bolt_tpu.tpu.fold import Fold
+    if kind == "q1":
+        day = int(position["shipdate_to"])
+        return Fold(_filter_of((), lambda r: r[0] <= day, _LINEITEM),
+                    group=("sum", _q1_group, _q1_terms, 6))
+    (d0, d1), (c0, c1) = position["shipdate"], position["discount"]
+    q = int(position["quantity_below"])
+
+    def pred(r):
+        return ((r[0] >= d0) & (r[0] < d1) & (r[3] >= c0) & (r[3] <= c1)
+                & (r[1] < q))
+    return Fold(_filter_of((), pred, _LINEITEM, (_q6_value,), ()),
+                (("sum", (0,), False, None),))
+
+
+@pytest.mark.parametrize("stream", range(5))
+@pytest.mark.parametrize("kind", ["q6", "q1"])
+def test_a_stream_s_own_parameters_are_one_thin_fold_on_v5e(v5e_device,
+                                                            kind, stream):
+    import jax
+    positions = _stream_positions(kind)
+    assert len(positions) == 5
+    compiled = _compile_fold(_stream_fold(kind, positions[stream]),
+                             _LINEITEM,
+                             jax.sharding.SingleDeviceSharding(v5e_device))
+    text = compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert len(_kernel_calls(text, "thin_fold")) == 1
+    assert text.count("tpu_custom_call") == 1
+    assert mem.argument_size_in_bytes == -(-300018951 // 128) * 128 * 8 * 4
+    assert mem.temp_size_in_bytes < 16e6
+
+
+# ---------------------------------------------------------------------
 # compile-only: the streamed swap's place program at the two-photon
 # session's size (ISSUE 32).  The swapped array is the program's own
 # argument handed back: aliased, with a temp of one transposed slab —
