@@ -59,11 +59,15 @@ def _func_label(func):
     return "map(%s)" % _name(func)
 
 
-def _percentile_note(func, split, aval):
+def _percentile_note(func, split, aval, blocked=None):
     """How an ``ops.normalize(baseline="percentile")`` stage takes its
     baseline over records of ``aval``: from the SAME function the
     lowering asks (``ops/select.py :: regime``), so the forecast and the
-    program cannot disagree.  Empty for any other stage."""
+    program cannot disagree.  ``blocked``: ``(records, block)`` where
+    the stage is the first map of a run lowered over blocks, whose
+    selection may read its block where the array lies: asked of the
+    trace of the lowering's own loop (``tpu/array.py :: _blocked_run``).
+    Empty for any other stage."""
     ax = getattr(func, "percentile_axis", None)
     if ax is None:
         return ""
@@ -73,15 +77,48 @@ def _percentile_note(func, split, aval):
     dtype = jax.numpy.promote_types(aval.dtype, np.float32)
     how = select.regime(length, dtype)
     if how == "kernel":
-        return ("percentile by selection, one read of a block: two exact "
+        found = []
+        if blocked is not None:
+            from bolt_tpu.tpu.array import _blocked_run
+            jax.eval_shape(
+                lambda x: _blocked_run((func,), 1, x, blocked[1],
+                                       found=found),
+                jax.ShapeDtypeStruct((blocked[0],) + aval.shape[split:],
+                                     aval.dtype))
+        return ("percentile by selection, one read of a block%s: two exact "
                 "order statistics of %d values found bit by bit on a tile "
                 "held in VMEM (a program for one TPU device; counting "
-                "passes over the block elsewhere), no sort" % length)
+                "passes over the block elsewhere), no sort"
+                % (" in place" if found else "", length))
     if how == "select":
         return ("percentile by selection: two exact order statistics of "
                 "%d values found bit by bit, no sort" % length)
     return "percentile by sort: %d values a record is under the %d " \
         "from which it is selected" % (length, select.select_from(dtype))
+
+
+def _block_heads(arr, base, funcs):
+    """``{i: (records, block)}``: the ``funcs[i]`` that are the first map
+    of a run which the rule of ``tpu/blocks.py`` lowers over blocks (the
+    one map of such a run whose operand is a block of the array
+    itself)."""
+    from bolt_tpu.tpu.array import _Window, _chain_runs
+    try:
+        marked = arr._block_plan(base, funcs)
+    except Exception:       # a record too large (BLT019: _note_blocks)
+        return {}           # or a later stage that does not trace (BLT001)
+    if marked is funcs:
+        return {}
+    heads, at, plan = {}, 0, iter(marked[-1].runs)
+    for part in _chain_runs(funcs):
+        if type(part) is _Window:
+            at += 1
+            continue
+        run = next(plan)
+        if run:
+            heads[at] = run
+        at += len(part)
+    return heads
 
 
 def _shared_note(shared):
@@ -821,6 +858,17 @@ def _check_impl(obj):
     idle_seen = _idle_device_check(mesh, aval.shape, walk_split, 0,
                                    diags, idle_seen)
 
+    # the chain whose program runs over the base, the array it is planned
+    # as and where its notes go: this one or, where a shared parent has
+    # still to run, the parent's (this chain's own reads its result)
+    planned = None
+    if not shared:
+        planned = (arr, funcs, len(funcs))
+    elif shared[1].kept is None:
+        at, node, _ = shared
+        planned = (arr._parent_of(at), funcs[:node.nfuncs], node.nfuncs)
+    heads = _block_heads(planned[0], base, planned[1]) if planned else {}
+
     # ---- the deferred map chain, one abstract stage per func --------
     failed = False
     for i, func in enumerate(funcs):
@@ -842,7 +890,7 @@ def _check_impl(obj):
                 hint="keep constants in the input dtype or cast back "
                      "with astype/map(dtype=...) if the widening is "
                      "unintended"))
-        note = _percentile_note(func, walk_split, aval)
+        note = _percentile_note(func, walk_split, aval, heads.get(i))
         if shared and i + 1 == shared[1].nfuncs:
             note = "; ".join(filter(None, (note, _shared_note(shared))))
         aval = nxt
@@ -910,14 +958,8 @@ def _check_impl(obj):
                                 _spec(mesh, aval.shape, 1), dynamic=True))
 
     if not failed:
-        if not shared:
-            _note_blocks(arr, base, funcs, len(funcs), diags)
-        elif shared[1].kept is None:
-            # the parent's chain is the program over the base; this
-            # chain's own reads the parent's result
-            at, node, _ = shared
-            _note_blocks(arr._parent_of(at), base, funcs[:node.nfuncs],
-                         node.nfuncs, diags)
+        if planned:
+            _note_blocks(planned[0], base, planned[1], planned[2], diags)
         _note_admission(
             int(base.nbytes)
             + prod(tuple(stages[-1].shape))

@@ -223,6 +223,9 @@ _SCHEMA = {
     # program for one TPU device); every other selection lowers to the
     # counting passes and counts nothing here
     "percentile_kernel_lowerings": 0,
+    # those of them whose kernel reads its block of records where the
+    # base lies, by an offset, and not from a slice written out for it
+    "percentile_based_lowerings": 0,
     # resident swaps across chips LOWERED as an explicit exchange and the
     # one-pass glue (bolt_tpu/parallel/swapmerge.py: a TPU mesh, the key
     # axis on the lanes in pieces that are no whole lane tiles); every
@@ -829,7 +832,8 @@ def record_percentile_lowering(regime):
     """A record function was traced with a percentile taken by
     ``regime`` (``"select"`` or ``"sort"``: ``ops/select.py ::
     percentile``), or a selection was lowered as the Mosaic kernel
-    (``"kernel"``: the ``percentile_select`` primitive's TPU rule)."""
+    (``"kernel"``: the ``percentile_select`` primitive's TPU rule; and
+    ``"based"`` where that kernel reads its block from the base)."""
     _COUNTERS.add("percentile_%s_lowerings" % regime)
 
 

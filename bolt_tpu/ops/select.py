@@ -55,11 +55,27 @@ step of one sublane); the kernel's lowering flattens the batch, a
 bitcast, and the fallback's maps ``_select`` over it again, so that off
 the TPU the program's text is what it was before the kernel came.
 
+The kernel's batch arrives by ONE OF TWO index maps (PR 47).  A custom
+call's operand is a buffer: where the batch is a block of a resident
+array (``tpu/array.py :: _blocked_run``'s ``dynamic_slice_in_dim``), XLA
+wrote the block out in front of every call, a read and a write of it to
+put the same bytes further along in the same memory.  So the blocked run
+says where its rows lie (:func:`block_of`), and a selection bound on
+those very rows (through nothing but a same-dtype ``astype``, which
+leaves no operation) takes the base and the offset as two more operands.
+The kernel's lowering then windows the BASE by an offset in rows, scalar-
+prefetched, where a slice is windowed by tile number; the body is the
+same.  The fallback reads the rows as ever and not the two operands, so
+its program is unchanged to the letter; a selection of anything computed
+first has an operand XLA writes anyway and keeps one.
+
 The constants below were read on the chip with
 ``scripts/select_probe.py``; PERF.md (section 6, PR 37 and PR 40) has the
 tables.
 """
 
+import threading
+from contextlib import contextmanager
 from functools import partial, reduce
 
 import numpy as np
@@ -402,59 +418,97 @@ def _tile(records, length):
     return max(group, min(fit, records) // group * group), group
 
 
-def _kernel_keys(x, low, high, bits=_KERNEL_BITS):
-    """``(low key, high key, NaN verdict)`` of every record of the flat
-    float32 batch ``x (records, length)``, each ``(records, 1)`` (the
-    keys uint32 as :func:`_keys` has them), by :func:`_select_kernel`."""
+def _kernel_keys(x, low, high, bits=_KERNEL_BITS, start=None, block=None):
+    """``(low key, high key, NaN verdict)`` of every record of a flat
+    float32 batch, each ``(records, 1)`` (the keys uint32 as
+    :func:`_keys` has them), by :func:`_select_kernel`.  The batch is
+    ``x (records, length)`` itself or, with ``start`` (an int32 scalar),
+    the ``block`` rows of the base ``x`` from there, both whole vregs of
+    sublanes (multiples of 8): the kernel's tiles are then read where the
+    array lies, and XLA writes no slice out for it."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-    records, length = x.shape
-    if records < 8:
+    length = x.shape[1]
+    records = x.shape[0] if start is None else block
+    held = max(records, 8)              # rows of the kernel's result
+    if start is None and records < 8:
         # one vreg's sublanes at the least; a batch this small is a copy
         # of a few records
         x = jnp.pad(x, ((0, 8 - records), (0, 0)))
-    tile, group = _tile(x.shape[0], length)
+    tile, group = _tile(held, length)
+    steps = pl.cdiv(held, tile)
+    if start is None:
+        # tile ``g`` of the batch by its number; the rows of the last
+        # tile past the batch are skipped (``held``)
+        operands = (x,)
+        reads = pl.BlockSpec((tile, length), lambda g: (g, 0))
+    else:
+        # tile ``g`` from row ``start + g * tile`` of the base: an offset
+        # in rows, since a block starts anywhere and not at a tile's
+        # number.  Where the block does not divide, its last tile starts
+        # early and hands some rows back a second time: no tile reads
+        # past the block, whose last ends where the array does
+        held, early = steps * tile, records - tile
+        operands = (start.astype(jnp.int32).reshape(1), x)
+        reads = pl.BlockSpec(
+            (pl.Element(tile), pl.Element(length)),
+            lambda g, at: (pl.multiple_of(
+                at[0] + jnp.minimum(g * tile, early), 8), 0))
     # Mosaic has no 64-bit types: whatever the session's x64 says, the
     # kernel's side traces int32 and float32
     with jax.enable_x64(False):
         out = pl.pallas_call(
-            partial(_select_kernel, records=x.shape[0], low=low, high=high,
-                    bits=bits),
-            out_shape=jax.ShapeDtypeStruct((x.shape[0], _LANES), jnp.int32),
-            grid=(pl.cdiv(x.shape[0], tile),),
-            in_specs=[pl.BlockSpec((tile, length), lambda g: (g, 0))],
-            out_specs=pl.BlockSpec((tile, _LANES), lambda g: (g, 0)),
-            scratch_shapes=[pltpu.VMEM((group, length), jnp.int32)],
+            # the body sees the tile and not where it came from
+            lambda *refs: _select_kernel(*refs[-3:], records=held, low=low,
+                                         high=high, bits=bits),
+            out_shape=jax.ShapeDtypeStruct((held, _LANES), jnp.int32),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(operands) - 1, grid=(steps,),
+                in_specs=[reads],
+                out_specs=pl.BlockSpec((tile, _LANES), lambda g, *_: (g, 0)),
+                scratch_shapes=[pltpu.VMEM((group, length), jnp.int32)]),
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel",)),
             name="percentile_select",
-        )(x)
+        )(*operands)
+    if start is not None:
+        out = jnp.concatenate([out[:early], out[(steps - 1) * tile:]])
     out = lax.bitcast_convert_type(out[:records, :3], jnp.uint32)
     return out[:, 0:1], out[:, 1:2], out[:, 2:3] != 0
 
 
-def _select_flat(x, perc, bits=_KERNEL_BITS):
+def _select_flat(x, perc, bits=_KERNEL_BITS, start=None, block=None):
     """``_select(x, perc, 1, True)`` of a flat float32 batch ``(records,
-    length)`` by the kernel."""
+    length)`` by the kernel; with ``start``, of the ``block`` rows of
+    ``x`` from there (:func:`_kernel_keys`)."""
     low, high, low_weight, high_weight = _ranks(x.shape[1], perc)
     with jax.named_scope("percentile_select"):
-        low_key, high_key, nan = _kernel_keys(x, low, high, bits)
+        low_key, high_key, nan = _kernel_keys(x, low, high, bits, start,
+                                              block)
     return _blend(low_key, high_key, nan, low_weight, high_weight, x.dtype)
 
 
-def _by_passes(a, perc, axis, keepdims, lead):
+def _by_passes(a, *where, perc, axis, keepdims, lead):
     """The primitive's fallback: :func:`_select` mapped over the ``lead``
     batch axes of ``a``, which is what the nested ``vmap`` traced before
-    the primitive stood in its way."""
+    the primitive stood in its way.  ``where`` (the base and the offset
+    ``a`` was sliced at) is the kernel's to use: the passes read the
+    slice, and their program is what it was without it."""
     fn = partial(_select, perc=perc, axis=axis, keepdims=keepdims)
     for _ in range(lead):
         fn = jax.vmap(fn)
     return fn(a)
 
 
-def _by_kernel(a, perc, axis, keepdims, lead):
+def _by_kernel(a, *where, perc, axis, keepdims, lead):
     """The same by the kernel: the batch flattened to ``(records,
-    length)``, a bitcast where ``axis`` is the record's last (a series)."""
+    length)``, a bitcast where ``axis`` is the record's last (a series).
+    With ``where``, a base and an offset (:func:`_lies_in`), the
+    batch is read from the base and ``a``, its slice, is not read."""
+    if where:
+        base, start = where
+        out = _select_flat(base, perc, start=start, block=a.shape[0])
+        return out if keepdims else jnp.squeeze(out, 1)
     at = lead + axis
     rows = jnp.moveaxis(a, at, -1)
     out = _select_flat(rows.reshape(-1, a.shape[at]), perc)
@@ -471,6 +525,42 @@ def _takes_kernel(ctx, tpu):
     return tpu and _mosaic_fits(ctx)
 
 
+_BLOCK = threading.local()
+
+
+@contextmanager
+def block_of(rows, base, start, step, found=None):
+    """While the function of a block of records is traced (``tpu/array.py
+    :: _blocked_run``'s ``vmap`` over ``rows``, which is
+    ``dynamic_slice_in_dim(base, start, len(rows))``; ``step`` divides
+    every ``start`` and the block): a selection whose operand IS ``rows``
+    (the very tracer: nothing computed from it, which XLA would have to
+    write anyway) is bound with the base and the offset beside it, where
+    the kernel can read them in place.  ``found``: a list that takes an
+    entry for every such selection."""
+    kept = getattr(_BLOCK, "at", None)
+    _BLOCK.at = (rows, base, start, step, [] if found is None else found)
+    try:
+        yield
+    finally:
+        _BLOCK.at = kept
+
+
+def _lies_in(a, lead):
+    """``(base, start)`` where the batch ``a`` is the block
+    :func:`block_of` names and the kernel can read it from the base:
+    a flat batch of series (the base is then ``(rows, length)`` as it
+    lies, no reshape of it a copy) whose blocks start and end on whole
+    vregs of 8 sublanes, which is what Mosaic asks of a window's offset
+    into a tiled array.  Else ``()``."""
+    rows, base, start, step, found = getattr(_BLOCK, "at", None) \
+        or (None,) * 5
+    if a is not rows or lead != 1 or a.ndim != 2 or step % 8:
+        return ()
+    found.append(a.shape)
+    return base, start
+
+
 def _select_primitive():
     """``percentile_select``: :func:`_select` of every record of a batch,
     as a primitive because its executor is chosen when a program is
@@ -485,7 +575,13 @@ def _select_primitive():
     deep the nesting.  The kernel's lowering flattens the batch to
     ``(records, length)``; the fallback maps :func:`_select` over the
     leading axes again (a reshape of key axes that GSPMD shards is not a
-    bitcast, so the bind itself folds nothing)."""
+    bitcast, so the bind itself folds nothing).
+
+    Two more operands say where the batch lies (the ``vmap`` rule binds
+    them, inside :func:`block_of`): the base it is
+    ``dynamic_slice_in_dim(base, start, records)`` of, and ``start``.
+    The answer is the same; the kernel's lowering reads the base and
+    leaves the slice to whoever else wants it."""
     from jax._src import dispatch       # eager calls: jax's own cache
     from jax.extend.core import Primitive
     from jax.interpreters import batching, mlir
@@ -493,19 +589,21 @@ def _select_primitive():
     prim.def_impl(partial(dispatch.apply_primitive, prim))
 
     @prim.def_abstract_eval
-    def _(a, *, perc, axis, keepdims, lead):
+    def _(a, *where, perc, axis, keepdims, lead):
         at = lead + axis
         return a.update(shape=a.shape[:at] + (1,) * keepdims
                         + a.shape[at + 1:])
 
     def lower(tpu):
-        def rule(ctx, a, **params):
+        def rule(ctx, a, *where, **params):
             fn = _by_passes
             if _takes_kernel(ctx, tpu):
                 _engine.record_percentile_lowering("kernel")
+                if where:
+                    _engine.record_percentile_lowering("based")
                 fn = _by_kernel
             return mlir.lower_fun(partial(fn, **params),
-                                  multiple_results=False)(ctx, a)
+                                  multiple_results=False)(ctx, a, *where)
         return rule
 
     mlir.register_lowering(prim, lower(False))
@@ -513,7 +611,8 @@ def _select_primitive():
 
     def fold(args, dims, *, lead, **params):
         a = jnp.moveaxis(args[0], dims[0], 0)
-        return prim.bind(a, lead=lead + 1, **params), 0
+        return prim.bind(a, *_lies_in(a, lead + 1), lead=lead + 1,
+                         **params), 0
 
     batching.primitive_batchers[prim] = fold
     # what the blocks rule counts a record's selection as holding

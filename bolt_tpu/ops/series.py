@@ -25,8 +25,10 @@ What that costs on the device depends on what a record's function keeps:
   exact) from a length on; a shorter record is sorted by
   ``jnp.percentile``, to the same answer to the bit.  In a program for
   one TPU device the passes run on a tile of records held in VMEM, one
-  Mosaic kernel that reads a block ONCE (PR 40); everywhere else they are
-  a loop of ``jax.numpy`` passes, nineteen reads of a block.  That loop,
+  Mosaic kernel that reads a block ONCE (PR 40), and where it lies in the
+  array when ``normalize`` is the first map of a blocked chain (PR 47);
+  everywhere else they are a loop of ``jax.numpy`` passes, nineteen
+  reads of a block.  That loop,
   the kernel and a sort (and a caller's own FFT or scan in a ``map``)
   keep record-sized temporaries that XLA does not fuse away.  Over a
   small array that changes nothing; over an array too large to hold them
@@ -35,7 +37,8 @@ What that costs on the device depends on what a record's function keeps:
   records, chosen by a rule and not by the caller
   (``bolt_tpu/tpu/blocks.py``; ``analysis.explain`` says "blocked: n
   blocks of r records", and of a ``normalize`` stage "percentile by
-  selection, one read of a block", "by selection" or "by sort").  Still
+  selection, one read of a block" ("in place" where the block is read
+  from the array), "by selection" or "by sort").  Still
   ONE program an action, but not one pass over HBM (PERF.md, PRs 36, 37
   and 40).
 * ``fourier`` returns two deferred arrays over one deferred parent.
@@ -377,7 +380,9 @@ def normalize(b, baseline="percentile", perc=20.0, axis=0, epsilon=0.0):
     value to the bit either way (engine counters
     ``percentile_select_lowerings`` / ``percentile_sort_lowerings``, and
     ``percentile_kernel_lowerings`` where a selection was lowered as the
-    kernel that reads a block once).
+    kernel that reads a block once, ``percentile_based_lowerings`` where
+    that kernel reads the block in the array it is a block of: the first
+    map of a chain lowered over blocks).
     """
     if baseline not in ("percentile", "mean"):
         raise ValueError(

@@ -41,6 +41,7 @@ import threading
 import warnings
 from collections import OrderedDict
 from functools import lru_cache, partial
+from math import gcd
 from typing import NamedTuple
 
 import numpy as np
@@ -693,7 +694,7 @@ def _sharded_blocked_run(run, split, x, block, mesh):
                       check_vma=False)(x)
 
 
-def _blocked_run(run, split, x, block, offsets=None):
+def _blocked_run(run, split, x, block, offsets=None, found=None):
     """The maps of ``run`` over ``x`` in blocks of ``block`` whole
     records: a loop inside the program, whose temporaries are a block's
     and not the array's.  Each record's value is what the nested ``vmap``
@@ -701,7 +702,11 @@ def _blocked_run(run, split, x, block, offsets=None):
     the count does not divide, the last block starts early and rewrites a
     few records with the values they already have.  ``offsets``: what to
     add to each key index a ``with_keys`` map is handed (a shard's place
-    in the whole array)."""
+    in the whole array).  ``found``: a list that takes an entry for every
+    percentile the run selects on a block's rows themselves, which a
+    program for one TPU device reads where the array lies
+    (``ops/select.py :: block_of``; ``analysis.explain`` asks)."""
+    from bolt_tpu.ops.select import block_of
     kshape = x.shape[:split]
     n = prod(kshape)
     block = min(block, n)
@@ -711,13 +716,16 @@ def _blocked_run(run, split, x, block, offsets=None):
 
     def piece(start):
         rows = jax.lax.dynamic_slice_in_dim(flat, start, block, axis=0)
-        if not keyed:
-            return jax.vmap(one)(rows)
-        keys = jnp.unravel_index(
-            start + jnp.arange(block, dtype=jnp.int32), kshape)
-        if offsets is not None:
-            keys = tuple(k + o for k, o in zip(keys, offsets))
-        return jax.vmap(one)(rows, *keys)
+        # every block starts at a multiple of the block or ends with the
+        # array
+        with block_of(rows, flat, start, gcd(block, n), found):
+            if not keyed:
+                return jax.vmap(one)(rows)
+            keys = jnp.unravel_index(
+                start + jnp.arange(block, dtype=jnp.int32), kshape)
+            if offsets is not None:
+                keys = tuple(k + o for k, o in zip(keys, offsets))
+            return jax.vmap(one)(rows, *keys)
 
     aval = jax.eval_shape(piece, jax.ShapeDtypeStruct((), jnp.int32))
 

@@ -14,10 +14,11 @@ for 40.00 G (PERF.md, PR 36; since PR 37 the percentile of a
 record that long is selected, and held the record's image of keys where
 the sort held its copy; since PR 40 the selection is the primitive
 ``percentile_select``, whose Mosaic kernel holds a tile's image in VMEM
-and needs its block of records written out as a buffer, and whose
-fallback is the loop of passes with the image as before: the record's
-jaxpr shows neither, only the primitive and its 4 bytes a record, so
-the rule walks the fallback the primitive names, as a call of it).
+(since PR 47 it reads the block from the array where the selection is a
+run's first map), and whose fallback is the loop of passes with the
+image as before: the record's jaxpr shows neither, only the primitive
+and its 4 bytes a record, so the rule walks the fallback the primitive
+names, as a call of it).
 
 The rule, static and with no knob.  The runs of maps of a chain (between
 its getitem windows) are traced ONCE, on ONE record's aval, to a jaxpr:

@@ -1290,6 +1290,24 @@ def _tuning_chain():
             series._pick_fn(0, 0))
 
 
+def _computations(text):
+    """The computations of an optimised HLO module by name (the entry as
+    ``"ENTRY"``): the lines of each, its ``ROOT`` wherever it stands
+    moved to the end."""
+    out, name = {}, None
+    for ln in text.splitlines():
+        head = re.match(r"(ENTRY )?(%[\w.\-]+) \(.*\{$", ln)
+        if head:
+            name = "ENTRY" if head.group(1) else head.group(2)
+            out[name] = []
+        elif ln == "}":
+            out[name].sort(key=lambda ln: "ROOT " in ln)
+            name = None
+        elif name:
+            out[name].append(ln)
+    return out
+
+
 # one of fourier's handles alone takes the whole chain and its pick; the
 # pair takes the chain up to fourier's (512, 512, 2) map ONCE, as the
 # shared parent's program (BoltArrayTPU._lower_from_shared, PR 39)
@@ -1333,11 +1351,38 @@ def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
             text)
         assert len(calls) == 1 and "while/body" in calls[0]
         assert calls[0].endswith("percentile_select/pallas_call")
+        # and that call reads its block where the array lies (PR 47): its
+        # operands are the block's offset and the loop's carry of the
+        # entry parameter, which nothing in the program copies, and no
+        # instruction of the loop's body writes a block out by slicing
+        # (every other reader has the slice fused into it)
+        comps = _computations(text)
+        body = comps[re.search(r" while\(.*?body=(%[\w.\-]+)", text).group(1)]
+        flat = r"f32\[%d,%d\]" % (512 * 512, _PIXELS[2])
+        call, = [ln for ln in body if "tpu_custom_call" in ln]
+        made = {ln.split(" = ")[0].replace("ROOT ", "").strip(): ln
+                for ln in body if " = " in ln}
+        read = [made[name] for name in re.search(
+            r"custom-call\(([^)]*)\)", call).group(1).split(", ")]
+        assert len(read) == 2 and " = s32[1]" in read[0], read
+        assert re.search(r" = %s\S* get-tuple-element\(" % flat, read[1]), read
+        whole = r" = f32\[(%d,%d|512,512,%d)\]" % (512 * 512, _PIXELS[2],
+                                                  _PIXELS[2])
+        for ln in comps["ENTRY"]:
+            if re.search(whole, ln):
+                assert re.search(r" (parameter|bitcast)\(", ln), ln
+        for ln in body:
+            if not re.search(r" = f32\[%d,%d\]" % (block, _PIXELS[2]), ln):
+                continue
+            if " fusion(" in ln:
+                ln = comps[re.search(r"calls=(%[\w.\-]+)", ln).group(1)][-1]
+                assert "ROOT " in ln
+            assert not re.search(r" (dynamic-slice|copy)\(", ln), ln
         # fourier's bin and energy are sums over the series (PR 44): no
-        # transform in the program, and the one temporary is the block
-        # written out for the selection's kernel, a quarter of what the
-        # program held with the FFT in it (9831aad: 879,325,696 and
-        # 877,326,848 bytes)
+        # transform in the program, and the one temporary is the
+        # residual (normalised and detrended, which fourier reads twice),
+        # a quarter of what the program held with the FFT in it (9831aad:
+        # 879,325,696 and 877,326,848 bytes)
         assert not re.search(r"\bfft\b", text)
         assert mem.temp_size_in_bytes < 1.02 * 4 * block * _PIXELS[2]
         with pytest.raises(Exception, match="Exceeded hbm capacity|hbm"):
@@ -1389,6 +1434,7 @@ def test_a_bare_fourier_compiles_whole_and_without_a_transform_on_v5e(
 # length that is not whole lane-groups, and a program GSPMD partitions
 @pytest.mark.parametrize("name,shape,dtype,x64,kernel", [
     ("the-cell's-block", (5352, 10240), "float32", False, True),
+    ("the-cell's-block-in-the-base", (5352, 10240), "float32", False, True),
     ("float32-under-x64", (64, 2048), "float32", True, True),
     ("float64-under-x64", (64, 2048), "float64", True, False),
     ("too-short-to-hide-a-pass", (64, 512), "float32", False, False),
@@ -1399,18 +1445,30 @@ def test_the_selections_executor_is_chosen_at_lowering_on_v5e(
         v5e_device, name, shape, dtype, x64, kernel):
     import jax
     from bolt_tpu import engine
-    from bolt_tpu.ops import series
-    fn = series._normalize_fn("percentile", 20.0, 0, 0.0)
+    from bolt_tpu.ops import select, series
+    fn = jax.vmap(series._normalize_fn("percentile", 20.0, 0, 0.0))
     where = jax.sharding.SingleDeviceSharding(v5e_device)
-    count = lambda: engine.counters()["percentile_kernel_lowerings"]
+    based = name.endswith("in-the-base")
+    if based:
+        # the block as tpu/array.py :: _blocked_run hands it over: rows
+        # of the cell's whole array from an offset the program is given
+        rows, shape = fn, (512 * 512, shape[1])
+
+        def fn(base, start):
+            part = jax.lax.dynamic_slice_in_dim(base, start, 5352)
+            with select.block_of(part, base, start, 8):
+                return rows(part)
+    count = lambda: tuple(engine.counters()["percentile_%s_lowerings" % k]
+                          for k in ("kernel", "based"))
     before = count()
     with jax.enable_x64(x64):
-        arg = jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=where)
-        lowered = jax.jit(jax.vmap(fn)).lower(arg)
+        args = (jax.ShapeDtypeStruct(shape, np.dtype(dtype), sharding=where),
+                jax.ShapeDtypeStruct((), np.int32, sharding=where))
+        lowered = jax.jit(fn).lower(*args[:1 + based])
         text = lowered.as_text()
         if dtype != "float64":          # XLA's TPU compiler has no u64
             lowered.compile()           # keys; the lowering is the point
-    assert count() == before + kernel, name
+    assert count() == (before[0] + kernel, before[1] + based), name
     assert ("tpu_custom_call" in text) == kernel
     # the passes are a ``while``; the kernel leaves none
     assert ("stablehlo.while" in text) == (not kernel), name
@@ -1434,6 +1492,37 @@ def test_the_selection_keeps_its_passes_in_a_program_for_four_chips(
             (64, 2048), _F32, sharding=where)).compile().as_text()
     assert engine.counters()["percentile_kernel_lowerings"] == before
     assert "tpu_custom_call" not in text and " while(" in text
+
+
+# under a fully manual ``shard_map`` over those four chips each shard is
+# one device's: the kernel, and inside the blocked lowering's loop it
+# reads a block from the SHARD by its offset there (PR 47)
+def test_the_selection_reads_a_shards_block_in_place_on_four_chips(
+        v5e_device):
+    import jax
+    from jax.experimental import topologies
+    from bolt_tpu import engine
+    from bolt_tpu.ops import series
+    from bolt_tpu.tpu.array import _sharded_blocked_run
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    mesh = jax.sharding.Mesh(np.asarray(topo.devices), ("k",))
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec("k"))
+    run = (series._normalize_fn("percentile", 20.0, 0, 0.0),
+           series._detrend_fn(2048, 3, 0))
+    count = lambda: tuple(engine.counters()["percentile_%s_lowerings" % k]
+                          for k in ("kernel", "based"))
+    before = count()
+    with jax.enable_x64(False):
+        # 512 records a shard in blocks of 200: the last starts at 312
+        text = jax.jit(lambda d: _sharded_blocked_run(
+            run, 2, d, 200, mesh)).lower(jax.ShapeDtypeStruct(
+                (64, 32, 2048), _F32, sharding=where)).compile().as_text()
+    assert count() == (before[0] + 1, before[1] + 1)
+    comps = _computations(text)
+    body = comps[re.search(r" while\(.*?body=(%[\w.\-]+)", text).group(1)]
+    call, = [ln for ln in body if "tpu_custom_call" in ln]
+    assert "f32[512,2048]" in call and "f32[200,2048]" not in call, call
 
 
 def _blocked_estimate(funcs, block):

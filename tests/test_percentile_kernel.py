@@ -26,9 +26,10 @@ from jax.experimental.pallas import tpu as pltpu
 import bolt_tpu as bolt
 from bolt_tpu import analysis, engine, ops
 from bolt_tpu.ops import select
-from bolt_tpu.tpu import array as tpu_array, blocks
+from bolt_tpu.tpu import array as tpu_array
 
-from test_percentile_select import KINDS, rows, same_bits
+from test_percentile_select import (BASE, KINDS, LONG, _base, _binds,
+                                    _of_a_block, rows, same_bits)
 from test_series_tuning import sessions, tuning
 
 PERCS = [0.0, 20.0, 50.0, 99.9, 100.0]  # 0, 100 and 50 at an odd length:
@@ -229,18 +230,6 @@ def test_any_axis_of_a_record_through_the_kernel(kernel_everywhere, axis,
     assert same_bits(got, want)
 
 
-def _binds(jaxpr):
-    """Every ``percentile_select`` equation of ``jaxpr``, those inside the
-    loops and calls it holds among them."""
-    out = []
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "percentile_select":
-            out.append(eqn)
-        for inner in blocks._sub_jaxprs(eqn):
-            out += _binds(inner)
-    return out
-
-
 @pytest.mark.parametrize("keepdims", [False, True], ids=["drop", "keep"])
 @pytest.mark.parametrize("depth,shape", [(0, (N,)), (1, (13, N)),
                                          (2, (5, 3, N)), (2, (4, 8, N + 256))],
@@ -337,6 +326,89 @@ def test_the_tuning_chain_blocked_by_the_rule_takes_the_kernel(
     # the percentile is the same to the bit; the thin products and the
     # transform after it round by the block's shape on the CPU
     assert np.max(np.abs(got - want)) < 1e-5
+
+
+# ---------------------------------------------------------------------
+# (d') the block read where the array lies (PR 47): the kernel's second
+# index map, an offset in rows scalar-prefetched into an element-indexed
+# window of the BASE, against the sort and against the kernel over the
+# slice; the last tile of a block that does not divide starts early
+# ---------------------------------------------------------------------
+
+def based_counters():
+    c = engine.counters()
+    return (c["percentile_kernel_lowerings"],
+            c["percentile_based_lowerings"])
+
+
+@pytest.mark.parametrize("kind", ["ties14", "zeros", "neginf"])
+@pytest.mark.parametrize("perc", [20.0, 100.0])
+@pytest.mark.parametrize("block,start", [
+    (72, 72), (72, BASE - 72), (136, BASE - 136), (40, 80), (8, 192),
+    (BASE, 0)],
+    ids=["a-start-between-tiles", "the-last-block-starts-early",
+         "three-tiles-the-last-early", "a-block-under-one-tile",
+         "one-vreg-of-records", "the-whole-base"])
+def test_the_kernel_reads_a_block_of_the_base_to_the_bit(
+        kernel_everywhere, block, start, perc, kind):
+    x = _base(kind, start, block)
+    at = jnp.int32(start)
+    before = based_counters()
+    got = np.asarray(jax.jit(_of_a_block(perc, block, 8))(x, at))
+    assert based_counters() == (before[0] + 1, before[1] + 1)
+    # the kernel over the slice counts as a kernel and not as based
+    sliced = jax.jit(_of_a_block(perc, block, None))(x, at)
+    assert based_counters() == (before[0] + 2, before[1] + 1)
+    want = jax.jit(lambda v: jnp.percentile(v, perc, axis=1, keepdims=True))(
+        x[start:start + block])
+    assert same_bits(got, sliced) and same_bits(got, want)
+    assert np.isnan(got[-1, 0]) and not np.isnan(got[:-1]).any()
+
+
+def test_the_based_kernel_takes_no_row_outside_its_block(kernel_everywhere):
+    # rows around the block poisoned: a tile that read past the block's
+    # end (or before its start) would answer NaN
+    block, start = 72, 64
+    x = rows("ties14", LONG, count=BASE)
+    want = np.asarray(jax.jit(_of_a_block(20.0, block, None))(
+        x, jnp.int32(start)))
+    x[:start] = np.nan
+    x[start + block:] = np.nan
+    got = np.asarray(jax.jit(_of_a_block(20.0, block, 8))(
+        x, jnp.int32(start)))
+    assert not np.isnan(got).any() and same_bits(got, want)
+
+
+@pytest.mark.parametrize("first,said", [
+    (True, "one read of a block in place"), (False, "one read of a block:")],
+    ids=["on-the-block's-rows", "after-a-map"])
+def test_a_blocked_chain_selects_in_place_and_explain_says_so(
+        kernel_everywhere, first, said):
+    mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("k",))
+    x = sessions((16, 8, N))
+
+    def make():
+        b = bolt.array(x, mesh, axis=(0, 1))
+        b = b if first else b.map(lambda v: v + 0, axis=(0, 1))
+        return ops.detrend(ops.normalize(b, "percentile", 20.0), order=3)
+    want = make().toarray()
+    engine.clear()
+    arr = make()
+    base = arr._chain[0]
+    # room for blocks of 24 of the 128 records
+    tpu_array._HBM_LIMIT_OVERRIDE = int(2 * base.nbytes + 12e5)
+    try:
+        marker = arr._block_plan(*arr._chain)[-1]
+        assert marker.block_records % 8 == 0 and marker.blocks > 1
+        text = analysis.explain(arr)
+        before = based_counters()
+        got = arr.toarray()
+        moved = based_counters()
+    finally:
+        tpu_array._HBM_LIMIT_OVERRIDE = None
+    assert said in text
+    assert moved == (before[0] + 1, before[1] + first)
+    assert np.array_equal(got, want)
 
 
 # ---------------------------------------------------------------------

@@ -272,3 +272,183 @@ def test_dff_holds_numpys_percentile_either_side_of_the_crossover(
                           axis=1).toarray()
     base32 = np.percentile(x, perc, axis=-1, keepdims=True)
     assert np.array_equal(local, (x - base32) / base32)
+
+
+# ---------------------------------------------------------------------
+# (e) a block of records selected where the array lies (PR 47): inside
+# ``select.block_of`` a selection whose operand IS the block's rows is
+# bound with the base and the offset beside the rows, for the kernel to
+# read in place.  Off a one-device TPU program it lowers to the passes
+# over the rows, so here every case is the fallback's arm
+# (``tests/test_percentile_kernel.py`` interprets the kernel's)
+# ---------------------------------------------------------------------
+
+LONG = select._KERNEL_FROM              # the primitive's shortest record
+BASE = 200                              # records of the base: 8 divides it
+
+
+def _binds(jaxpr):
+    """Every ``percentile_select`` equation of ``jaxpr``, nested ones
+    among them."""
+    from bolt_tpu.tpu import blocks
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "percentile_select":
+            out.append(eqn)
+            continue
+        for inner in blocks._sub_jaxprs(eqn):
+            out += _binds(inner)
+    return out
+
+
+def _base(kind, start, block):
+    """``BASE`` rows of ``LONG`` values of ``kind``; the last two rows of
+    the block at ``start`` hold a NaN and both zeros."""
+    x = rows(kind, LONG, seed=start + block, count=BASE)
+    x[start + block - 1, 5] = np.nan
+    x[start + block - 2, :4] = [0.0, -0.0, -0.0, 0.0]
+    return x
+
+
+def _of_a_block(perc, block, step):
+    """The percentile of every record of the ``block`` rows of a base
+    from ``start``, traced as ``tpu/array.py :: _blocked_run`` traces a
+    block's maps (``step`` None: with nobody saying where the rows
+    lie)."""
+    def fn(base, start):
+        part = jax.lax.dynamic_slice_in_dim(base, start, block)
+        one = jax.vmap(lambda v: select.percentile(v, perc, 0, True))
+        if step is None:
+            return one(part)
+        with select.block_of(part, base, start, step):
+            return one(part)
+    return fn
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+@pytest.mark.parametrize("kind", ["ties14", "zeros", "normal"])
+@pytest.mark.parametrize("block,start", [
+    (72, 72), (72, BASE - 72), (40, 80), (8, 192), (BASE, 0)],
+    ids=["a-start-between-tiles", "the-last-block-starts-early",
+         "a-block-under-one-tile", "one-vreg-of-records", "the-whole-base"])
+def test_a_block_in_the_base_is_the_slices_percentile_to_the_bit(
+        block, start, kind, x64):
+    assert BASE % 72 and select._tile(72, LONG)[0] == 64    # what the ids say
+    x = _base(kind, start, block)
+    perc = 20.0
+    with jax.enable_x64(x64):
+        based = _of_a_block(perc, block, 8)
+        sliced = _of_a_block(perc, block, None)
+        by_sort = lambda base, at: jnp.percentile(
+            jax.lax.dynamic_slice_in_dim(base, at, block), perc, axis=1,
+            keepdims=True)
+        at = jnp.int32(start)
+        (bind,) = _binds(jax.make_jaxpr(based)(x, at).jaxpr)
+        rows_, base, offset = bind.invars
+        assert rows_.aval.shape == (block, LONG) and bind.params["lead"] == 1
+        assert base.aval.shape == x.shape and offset.aval.shape == ()
+        (bind,) = _binds(jax.make_jaxpr(sliced)(x, at).jaxpr)
+        assert len(bind.invars) == 1
+        got = np.asarray(jax.jit(based)(x, at))
+        assert same_bits(got, jax.jit(sliced)(x, at))
+        assert same_bits(got, jax.jit(by_sort)(x, at))
+        assert np.isnan(got[-1, 0]) and not np.isnan(got[:-1]).any()
+        # and the program is the slice's own: the base and the offset
+        # are operands the passes do not read
+        assert jax.jit(based).lower(x, at).as_text() \
+            == jax.jit(sliced).lower(x, at).as_text()
+
+
+@pytest.mark.parametrize("why,block,step,lead", [
+    ("blocks-off-the-sublanes", 72, 4, 0),
+    ("a-record-that-is-no-series", 72, 8, 1)])
+def test_a_block_the_kernel_could_not_read_in_place_stays_a_slice(
+        why, block, step, lead):
+    shape = (BASE,) + (3,) * lead + (LONG,)
+    x = np.zeros(shape, np.float32)
+
+    def fn(base, start):
+        part = jax.lax.dynamic_slice_in_dim(base, start, block)
+        with select.block_of(part, base, start, step):
+            return jax.vmap(lambda v: select.percentile(
+                v, 20.0, lead, True))(part)
+    (bind,) = _binds(jax.make_jaxpr(fn)(x, jnp.int32(0)).jaxpr)
+    assert len(bind.invars) == 1, why
+
+
+def _double(v):
+    return v * 2
+
+
+# what ``jax.jit(_blocked_run((_double, normalize), 2, ., 24)).lower(
+# f32[8, 8, LONG])`` read as at the parent commit (0e07acc, jax 0.9.0,
+# under tests/conftest.py)
+PARENT_BLOCKED_AFTER_A_MAP = \
+    "7c0a5afa6906f65aa3b55940c34008f19414d0af8b30e27a7a5d46431a5a2d0b"
+
+
+def test_a_selection_of_something_computed_first_lowers_as_it_did():
+    import hashlib
+    from bolt_tpu.tpu import array as tpu_array
+    normalize = ops.series._normalize_fn("percentile", 20.0, 0, 0.0)
+    x = jax.ShapeDtypeStruct((8, 8, LONG), np.float32)
+    after = lambda d: tpu_array._blocked_run((_double, normalize), 2, d, 24)
+    first = lambda d: tpu_array._blocked_run((normalize, _double), 2, d, 24)
+    # the operand is the map's result, which XLA writes anyway: one
+    # operand, as ever; straight on the block's rows it is three
+    (bind,) = _binds(jax.make_jaxpr(after)(x).jaxpr)
+    assert len(bind.invars) == 1
+    (bind,) = _binds(jax.make_jaxpr(first)(x).jaxpr)
+    assert len(bind.invars) == 3
+    found = []
+    jax.eval_shape(lambda d: tpu_array._blocked_run(
+        (_double, normalize), 2, d, 24, found=found), x)
+    assert not found
+    jax.eval_shape(lambda d: tpu_array._blocked_run(
+        (normalize, _double), 2, d, 24, found=found), x)
+    assert found
+    if jax.__version__ == "0.9.0":
+        text = jax.jit(after).lower(x).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest() \
+            == PARENT_BLOCKED_AFTER_A_MAP
+
+
+@pytest.fixture(scope="module")
+def four_devices():
+    return jax.sharding.Mesh(np.asarray(jax.devices()[:4]), ("k",))
+
+
+@pytest.mark.parametrize("x64", [True, False], ids=["x64", "x32"])
+@pytest.mark.parametrize("sharded", [False, True],
+                         ids=["one-device", "four-devices"])
+def test_through_the_blocked_run_one_device_and_four(four_devices, sharded,
+                                                     x64):
+    from bolt_tpu.tpu import array as tpu_array
+    rng = np.random.default_rng(47)
+    x = rng.integers(4000, 4064, (32, 8, LONG)).astype(np.float32)
+    x[31, 7, 9] = np.nan                    # in the last block's last tile
+    x[31, 6, :4] = [0.0, -0.0, -0.0, 0.0]
+    run = (ops.series._normalize_fn("percentile", 20.0, 0, 0.0),)
+    # 64 records a shard (256 on one device) in blocks of 24: the last
+    # block of each starts early, at 40 (232)
+    if sharded:
+        blocked = lambda d: tpu_array._sharded_blocked_run(
+            run, 2, d, 24, four_devices)
+    else:
+        blocked = lambda d: tpu_array._blocked_run(run, 2, d, 24)
+    with jax.enable_x64(x64):
+        (bind,) = _binds(jax.make_jaxpr(blocked)(x).jaxpr)
+        rows_, base, _ = bind.invars
+        assert rows_.aval.shape == (24, LONG)
+        assert base.aval.shape == ((64 if sharded else 256), LONG)
+        got = np.asarray(jax.jit(blocked)(x))
+        whole = np.asarray(jax.jit(
+            lambda d: tpu_array._chain_apply(run, 2, d))(x))
+
+        def parent(v):
+            base = jnp.percentile(v, 20.0, axis=0, keepdims=True)
+            return (v - base) / jnp.where(base >= 0, base + 0.0, base - 0.0)
+        by_sort = np.asarray(jax.jit(jax.vmap(jax.vmap(parent)))(x))
+    assert np.array_equal(got, whole, equal_nan=True)
+    assert np.array_equal(got, by_sort, equal_nan=True)
+    assert np.isnan(got[31, 7]).all() and not np.isnan(got[:31]).any()
