@@ -50,6 +50,14 @@ paths compile (``tpu/chunk.py :: _uniform_map_body`` /
 materialised results cannot drift semantically — the out-of-core parity
 suite (``tests/test_stream.py``) bit-compares them.
 
+Who waits for whom is on the tracer (``bolt_tpu.obs``): the consumer
+starved of uploads is ``stream.wait.slab`` (inside the pool's ``next``),
+an ingesting thread with no ring permit to work under is
+``stream.wait.ring`` (a worker with no job to take: the dispenser hands
+one out the moment it holds a permit; an iterator's one thread in front
+of its pull), the slab program's call alone is ``stream.dispatch`` and
+the block for it ``stream.sync``.
+
 Accounting lands in the engine counters: ``transfer_bytes`` /
 ``transfer_seconds`` for every counted upload (the link's busy time,
 counted once where the pool's copies overlap; each copy's own in
@@ -1839,10 +1847,19 @@ class _IngestPool:
     def next(self, idle=None):
         """The next slab in order, ``None`` at end-of-stream; re-raises
         a pool fault and names dead threads (:meth:`_Reseq.next`, which
-        says what ``idle`` is for)."""
-        got = self._rsq.next(self.threads, workers=self._ingesters,
-                             idle=idle)
-        return None if got is None else got[1]
+        says what ``idle`` is for).  The call is the consumer's
+        ``stream.wait.slab`` span: the time it is starved of uploads."""
+        sp = _obs.begin("stream.wait.slab", parent=self._parent)
+        try:
+            got = self._rsq.next(self.threads, workers=self._ingesters,
+                                 idle=idle)
+            if got is None:
+                return None
+            if sp is not None:
+                sp.set(slab=got[1][0])
+            return got[1]
+        finally:
+            _obs.end(sp)
 
     def give_back(self, slabs, nbytes):
         """Return ``slabs`` ring permits and ``nbytes`` lease bytes (the
@@ -1954,7 +1971,17 @@ class _IngestPool:
         try:
             with _engine.tenant(run.tenant):
                 while True:
-                    job = self._jobq.get()
+                    # the dispenser hands a job out the moment it holds
+                    # a permit, so a worker with none to take waits for
+                    # the ring: for the consumer, or the device
+                    wsp = _obs.begin("stream.wait.ring",
+                                     parent=self._parent, worker=wid)
+                    try:
+                        job = self._jobq.get()
+                        if wsp is not None and job is not None:
+                            wsp.set(slab=job[1])
+                    finally:
+                        _obs.end(wsp)
                     if job is None or stop.is_set():
                         return
                     j, g, lo, hi = job
@@ -2003,9 +2030,14 @@ class _IngestPool:
             with _engine.tenant(run.tenant):
                 it = self._blocks()
                 while True:
-                    if stop.is_set() or not _acquire(self._permits, stop):
-                        return
                     g = self._first + j
+                    wsp = _obs.begin("stream.wait.ring",
+                                     parent=self._parent, slab=g)
+                    try:
+                        if not _acquire(self._permits, stop):
+                            return      # the run is aborting
+                    finally:
+                        _obs.end(wsp)
                     buf = None
                     self._enter()
                     sp = _obs.begin("stream.ingest", parent=self._parent,
@@ -2434,23 +2466,27 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                 if keyed:
                                     extra = (np.int32(slab_hi
                                                       - wshape[0]),) + side
-                                if pend is None:
-                                    prog = _slab_program(
-                                        source, terminal, wshape, ddof,
-                                        rfunc, comps=comps,
-                                        sharded=mspec is not None,
-                                        codec_obj=codec_obj)
-                                    pend = prog(buf, *extra)
-                                    pend_bytes = slab_bytes
-                                    pairp = None
+                                # an odd slab's program has the
+                                # level-0 fold with its pair fused in
+                                fused = pend is not None
+                                prog = _slab_program(
+                                    source, terminal, wshape, ddof,
+                                    rfunc, fused=fused, comps=comps,
+                                    sharded=mspec is not None,
+                                    codec_obj=codec_obj)
+                                xsp = _obs.begin("stream.dispatch",
+                                                 slab=slab_g)
+                                try:
+                                    part = (prog(buf, pend, *extra)
+                                            if fused
+                                            else prog(buf, *extra))
+                                finally:
+                                    _obs.end(xsp)
+                                if fused:
+                                    pairp = part
                                 else:
-                                    # level-0 fold fused in
-                                    prog = _slab_program(
-                                        source, terminal, wshape, ddof,
-                                        rfunc, fused=True, comps=comps,
-                                        sharded=mspec is not None,
-                                        codec_obj=codec_obj)
-                                    pairp = prog(buf, pend, *extra)
+                                    pend, pend_bytes = part, slab_bytes
+                                    pairp = None
                             finally:
                                 _obs.end(dsp)
                             if pairp is not None:
@@ -3117,21 +3153,32 @@ def _resolve_one_swap(source, collect=False):
                             warnings.filterwarnings(
                                 "ignore", message="Some donated "
                                 "buffers were not usable")
-                            if plan.resident:
-                                # slabs arrive re-sequenced, in key
-                                # order, and a resident run skips none
-                                psp = _obs.begin("stream.collect.place",
-                                                 slab=g) \
-                                    if collect else None
-                                try:
-                                    out, cursor = prog(out, buf, cursor,
-                                                       *side)
-                                finally:
-                                    _obs.end(psp)
-                                part = out
-                            else:
-                                part = prog(buf, *side)
-                        _pod_sync(part, pod, "shuffle re-bucket", slab=g)
+                            xsp = _obs.begin("stream.dispatch", slab=g)
+                            try:
+                                if plan.resident:
+                                    # slabs arrive re-sequenced, in key
+                                    # order, and a resident run skips
+                                    # none
+                                    psp = _obs.begin(
+                                        "stream.collect.place", slab=g) \
+                                        if collect else None
+                                    try:
+                                        out, cursor = prog(out, buf,
+                                                           cursor, *side)
+                                    finally:
+                                        _obs.end(psp)
+                                    part = out
+                                else:
+                                    part = prog(buf, *side)
+                            finally:
+                                _obs.end(xsp)
+                        ssp = _obs.begin("stream.sync", slabs=1,
+                                         shuffle=True)
+                        try:
+                            _pod_sync(part, pod, "shuffle re-bucket",
+                                      slab=g)
+                        finally:
+                            _obs.end(ssp)
                         break
                     except _podwatch.PeerLostError:
                         raise

@@ -17,6 +17,17 @@ traced window and per request:
   holds) and by ``bolt.*`` span, whole window;
 * the same inside ``bench.fetch`` alone, and the share of it that falls in
   a named ``bolt.*`` span;
+* where a streamed run lies in the window, the gaps by the ``bolt.*`` spans
+  of the CONSUMER's thread alone (the one that opened ``stream.run``,
+  ``stream.shuffle`` or ``stream.collect``: starved in ``stream.wait.slab``,
+  calling in ``stream.dispatch``, blocked in ``stream.sync``), and beside it
+  by those of every other thread (the pool's: ``stream.ingest``,
+  ``stream.wait.ring``).  With a wait open on a pool thread and a span open
+  on the consumer's, "the latest-started takes the time" picks either;
+  and the idle time inside the consumer's ``bolt.stream.sync`` in the three
+  parts given below for ``bolt.array.fetch.wait``: a block whose program
+  runs at its END waited for the program to start (for something in front
+  of it on the device), one whose program runs at its START waited after it;
 * the idle time inside ``bolt.array.fetch.wait`` in three parts: before the
   first device operation under the span (launch latency), between its
   operations, and after the last (copy back and wake-up);
@@ -51,8 +62,10 @@ import run as bench_run          # noqa: E402
 import tracered as tr            # noqa: E402
 
 BOLT = "bolt."
+RUNS = ("bolt.stream.run", "bolt.stream.shuffle", "bolt.stream.collect")
 FETCH = "bench.fetch"
 WAIT = "bolt.array.fetch.wait"
+SYNC = "bolt.stream.sync"
 
 
 def intersect(a, b):
@@ -114,6 +127,18 @@ def host_spans(raw):
             if ev[0].startswith(tr.SPAN_PREFIX) or ev[0].startswith(BOLT)]
 
 
+def consumer_spans(raw):
+    """The ``bolt.*`` events of the host plane in two lists: those of the
+    threads that opened a streamed run's own span (``RUNS``; a line of the
+    plane is a thread), and every other thread's."""
+    mine, others = [], []
+    for _, line in tr._lines(raw, tr.HOST_PLANE):
+        events = [ev for ev in line["events"] if ev[0].startswith(BOLT)]
+        ran = any(ev[0] in RUNS for ev in events)
+        (mine if ran else others).extend(events)
+    return mine, others
+
+
 def first_chip_ops(raw):
     """The device operations of the first chip, as ``tracered`` orders the
     chips."""
@@ -167,9 +192,10 @@ def clock_offset(ops, host, t0, t1, check):
     return (float(min(fits)), float(max(fits))) if fits else None
 
 
-def idle_tables(ops, check, host, t0, t1):
+def idle_tables(ops, check, host, t0, t1, consumer=((), ())):
     """The idle gaps of ``ops`` (merged busy intervals of the first chip)
-    given to the spans, as the module docstring lists them."""
+    given to the spans, as the module docstring lists them; ``consumer``
+    is ``consumer_spans``' pair."""
     busy = tr.clip(ops, t0, t1)
     both = tr.union(np.concatenate([busy, check]))
     gaps = tr.complement(both, t0, t1)
@@ -177,7 +203,14 @@ def idle_tables(ops, check, host, t0, t1):
         tr.intervals(host, {FETCH})), t0, t1))
     fetch_by_bolt = by_span(in_fetch, host, BOLT)
     idle_fetch = tr.total(in_fetch)
+    mine, others = consumer
+    streamed = {"idle_by_consumer": by_span(gaps, mine, BOLT),
+                "idle_by_other_threads": by_span(gaps, others, BOLT),
+                "idle_in_sync": wait_parts(gaps, busy, tr.clip(
+                    tr.intervals(mine, {SYNC}), t0, t1))} \
+        if mine else {}
     return {
+        **streamed,
         "busy_s": tr.total(both) - tr.total(check),
         "idle_s": tr.total(gaps),
         "idle_by_bench": by_span(gaps, host, tr.SPAN_PREFIX,
@@ -205,7 +238,7 @@ def split(raw):
     t0, t1 = float(window[0, 0]), float(window[0, 1])
     check = tr.clip(tr.union(tr.intervals(host, {tr.CHECK_SPAN})), t0, t1)
     ops = tr.union(tr.intervals(first_chip_ops(raw)))
-    out = idle_tables(ops, check, host, t0, t1)
+    out = idle_tables(ops, check, host, t0, t1, consumer_spans(raw))
     out["window_s"] = (t1 - t0) - tr.total(check)
     out["bolt_events"] = sum(1 for ev in host if ev[0].startswith(BOLT))
     offset = clock_offset(ops, host, t0, t1, check)
@@ -294,6 +327,13 @@ def main(argv=None):
           out["idle_by_bench"], requests)
     table("idle by innermost bolt.* span, whole window:",
           out["idle_by_bolt"], requests)
+    if "idle_by_consumer" in out:
+        table("idle by innermost bolt.* span of the streamed run's own "
+              "thread, the consumer's:", out["idle_by_consumer"], requests)
+        table("  and beside it, by those of every other thread:",
+              out["idle_by_other_threads"], requests)
+        table("idle inside the consumer's %s, by where the device's work "
+              "lies in it:" % SYNC, out["idle_in_sync"], requests)
     table("idle inside %s (%.4f s) by innermost bolt.* span:"
           % (FETCH, out["idle_in_fetch_s"]),
           out["idle_in_fetch_by_bolt"], requests)

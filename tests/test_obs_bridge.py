@@ -528,6 +528,69 @@ def test_host_gaps_gives_the_idle_time_to_the_innermost_span(host_gaps):
     assert wait["none"] == 0
 
 
+def test_host_gaps_gives_the_consumers_thread_its_own_account(host_gaps):
+    """A streamed pass of two slabs, 20 ms: the consumer's thread waits for
+    a slab, calls, blocks; a pool thread ingests and waits for the ring.
+    Over every thread at once the latest-started span takes a gap, so a
+    pool thread's wait takes time from the consumer's; by thread it does
+    not."""
+    ms = 1000000
+    mine = [("bench.window", 0, 20), ("bench.fetch", 0, 20),
+            ("bolt.stream.shuffle", 0, 20),
+            ("bolt.stream.wait.slab", 0, 6), ("bolt.stream.compute", 6, 4),
+            ("bolt.stream.dispatch", 6, 1), ("bolt.stream.sync", 7, 3),
+            ("bolt.stream.wait.slab", 10, 2), ("bolt.stream.compute", 12, 8),
+            ("bolt.stream.dispatch", 12, 1), ("bolt.stream.sync", 13, 7)]
+    pool = [("bolt.stream.ingest", 0, 6), ("bolt.stream.ingest", 6, 5),
+            ("bolt.stream.wait.ring", 11, 9)]
+    raw = {"planes": [
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+            ["%fusion = f32[] fusion()", 8 * ms, 2 * ms],
+            ["%fusion = f32[] fusion()", 16 * ms, 2 * ms]]}]},
+        {"name": "/host:CPU", "lines": [
+            {"name": "main", "events": [[n, int(s * ms), int(d * ms)]
+                                        for n, s, d in mine]},
+            {"name": "bolt-stream-upload-0", "events": [
+                [n, int(s * ms), int(d * ms)] for n, s, d in pool]}]}]}
+    out = host_gaps.split(raw)
+    ms = 1e-3
+    # idle: 0-8, 10-16, 18-20
+    assert out["idle_s"] == pytest.approx(16 * ms)
+    consumer = out["idle_by_consumer"]
+    assert consumer["bolt.stream.wait.slab"] == pytest.approx(8 * ms)
+    assert consumer["bolt.stream.dispatch"] == pytest.approx(2 * ms)
+    assert consumer["bolt.stream.sync"] == pytest.approx(6 * ms)
+    assert consumer["bolt.stream.compute"] == pytest.approx(0)
+    assert consumer["_no_span_open_"] == pytest.approx(0)
+    others = out["idle_by_other_threads"]
+    assert others["bolt.stream.ingest"] == pytest.approx(9 * ms)
+    assert others["bolt.stream.wait.ring"] == pytest.approx(7 * ms)
+    # every thread at once: 11-12 goes to the pool's wait, which started
+    # after the consumer's, and the consumer's account loses it
+    both = out["idle_by_bolt"]
+    assert both["bolt.stream.wait.ring"] == pytest.approx(1 * ms)
+    assert both["bolt.stream.wait.slab"] < 8 * ms
+    assert sum(consumer.values()) == pytest.approx(out["idle_s"])
+    assert sum(others.values()) == pytest.approx(out["idle_s"])
+    # the blocks 7-10 and 13-20 hold the programs 8-10 and 16-18: each
+    # waited a millisecond and three for its program to start, and the
+    # second two more after it
+    sync = out["idle_in_sync"]
+    assert sync["before_first_op"] == pytest.approx((1 + 3) * ms)
+    assert sync["after_last_op"] == pytest.approx(2 * ms)
+    assert sync["between_ops"] == pytest.approx(0) and sync["none"] == 0
+
+
+def test_host_gaps_has_no_consumers_account_without_a_streamed_run(
+        host_gaps):
+    raw = _trace(ops=[("%a = f32[] a()", 1, 2)],
+                 host=[("bench.window", 0, 10), ("bench.fetch", 0, 4),
+                       ("bolt.array.fetch", 0, 4)])
+    out = host_gaps.split(raw)
+    assert "idle_by_consumer" not in out
+    assert "idle_by_other_threads" not in out and "idle_in_sync" not in out
+
+
 def test_host_gaps_takes_the_checks_instants_out(host_gaps):
     raw = _trace(
         ops=[("%a = f32[] a()", 1, 2), ("%check = f32[] c()", 5, 2)],
