@@ -97,6 +97,17 @@ def _percentile_note(func, split, aval, blocked=None):
         "from which it is selected" % (length, select.select_from(dtype))
 
 
+def _centring_note(func):
+    """What an ``ops.fourier`` stage built behind ``detrend``, ``center``
+    or ``zscore`` says of its mean: from the attribute of the record
+    function that ``ops/series.py :: _fourier_fn`` builds without its own
+    centring.  Empty for any other stage."""
+    if getattr(func, "centred_by_parent", None) is None:
+        return ""
+    return ("centred by its parent: no pass for the mean, one reader of "
+            "the parent's result")
+
+
 def _block_heads(arr, base, funcs):
     """``{i: (records, block)}``: the ``funcs[i]`` that are the first map
     of a run which the rule of ``tpu/blocks.py`` lowers over blocks (the
@@ -890,7 +901,8 @@ def _check_impl(obj):
                 hint="keep constants in the input dtype or cast back "
                      "with astype/map(dtype=...) if the widening is "
                      "unintended"))
-        note = _percentile_note(func, walk_split, aval, heads.get(i))
+        note = (_percentile_note(func, walk_split, aval, heads.get(i))
+                or _centring_note(func))
         if shared and i + 1 == shared[1].nfuncs:
             note = "; ".join(filter(None, (note, _shared_note(shared))))
         aval = nxt

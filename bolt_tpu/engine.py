@@ -226,6 +226,10 @@ _SCHEMA = {
     # those of them whose kernel reads its block of records where the
     # base lies, by an offset, and not from a slice written out for it
     "percentile_based_lowerings": 0,
+    # ``ops.fourier`` record functions TRACED without a pass for the mean,
+    # because the stage before them (detrend, center, zscore) left it at
+    # zero: one reader of the parent's result, which XLA fuses with it
+    "fourier_centred_by_parent": 0,
     # resident swaps across chips LOWERED as an explicit exchange and the
     # one-pass glue (bolt_tpu/parallel/swapmerge.py: a TPU mesh, the key
     # axis on the lanes in pieces that are no whole lane tiles); every
@@ -835,6 +839,12 @@ def record_percentile_lowering(regime):
     (``"kernel"``: the ``percentile_select`` primitive's TPU rule; and
     ``"based"`` where that kernel reads its block from the base)."""
     _COUNTERS.add("percentile_%s_lowerings" % regime)
+
+
+def record_fourier_centred_by_parent():
+    """``ops.fourier``'s record function was traced without its own
+    centring (``ops/series.py :: _fourier_fn``)."""
+    _COUNTERS.add("fourier_centred_by_parent")
 
 
 def record_swap_merge_lowering():

@@ -13,12 +13,27 @@ What that costs on the device depends on what a record's function keeps:
   ``normalize(baseline="mean")`` are element-wise work and reductions
   within a record: XLA fuses them, so ``zscore(detrend(b)).stats()`` is
   one compiled pass over HBM with no temporary (compiled for the v5e,
-  ``detrend -> sum`` over 10.74 GB takes none, and ``fourier`` over it
-  is two reads with none: the mean, then five sums in one fusion).
+  ``detrend -> sum`` over 10.74 GB takes none, and a bare ``fourier``
+  over it is two reads with none: the mean, then five sums in one
+  fusion).
   ``fourier`` asks for ONE bin of each record's spectrum and for the
   spectrum's energy, and on the device it computes those and no
   transform: the bin is the centred record's product with a cosine and a
   sine, the energy Parseval's identity on the record itself (PR 44).
+  Behind ``detrend``, ``center`` or ``zscore`` along the same axis it is
+  ONE read: those stages leave a record's mean at zero and say so
+  (``zero_mean_axis`` on their record functions), ``fourier`` reads that
+  off its argument's deferred chain and spends no pass on a mean, and a
+  ``detrend`` of up to ``_FIT_TERMS_ON_VPU`` terms takes its fit out
+  element-wise, so the residual has one reader that XLA fuses with it
+  and is never written (PR 49: the five sums of ``normalize -> detrend
+  -> fourier`` read the array through the fit; engine counter
+  ``fourier_centred_by_parent``, ``analysis.explain``'s "centred by its
+  parent").  It takes both: a second reader of the residual (a mean, a
+  pilot) makes XLA write it out, and the thin product ``coef @ A.T`` XLA
+  lowers as a convolution, whose fusion takes no reduction behind it, or
+  expands into the reader at half again Horner's cost, by the shapes
+  around it.
 * ``normalize(baseline="percentile")`` takes two order statistics of each
   record.  They are SELECTED (``ops/select.py``: the k-th smallest built
   bit by bit by counting passes over the record's image of integer keys,
@@ -47,10 +62,12 @@ What that costs on the device depends on what a record's function keeps:
   (``tpu/array.py :: _lower_from_shared``, PR 39; measured by the
   ``pixelseries512-1chip.tuning`` cell).
 
-Polynomial detrending is two thin matmuls per record against the
-precomputed Vandermonde ``A`` and its pseudo-inverse (``v - A @
-(pinv(A) @ v)``) — MXU-shaped work, built host-side once per
-(length, order).
+Polynomial detrending is ``v - A @ (pinv(A) @ v)`` against the
+precomputed Vandermonde ``A`` and its pseudo-inverse, built host-side
+once per (length, order): the projection ``pinv(A) @ v`` (K = the
+record's length) is a thin matmul, MXU-shaped work; the fit ``A @ coef``
+(K = ``order + 1``) is Horner's rule on the vector unit up to
+``_FIT_TERMS_ON_VPU`` terms and a second thin matmul above.
 """
 
 from functools import lru_cache
@@ -58,8 +75,18 @@ from functools import lru_cache
 import numpy as np
 import jax.numpy as jnp
 
+from bolt_tpu import engine as _engine
 from bolt_tpu._precision import resolve as _resolve
 from bolt_tpu.ops import select as _select
+
+# A polynomial fit of up to this many terms (``order + 1``) is taken out on
+# the vector unit, above it by the thin matrix product (``_detrend_fn``).
+# On the v5e Horner's rule reads faster than the product at every order
+# swept, 0 to 15, under a consumer that reduces the residual (15.0-20.0 ms
+# against 37.0-37.6 over 5.37 GB: the product's residual is written out),
+# and within 2 % of it where the residual is written anyway; no order
+# above 15 was measured (PERF.md, PR 49: ``scripts/detrend_fit_probe.py``).
+_FIT_TERMS_ON_VPU = 16
 
 
 def _value_axis(b, axis):
@@ -87,9 +114,14 @@ def detrend(b, order=1, axis=0):
     value axis ``axis`` of every record.
 
     ``order=0`` removes the mean, ``order=1`` a linear trend, etc.  The
-    fit is exact (normal equations via ``pinv``, precomputed host-side),
-    and the subtraction is one matmul along the axis inside the fused
-    per-record program.
+    fit is exact (normal equations via ``pinv``, precomputed host-side):
+    one thin matmul along the axis for the coefficients, and on the
+    device the polynomial taken out by Horner's rule, ``order`` float32
+    multiply-adds, where it has at most ``_FIT_TERMS_ON_VPU`` terms (a
+    second thin matmul above that, and locally), inside the fused
+    per-record program.  The residual's mean along ``axis`` is zero up
+    to rounding at every order (the fit spans the constants), which a
+    ``fourier`` behind it reads off the chain.
     """
     order = int(order)
     if order < 0:
@@ -118,20 +150,33 @@ def _detrend_fn(length, order, ax):
         # promote to float: casting the fit matrices to an int dtype
         # would truncate them to zeros and silently return zeros
         dt = xp.promote_types(v.dtype, xp.float32)
-        a_ = xp.asarray(a_mat, dtype=dt)
         p_ = xp.asarray(pinv_a, dtype=dt)
         moved = xp.moveaxis(v.astype(dt), ax, -1)
-        if xp is jnp:
+        if xp is not jnp:
+            fit = (moved @ p_.T) @ xp.asarray(a_mat, dtype=dt).T
+        else:
             # deliberate pin through the resolver (explicit always wins):
             # the fit matrices are f32/f64 host constants — a bf16 pass
             # here would dominate the detrend residual
             coef = jnp.matmul(moved, p_.T, precision=_resolve("highest"))
-            fit = jnp.matmul(coef, a_.T, precision=_resolve("highest"))
-        else:
-            coef = moved @ p_.T
-            fit = coef @ a_.T
+            if order + 1 <= _FIT_TERMS_ON_VPU:
+                # the polynomial by Horner's rule in ``t``: float32
+                # multiply-adds, work that a reader of the residual
+                # fuses.  The thin product ``coef @ A.T`` XLA lowers as
+                # a convolution, or not, by the shapes around it, and a
+                # convolution's result is written out for any reduction
+                # behind it
+                t_ = jnp.asarray(t, dtype=dt)
+                fit = coef[..., order:]
+                for k in reversed(range(order)):
+                    fit = fit * t_ + coef[..., k:k + 1]
+            else:
+                fit = jnp.matmul(coef, jnp.asarray(a_mat, dtype=dt).T,
+                                 precision=_resolve("highest"))
         return xp.moveaxis(moved - fit, -1, ax)
 
+    # the residual is orthogonal to A's column of ones
+    f.zero_mean_axis = ax
     return f
 
 
@@ -155,6 +200,7 @@ def _zscore_fn(ax, ddof, epsilon):
         mu = xp.mean(v, axis=ax, keepdims=True)
         sd = xp.std(v, axis=ax, ddof=ddof, keepdims=True)
         return (v - mu) / (sd + epsilon)
+    f.zero_mean_axis = ax
     return f
 
 
@@ -169,6 +215,7 @@ def _center_fn(ax):
     def f(v):
         xp = np if isinstance(v, np.ndarray) else jnp
         return v - xp.mean(v, axis=ax, keepdims=True)
+    f.zero_mean_axis = ax
     return f
 
 
@@ -263,6 +310,15 @@ def fourier(b, freq, axis=0, epsilon=0.0):
     ``epsilon`` guards constant records, which otherwise divide 0/0 to
     NaN (same convention as ``zscore``/``crosscorr``).
 
+    On the device a ``b`` whose deferred chain ends in ``detrend``,
+    ``center`` or ``zscore`` along ``axis`` is not centred again: its
+    mean is zero already, the bin and the energy below leave the DC bin
+    out of what they are handed, and with no pass for the mean this
+    stage is the ONE reader of its parent's result, which XLA fuses into
+    it (engine counter ``fourier_centred_by_parent``).  Anything else
+    (stored data, a caller's own map) is centred here: float32 sums over
+    a large level would lose the energy.
+
     Locally ``co`` is ``np.fft.rfft``'s (the oracle).  On the device no
     transform runs: one bin of a DFT is the record's product with a
     cosine and a sine, and ``sum_{k>=1} |co[k]|^2`` is Parseval's
@@ -278,7 +334,13 @@ def fourier(b, freq, axis=0, epsilon=0.0):
             "freq must be in [1, %d] for an axis of length %d, got %d"
             % (length // 2, length, freq))
 
-    out = _apply_map(b, _fourier_fn(freq, ax, float(epsilon)))
+    func = _fourier_fn(freq, ax, float(epsilon))
+    # the last stage of the argument's chain may have left the mean along
+    # this axis at zero (detrend, center, zscore say so on their functions)
+    if (b.mode == "tpu" and b.deferred and getattr(
+            b._chain[1][-1], "zero_mean_axis", None) == ax):
+        func = func.after_zero_mean
+    out = _apply_map(b, func)
     return (_apply_map(out, _pick_fn(ax, 0)),
             _apply_map(out, _pick_fn(ax, 1)))
 
@@ -304,19 +366,38 @@ def _bin_rows(freq, length):
 
 @lru_cache(maxsize=128)
 def _fourier_fn(freq, ax, epsilon):
-    def f(v):
-        xp = np if isinstance(v, np.ndarray) else jnp
-        dt = xp.promote_types(v.dtype, xp.float32)
-        moved = xp.moveaxis(v.astype(dt), ax, -1)
-        y = moved - xp.mean(moved, axis=-1, keepdims=True)
-        if xp is jnp:
-            return jnp.stack(_bin_and_energy(y, freq, epsilon), axis=ax)
-        co = xp.fft.rfft(y, axis=-1)
-        mag2 = xp.abs(co[..., 1:]) ** 2
-        coh = (xp.abs(co[..., freq])
-               / (xp.sqrt(xp.sum(mag2, axis=-1)) + epsilon))
-        ph = xp.angle(co[..., freq])
-        return xp.stack([coh, ph], axis=ax)
+    """The record function of ``fourier``, and as its ``after_zero_mean``
+    the same for records whose parent stage left their mean at zero
+    (``zero_mean_axis``): on the device that one spends no pass on a
+    mean, so it is ONE reader of its argument and fuses with the work
+    that made it.  ``_bin_and_energy`` leaves the DC bin out of whatever
+    it is handed.  A large level it could not take: float32 sums of
+    ``1000 +- 10`` lose the energy, which is why a bare ``fourier``
+    centres."""
+    def build(centred_by_parent):
+        def f(v):
+            xp = np if isinstance(v, np.ndarray) else jnp
+            dt = xp.promote_types(v.dtype, xp.float32)
+            y = xp.moveaxis(v.astype(dt), ax, -1)
+            if centred_by_parent and xp is jnp:
+                _engine.record_fourier_centred_by_parent()
+            else:
+                y = y - xp.mean(y, axis=-1, keepdims=True)
+            if xp is jnp:
+                return jnp.stack(_bin_and_energy(y, freq, epsilon), axis=ax)
+            co = xp.fft.rfft(y, axis=-1)
+            mag2 = xp.abs(co[..., 1:]) ** 2
+            coh = (xp.abs(co[..., freq])
+                   / (xp.sqrt(xp.sum(mag2, axis=-1)) + epsilon))
+            ph = xp.angle(co[..., freq])
+            return xp.stack([coh, ph], axis=ax)
+        if centred_by_parent:
+            # what analysis.explain reads to say the stage takes no mean
+            f.centred_by_parent = ax
+        return f
+
+    f = build(False)
+    f.after_zero_mean = build(True)
     return f
 
 

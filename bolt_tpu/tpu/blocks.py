@@ -36,8 +36,9 @@ its getitem windows) are traced ONCE, on ONE record's aval, to a jaxpr:
   compiled for the v5e the ``tuning`` cell's whole chain took 0.6 of the
   estimate while its last map was an FFT, and that FFT alone 1.55 of its
   own, XLA's scratch; since PR 44 ``ops.fourier`` holds no heavy
-  primitive, and the chain, heavy by its selection, takes 0.14), and all
-  its records at once hold ``records x`` that;
+  primitive, and the chain, heavy by its selection, takes 0.14, and
+  nothing since PR 49, whose ``detrend -> fourier`` writes no residual),
+  and all its records at once hold ``records x`` that;
 * when that passes :data:`SHARE` of what the device has left after the
   chain's base and its result (``memory_stats()["bytes_limit"]`` less
   both), the run is lowered over blocks of whole records, the largest block

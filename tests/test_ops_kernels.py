@@ -1285,8 +1285,10 @@ _V5E_HBM = int(15.75 * 2 ** 30)        # the chip's memory_stats() limit
 
 def _tuning_chain():
     from bolt_tpu.ops import series
+    # what ops.fourier builds behind a detrend: no pass for the mean
     return (series._normalize_fn("percentile", 20.0, 0, 0.0),
-            series._detrend_fn(10240, 5, 0), series._fourier_fn(16, 0, 0.0),
+            series._detrend_fn(10240, 5, 0),
+            series._fourier_fn(16, 0, 0.0).after_zero_mean,
             series._pick_fn(0, 0))
 
 
@@ -1313,7 +1315,7 @@ def _computations(text):
 # shared parent's program (BoltArrayTPU._lower_from_shared, PR 39)
 @pytest.mark.parametrize("picked", [1, 0], ids=["one-handle",
                                                 "the-shared-parent"])
-def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
+def test_the_tuning_map_compiles_blocked_and_writes_no_residual_on_v5e(
         v5e_device, picked):
     import jax
     from bolt_tpu.tpu.array import _Blocked, _chain_apply, _plan_blocks
@@ -1379,14 +1381,32 @@ def test_the_tuning_map_compiles_blocked_and_is_refused_whole_on_v5e(
                 assert "ROOT " in ln
             assert not re.search(r" (dynamic-slice|copy)\(", ln), ln
         # fourier's bin and energy are sums over the series (PR 44): no
-        # transform in the program, and the one temporary is the
-        # residual (normalised and detrended, which fourier reads twice),
-        # a quarter of what the program held with the FFT in it (9831aad:
-        # 879,325,696 and 877,326,848 bytes)
+        # transform in the program.  And the residual is never written
+        # (PR 49: the fit is taken out element-wise and fourier spends no
+        # pass on a mean its parent took out, so it is the residual's ONE
+        # reader and XLA fuses the two): no instruction of the loop's
+        # body has a block-sized float32 result, no temporary to speak of
+        # (219,540,480 bytes before), and exactly three instructions read
+        # the base: the kernel, detrend's projection, and one fusion that
+        # normalises, takes the fit out and reduces to fourier's five sums
         assert not re.search(r"\bfft\b", text)
-        assert mem.temp_size_in_bytes < 1.02 * 4 * block * _PIXELS[2]
-        with pytest.raises(Exception, match="Exceeded hbm capacity|hbm"):
-            jax.jit(lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
+        assert mem.temp_size_in_bytes < 8 * 2 ** 20
+        assert not [ln for ln in body if re.search(
+            r" = \(?f32\[%d,%d\]" % (block, _PIXELS[2]), ln)]
+        base_name = re.match(r"\s*(%\S+) = ", read[1]).group(1)
+        readers = [ln for ln in body if " tuple(" not in ln and re.search(
+            r"[(, ]%s[,)]" % re.escape(base_name), ln.split(" = ", 1)[1])]
+        assert len(readers) == 3 and call in readers, readers
+        fused = [ln for ln in readers if " fusion(" in ln]
+        assert sorted(ln[:ln.index(" fusion(")].count("f32[%d]" % block)
+                      for ln in fused) == [0, 5], fused
+        # what the chip refused of the chain lowered whole was that
+        # residual for every record at once; without it the whole chain
+        # compiles too, beside the kernel's keys (134 MB a plane).  The
+        # rule goes by its estimate of the selection, and blocks it still
+        whole = jax.jit(
+            lambda d: _chain_apply(funcs, 2, d)).lower(arg).compile()
+        assert whole.memory_analysis().temp_size_in_bytes < 0.05 * base
 
 
 # ``fourier`` straight over the resident array holds no primitive of
@@ -1398,7 +1418,8 @@ def test_a_bare_fourier_compiles_whole_and_without_a_transform_on_v5e(
         v5e_device):
     import jax
     from bolt_tpu.tpu.array import _chain_apply, _plan_blocks
-    funcs = _tuning_chain()[2:3]
+    from bolt_tpu.ops import series
+    funcs = (series._fourier_fn(16, 0, 0.0),)
     base = 4 * int(np.prod(_PIXELS))
     free = _V5E_HBM - base - 4 * 512 * 512 * 2
     assert _plan_blocks(funcs, 2, _PIXELS, np.float32, free) == funcs
@@ -1424,6 +1445,76 @@ def test_a_bare_fourier_compiles_whole_and_without_a_transform_on_v5e(
     assert any(" reduce(" in line for line in reads)
     sums, = [line for line in reads if " fusion(" in line]
     assert sums[:sums.index(" fusion(")].count("f32[512,512]") == 5, sums
+
+
+# a ``fourier`` whose parent stage left the mean at zero spends no pass
+# on it (PR 49), so it is ONE reader of what its parent made and XLA
+# fuses the two: the array is read for the parent's own reductions
+# (detrend's projection; center's mean; zscore's mean and deviation) and
+# then ONCE for the five sums, with nothing written between.  ``detrend``
+# takes its fit out by Horner's rule up to ``_FIT_TERMS_ON_VPU`` terms;
+# above it the thin product stays, which XLA lowers as a convolution
+# whose result it writes out here (records keyed by one axis; keyed by
+# two it fuses that one too: the shapes decide, which is why the fit is
+# not left to them)
+_ONE_KEY = (131072, 10240)
+
+_ZERO_MEAN_CASES = [
+    # name, parent, shape, split, the parent's own reads, written out
+    ("detrend-0", lambda s: s._detrend_fn(10240, 0, 0), _PIXELS, 2, 1, False),
+    ("detrend-1", lambda s: s._detrend_fn(10240, 1, 0), _PIXELS, 2, 1, False),
+    ("detrend-5", lambda s: s._detrend_fn(10240, 5, 0), _PIXELS, 2, 1, False),
+    ("detrend-5-one-key-axis", lambda s: s._detrend_fn(10240, 5, 0),
+     _ONE_KEY, 1, 1, False),
+    ("detrend-15-one-key-axis", lambda s: s._detrend_fn(10240, 15, 0),
+     _ONE_KEY, 1, 1, False),
+    ("detrend-16-above-the-bound", lambda s: s._detrend_fn(10240, 16, 0),
+     _ONE_KEY, 1, 1, True),
+    ("center", lambda s: s._center_fn(0), _PIXELS, 2, 1, False),
+    ("zscore", lambda s: s._zscore_fn(0, 0, 0.0), _PIXELS, 2, 2, False),
+]
+
+
+@pytest.mark.parametrize("name,parent,shape,split,own,written",
+                         _ZERO_MEAN_CASES,
+                         ids=[case[0] for case in _ZERO_MEAN_CASES])
+def test_fourier_behind_a_zero_mean_parent_is_one_more_read_on_v5e(
+        v5e_device, name, parent, shape, split, own, written):
+    import jax
+    from bolt_tpu.ops import series
+    from bolt_tpu.tpu.array import _chain_apply, _plan_blocks
+    funcs = (parent(series), series._fourier_fn(16, 0, 0.0).after_zero_mean)
+    nbytes = 4 * int(np.prod(shape))
+    assert _plan_blocks(funcs, split, shape, np.float32,
+                        _V5E_HBM - nbytes) == funcs
+    where = jax.sharding.SingleDeviceSharding(v5e_device)
+    arg = jax.ShapeDtypeStruct(shape, _F32, sharding=where)
+    with jax.enable_x64(False):
+        compiled = jax.jit(
+            lambda d: _chain_apply(funcs, split, d)).lower(arg).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    assert mem.argument_size_in_bytes == nbytes
+    assert not re.search(r"\bfft\b| while\(", text)
+    whole = r" = \(?f32\[%s\]" % ",".join(map(str, shape))
+    entry = text[text.index("\nENTRY "):].splitlines()
+    made = [ln for ln in entry if re.search(whole, ln)
+            and " parameter(" not in ln]
+    if written:
+        # the product's residual, for the five sums to read back
+        assert "convolution" in text and len(made) == 1, made
+        assert nbytes <= mem.temp_size_in_bytes < 1.01 * nbytes
+        return
+    assert not made and mem.temp_size_in_bytes < 8 * 2 ** 20
+    keys = r"f32\[%s\]" % ",".join(map(str, shape[:split]))
+    reads = _argument_readers(text)
+    assert len(reads) == own + 1, reads
+    sums = [ln for ln in reads if " fusion(" in ln
+            and len(re.findall(keys, ln[:ln.index(" fusion(")])) == 5]
+    assert len(sums) == 1, reads
+    if name in ("center", "zscore"):
+        # the parent's mean, and no second one
+        assert sum(" reduce(" in ln for ln in reads) == 1, reads
 
 
 # which executor a selection gets is the lowering's to say, from the

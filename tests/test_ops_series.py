@@ -383,6 +383,274 @@ def test_fourier_along_any_value_axis(mesh, name, shape, split, axis):
         assert allclose(lph.toarray(), np.angle(co[..., 5]), rtol=1e-12)
 
 
+# ---------------------------------------------------------------------
+# a ``fourier`` whose argument's chain ends in ``detrend``, ``center`` or
+# ``zscore`` along the same axis spends no pass on a mean its parent took
+# out (PR 49): one reader of the parent's result, which XLA fuses with
+# it.  Held here to what the centring route (the parent commit's, built
+# by hand from the bare record function) reads on the same data, over
+# what an uncentred sum could get wrong: a level far above the signal, a
+# ramp the fit takes out by cancellation, a spike, no energy at all, the
+# parity of the length and the Nyquist bin; and both to NumPy in float64
+# ---------------------------------------------------------------------
+
+def _detrend64(order):
+    def f(x):
+        t = np.linspace(-1.0, 1.0, x.shape[-1])
+        q, _ = np.linalg.qr(np.vander(t, order + 1, increasing=True))
+        return x - (x @ q) @ q.T
+    return f
+
+
+def _center64(x):
+    return x - x.mean(axis=-1, keepdims=True)
+
+
+def _zscore64(x):
+    return _center64(x) / x.std(axis=-1, keepdims=True)
+
+
+def _dff64(x):
+    base = np.percentile(x, 20.0, axis=-1, keepdims=True)
+    return (x - base) / base
+
+
+def _cell_like(n, length, freq, rs):
+    """Series made as ``benchmark/operands/pixelseries.py`` makes them:
+    whole counts, a resting level of thousands, a drift of order 2, a
+    sinusoid at the bin on two pixels in three, uniform noise."""
+    t = np.arange(length)
+    rest = rs.randint(4000, 8001, (n, 1))
+    drift = (np.floor(rs.randint(-400, 401, (n, 1)) * t / length)
+             + np.floor(rs.randint(-400, 401, (n, 1)) * (t / length) ** 2))
+    tuned = np.arange(n)[:, None] % 3 != 0
+    w = 2.0 * np.pi * freq * t / length
+    wave = np.floor((tuned * rs.randint(-400, 401, (n, 1))
+                     * np.rint(1024 * np.cos(w))
+                     + tuned * rs.randint(-400, 401, (n, 1))
+                     * np.rint(1024 * np.sin(w))) / 1024)
+    return rest + drift + wave + rs.randint(-256, 257, (n, length))
+
+
+def _wave_in_noise(n, length, freq, rs, level=0.0):
+    t = np.arange(length)
+    return (level + rs.randn(n, length)
+            + 0.7 * np.cos(2 * np.pi * freq * t / length
+                           + 6 * rs.rand(n, 1)))
+
+
+def _zero_mean_case(name):
+    """``(x, chain, the same in float64, freq, epsilon)`` of a case."""
+    from bolt_tpu.ops import normalize
+    rs = np.random.RandomState(49)
+    if name == "the-cells-closed-form":
+        return (_cell_like(48, 10240, 16, rs),
+                lambda b: detrend(normalize(b, perc=20.0), order=5),
+                lambda x: _detrend64(5)(_dff64(x)), 16, 0.0)
+    if name in ("a-level-of-1e6-center", "a-level-of-1e6-zscore"):
+        x = _wave_in_noise(48, 2048, 8, rs, level=1e6)
+        if name.endswith("center"):
+            return x, center, _center64, 8, 0.0
+        return x, zscore, _zscore64, 8, 0.0
+    if name == "a-steep-ramp-detrend-1":
+        x = (_wave_in_noise(48, 2048, 8, rs)
+             + np.linspace(0.0, 1e5, 2048) * rs.uniform(0.5, 2.0, (48, 1)))
+        return x, lambda b: detrend(b, order=1), _detrend64(1), 8, 0.0
+    if name in ("a-spike-first-detrend-1", "a-spike-first-center"):
+        x = _wave_in_noise(48, 2048, 8, rs)
+        x[:, 0] += 1e4
+        if name.endswith("center"):
+            return x, center, _center64, 8, 0.0
+        return x, lambda b: detrend(b, order=1), _detrend64(1), 8, 0.0
+    if name in ("a-constant-record-center", "a-constant-record-detrend-1"):
+        x = np.full((6, 512), 37.3)
+        if name.endswith("center"):
+            return x, center, _center64, 4, 1e-6
+        return x, lambda b: detrend(b, order=1), _detrend64(1), 4, 1e-6
+    length = {"odd-length": 2047, "even-length": 2048,
+              "odd-length-last-bin": 2047, "the-nyquist-bin": 2048}[name]
+    freq = length // 2 if name.endswith("bin") else 8
+    x = _wave_in_noise(48, length, freq, rs)
+    if 2 * freq == length:
+        x = x + np.cos(np.pi * np.arange(length))
+    return x, lambda b: detrend(b, order=3), _detrend64(3), freq, 0.0
+
+
+def _coherence_and_phase64(y, freq, epsilon):
+    co = np.fft.rfft(_center64(y), axis=-1)
+    energy = np.sum(np.abs(co[..., 1:]) ** 2, axis=-1)
+    return (np.abs(co[..., freq]) / (np.sqrt(energy) + epsilon),
+            np.angle(co[..., freq]))
+
+
+def _centring_route(d, freq, epsilon):
+    """``fourier`` of the deferred ``d`` with its own pass for the mean,
+    whatever ``d``'s chain says: the bare record function, mapped by
+    hand."""
+    from bolt_tpu.ops import series
+    out = series._apply_map(d, series._fourier_fn(freq, 0, float(epsilon)))
+    return tuple(series._apply_map(out, series._pick_fn(0, i))
+                 for i in (0, 1))
+
+
+@pytest.mark.parametrize("name", [
+    "the-cells-closed-form", "a-level-of-1e6-center",
+    "a-level-of-1e6-zscore", "a-steep-ramp-detrend-1",
+    "a-spike-first-detrend-1", "a-spike-first-center",
+    "a-constant-record-center", "a-constant-record-detrend-1",
+    "odd-length", "even-length", "odd-length-last-bin", "the-nyquist-bin"])
+def test_fourier_behind_a_zero_mean_parent_reads_as_the_centring_route(
+        mesh, name):
+    from bolt_tpu.ops import fourier
+    x, chain, chain64, freq, epsilon = _zero_mean_case(name)
+    x = x.astype(np.float32)
+    b = bolt.array(x, mesh)
+    fused = fourier(chain(b), freq=freq, epsilon=epsilon)
+    centred = _centring_route(chain(b), freq, epsilon)
+    # the record functions in front of the pick: without, and with, a mean
+    assert [getattr(pair[0]._chain[1][-2], "centred_by_parent", None)
+            for pair in (fused, centred)] == [0, None]
+    fcoh, fph = (h.toarray() for h in fused)
+    ccoh, cph = (h.toarray() for h in centred)
+    assert fcoh.dtype == fph.dtype == np.float32
+    coh64, ph64 = _coherence_and_phase64(
+        chain64(x.astype(np.float64)), freq, epsilon)
+    if name.startswith("a-constant-record"):
+        # no energy: what the parent's rounding left is a few ulp of the
+        # level and far under the guard; an empty bin has no angle
+        assert np.max(np.abs(fcoh)) < 1e-3 and np.isfinite(fph).all()
+        assert np.max(np.abs(ccoh)) < 1e-3
+        return
+    # a few ulp of a coherence (at most 1) and of an angle (at most pi)
+    assert np.max(np.abs(fcoh - ccoh)) < 6e-7
+    # a spike is every bin alike, its own among them: 1 / sqrt(L / 2)
+    tuned = coh64 >= (0.02 if name.startswith("a-spike-first") else 0.3)
+    assert tuned.sum() >= 8
+    assert np.max(_turn(fph, cph.astype(np.float64))[tuned]) < 2e-6
+    # and no further from the float64 reading than the centring route is
+    # (the steep ramp costs both 1e-3: float32 cancels 1e5 against 1)
+    for got, want, far in ((fcoh, coh64, np.abs(ccoh - coh64)),
+                           (fph, ph64, _turn(cph, ph64))):
+        off = (np.abs(got - want) if want is coh64
+               else _turn(got, want))[tuned]
+        assert np.max(off) < 1.5 * np.max(far[tuned]) + 5e-7
+
+
+@pytest.fixture
+def matrix_fit(monkeypatch):
+    """Every device ``detrend`` made inside takes its fit out by the thin
+    matrix product, whatever its order (the spelling above the bound)."""
+    from bolt_tpu.ops import series
+    monkeypatch.setattr(series, "_FIT_TERMS_ON_VPU", 0)
+    series._detrend_fn.cache_clear()
+    yield
+    series._detrend_fn.cache_clear()
+
+
+@pytest.mark.parametrize("order", [0, 1, 5, 15])
+@pytest.mark.parametrize("name", [
+    "the-cells-closed-form", "a-steep-ramp-detrend-1",
+    "a-spike-first-detrend-1", "odd-length", "even-length"])
+def test_the_fit_by_horners_rule_is_the_matrix_products(mesh, matrix_fit,
+                                                        name, order):
+    # one polynomial, two ways to evaluate it in float32 from the same
+    # coefficients: they part by a few ulp of what they sum, and the
+    # bound between them (ops/series.py :: _FIT_TERMS_ON_VPU) is about
+    # speed alone
+    from bolt_tpu.ops import series
+    x = _zero_mean_case(name)[0].astype(np.float32)
+    if name == "the-cells-closed-form":
+        x = x[:, :2048] / np.float32(4096)
+    b = bolt.array(x, mesh)
+    by_product = detrend(b, order=order).toarray()
+    series._detrend_fn.cache_clear()
+    series._FIT_TERMS_ON_VPU = order + 1        # the fixture puts it back
+    by_horner = detrend(b, order=order).toarray()
+    assert by_horner.dtype == by_product.dtype == np.float32
+    # the size of what either sums: the data and the polynomial's terms
+    # (at order 15 the monomials' coefficients are thousands of times the
+    # data, with signs that cancel: both lose those digits alike)
+    t = np.linspace(-1.0, 1.0, x.shape[-1])
+    van = np.abs(np.vander(t, order + 1, increasing=True))
+    coef = x.astype(np.float64) @ np.linalg.pinv(
+        np.vander(t, order + 1, increasing=True)).T
+    size = np.max(np.abs(x) + np.abs(coef) @ van.T, axis=-1, keepdims=True)
+    ulp = np.finfo(np.float32).eps
+    assert np.max(np.abs(by_horner - by_product) / size) < 8 * ulp
+    want = _detrend64(order)(x.astype(np.float64))
+    far = np.max(np.abs(by_product - want) / size)
+    assert np.max(np.abs(by_horner - want) / size) < 1.5 * far + 8 * ulp
+
+
+def _own_map(v):
+    return v * 2.0
+
+
+@pytest.mark.parametrize("name,chain,axis,engages", [
+    ("detrend-0", lambda b: detrend(b, order=0, axis=1), 1, True),
+    ("detrend-5", lambda b: detrend(b, order=5, axis=1), 1, True),
+    ("detrend-9", lambda b: detrend(b, order=9, axis=1), 1, True),
+    ("center", lambda b: center(b, axis=1), 1, True),
+    ("zscore", lambda b: zscore(b, axis=-1), 1, True),
+    ("center-behind-a-map", lambda b: center(b.map(_own_map), axis=1), 1,
+     True),
+    ("stored-data", lambda b: b, 1, False),
+    ("another-axis", lambda b: center(b, axis=0), 1, False),
+    ("a-map-behind-center", lambda b: center(b, axis=1).map(_own_map), 1,
+     False),
+    ("a-cast-behind-center",
+     lambda b: center(b, axis=1).astype(np.float32), 1, False),
+    ("the-residual-cached",       # the local backend has nothing to cache
+     lambda b: getattr(detrend(b, order=1, axis=1), "cache", lambda: None)()
+     or detrend(b, order=1, axis=1), 1, False),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_which_fourier_takes_its_parents_word_for_the_mean(mesh, name,
+                                                           chain, axis,
+                                                           engages):
+    # decided from the argument's deferred chain, its LAST stage and the
+    # same axis; whatever else comes centres as a bare fourier does, and
+    # analysis.explain says which from the function's own attribute
+    from bolt_tpu import analysis
+    from bolt_tpu.ops import fourier
+    rs = np.random.RandomState(7)
+    x = (50.0 + _wave_in_noise(12 * 3, 64, 5, rs)).reshape(12, 3, 64)
+    coh, ph = fourier(chain(bolt.array(x, mesh)), freq=5, axis=axis)
+    took = getattr(coh._chain[1][-2], "centred_by_parent", None)
+    assert took == (axis if engages else None)
+    said = str(analysis.explain(coh))
+    assert ("centred by its parent: no pass for the mean" in said) == engages
+    lcoh, lph = fourier(chain(bolt.array(x)), freq=5, axis=axis)
+    assert allclose(coh.toarray(), lcoh.toarray(), rtol=1e-6, atol=1e-7)
+    assert np.max(_turn(ph.toarray(), lph.toarray())) < 1e-5
+
+
+def test_the_counter_counts_traces_without_a_mean(mesh):
+    from bolt_tpu import engine
+    from bolt_tpu.ops import fourier
+    x = _wave_in_noise(16, 93, 11, np.random.RandomState(3), level=5.0)
+    b = bolt.array(x, mesh)
+    c0 = engine.counters()["fourier_centred_by_parent"]
+    fourier(b, freq=11)[0].toarray()
+    fourier(center(bolt.array(x)), freq=11)[0].toarray()    # local: NumPy
+    assert engine.counters()["fourier_centred_by_parent"] == c0
+    fourier(center(b), freq=11)[0].toarray()
+    assert engine.counters()["fourier_centred_by_parent"] > c0
+
+
+def test_fourier_of_a_cached_residual_centres_and_reads_the_same(mesh):
+    # no chain to read once the residual is an array: it centres, and
+    # differs from the fused route by rounding alone
+    from bolt_tpu.ops import fourier
+    x, chain, _, freq, _ = _zero_mean_case("a-steep-ramp-detrend-1")
+    b = bolt.array(x.astype(np.float32), mesh)
+    fcoh, fph = (h.toarray() for h in fourier(chain(b), freq=freq))
+    kept = chain(b).cache()
+    assert not kept.deferred
+    kcoh, kph = (h.toarray() for h in fourier(kept, freq=freq))
+    assert np.max(np.abs(fcoh - kcoh)) < 6e-7
+    assert np.max(_turn(fph, kph.astype(np.float64))) < 2e-6
+
+
 def test_normalize_parity(mesh):
     from bolt_tpu.ops import normalize
     rs = np.random.RandomState(23)

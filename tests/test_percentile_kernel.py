@@ -452,14 +452,17 @@ def fallback_program(name):
 
 # what they read as at the parent commit (57398e9, jax 0.9.0): printed
 # there by ``fallback_program`` under ``tests/conftest.py``.  The two
-# ``tuning`` chains are PR 44's text: that PR took the FFT out of
-# ``fourier``'s record function, the chain's last map, and nothing out
-# of the selection's passes, which the third case still holds to 57398e9
+# ``tuning`` chains are PR 49's text: that PR took ``detrend``'s fit out
+# by Horner's rule and the mean's pass out of the ``fourier`` behind it,
+# the chain's last two maps (PR 44's text, with the FFT out of
+# ``fourier``: d78126b447142f7d... / d3db665c551c4f7b...), and nothing
+# out of the selection's passes, which the third case still holds to
+# 57398e9
 PARENT_PROGRAMS = {
     "tuning-whole": [
-        "d78126b447142f7d1ece4be576d88ca6866782cf966cf0de80e80bf5491c0033"],
+        "ebee1c6ea890d60f14dbcd311cb8f64dc891b9257a391c48ee3192081c459c7b"],
     "tuning-blocked": [
-        "d3db665c551c4f7bc64a45eac87763cad41afaa6a5aba4c12d11610cf2aefcb5"],
+        "580333acf5336368850d9fe331be04b9c9ed4042983b0c7f71d6c20fc30901be"],
     "normalize-an-inner-axis": [
         "d29e7810ad7cceb60e30c8c72eab59bec4291ea7a868ca2ab12fa2a429e548b3"],
 }

@@ -459,7 +459,11 @@ def test_explain_names_the_shared_parent(one_device, little_hbm):
     text = analysis.explain(coh)
     assert since(start)["dispatches"] == 0
     stage = rep.stages[3]                       # fourier's map, of 4
-    assert stage.note == "shared parent: materialised once for 2 consumers"
+    # behind a detrend, it says so first (PR 49)
+    centred = ("centred by its parent: no pass for the mean, one reader "
+               "of the parent's result; ")
+    assert stage.note == (centred + "shared parent: materialised once for "
+                          "2 consumers")
     assert "materialised once for 2 consumers" in str(text)
     # the forecast blocks are the parent's program's
     note, = [d for d in rep.diagnostics if d.code == "BLT018"]
@@ -469,8 +473,8 @@ def test_explain_names_the_shared_parent(one_device, little_hbm):
     assert "blocked: %d blocks" % plan.blocks in note.message
     coh.toarray()
     assert analysis.check(ph).stages[3].note == (
-        "shared parent: materialised once, its result kept for the 1 "
-        "still deferred")
+        centred + "shared parent: materialised once, its result kept for "
+        "the 1 still deferred")
     # one consumer: nothing to say
     alone = tuning(b)[0]
     assert not any("shared parent" in (s.note or "")
@@ -539,15 +543,17 @@ def single_consumer_program(mesh, cell):
 
 # what they read as at the parent commit (85d0d52, jax 0.9.0): printed
 # there by ``single_consumer_program`` under ``tests/conftest.py``.  The
-# tuning chain's text is PR 44's, under the engine key it had: that PR
-# took the FFT out of ``fourier``'s record function, the chain's last map
+# tuning chain's text is PR 49's, under the engine key it has: that PR
+# spelt ``detrend``'s fit element-wise and took the mean's pass out of a
+# ``fourier`` behind it, the chain's last two maps (PR 44's text, with
+# the FFT out of ``fourier``: 3ca6f753dc6fd6aa / b0cd407b4582c347...)
 PARENT_PROGRAMS = {
     "three-maps": {
         "4e42b4d2a6a0b9d7":
         "6a407a87d54afd055b4c18d333db192d63f5faf22062374a7b509bdef320a1a2"},
     "tuning-one-handle": {
-        "3ca6f753dc6fd6aa":
-        "b0cd407b4582c347387673c7c0254a35f86973967eee4a39f9f145fac74a34b9"},
+        "cd1ca571a63bba88":
+        "d0768b8fd3abda321929786adf27ebcadcd288580e194c175323763cb7d44187"},
     "v+1": {"ccbde19964e72a4a":
             "26d723e7fcc2eb9405883b047b615105f311451d5688368ffc081acea2001d31"},
     "q1q6-product": {
