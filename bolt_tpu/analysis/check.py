@@ -1221,7 +1221,11 @@ def _check_stream(arr, target, stages, diags):
                 "mask without materialising, any other consumer "
                 "materialises the whole source" % n))
             dynamic = True
-            break
+            # behind a filter come record-wise maps alone: they read
+            # the flattened records and keep the dynamic row count
+            aval = jax.ShapeDtypeStruct(out_shape, aval.dtype)
+            walk_split = 1
+            continue
         label = "%s [streamed]" % _stream.stage_label(stage)
         try:
             nxt = _stream.stage_aval(stage, walk_split, aval)
@@ -1255,12 +1259,12 @@ def _check_stream(arr, target, stages, diags):
         aval = nxt
         stages.append(Stage(idx, label, aval.shape, np.dtype(aval.dtype),
                             walk_split, _spec(mesh, aval.shape,
-                                              walk_split)))
+                                              walk_split), dynamic=dynamic))
         idle_seen = _idle_device_check(mesh, aval.shape, walk_split, idx,
                                        diags, idle_seen)
     else:
         # every stage traced: what taking the mapped result whole does
-        if src.stages and not _stream.has_swap(src):
+        if src.stages and not dynamic and not _stream.has_swap(src):
             _note_collect(src, len(src.stages), diags)
     return Report(target + ", streaming (out-of-core)", stages, diags,
                   dynamic=dynamic)

@@ -280,6 +280,14 @@ _SCHEMA = {
                                   # first key as an operand (a with_keys
                                   # map on a streamed source; 0 where it
                                   # fell back to materialising)
+    "stream_group_slabs": 0,      # slabs folded by a grouped terminal
+                                  # (ops.segment_reduce by a label
+                                  # function on a streamed source; 0
+                                  # where it materialised)
+    "stream_thin_slabs": 0,       # slabs of thin records that went up as
+                                  # a dense view of their bytes and were
+                                  # re-seated by their slab program
+                                  # (stream.thin_records)
 }
 
 _COUNTERS = _metrics.registry().group("engine", _SCHEMA)
@@ -774,7 +782,8 @@ def record_resplit_view():
 def record_filter_fused():
     """One deferred filter was folded into the program of the terminal
     that read it (``tpu/array.py :: _launch_filter_terminal``, span
-    ``array.filter_stat``): one pass, no survivor buffer."""
+    ``array.filter_stat``; a streamed filter into its run's slab
+    programs, ``stream.execute``): one pass, no survivor buffer."""
     _COUNTERS.add("filters_fused")
 
 
@@ -1013,7 +1022,7 @@ def record_checkpoint(nbytes, seconds):
 
 
 def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
-                  uploaders=1, inflight=1, keyed=0):
+                  uploaders=1, inflight=1, keyed=0, group=0, thin=0):
     """Tally one completed streamed run (bolt_tpu.stream executor); the
     keys apply atomically — a snapshot can never see a run's wall time
     without its overlap.  Called by the run's own thread as the run ends,
@@ -1022,12 +1031,16 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
     observed concurrent uploader high-water, ``inflight`` its
     dispatched-but-unconfirmed slab-program high-water; both (and the
     depth) keep process maxima.  ``keyed``: of ``chunks``, the slabs
-    whose program took the slab's first key as an operand."""
+    whose program took the slab's first key as an operand; ``group``:
+    those a grouped terminal folded; ``thin``: those that went up dense
+    and were re-seated on the device."""
     _COUNTERS.update(_maxima={"stream_prefetch_depth": int(depth),
                               "stream_upload_threads": int(uploaders),
                               "stream_inflight_high_water": int(inflight)},
                      stream_chunks=int(chunks),
                      stream_keyed_slabs=int(keyed),
+                     stream_group_slabs=int(group),
+                     stream_thin_slabs=int(thin),
                      stream_ingest_seconds=ingest_s,
                      stream_compute_seconds=compute_s,
                      stream_wall_seconds=wall_s,

@@ -4,8 +4,8 @@ run slab by slab through one ingest pool.
 A lazy :class:`StreamSource` describes host-resident data as a sequence
 of record *slabs* (consecutive blocks along the first key axis) plus a
 chain of device-side stages (per-record maps, chunked maps, stacked
-maps, a trailing filter predicate, a recorded swap).  Every streamed
-run is the same four pieces::
+maps, a filter predicate and the record-wise maps called on it since,
+a recorded swap).  Every streamed run is the same four pieces::
 
     StreamSource -> _Run -> _IngestPool -> consumer: fold | place | spill
 
@@ -18,7 +18,9 @@ run is the same four pieces::
 * the **ingest pool** (:class:`_IngestPool`) turns host blocks into
   uploaded slabs.  For random-access ``fromcallback`` sources each of N
   workers produces AND uploads its own slab (per-device sub-blocks via
-  ``parallel.sharding.device_placements``), so one CPU thread is never
+  ``parallel.sharding.device_placements``; a slab of THIN records for
+  one device as a dense view of its bytes, which its slab program
+  re-seats: :func:`thin_records`), so one CPU thread is never
   the bottleneck feeding many chips; sequential ``fromiter`` sources
   keep one produce+upload thread.  A **re-sequencer** (:class:`_Reseq`)
   hands slabs to the consumer strictly in slab order whatever order the
@@ -37,7 +39,9 @@ run is the same four pieces::
   acc)``: half the fold dispatches), and pair-partials above level 0
   combine as a pairwise tree (``add``/``func`` for ``sum``/``reduce``,
   a Welford/Chan ``n, μ, M2`` merge for ``mean``/``var``/``std``:
-  power-of-two slab counts keep the Chan denominators exact).
+  power-of-two slab counts keep the Chan denominators exact; component
+  by component for the tuples of a fused multi-stat group and of
+  ``ops.segment_reduce`` by a label function, :func:`maybe_group`).
   :func:`_resolve_one_swap` PLACES each slab into a resident re-keyed
   array (a recorded swap, or :func:`collect`'s mapped result) or SPILLS
   its buckets to files that stream again as a fresh source
@@ -560,7 +564,54 @@ def transfer(x, sharding=None, wait=False):
     return out
 
 
-def _upload_slab(block, mesh, split):
+_LANES = 128        # the lanes of the chip's (8, 128) tile of 32-bit words
+
+
+def thin_records(shape, dtype):
+    """Whether slabs of a source of ``shape``/``dtype`` go up DENSE: one
+    key axis and one value axis of 32-bit elements whose extent pads by
+    more than two under the 128 lanes of the chip's tile (``c < 64``).
+    The device holds such a slab with the rows on the lanes
+    (``f32[n,7]{0,1:T(8,128)}``), and the runtime transposes the loader's
+    row-major block on the HOST on its way there, at two thirds of what
+    the link carries (8.9 against 13.8 GB/s with two copies in flight:
+    PERF.md section 6, PR 51).  The same bytes as ``(n / 128, 128 * c)``
+    pad nothing and go up as fat records do; the slab program gives them
+    their shape on the device (:func:`_reseat`).  The rule reads the
+    record alone: nothing a caller sets."""
+    return (len(shape) == 2 and np.dtype(dtype).itemsize == 4
+            and 2 * int(shape[1]) < _LANES)
+
+
+def _dense_views(block):
+    """The rows of a C-contiguous ``(n, c)`` block as zero-copy views
+    that pad nothing: the whole groups of 128 rows as ``(n // 128,
+    128 * c)``, then the rows past them as they are (under 128 of them:
+    once a slab, a few KB)."""
+    n, c = block.shape
+    whole = n // _LANES * _LANES
+    views = [block[:whole].reshape(whole // _LANES, _LANES * c)]
+    if whole < n:
+        views.append(block[whole:])
+    return views
+
+
+def _reseat(parts):
+    """The ``(n, c)`` slab of :func:`_dense_views`' uploads, traced as the
+    slab program's first operation: ONE copy of a slab on the device
+    (0.7 ms of 64 MiB beside 4.9 ms of link; a plain ``reshape(n, c)``
+    goes through the row-major tiled form, seven padded to 128 lanes, and
+    costs GBs of temp: compiled for the v5e, ISSUE 51)."""
+    dense = parts[0]
+    groups, c = dense.shape[0], dense.shape[1] // _LANES
+    x = dense.reshape(groups, _LANES, c).transpose(2, 0, 1).reshape(
+        c, groups * _LANES).T
+    if len(parts) > 1:
+        x = jnp.concatenate([x, parts[1]], axis=0)
+    return x
+
+
+def _upload_slab(block, mesh, split, dense=False):
     """Upload ONE host slab as its per-device sub-blocks and assemble
     the global sharded array — the uploader-pool hot path.
 
@@ -572,11 +623,12 @@ def _upload_slab(block, mesh, split):
     detail, not payload), and every sub-block is blocked on before the
     seconds are recorded, so ``transfer_seconds`` stays honest.  The
     degenerate case of :func:`_upload_slab_mh` — the local range is the
-    whole slab."""
-    return _upload_slab_mh(block, mesh, split, block.shape, 0)
+    whole slab.  ``dense``: a slab of thin records for ONE device goes up
+    as :func:`_dense_views` of it, a tuple the slab program re-seats."""
+    return _upload_slab_mh(block, mesh, split, block.shape, 0, dense)
 
 
-def _upload_slab_mh(block, mesh, split, slab_shape, axis0_off):
+def _upload_slab_mh(block, mesh, split, slab_shape, axis0_off, dense=False):
     """Upload THIS PROCESS's sub-block of one slab and assemble the
     global sharded array — the ONE uploader hot path (single-process
     through :func:`_upload_slab`, pod-scale directly under the
@@ -598,19 +650,26 @@ def _upload_slab_mh(block, mesh, split, slab_shape, axis0_off):
     try:
         sharding, placements = _sh.device_placements(mesh, slab_shape,
                                                      split)
-        parts = []
-        for dev, index in placements:
-            lo0, hi0, _ = index[0].indices(slab_shape[0])
-            local = (slice(lo0 - axis0_off, hi0 - axis0_off),) \
-                + tuple(index[1:])
-            parts.append(jax.device_put(block[local], dev))
-        for p in parts:
-            p.block_until_ready()
-        out = _sh.assemble_from_parts(slab_shape, sharding, parts)
+        if dense:
+            (dev, _), = placements          # one device holds the slab
+            out = tuple(jax.device_put(v, dev) for v in _dense_views(block))
+            jax.block_until_ready(out)
+            nparts = 1
+        else:
+            parts = []
+            for dev, index in placements:
+                lo0, hi0, _ = index[0].indices(slab_shape[0])
+                local = (slice(lo0 - axis0_off, hi0 - axis0_off),) \
+                    + tuple(index[1:])
+                parts.append(jax.device_put(block[local], dev))
+            for p in parts:
+                p.block_until_ready()
+            out = _sh.assemble_from_parts(slab_shape, sharding, parts)
+            nparts = len(parts)
         nbytes = int(block.nbytes)
-        _engine.record_transfer(nbytes, _clock() - t0, parts=len(parts))
+        _engine.record_transfer(nbytes, _clock() - t0, parts=nparts)
         if sp is not None:
-            sp.set(bytes=nbytes, parts=len(parts))
+            sp.set(bytes=nbytes, parts=nparts)
     finally:
         _obs.end(sp)
     return out
@@ -916,9 +975,15 @@ def result_state(source):
     dynamic = False
     for stage in source.stages:
         if stage[0] == "filter":
+            # behind a filter come record-wise maps alone (post_map_stage):
+            # they read the flattened records, one key axis
             pred = stage[1]
             dynamic = True
-            break                     # a filter is always the last stage
+            aval = jax.ShapeDtypeStruct(
+                (prod(aval.shape[:split]),) + tuple(aval.shape[split:]),
+                aval.dtype)
+            split = 1
+            continue
         aval = stage_aval(stage, split, aval)
         if stage[0] == "swap":
             split = stage[2]          # the swap re-draws the key|value cut
@@ -954,9 +1019,25 @@ def map_stage(arr, func):
 
 
 def filter_stage(arr, pred):
-    """Record a trailing filter predicate (lazy, dynamic shape)."""
+    """Record a filter predicate (lazy, dynamic shape): the last stage
+    but for record-wise maps called on the filter since."""
     from bolt_tpu.tpu.array import BoltArrayTPU
     return BoltArrayTPU._streamed(arr._stream.with_stage(("filter", pred)))
+
+
+def post_map_stage(arr, func):
+    """Record a record-wise map called on a streamed FILTER (lazy): a
+    map commutes with the selection, so it becomes a stage behind the
+    predicate, as it joins a resident deferred filter's ``post`` maps
+    (``BoltArrayTPU._map_filter``), and the terminal folds the mask over
+    what it gives.  ``None`` for a callable that does not trace on a
+    record (the caller materialises, and takes the host fallback)."""
+    from bolt_tpu.tpu.array import _TRACE_ERRORS
+    try:
+        out = map_stage(arr, func)      # walks the chain abstractly
+    except _TRACE_ERRORS:
+        return None
+    return None if out is NotImplemented else out
 
 
 def chunked_map_stage(view, func, dtype):
@@ -1160,6 +1241,35 @@ def maybe_reduce(arr, func, axes, keepdims):
     return execute(arr, "reduce", rfunc=func)
 
 
+def maybe_group(arr, label, value, nseg, op):
+    """Stream ``ops.segment_reduce`` by a label FUNCTION when the source
+    allows it: ``(folded, counts)`` as the resident terminal returns
+    them (``BoltArrayTPU._grouped_fold``), or NotImplemented (the caller
+    materialises, as it always did).  What streams: one key axis, stages
+    that are record-wise maps and at most one filter (its mask is the
+    fold's predicate), a fold whose partials merge, one process."""
+    from bolt_tpu.tpu import fold as _fold
+    from bolt_tpu.tpu.array import _FOLDS
+    src = arr._stream
+    if src is None or op not in _FOLDS:
+        return NotImplemented
+    if has_swap(src):
+        src = _swap_resolved(arr)     # see maybe_stat
+        if src is None:
+            return NotImplemented
+    if src.split != 1 or _multihost.mesh_process_count(src.mesh) > 1:
+        return NotImplemented
+    maps = tuple(s for s in src.stages if s[0] != "filter")
+    if (any(s[0] != "map" for s in maps)
+            or stage_extras(maps) != (False, ())
+            or not _fold._plain_callables(tuple(s[1] for s in maps))):
+        return NotImplemented
+    st = result_state(src)
+    if st.n == 0:
+        return NotImplemented           # empty: materialised path's rules
+    return execute(arr, "group", group=(op, label, value, int(nseg)))
+
+
 # ---------------------------------------------------------------------
 # per-slab programs and on-device partial merges
 # ---------------------------------------------------------------------
@@ -1174,8 +1284,11 @@ def _combine(terminal, rfunc, a, b, comps=None):
     formula, vectorised over the value block).  ``terminal="multi"``
     (the fused multi-stat accumulator, bolt_tpu/tpu/multistat.py) merges
     a TUPLE of components — each through this same function, so the
-    fused tuple merge and the standalone merges share one arithmetic."""
-    if terminal == "multi":
+    fused tuple merge and the standalone merges share one arithmetic.
+    ``terminal="group"`` (the grouped fold, :func:`maybe_group`) is such
+    a tuple too: a group's every leaf by the fold's own merge, then the
+    int32 counts, which add."""
+    if terminal in _TUPLED:
         return tuple(_combine(_COMP_MERGE[c], rfunc, x, y)
                      for c, x, y in zip(comps, a, b))
     if terminal == "sum":
@@ -1201,6 +1314,9 @@ def _combine(terminal, rfunc, a, b, comps=None):
 # mean/var/std member of a fused group)
 _COMP_MERGE = {"sum": "sum", "min": "min", "max": "max",
                "moments": "moments"}
+# terminals whose partial is a TUPLE of components, each with a merge of
+# its own (``comps``): the fused multi-stat group and the grouped fold
+_TUPLED = ("multi", "group")
 
 
 def _terminal_partial(terminal, flat, mask, mfull, vshape, n, rfunc,
@@ -1285,8 +1401,62 @@ def _terminal_partial(terminal, flat, mask, mfull, vshape, n, rfunc,
     return cnt, mu, m2
 
 
+def _split_at_filter(stages):
+    """``(head, pred, post)``: the stages in front of the filter, its
+    predicate (``None``: no filter) and the record-wise maps behind it."""
+    for i, stage in enumerate(stages):
+        if stage[0] == "filter":
+            return stages[:i], stage[1], stages[i + 1:]
+    return stages, None, ()
+
+
+def _promoted(value):
+    """``value`` with every leaf that is not inexact cast to the canonical
+    float, as a grouped ``mean`` casts it before it sums
+    (``tpu/array.py :: _grouped_fold_expr``): a slab's partial of a mean
+    is the SUMS of that.  Made once a slab program's build, which the
+    engine keeps; nothing else holds the caller's function."""
+    def promoted(r):
+        floats = jax.dtypes.canonicalize_dtype(np.float64)
+        return jax.tree_util.tree_map(
+            lambda lf: lf if jnp.issubdtype(jnp.result_type(lf),
+                                            jnp.inexact)
+            else jnp.asarray(lf).astype(floats),
+            r if value is None else value(r))
+    return promoted
+
+
+def _group_partial(group, funcs, pred, post, x):
+    """One slab's partial of the grouped fold ``group`` (``(op, label,
+    value, nseg)``) over the slab ``x`` as uploaded: the flat tuple of
+    the folded value's leaves, then the int32 counts.  It IS the resident
+    terminal's fold (``tpu/array.py :: _grouped_fold``), through the same
+    entry, ``fold.fold_records``, with the slab as its stored table: the
+    maps ``funcs``, the predicate, the maps ``post`` behind it, the label
+    and the value are traced into one pass, which over thin records in a
+    program for one TPU device is the ``thin_fold`` kernel.  A ``mean``
+    folds SUMS here; the quotient is the finalise's."""
+    from bolt_tpu.tpu import fold as _fold
+    from bolt_tpu.tpu.array import _Filter, _chain_apply
+    op, label, value, nseg = group
+    if op == "mean":
+        op, value = "sum", _promoted(value)
+    if pred is None:
+        src = _fold.Chain(funcs, 1)
+    else:
+        rec = jax.eval_shape(lambda d: _chain_apply(funcs, 1, d), x)
+        one = jax.ShapeDtypeStruct(rec.shape[1:], rec.dtype)
+        src = _Filter(None, funcs, pred, 1, tuple(one.shape), x.shape[0],
+                      one.dtype, post, jax.eval_shape(
+                          lambda r: _chain_apply(post, 0, r), one))
+    folded, counts = _fold.fold_records(
+        _fold.Fold(src, group=(op, label, value, nseg)), x)
+    return tuple(jax.tree_util.tree_leaves(folded)) + (counts,)
+
+
 def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
-                  comps=None, sharded=False, codec_obj=None):
+                  comps=None, sharded=False, codec_obj=None, group=None,
+                  thin=False):
     """The ONE compiled program each slab runs: device-side stages +
     (masked) terminal partial, with the slab buffer DONATED so the ring
     recycles its memory.  ``fused=True`` is the level-0 fold fusion: the
@@ -1297,6 +1467,10 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
     the SAME single read of the slab — the streamed half of the fused
     multi-stat layer (bolt_tpu/tpu/multistat.py); each component traces
     the exact standalone expression via :func:`_terminal_partial`.
+    ``terminal="group"`` emits the grouped fold ``group``'s partial, a
+    tuple likewise (:func:`_group_partial`).  ``thin``: the uploaded
+    buffer is the dense form of a slab of thin records
+    (:func:`_dense_views`), given its shape by :func:`_reseat` first.
 
     ``codec_obj`` (ISSUE 14) is the ingest codec whose device-side
     DECODE is fused in as the program's FIRST traced expression: the
@@ -1322,11 +1496,7 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
     starts).  Engine-cached per (stages, terminal, slab geometry,
     fused, comps, codec, process topology): uniform slabs compile
     exactly once per variant PER PROCESS."""
-    stages = source.stages
-    pred = None
-    if stages and stages[-1][0] == "filter":
-        pred = stages[-1][1]
-        stages = stages[:-1]
+    stages, pred, post = _split_at_filter(source.stages)
     split = source.split
     mesh = source.mesh
     raw_dtype = source.dtype
@@ -1338,14 +1508,14 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
                   and not sharded and split == 1
                   and mesh.devices.size == 1
                   and _codec_registry().kernel_enabled())
-    keyed, side = stage_extras(stages)
+    keyed, side = stage_extras(stages + post)
     key = ("stream-slab-acc" if fused else "stream-slab", terminal,
            stage_keys(stages), pred, slab_shape, str(source.dtype), split,
            ddof,
            rfunc, comps, mesh,
            _multihost.topology_token() if sharded else None,
            codec_obj.name if codec_obj is not None else None,
-           use_kernel)
+           stage_keys(post), group, thin, use_kernel)
 
     def build():
         axes = _multihost.key_collective_axes(mesh, slab_shape, split) \
@@ -1362,7 +1532,7 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
             key0 = extra[0] if keyed else None
             operands = iter(extra[1:] if keyed else extra)
             if codec_obj is None:
-                x = data
+                x = _reseat(data) if thin else data
             else:
                 if use_kernel:
                     # the opt-in in-register decode-and-reduce: plan
@@ -1379,6 +1549,11 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
                                          delta_ok)
                 else:
                     x = codec_obj.decode(data, (), raw_dtype, delta_ok)
+            if terminal == "group":
+                # record-wise maps alone (maybe_group): the fold's own
+                return _group_partial(
+                    group, tuple(s[1] for s in stages), pred,
+                    tuple(s[1] for s in post), x)
             for stg in stages:
                 x = _stage_apply(stg, split, x, key0, operands)
             vshape = x.shape[split:]
@@ -1387,6 +1562,11 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
             mask = mfull = None
             if pred is not None:
                 mask = _pred_mask(pred, flat)
+                # a map behind the filter reads every record; the mask
+                # then folds what it gave for a dropped one away
+                for stg in post:
+                    flat = _stage_apply(stg, 1, flat, key0, operands)
+                vshape = flat.shape[1:]
                 mfull = mask.reshape((n,) + (1,) * len(vshape))
             if terminal == "multi":
                 return tuple(
@@ -1482,6 +1662,55 @@ def _finalise_program(terminal, shape, dtype, ddof, mesh):
     return _cached_jit(key, build)
 
 
+def _group_avals(source, group):
+    """``(record, value)`` avals of a grouped fold over ``source``: ONE
+    record as the stage chain leaves it, and what ``value`` gives for it
+    (a tree of avals; the record where ``value`` is ``None``)."""
+    from bolt_tpu.tpu.array import _cached_eval_shape
+    st = result_state(source)
+    rec = jax.ShapeDtypeStruct(tuple(st.vshape), st.dtype)
+    value = group[2]
+    if value is None:
+        return rec, rec
+    return rec, _cached_eval_shape(
+        ("segreduce-value", value, tuple(rec.shape), str(rec.dtype)),
+        lambda: jax.eval_shape(value, rec))
+
+
+def _group_comps(source, group):
+    """The merges of a grouped partial's components: the fold's own for
+    every leaf of the value, ``sum`` for the counts."""
+    leaves = jax.tree_util.tree_leaves(_group_avals(source, group)[1])
+    fop = "sum" if group[0] == "mean" else group[0]
+    return (fop,) * len(leaves) + ("sum",)
+
+
+def _finalise_group(folded, group, source):
+    """The folded flat tuple -> ``(folded tree, counts)`` keyed by group,
+    as the resident program returns them; a ``mean``'s sums over its
+    counts (engine-cached)."""
+    from bolt_tpu.tpu.array import _constrain
+    op, nseg = group[0], group[3]
+    tree = jax.tree_util.tree_structure(_group_avals(source, group)[1])
+    mesh = source.mesh
+    sig = tuple((tuple(x.shape), str(x.dtype)) for x in folded)
+    key = ("stream-final-group", op, nseg, tree, sig, mesh)
+
+    def build():
+        def final(*parts):
+            counts = parts[-1]
+            outs = []
+            for out in parts[:-1]:
+                if op == "mean":
+                    out = out / jnp.maximum(counts, 1).astype(
+                        out.dtype).reshape((nseg,) + (1,) * (out.ndim - 1))
+                outs.append(_constrain(out, mesh, 1))
+            return (jax.tree_util.tree_unflatten(tree, outs),
+                    _constrain(counts, mesh, 1))
+        return jax.jit(final)
+    return _cached_jit(key, build)(*folded)
+
+
 class _PairFold:
     """Binary-counter pairwise tree over streamed PAIR partials (level-0
     merges are fused into the odd slab programs): leaf *i* merges at
@@ -1534,7 +1763,7 @@ def _make_fold(terminal, rfunc, comps, mesh, part):
         shape, dtype = part.shape, part.dtype
         return _PairFold(lambda: _merge_program(terminal, shape, dtype,
                                                 rfunc, mesh))
-    if terminal == "multi":
+    if terminal in _TUPLED:
         sig = tuple((tuple(leaf.shape), str(leaf.dtype))
                     for leaf in jax.tree_util.tree_leaves(part))
 
@@ -1559,7 +1788,8 @@ def _stage_token(stage):
                     for x in stage)
 
 
-def _run_fingerprint(source, terminal, ddof, rfunc, specs, codec=None):
+def _run_fingerprint(source, terminal, ddof, rfunc, specs, codec=None,
+                     group=None):
     """Identity of one LOGICAL streamed run for checkpoint matching:
     source geometry + slab plan + stage chain + terminal + ingest
     CODEC, with every user callable (stage funcs, the filter predicate,
@@ -1575,6 +1805,11 @@ def _run_fingerprint(source, terminal, ddof, rfunc, specs, codec=None):
     from bolt_tpu.utils import code_token
     stages = "|".join(_stage_token(s) for s in source.stages)
     members = "|".join("%s:%s" % (n, d) for n, d in specs) if specs else ""
+    if group is not None:
+        # a grouped fold's members: its op, its group count, and the
+        # label and value functions by bytecode like every callable
+        members = "/".join(code_token(x) if callable(x) else repr(x)
+                           for x in group)
     return ("bolt-stream-ckpt-v2", str(terminal), str(ddof),
             code_token(rfunc) if rfunc is not None else "",
             "x".join(str(s) for s in source.shape),
@@ -1806,12 +2041,14 @@ class _IngestPool:
     programs retire, and ALWAYS calls :meth:`close`.  ``noun`` names a
     slab in the retry errors; ``parent`` is the run's span the
     ``stream.ingest`` spans nest under (nesting does not cross
-    threads)."""
+    threads).  ``dense``: the consumer's slab programs take a slab of
+    thin records as :func:`_dense_views` of it (:func:`thin_records`)."""
 
     def __init__(self, run, source, ring, jobs=None, blocks=None, first=0,
-                 noun="slab", parent=None):
+                 noun="slab", parent=None, dense=False):
         self._run = run
         self._source = source
+        self._dense = dense     # the consumer re-seats thin slabs
         self._jobs = jobs
         self._blocks = blocks or source.slabs
         self._first = first
@@ -1935,7 +2172,11 @@ class _IngestPool:
             payload = block
         else:
             payload, side = _encode_slab(run.codec, block, run.delta_ok)
-        if run.mspec is None:
+        if run.mspec is None and self._dense \
+                and payload.shape[0] >= _LANES \
+                and payload.flags.c_contiguous:
+            buf = _upload_slab(payload, source.mesh, source.split, True)
+        elif run.mspec is None:
             # through the module-level name: the tests' patch point
             buf = _upload_slab(payload, source.mesh, source.split)
         else:
@@ -2151,7 +2392,7 @@ def _multi_comps(specs):
 
 
 def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
-            source=None):
+            source=None, group=None):
     """Run a streamed reduction terminal over ``arr``'s source: the
     parallel-ingest, async-dispatch pipeline described in the module
     docstring.  Returns a value-shaped ``BoltArrayTPU`` (``split=0``).
@@ -2164,11 +2405,20 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     shared folded components exactly as its standalone streamed
     terminal would be.  ``source`` overrides ``arr._stream`` for
     callers resolving already-detached pending handles (``arr=None``
-    skips the strict gate — the handle was gated at creation)."""
+    skips the strict gate — the handle was gated at creation).
+
+    ``terminal="group"`` streams ``ops.segment_reduce`` by a label
+    function (:func:`maybe_group`): ``group`` is ``(op, label, value,
+    nseg)``, a slab's partial the flat tuple of the folded leaves and
+    the int32 counts (:func:`_group_partial`), merged component by
+    component as the multi tuple is, and the return value the pair
+    ``(folded tree, counts)`` of bolt arrays keyed by group."""
     from bolt_tpu.tpu.array import BoltArrayTPU
-    comps = _multi_comps(specs) if terminal == "multi" else None
     if source is None:
         source = arr._stream
+    comps = (_multi_comps(specs) if terminal == "multi"
+             else _group_comps(source, group) if terminal == "group"
+             else None)
     if arr is not None:
         _engine.strict_guard(arr, "stream.%s()" % terminal)
     if has_swap(source):
@@ -2185,7 +2435,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     # refuse them
     if codec_obj is not None and not codec_obj.lossless:
         order = terminal in ("min", "max") or (
-            terminal == "multi"
+            terminal in _TUPLED
             and any(c in ("min", "max") for c in comps))
         if order:
             names = [n for n, _ in specs] if specs else [terminal]
@@ -2236,7 +2486,8 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                    _multihost.process_count()))
         ck_fp = _run_fingerprint(
             source, terminal, ddof, rfunc, specs,
-            codec=codec_obj.name if codec_obj is not None else None)
+            codec=codec_obj.name if codec_obj is not None else None,
+            group=group)
         # the MESH's multiprocess answer, not the runtime's: a
         # process-local mesh inside a multi-process runtime checkpoints
         # single-process (its peers are elsewhere; a barrier would hang)
@@ -2278,8 +2529,12 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                         uploaders=nwork, kind=source.kind,
                         **({"codec": codec_obj.name}
                            if codec_obj is not None else {}))
+    # thin records for ONE device go up dense, and the slab program
+    # re-seats them (a codec's wire form and a pod's shards do not)
+    dense = (codec_obj is None and mspec is None and mesh.devices.size == 1
+             and thin_records(source.shape, source.dtype))
     pool = _IngestPool(run, source, ring, jobs=jobs, blocks=blocks,
-                       first=start_slab, parent=run_sp)
+                       first=start_slab, parent=run_sp, dense=dense)
 
     from bolt_tpu.tpu.array import _place_operands
     keyed, side = stage_extras(source.stages)
@@ -2288,6 +2543,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     ingest = 0.0
     compute = 0.0
     nslabs = 0
+    nthin = 0                   # slabs that went up dense
     fold = None
     pend = None                 # even slab's partial awaiting its pair
     pend_bytes = 0              # that slab's arbiter bytes, still held
@@ -2427,8 +2683,15 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 # acquired for the slab, which give_back must mirror
                 ingest += tsec
                 t0 = _clock()
-                wshape = (buf[0].shape if isinstance(buf, tuple)
-                          else buf.shape)
+                thin = dense and isinstance(buf, tuple)
+                if thin:
+                    wshape = (sum(b.shape[0] for b in buf[1:])
+                              + buf[0].shape[0] * _LANES,
+                              buf[0].shape[1] // _LANES)
+                    nthin += 1
+                else:
+                    wshape = (buf[0].shape if isinstance(buf, tuple)
+                              else buf.shape)
                 csp = _obs.begin("stream.compute",
                                  slab=slab_g,
                                  **({"codec": codec_obj.name}
@@ -2473,7 +2736,8 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                     source, terminal, wshape, ddof,
                                     rfunc, fused=fused, comps=comps,
                                     sharded=mspec is not None,
-                                    codec_obj=codec_obj)
+                                    codec_obj=codec_obj, group=group,
+                                    thin=thin)
                                 xsp = _obs.begin("stream.dispatch",
                                                  slab=slab_g)
                                 try:
@@ -2592,6 +2856,8 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 out = fold.result()
             elif terminal == "multi":
                 out = _finalise_multi(fold.result(), comps, specs, mesh)
+            elif terminal == "group":
+                out = _finalise_group(fold.result(), group, source)
             else:
                 n, mu, m2 = fold.result()
                 out = _finalise_program(terminal, mu.shape, mu.dtype,
@@ -2621,7 +2887,13 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         _engine.record_stream(nslabs, ingest, compute, wall, overlap,
                               depth, uploaders=max(pool.high_water, 1),
                               inflight=max(inflight_hw, 1),
-                              keyed=nslabs if keyed else 0)
+                              keyed=nslabs if keyed else 0,
+                              group=nslabs if group is not None else 0,
+                              thin=nthin)
+        if result_state(source).pred is not None:
+            # a filter that ended in this terminal: no buffer was built
+            # for it, as for a resident deferred one
+            _engine.record_filter_fused()
         if run_sp is not None:
             run_sp.set(slabs=nslabs, ingest_s=round(ingest, 6),
                        compute_s=round(compute, 6),
@@ -2630,6 +2902,9 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                        inflight_high_water=max(inflight_hw, 1))
         if terminal == "multi":
             return list(out)              # one jax array per member spec
+        if terminal == "group":
+            wrap = lambda o: BoltArrayTPU(o, 1, mesh)      # noqa: E731
+            return jax.tree_util.tree_map(wrap, out[0]), wrap(out[1])
         return BoltArrayTPU(out, 0, mesh)
     finally:
         if mspec is not None:

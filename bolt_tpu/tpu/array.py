@@ -1049,6 +1049,21 @@ def _moments(name, s1, s2, den, ddof):
     return out
 
 
+def _check_label(label, rec):
+    """A label function on one record ``rec`` (an aval) gives one
+    integer, or this says what it gave."""
+    lab = _cached_eval_shape(
+        ("segreduce-label", label, tuple(rec.shape), str(rec.dtype)),
+        lambda: jax.eval_shape(label, rec))
+    if prod(lab.shape) != 1 or not (
+            np.issubdtype(lab.dtype, np.integer)
+            or lab.dtype == np.bool_):
+        raise ValueError(
+            "a label function must return one integer per record; got "
+            "shape %s dtype %s for value shape %s"
+            % (tuple(lab.shape), lab.dtype, tuple(rec.shape)))
+
+
 def _launch_filter_terminal(fn, base, op, donate):
     """Launch a program that folds a deferred filter into a terminal (a
     statistic, a reduce, the grouped fold): span ``array.filter_stat``
@@ -1747,6 +1762,14 @@ class BoltArrayTPU(BoltArray):
             out = self._map_filter(func, value_shape, dtype)
             if out is not None:
                 return out
+        if self._stream is not None and self._aval is None \
+                and axes == [0] and not with_keys:
+            # a streamed filter: the map joins the stages behind the
+            # predicate, as it joins a resident filter's (no count
+            # sync, nothing materialised)
+            out = self._map_streamed_filter(func, value_shape, dtype)
+            if out is not None:
+                return out
         aligned = self._align(axes)
         split = aligned._split
         kshape = aligned.shape[:split]
@@ -1928,6 +1951,19 @@ class BoltArrayTPU(BoltArray):
         out = BoltArrayTPU(None, 1, self._mesh)
         out._fpending = fp._replace(post=post, out=jax.ShapeDtypeStruct(
             tuple(aval.shape), aval.dtype))
+        return out
+
+    def _map_streamed_filter(self, func, value_shape, dtype):
+        """``map`` on a streamed filter (``stream.post_map_stage``):
+        :meth:`_map_filter` for a lazy out-of-core source.  ``None`` for
+        a callable that does not trace."""
+        out = _streamlib.post_map_stage(self, func)
+        if out is None:
+            return None
+        st = _streamlib.result_state(out._stream)
+        _check_value_shape(value_shape, tuple(st.vshape))
+        if dtype is not None and np.dtype(dtype) != np.dtype(st.dtype):
+            out = _streamlib.post_map_stage(out, _cast_fn(_canon(dtype)))
         return out
 
     def reduce(self, func, axis=(0,), keepdims=False):
@@ -2254,6 +2290,14 @@ class BoltArrayTPU(BoltArray):
         maps; the label and the value are traced into the same program as
         they are."""
         _engine.strict_guard(self, "segment_reduce()")
+        if self._stream is not None:
+            # a lazy out-of-core source: the fold is a terminal of the
+            # streamed executor where the stage chain allows (one key
+            # axis, record-wise maps, a filter); anything else
+            # materialises below, as every other consumer does
+            out = self._grouped_fold_streamed(label, value, nseg, op)
+            if out is not NotImplemented:
+                return out
         fp = self._fpending
         if fp is not None:
             base, source, key, rec = fp.base, fp.geometry(), fp.key(), fp.out
@@ -2262,16 +2306,7 @@ class BoltArrayTPU(BoltArray):
             source = _fold.Chain(funcs, self._split)
             key = (funcs, base.shape, str(base.dtype), self._split)
             rec = jax.ShapeDtypeStruct(self.shape[1:], self.dtype)
-        lab = _cached_eval_shape(
-            ("segreduce-label", label, tuple(rec.shape), str(rec.dtype)),
-            lambda: jax.eval_shape(label, rec))
-        if prod(lab.shape) != 1 or not (
-                np.issubdtype(lab.dtype, np.integer)
-                or lab.dtype == np.bool_):
-            raise ValueError(
-                "a label function must return one integer per record; got "
-                "shape %s dtype %s for value shape %s"
-                % (tuple(lab.shape), lab.dtype, tuple(rec.shape)))
+        _check_label(label, rec)
         mesh = self._mesh
 
         def build():
@@ -2295,6 +2330,28 @@ class BoltArrayTPU(BoltArray):
                 folded, counts = fn(_check_live(base))
         wrap = lambda o: BoltArrayTPU(o, 1, mesh)      # noqa: E731
         return jax.tree_util.tree_map(wrap, folded), wrap(counts)
+
+    def _grouped_fold_streamed(self, label, value, nseg, op):
+        """:meth:`_grouped_fold` over a stream-backed array, slab by slab
+        (``stream.maybe_group``): nothing is materialised, and the span
+        ``group.segment_reduce`` says ``streamed=True``.  NotImplemented
+        where the executor does not take the chain."""
+        st = _streamlib.result_state(self._stream)
+        if st.split == 1:       # the records the executor would fold
+            _check_label(label, jax.ShapeDtypeStruct(tuple(st.vshape),
+                                                     st.dtype))
+        sp = _obs.begin("group.segment_reduce", op=op, segments=nseg,
+                        streamed=True)
+        try:
+            out = _streamlib.maybe_group(self, label, value, nseg, op)
+        except BaseException as exc:
+            _obs.end(sp, error=type(exc).__name__)
+            raise
+        if out is NotImplemented:
+            _obs.cancel(sp)         # not taken: the resident span follows
+        else:
+            _obs.end(sp)
+        return out
 
     def mean(self, axis=None, keepdims=False):
         """Mean over ``axis`` (default: all key axes)."""

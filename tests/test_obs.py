@@ -331,7 +331,8 @@ _EXPECTED_ENGINE_KEYS = {
     "shuffle_bytes": False, "spill_bytes": False,
     "shuffle_seconds": True,
     "stream_collect_slabs": False, "stream_collect_bytes": False,
-    "stream_keyed_slabs": False,
+    "stream_keyed_slabs": False, "stream_group_slabs": False,
+    "stream_thin_slabs": False,
     "stream_alltoall_bytes": False, "stream_upload_parts": False,
 }
 

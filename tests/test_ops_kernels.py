@@ -1214,6 +1214,88 @@ def test_a_stream_s_own_parameters_are_one_thin_fold_on_v5e(v5e_device,
 
 
 # ---------------------------------------------------------------------
+# compile-only: the slab programs of LINEITEM at SF 100 WHOLE, streamed
+# (ISSUE 51; ``lineitem-streamed-1chip.scan_q1q6``).  A slab of thin
+# records arrives as a dense view of its bytes (whole groups of 128 rows,
+# then the rows past them) and the program's first operation re-seats it:
+# Q1's grouped fold is then ONE ``thin_fold`` call a slab, as over the
+# resident table, and nothing holds two slabs of temp
+# ---------------------------------------------------------------------
+
+_SF100 = (600037902, 7)
+
+
+def _streamed_lineitem(v5e_device, query):
+    """``(source, terminal, kwargs)`` of one query over the streamed table,
+    as the public calls hand them to the executor."""
+    from bolt_tpu import stream
+    from bolt_tpu.tpu.array import BoltArrayTPU
+    mesh = _series_mesh(v5e_device)
+    src = stream.StreamSource.from_callback(lambda index: None, _SF100, 1,
+                                            np.float32, mesh)
+    assert src.slab == 2396745 and len(src.slab_ranges()) == 251
+    assert src.slab_ranges()[-1] == (599186250, 600037902)   # 851,652 rows
+    assert stream.thin_records(src.shape, src.dtype)
+    b = BoltArrayTPU._streamed(src)
+    if query == "q6":
+        out = b.filter(_q6_pred).map(_q6_value)
+        assert out.streaming                      # nothing was uploaded
+        return out._stream, "sum", {}
+    group = ("sum", _q1_group, _q1_terms, 6)
+    source = b.filter(_q1_pred)._stream
+    return source, "group", {"group": group,
+                             "comps": stream._group_comps(source, group)}
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["first", "acc-fused"])
+@pytest.mark.parametrize("rows", [2396745, 851652],
+                         ids=["full-slab", "short-tail"])
+@pytest.mark.parametrize("query", ["q6", "q1"])
+def test_a_thin_slab_is_reseated_and_folded_in_place_on_v5e(v5e_device,
+                                                            query, rows,
+                                                            fused):
+    import warnings
+    import jax
+    from bolt_tpu import stream
+    source, terminal, kw = _streamed_lineitem(v5e_device, query)
+    mesh = source.mesh
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    groups, rest = divmod(rows, 128)
+    assert rest                                   # neither slab is whole
+    slab = tuple(jax.ShapeDtypeStruct(shape, np.float32, sharding=where)
+                 for shape in ((groups, 128 * 7), (rest, 7)))
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not")
+        first = stream._slab_program(source, terminal, (rows, 7), None, None,
+                                     thin=True, **kw).lower(slab)
+        if fused:
+            acc = jax.tree_util.tree_map(
+                lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype,
+                                               sharding=where),
+                first.out_info)
+            compiled = stream._slab_program(
+                source, terminal, (rows, 7), None, None, fused=True,
+                thin=True, **kw).lower(slab, acc).compile()
+        else:
+            compiled = first.compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    slab_bytes = rows * 7 * 4
+    # the dense form pads nothing: the argument is the slab's own bytes
+    # (and the partial it merges), not 8/7 of them
+    assert slab_bytes <= mem.argument_size_in_bytes < slab_bytes + 65536
+    assert mem.temp_size_in_bytes < 2 * slab_bytes          # one re-seat
+    assert mem.output_size_in_bytes < 65536
+    if query == "q1":
+        assert len(_kernel_calls(text, "thin_fold")) == 1
+        assert text.count("tpu_custom_call") == 1
+        assert "f32[7,%d]" % rows in text         # the kernel's own view
+    else:
+        assert "tpu_custom_call" not in text      # the masked sum's fusion
+    assert not re.search(r"= \S+ (gather|sort)\(", text)
+
+
+# ---------------------------------------------------------------------
 # compile-only: the streamed swap's place program at the two-photon
 # session's size (ISSUE 32).  The swapped array is the program's own
 # argument handed back: aliased, with a temp of one transposed slab —

@@ -1,0 +1,182 @@
+"""Thin records on the link (ISSUE 51, part B): a slab of a source whose
+records are a few 32-bit values goes up as a dense view of the bytes the
+loader returned and is given its shape by its slab program's first
+operation (``stream.thin_records``, ``_dense_views``, ``_reseat``).  The
+rule reads the record's shape and dtype and nothing a caller sets; a fat
+record's upload, a codec's wire form and a mesh of several devices keep
+what they had.
+"""
+
+import numpy as np
+import pytest
+
+import jax
+
+import bolt_tpu as bolt
+from bolt_tpu import engine, obs, stream
+
+
+@pytest.fixture
+def one():
+    """A one-device mesh: the dense form is one device's."""
+    return jax.sharding.Mesh(np.array(jax.devices()[:1]), ("k",))
+
+
+def _table(rows, cols, dtype, seed=11):
+    rng = np.random.default_rng(seed)
+    return rng.integers(-1000, 1000, size=(rows, cols)).astype(dtype)
+
+
+def _source(x, mesh, chunks=None, **kw):
+    return bolt.fromcallback(lambda idx: x[idx], x.shape, mesh,
+                             dtype=x.dtype, chunks=chunks, **kw)
+
+
+@pytest.mark.parametrize("shape,dtype,thin", [
+    ((1000, 1), np.float32, True), ((1000, 7), np.float32, True),
+    ((1000, 8), np.float32, True), ((1000, 9), np.float32, True),
+    ((1000, 63), np.int32, True), ((1000, 7), np.uint32, True),
+    ((1000, 64), np.float32, False), ((1000, 128), np.float32, False),
+    ((1000, 7), np.float64, False), ((1000, 7), np.int16, False),
+    ((1000, 256, 128), np.float32, False), ((1000, 2, 7), np.float32, False),
+    ((1000,), np.float32, False)])
+def test_the_rule_reads_the_record_alone(shape, dtype, thin):
+    assert stream.thin_records(shape, dtype) is thin
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.int32],
+                         ids=["float32", "int32"])
+@pytest.mark.parametrize("cols", [1, 7, 8, 9])
+@pytest.mark.parametrize("rows", [128, 1024, 129, 1000, 1151],
+                         ids=lambda r: "rows%d" % r)
+def test_a_thin_slab_goes_up_dense_and_reads_back_to_the_bit(rows, cols,
+                                                             dtype):
+    x = _table(rows, cols, dtype)
+    views = stream._dense_views(x)
+    assert all(np.shares_memory(v, x) for v in views)       # zero-copy
+    assert views[0].shape == (rows // 128, 128 * cols)
+    assert len(views) == (2 if rows % 128 else 1)
+    assert sum(v.size for v in views) == x.size
+    back = jax.jit(stream._reseat)(tuple(jax.device_put(v) for v in views))
+    assert back.shape == x.shape and back.dtype == x.dtype
+    assert np.array_equal(np.asarray(back), x)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.int32],
+                         ids=["float32", "int32"])
+@pytest.mark.parametrize("chunks", [256, 300, 100],
+                         ids=["whole-groups", "ragged", "under-a-group"])
+@pytest.mark.parametrize("cols", [1, 7, 9])
+def test_a_streamed_fold_of_thin_records_is_the_resident_one(one, cols,
+                                                             chunks, dtype):
+    x = _table(1000, cols, dtype)
+    c0 = engine.counters()
+    got = _source(x, one, chunks).map(lambda r: r * 2).sum().toarray()
+    c1 = engine.counters()
+    nslabs = -(-1000 // chunks)
+    assert c1["stream_chunks"] - c0["stream_chunks"] == nslabs
+    # every slab of 128 rows and more went up dense (the tail too)
+    dense = sum(1 for lo in range(0, 1000, chunks)
+                if min(chunks, 1000 - lo) >= 128)
+    assert c1["stream_thin_slabs"] - c0["stream_thin_slabs"] == dense
+    assert c1["transfer_bytes"] - c0["transfer_bytes"] == x.nbytes
+    assert np.array_equal(got, (x * 2).sum(axis=0))
+    want = bolt.array(x, one).map(lambda r: r * 2).sum().toarray()
+    assert np.array_equal(got, want)
+    # the other statistics, through the fused group
+    stats = _source(x, one, chunks).stats("mean", "max")
+    assert np.allclose(stats["mean"].toarray(), x.mean(axis=0))
+    assert np.array_equal(stats["max"].toarray(), x.max(axis=0))
+
+
+def test_what_is_not_thin_goes_up_as_it_did(one, mesh, monkeypatch):
+    seen = []
+    real = stream._upload_slab
+
+    def spy(block, mesh_, split, *dense):
+        seen.append((block.shape, dense))
+        return real(block, mesh_, split, *dense)
+    monkeypatch.setattr(stream, "_upload_slab", spy)
+
+    def run(x, m, **kw):
+        seen.clear()
+        c0 = engine.counters()["stream_thin_slabs"]
+        got = _source(x, m, 256, **kw).sum().toarray()
+        assert np.allclose(got, x.sum(axis=0))
+        return engine.counters()["stream_thin_slabs"] - c0
+
+    fat = _table(512, 128, np.float32)
+    assert run(fat, one) == 0                    # a fat record
+    assert seen == [((256, 128), ())] * 2        # the parent's call
+    assert run(_table(512, 64, np.float32), one) == 0    # pads by two
+    thin = _table(512, 7, np.float32)
+    assert run(thin, one) == 2
+    assert seen == [((256, 7), (True,))] * 2
+    assert run(thin, mesh) == 0                  # eight devices
+    assert all(d == () for _, d in seen)
+    assert run(thin, one, codec="delta-f32") == 0        # a wire form
+    assert run(_table(512, 7, np.float64), one) == 0     # 64-bit
+
+
+def test_a_thin_swap_and_a_collect_keep_their_uploads(one):
+    """The resolver's programs take the slab as it is: only the fold's
+    pool lays it dense."""
+    x = _table(1024, 6, np.float32)
+    c0 = engine.counters()["stream_thin_slabs"]
+    mapped = _source(x, one, 256).map(lambda r: r + 1)
+    assert np.array_equal(mapped.toarray(), x + 1)
+    assert engine.counters()["stream_thin_slabs"] == c0
+
+
+# what ``stack4d-1chip.stream``'s two slab programs read as at the parent
+# commit (a712fb1, jax 0.9.0): sha256 of ``lower(...).as_text()``
+PARENT_TEXT = {
+    "slab-sum":
+        "272fe560eb5da2d8c34ba3043691d44bc4e2eda5303febf41a487bda39763ab0",
+    "slab-sum-fused":
+        "0563f4b4c2fa7a0e650824a2c64e17fb98d92eca2d844f2c85a26a9cb57fab48",
+}
+
+
+def plus_one(v):
+    return v + 1
+
+
+@pytest.mark.skipif(jax.__version__ != "0.9.0",
+                    reason="the recorded text is jax 0.9.0's")
+def test_the_fat_slab_program_is_the_parents_to_the_letter(one):
+    """``stack4d-1chip.stream``'s programs at its own sizes (records of
+    (256, 128), slabs of 512, a map, a sum): the lowered text does not
+    know of thin records."""
+    import hashlib
+    src = bolt.fromcallback(lambda idx: None, (163840, 256, 128), one,
+                            dtype=np.float32).map(plus_one)._stream
+    assert not stream.thin_records(src.shape, src.dtype)
+    slab = jax.ShapeDtypeStruct((512, 256, 128), np.float32)
+    acc = jax.ShapeDtypeStruct((256, 128), np.float32)
+    texts = {
+        "slab-sum": stream._slab_program(
+            src, "sum", slab.shape, None, None).lower(slab).as_text(),
+        "slab-sum-fused": stream._slab_program(
+            src, "sum", slab.shape, None, None, fused=True).lower(
+                slab, acc).as_text(),
+    }
+    assert {k: hashlib.sha256(v.encode()).hexdigest()
+            for k, v in texts.items()} == PARENT_TEXT
+
+
+def test_the_upload_is_counted_once_and_spanned(one):
+    x = _table(1000, 7, np.float32)
+    obs.enable()
+    obs.clear()
+    try:
+        c0 = engine.counters()
+        _source(x, one, 300).sum().toarray()
+        c1 = engine.counters()
+        spans = obs.totals()
+    finally:
+        obs.disable()
+        obs.clear()
+    assert spans["stream.transfer"]["count"] == 4
+    assert spans["stream.transfer"]["bytes"] == x.nbytes
+    assert c1["stream_upload_parts"] - c0["stream_upload_parts"] == 4
