@@ -230,6 +230,11 @@ _SCHEMA = {
     # because the stage before them (detrend, center, zscore) left it at
     # zero: one reader of the parent's result, which XLA fuses with it
     "fourier_centred_by_parent": 0,
+    # ``ops.register.fit`` calls whose frames take the cross-correlation
+    # surface as float32 DFT matrix products (traced float32 frames of
+    # 128 to ``register.N_MAX`` a side); every other call keeps XLA's FFT
+    # and counts nothing here
+    "crosscorr_on_mxu": 0,
     # resident swaps across chips LOWERED as an explicit exchange and the
     # one-pass glue (bolt_tpu/parallel/swapmerge.py: a TPU mesh, the key
     # axis on the lanes in pieces that are no whole lane tiles); every
@@ -854,6 +859,12 @@ def record_fourier_centred_by_parent():
     """``ops.fourier``'s record function was traced without its own
     centring (``ops/series.py :: _fourier_fn``)."""
     _COUNTERS.add("fourier_centred_by_parent")
+
+
+def record_crosscorr_on_mxu():
+    """``ops.register.fit`` was called on frames whose surface is taken
+    by matrix products (``ops/register.py :: _by_products``)."""
+    _COUNTERS.add("crosscorr_on_mxu")
 
 
 def record_swap_merge_lowering():

@@ -11,9 +11,20 @@ here, and these definitions are what runs:
 * **the surface.**  For a frame ``a`` and the reference ``b``, both
   ``(h, w)``, ``c[d] = sum_x a[x + d] * b[x]`` with cyclic indices, that is
   ``c = ifft2(fft2(a) * conj(fft2(b)))``, real for real images and computed
-  by the real transforms (``irfft2(rfft2(a) * conj(rfft2(b)))``: the same
-  surface, every shift of it, half the arithmetic).  Arithmetic in the
-  frame's floating type, float32 at the least.
+  from the half-spectrum a real image has (``irfft2(rfft2(a) *
+  conj(rfft2(b)))``: the same surface, every shift of it, half the
+  arithmetic).  Arithmetic in the frame's floating type, float32 at the
+  least.  **How it is computed** follows from what the code can see, the
+  frame's shape and type, and from nothing else: traced, float32 frames
+  of ``_N_MIN`` (128) to :data:`N_MAX` a side take the transforms as
+  dense DFT matrix products (four a frame), float32 in and out at
+  ``Precision.HIGHEST``, the slab in one layout from the frames to the
+  surface (:func:`_surface_by_products`; engine counter
+  ``crosscorr_on_mxu``); every other frame, float64 under x64 included,
+  takes XLA's real FFT, and NumPy arrays NumPy's.  A dense product sums
+  ``n`` terms a value where the FFT sums ``log2(n)`` stages, so it rounds
+  a few times more (PERF.md section 2 has both readings); ties are the
+  computed surface's, as they always were.
 * **the displacement** of a frame is the arg-max of ``|c|``.  **Tie rule:**
   the first maximum in C order, as ``argmax`` gives it.  **Cyclic
   adjustment:** a component above half its axis (``d > n // 2``) names the
@@ -45,9 +56,22 @@ held whole on the device.
 
 import numpy as np
 import jax.numpy as jnp
+from jax import lax
 
+from bolt_tpu import engine as _engine
 from bolt_tpu.obs import trace as _obs
 from bolt_tpu.utils import with_operands
+
+# Which frames take the surface as DFT matrix products: float32 frames
+# whose two axes both lie in [_N_MIN, N_MAX].  Under the MXU's 128-wide
+# tile the products have nothing to fill; a dense product costs O(n) a
+# value where the FFT costs O(log n), so past N_MAX XLA's FFT wins back.
+# N_MAX from scripts/shift_probe.py --hw on one v5e chip (PR 54, call 3),
+# ms a slab of 64 MiB of square float32 frames, XLA's rfft2 against the
+# products:   256: 5.03 / 2.93    512: 9.27 / 3.92
+#            1024: 7.88 / 6.73   2048: 8.92 / 12.36
+_N_MIN = 128
+N_MAX = 1024
 
 
 def _xp(*arrays):
@@ -56,17 +80,117 @@ def _xp(*arrays):
                      for a in arrays) else jnp
 
 
+def _by_products(shape, dtype):
+    """Whether traced frames of ``shape`` and ``dtype`` take the surface
+    as matrix products (the rule above :data:`N_MAX`)."""
+    return dtype == np.float32 and all(_N_MIN <= n <= N_MAX for n in shape)
+
+
+def _cos_sin(n, rows, cols):
+    """``cos`` and ``sin`` of ``2 pi r c / n`` for the int32 index vectors
+    ``rows`` and ``cols``, float32, made in the program from its iotas
+    (nothing of a table's size is a constant of the executable).  ``r c``
+    is reduced modulo ``n`` in integers before an angle is formed, and the
+    remainder folded to the first octant (in quarter steps, so an odd
+    ``n`` folds exactly): the float32 angle is at most ``pi / 4`` and the
+    table's error, 5e-8 where a float64 table rounded once has 3e-8, does
+    not grow with the index."""
+    q = 4 * ((rows[:, None] * cols[None, :]) % n)   # (pi / 2) q / n
+    quad, r = q // n, q % n
+    swap = 2 * r > n
+    theta = (jnp.where(swap, n - r, r).astype(jnp.float32)
+             * np.float32(np.pi / (2 * n)))
+    c, s = jnp.cos(theta), jnp.sin(theta)
+    c, s = jnp.where(swap, s, c), jnp.where(swap, c, s)
+    odd = quad % 2 == 1
+    return (jnp.where(odd, s, c) * jnp.where((quad == 1) | (quad == 2), -1, 1),
+            jnp.where(odd, c, s) * jnp.where(quad >= 2, -1, 1))
+
+
+def _dot(x, table, axis):
+    """``x`` with its ``axis`` contracted where it lies against ``table``'s
+    first: float32 in, float32 out, ``Precision.HIGHEST`` (the result's
+    axes are ``x``'s others, then the table's second)."""
+    return lax.dot_general(x, table, (((axis,), (0,)), ((), ())),
+                           precision=(lax.Precision.HIGHEST,) * 2,
+                           preferred_element_type=jnp.float32)
+
+
+def _packed_tables(n):
+    """The PACKED real transform of an axis of ``n`` values and its way
+    back, ``(n, n)`` each.  A real row has ``n // 2 + 1`` cosine sums
+    ``C[k] = sum_j x[j] cos(2 pi k j / n)`` and ``(n - 1) // 2`` sine sums
+    ``S[k]``, ``k`` from 1, that are not zero (its spectrum is ``C - i
+    S``): ``n`` numbers, the forward table's columns, cosines first.  On
+    the way back a sum that has no conjugate twin in the half-spectrum
+    (``k = 0``, and ``n / 2`` where ``n`` is even) counts once and every
+    other twice."""
+    k = jnp.arange(n // 2 + 1, dtype=jnp.int32)
+    cos, sin = _cos_sin(n, jnp.arange(n, dtype=jnp.int32), k)
+    sin = sin[:, 1:1 + (n - 1) // 2]
+    once = (k == 0) | (2 * k == n)
+    return (jnp.concatenate([cos, sin], axis=1),
+            jnp.concatenate([(cos * jnp.where(once, 1.0, 2.0)).T,
+                             2.0 * sin.T]))
+
+
+def _surface_by_products(a, b):
+    """The surface of float32 ``a`` against ``b``, both ``(h, w)``, by
+    FOUR matrix products a frame: the packed transform along ``w``, then
+    along ``h``, the spectra's product, and the two ways back.  Each
+    product contracts its axis where it lies, so the packed square
+    travels transposed, ``(w, h)``, between the two products along ``h``
+    and the two along ``w`` undo each other's order: no transpose is
+    written.
+
+    The packed square holds, for ``g`` along ``h`` and ``k`` along ``w``,
+    the four sums ``cc, sc, cs, ss`` of the frame against ``cos`` or
+    ``sin`` of either angle, each in its quadrant (where a sine sum is
+    zero the quadrant is a row or a column short, and padded).  The
+    spectrum ``A = rfft2(frame)`` is ``A[g, k] = (cc - ss) - i (sc + cs)``
+    and ``A[-g, k] = (cc + ss) - i (cs - sc)``; with ``p - i q`` and ``p'
+    - i q'`` the same two values of ``A conj(B)``, the packed square of
+    the surface is ``cc = p + p'``, ``sc = q - q'``, ``cs = q + q'``,
+    ``ss = p' - p``, over ``2 h w``."""
+    h, w = a.shape
+    nh, nw = h // 2 + 1, w // 2 + 1                 # cosine sums an axis
+    mh, mw = (h - 1) // 2, (w - 1) // 2             # sine sums, from 1
+    (there_w, back_w), (there_h, back_h) = _packed_tables(w), _packed_tables(h)
+    rows, cols = (1, nw - mw - 1), (1, nh - mh - 1)
+
+    def spectrum(x):
+        """``p, q, p', q'`` of ``rfft2(x)``, ``(nw, nh)`` each."""
+        sums = _dot(_dot(x, there_w, 1), there_h, 0)            # (w, h)
+        cc = sums[:nw, :nh]
+        sc = jnp.pad(sums[:nw, nh:], ((0, 0), cols))
+        cs = jnp.pad(sums[nw:, :nh], (rows, (0, 0)))
+        ss = jnp.pad(sums[nw:, nh:], (rows, cols))
+        return cc - ss, sc + cs, cc + ss, cs - sc
+
+    (pa, qa, pa2, qa2), (pb, qb, pb2, qb2) = spectrum(a), spectrum(b)
+    p, q = pa * pb + qa * qb, qa * pb - pa * qb                 # A conj(B)
+    p2, q2 = pa2 * pb2 + qa2 * qb2, qa2 * pb2 - pa2 * qb2
+    sums = jnp.concatenate([
+        jnp.concatenate([p + p2, (q - q2)[:, 1:1 + mh]], axis=1),
+        jnp.concatenate([(q + q2)[1:1 + mw],
+                         (p2 - p)[1:1 + mw, 1:1 + mh]], axis=1)])
+    return _dot(_dot(sums, back_h * (0.5 / h), 1), back_w * (1.0 / w), 0)
+
+
 def crosscorr_shift(frame, reference):
     """The displacement ``(dx, dy)``, int32, of the 2-d ``frame`` against
     ``reference`` (same shape): the arg-max of the cyclic cross-correlation
-    surface, adjusted (module docstring: surface, tie rule, cyclic
-    adjustment, sign convention)."""
+    surface, adjusted (module docstring: surface, how it is computed, tie
+    rule, cyclic adjustment, sign convention)."""
     xp = _xp(frame, reference)
     dt = xp.promote_types(frame.dtype, xp.float32)
     a, b = frame.astype(dt), reference.astype(dt)
     h, w = a.shape
-    surface = xp.fft.irfft2(xp.fft.rfft2(a) * xp.conj(xp.fft.rfft2(b)),
-                            s=(h, w))
+    if xp is jnp and _by_products((h, w), dt):
+        surface = _surface_by_products(a, b)
+    else:
+        surface = xp.fft.irfft2(xp.fft.rfft2(a) * xp.conj(xp.fft.rfft2(b)),
+                                s=(h, w))
     at = xp.argmax(xp.abs(surface))
     d = xp.stack([at // w, at % w]).astype(xp.int32)
     n = xp.asarray([h, w], dtype=xp.int32)
@@ -122,6 +246,9 @@ def fit(images, reference):
                              "%s" % (tuple(np.shape(reference)), fshape))
         if images.mode != "tpu":
             reference = np.asarray(reference)
+        elif _by_products(fshape, jnp.promote_types(images.dtype,
+                                                    jnp.float32)):
+            _engine.record_crosscorr_on_mxu()
         return images.map(with_operands(crosscorr_shift, reference),
                           axis=axes)
 

@@ -322,6 +322,7 @@ _EXPECTED_ENGINE_KEYS = {
     "percentile_kernel_lowerings": False,
     "percentile_based_lowerings": False,
     "fourier_centred_by_parent": False,
+    "crosscorr_on_mxu": False,
     "swap_merge_lowerings": False,
     "filters_fused": False, "filter_compactions": False,
     "coalesced_builds": False, "coalesced_compiles": False,

@@ -1923,3 +1923,76 @@ def test_the_kept_export_of_the_swap_lowers_without_pallas(
         assert "tpu_custom_call" in text and "swap_merge" in text
     finally:
         engine.persistent_cache(enable=False)
+
+
+# ---------------------------------------------------------------------
+# compile-only: the slab program of a streamed ``ops.register.fit`` at
+# the motion cell's shape (ISSUE 54): 64 frames of 512 x 512 float32, the
+# reference an operand.  The surface is six float32 matrix products at
+# HIGHEST in one layout; XLA's FFT was ten copies, six of them
+# slab-sized, 7.21 GB accessed a 67 MB slab and 0.271 GB of temporaries
+# ---------------------------------------------------------------------
+
+def _fit_slab_program(v5e_device, frame):
+    """The resolver's place program of ``fit`` over a ``fromcallback``
+    session of ``frame`` frames, compiled: what ``stream.collect`` runs a
+    slab (the plan without a budget: a described device has no memory
+    statistics)."""
+    import warnings
+    import jax
+    from bolt_tpu import stream
+    from bolt_tpu.ops import register
+    from bolt_tpu.parallel import shuffle
+    from bolt_tpu.tpu.array import BoltArrayTPU
+    mesh = _series_mesh(v5e_device)
+    session = (10240,) + frame
+    src = stream.StreamSource.from_callback(lambda index: None, session, 1,
+                                            np.float32, mesh)
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not")
+        source = register.fit(BoltArrayTPU._streamed(src),
+                              np.zeros(frame, np.float32))._stream
+        st = stream.result_state(source)
+        assert st.shape == (10240, 2) and st.dtype == np.int32
+        plan = shuffle.plan_shuffle(st.shape, st.dtype, st.split, (0, 1),
+                                    st.split, mesh, source.slab, None, None,
+                                    ring=3)
+        slab = (source.slab,) + frame
+        program = shuffle.place_program(plan, source.stages, mesh, None,
+                                        np.float32, slab, True, source.slab)
+        return slab, program.lower(
+            spec(plan.out_shape, np.int32), spec(slab, _F32),
+            spec((), np.uint32), spec(frame, _F32)).compile()
+
+
+def _slab_sized_moves(text, slab):
+    """``copy`` and ``transpose`` operations of the entry computation whose
+    result has as many elements as the slab, in any order of its axes."""
+    entry = text[text.index("ENTRY"):]
+    size = int(np.prod(slab))
+    return [m.group(0) for m in re.finditer(
+        r"= f32\[([\d,]+)\]\S* (copy|transpose)\(", entry)
+        if int(np.prod([int(d) for d in m.group(1).split(",")])) >= size]
+
+
+def test_fit_s_slab_program_is_float32_products_in_one_layout_on_v5e(
+        v5e_device):
+    slab, compiled = _fit_slab_program(v5e_device, (512, 512))
+    assert slab == (64, 512, 512)
+    text = compiled.as_text()
+    products = re.findall(r" convolution\([^\n]*", text)
+    assert len(products) == 6             # 2 for the reference, 4 a slab
+    assert all("operand_precision={highest,highest}" in p for p in products)
+    assert not re.search(r"bf16\[\d+,\d+", text)      # nothing held in bf16
+    assert not re.search(r"= \S+ (fft|sort)\(", text)
+    assert len(_slab_sized_moves(text, slab)) <= 1    # 6 by XLA's FFT
+    assert compiled.cost_analysis()["bytes accessed"] < 3.0e9     # 7.21e9
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.3e9
+    assert mem.output_size_in_bytes < 1 << 20     # the displacements alone
+

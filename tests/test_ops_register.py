@@ -221,3 +221,175 @@ def test_the_calls_are_spans_on_the_callers_thread(mesh):
         obs.disable()
         obs.clear()
     assert row["count"] == 2 and row["seconds"] > 0
+
+
+# ---------------------------------------------------------------------
+# the surface as DFT matrix products (ISSUE 54): traced float32 frames of
+# 128 to ``register.N_MAX`` a side; the frames above (24 x 40, 8 x 16)
+# keep XLA's FFT and keep guarding it
+# ---------------------------------------------------------------------
+
+ON_MXU = [(128, 128), (128, 256), (192, 160), (129, 131)]
+
+
+@pytest.mark.parametrize("off", [(0, 0), (1, 0), (0, -1), (3, -2), (-4, 5),
+                                 (-6, -6), (2, -7)])
+@pytest.mark.parametrize("h,w", ON_MXU)
+def test_a_planted_displacement_is_found_by_the_products(h, w, off):
+    """Powers of two, a non-square frame, axes that are no power of two
+    and odd ones: ``d = -off`` by the NumPy spelling and by the traced
+    one alike."""
+    m = 8
+    sc = scene(h + w, h, w, m)
+    ref, frame = crop(sc, h, w, m, (0, 0)), crop(sc, h, w, m, off)
+    assert register._by_products((h, w), frame.dtype)
+    want = np.asarray([-off[0], -off[1]], np.int32)
+    assert np.array_equal(register.crosscorr_shift(frame, ref), want)
+    traced = jax.jit(register.crosscorr_shift)(frame, ref)
+    assert traced.dtype == jnp.int32 and np.array_equal(traced, want)
+
+
+@pytest.mark.parametrize("rolled,want", [
+    ((64, 0), (64, 0)), ((65, 0), (-63, 0)), ((-63, 0), (-63, 0)),
+    ((0, 128), (0, 128)), ((0, 129), (0, -127)), ((127, 255), (-1, -1)),
+    ((64, 128), (64, 128)), ((-64, -128), (64, 128)),
+    ((65, -127), (-63, -127))])
+def test_the_cyclic_adjustment_of_the_products(rolled, want):
+    h, w = 128, 256
+    ref = scene(10, h, w, 0)
+    frame = np.roll(ref, rolled, axis=(0, 1))
+    got = register.crosscorr_shift(frame, ref)
+    assert np.array_equal(got, np.asarray(want, np.int32))
+    assert np.array_equal(jax.jit(register.crosscorr_shift)(frame, ref), got)
+
+
+def moving_scene(seed, frames, h, w, margin=16, walk=12):
+    """Frames of the benchmark's kind (``benchmark/operands/motion.py``):
+    crops of one scene (a resting level of 1,500, Gaussian cell bodies up
+    to 4,000 bright, texture up to 200) at offsets within ``walk`` pixels,
+    noise within 150 a frame; integers, float32."""
+    rng = np.random.default_rng(seed)
+    rows, cols = h + 2 * margin, w + 2 * margin
+    u, v = np.arange(rows)[:, None], np.arange(cols)[None, :]
+    sc = np.full((rows, cols), 1500.0)
+    for _ in range(400 * h * w // (512 * 512)):
+        cu, cv = rng.uniform(0, rows), rng.uniform(0, cols)
+        s = rng.uniform(2, 6)
+        sc += rng.uniform(400, 4000) * np.exp(
+            -((u - cu) ** 2 + (v - cv) ** 2) / (2 * s * s))
+    sc = np.minimum(np.rint(sc + rng.integers(0, 201, size=sc.shape)), 16000)
+    offs = rng.integers(-walk, walk + 1, size=(frames, 2))
+    out = np.stack([crop(sc, h, w, margin, o) for o in offs])
+    return (out + rng.integers(-150, 151, size=out.shape)).astype(np.float32)
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_products_surface_is_float64s_to_a_tenth_of_the_regret(seed):
+    """512 x 512, the cell's frames: the traced surface against NumPy in
+    float64 within 1e-5 of its largest value (the regret's limit is 1e-4;
+    a dense DFT sums 512 products a value and reads 2-6e-6 here, XLA's FFT
+    6e-7), and the displacement float64's own."""
+    h = w = 512
+    data = moving_scene(seed, 3, h, w)
+    ref = data.mean(axis=0, dtype=np.float64).astype(np.float32)
+    want = np.fft.irfft2(np.fft.rfft2(data.astype(np.float64))
+                         * np.conj(np.fft.rfft2(ref.astype(np.float64))),
+                         s=(h, w))
+    got = jax.jit(jax.vmap(register._surface_by_products,
+                           in_axes=(0, None)))(data, ref)
+    assert got.dtype == jnp.float32
+    assert np.abs(np.asarray(got) - want).max() < 1e-5 * np.abs(want).max()
+    at = np.abs(want).reshape(3, -1).argmax(axis=1)
+    d = np.stack([at // w, at % w], axis=1)
+    d = np.where(d > np.asarray([h, w]) // 2, d - np.asarray([h, w]), d)
+    assert np.array_equal(jax.jit(jax.vmap(register.crosscorr_shift,
+                                           in_axes=(0, None)))(data, ref), d)
+
+
+def _primitives(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _primitives(sub)
+
+
+def _traced(h, w, dtype=np.float32):
+    frames = jax.ShapeDtypeStruct((2, h, w), dtype)
+    return list(_primitives(jax.make_jaxpr(jax.vmap(
+        register.crosscorr_shift, in_axes=(0, None)))(
+            frames, jax.ShapeDtypeStruct((h, w), dtype)).jaxpr))
+
+
+@pytest.mark.parametrize("h,w", [(512, 512), (128, 256), (129, 131),
+                                 (register.N_MAX, register.N_MAX)])
+def test_every_product_is_float32_at_highest_and_no_fft_is_left(h, w):
+    """The precision guard: the configuration states float32 arithmetic
+    and the cell's checks cannot see the transform's precision, so it is
+    held here."""
+    eqns = _traced(h, w)
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 6                 # 2 for the reference, 4 a frame
+    highest = jax.lax.Precision.HIGHEST
+    for e in dots:
+        assert tuple(e.params["precision"]) == (highest, highest)
+        assert e.params["preferred_element_type"] == jnp.float32
+        assert all(v.aval.dtype == jnp.float32
+                   for v in list(e.invars) + list(e.outvars))
+    assert not [e for e in eqns if e.primitive.name == "fft"]
+    assert not [v.aval.dtype for e in eqns for v in e.outvars
+                if v.aval.dtype in (jnp.bfloat16, jnp.float16)]
+
+
+@pytest.mark.parametrize("h,w,dtype", [
+    (64, 64, np.float32), (127, 512, np.float32), (512, 127, np.float32),
+    (register.N_MAX + 1, register.N_MAX + 1, np.float32),
+    (128, register.N_MAX + 1, np.float32), (512, 512, np.float64)])
+def test_every_other_frame_keeps_xlas_fft(h, w, dtype):
+    """Under the MXU's tile, past ``N_MAX`` on either axis, and float64
+    under x64: ``jnp.fft`` as it stood, and no product."""
+    assert not register._by_products((h, w), dtype)
+    names = [e.primitive.name for e in _traced(h, w, dtype)]
+    assert names.count("fft") == 3 and "dot_general" not in names
+
+
+def test_the_rule_reads_the_frames_shape_and_type_and_nothing_else():
+    import inspect
+    assert list(inspect.signature(register.crosscorr_shift).parameters) \
+        == ["frame", "reference"]
+    assert list(inspect.signature(register.fit).parameters) \
+        == ["images", "reference"]
+    assert register._by_products((128, register.N_MAX), np.float32)
+    assert register._by_products((512, 512), np.dtype("float32"))
+    # integers are promoted to float32 by jax.numpy, and take the products
+    eqns = _traced(128, 128, np.int16)
+    assert "dot_general" in [e.primitive.name for e in eqns]
+    # NumPy's side keeps np.fft at any size: it is the oracle
+    ref = scene(1, 128, 128, 0)
+    assert isinstance(register.crosscorr_shift(ref, ref), np.ndarray)
+
+
+@pytest.mark.parametrize("seed", [0, 3])
+def test_fit_by_the_products_on_the_three_kinds_of_array(mesh, seed):
+    """128 x 128 frames: a ``fromcallback`` source, the resident array and
+    ``mode='local'`` agree, and the counter counts the calls that took
+    the products (the two traced ones) and not the others."""
+    data, ref, planted = session(seed, frames=24, h=128, w=128, margin=8)
+    c0 = engine.counters()["crosscorr_on_mxu"]
+    disp = register.fit(bolt.array(data), ref).toarray()
+    assert np.array_equal(disp, planted)
+    assert engine.counters()["crosscorr_on_mxu"] == c0      # NumPy's FFT
+    assert np.array_equal(register.fit(bolt.array(data, mesh),
+                                       ref).toarray(), disp)
+    assert engine.counters()["crosscorr_on_mxu"] == c0 + 1
+    source = bolt.fromcallback(lambda i: data[tuple(i)], data.shape, mesh,
+                               dtype=np.float32, chunks=8)
+    got = register.fit(source, ref)
+    assert got._stream is not None
+    assert np.array_equal(got.toarray(), disp)
+    assert engine.counters()["crosscorr_on_mxu"] == c0 + 2
+    # frames under the rule's floor, and float64 ones, count nothing
+    small, sref, _ = session(seed)
+    register.fit(bolt.array(small, mesh), sref).toarray()
+    register.fit(bolt.array(data.astype(np.float64), mesh), ref)
+    assert engine.counters()["crosscorr_on_mxu"] == c0 + 2
