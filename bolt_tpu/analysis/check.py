@@ -568,6 +568,15 @@ def _note_codec(src, idx, diags, members=()):
              "passes, and the arbiter leases the wire bytes"))
 
 
+def _whole_need(src):
+    """``(bytes a device, the device's limit, refused)`` of materialising
+    ``src`` whole: what BLT020 and BLT021 say where nothing streams."""
+    from bolt_tpu import stream as _stream
+    from bolt_tpu.tpu.array import _hbm_limit
+    need, limit = _stream.materialize_bytes(src), _hbm_limit()
+    return need, limit, limit is not None and need > limit
+
+
 def _note_collect(src, idx, diags):
     """``BLT020``: what taking this mapped streamed result WHOLE
     (``toarray`` / ``tojax`` / ``cache``) will do, by the rules the run
@@ -576,7 +585,6 @@ def _note_collect(src, idx, diags):
     slab by slab, materialised with the base uploaded whole, or refused.
     Reduction terminals stream whatever this says."""
     from bolt_tpu import stream as _stream
-    from bolt_tpu.tpu.array import _hbm_limit
     why = _stream.collect_refusal(src)
     if why is None:
         plan = _stream.collect_plan(src)
@@ -595,8 +603,7 @@ def _note_collect(src, idx, diags):
                  "to materialising it (stream_collect_slabs / "
                  "stream_collect_bytes engine counters)"))
         return
-    need, limit = _stream.materialize_bytes(src), _hbm_limit()
-    refused = limit is not None and need > limit
+    need, limit, refused = _whole_need(src)
     diags.append(Diagnostic(
         "BLT020", idx,
         "taken whole, this result materialises, %s a device: the base "
@@ -607,8 +614,74 @@ def _note_collect(src, idx, diags):
            "dispatch with these words" % _fmt_bytes(limit)
            if refused else ""),
         severity="warning" if refused else "info",
-        hint="reduction terminals (sum/mean/var/std/reduce) stream it "
-             "slab by slab whatever its size"))
+        hint="reduction terminals (sum/mean/var/std/reduce, and "
+             "ops.pca / ops.cov where BLT021 says so) stream it slab by "
+             "slab whatever its size"))
+
+
+def _note_gram(src, idx, diags):
+    """``BLT021``: what ``ops.cov`` and ``ops.pca`` of this streamed
+    source will do, by the rules the run itself decides by
+    (``stream.gram_refusal``; for pca's scores ``stream.collect_plan`` of
+    the source with the projection as its last stage, as
+    ``ops/linalg.py :: _pca_streamed`` asks it): one pass that folds the
+    Gram matrix slab by slab, a second that collects the scores, or the
+    source materialised whole with the reason.  Over the sample axes a
+    caller would name: the key axes and the value axes behind them until
+    the samples outnumber the features.  Nothing where no such reading
+    exists or the features' Gram matrix would outgrow a slab."""
+    from bolt_tpu import stream as _stream
+    from bolt_tpu.ops import linalg as _linalg
+    st = _stream.result_state(src)
+    shape = tuple(st.shape)
+    m = max(st.split, 1)
+    while m < len(shape) - 1 and prod(shape[:m]) < prod(shape[m:]):
+        m += 1
+    n, d = prod(shape[:m]), prod(shape[m:])
+    item = np.dtype(st.dtype).itemsize
+    if m >= len(shape) or n < d or d * d * item > _stream_slab_bytes(src):
+        return
+    axes = tuple(range(m))
+    try:
+        why = _stream.gram_refusal(src, axes, passes=2)
+    except ValueError:
+        return          # a codec that does not resolve is BLT016's to say
+    if why is not None:
+        need, limit, refused = _whole_need(src)
+        diags.append(Diagnostic(
+            "BLT021", idx,
+            "ops.pca / ops.cov over axis=%s materialise this source, %s "
+            "a device (not folded slab by slab: %s)%s"
+            % (axes, _fmt_bytes(need), why,
+               " — the device holds %s: the call is refused at dispatch "
+               "(BLT020)" % _fmt_bytes(limit) if refused else ""),
+            severity="warning" if refused else "info",
+            hint="record-wise maps in front, the leading axes as samples "
+                 "and one process stream at any size"))
+        return
+    # the scores' place, by the plan of ONE component: a component more
+    # is a result that much larger beside the same slabs in flight
+    plan = _stream.collect_plan(_linalg._scores_plan_source(
+        src, m, d, 1, False, "highest"))
+    room = None if plan.budget is None else max(
+        0, (plan.budget - (plan.resident_bytes - plan.total_bytes))
+        // plan.total_bytes)
+    diags.append(Diagnostic(
+        "BLT021", idx,
+        "ops.cov over axis=%s streams this source in ONE pass: the %d x "
+        "%d Gram matrix and the column sums folded slab by slab, %d "
+        "slab%s; ops.pca in TWO: that pass, then every slab projected "
+        "and its scores collected into the resident result, %s a "
+        "component (%s)"
+        % (axes, d, d, plan.nslabs, "s" if plan.nslabs != 1 else "",
+           _fmt_bytes(plan.total_bytes),
+           "unbounded budget" if room is None else
+           "k up to %d of %d fit the %s budget beside %d slabs in flight%s"
+           % (min(room, d), d, _fmt_bytes(plan.budget), plan.ring,
+              "" if room else ": pca materialises the source whole, or "
+              "is refused (BLT020)")),
+        hint="the source never lives whole on the device "
+             "(stream_gram_slabs / stream_project_slabs engine counters)"))
 
 
 def _note_shuffle(src, stage, aval, split, mesh, idx, diags, keyed=False):
@@ -626,7 +699,7 @@ def _note_shuffle(src, stage, aval, split, mesh, idx, diags, keyed=False):
     try:
         plan = _shuffle.plan_shuffle(
             tuple(aval.shape), np.dtype(aval.dtype), split, perm,
-            new_split, mesh, src.slab, _stream.swap_budget(mesh),
+            new_split, mesh, src.slab, _stream.place_budget(src),
             spill_dir, ring=_stream.swap_ring(src),
             raw_slab_bytes=_stream._raw_slab_bytes(src))
     except ValueError as exc:
@@ -1266,6 +1339,8 @@ def _check_stream(arr, target, stages, diags):
         # every stage traced: what taking the mapped result whole does
         if src.stages and not dynamic and not _stream.has_swap(src):
             _note_collect(src, len(src.stages), diags)
+        if not dynamic and not _stream.has_swap(src):
+            _note_gram(src, len(src.stages), diags)
     return Report(target + ", streaming (out-of-core)", stages, diags,
                   dynamic=dynamic)
 

@@ -59,6 +59,10 @@ CODES = {
     "BLT020": ("info",
                "a mapped streamed result taken whole: collected slab by "
                "slab, or materialised with its base uploaded whole"),
+    "BLT021": ("info",
+               "ops.pca / ops.cov of a streamed source: the Gram matrix "
+               "folded slab by slab and the scores collected, or the "
+               "source materialised whole"),
 }
 
 SEVERITIES = ("error", "warning", "info")

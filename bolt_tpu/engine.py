@@ -293,6 +293,16 @@ _SCHEMA = {
                                   # a dense view of their bytes and were
                                   # re-seated by their slab program
                                   # (stream.thin_records)
+    # a Gram matrix folded slab by slab (ops.pca / ops.cov on a streamed
+    # source: stream.maybe_gram) and pca's second pass
+    "stream_gram_slabs": 0,       # slabs folded by the Gram terminal (0
+                                  # where the source was materialised)
+    "stream_gram_kernel_slabs": 0,  # those of them whose slab program was
+                                  # LOWERED with the packed_gram kernel
+                                  # (a fall-back to dot_general reads
+                                  # fewer; 0 on the CPU)
+    "stream_project_slabs": 0,    # slabs a streamed pca's second pass
+                                  # projected and placed into the scores
 }
 
 _COUNTERS = _metrics.registry().group("engine", _SCHEMA)
@@ -800,6 +810,9 @@ def record_filter_compaction():
     _COUNTERS.add("filter_compactions")
 
 
+_LOWERED = threading.local()     # what THIS thread's lowerings placed
+
+
 def record_gram_kernel_program(sums=False):
     """One program was lowered with the ``packed_gram`` Mosaic kernel in
     it (``ops/linalg.py :: _gram_primitive``): a program for one TPU
@@ -812,6 +825,16 @@ def record_gram_kernel_program(sums=False):
     _COUNTERS.add("gram_kernel_programs")
     if sums:
         _COUNTERS.add("gram_sums_programs")
+    _LOWERED.gram = gram_kernel_lowerings() + 1
+
+
+def gram_kernel_lowerings():
+    """How many programs the calling thread has lowered with the
+    ``packed_gram`` kernel in them (a program is lowered by the thread
+    that first calls it: :class:`_Dispatch` reads this around its own
+    lowering and keeps the answer with the cached program, which is what
+    ``stream_gram_kernel_slabs`` counts slabs by)."""
+    return getattr(_LOWERED, "gram", 0)
 
 
 def record_fold_kernel_program():
@@ -997,14 +1020,17 @@ def record_shuffle(nbytes, seconds, alltoall=0):
                      stream_alltoall_bytes=int(alltoall))
 
 
-def record_collect(slabs, nbytes):
+def record_collect(slabs, nbytes, project=False):
     """Tally one streamed collect (bolt_tpu.stream's resident leg run
     with no re-axis): the slabs it placed and the bytes of result they
     made.  One update a collect, at its end; the timeline carries it as
     the ``stream.collect`` span and a ``stream.collect.place`` span a
-    slab."""
+    slab.  ``project``: the collect is a streamed pca's second pass
+    (``ops/linalg.py``), whose slabs count under ``stream_project_slabs``
+    too."""
     _COUNTERS.update(stream_collect_slabs=int(slabs),
-                     stream_collect_bytes=int(nbytes))
+                     stream_collect_bytes=int(nbytes),
+                     stream_project_slabs=int(slabs) if project else 0)
 
 
 def record_spill(nbytes):
@@ -1033,7 +1059,8 @@ def record_checkpoint(nbytes, seconds):
 
 
 def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
-                  uploaders=1, inflight=1, keyed=0, group=0, thin=0):
+                  uploaders=1, inflight=1, keyed=0, group=0, thin=0,
+                  gram=0, gram_kernel=0):
     """Tally one completed streamed run (bolt_tpu.stream executor); the
     keys apply atomically — a snapshot can never see a run's wall time
     without its overlap.  Called by the run's own thread as the run ends,
@@ -1044,7 +1071,9 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
     depth) keep process maxima.  ``keyed``: of ``chunks``, the slabs
     whose program took the slab's first key as an operand; ``group``:
     those a grouped terminal folded; ``thin``: those that went up dense
-    and were re-seated on the device."""
+    and were re-seated on the device; ``gram``: those the Gram terminal
+    folded, ``gram_kernel`` of them by a program lowered with the
+    ``packed_gram`` kernel."""
     _COUNTERS.update(_maxima={"stream_prefetch_depth": int(depth),
                               "stream_upload_threads": int(uploaders),
                               "stream_inflight_high_water": int(inflight)},
@@ -1052,6 +1081,8 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                      stream_keyed_slabs=int(keyed),
                      stream_group_slabs=int(group),
                      stream_thin_slabs=int(thin),
+                     stream_gram_slabs=int(gram),
+                     stream_gram_kernel_slabs=int(gram_kernel),
                      stream_ingest_seconds=ingest_s,
                      stream_compute_seconds=compute_s,
                      stream_wall_seconds=wall_s,
@@ -1202,11 +1233,14 @@ class _Dispatch:
     signature; falls back to plain jit dispatch for argument structures
     the AOT path cannot serve (and counts the fallback)."""
 
-    __slots__ = ("jitted", "compiled", "key", "_compile_lock")
+    __slots__ = ("jitted", "compiled", "key", "_compile_lock",
+                 "gram_kernel")
 
     def __init__(self, jitted, key=None):
         self.jitted = jitted
         self.compiled = {}           # signature -> compiled executable
+        self.gram_kernel = False     # a lowering of this entry placed
+        #                              the packed_gram kernel
         self.key = key               # engine cache key: what the
         #                              schedule digest folds per enqueue
         # serialises the per-signature lower+compile: N tenants racing
@@ -1276,7 +1310,10 @@ class _Dispatch:
                             lsp = _obs.begin("engine.lower", family=family)
                             try:
                                 t0 = _clock()
+                                placed = gram_kernel_lowerings()
                                 lowered = self.jitted.lower(*args)
+                                if gram_kernel_lowerings() != placed:
+                                    self.gram_kernel = True
                                 t1 = _clock()
                             finally:
                                 _obs.end(lsp)

@@ -1345,18 +1345,45 @@ def pca(b, k=None, center=False, axis=None, return_mean=False,
     ``axis=(0, 1)`` is never flattened to ``(K*N, d)``, which on the
     chip's tiled layout is a relayout copy as large as the data.
 
+    A lazy out-of-core source (``bolt.fromcallback`` / ``fromiter``) is
+    never uploaded whole: the decomposition is TWO passes of the
+    streamed executor over it, because the components are not known
+    until every sample has been seen (:func:`_pca_streamed`).  Pass 1
+    folds the Gram matrix and the column sums slab by slab
+    (``stream.maybe_gram``: this program's own Gram body a slab, the
+    partials added on the device), the ``(d, d)`` eigenproblem is solved
+    on the device, and pass 2 maps the source by ``x -> x @ V - mu @ V``
+    and collects the scores slab by slab into the one resident array
+    (``stream.collect``), which has to fit beside the slabs in flight.
+    What the executor does not take (a filter, a chunked or stacked
+    stage or a swap in front, sample axes that are not the leading ones,
+    several processes, a one-shot iterator, scores past the resident
+    budget: ``stream.gram_refusal`` and ``collect_refusal`` say which)
+    materialises first, as every such source did before.
+
     Spans: ``linalg.pca`` from entry to the results in hand, with
     ``linalg.pca.launch`` (alignment and the one program enqueued) and
     ``linalg.pca.fetch`` (the small results brought to the host;
-    absent with ``fetch=False``) beneath it.
+    absent with ``fetch=False``) beneath it; over a streamed source, in
+    ``launch``'s place, ``linalg.pca.gram_pass`` (pass 1, from its first
+    slab asked for to the Gram matrix in hand), ``linalg.pca.decompose``
+    (the eigensolver's program enqueued: nothing waits for it, it runs
+    on the device while pass 2's first slab goes up) and
+    ``linalg.pca.project_pass`` (pass 2, to the scores ready: the
+    executor's own wait), the executor's own ``stream.*`` spans beneath
+    them.
     """
     from bolt_tpu._precision import resolve
     pr = resolve(precision)
     if getattr(b, "mode", None) != "tpu":
         return _pca_local(b, k, center, axis, return_mean)
     with _obs.span("linalg.pca", k=k, center=bool(center)):
-        with _obs.span("linalg.pca.launch"):
-            scores, vec, sv, mu = _pca_launch(b, k, center, axis, pr)
+        out = _pca_streamed(b, k, center, axis, pr) if b.streaming \
+            else NotImplemented
+        if out is NotImplemented:
+            with _obs.span("linalg.pca.launch"):
+                out = _pca_launch(b, k, center, axis, pr)
+        scores, vec, sv, mu = out
         if fetch:
             # ONE batched host fetch for the small results: separate
             # device_gets cost a full host round-trip EACH.  With
@@ -1420,23 +1447,37 @@ def _pca_program(funcs, split, kshape, d, k, center, pr, mesh):
         # test_pca_centering_fold_large_offset).  Pre-shift data with
         # larger offsets.
         if not center:
-            mu = jnp.zeros(d, x.dtype)
-            g = _sample_gram(x, pr, widened=widened)
+            g, total = _sample_gram(x, pr, widened=widened), None
         else:
             g, total = _sample_gram(x, pr, widened=widened, sums=True)
-            mu = total / n
-            g = g - n * jnp.outer(jnp.conj(mu), mu)
-        vec, ev = _decompose_gram(g, k, jnp, _tpu_eigh)
+        vec, sv, mu, off = _pca_decompose(g, total, n, k, pr)
         # pinned "highest": the MXU's bf16 default costs ~3 decimal
         # digits on f32 data — visible in scores at PCA scale; the
         # scoped policy buys it back where the user accepts that
         scores = _project(x, vec, pr)
         if center:
-            scores = scores - jnp.matmul(mu, vec, precision=pr)
+            scores = scores - off
         scores = jax.lax.with_sharding_constraint(
             scores, key_sharding(mesh, kshape + (k,), split))
-        return scores, vec, jnp.sqrt(ev), mu
+        return scores, vec, sv, mu
     return program
+
+
+def _pca_decompose(g, total, n, k, pr):
+    """From the raw Gram matrix ``g`` and the column sums ``total``
+    (``None``: not centred) of ``n`` samples to ``(vec (d, k), sv (k,),
+    mu (d,), off (k,))``: the centring folded into the matrix
+    (``G - n mu mu^H``), its top ``k`` eigenpairs, and the offset
+    ``mu @ vec`` that the projection of raw samples takes away.  Traced
+    by the resident program and, on its own, between a streamed source's
+    two passes."""
+    if total is None:
+        mu = jnp.zeros(g.shape[-1], g.dtype)
+    else:
+        mu = total / n
+        g = g - n * jnp.outer(jnp.conj(mu), mu)
+    vec, ev = _decompose_gram(g, k, jnp, _tpu_eigh)
+    return vec, jnp.sqrt(ev), mu, jnp.matmul(mu, vec, precision=pr)
 
 
 def _pca_launch(b, k, center, axis, pr):
@@ -1460,6 +1501,85 @@ def _pca_launch(b, k, center, axis, pr):
                       mesh, k, center, pr), build)
     scores, vec, sv, mu = fn(base)
     return type(b)(scores, split, mesh), vec, sv, mu
+
+
+def _sample_axes(b, axis):
+    """The sample axes a streamed source is asked for, sorted: ``axis``,
+    or its key axes."""
+    from bolt_tpu.utils import tupleize
+    return tuple(sorted(tupleize(axis))) if axis is not None \
+        else tuple(range(b.split))
+
+
+@lru_cache(maxsize=None)
+def _projection(lead, d, pr, center):
+    """The record-wise map of a streamed pca's second pass, ``(record,
+    vec[, off]) -> scores``: the record's ``lead`` sample axes kept, its
+    features merged as :func:`_features_last` merges them, projected by
+    the resident program's own :func:`_project`.  One function a
+    geometry, so that every request runs the program of the first."""
+    def project(rec, vec, *off):
+        x, _ = _features_last(rec, rec.shape[:lead], d)
+        out = _project(x, vec, pr)
+        return out - off[0] if center else out
+    return project
+
+
+def _scores_source(src, m, d, center, pr, vec, off):
+    """``src`` one stage longer: every record projected onto ``vec`` (and
+    ``off`` taken away), the two as side operands of the slab program."""
+    from bolt_tpu import stream
+    from bolt_tpu.utils import with_operands
+    st = stream.result_state(src)
+    ops = (vec, off) if center else (vec,)
+    return src.with_stage(("map", with_operands(
+        _projection(m - st.split, d, pr, center), *ops)))
+
+
+def _scores_plan_source(src, m, d, k, center, pr):
+    """:func:`_scores_source` with components of the right shape and dtype
+    and no values: what the scores' place is planned by before a slab
+    moves (``stream.collect_refusal``; ``analysis.check``'s BLT021)."""
+    from bolt_tpu import stream
+    wide = _widen(jnp.zeros((), stream.result_state(src).dtype), jnp).dtype
+    return _scores_source(src, m, d, center, pr, np.zeros((d, k), wide),
+                          np.zeros(k, wide))
+
+
+def _pca_streamed(b, k, center, axis, pr):
+    """:func:`pca` over a lazy out-of-core source in two passes of the
+    streamed executor, or NotImplemented where it does not take the
+    source (the caller materialises): both refusals are asked before a
+    slab moves.  Returns what :func:`_pca_launch` returns."""
+    from bolt_tpu import stream
+    from bolt_tpu.tpu.array import _cached_jit
+    src, axes = b._stream, _sample_axes(b, axis)
+    m = len(axes)
+    if stream.gram_refusal(src, axes, passes=2) is not None:
+        return NotImplemented
+    st = stream.result_state(src)
+    n, d = prod(st.shape[:m]), prod(st.shape[m:])
+    k = _pca_sizes(k, n, d)
+    if stream.collect_refusal(_scores_plan_source(
+            src, m, d, k, center, pr)) is not None:
+        # the scores have no resident place: the source materialises
+        # whole, or is refused in words (BLT020), as before
+        return NotImplemented
+    with _obs.span("linalg.pca.gram_pass"):
+        parts = stream.maybe_gram(b, axes, pr, sums=center, passes=2)
+    with _obs.span("linalg.pca.decompose"):
+        fn = _cached_jit(
+            ("ops-pca-decompose", n, d, k, center, pr, str(parts[0].dtype)),
+            lambda: jax.jit(lambda g, *total: _pca_decompose(
+                g, total[0] if total else None, n, k, pr)))
+        vec, sv, mu, off = fn(*parts)
+    with _obs.span("linalg.pca.project_pass"):
+        scores = stream.collect(
+            _scores_source(src, m, d, center, pr, vec, off), project=True)
+        # the scores keep the source's keys; the sample axes past them
+        # become keys as the resident program's alignment makes them
+        scores = scores._align(list(axes))
+    return scores, vec, sv, mu
 
 
 def tallskinny_pca(x, k=None):
@@ -1496,9 +1616,7 @@ def _samples_features(b, axis, name, hint=""):
         raise TypeError("%s expects a bolt array (mode 'local' or 'tpu')%s"
                         % (name, hint))
     if mode == "tpu":
-        axes = sorted(tupleize(axis)) if axis is not None \
-            else list(range(b.split))
-        b = b._align(axes)
+        b = b._align(list(_sample_axes(b, axis)))
         split = b.split
         x_full = None
         shape = b.shape
@@ -1533,13 +1651,19 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
     ``return_mean=True`` appends the per-feature mean.  Superset of the
     reference (its ecosystem computes this via per-chunk jobs).
     ``precision=None`` resolves through the scoped policy like
-    :func:`pca` (the Gram matmul is the cost)."""
+    :func:`pca` (the Gram matmul is the cost).  A lazy out-of-core source
+    is read in ONE pass of the streamed executor and never uploaded
+    whole, where :func:`pca` would stream its first pass."""
     from bolt_tpu._precision import resolve
     pr = resolve(precision)
+    if getattr(b, "mode", None) == "tpu" and b.streaming:
+        # a lazy out-of-core source: ONE pass of the streamed executor,
+        # where it takes the source (see pca)
+        out = _cov_streamed(b, axis, center, ddof, pr)
+        if out is not NotImplemented:
+            return _cov_fetch(out, return_mean)
     mode, b, x_full, split, shape, n, d = _samples_features(b, axis, "cov")
-    if n - ddof <= 0:
-        raise ValueError("cov needs more than ddof=%d samples, got %d"
-                         % (ddof, n))
+    _cov_sizes(n, ddof)
 
     if mode == "local":
         x = _widen(x_full.reshape(n, d), np)
@@ -1561,7 +1685,39 @@ def cov(b, axis=None, center=True, ddof=1, return_mean=False,
 
     fn = _cached_jit(("ops-cov", funcs, base.shape, str(base.dtype), split,
                       mesh, center, ddof, pr), build)
-    c, mu = fn(base)
+    return _cov_fetch(fn(base), return_mean)
+
+
+def _cov_sizes(n, ddof):
+    if n - ddof <= 0:
+        raise ValueError("cov needs more than ddof=%d samples, got %d"
+                         % (ddof, n))
+
+
+def _cov_streamed(b, axis, center, ddof, pr):
+    """:func:`cov`'s ``(c, mu)`` over a lazy out-of-core source, the
+    second moments folded slab by slab (``stream.maybe_gram``), or
+    NotImplemented where the executor does not take the source."""
+    from bolt_tpu import stream
+    from bolt_tpu.tpu.array import _cached_jit
+    axes = _sample_axes(b, axis)
+    if stream.gram_refusal(b._stream, axes) is not None:
+        return NotImplemented
+    shape = stream.result_state(b._stream).shape
+    n = prod(shape[:len(axes)])
+    _cov_sizes(n, ddof)
+    parts = stream.maybe_gram(b, axes, pr, sums=center, second_conj=True)
+    fn = _cached_jit(
+        ("ops-cov-finish", n, tuple(parts[0].shape), center, ddof,
+         str(parts[0].dtype)),
+        lambda: jax.jit(lambda c, *total: _cov_finish(
+            c, total[0] if total else None, n, ddof)))
+    return fn(*parts)
+
+
+def _cov_fetch(out, return_mean):
+    """``(c, mu)`` on the device -> what :func:`cov` returns."""
+    c, mu = out
     if return_mean:
         c, mu = jax.device_get((c, mu))    # one batched round-trip
         return np.asarray(c), np.asarray(mu)
@@ -1584,24 +1740,36 @@ def _cov_program(funcs, split, kshape, d, center, ddof, pr):
         # (mu/sigma)^2 relative error in the entries).  The mean's sums
         # come with the Gram matrix (see _pca_program)
         if not center:
-            mu = jnp.zeros(d, x.dtype)
-            c = _sample_gram(x, pr, second_conj=True, widened=widened)
+            c, total = _sample_gram(x, pr, second_conj=True,
+                                    widened=widened), None
         else:
             c, total = _sample_gram(x, pr, second_conj=True,
                                     widened=widened, sums=True)
-            mu = total / n
-            c = c - n * jnp.outer(mu, jnp.conj(mu))
-            # the explicit-centering path this fold replaced computed
-            # Xc^H Xc, whose diagonal (sum of squared moduli) cannot
-            # go negative; the fold can cancel past f32 precision for
-            # tiny-variance features on a large offset, so restore
-            # the invariant (mirrors _decompose_gram's eigenvalue
-            # clamp) — corrcoef's sqrt(diag) depends on it
-            idx = jnp.arange(d)
-            diag = jnp.maximum(jnp.real(c[idx, idx]), 0.0)
-            c = c.at[idx, idx].set(diag.astype(c.dtype))
-        return c / (n - ddof), mu
+        return _cov_finish(c, total, n, ddof)
     return program
+
+
+def _cov_finish(c, total, n, ddof):
+    """From the raw second moments ``c`` and the column sums ``total``
+    (``None``: not centred) of ``n`` samples to ``(covariance, mu)``.
+    Traced by the resident program and, on its own, behind a streamed
+    source's one pass."""
+    d = c.shape[-1]
+    if total is None:
+        mu = jnp.zeros(d, c.dtype)
+    else:
+        mu = total / n
+        c = c - n * jnp.outer(mu, jnp.conj(mu))
+        # the explicit-centering path this fold replaced computed
+        # Xc^H Xc, whose diagonal (sum of squared moduli) cannot
+        # go negative; the fold can cancel past f32 precision for
+        # tiny-variance features on a large offset, so restore
+        # the invariant (mirrors _decompose_gram's eigenvalue
+        # clamp) — corrcoef's sqrt(diag) depends on it
+        idx = jnp.arange(d)
+        diag = jnp.maximum(jnp.real(c[idx, idx]), 0.0)
+        c = c.at[idx, idx].set(diag.astype(c.dtype))
+    return c / (n - ddof), mu
 
 
 def corrcoef(b, axis=None, precision=None):

@@ -402,7 +402,7 @@ def alloc_program(plan, mesh):
 
 
 def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
-                  raw_slab_shape, delta_ok, unit):
+                  raw_slab_shape, delta_ok, unit, thin=False):
     """The ONE compiled program each input slab of the RESIDENT leg
     runs, ``(out, data, cursor, *operands) -> (out, cursor')``
     (``operands``: the side operands of the stages before the re-axis,
@@ -422,10 +422,14 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
     tiles on the minor axis the update is an aligned copy — half the
     device time of the same update at an offset it knows nothing about
     (PERF.md, PR 32).  Unsigned, because a signed index is first
-    wrapped (``select(i < 0, i + n, i)``), which hides those bits."""
+    wrapped (``select(i < 0, i + n, i)``), which hides those bits.
+
+    ``thin``: ``data`` is the dense form of a slab of thin records
+    (``stream._dense_views``), given its shape ``raw_slab_shape`` by
+    ``stream._reseat`` first, as a fold's slab program is handed it."""
     unit = int(unit)
     key = _program_key("stream-shuffle-place", plan, pre_stages, mesh,
-                       codec_obj, raw_dtype, raw_slab_shape) + (unit,)
+                       codec_obj, raw_dtype, raw_slab_shape) + (unit, thin)
 
     def build():
         from bolt_tpu.tpu.array import _constrain
@@ -434,7 +438,7 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
         j0 = plan.j0
         step = -(-int(raw_slab_shape[0]) // unit)
 
-        from bolt_tpu.stream import stage_extras
+        from bolt_tpu.stream import _reseat, stage_extras
         keyed, _ = stage_extras(pre_stages)
 
         def run(out, data, cursor, *operands):
@@ -442,7 +446,8 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
             first = cursor * jnp.uint32(unit)
             at = tuple(first if i == j0 else zero
                        for i in range(out.ndim))
-            block = body(data, first.astype(jnp.int32) if keyed else None,
+            block = body(_reseat(data) if thin else data,
+                         first.astype(jnp.int32) if keyed else None,
                          operands)
             out = jax.lax.dynamic_update_slice(out, block, at)
             return (_constrain(out, mesh, plan.new_split),

@@ -40,8 +40,9 @@ a recorded swap).  Every streamed run is the same four pieces::
   combine as a pairwise tree (``add``/``func`` for ``sum``/``reduce``,
   a Welford/Chan ``n, μ, M2`` merge for ``mean``/``var``/``std``:
   power-of-two slab counts keep the Chan denominators exact; component
-  by component for the tuples of a fused multi-stat group and of
-  ``ops.segment_reduce`` by a label function, :func:`maybe_group`).
+  by component for the tuples of a fused multi-stat group, of
+  ``ops.segment_reduce`` by a label function, :func:`maybe_group`, and
+  of a Gram matrix with its column sums, :func:`maybe_gram`).
   :func:`_resolve_one_swap` PLACES each slab into a resident re-keyed
   array (a recorded swap, or :func:`collect`'s mapped result) or SPILLS
   its buckets to files that stream again as a fresh source
@@ -497,6 +498,19 @@ def swap_budget(mesh=None):
     return _device_headroom(mesh)
 
 
+def place_budget(source):
+    """:func:`swap_budget` as the resident leg over ``source`` can spend
+    it: less the one thing its place program holds that the plan does not
+    count, the re-seat's copies of a slab of thin records
+    (:func:`dense_route`; two: rows of 64 are re-seated through two
+    copies of the slab, compiled for the v5e, rows of seven through
+    one)."""
+    budget = swap_budget(source.mesh)
+    if budget is not None and dense_route(source):
+        budget -= 2 * _raw_slab_bytes(source)
+    return budget
+
+
 def swap_ring(source):
     """Uploaded slabs a streamed-swap resolution over ``source`` keeps
     in flight: the prefetch depth plus the uploader pool — the ring the
@@ -568,26 +582,48 @@ _LANES = 128        # the lanes of the chip's (8, 128) tile of 32-bit words
 
 
 def thin_records(shape, dtype):
-    """Whether slabs of a source of ``shape``/``dtype`` go up DENSE: one
-    key axis and one value axis of 32-bit elements whose extent pads by
-    more than two under the 128 lanes of the chip's tile (``c < 64``).
-    The device holds such a slab with the rows on the lanes
-    (``f32[n,7]{0,1:T(8,128)}``), and the runtime transposes the loader's
-    row-major block on the HOST on its way there, at two thirds of what
-    the link carries (8.9 against 13.8 GB/s with two copies in flight:
-    PERF.md section 6, PR 51).  The same bytes as ``(n / 128, 128 * c)``
-    pad nothing and go up as fat records do; the slab program gives them
-    their shape on the device (:func:`_reseat`).  The rule reads the
-    record alone: nothing a caller sets."""
-    return (len(shape) == 2 and np.dtype(dtype).itemsize == 4
-            and 2 * int(shape[1]) < _LANES)
+    """Whether slabs of a source of ``shape``/``dtype`` go up DENSE: the
+    last axis of 32-bit elements whose extent pads by two or more under
+    the 128 lanes of the chip's tile (``c <= 64``), the records either
+    such rows themselves (``(n, c)``, one key axis) or PLANES of them
+    (``(K, N, c)`` with ``N`` whole groups of 128 rows).  The device holds
+    such a slab with the rows on the lanes (``f32[n,7]{0,1:T(8,128)}``,
+    ``f32[1,1048576,64]{1,2,0:T(8,128)}``), and the runtime re-tiles the
+    loader's row-major block on the HOST on its way there: at two thirds
+    of what the link carries for rows of seven (8.9 against 13.8 GB/s
+    with two copies in flight: PERF.md section 6, PR 51), at nine tenths
+    for rows of 64 (12.6 against 13.8, PR 55), and under the profiler an
+    event a tile, which a traced pass of 17 GB does not survive.  The
+    same bytes as rows of whole lanes pad nothing and go up as fat
+    records do; the slab program gives them their shape on the device
+    (:func:`_reseat`).  The rule reads the record alone: nothing a caller
+    sets."""
+    if len(shape) not in (2, 3) or np.dtype(dtype).itemsize != 4 \
+            or 2 * int(shape[-1]) > _LANES:
+        return False
+    return len(shape) == 2 or int(shape[1]) % _LANES == 0
+
+
+def dense_route(source):
+    """Whether ``source``'s slabs go up as :func:`_dense_views` of them
+    and are re-seated by their slab or place program: thin records for
+    ONE device (a codec's wire form and a pod's shards keep what they
+    had)."""
+    return (thin_records(source.shape, source.dtype)
+            and source.mesh.devices.size == 1
+            and _multihost.mesh_process_count(source.mesh) <= 1
+            and resolve_codec(source) is None)
 
 
 def _dense_views(block):
-    """The rows of a C-contiguous ``(n, c)`` block as zero-copy views
-    that pad nothing: the whole groups of 128 rows as ``(n // 128,
-    128 * c)``, then the rows past them as they are (under 128 of them:
-    once a slab, a few KB)."""
+    """A C-contiguous slab of thin records as zero-copy views that pad
+    nothing.  Rows ``(n, c)``: the whole groups of 128 rows as ``(n //
+    128, 128 * c)``, then the rows past them as they are (under 128 of
+    them: once a slab, a few KB).  Planes ``(k, N, c)``: every plane's
+    groups, ``(k, N // 128, 128 * c)``."""
+    if block.ndim == 3:
+        k, n, c = block.shape
+        return [block.reshape(k, n // _LANES, _LANES * c)]
     n, c = block.shape
     whole = n // _LANES * _LANES
     views = [block[:whole].reshape(whole // _LANES, _LANES * c)]
@@ -596,13 +632,31 @@ def _dense_views(block):
     return views
 
 
-def _reseat(parts):
-    """The ``(n, c)`` slab of :func:`_dense_views`' uploads, traced as the
-    slab program's first operation: ONE copy of a slab on the device
-    (0.7 ms of 64 MiB beside 4.9 ms of link; a plain ``reshape(n, c)``
-    goes through the row-major tiled form, seven padded to 128 lanes, and
-    costs GBs of temp: compiled for the v5e, ISSUE 51)."""
+def _dense_shape(parts):
+    """The shape of the slab that :func:`_dense_views`' uploads ``parts``
+    are the bytes of."""
     dense = parts[0]
+    if dense.ndim == 3:
+        return (dense.shape[0], dense.shape[1] * _LANES,
+                dense.shape[2] // _LANES)
+    return (sum(b.shape[0] for b in parts[1:]) + dense.shape[0] * _LANES,
+            dense.shape[1] // _LANES)
+
+
+def _reseat(parts):
+    """The slab of :func:`_dense_views`' uploads, traced as the slab
+    program's first operation: ONE copy of a slab on the device (0.7 ms of
+    64 MiB of rows of seven, 2.5 ms of a 268 MB plane, beside 4.9 and 21
+    ms of link; a plain ``reshape`` to the slab's shape goes through the
+    row-major tiled form, the row padded to 128 lanes, and costs GBs of
+    temp: compiled for the v5e, ISSUEs 51 and 55)."""
+    dense = parts[0]
+    if dense.ndim == 3:
+        k, groups, c = dense.shape[0], dense.shape[1], \
+            dense.shape[2] // _LANES
+        return jnp.swapaxes(
+            dense.reshape(k, groups, _LANES, c).transpose(0, 3, 1, 2)
+            .reshape(k, c, groups * _LANES), -1, -2)
     groups, c = dense.shape[0], dense.shape[1] // _LANES
     x = dense.reshape(groups, _LANES, c).transpose(2, 0, 1).reshape(
         c, groups * _LANES).T
@@ -1270,6 +1324,63 @@ def maybe_group(arr, label, value, nseg, op):
     return execute(arr, "group", group=(op, label, value, int(nseg)))
 
 
+def gram_refusal(source, axes, passes=1):
+    """Why the Gram matrix of ``source`` over the sample axes ``axes``
+    (``ops.pca`` / ``ops.cov``) cannot be folded slab by slab (the caller
+    then materialises, as it always did), or ``None`` where it can.
+    ``passes``: how often the caller reads the source (a ``pca`` reads it
+    twice: its scores are a second pass).  What streams: record-wise maps
+    in front, sample axes that are the leading axes and hold every key
+    axis (a slab is then whole samples, and the scores keep the source's
+    keys), one process."""
+    st = result_state(source)
+    if st.dynamic:
+        return ("the row count is dynamic (a filter's survivor count is "
+                "not known until the predicate has run): the scores' "
+                "place in the result cannot be planned")
+    if any(s[0] != "map" for s in source.stages):
+        return ("a %s stage is in front: record-wise maps alone are "
+                "traced into the Gram pass (a swap is resolved first, by "
+                "whatever materialises the source)"
+                % next(s[0] for s in source.stages if s[0] != "map"))
+    m = len(axes)
+    if tuple(axes) != tuple(range(m)) or not st.split <= m < len(st.shape):
+        return ("the sample axes %s are not the leading axes with every "
+                "key axis among them: the samples would have to be "
+                "re-axed first" % (tuple(axes),))
+    if st.n == 0 or source.shape[0] == 0:
+        return "the source is empty"
+    if _multihost.mesh_process_count(source.mesh) > 1:
+        return ("the mesh spans several processes (the slab program "
+                "under shard_map has no Gram partial: psum of it is the "
+                "seam)")
+    codec_obj = resolve_codec(source)
+    if codec_obj is not None and not codec_obj.lossless:
+        return ("the ingest codec %r is lossy (the materialised path "
+                "uploads the base unencoded)" % (codec_obj.name,))
+    if passes > 1 and source.kind != "callback" \
+            and iter(source.blocks) is source.blocks:
+        return ("the fromiter source is a one-shot iterator and the "
+                "caller reads it %d times" % passes)
+    return None
+
+
+def maybe_gram(arr, axes, precision, sums=False, second_conj=False,
+               passes=1):
+    """Fold the Gram matrix of a stream-backed array over its sample
+    axes ``axes`` slab by slab: ``(G,)``, or with ``sums`` ``(G, s)``,
+    device arrays ``(d, d)`` and ``(d,)`` with the features every axis
+    behind the samples, flattened: the sum over all slabs of
+    ``ops.linalg._sample_gram`` of a slab after its recorded stages,
+    which is the resident program's own body.  NotImplemented where
+    :func:`gram_refusal` says why (the caller materialises)."""
+    src = arr._stream
+    if src is None or gram_refusal(src, axes, passes) is not None:
+        return NotImplemented
+    return execute(arr, "gram", gram=(len(axes), precision,
+                                      bool(second_conj), bool(sums)))
+
+
 # ---------------------------------------------------------------------
 # per-slab programs and on-device partial merges
 # ---------------------------------------------------------------------
@@ -1287,7 +1398,8 @@ def _combine(terminal, rfunc, a, b, comps=None):
     fused tuple merge and the standalone merges share one arithmetic.
     ``terminal="group"`` (the grouped fold, :func:`maybe_group`) is such
     a tuple too: a group's every leaf by the fold's own merge, then the
-    int32 counts, which add."""
+    int32 counts, which add; ``terminal="gram"`` (:func:`maybe_gram`) the
+    Gram matrix and the column sums, which add."""
     if terminal in _TUPLED:
         return tuple(_combine(_COMP_MERGE[c], rfunc, x, y)
                      for c, x, y in zip(comps, a, b))
@@ -1315,8 +1427,9 @@ def _combine(terminal, rfunc, a, b, comps=None):
 _COMP_MERGE = {"sum": "sum", "min": "min", "max": "max",
                "moments": "moments"}
 # terminals whose partial is a TUPLE of components, each with a merge of
-# its own (``comps``): the fused multi-stat group and the grouped fold
-_TUPLED = ("multi", "group")
+# its own (``comps``): the fused multi-stat group, the grouped fold and
+# the Gram matrix with its sums
+_TUPLED = ("multi", "group", "gram")
 
 
 def _terminal_partial(terminal, flat, mask, mfull, vshape, n, rfunc,
@@ -1454,9 +1567,25 @@ def _group_partial(group, funcs, pred, post, x):
     return tuple(jax.tree_util.tree_leaves(folded)) + (counts,)
 
 
+def _gram_partial(gram, x):
+    """One slab's partial of the Gram terminal ``gram`` (``(sample axes,
+    precision, second_conj, sums)``) over the slab ``x`` as its stages
+    leave it: ``(G,)`` or ``(G, s)``.  The resident program's own body
+    (``ops/linalg.py :: _pca_program``): the features merged and widened
+    by ``_features_last``, the samples contracted where they lie by
+    ``_sample_gram``, which in a program for one TPU device is the
+    ``packed_gram`` kernel over the slab where it lies."""
+    from bolt_tpu.ops import linalg as _linalg
+    m, precision, second_conj, sums = gram
+    x, widened = _linalg._features_last(x, x.shape[:m], prod(x.shape[m:]))
+    out = _linalg._sample_gram(x, precision, second_conj=second_conj,
+                               widened=widened, sums=sums)
+    return tuple(out) if sums else (out,)
+
+
 def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
                   comps=None, sharded=False, codec_obj=None, group=None,
-                  thin=False):
+                  thin=False, gram=None):
     """The ONE compiled program each slab runs: device-side stages +
     (masked) terminal partial, with the slab buffer DONATED so the ring
     recycles its memory.  ``fused=True`` is the level-0 fold fusion: the
@@ -1468,8 +1597,9 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
     multi-stat layer (bolt_tpu/tpu/multistat.py); each component traces
     the exact standalone expression via :func:`_terminal_partial`.
     ``terminal="group"`` emits the grouped fold ``group``'s partial, a
-    tuple likewise (:func:`_group_partial`).  ``thin``: the uploaded
-    buffer is the dense form of a slab of thin records
+    tuple likewise (:func:`_group_partial`), ``terminal="gram"`` the
+    Gram matrix ``gram``'s (:func:`_gram_partial`).  ``thin``: the
+    uploaded buffer is the dense form of a slab of thin records
     (:func:`_dense_views`), given its shape by :func:`_reseat` first.
 
     ``codec_obj`` (ISSUE 14) is the ingest codec whose device-side
@@ -1515,7 +1645,7 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
            rfunc, comps, mesh,
            _multihost.topology_token() if sharded else None,
            codec_obj.name if codec_obj is not None else None,
-           stage_keys(post), group, thin, use_kernel)
+           stage_keys(post), group, thin, gram, use_kernel)
 
     def build():
         axes = _multihost.key_collective_axes(mesh, slab_shape, split) \
@@ -1556,6 +1686,9 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
                     tuple(s[1] for s in post), x)
             for stg in stages:
                 x = _stage_apply(stg, split, x, key0, operands)
+            if terminal == "gram":
+                # no filter (gram_refusal): every sample of the slab
+                return _gram_partial(gram, x)
             vshape = x.shape[split:]
             n = prod(x.shape[:split])
             flat = x.reshape((n,) + vshape)
@@ -1789,7 +1922,7 @@ def _stage_token(stage):
 
 
 def _run_fingerprint(source, terminal, ddof, rfunc, specs, codec=None,
-                     group=None):
+                     group=None, gram=None):
     """Identity of one LOGICAL streamed run for checkpoint matching:
     source geometry + slab plan + stage chain + terminal + ingest
     CODEC, with every user callable (stage funcs, the filter predicate,
@@ -1810,6 +1943,9 @@ def _run_fingerprint(source, terminal, ddof, rfunc, specs, codec=None,
         # label and value functions by bytecode like every callable
         members = "/".join(code_token(x) if callable(x) else repr(x)
                            for x in group)
+    if gram is not None:
+        # a Gram matrix's: its sample axes, precision, conjugation, sums
+        members = repr(gram)
     return ("bolt-stream-ckpt-v2", str(terminal), str(ddof),
             code_token(rfunc) if rfunc is not None else "",
             "x".join(str(s) for s in source.shape),
@@ -2173,7 +2309,7 @@ class _IngestPool:
         else:
             payload, side = _encode_slab(run.codec, block, run.delta_ok)
         if run.mspec is None and self._dense \
-                and payload.shape[0] >= _LANES \
+                and (payload.ndim == 3 or payload.shape[0] >= _LANES) \
                 and payload.flags.c_contiguous:
             buf = _upload_slab(payload, source.mesh, source.split, True)
         elif run.mspec is None:
@@ -2392,7 +2528,7 @@ def _multi_comps(specs):
 
 
 def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
-            source=None, group=None):
+            source=None, group=None, gram=None):
     """Run a streamed reduction terminal over ``arr``'s source: the
     parallel-ingest, async-dispatch pipeline described in the module
     docstring.  Returns a value-shaped ``BoltArrayTPU`` (``split=0``).
@@ -2412,12 +2548,19 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     nseg)``, a slab's partial the flat tuple of the folded leaves and
     the int32 counts (:func:`_group_partial`), merged component by
     component as the multi tuple is, and the return value the pair
-    ``(folded tree, counts)`` of bolt arrays keyed by group."""
+    ``(folded tree, counts)`` of bolt arrays keyed by group.
+
+    ``terminal="gram"`` streams a Gram matrix (:func:`maybe_gram`):
+    ``gram`` is ``(sample axes, precision, second_conj, sums)``, a slab's
+    partial ``(G,)`` or ``(G, s)`` (:func:`_gram_partial`), its
+    components added, and the return value that tuple of device
+    arrays."""
     from bolt_tpu.tpu.array import BoltArrayTPU
     if source is None:
         source = arr._stream
     comps = (_multi_comps(specs) if terminal == "multi"
              else _group_comps(source, group) if terminal == "group"
+             else ("sum",) * (1 + gram[3]) if terminal == "gram"
              else None)
     if arr is not None:
         _engine.strict_guard(arr, "stream.%s()" % terminal)
@@ -2487,7 +2630,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         ck_fp = _run_fingerprint(
             source, terminal, ddof, rfunc, specs,
             codec=codec_obj.name if codec_obj is not None else None,
-            group=group)
+            group=group, gram=gram)
         # the MESH's multiprocess answer, not the runtime's: a
         # process-local mesh inside a multi-process runtime checkpoints
         # single-process (its peers are elsewhere; a barrier would hang)
@@ -2530,9 +2673,8 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                         **({"codec": codec_obj.name}
                            if codec_obj is not None else {}))
     # thin records for ONE device go up dense, and the slab program
-    # re-seats them (a codec's wire form and a pod's shards do not)
-    dense = (codec_obj is None and mspec is None and mesh.devices.size == 1
-             and thin_records(source.shape, source.dtype))
+    # re-seats them
+    dense = dense_route(source)
     pool = _IngestPool(run, source, ring, jobs=jobs, blocks=blocks,
                        first=start_slab, parent=run_sp, dense=dense)
 
@@ -2544,6 +2686,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     compute = 0.0
     nslabs = 0
     nthin = 0                   # slabs that went up dense
+    ngramk = 0                  # slabs a packed_gram program folded
     fold = None
     pend = None                 # even slab's partial awaiting its pair
     pend_bytes = 0              # that slab's arbiter bytes, still held
@@ -2685,9 +2828,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 t0 = _clock()
                 thin = dense and isinstance(buf, tuple)
                 if thin:
-                    wshape = (sum(b.shape[0] for b in buf[1:])
-                              + buf[0].shape[0] * _LANES,
-                              buf[0].shape[1] // _LANES)
+                    wshape = _dense_shape(buf)
                     nthin += 1
                 else:
                     wshape = (buf[0].shape if isinstance(buf, tuple)
@@ -2737,7 +2878,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                     rfunc, fused=fused, comps=comps,
                                     sharded=mspec is not None,
                                     codec_obj=codec_obj, group=group,
-                                    thin=thin)
+                                    thin=thin, gram=gram)
                                 xsp = _obs.begin("stream.dispatch",
                                                  slab=slab_g)
                                 try:
@@ -2746,6 +2887,10 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                             else prog(buf, *extra))
                                 finally:
                                     _obs.end(xsp)
+                                if gram is not None:
+                                    # known once the call has lowered it
+                                    ngramk += getattr(prog, "gram_kernel",
+                                                      False)
                                 if fused:
                                     pairp = part
                                 else:
@@ -2852,7 +2997,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         fsp = _obs.begin("stream.fold", final=True)
         t0 = _clock()
         try:
-            if terminal in ("sum", "reduce"):
+            if terminal in ("sum", "reduce", "gram"):
                 out = fold.result()
             elif terminal == "multi":
                 out = _finalise_multi(fold.result(), comps, specs, mesh)
@@ -2889,7 +3034,9 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                               inflight=max(inflight_hw, 1),
                               keyed=nslabs if keyed else 0,
                               group=nslabs if group is not None else 0,
-                              thin=nthin)
+                              thin=nthin,
+                              gram=nslabs if gram is not None else 0,
+                              gram_kernel=ngramk)
         if result_state(source).pred is not None:
             # a filter that ended in this terminal: no buffer was built
             # for it, as for a resident deferred one
@@ -2900,7 +3047,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                        overlap_s=round(overlap, 6),
                        concurrent_uploaders=max(pool.high_water, 1),
                        inflight_high_water=max(inflight_hw, 1))
-        if terminal == "multi":
+        if terminal in ("multi", "gram"):
             return list(out)              # one jax array per member spec
         if terminal == "group":
             wrap = lambda o: BoltArrayTPU(o, 1, mesh)      # noqa: E731
@@ -3050,7 +3197,7 @@ def collect_plan(source):
     nslabs = max(1, -(-source.shape[0] // max(source.slab, 1)))
     return _shuffle.plan_shuffle(
         st.shape, st.dtype, st.split, tuple(range(len(st.shape))),
-        st.split, source.mesh, source.slab, swap_budget(source.mesh),
+        st.split, source.mesh, source.slab, place_budget(source),
         None, ring=min(swap_ring(source), nslabs),
         raw_slab_bytes=_raw_slab_bytes(source))
 
@@ -3063,7 +3210,7 @@ def _raw_slab_bytes(source):
     return min(source.slab, source.shape[0]) * prod(source.shape[1:]) * item
 
 
-def collect(source):
+def collect(source, project=False):
     """The CONCRETE array of a mapped stream source, assembled slab by
     slab: every slab goes up through the uploader pool, ONE program a
     slab applies the recorded stages and places the slab's records into
@@ -3075,11 +3222,12 @@ def collect(source):
     words what it cannot take (:func:`collect_refusal`), a result past
     the resident budget among them (it has no other sink:
     :func:`materialize` then says whether the device can hold the source
-    whole, BLT020)."""
+    whole, BLT020).  ``project``: the pass is a streamed ``ops.pca``'s
+    second, and its slabs count as ``stream_project_slabs`` too."""
     why = collect_refusal(source)
     if why is not None:
         raise ValueError("stream.collect: %s" % why)
-    return _resolve_one_swap(source, collect=True)
+    return _resolve_one_swap(source, collect=True, project=project)
 
 
 def _replay_stages(b, stages):
@@ -3221,7 +3369,7 @@ def _owned_buckets(part, out_block):
     return sorted(owned)
 
 
-def _resolve_one_swap(source, collect=False):
+def _resolve_one_swap(source, collect=False, project=False):
     """Resolve the FIRST recorded swap of ``source`` via the two-phase
     streaming shuffle (module docstring of
     ``bolt_tpu.parallel.shuffle``): phase 1 streams input slabs through
@@ -3269,7 +3417,7 @@ def _resolve_one_swap(source, collect=False):
     else:
         plan = _shuffle.plan_shuffle(st.shape, st.dtype, st.split, perm,
                                      new_split, mesh, base.slab,
-                                     swap_budget(mesh), spill_dir,
+                                     place_budget(base), spill_dir,
                                      ring=swap_ring(base),
                                      raw_slab_bytes=_raw_slab_bytes(base))
     if keyed and not plan.resident:
@@ -3337,9 +3485,13 @@ def _resolve_one_swap(source, collect=False):
                         out_block=plan.out_block,
                         alltoall_bytes=plan.alltoall_bytes,
                         devices=plan.devices)
+    # thin records for ONE device go up dense and the place program
+    # re-seats them, as execute's slab programs do (the spill leg's
+    # program takes the slab as the loader hands it over)
+    dense = plan.resident and dense_route(base)
     # a one-shot iterable cannot resume, so `done` is empty without jobs
     pool = _IngestPool(run, base, plan.ring, jobs=jobs,
-                       noun="shuffle slab", parent=run_sp)
+                       noun="shuffle slab", parent=run_sp, dense=dense)
 
     def _spill_part(part, g):
         """Extract and persist every LOCALLY-OWNED bucket of slab
@@ -3373,6 +3525,7 @@ def _resolve_one_swap(source, collect=False):
     t_start = _clock()
     moved = 0
     placed = 0
+    nthin = 0                   # slabs that went up dense
     ingest = compute = 0.0
     out = cursor = None
     if plan.resident:
@@ -3398,8 +3551,13 @@ def _resolve_one_swap(source, collect=False):
             g, buf, bnb, tsec, _ = got
             ingest += tsec
             t0 = _clock()
-            wshape = (buf[0].shape if isinstance(buf, tuple)
-                      else buf.shape)
+            thin = dense and isinstance(buf, tuple)
+            nthin += thin
+            if thin:
+                wshape = _dense_shape(buf)
+            else:
+                wshape = (buf[0].shape if isinstance(buf, tuple)
+                          else buf.shape)
             csp = _obs.begin("stream.compute", slab=g, shuffle=True)
             attempt = 0
             prev = None
@@ -3414,7 +3572,7 @@ def _resolve_one_swap(source, collect=False):
                         if plan.resident:
                             prog = _shuffle.place_program(
                                 plan, pre, mesh, run.codec, source.dtype,
-                                wshape, run.delta_ok, unit)
+                                wshape, run.delta_ok, unit, thin)
                         else:
                             prog = _shuffle.rebucket_program(
                                 plan, pre, mesh, run.codec, source.dtype,
@@ -3482,7 +3640,7 @@ def _resolve_one_swap(source, collect=False):
         # this run moved (a resumed spill skips slabs)
         crossed = plan.alltoall_bytes * moved // max(plan.total_bytes, 1)
         if collect:
-            _engine.record_collect(placed, moved)
+            _engine.record_collect(placed, moved, project)
         else:
             _engine.record_shuffle(moved, wall, alltoall=crossed)
         if run_sp is not None:
@@ -3494,7 +3652,7 @@ def _resolve_one_swap(source, collect=False):
     _engine.record_stream(placed, ingest, compute, wall,
                           max(0.0, ingest + compute - wall), run.depth,
                           uploaders=max(pool.high_water, 1),
-                          keyed=placed if keyed else 0)
+                          keyed=placed if keyed else 0, thin=nthin)
 
     if plan.resident:
         if not placed:

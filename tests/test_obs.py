@@ -334,6 +334,8 @@ _EXPECTED_ENGINE_KEYS = {
     "stream_collect_slabs": False, "stream_collect_bytes": False,
     "stream_keyed_slabs": False, "stream_group_slabs": False,
     "stream_thin_slabs": False,
+    "stream_gram_slabs": False, "stream_gram_kernel_slabs": False,
+    "stream_project_slabs": False,
     "stream_alltoall_bytes": False, "stream_upload_parts": False,
 }
 

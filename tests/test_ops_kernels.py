@@ -1996,3 +1996,131 @@ def test_fit_s_slab_program_is_float32_products_in_one_layout_on_v5e(
     assert mem.temp_size_in_bytes < 0.3e9
     assert mem.output_size_in_bytes < 1 << 20     # the displacements alone
 
+
+
+# ---------------------------------------------------------------------
+# compile-only: the two slab programs of a series PCA over a recording
+# past HBM (ISSUE 55; ``series64-streamed-1chip.scan_pca``): 64 planes of
+# (1048576, 64) float32, 17.18 GB, a slab is one plane.  A plane arrives
+# as a dense view of its bytes (rows of 8,192 lanes: the loader's
+# row-major plane re-tiled on the host floods the profiler) and the
+# program's first operation re-seats it, ONE copy of a slab.  Pass 1's
+# program is then ONE ``packed_gram_sums`` call over a bitcast of that
+# and takes no other temporary of a slab's size; pass 2's projects a
+# plane and places its scores into the 2.15 GB result in place
+# ---------------------------------------------------------------------
+
+_RECORDING = (64, 1048576, 64)        # planes x voxels x time points
+_PLANE = (1,) + _RECORDING[1:]
+_DENSE_PLANE = (1, 8192, 8192)        # the same bytes, nothing padded
+
+
+def _streamed_recording(v5e_device):
+    from bolt_tpu import stream
+    mesh = _series_mesh(v5e_device)
+    src = stream.StreamSource.from_callback(lambda index: None, _RECORDING,
+                                            1, np.float32, mesh)
+    assert src.slab == 1 and len(src.slab_ranges()) == 64
+    assert stream.thin_records(src.shape, src.dtype)
+    assert stream.dense_route(src)
+    assert stream.gram_refusal(src, (0, 1), passes=2) is None
+    return src, mesh
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["first", "acc-fused"])
+@pytest.mark.parametrize("center", [True, False],
+                         ids=["centred", "uncentred"])
+def test_a_plane_is_one_packed_gram_over_a_bitcast_on_v5e(v5e_device,
+                                                          center, fused):
+    import warnings
+    import jax
+    from bolt_tpu import engine, stream
+    from bolt_tpu.ops import linalg
+    src, mesh = _streamed_recording(v5e_device)
+    gram = (2, "highest", False, center)
+    kw = {"gram": gram, "comps": ("sum",) * (1 + center), "thin": True}
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    slab = (jax.ShapeDtypeStruct(_DENSE_PLANE, np.float32, sharding=where),)
+    placed = engine.gram_kernel_lowerings()
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not")
+        first = stream._slab_program(src, "gram", _PLANE, None, None,
+                                     **kw).lower(slab)
+        if fused:
+            acc = jax.tree_util.tree_map(
+                lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype,
+                                               sharding=where),
+                first.out_info)
+            compiled = stream._slab_program(
+                src, "gram", _PLANE, None, None, fused=True,
+                **kw).lower(slab, acc).compile()
+        else:
+            compiled = first.compile()
+    # the lowering said so, which is what stream_gram_kernel_slabs counts
+    # (jax keeps a lowering it has made: "first" may be the last case's)
+    assert engine.gram_kernel_lowerings() > placed
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    slab_bytes = 4 * int(np.prod(_PLANE))
+    # the dense form pads nothing: the argument is the plane's own bytes
+    assert slab_bytes <= mem.argument_size_in_bytes < slab_bytes + 65536
+    # the re-seat of rows of 64 is TWO copies of a plane (the compiler
+    # transposes the (8192, 8192) view whole, then regroups it), where
+    # rows of seven take one: what place_budget takes off the budget
+    assert slab_bytes <= mem.temp_size_in_bytes < 2 * slab_bytes + (1 << 20)
+    assert mem.output_size_in_bytes < 65536       # (64, 64) and (64,)
+    # ONE kernel call over the re-seated plane, and no matrix-unit fusion
+    # with a (64, 64) result beside it
+    assert text.count("tpu_custom_call") == 1
+    calls = _kernel_calls(text, linalg._GRAM_SUMS_KERNEL_NAME if center
+                          else linalg._GRAM_KERNEL_NAME)
+    assert len(calls) == 1, calls
+    assert not [ln for ln in text.splitlines() if "convolution" in ln
+                and "f32[64,64]" in ln.split("=")[1][:40]]
+    assert len(_slab_sized_moves(text, _PLANE)) == 2      # the re-seat
+
+
+def test_the_scores_are_placed_into_their_result_in_place_on_v5e(
+        v5e_device):
+    import warnings
+    import jax
+    from bolt_tpu import stream
+    from bolt_tpu.ops import linalg
+    from bolt_tpu.parallel import shuffle
+    src, mesh = _streamed_recording(v5e_device)
+    k = 8
+    scored = linalg._scores_source(src, 2, 64, True, "highest",
+                                   np.zeros((64, k), np.float32),
+                                   np.zeros(k, np.float32))
+    assert stream.result_state(scored).shape == _RECORDING[:2] + (k,)
+    # the rule the run decides by finds the scores inside a v5e's budget
+    plan = shuffle.plan_shuffle(
+        _RECORDING[:2] + (k,), np.float32, 1, (0, 1, 2), 1, mesh, 1,
+        int(0.9 * _V5E_HBM) - 2 * 4 * int(np.prod(_PLANE)), None, ring=4,
+        raw_slab_bytes=4 * int(np.prod(_PLANE)))
+    out_bytes = 4 * int(np.prod(plan.out_shape))
+    assert plan.resident and plan.out_shape == _RECORDING[:2] + (k,)
+    assert out_bytes == 2147483648
+    assert out_bytes + 4 * 268435456 <= plan.resident_bytes < 4e9
+    program = shuffle.place_program(plan, scored.stages, mesh, None,
+                                    np.float32, _PLANE, True, 1, True)
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not")
+        compiled = program.lower(
+            spec(plan.out_shape, _F32), (spec(_DENSE_PLANE, _F32),),
+            spec((), np.uint32), spec((64, k), _F32),
+            spec((k,), _F32)).compile()
+    mem = compiled.memory_analysis()
+    assert out_bytes <= mem.output_size_in_bytes < out_bytes + 4096
+    assert out_bytes <= mem.alias_size_in_bytes          # in place
+    # the re-seat's two copies of a plane (537 MB: what place_budget
+    # takes off the budget) and a plane's scores (33.5 MB)
+    assert mem.temp_size_in_bytes < 2 * 268435456 + 0.1e9
+    text = compiled.as_text()
+    assert "operand_precision={highest,highest}" in text
+    assert "tpu_custom_call" not in text

@@ -36,9 +36,14 @@ def _source(x, mesh, chunks=None, **kw):
     ((1000, 1), np.float32, True), ((1000, 7), np.float32, True),
     ((1000, 8), np.float32, True), ((1000, 9), np.float32, True),
     ((1000, 63), np.int32, True), ((1000, 7), np.uint32, True),
-    ((1000, 64), np.float32, False), ((1000, 128), np.float32, False),
+    ((1000, 64), np.float32, True), ((1000, 65), np.float32, False),
+    ((1000, 128), np.float32, False),
     ((1000, 7), np.float64, False), ((1000, 7), np.int16, False),
     ((1000, 256, 128), np.float32, False), ((1000, 2, 7), np.float32, False),
+    # planes of thin rows, whole groups of 128 rows a plane (ISSUE 55)
+    ((64, 1048576, 64), np.float32, True), ((5, 256, 16), np.int32, True),
+    ((5, 200, 16), np.float32, False), ((5, 256, 65), np.float32, False),
+    ((2, 5, 256, 16), np.float32, False),
     ((1000,), np.float32, False)])
 def test_the_rule_reads_the_record_alone(shape, dtype, thin):
     assert stream.thin_records(shape, dtype) is thin
@@ -108,7 +113,7 @@ def test_what_is_not_thin_goes_up_as_it_did(one, mesh, monkeypatch):
     fat = _table(512, 128, np.float32)
     assert run(fat, one) == 0                    # a fat record
     assert seen == [((256, 128), ())] * 2        # the parent's call
-    assert run(_table(512, 64, np.float32), one) == 0    # pads by two
+    assert run(_table(512, 65, np.float32), one) == 0    # pads by under two
     thin = _table(512, 7, np.float32)
     assert run(thin, one) == 2
     assert seen == [((256, 7), (True,))] * 2
@@ -118,14 +123,50 @@ def test_what_is_not_thin_goes_up_as_it_did(one, mesh, monkeypatch):
     assert run(_table(512, 7, np.float64), one) == 0     # 64-bit
 
 
-def test_a_thin_swap_and_a_collect_keep_their_uploads(one):
-    """The resolver's programs take the slab as it is: only the fold's
-    pool lays it dense."""
+def test_a_collect_and_a_resident_swap_of_thin_records_go_up_dense(one):
+    """The resolver's resident leg takes a thin slab as the fold does
+    (ISSUE 55): dense up the link, re-seated by the place program, and
+    what it assembles is the materialised result to the bit."""
     x = _table(1024, 6, np.float32)
     c0 = engine.counters()["stream_thin_slabs"]
     mapped = _source(x, one, 256).map(lambda r: r + 1)
     assert np.array_equal(mapped.toarray(), x + 1)
-    assert engine.counters()["stream_thin_slabs"] == c0
+    assert engine.counters()["stream_thin_slabs"] - c0 == 4
+    swapped = _source(x, one, 256).swap((0,), (0,))
+    assert np.array_equal(swapped.toarray(), x.T)
+    assert engine.counters()["stream_thin_slabs"] - c0 == 8
+    # the re-seat's copies of a slab come off the resident budget
+    src = mapped._stream if mapped.streaming else _source(
+        x, one, 256).map(lambda r: r + 1)._stream
+    with stream.spill(budget=10 ** 6):
+        assert stream.place_budget(src) == 10 ** 6 - 2 * 256 * 6 * 4
+
+
+@pytest.mark.parametrize("planes", [1, 2, 3], ids=lambda k: "slab%d" % k)
+@pytest.mark.parametrize("cols", [8, 64])
+def test_planes_of_thin_rows_go_up_dense_and_read_back_to_the_bit(
+        one, planes, cols):
+    rng = np.random.default_rng(5)
+    x = rng.integers(-1000, 1000, size=(5, 256, cols)).astype(np.float32)
+    views = stream._dense_views(x[:planes])
+    assert len(views) == 1 and np.shares_memory(views[0], x)
+    assert views[0].shape == (planes, 2, 128 * cols)
+    parts = tuple(jax.device_put(v) for v in views)
+    assert stream._dense_shape(parts) == (planes, 256, cols)
+    back = jax.jit(stream._reseat)(parts)
+    assert np.array_equal(np.asarray(back), x[:planes])
+    # through the executor, both consumers: a fold and a collect
+    src = bolt.fromcallback(lambda idx: x[idx], x.shape, one,
+                            dtype=x.dtype, chunks=planes)
+    c0 = engine.counters()["stream_thin_slabs"]
+    assert np.array_equal(src.sum().toarray(), x.sum(axis=0))
+    nslabs = -(-5 // planes)
+    assert engine.counters()["stream_thin_slabs"] - c0 == nslabs
+    mapped = bolt.fromcallback(lambda idx: x[idx], x.shape, one,
+                               dtype=x.dtype, chunks=planes).map(
+        lambda v: v[:, :2] * 2)
+    assert np.array_equal(mapped.toarray(), x[:, :, :2] * 2)
+    assert engine.counters()["stream_thin_slabs"] - c0 == 2 * nslabs
 
 
 # what ``stack4d-1chip.stream``'s two slab programs read as at the parent
