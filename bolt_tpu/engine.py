@@ -155,7 +155,12 @@ _SCHEMA = {
                                    # the parallel-ingest proof)
     "stream_inflight_high_water": 0,  # high-water slab programs
                                       # dispatched but not yet confirmed
-                                      # complete (the async window)
+                                      # complete (the async window:
+                                      # execute's, and the swap / collect
+                                      # resolver's own since PR 56)
+    "stream_windowed_slabs": 0,   # place calls the swap / collect
+                                  # resolver dispatched while an earlier
+                                  # one was still unconfirmed
     # fault-tolerance accounting (ISSUE 9: resumable streams).  A retry
     # is one re-attempted slab ingest (stream.retries / the serve layer's
     # per-submit retries); a resume is one streamed run that restarted
@@ -1060,20 +1065,24 @@ def record_checkpoint(nbytes, seconds):
 
 def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                   uploaders=1, inflight=1, keyed=0, group=0, thin=0,
-                  gram=0, gram_kernel=0):
+                  gram=0, gram_kernel=0, windowed=0):
     """Tally one completed streamed run (bolt_tpu.stream executor); the
     keys apply atomically — a snapshot can never see a run's wall time
     without its overlap.  Called by the run's own thread as the run ends,
     so what that thread spent lowering and compiling since ``wall_s`` ago
     is the run's (``stream_compile_seconds``).  ``uploaders`` is the run's
     observed concurrent uploader high-water, ``inflight`` its
-    dispatched-but-unconfirmed slab-program high-water; both (and the
-    depth) keep process maxima.  ``keyed``: of ``chunks``, the slabs
-    whose program took the slab's first key as an operand; ``group``:
+    dispatched-but-unconfirmed slab-program high-water (``execute``'s
+    window of pair partials, and the swap / collect resolver's window of
+    place calls: 1 where it confirms every call before the next); both
+    (and the depth) keep process maxima.  ``keyed``: of ``chunks``, the
+    slabs whose program took the slab's first key as an operand; ``group``:
     those a grouped terminal folded; ``thin``: those that went up dense
     and were re-seated on the device; ``gram``: those the Gram terminal
     folded, ``gram_kernel`` of them by a program lowered with the
-    ``packed_gram`` kernel."""
+    ``packed_gram`` kernel; ``windowed``: those whose place call the swap
+    / collect resolver dispatched while an earlier one was still
+    unconfirmed (its window at work: 0 at ``prefetch(1)``)."""
     _COUNTERS.update(_maxima={"stream_prefetch_depth": int(depth),
                               "stream_upload_threads": int(uploaders),
                               "stream_inflight_high_water": int(inflight)},
@@ -1083,6 +1092,7 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                      stream_thin_slabs=int(thin),
                      stream_gram_slabs=int(gram),
                      stream_gram_kernel_slabs=int(gram_kernel),
+                     stream_windowed_slabs=int(windowed),
                      stream_ingest_seconds=ingest_s,
                      stream_compute_seconds=compute_s,
                      stream_wall_seconds=wall_s,

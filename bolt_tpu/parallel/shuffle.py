@@ -55,8 +55,13 @@ BLT017 forecast), so the forecast and the measured decision cannot
 drift: both read the same resident/spill rule off the same budget
 (``stream.swap_budget``: a ``spill`` scope's, the serving arbiter's,
 else the device's own free memory).  The rule counts what the resident
-programs hold: ``resident_bytes`` = the output + ``ring + 1`` slabs
-(the uploaded slabs in flight and one program's transposed temp).
+programs hold: ``resident_bytes`` = the output + ``ring + 1`` slabs.
+``ring`` (``stream.swap_ring``) is the resolver's window of dispatched,
+unconfirmed place calls, whose donated slabs the device frees a call at
+a time, plus one uploaded slab in the hand of every pool worker; the
+one more is ONE program's transposed temp, because the calls of a
+window run one after another on the device (each is handed the array
+the call before it returns).
 """
 
 import numpy as np
@@ -153,7 +158,8 @@ def plan_shuffle(staged_shape, dtype, split, perm, new_split, mesh,
     the input records per slab; ``budget`` the resident ceiling in
     bytes (``None`` = unbounded → always resident); ``spill_dir``
     where bucket files would land; ``ring`` the uploaded slabs the
-    executor keeps in flight (``stream.swap_ring``);
+    executor keeps on the device, uploading or handed to a place call
+    not yet confirmed (``stream.swap_ring``);
     ``raw_slab_bytes`` what ONE of them holds as uploaded, where the
     stages before the re-axis change a slab's size (a collect of small
     mapped records rings slabs far larger than what it places).  The

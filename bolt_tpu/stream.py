@@ -44,9 +44,13 @@ a recorded swap).  Every streamed run is the same four pieces::
   ``ops.segment_reduce`` by a label function, :func:`maybe_group`, and
   of a Gram matrix with its column sums, :func:`maybe_gram`).
   :func:`_resolve_one_swap` PLACES each slab into a resident re-keyed
-  array (a recorded swap, or :func:`collect`'s mapped result) or SPILLS
-  its buckets to files that stream again as a fresh source
-  (``bolt_tpu.parallel.shuffle``).
+  array (a recorded swap, or :func:`collect`'s mapped result), its
+  place calls dispatched into a bounded window of its own (the prefetch
+  depth and a step unconfirmed at most, :func:`swap_ring`, each
+  confirmed by the cursor it returned: the array it wrote into is
+  donated into the next call), or
+  SPILLS its buckets, a block a slab, to files that stream again as a
+  fresh source (``bolt_tpu.parallel.shuffle``).
 
 The per-slab program applies the SAME traced bodies the materialised
 paths compile (``tpu/chunk.py :: _uniform_map_body`` /
@@ -164,6 +168,22 @@ _UPLOADERS = max(0, int(os.environ.get("BOLT_STREAM_UPLOAD_THREADS",
 # MiB: 9.44, 14.03, 13.98, 14.05; one thread keeping N puts issued reads
 # the same as N threads).  2 is the smallest count within 2 % of the best.
 _LINK_COPIES = 2
+
+# place calls the swap / collect resolver keeps dispatched and
+# unconfirmed BEYOND the prefetch depth (swap_ring grows by as many, so
+# the window and the ring move together and plan_shuffle counts them).
+# A place call is done about one slab period after its dispatch, because
+# it starts behind the uploads in flight when it was issued (8.3 ms of a
+# 9.5 ms period in `toseries`): at a window of 2 the consumer confirms a
+# call that is only just finishing, at 3 one long done.  MEASURED
+# (PERF.md section 5, PR 56; scripts/swap_window_probe.py on a v5e host,
+# GB/s of a request at windows 1 / 2 / 3 / 4, two rounds): `toseries`
+# 10.9-11.3 / 13.93-13.96 / 13.97 / 13.97, the link's own rate from 2
+# on, with the consumer blocked 0.87 / 0.004 of the pass at 2 / 3;
+# `register`, whose `fit` pass has 3.3 ms of device work a slab,
+# 8.9-10.6 / 11.8-12.6 / 13.71 / 13.5-13.7; a permit is one slab of HBM
+# (134 MB there).  One step is the smallest that leaves no pass short.
+_SWAP_WINDOW_STEP = 1
 
 # the prefetch()/uploaders() SCOPES are thread-local (like
 # engine.donation and bolt.precision): under the multi-tenant serving
@@ -513,10 +533,17 @@ def place_budget(source):
 
 def swap_ring(source):
     """Uploaded slabs a streamed-swap resolution over ``source`` keeps
-    in flight: the prefetch depth plus the uploader pool — the ring the
-    resolver's permits bound, and what ``plan_shuffle`` counts beside
-    the output."""
-    return prefetch_depth() + pool_size(source)
+    on the device: the resolver's window of dispatched, unconfirmed
+    place calls (a slab's permit goes back when its call is confirmed)
+    plus one in the hand of every worker of the uploader pool — the ring
+    the resolver's permits bound, and what ``plan_shuffle`` counts beside
+    the output.  The window is the prefetch depth and
+    ``_SWAP_WINDOW_STEP`` more; ``prefetch(1)`` stays one call at a
+    time."""
+    depth = prefetch_depth()
+    if depth > 1:
+        depth += _SWAP_WINDOW_STEP
+    return depth + pool_size(source)
 
 
 def pool_size(source):
@@ -2510,6 +2537,13 @@ def _pod_sync(x, pod, phase, slab=None):
         _podwatch.reraise(exc, phase=phase, slab=slab)
 
 
+def _retired(handle):
+    """Whether the slab program that returned ``handle`` is done, asked
+    without blocking (through the module-level name: the tests' patch
+    point, where a CPU's programs are done as soon as dispatched)."""
+    return handle.is_ready()
+
+
 def _multi_comps(specs):
     """Canonical component tuple for a fused multi-stat spec list —
     ONE 'moments' triple serves every mean/var/std member, 'min'/'max'
@@ -3528,6 +3562,18 @@ def _resolve_one_swap(source, collect=False, project=False):
     nthin = 0                   # slabs that went up dense
     ingest = compute = 0.0
     out = cursor = None
+    # place calls dispatched and not yet confirmed, oldest first: (slab,
+    # handle, the slab's lease bytes).  The resident leg keeps up to
+    # `window` of them, what the ring holds beyond a slab in every
+    # worker's hand, as execute does: call g's `out` is donated into
+    # call g + 1, so what it is confirmed by is the cursor it returned,
+    # a fresh scalar ready when the call is done, and nothing else of
+    # the slab is held.  The spill leg reads its part on the host, one
+    # block a slab.
+    inflight = deque()
+    window = max(1, plan.ring - run.nwork) if plan.resident else 1
+    windowed = 0                # calls dispatched behind an unconfirmed one
+    inflight_hw = 1
     if plan.resident:
         # phase 2 in place: the swapped array exists ONCE, from here
         # on; every slab's program is handed it (donated) and hands it
@@ -3536,13 +3582,65 @@ def _resolve_one_swap(source, collect=False, project=False):
         # g * slab) and records for an iterator's own blocks
         out, cursor = _shuffle.alloc_program(plan, mesh)()
         unit = base.slab if base.kind == "callback" else 1
+
+    def _confirm_oldest():
+        """Block for the OLDEST unconfirmed call (the spill leg: and
+        persist its part), then hand its ring permit and lease bytes
+        back.  A failure here is final: the slab the call was handed is
+        donated, and so is the array it wrote into, to the calls behind
+        it, so nothing is left to dispatch again.  The entry leaves the
+        window only once it is confirmed: what a failure leaves there
+        the run's way out gives back."""
+        nonlocal compute
+        g, handle, nb = inflight[0]
+        t0 = _clock()
+        ssp = _obs.begin("stream.sync", slabs=1, shuffle=True, slab=g)
+        try:
+            _pod_sync(handle, pod, "shuffle re-bucket", slab=g)
+        except _podwatch.PeerLostError:
+            raise
+        except Exception as exc:  # noqa: BLE001
+            raise RuntimeError(
+                "shuffle slab %d failed at the confirm of its call "
+                "(%s: %s); what the call was handed is donated (the "
+                "slab, and the array the calls dispatched behind it "
+                "write into), so it cannot be retried in place and the "
+                "run ends here" % (g, type(exc).__name__, exc)) from exc
+        finally:
+            _obs.end(ssp)
+        if not plan.resident:
+            _spill_part(handle, g)
+        inflight.popleft()
+        del handle              # a spilled part goes before its permit
+        compute += _clock() - t0
+        pool.give_back(1, nb)
+
+    def _retire(keep):
+        """Confirm the oldest calls, blocking, until at most ``keep``
+        stay unconfirmed, and WITHOUT blocking every head of the window
+        that is done already: its permit goes back when the device lets
+        go of the slab, not a slab later."""
+        while inflight and (len(inflight) > keep
+                            or _retired(inflight[0][1])):
+            _confirm_oldest()
+
+    def _starved():
+        """``execute``'s valve (``pool.next``'s ``idle``): with the
+        feeder possibly blocked on budget bytes, confirm one call per
+        empty poll so its bytes recycle, and a budget smaller than the
+        full ring runs a shallower window instead of deadlocking."""
+        if inflight and run.lease.arbiter.waiting():
+            _confirm_oldest()
+
     pool.start()
     if pod:
         _podwatch.pod_enter()
     ready_done = False
     try:
         while True:
-            got = pool.next()
+            _retire(window)
+            got = pool.next(idle=_starved if run.lease is not None
+                            else None)
             if got is None:
                 break
             if pod and not ready_done:
@@ -3567,7 +3665,8 @@ def _resolve_one_swap(source, collect=False, project=False):
                         # the chaos seam fires BEFORE the dispatch, so
                         # an injected raise leaves the donated buffers
                         # intact — the in-place retry (same fence as
-                        # ingest retries) re-dispatches them verbatim
+                        # ingest retries) re-dispatches them verbatim,
+                        # whatever is still unconfirmed in front
                         _chaos.hit("stream.shuffle")
                         if plan.resident:
                             prog = _shuffle.place_program(
@@ -3600,18 +3699,11 @@ def _resolve_one_swap(source, collect=False, project=False):
                                                            cursor, *side)
                                     finally:
                                         _obs.end(psp)
-                                    part = out
+                                    handle = cursor
                                 else:
-                                    part = prog(buf, *side)
+                                    handle = prog(buf, *side)
                             finally:
                                 _obs.end(xsp)
-                        ssp = _obs.begin("stream.sync", slabs=1,
-                                         shuffle=True)
-                        try:
-                            _pod_sync(part, pod, "shuffle re-bucket",
-                                      slab=g)
-                        finally:
-                            _obs.end(ssp)
                         break
                     except _podwatch.PeerLostError:
                         raise
@@ -3619,18 +3711,25 @@ def _resolve_one_swap(source, collect=False, project=False):
                         prev = pool.retry(g, attempt, prev, exc,
                                           "shuffle dispatch")
                         attempt += 1
+                del buf, got
+                moved += wshape[0] * (plan.total_bytes // plan.in_shape[0])
+                placed += 1
+                windowed += bool(inflight)
+                inflight.append((g, handle, bnb))
+                del handle
+                inflight_hw = max(inflight_hw, len(inflight))
+                compute += _clock() - t0
+                _retire(window - 1)
             finally:
                 _obs.end(csp)
-            del buf, got
-            moved += wshape[0] * (plan.total_bytes // plan.in_shape[0])
-            placed += 1
-            if not plan.resident:
-                _spill_part(part, g)
-            del part
-            compute += _clock() - t0
-            pool.give_back(1, bnb)
+        _retire(0)              # the drain, in slab order
     finally:
         pool.close()
+        for _, _, nb in inflight:
+            # a run that ended early: the window's permits and lease
+            # bytes go back on the way out
+            pool.give_back(1, nb)
+        inflight.clear()
         if pod:
             _podwatch.pod_exit()
         if run.lease is not None:
@@ -3652,6 +3751,7 @@ def _resolve_one_swap(source, collect=False, project=False):
     _engine.record_stream(placed, ingest, compute, wall,
                           max(0.0, ingest + compute - wall), run.depth,
                           uploaders=max(pool.high_water, 1),
+                          inflight=inflight_hw, windowed=windowed,
                           keyed=placed if keyed else 0, thin=nthin)
 
     if plan.resident:

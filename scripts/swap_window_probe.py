@@ -1,0 +1,121 @@
+#!/usr/bin/env python3
+"""What the swap / collect resolver's window of unconfirmed place calls is
+worth in a streamed cell, from ONE process on the chip.
+
+    python3 scripts/swap_window_probe.py <cell> --seed N
+        [--windows 1 2 3 4] [--passes 3] [--rounds 2] [--tiny]
+
+The cell's operand is built once (the host tile), then its one request is
+repeated under bolt's tracer alone (``obs.enable()``, no profiler) at each
+process-wide prefetch depth W (``stream.set_prefetch_depth``) with
+``stream._SWAP_WINDOW_STEP`` set to 0, so that W IS the resolver's window,
+ring W + pool (``stream.swap_ring``; the shipped step makes a caller's
+depth 2 a window of 3); the rounds walk the windows up and then down.  A JSON line a (round, W): the requests'
+walls, GB/s streamed, the link's own account (``transfer_seconds``), the
+consumer's and the pool's waits as shares of the wall and per span, and the
+window's counters (``stream_windowed_slabs``, the high-water, a process
+maximum).  What PERF.md section 5's window table (PR 56) was read from;
+``--tiny`` rehearses at ``benchmark/tests``' toy sizes on any device.  Runs
+in no cell of the benchmark; a cell of one request a cycle (``toseries``,
+``toseries4``, ``register``, ``scan_pca``)."""
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+for _p in (ROOT, BENCH):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import manifest as manifest_mod  # noqa: E402
+import pipeline                  # noqa: E402
+import run as bench_run          # noqa: E402
+
+PER_SLAB = ("stream.sync", "stream.wait.slab", "stream.wait.ring",
+            "stream.dispatch", "stream.compute")
+SPANS = PER_SLAB + ("stream.ingest", "stream.shuffle", "stream.collect",
+                    "stream.run", "stream.handover")
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("cell")
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--windows", type=int, nargs="+", default=[1, 2, 3, 4])
+    ap.add_argument("--passes", type=int, default=3)
+    ap.add_argument("--rounds", type=int, default=2)
+    ap.add_argument("--tiny", action="store_true",
+                    help="benchmark/tests' toy sizes, on whatever device")
+    args = ap.parse_args()
+    roots = ((os.path.join(BENCH, "tests", "tiny"), BENCH) if args.tiny
+             else (BENCH,))
+    man = manifest_mod.Manifest(manifest_mod.REAL, roots=roots)
+    cell = bench_run.Cell(man, args.cell, args.seed, 20.0, False,
+                          require_tpu=not args.tiny)
+    cell.open_device()
+    cell.build()
+    from bolt_tpu import engine, obs, stream
+    stream._SWAP_WINDOW_STEP = 0        # the depth below IS the window
+    if args.tiny:
+        stream._SLAB_BYTES = 16 * 16 * 32 * 4
+    kinds = cell.traffic["requests"]
+    (k, _, steps), = pipeline.expand(cell.traffic)
+    call = pipeline.compile_call(man, steps)
+    fetch = man.module("fetches", kinds[k]["fetch"]).take
+    operand = cell.operand
+
+    def request():
+        out = fetch(call(operand.operand()))
+        del out
+
+    request()                           # programs compile here
+    request()
+    for rnd in range(args.rounds):
+        order = args.windows if rnd % 2 == 0 else args.windows[::-1]
+        for w in order:
+            stream.set_prefetch_depth(w)
+            request()                   # settle at this depth
+            obs.clear()
+            obs.enable()
+            c0 = engine.counters()
+            walls = []
+            for _ in range(args.passes):
+                t0 = time.perf_counter()
+                request()
+                walls.append(time.perf_counter() - t0)
+            c1 = engine.counters()
+            totals = obs.totals()
+            obs.disable()
+            obs.clear()
+            d = {key: c1[key] - c0[key] for key in c1
+                 if isinstance(c1[key], (int, float))}
+            wall = sum(walls)
+            up = d["transfer_bytes"]
+            row = {
+                "cell": args.cell, "round": rnd, "window": w,
+                "walls_s": [round(x, 4) for x in walls],
+                "GBps": up / wall / 1e9,
+                "upload_GBps": up / max(d["transfer_seconds"], 1e-9) / 1e9,
+                "copies_in_flight": d["transfer_copy_seconds"]
+                / max(d["transfer_seconds"], 1e-9),
+                "slabs": d["stream_chunks"],
+                "windowed": d.get("stream_windowed_slabs"),
+                "inflight_hw": c1["stream_inflight_high_water"],
+                "workers_busy": totals.get("stream.ingest", {}).get(
+                    "seconds", 0.0) / wall,
+                "share": {n.split("stream.")[1]: round(
+                    totals[n]["seconds"] / wall, 4)
+                    for n in SPANS if n in totals},
+                "us_a_span": {n.split("stream.")[1]: round(
+                    1e6 * totals[n]["seconds"] / totals[n]["count"], 1)
+                    for n in PER_SLAB if n in totals},
+                "peak_GB": cell.memory_peak() / 1e9,
+            }
+            print(json.dumps(row), flush=True)
+
+
+if __name__ == "__main__":
+    main()

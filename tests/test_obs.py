@@ -337,6 +337,7 @@ _EXPECTED_ENGINE_KEYS = {
     "stream_gram_slabs": False, "stream_gram_kernel_slabs": False,
     "stream_project_slabs": False,
     "stream_alltoall_bytes": False, "stream_upload_parts": False,
+    "stream_windowed_slabs": False,
 }
 
 

@@ -588,6 +588,7 @@ def test_uploaders_scope_and_pool_size(ndev):
                                             4)
         # the ring and the resident/spill plan read the same count
         assert stream.swap_ring(src) == (stream.prefetch_depth()
+                                         + stream._SWAP_WINDOW_STEP
                                          + stream.pool_size(src))
         with stream.uploaders(7):
             assert stream.upload_threads() == 7

@@ -110,6 +110,68 @@ def test_collect_over_an_iterators_own_blocks(mesh, chain, sizes):
     assert np.array_equal(got, want)
 
 
+# the resolver's window of unconfirmed place calls (ISSUE 56): a NEW lazy
+# mapped source each call (an iterator is one-shot)
+def _thin_rows(mesh):
+    one = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("k",))
+    x = data(1024, (6,), seed=3)
+    return callback(x, one, 128).map(lambda r: r * 2.0 + 1.0)
+
+
+WINDOW_SOURCES = {
+    "callback-map": lambda mesh: CHAINS["map"](callback(data(), mesh, 7)),
+    "callback-keyed":
+        lambda mesh: CHAINS["keyed-map"](callback(data(), mesh, 7)),
+    "iterator-map": lambda mesh: CHAINS["map"](
+        blocks(data(), mesh, (7, 7, 7, 7, 7, 7, 8))),
+    "iterator-keyed": lambda mesh: CHAINS["keyed"](
+        blocks(data(), mesh, (20, 30))),
+    "thin-records": _thin_rows,
+}
+
+
+@pytest.mark.parametrize("open_window", [False, True],
+                         ids=["done-at-once", "never-done"])
+@pytest.mark.parametrize("depth", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(WINDOW_SOURCES))
+def test_windowed_collect_is_the_materialised_map_to_the_bit(
+        mesh, case, depth, open_window, monkeypatch):
+    if open_window:
+        # a CPU's programs are done as soon as dispatched: no handle
+        # reads done until the resolver blocks for it
+        monkeypatch.setattr(stream, "_retired", lambda handle: False)
+    want = oracle(WINDOW_SOURCES[case](mesh)._stream)
+    seen = []
+    record = engine.record_stream
+    monkeypatch.setattr(engine, "record_stream", lambda *a, **kw: (
+        seen.append(kw), record(*a, **kw))[1])
+    with stream.prefetch(depth):
+        arr = WINDOW_SOURCES[case](mesh)
+        src = arr._stream
+        # the window is what the ring holds beyond a slab a worker's
+        # hand, and a collect's ring never passes its slab count
+        window = max(1, stream.collect_plan(src).ring
+                     - stream.pool_size(src))
+        c0 = engine.counters()
+        got = np.asarray(arr.toarray())
+    c1 = engine.counters()
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    slabs = c1["stream_collect_slabs"] - c0["stream_collect_slabs"]
+    mine, = seen
+    assert c1["stream_windowed_slabs"] - c0["stream_windowed_slabs"] \
+        == mine["windowed"]
+    if open_window or window == 1:
+        # every call but the first goes out behind an unconfirmed one,
+        # or (a window of one) none does
+        assert mine["windowed"] == (slabs - 1 if window > 1 else 0)
+        assert mine["inflight"] == min(window, slabs)
+    else:
+        # how many were done by the time the resolver asked is the
+        # device's to say
+        assert 0 <= mine["windowed"] <= slabs - 1
+        assert 1 <= mine["inflight"] <= min(window, slabs)
+
+
 def test_collect_records_its_spans_and_no_materialize_span(mesh):
     x = data()
     obs.clear()

@@ -20,8 +20,13 @@ dict codec + spill-file layer keep their format contracts.
 
 import contextlib
 import glob
+import json
 import os
+import subprocess
+import sys
+import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -298,7 +303,9 @@ def test_resident_rule_counts_what_the_program_holds(mesh):
     data = _data()
     src = _source(data, mesh, 4)._stream
     ring = stream.swap_ring(src)
-    assert ring == stream.prefetch_depth() + stream.pool_size(src)
+    # the resolver's window (the depth and a step) and a slab a worker
+    assert ring == stream.prefetch_depth() + stream._SWAP_WINDOW_STEP \
+        + stream.pool_size(src)
     slab_bytes = 4 * V0 * V1 * 4
     holds = data.nbytes + (ring + 1) * slab_bytes
 
@@ -311,7 +318,11 @@ def test_resident_rule_counts_what_the_program_holds(mesh):
     assert data.nbytes + slab_bytes <= holds - 1
     # a deeper ring holds more
     with stream.prefetch(5):
-        assert stream.swap_ring(src) == 5 + stream.pool_size(src)
+        assert stream.swap_ring(src) == 5 + stream._SWAP_WINDOW_STEP \
+            + stream.pool_size(src)
+    # one call at a time has no window to deepen
+    with stream.prefetch(1):
+        assert stream.swap_ring(src) == 1 + stream.pool_size(src)
     # BLT017 and the run agree at the edge, both ways
     for budget, resident in ((holds, True), (holds - 1, False)):
         with stream.spill(budget=budget):
@@ -511,6 +522,289 @@ def test_shuffle_chaos_raise_absorbed_in_place(mesh):
         c1 = engine.counters()
         assert c1["stream_retries"] - c0["stream_retries"] == 1, seam
         assert np.array_equal(got, ref), seam
+
+
+# ---------------------------------------------------------------------
+# the resolver's window of dispatched, unconfirmed place calls (ISSUE 56)
+# ---------------------------------------------------------------------
+
+def _scale_keys(kv):
+    (k,), v = kv
+    return v * (k % 7 + 1)
+
+
+def _thin_table():
+    rng = np.random.default_rng(11)
+    return rng.integers(-1000, 1000, size=(1024, 6)).astype(np.float32)
+
+
+def _one():
+    return jax.sharding.Mesh(np.array(jax.devices()[:1]), ("k",))
+
+
+# case -> a NEW lazy streamed swap each call (an iterator is one-shot)
+_WINDOW_CASES = {
+    "callback": lambda mesh: _source(_data(), mesh, 4).swap((0,), (0,)),
+    "callback-short-tail":
+        lambda mesh: _source(_data(), mesh, 5).swap((0,), (0, 1)),
+    "iterator": lambda mesh: bolt.fromiter(
+        np.array_split(_data(), 6), SHAPE, mesh,
+        dtype=np.float32).swap((0,), (1,)),
+    "thin-records": lambda mesh: _source(
+        _thin_table(), _one(), 128).swap((0,), (0,)),
+    "keyed": lambda mesh: _source(_data(), mesh, 4).map(
+        _scale_keys, with_keys=True).swap((0,), (0,)),
+}
+
+
+@pytest.fixture
+def never_done(monkeypatch):
+    """A CPU's programs are done as soon as dispatched, so the window
+    never opens here by itself: no handle reads done until the resolver
+    BLOCKS for it (the chip's order of events, with a slow program)."""
+    monkeypatch.setattr(stream, "_retired", lambda handle: False)
+
+
+def _materialised(lazy):
+    src = lazy._stream
+    return np.asarray(stream._replay_stages(
+        stream._materialize_base(src), src.stages).toarray())
+
+
+@pytest.mark.parametrize("open_window", [False, True],
+                         ids=["done-at-once", "never-done"])
+@pytest.mark.parametrize("depth", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+def test_windowed_resolver_is_the_materialised_swap_to_the_bit(
+        mesh, case, depth, open_window, monkeypatch):
+    if open_window:
+        monkeypatch.setattr(stream, "_retired", lambda handle: False)
+    want = _materialised(_WINDOW_CASES[case](mesh))
+    c0 = engine.counters()
+    with stream.prefetch(depth):
+        got = np.asarray(_WINDOW_CASES[case](mesh)._data)
+    c1 = engine.counters()
+    assert got.dtype == want.dtype and np.array_equal(got, want)
+    windowed = c1["stream_windowed_slabs"] - c0["stream_windowed_slabs"]
+    slabs = c1["stream_chunks"] - c0["stream_chunks"]
+    if open_window or depth == 1:
+        # every call but the first goes out behind an unconfirmed one,
+        # or none does: prefetch(1) is one call at a time
+        assert windowed == (slabs - 1 if depth > 1 else 0)
+    else:
+        # how many were done by the time the resolver asked is the
+        # device's to say
+        assert 0 <= windowed <= slabs - 1
+
+
+def _spy_record_stream(monkeypatch):
+    seen = []
+    record = engine.record_stream
+
+    def spy(*args, **kw):
+        seen.append(kw)
+        return record(*args, **kw)
+    monkeypatch.setattr(engine, "record_stream", spy)
+    return seen
+
+
+def _traced(run):
+    obs.clear()
+    obs.enable()
+    try:
+        got = run()
+        assert obs.active_count() == 0
+        return got, obs.spans()
+    finally:
+        obs.disable()
+        obs.clear()
+
+
+def _by_slab(spans, name):
+    return {sp.attrs["slab"]: sp for sp in spans if sp.name == name}
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3, 4])
+def test_the_window_holds_depth_calls_and_the_ring_its_permits(
+        mesh, depth, never_done, monkeypatch):
+    """With a place program made slow (a confirm that takes a while and
+    no handle done before it), slab g + 1's call goes out before slab
+    g's confirm ends, never more than the window unconfirmed, never
+    more than the ring's permits out, and the counters say so."""
+    sync = stream._pod_sync
+
+    def slow(x, pod, phase, slab=None):
+        time.sleep(0.004)
+        return sync(x, pod, phase, slab=slab)
+    monkeypatch.setattr(stream, "_pod_sync", slow)
+    seen = _spy_record_stream(monkeypatch)
+    data = _data()
+    nslabs = N // 2
+    c0 = engine.counters()
+    with stream.prefetch(depth), stream.uploaders(2):
+        src = _source(data, mesh, 2)
+        ring = stream.swap_ring(src._stream)
+        got, spans = _traced(
+            lambda: np.asarray(src.swap((0,), (0,))._data))
+    c1 = engine.counters()
+    assert np.array_equal(got, np.transpose(data, (1, 0, 2)))
+    # the window: the ring less a slab in each of the two workers' hands
+    window = ring - 2
+    assert window == (depth + stream._SWAP_WINDOW_STEP if depth > 1 else 1)
+    calls, blocks = (_by_slab(spans, n) for n in ("stream.dispatch",
+                                                  "stream.sync"))
+    assert sorted(calls) == sorted(blocks) == list(range(nslabs))
+    for g in range(nslabs - 1):
+        # confirmed in slab order, each after its own call
+        assert calls[g].t1 <= blocks[g].t0 <= blocks[g + 1].t0
+        if window > 1:
+            assert calls[g + 1].t0 < blocks[g].t1
+        else:
+            assert blocks[g].t1 <= calls[g + 1].t0
+    for g in range(nslabs):
+        t = calls[g].t0
+        unconfirmed = sum(c.t0 <= t for c in calls.values()) \
+            - sum(b.t1 <= t for b in blocks.values())
+        assert unconfirmed <= window
+    if window > 1:
+        # and the window does fill: slab `window - 1`'s call went out
+        # with every call before it unconfirmed
+        assert blocks[0].t0 >= calls[window - 1].t1
+    ingests = [sp for sp in spans if sp.name == "stream.ingest"]
+    assert len(ingests) == nslabs
+    for sp in ingests:
+        out = sum(i.t0 <= sp.t0 for i in ingests) \
+            - sum(b.t1 <= sp.t0 for b in blocks.values())
+        assert out <= ring
+    mine, = seen
+    assert mine["inflight"] == min(window, nslabs)
+    assert mine["windowed"] == (nslabs - 1 if window > 1 else 0)
+    assert c1["stream_windowed_slabs"] - c0["stream_windowed_slabs"] \
+        == mine["windowed"]
+    assert c1["stream_inflight_high_water"] >= mine["inflight"]
+
+
+def test_the_spill_leg_keeps_one_block_a_slab(mesh, tmp_path, never_done,
+                                              monkeypatch):
+    seen = _spy_record_stream(monkeypatch)
+    data = _data()
+    td = str(tmp_path)
+    with stream.prefetch(4), stream.spill(dir=td, budget=1):
+        got, spans = _traced(lambda: np.asarray(
+            _source(data, mesh, 4).swap((0,), (0,))._data))
+    checkpoint.spill_clear(td)
+    assert np.array_equal(got, np.transpose(data, (1, 0, 2)))
+    calls, blocks = (_by_slab(spans, n) for n in ("stream.dispatch",
+                                                  "stream.sync"))
+    computes = _by_slab(spans, "stream.compute")
+    # the spill's own pass re-streams the buckets through execute: its
+    # compute spans carry no shuffle=True
+    computes = {g: sp for g, sp in computes.items()
+                if sp.attrs.get("shuffle")}
+    assert len(computes) == N // 4
+    for g, csp in computes.items():
+        assert calls[g].pid == blocks[g].pid == csp.sid
+        assert calls[g].t1 <= blocks[g].t0
+    phase1 = [kw for kw in seen if "windowed" in kw]
+    assert [kw["windowed"] for kw in phase1] == [0]
+    assert [kw["inflight"] for kw in phase1] == [1]
+
+
+def test_a_raise_at_the_seam_with_the_window_open_retries_in_place(
+        mesh, never_done):
+    data = _data()
+    _chaos.inject("stream.shuffle", nth=4)
+    c0 = engine.counters()
+    try:
+        with stream.retries(1), stream.prefetch(4):
+            got = np.asarray(_source(data, mesh, 2).swap((0,), (0,))._data)
+    finally:
+        _chaos.clear()
+    c1 = engine.counters()
+    assert c1["stream_retries"] - c0["stream_retries"] == 1
+    # the retried call went out behind the unconfirmed ones all the same
+    assert c1["stream_windowed_slabs"] - c0["stream_windowed_slabs"] \
+        == N // 2 - 1
+    assert np.array_equal(got, np.transpose(data, (1, 0, 2)))
+
+
+@pytest.mark.parametrize("served", [False, True],
+                         ids=["alone", "under-a-serving-budget"])
+def test_a_raise_at_a_confirm_ends_the_run_and_names_the_slab(
+        mesh, served, never_done, monkeypatch):
+    sync = stream._pod_sync
+
+    def failing(x, pod, phase, slab=None):
+        if phase == "shuffle re-bucket" and slab == 2:
+            raise OSError("device gone")
+        return sync(x, pod, phase, slab=slab)
+    monkeypatch.setattr(stream, "_pod_sync", failing)
+    data = _data()
+    c0 = engine.counters()
+    obs.clear()
+    obs.enable()
+    try:
+        server = (serve.serving(workers=1, budget_bytes=64 << 20)
+                  if served else contextlib.nullcontext())
+        with server as sv:
+            # a retry budget changes nothing: the confirm is final
+            with stream.retries(2), stream.prefetch(3), \
+                    pytest.raises(RuntimeError,
+                                  match="shuffle slab 2 failed at the "
+                                        "confirm") as err:
+                _source(data, mesh, 2).swap((0,), (0,))._data
+            if served:
+                # every permit's lease bytes came back on the way out
+                assert sv.stats()["arbiter"]["in_use_bytes"] == 0
+        assert obs.active_count() == 0
+        syncs = [sp.attrs["slab"] for sp in obs.spans()
+                 if sp.name == "stream.sync"]
+    finally:
+        obs.disable()
+        obs.clear()
+    assert isinstance(err.value.__cause__, OSError)
+    assert syncs == [0, 1, 2]
+    assert engine.counters()["stream_retries"] == c0["stream_retries"]
+    assert all(not t.is_alive() for t in stream._LAST_POOL)
+
+
+def test_a_serving_budget_under_the_ring_runs_a_shallower_window(
+        mesh, never_done):
+    """execute's valve: the feeder waits for budget bytes that only a
+    confirm gives back, so the consumer confirms one call per empty
+    poll instead of waiting for a slab that cannot come."""
+    data = _data()
+    slab_bytes = 2 * V0 * V1 * 4
+    with serve.serving(workers=1, budget_bytes=slab_bytes) as sv:
+        # resident by the scope's own budget; the lease is the arbiter's
+        with stream.prefetch(4), stream.spill(budget=1 << 30):
+            src = _source(data, mesh, 2)
+            assert stream.swap_ring(src._stream) * slab_bytes \
+                > sv.stats()["arbiter"]["budget_bytes"]
+            got = np.asarray(src.swap((0,), (0,))._data)
+        assert sv.stats()["arbiter"]["in_use_bytes"] == 0
+    assert np.array_equal(got, np.transpose(data, (1, 0, 2)))
+
+
+def test_the_window_probe_runs_at_toy_size():
+    """``scripts/swap_window_probe.py``, what PERF.md's window table was
+    read from, end to end at ``benchmark/tests``' toy sizes: a JSON line
+    a window, in the order asked for."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "scripts",
+                                      "swap_window_probe.py"),
+         "twophoton512-1chip.toseries", "--seed", "7", "--tiny",
+         "--windows", "1", "2", "--passes", "1", "--rounds", "1"],
+        capture_output=True, text=True, timeout=300, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-2000:]
+    rows = [json.loads(line) for line in done.stdout.splitlines()
+            if line.startswith('{"cell"')]
+    assert [r["window"] for r in rows] == [1, 2]
+    assert all(r["slabs"] >= 1 and r["windowed"] <= r["slabs"] - 1
+               and "sync" in r["share"] for r in rows)
+    assert rows[0]["windowed"] == 0
 
 
 # ---------------------------------------------------------------------

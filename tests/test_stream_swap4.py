@@ -219,7 +219,8 @@ def test_the_forecast_is_the_runs_plan(mesh4):
     assert "all-to-all ~%.1f MiB" % (data.nbytes * 0.75 / 2**20) \
         in d.message
     assert "one all-to-all per slab across its 4 devices" in d.hint
-    assert plan.ring == stream.prefetch_depth() + 4
+    assert plan.ring == stream.prefetch_depth() \
+        + stream._SWAP_WINDOW_STEP + 4
     c0 = engine.counters()
     out, spans = _traced(lambda: arr._data)
     c1 = engine.counters()

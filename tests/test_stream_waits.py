@@ -7,7 +7,8 @@
   under: a pool worker with no job to take (the dispenser hands one out the
   moment it holds a permit), an iterator's one thread in front of its pull;
 * ``stream.dispatch`` — the compiled slab program's call alone, and in the
-  resolver a ``stream.sync`` beside it for the block behind the call.
+  resolver a ``stream.sync`` a slab for the confirm of a call dispatched
+  earlier (``slab=`` names it; PR 56).
 
 No test here holds a span against a wall-clock threshold (ROADMAP D9): a
 span's seconds are compared with another's by a wide factor, under a delay
@@ -182,34 +183,49 @@ def test_the_consumer_waits_once_a_slab_and_once_for_the_end(mesh, consumer):
 
 
 # ----------------------------------------------------------------------
-# (c) the resolver's stream.compute: a call and a block a slab
+# (c) the resolver's stream.compute: a call a compute, a block a slab
 # ----------------------------------------------------------------------
 
 @pytest.mark.parametrize("consumer", ["swap", "collect"])
 def test_the_resolvers_compute_is_one_call_and_one_block_a_slab(mesh,
                                                                 consumer):
+    """Since PR 56 the block is the CONFIRM of a call dispatched earlier:
+    one ``stream.dispatch`` a ``stream.compute``, one ``stream.sync`` a
+    slab over the run, each naming the slab it confirms, in slab order,
+    under a later slab's compute, between two computes or in the drain."""
     spans, totals = traced(mesh, consumer)
+    run = the_run(spans, consumer)
     computes = named(spans, "stream.compute")
     assert len(computes) == SLABS
+    calls = {}
     for csp in computes:
         kids = [sp for sp in spans if sp.pid == csp.sid
                 and sp.name.startswith("stream.")]
-        assert sorted(sp.name for sp in kids) == ["stream.dispatch",
-                                                  "stream.sync"]
-        call, block = sorted(kids, key=lambda sp: sp.t0)
-        assert (call.name, call.attrs["slab"]) == ("stream.dispatch",
-                                                   csp.attrs["slab"])
-        assert block.attrs == {"slabs": 1, "shuffle": True}
-        assert call.t1 <= block.t0
-        # the older span holds both, and the lookup in front of them
-        assert csp.t0 <= call.t0 and block.t1 <= csp.t1
+        call, = [sp for sp in kids if sp.name == "stream.dispatch"]
+        assert call.attrs["slab"] == csp.attrs["slab"]
+        assert csp.t0 <= call.t0 and call.t1 <= csp.t1
+        # whatever else the compute holds is a confirm, after its call,
+        # of this slab or an earlier one
+        for sp in kids:
+            if sp is not call:
+                assert sp.name == "stream.sync" and call.t1 <= sp.t0
+                assert sp.attrs["slab"] <= csp.attrs["slab"]
         places = [sp for sp in spans if sp.pid == call.sid
                   and sp.name == "stream.collect.place"]
         assert len(places) == (1 if consumer == "collect" else 0)
+        calls[csp.attrs["slab"]] = call
     assert ("stream.collect.place" in totals) == (consumer == "collect")
+    blocks = sorted(named(spans, "stream.sync"), key=lambda sp: sp.t0)
     assert totals["stream.sync"]["count"] == SLABS
+    assert [sp.attrs for sp in blocks] \
+        == [{"slabs": 1, "shuffle": True, "slab": g} for g in range(SLABS)]
+    by_sid = {sp.sid: sp for sp in computes}
+    for sp in blocks:
+        # a block lies after its own slab's call, on the consumer's
+        # thread, under a compute or under the run itself
+        assert calls[sp.attrs["slab"]].t1 <= sp.t0 and sp.tid == run.tid
+        assert sp.pid == run.sid or sp.pid in by_sid
     assert seconds(totals, "stream.dispatch") \
-        + seconds(totals, "stream.sync") \
         <= seconds(totals, "stream.compute")
 
 
