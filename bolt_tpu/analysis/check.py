@@ -283,11 +283,10 @@ def _stream_slab_bytes(src):
 
 def _stream_ring_bytes(src):
     """A streaming plan's peak device footprint: slab bytes times the
-    donated-ring bound (prefetch depth + uploader pool) — exactly the
-    budget one run's slabs can hold at once in ``stream.execute``."""
+    donated-ring bound (``stream.fold_ring``) — exactly the budget one
+    run's slabs can hold at once in ``stream.execute``."""
     from bolt_tpu import stream as _stream
-    return _stream_slab_bytes(src) * (_stream.prefetch_depth()
-                                      + _stream.pool_size(src))
+    return _stream_slab_bytes(src) * _stream.fold_ring(src)
 
 
 def _admission_budget():
@@ -1228,10 +1227,10 @@ def _check_stream(arr, target, stages, diags):
     nslabs = -(-src.shape[0] // src.slab) if src.shape[0] else 0
     aval = jax.ShapeDtypeStruct(tuple(src.shape), src.dtype)
     nproc = _mh.mesh_process_count(mesh)
+    pool = _stream.pool_size(src)
     note = ("out-of-core: ~%d slabs of %d records, prefetch depth %d, "
             "uploader pool %d"
-            % (nslabs, src.slab, _stream.prefetch_depth(),
-               _stream.pool_size(src)))
+            % (nslabs, src.slab, _stream.fold_ring(src) - pool, pool))
     if nproc > 1:
         # the per-host plan (explain() shows it): each process produces
         # and uploads only its shard of every slab; the cross-host fold

@@ -6,8 +6,6 @@ benchmark measures it); the kernels here are the ones something calls:
 * :func:`fused_welford` — mean / centred second moment / min / max over
   axis 0 in ONE pass over HBM (the centred moment needs the finished mean,
   so XLA reads twice); ``tpu/stats.py`` calls it for ``stats()``.
-* :func:`fused_decode_sum` — an int8 slab decoded and reduced in one pass,
-  behind ``BOLT_CODEC_KERNEL`` (``stream.py``).
 * :func:`sepfilter1d` — a separable filter's 1-d pass with every block read
   once, and its wide-window lane form :func:`lane_band_pallas` (with the
   XLA twin :func:`lane_band_conv`); ``ops/overlap.py`` calls them.
@@ -157,72 +155,6 @@ def fused_welford(x, interpret=None):
     # returns the same dtype/precision whether or not the kernel engaged
     # (sub-f32 inputs accumulate in f32 in VMEM, then narrow once here)
     return tuple(v.astype(x.dtype) for v in (mu, m2, mn, mx))
-
-
-def _decode_sum_kernel(q_ref, s_ref, z_ref, o_ref):
-    """Affine int8 decode + leading-axis sum, accumulated in f32 on the
-    VMEM-resident tile: the quantised block never materialises its
-    decoded float form in HBM — decode stays in-register on the way
-    into the reduction (the ISSUE-14 compressed-ingest hot path)."""
-    i = pl.program_id(1)
-    # widen through int32: Mosaic has no direct uint8 -> float32 cast
-    q = q_ref[...].astype(jnp.int32).astype(jnp.float32)
-    part = jnp.sum(q * s_ref[0, 0] + z_ref[0, 0], axis=0)
-
-    @pl.when(i == 0)
-    def _init():
-        o_ref[...] = part
-
-    @pl.when(i > 0)
-    def _acc():
-        o_ref[...] += part
-
-
-def fused_decode_sum(q, scale, zp, interpret=None):
-    """One-pass decode-and-reduce for an affine-quantised slab: the
-    streamed ``sum`` partial ``sum(q * scale + zp, axis=0)`` with the
-    int8→f32 decode fused in-register (``q`` is the uint8/int8 wire
-    block of shape ``(n, *vshape)``; ``scale``/``zp`` the per-slab
-    float sidecar).  The opt-in door for bolt_tpu/tpu/codec.py's int8
-    codec (``BOLT_CODEC_KERNEL=1``): XLA already fuses the decode into
-    its reduction, so like every kernel here this one exists for the
-    geometries where explicit VMEM control wins, returns ``None`` when
-    the plan does not engage (the caller keeps the XLA decode path —
-    which tests parity-lock this kernel against), and runs in
-    interpret mode off-TPU so the same code path is testable on the
-    CPU mesh.  The plan is :func:`welford_plan`'s (the blocks widen to
-    f32 in VMEM, so the budget uses itemsize 4)."""
-    if q.dtype not in (jnp.uint8, jnp.int8) or q.ndim < 2:
-        return None
-    plan = welford_plan(q.shape, 4)
-    if plan is None:
-        return None
-    t0, v0 = plan
-    n = q.shape[0]
-    vshape = q.shape[1:]
-    grid = (vshape[0] // v0, n // t0)   # n innermost: accumulator stays
-    block = (t0, v0) + tuple(vshape[1:])
-    out_block = (v0,) + tuple(vshape[1:])
-    if interpret is None:
-        interpret = _interpret_default()
-
-    def in_map(j, i):
-        return (i, j) + (0,) * (len(vshape) - 1)
-
-    def out_map(j, i):
-        return (j,) + (0,) * (len(vshape) - 1)
-
-    return pl.pallas_call(
-        _decode_sum_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec(block, in_map),
-                  pl.BlockSpec((1, 1), lambda j, i: (0, 0)),
-                  pl.BlockSpec((1, 1), lambda j, i: (0, 0))],
-        out_specs=pl.BlockSpec(out_block, out_map),
-        out_shape=jax.ShapeDtypeStruct(vshape, jnp.float32),
-        interpret=interpret,
-    )(q, jnp.asarray(scale, jnp.float32).reshape(1, 1),
-      jnp.asarray(zp, jnp.float32).reshape(1, 1))
 
 
 # windowing ALONG the minor (lane) axis: the lane-shift chain COMPILES

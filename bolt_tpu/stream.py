@@ -7,7 +7,8 @@ chain of device-side stages (per-record maps, chunked maps, stacked
 maps, a filter predicate and the record-wise maps called on it since,
 a recorded swap).  Every streamed run is the same four pieces::
 
-    StreamSource -> _Run -> _IngestPool -> consumer: fold | place | spill
+    StreamSource -> _Run -> _IngestPool -> consumer -> _Window
+                                           fold (a _Terminal) | place | spill
 
 * the **run set-up** (:class:`_Run`) resolves once, on the calling
   thread, what the run is held to: prefetch depth and pool size
@@ -29,28 +30,24 @@ a recorded swap).  Every streamed run is the same four pieces::
   (and, under ``bolt_tpu.serve``, the slab's wire bytes from the
   device-memory arbiter) is taken per slab in slab order and comes back
   when the consumer says the slab's program retired;
-* the **consumer** is the calling thread.  :func:`execute` FOLDS a
-  reduction terminal: slab programs **dispatch asynchronously** into a
-  bounded in-flight window — no per-slab ``block_until_ready``; it
-  syncs only on window overflow (an already-retired old partial, ~free)
-  and on the final result, so device compute and host ingest overlap.
-  Each ring buffer is **donated** into its slab program, the **level-0
-  fold is fused into the slab program** (odd slabs run ``prog(buf,
-  acc)``: half the fold dispatches), and pair-partials above level 0
-  combine as a pairwise tree (``add``/``func`` for ``sum``/``reduce``,
-  a Welford/Chan ``n, μ, M2`` merge for ``mean``/``var``/``std``:
-  power-of-two slab counts keep the Chan denominators exact; component
-  by component for the tuples of a fused multi-stat group, of
-  ``ops.segment_reduce`` by a label function, :func:`maybe_group`, and
-  of a Gram matrix with its column sums, :func:`maybe_gram`).
-  :func:`_resolve_one_swap` PLACES each slab into a resident re-keyed
-  array (a recorded swap, or :func:`collect`'s mapped result), its
-  place calls dispatched into a bounded window of its own (the prefetch
-  depth and a step unconfirmed at most, :func:`swap_ring`, each
-  confirmed by the cursor it returned: the array it wrote into is
-  donated into the next call), or
-  SPILLS its buckets, a block a slab, to files that stream again as a
-  fresh source (``bolt_tpu.parallel.shuffle``).
+* the **consumer** is the calling thread: it takes a slab, builds its
+  program, dispatches it **asynchronously** and pushes the handle on
+  its **confirm window** (:class:`_Window`) — no per-slab
+  ``block_until_ready``; confirming a call gives its ring permits back,
+  and when to block for one is the consumer's policy.  :func:`execute`
+  FOLDS a reduction terminal, a VALUE that says what a slab's partial is
+  and how two merge (:class:`_Terminal`: a sum, a ``reduce``, moments,
+  the tuples of a fused multi-stat group, of :func:`maybe_group` and of
+  :func:`maybe_gram`): it syncs only on window overflow
+  (:func:`fold_ring`; an already-retired old partial, ~free) and on the
+  final result, so compute and ingest overlap.  Each ring buffer is
+  **donated** into its slab program, the **level-0 fold is fused into
+  the slab program** (odd slabs run ``prog(buf, acc)``: half the fold
+  dispatches), and pair-partials above level 0 combine as a pairwise
+  tree.  :func:`_resolve_one_swap` PLACES each slab into a resident
+  re-keyed array (a recorded swap, or :func:`collect`'s mapped result;
+  :func:`swap_ring`), or SPILLS its buckets, a block a slab, to files
+  that stream again as a fresh source (``bolt_tpu.parallel.shuffle``).
 
 The per-slab program applies the SAME traced bodies the materialised
 paths compile (``tpu/chunk.py :: _uniform_map_body`` /
@@ -544,6 +541,14 @@ def swap_ring(source):
     if depth > 1:
         depth += _SWAP_WINDOW_STEP
     return depth + pool_size(source)
+
+
+def fold_ring(source):
+    """Uploaded slabs a folded run (:func:`execute`) over ``source``
+    keeps on the device: its window of unconfirmed slab programs, the
+    prefetch depth, plus one in the hand of every pool worker — the ring
+    its permits bound, and what ``analysis.check`` prices."""
+    return prefetch_depth() + pool_size(source)
 
 
 def pool_size(source):
@@ -1290,7 +1295,7 @@ def maybe_stat(arr, axis, name, keepdims, ddof):
     if name in ("mean", "var", "std") and np.issubdtype(
             st.dtype, np.complexfloating):
         return NotImplemented           # mirror the fused-filter gate
-    return execute(arr, name, ddof=ddof)
+    return execute(arr, stat_terminal(name, ddof))
 
 
 def maybe_reduce(arr, func, axes, keepdims):
@@ -1319,7 +1324,7 @@ def maybe_reduce(arr, func, axes, keepdims):
             lambda: jax.eval_shape(func, vaval, vaval))
     except _TRACE_ERRORS:
         return NotImplemented           # host-fallback path resolves
-    return execute(arr, "reduce", rfunc=func)
+    return execute(arr, _Reduce("reduce", rfunc=func))
 
 
 def maybe_group(arr, label, value, nseg, op):
@@ -1348,7 +1353,7 @@ def maybe_group(arr, label, value, nseg, op):
     st = result_state(src)
     if st.n == 0:
         return NotImplemented           # empty: materialised path's rules
-    return execute(arr, "group", group=(op, label, value, int(nseg)))
+    return execute(arr, _Group((op, label, value, int(nseg)), src))
 
 
 def gram_refusal(source, axes, passes=1):
@@ -1404,104 +1409,173 @@ def maybe_gram(arr, axes, precision, sums=False, second_conj=False,
     src = arr._stream
     if src is None or gram_refusal(src, axes, passes) is not None:
         return NotImplemented
-    return execute(arr, "gram", gram=(len(axes), precision,
-                                      bool(second_conj), bool(sums)))
+    return execute(arr, _Gram((len(axes), precision, bool(second_conj),
+                               bool(sums))))
 
 
 # ---------------------------------------------------------------------
-# per-slab programs and on-device partial merges
+# terminals: what a streamed run folds its slabs into
 # ---------------------------------------------------------------------
 
-def _combine(terminal, rfunc, a, b, comps=None):
-    """The ONE partial-merge arithmetic — traced by BOTH the standalone
-    merge program (the pairwise tree above level 0) and the acc-fused
-    slab program (level 0), so in-program and between-program merges
-    cannot drift.  ``a`` is the EARLIER partial (fold order matters for
-    ``reduce``); moments partials are ``(n, mu, M2)`` triples merged by
-    the Chan et al. parallel recurrence (the statcounter ``mergeStats``
-    formula, vectorised over the value block).  ``terminal="multi"``
-    (the fused multi-stat accumulator, bolt_tpu/tpu/multistat.py) merges
-    a TUPLE of components — each through this same function, so the
-    fused tuple merge and the standalone merges share one arithmetic.
-    ``terminal="group"`` (the grouped fold, :func:`maybe_group`) is such
-    a tuple too: a group's every leaf by the fold's own merge, then the
-    int32 counts, which add; ``terminal="gram"`` (:func:`maybe_gram`) the
-    Gram matrix and the column sums, which add."""
-    if terminal in _TUPLED:
-        return tuple(_combine(_COMP_MERGE[c], rfunc, x, y)
-                     for c, x, y in zip(comps, a, b))
-    if terminal == "sum":
-        return jnp.add(a, b)
-    if terminal == "min":
-        return jnp.minimum(a, b)
-    if terminal == "max":
-        return jnp.maximum(a, b)
-    if terminal == "reduce":
-        return rfunc(a, b)
-    n1, mu1, m21 = a
-    n2, mu2, m22 = b
-    n = n1 + n2
-    safe = jnp.where(n > 0, n, jnp.asarray(1, n.dtype))
-    delta = mu2 - mu1
-    mu = mu1 + delta * (n2 / safe)
-    m2 = m21 + m22 + delta * delta * (n1 * n2 / safe)
-    return n, mu, m2
+class _Terminal:
+    """A streamed reduction terminal as a VALUE: what one slab's partial
+    is (:meth:`partial`), how two partials merge (:meth:`combine`) and
+    what the folded partial becomes (:meth:`finalise`, :meth:`wrap`).
+    Immutable, and equal and hashable by ``key``, because it rides in the
+    engine's program keys: the terminals two calls of ``b.sum()`` build
+    hit ONE cached slab program.  A new streamed terminal is one
+    subclass; nothing else in this file asks which one it was handed."""
+
+    # min/max/ptp are exact by contract: a lossy ingest codec refuses them
+    order_sensitive = False
+
+    def __init__(self, name, ddof=None, rfunc=None, comps=None, params=()):
+        # ``name``: the word in spans, errors and the fingerprint;
+        # ``comps``: where the partial is a TUPLE, what each component
+        # merges as; ``params``: a multi's specs, a group, a gram
+        self.__dict__.update(
+            name=name, ddof=ddof, rfunc=rfunc, comps=comps, params=params,
+            key=(name, ddof, rfunc, comps, params))
+
+    def __setattr__(self, attr, value):
+        raise AttributeError("a streamed terminal is a value: %r is set "
+                             "where it is built" % (attr,))
+
+    def __eq__(self, other):
+        return type(other) is type(self) and other.key == self.key
+
+    def __hash__(self):
+        return hash(self.key)
+
+    @property
+    def names(self):
+        """The terminal(s) a refusal names to the caller."""
+        return [self.name]
+
+    @property
+    def members(self):
+        """What ``params`` add to a run's checkpoint fingerprint."""
+        return ""
+
+    def partial(self, x, stages, pred, post, split, apply, axes):
+        """One slab's partial: ``x`` is the slab as uploaded (decoded,
+        re-seated), ``stages`` / ``pred`` / ``post`` the source's chain
+        cut at its filter, ``apply(stage, split, x)`` a stage's traced
+        body with the slab's key and side operands bound.  The common
+        path: stages, flatten, mask, the maps behind it, :meth:`of_records`.
+
+        ``axes`` is the MULTI-PROCESS hook: inside a shard_map'd slab
+        program ``x`` is one device shard's records and ``axes`` names
+        the mesh axes the slab's key axes shard over — the reduction
+        points then insert the cross-host collective (one per slab for
+        sum/min/max, two for moments: the count+sum pair rides ONE fused
+        psum, M2 needs the global mean first), so the partial leaves the
+        program already combined across the pod.  Sums of sums: equal to
+        the one-process run whenever the data keeps the reduction exact
+        (even splits; the parity suite's contract)."""
+        from bolt_tpu.tpu.array import _pred_mask
+        for stg in stages:
+            x = apply(stg, split, x)
+        vshape = x.shape[split:]
+        n = prod(x.shape[:split])
+        flat = x.reshape((n,) + vshape)
+        mask = mfull = None
+        if pred is not None:
+            mask = _pred_mask(pred, flat)
+            # a map behind the filter reads every record; the mask then
+            # folds what it gave for a dropped one away
+            for stg in post:
+                flat = apply(stg, 1, flat)
+            vshape = flat.shape[1:]
+            mfull = mask.reshape((n,) + (1,) * len(vshape))
+        return self.of_records(flat, mask, mfull, vshape, n, axes)
+
+    def of_records(self, flat, mask, mfull, vshape, n, axes):
+        """The partial over the flattened records (``mask`` / ``mfull``:
+        the filter's verdicts, ``None`` without one): the expression a
+        standalone slab program traces, and a fused group per component."""
+        raise NotImplementedError
+
+    def combine(self, a, b):
+        """The ONE partial-merge arithmetic, traced by BOTH the merge
+        program (the tree above level 0) and the acc-fused slab program
+        (level 0), so the two cannot drift.  ``a`` is the EARLIER partial
+        (fold order matters for ``reduce``)."""
+        raise NotImplementedError
+
+    def fold(self, mesh, sample):
+        """A fresh :class:`_PairFold` for one run, its (tiny,
+        engine-cached) merge program derived from a sample partial — a
+        live device value (the first pushed pair) OR a host array a
+        checkpoint restored.  Captures only shape/dtype: a factory
+        closing over the live partial would pin its device buffers for
+        the whole run."""
+        shape, dtype = tuple(sample.shape), str(sample.dtype)
+
+        def factory():
+            key = ("stream-merge", self.name, self.rfunc, shape, dtype,
+                   mesh, _multihost.topology_token())
+            return _cached_jit(key, lambda: jax.jit(
+                lambda a, b: self.combine(a, b)))
+        return _PairFold(factory)
+
+    def finalise(self, folded, source):
+        """The folded partial → the device value(s) the caller asked for."""
+        return folded
+
+    def wrap(self, out, mesh):
+        """The finalised value(s) as :func:`execute` returns them."""
+        from bolt_tpu.tpu.array import BoltArrayTPU
+        return BoltArrayTPU(out, 0, mesh)
+
+    def tally(self, nslabs, kernel_slabs):
+        """What a run of ``nslabs`` adds to ``engine.record_stream``
+        (``kernel_slabs`` of them by a ``packed_gram`` program)."""
+        return {}
 
 
-# multi-stat accumulator components -> the merge arithmetic each rides
-# ("moments" is the statcounter (n, mu, M2) triple shared by every
-# mean/var/std member of a fused group)
-_COMP_MERGE = {"sum": "sum", "min": "min", "max": "max",
-               "moments": "moments"}
-# terminals whose partial is a TUPLE of components, each with a merge of
-# its own (``comps``): the fused multi-stat group, the grouped fold and
-# the Gram matrix with its sums
-_TUPLED = ("multi", "group", "gram")
+class _Sum(_Terminal):
+    def __init__(self):
+        super().__init__("sum")
 
-
-def _terminal_partial(terminal, flat, mask, mfull, vshape, n, rfunc,
-                      axes=None):
-    """Per-slab partial for ONE terminal over the flattened records —
-    the exact expressions the standalone slab programs have always
-    traced, factored out so the fused multi-stat slab program composes
-    the SAME arithmetic per component (streamed-fused vs streamed-
-    standalone parity by construction).
-
-    ``axes`` is the MULTI-PROCESS hook: inside a shard_map'd slab
-    program ``flat`` is one device shard's records and ``axes`` names
-    the mesh axes the slab's key axes shard over — the reduction points
-    then insert the cross-host collective (``psum`` for sum and the
-    moment components, ``pmin``/``pmax`` for order statistics), so the
-    global partial leaves the program already combined across the pod:
-    one collective per slab for sum/min/max, two for moments (the
-    count+sum pair rides ONE fused psum; M2 needs the global mean
-    first).  The arithmetic is the single-process expression applied
-    hierarchically — sums of sums — so results match the one-process
-    run exactly whenever the data keeps the reduction exact (even
-    splits; the parity suite's contract)."""
-    if terminal == "sum":
+    def of_records(self, flat, mask, mfull, vshape, n, axes):
         # identity fold, exactly like _fused_filter_stat: dropped
         # records (NaNs included) become inert zeros
         v = flat if mfull is None else jnp.where(
             mfull, flat, jnp.asarray(0, flat.dtype))
         s = jnp.sum(v, axis=0)
         return jax.lax.psum(s, axes) if axes else s
-    if terminal in ("min", "max"):
-        # exact order statistics; a filter predicate never reaches here
-        # (min/max multi-stat members are ineligible under a filter —
-        # zero survivors would need the materialised error contract)
-        op = jnp.min if terminal == "min" else jnp.max
+
+    def combine(self, a, b):
+        return jnp.add(a, b)
+
+
+class _Extremum(_Terminal):
+    """``min`` / ``max``: components of a fused multi-stat group and of a
+    grouped fold.  Exact order statistics; a filter predicate never
+    reaches here (min/max members are ineligible under a filter — zero
+    survivors would need the materialised error contract)."""
+
+    order_sensitive = True
+    _OPS = {"min": (jnp.min, jax.lax.pmin, jnp.minimum),
+            "max": (jnp.max, jax.lax.pmax, jnp.maximum)}
+
+    def of_records(self, flat, mask, mfull, vshape, n, axes):
+        op, collective, _ = self._OPS[self.name]
         p = op(flat, axis=0)
-        if axes:
-            p = jax.lax.pmin(p, axes) if terminal == "min" \
-                else jax.lax.pmax(p, axes)
-        return p
-    if terminal == "reduce":
+        return collective(p, axes) if axes else p
+
+    def combine(self, a, b):
+        return self._OPS[self.name][2](a, b)
+
+
+class _Reduce(_Terminal):
+    def of_records(self, flat, mask, mfull, vshape, n, axes):
         if axes:
             raise ValueError(
                 "streamed reduce(func) cannot run on a multi-process "
                 "mesh: a user combine function has no mesh collective")
-        vfunc = jax.vmap(rfunc)
+        vfunc = jax.vmap(self.rfunc)
         y = flat
         while y.shape[0] > 1:
             half = y.shape[0] // 2
@@ -1514,32 +1588,307 @@ def _terminal_partial(terminal, flat, mask, mfull, vshape, n, rfunc,
             y = jnp.concatenate([combined, rem], axis=0) \
                 if rem.shape[0] else combined
         return y[0]
-    # moments: the statcounter triple (n, mu, M2) per value slot
-    out_dt = jax.eval_shape(
-        lambda t: jnp.mean(t, axis=0),
-        jax.ShapeDtypeStruct((1,) + tuple(vshape), flat.dtype)).dtype
-    if mfull is None:
-        cnt = jnp.asarray(n, out_dt)
-        xf = flat.astype(out_dt)
-    else:
-        cnt = jnp.sum(mask.astype(out_dt))
-        xf = jnp.where(mfull, flat,
-                       jnp.asarray(0, flat.dtype)).astype(out_dt)
-    sums = jnp.sum(xf, axis=0)
-    if axes:
-        # ONE fused collective for the pre-mean components: the global
-        # count and per-slot sum land together
-        cnt, sums = jax.lax.psum((cnt, sums), axes)
-    safe = jnp.where(cnt > 0, cnt, jnp.asarray(1, out_dt))
-    mu = sums / safe
-    dev = xf - mu
-    if mfull is not None:
-        dev = jnp.where(mfull, dev, jnp.asarray(0, out_dt))
-    m2 = jnp.sum(dev * dev, axis=0)
-    if axes:
-        m2 = jax.lax.psum(m2, axes)
-    return cnt, mu, m2
 
+    def combine(self, a, b):
+        return self.rfunc(a, b)
+
+
+class _Moments(_Terminal):
+    """``mean`` / ``var`` / ``std``, and ``"moments"``: the statcounter
+    triple ``(n, mu, M2)`` per value slot itself, ONE of which serves
+    every mean/var/std member of a fused multi-stat group.  Partials
+    merge by the Chan et al. parallel recurrence (statcounter's
+    ``mergeStats``, vectorised over the value block): in a pairwise tree
+    power-of-two slab counts keep its denominators exact."""
+
+    def of_records(self, flat, mask, mfull, vshape, n, axes):
+        out_dt = jax.eval_shape(
+            lambda t: jnp.mean(t, axis=0),
+            jax.ShapeDtypeStruct((1,) + tuple(vshape), flat.dtype)).dtype
+        if mfull is None:
+            cnt = jnp.asarray(n, out_dt)
+            xf = flat.astype(out_dt)
+        else:
+            cnt = jnp.sum(mask.astype(out_dt))
+            xf = jnp.where(mfull, flat,
+                           jnp.asarray(0, flat.dtype)).astype(out_dt)
+        sums = jnp.sum(xf, axis=0)
+        if axes:
+            # ONE fused collective for the pre-mean components: the global
+            # count and per-slot sum land together
+            cnt, sums = jax.lax.psum((cnt, sums), axes)
+        safe = jnp.where(cnt > 0, cnt, jnp.asarray(1, out_dt))
+        mu = sums / safe
+        dev = xf - mu
+        if mfull is not None:
+            dev = jnp.where(mfull, dev, jnp.asarray(0, out_dt))
+        m2 = jnp.sum(dev * dev, axis=0)
+        if axes:
+            m2 = jax.lax.psum(m2, axes)
+        return cnt, mu, m2
+
+    def combine(self, a, b):
+        n1, mu1, m21 = a
+        n2, mu2, m22 = b
+        n = n1 + n2
+        safe = jnp.where(n > 0, n, jnp.asarray(1, n.dtype))
+        delta = mu2 - mu1
+        mu = mu1 + delta * (n2 / safe)
+        m2 = m21 + m22 + delta * delta * (n1 * n2 / safe)
+        return n, mu, m2
+
+    def fold(self, mesh, sample):
+        shape, dtype = tuple(sample[1].shape), str(sample[1].dtype)
+
+        def factory():
+            key = ("stream-merge-moments", shape, dtype, mesh,
+                   _multihost.topology_token())
+
+            def build():
+                def merge(n1, mu1, m21, n2, mu2, m22):
+                    return self.combine((n1, mu1, m21), (n2, mu2, m22))
+                return jax.jit(merge)
+            mp = _cached_jit(key, build)
+            return lambda a, b: tuple(mp(*a, *b))
+        return _PairFold(factory)
+
+    def finalise(self, folded, source):
+        """Moments triple → the requested statistic (engine-cached)."""
+        name, ddof, mesh = self.name, self.ddof, source.mesh
+        n, mu, m2 = folded
+        dtype = mu.dtype
+        key = ("stream-final", name, tuple(mu.shape), str(dtype), ddof,
+               mesh, _multihost.topology_token())
+
+        def build():
+            nan = jnp.asarray(jnp.nan, dtype)
+            dd = 0.0 if ddof is None else ddof
+
+            def final(n, mu, m2):
+                if name == "mean":
+                    return jnp.where(n > 0, mu, nan)
+                var = jnp.where(n > 0, m2 / (n - jnp.asarray(dd, n.dtype)),
+                                nan)
+                if name == "std":
+                    return jnp.sqrt(var)
+                return var
+            return jax.jit(final)
+        return _cached_jit(key, build)(n, mu, m2)
+
+
+def stat_terminal(name, ddof=None):
+    """The terminal of ONE streamed ``sum`` / ``mean`` / ``var`` / ``std``
+    (:func:`maybe_stat`; a fused group of one: ``tpu/multistat.py``)."""
+    return _Sum() if name == "sum" else _Moments(name, ddof)
+
+
+class _Tupled(_Terminal):
+    """A partial that is a TUPLE of components, each merged as ``comps``
+    says, all in one dispatch."""
+
+    @property
+    def order_sensitive(self):
+        return any(c.order_sensitive for c in self.comps)
+
+    def combine(self, a, b):
+        return tuple(c.combine(x, y) for c, x, y in zip(self.comps, a, b))
+
+    def fold(self, mesh, sample):
+        sig = tuple((tuple(leaf.shape), str(leaf.dtype))
+                    for leaf in jax.tree_util.tree_leaves(sample))
+
+        def factory():
+            key = ("stream-merge-multi", self.comps, sig, mesh,
+                   _multihost.topology_token())
+            mp = _cached_jit(key, lambda: jax.jit(
+                lambda a, b: self.combine(a, b)))
+            return lambda a, b: tuple(mp(a, b))
+        return _PairFold(factory)
+
+    def wrap(self, out, mesh):
+        return list(out)                  # one jax array per component
+
+
+class _Multi(_Tupled):
+    """A fused multi-stat group (``tpu/multistat.py``): ``specs`` is the
+    ordered ``(name, ddof)`` member list, a slab's partial one component
+    tuple from a SINGLE read of the slab, and the result one device
+    value a member, each finalised from the shared folded components
+    exactly as its standalone streamed terminal would be."""
+
+    def __init__(self, specs):
+        # ONE moments triple serves every mean/var/std member, min/max
+        # serve their members AND both halves of a ``ptp``
+        names = [name for name, _ in specs]
+        comps = []
+        if "sum" in names:
+            comps.append(_Sum())
+        if any(n in ("mean", "var", "std") for n in names):
+            comps.append(_Moments("moments"))
+        if "min" in names or "ptp" in names:
+            comps.append(_Extremum("min"))
+        if "max" in names or "ptp" in names:
+            comps.append(_Extremum("max"))
+        super().__init__("multi", comps=tuple(comps), params=tuple(specs))
+
+    @property
+    def names(self):
+        return [name for name, _ in self.params]
+
+    @property
+    def members(self):
+        return "|".join("%s:%s" % (n, d) for n, d in self.params)
+
+    def of_records(self, *records):
+        return tuple(c.of_records(*records) for c in self.comps)
+
+    def finalise(self, folded, source):
+        by = dict(zip((c.name for c in self.comps), folded))
+        outs = []
+        for name, ddof in self.params:
+            if name == "ptp":
+                # the SAME cached max−min program the in-memory fused
+                # groups use (one "multi-stat-sub" key per geometry)
+                from bolt_tpu.tpu.multistat import _sub_program
+                hi, lo = by["max"], by["min"]
+                outs.append(_sub_program(hi.shape, hi.dtype,
+                                         source.mesh)(hi, lo))
+            elif name in by:
+                outs.append(by[name])
+            else:
+                outs.append(_Moments(name, ddof).finalise(by["moments"],
+                                                          source))
+        return outs
+
+
+class _Group(_Tupled):
+    """``ops.segment_reduce`` by a label function (:func:`maybe_group`):
+    ``group`` is ``(op, label, value, nseg)``, a slab's partial the flat
+    tuple of the folded value's leaves (merged by the fold's own
+    operator), then the int32 counts (added), and the result the pair
+    ``(folded tree, counts)`` of bolt arrays keyed by group, as the
+    resident terminal's (``BoltArrayTPU._grouped_fold``).  A ``mean``
+    folds SUMS; the quotient is the finalise's."""
+
+    def __init__(self, group, source):
+        leaves = jax.tree_util.tree_leaves(self._avals(source, group[2]))
+        fop = _Sum() if group[0] in ("sum", "mean") else _Extremum(group[0])
+        super().__init__("group", comps=(fop,) * len(leaves) + (_Sum(),),
+                         params=tuple(group))
+
+    @staticmethod
+    def _avals(source, value):
+        """What ``value`` gives for ONE staged record (a tree of avals)."""
+        from bolt_tpu.tpu.array import _cached_eval_shape
+        st = result_state(source)
+        rec = jax.ShapeDtypeStruct(tuple(st.vshape), st.dtype)
+        if value is None:
+            return rec
+        return _cached_eval_shape(
+            ("segreduce-value", value, tuple(rec.shape), str(rec.dtype)),
+            lambda: jax.eval_shape(value, rec))
+
+    @property
+    def members(self):
+        from bolt_tpu.utils import code_token
+        return "/".join(code_token(x) if callable(x) else repr(x)
+                        for x in self.params)
+
+    def partial(self, x, stages, pred, post, split, apply, axes):
+        """It IS the resident terminal's fold, through the same entry,
+        ``fold.fold_records``, with the slab as its stored table: the
+        record-wise maps in front (:func:`maybe_group` admits no other
+        stage), the predicate, the maps behind it, the label and the
+        value traced into one pass, which over thin records in a program
+        for one TPU device is the ``thin_fold`` kernel."""
+        from bolt_tpu.tpu import fold as _fold
+        from bolt_tpu.tpu.array import _Filter, _chain_apply
+        op, label, value, nseg = self.params
+        funcs = tuple(s[1] for s in stages)
+        post = tuple(s[1] for s in post)
+        if op == "mean":
+            op, value = "sum", _promoted(value)
+        if pred is None:
+            src = _fold.Chain(funcs, 1)
+        else:
+            rec = jax.eval_shape(lambda d: _chain_apply(funcs, 1, d), x)
+            one = jax.ShapeDtypeStruct(rec.shape[1:], rec.dtype)
+            src = _Filter(None, funcs, pred, 1, tuple(one.shape), x.shape[0],
+                          one.dtype, post, jax.eval_shape(
+                              lambda r: _chain_apply(post, 0, r), one))
+        folded, counts = _fold.fold_records(
+            _fold.Fold(src, group=(op, label, value, nseg)), x)
+        return tuple(jax.tree_util.tree_leaves(folded)) + (counts,)
+
+    def finalise(self, folded, source):
+        from bolt_tpu.tpu.array import _constrain
+        op, _, value, nseg = self.params
+        tree = jax.tree_util.tree_structure(self._avals(source, value))
+        mesh = source.mesh
+        sig = tuple((tuple(x.shape), str(x.dtype)) for x in folded)
+        key = ("stream-final-group", op, nseg, tree, sig, mesh)
+
+        def build():
+            def final(*parts):
+                counts = parts[-1]
+                outs = []
+                for out in parts[:-1]:
+                    if op == "mean":
+                        out = out / jnp.maximum(counts, 1).astype(
+                            out.dtype).reshape(
+                                (nseg,) + (1,) * (out.ndim - 1))
+                    outs.append(_constrain(out, mesh, 1))
+                return (jax.tree_util.tree_unflatten(tree, outs),
+                        _constrain(counts, mesh, 1))
+            return jax.jit(final)
+        return _cached_jit(key, build)(*folded)
+
+    def wrap(self, out, mesh):
+        from bolt_tpu.tpu.array import BoltArrayTPU
+        wrap = lambda o: BoltArrayTPU(o, 1, mesh)      # noqa: E731
+        return jax.tree_util.tree_map(wrap, out[0]), wrap(out[1])
+
+    def tally(self, nslabs, kernel_slabs):
+        return {"group": nslabs}
+
+
+class _Gram(_Tupled):
+    """A Gram matrix over the sample axes (:func:`maybe_gram`): ``gram``
+    is ``(sample axes, precision, second_conj, sums)``, a slab's partial
+    ``(G,)`` or ``(G, s)``, added, and the result those device arrays."""
+
+    def __init__(self, gram):
+        super().__init__("gram", comps=(_Sum(),) * (1 + gram[3]),
+                         params=tuple(gram))
+
+    @property
+    def members(self):
+        return repr(self.params)
+
+    def partial(self, x, stages, pred, post, split, apply, axes):
+        """The resident program's own body (``ops/linalg.py ::
+        _pca_program``) over the slab as its stages leave it (no filter:
+        :func:`gram_refusal`): the features merged and widened by
+        ``_features_last``, the samples contracted where they lie by
+        ``_sample_gram``, in a program for one TPU device the
+        ``packed_gram`` kernel."""
+        from bolt_tpu.ops import linalg as _linalg
+        for stg in stages:
+            x = apply(stg, split, x)
+        m, precision, second_conj, sums = self.params
+        x, widened = _linalg._features_last(x, x.shape[:m],
+                                            prod(x.shape[m:]))
+        out = _linalg._sample_gram(x, precision, second_conj=second_conj,
+                                   widened=widened, sums=sums)
+        return tuple(out) if sums else (out,)
+
+    def tally(self, nslabs, kernel_slabs):
+        return {"gram": nslabs, "gram_kernel": kernel_slabs}
+
+
+# ---------------------------------------------------------------------
+# per-slab programs and the pairwise fold of their partials
+# ---------------------------------------------------------------------
 
 def _split_at_filter(stages):
     """``(head, pred, post)``: the stages in front of the filter, its
@@ -1566,113 +1915,44 @@ def _promoted(value):
     return promoted
 
 
-def _group_partial(group, funcs, pred, post, x):
-    """One slab's partial of the grouped fold ``group`` (``(op, label,
-    value, nseg)``) over the slab ``x`` as uploaded: the flat tuple of
-    the folded value's leaves, then the int32 counts.  It IS the resident
-    terminal's fold (``tpu/array.py :: _grouped_fold``), through the same
-    entry, ``fold.fold_records``, with the slab as its stored table: the
-    maps ``funcs``, the predicate, the maps ``post`` behind it, the label
-    and the value are traced into one pass, which over thin records in a
-    program for one TPU device is the ``thin_fold`` kernel.  A ``mean``
-    folds SUMS here; the quotient is the finalise's."""
-    from bolt_tpu.tpu import fold as _fold
-    from bolt_tpu.tpu.array import _Filter, _chain_apply
-    op, label, value, nseg = group
-    if op == "mean":
-        op, value = "sum", _promoted(value)
-    if pred is None:
-        src = _fold.Chain(funcs, 1)
-    else:
-        rec = jax.eval_shape(lambda d: _chain_apply(funcs, 1, d), x)
-        one = jax.ShapeDtypeStruct(rec.shape[1:], rec.dtype)
-        src = _Filter(None, funcs, pred, 1, tuple(one.shape), x.shape[0],
-                      one.dtype, post, jax.eval_shape(
-                          lambda r: _chain_apply(post, 0, r), one))
-    folded, counts = _fold.fold_records(
-        _fold.Fold(src, group=(op, label, value, nseg)), x)
-    return tuple(jax.tree_util.tree_leaves(folded)) + (counts,)
-
-
-def _gram_partial(gram, x):
-    """One slab's partial of the Gram terminal ``gram`` (``(sample axes,
-    precision, second_conj, sums)``) over the slab ``x`` as its stages
-    leave it: ``(G,)`` or ``(G, s)``.  The resident program's own body
-    (``ops/linalg.py :: _pca_program``): the features merged and widened
-    by ``_features_last``, the samples contracted where they lie by
-    ``_sample_gram``, which in a program for one TPU device is the
-    ``packed_gram`` kernel over the slab where it lies."""
-    from bolt_tpu.ops import linalg as _linalg
-    m, precision, second_conj, sums = gram
-    x, widened = _linalg._features_last(x, x.shape[:m], prod(x.shape[m:]))
-    out = _linalg._sample_gram(x, precision, second_conj=second_conj,
-                               widened=widened, sums=sums)
-    return tuple(out) if sums else (out,)
-
-
-def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
-                  comps=None, sharded=False, codec_obj=None, group=None,
-                  thin=False, gram=None):
+def _slab_program(source, terminal, slab_shape, fused=False, sharded=False,
+                  codec_obj=None, thin=False):
     """The ONE compiled program each slab runs: device-side stages +
-    (masked) terminal partial, with the slab buffer DONATED so the ring
-    recycles its memory.  ``fused=True`` is the level-0 fold fusion: the
-    program additionally takes the PREVIOUS slab's partial and merges it
-    in the same dispatch (``prog(buf, acc)``), halving fold dispatches —
-    the acc is donated too, it is consumed.  ``terminal="multi"`` emits
-    a TUPLE of component partials (``comps`` ⊆ sum/moments/min/max) from
-    the SAME single read of the slab — the streamed half of the fused
-    multi-stat layer (bolt_tpu/tpu/multistat.py); each component traces
-    the exact standalone expression via :func:`_terminal_partial`.
-    ``terminal="group"`` emits the grouped fold ``group``'s partial, a
-    tuple likewise (:func:`_group_partial`), ``terminal="gram"`` the
-    Gram matrix ``gram``'s (:func:`_gram_partial`).  ``thin``: the
+    ``terminal``'s (masked) partial (:meth:`_Terminal.partial`), with the
+    slab buffer DONATED so the ring recycles its memory.  ``fused=True``
+    is the level-0 fold fusion: the program additionally takes the
+    PREVIOUS slab's partial and merges it in the same dispatch
+    (``prog(buf, acc)``, :meth:`_Terminal.combine`), halving fold
+    dispatches — the acc is donated too, it is consumed.  ``thin``: the
     uploaded buffer is the dense form of a slab of thin records
     (:func:`_dense_views`), given its shape by :func:`_reseat` first.
 
     ``codec_obj`` (ISSUE 14) is the ingest codec whose device-side
-    DECODE is fused in as the program's FIRST traced expression: the
-    uploaded buffer is the wire representation (plus sidecar leaves for
-    sidecar codecs — the whole pytree is donated like the raw slab
-    was), and the decoded values feed the exact same stage chain and
-    terminal partial the uncompressed program traces — decode costs
-    zero extra HBM passes.  With ``BOLT_CODEC_KERNEL=1`` an int8
-    streamed ``sum`` with no stages routes through the Pallas
-    decode-and-reduce kernel (``ops.kernels.fused_decode_sum``,
-    geometry-gated, parity-locked) so the decode never leaves
-    registers.
+    DECODE is the program's FIRST traced expression: the uploaded buffer
+    is the wire representation (plus sidecar leaves, donated like the
+    raw slab was), and the decoded values feed the same stage chain and
+    partial the uncompressed program traces — no extra HBM pass.
 
     ``sharded=True`` is the POD form (``parallel.multihost``): the same
-    partial body runs under ``shard_map`` — each device computes its
-    shard's partial and the reduction points carry the cross-host
-    mesh-axis collective (see :func:`_terminal_partial`), so the
-    program's output is the ALREADY-GLOBAL pair partial, replicated on
-    every process (``out_specs=P()``).  The level-0 acc merge stays an
-    elementwise combine on replicated values outside the shard_map —
-    no extra collective; codec decode happens per shard INSIDE the
-    shard_map (sidecar codecs are refused on pods before any thread
-    starts).  Engine-cached per (stages, terminal, slab geometry,
-    fused, comps, codec, process topology): uniform slabs compile
-    exactly once per variant PER PROCESS."""
+    partial body under ``shard_map`` with the terminal's cross-host
+    collectives in it, so the program's output is the ALREADY-GLOBAL
+    pair partial, replicated on every process (``out_specs=P()``).  The
+    level-0 acc merge stays an elementwise combine on replicated values
+    outside the shard_map; codec decode happens per shard INSIDE it
+    (sidecar codecs are refused on pods before any thread starts).
+    Engine-cached per (stages, terminal, slab geometry, fused, codec,
+    process topology): uniform slabs compile once a variant A PROCESS."""
     stages, pred, post = _split_at_filter(source.stages)
     split = source.split
     mesh = source.mesh
     raw_dtype = source.dtype
     delta_ok = split < len(source.shape)
-    # one device only: GSPMD cannot partition a Mosaic kernel, and this
-    # program is not under shard_map off pods
-    use_kernel = (codec_obj is not None and codec_obj.name == "int8"
-                  and terminal == "sum" and not stages and pred is None
-                  and not sharded and split == 1
-                  and mesh.devices.size == 1
-                  and _codec_registry().kernel_enabled())
-    keyed, side = stage_extras(stages + post)
+    keyed, _ = stage_extras(stages + post)
     key = ("stream-slab-acc" if fused else "stream-slab", terminal,
            stage_keys(stages), pred, slab_shape, str(source.dtype), split,
-           ddof,
-           rfunc, comps, mesh,
-           _multihost.topology_token() if sharded else None,
+           mesh, _multihost.topology_token() if sharded else None,
            codec_obj.name if codec_obj is not None else None,
-           stage_keys(post), group, thin, gram, use_kernel)
+           stage_keys(post), thin)
 
     def build():
         axes = _multihost.key_collective_axes(mesh, slab_shape, split) \
@@ -1680,62 +1960,21 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
 
         def partial(data, *extra):
             # under shard_map ``data`` is ONE device shard; standalone it
-            # is the whole slab — the body is shape-polymorphic and the
-            # collective points in _terminal_partial close the gap.
+            # is the whole slab (the body is shape-polymorphic).
             # ``extra``: the slab's first key (a keyed stage chain) then
-            # the side operands; empty for every other chain, whose
-            # program is the one it always was
-            from bolt_tpu.tpu.array import _pred_mask
+            # the side operands; empty for every other chain
             key0 = extra[0] if keyed else None
             operands = iter(extra[1:] if keyed else extra)
             if codec_obj is None:
                 x = _reseat(data) if thin else data
+            elif codec_obj.sidecar:
+                x = codec_obj.decode(data[0], data[1:], raw_dtype, delta_ok)
             else:
-                if use_kernel:
-                    # the opt-in in-register decode-and-reduce: plan
-                    # resolution is static (shapes), so this branch is
-                    # decided at trace time; off-plan geometries fall
-                    # through to the XLA decode below
-                    from bolt_tpu.ops.kernels import fused_decode_sum
-                    out = fused_decode_sum(data[0], data[1], data[2])
-                    if out is not None:
-                        s = out.astype(raw_dtype)
-                        return jax.lax.psum(s, axes) if axes else s
-                if codec_obj.sidecar:
-                    x = codec_obj.decode(data[0], data[1:], raw_dtype,
-                                         delta_ok)
-                else:
-                    x = codec_obj.decode(data, (), raw_dtype, delta_ok)
-            if terminal == "group":
-                # record-wise maps alone (maybe_group): the fold's own
-                return _group_partial(
-                    group, tuple(s[1] for s in stages), pred,
-                    tuple(s[1] for s in post), x)
-            for stg in stages:
-                x = _stage_apply(stg, split, x, key0, operands)
-            if terminal == "gram":
-                # no filter (gram_refusal): every sample of the slab
-                return _gram_partial(gram, x)
-            vshape = x.shape[split:]
-            n = prod(x.shape[:split])
-            flat = x.reshape((n,) + vshape)
-            mask = mfull = None
-            if pred is not None:
-                mask = _pred_mask(pred, flat)
-                # a map behind the filter reads every record; the mask
-                # then folds what it gave for a dropped one away
-                for stg in post:
-                    flat = _stage_apply(stg, 1, flat, key0, operands)
-                vshape = flat.shape[1:]
-                mfull = mask.reshape((n,) + (1,) * len(vshape))
-            if terminal == "multi":
-                return tuple(
-                    _terminal_partial(c, flat, mask, mfull, vshape, n,
-                                      None, axes=axes)
-                    for c in comps)
-            return _terminal_partial(
-                terminal if terminal in ("sum", "reduce") else "moments",
-                flat, mask, mfull, vshape, n, rfunc, axes=axes)
+                x = codec_obj.decode(data, (), raw_dtype, delta_ok)
+            return terminal.partial(
+                x, stages, pred, post, split,
+                lambda stg, at, v: _stage_apply(stg, at, v, key0, operands),
+                axes)
 
         if sharded:
             from jax.sharding import PartitionSpec
@@ -1758,117 +1997,10 @@ def _slab_program(source, terminal, slab_shape, ddof, rfunc, fused=False,
         def run(data, acc, *extra):
             # level-0 fold fused in: acc (the EVEN slab's partial) merges
             # with this (ODD) slab's partial inside one dispatch
-            return _combine(terminal, rfunc, acc, body(data, *extra),
-                            comps=comps)
+            return terminal.combine(acc, body(data, *extra))
         return jax.jit(run, donate_argnums=(0, 1))
 
     return _cached_jit(key, build)
-
-
-def _merge_program(terminal, shape, dtype, rfunc, mesh):
-    """On-device merge of two pair-partials — the tree above level 0
-    (tiny, engine-cached, same :func:`_combine` arithmetic the fused
-    slab program traces)."""
-    if terminal in ("sum", "reduce"):
-        key = ("stream-merge", terminal, rfunc, tuple(shape), str(dtype),
-               mesh, _multihost.topology_token())
-
-        def build():
-            return jax.jit(lambda a, b: _combine(terminal, rfunc, a, b))
-        return _cached_jit(key, build)
-
-    key = ("stream-merge-moments", tuple(shape), str(dtype), mesh,
-           _multihost.topology_token())
-
-    def build():
-        def merge(n1, mu1, m21, n2, mu2, m22):
-            return _combine("moments", None, (n1, mu1, m21),
-                            (n2, mu2, m22))
-        return jax.jit(merge)
-    return _cached_jit(key, build)
-
-
-def _merge_multi_program(comps, sig, mesh):
-    """Pairwise merge of two fused multi-stat partial TUPLES (pytree
-    in, pytree out — one dispatch merges every component; ``sig`` is
-    the flattened (shape, dtype) leaf signature for the cache key)."""
-    key = ("stream-merge-multi", comps, sig, mesh,
-           _multihost.topology_token())
-
-    def build():
-        return jax.jit(lambda a, b: _combine("multi", None, a, b,
-                                             comps=comps))
-    return _cached_jit(key, build)
-
-
-def _finalise_program(terminal, shape, dtype, ddof, mesh):
-    """Moments triple → the requested statistic (engine-cached)."""
-    key = ("stream-final", terminal, tuple(shape), str(dtype), ddof, mesh,
-           _multihost.topology_token())
-
-    def build():
-        nan = jnp.asarray(jnp.nan, dtype)
-        dd = 0.0 if ddof is None else ddof
-
-        def final(n, mu, m2):
-            if terminal == "mean":
-                return jnp.where(n > 0, mu, nan)
-            var = jnp.where(n > 0, m2 / (n - jnp.asarray(dd, n.dtype)),
-                            nan)
-            if terminal == "std":
-                return jnp.sqrt(var)
-            return var
-        return jax.jit(final)
-    return _cached_jit(key, build)
-
-
-def _group_avals(source, group):
-    """``(record, value)`` avals of a grouped fold over ``source``: ONE
-    record as the stage chain leaves it, and what ``value`` gives for it
-    (a tree of avals; the record where ``value`` is ``None``)."""
-    from bolt_tpu.tpu.array import _cached_eval_shape
-    st = result_state(source)
-    rec = jax.ShapeDtypeStruct(tuple(st.vshape), st.dtype)
-    value = group[2]
-    if value is None:
-        return rec, rec
-    return rec, _cached_eval_shape(
-        ("segreduce-value", value, tuple(rec.shape), str(rec.dtype)),
-        lambda: jax.eval_shape(value, rec))
-
-
-def _group_comps(source, group):
-    """The merges of a grouped partial's components: the fold's own for
-    every leaf of the value, ``sum`` for the counts."""
-    leaves = jax.tree_util.tree_leaves(_group_avals(source, group)[1])
-    fop = "sum" if group[0] == "mean" else group[0]
-    return (fop,) * len(leaves) + ("sum",)
-
-
-def _finalise_group(folded, group, source):
-    """The folded flat tuple -> ``(folded tree, counts)`` keyed by group,
-    as the resident program returns them; a ``mean``'s sums over its
-    counts (engine-cached)."""
-    from bolt_tpu.tpu.array import _constrain
-    op, nseg = group[0], group[3]
-    tree = jax.tree_util.tree_structure(_group_avals(source, group)[1])
-    mesh = source.mesh
-    sig = tuple((tuple(x.shape), str(x.dtype)) for x in folded)
-    key = ("stream-final-group", op, nseg, tree, sig, mesh)
-
-    def build():
-        def final(*parts):
-            counts = parts[-1]
-            outs = []
-            for out in parts[:-1]:
-                if op == "mean":
-                    out = out / jnp.maximum(counts, 1).astype(
-                        out.dtype).reshape((nseg,) + (1,) * (out.ndim - 1))
-                outs.append(_constrain(out, mesh, 1))
-            return (jax.tree_util.tree_unflatten(tree, outs),
-                    _constrain(counts, mesh, 1))
-        return jax.jit(final)
-    return _cached_jit(key, build)(*folded)
 
 
 class _PairFold:
@@ -1912,33 +2044,6 @@ class _PairFold:
         return acc
 
 
-def _make_fold(terminal, rfunc, comps, mesh, part):
-    """A fresh :class:`_PairFold` for one run, its merge-program factory
-    derived from a sample partial ``part`` — which may be a live device
-    value (the first pushed pair) OR a host array restored from a
-    checkpoint (the resume path rebuilds the fold around the persisted
-    levels).  Captures only shape/dtype: a factory closing over the
-    live partial would pin its device buffers for the whole run."""
-    if terminal in ("sum", "reduce"):
-        shape, dtype = part.shape, part.dtype
-        return _PairFold(lambda: _merge_program(terminal, shape, dtype,
-                                                rfunc, mesh))
-    if terminal in _TUPLED:
-        sig = tuple((tuple(leaf.shape), str(leaf.dtype))
-                    for leaf in jax.tree_util.tree_leaves(part))
-
-        def factory():
-            mp = _merge_multi_program(comps, sig, mesh)
-            return lambda a, b: tuple(mp(a, b))
-        return _PairFold(factory)
-    mshape, mdtype = part[1].shape, part[1].dtype
-
-    def factory():
-        mp = _merge_program(terminal, mshape, mdtype, None, mesh)
-        return lambda a, b: tuple(mp(*a, *b))
-    return _PairFold(factory)
-
-
 def _stage_token(stage):
     """One stage's fingerprint element: the kind, every callable by its
     BYTECODE token (``utils.code_token`` — two lambdas with different
@@ -1948,38 +2053,30 @@ def _stage_token(stage):
                     for x in stage)
 
 
-def _run_fingerprint(source, terminal, ddof, rfunc, specs, codec=None,
-                     group=None, gram=None):
+def _run_fingerprint(source, terminal, codec=None):
     """Identity of one LOGICAL streamed run for checkpoint matching:
     source geometry + slab plan + stage chain + terminal + ingest
     CODEC, with every user callable (stage funcs, the filter predicate,
-    ``rfunc``, a callback source's ``produce``) identified by its
-    bytecode digest — an EDITED pipeline over the same dir is refused,
-    never resumed wrong, and a resumed run never adopts a checkpoint
-    cut under a DIFFERENT codec (the fold partials are decoded values;
-    mixing an uncompressed prefix with a quantised tail would be
-    silently wrong, so a codec change restarts from scratch).  Closure
-    DATA is not hashable (no checkpoint format's is): re-pointing an
-    identical loader at different bytes of the same geometry is the
-    caller's contract, as with any resume system."""
+    a ``reduce``'s function, a group's label and value, a callback
+    source's ``produce``) identified by its bytecode digest — an EDITED
+    pipeline over the same dir is refused, never resumed wrong, and a
+    resumed run never adopts a checkpoint cut under a DIFFERENT codec
+    (the fold partials are decoded values; mixing an uncompressed
+    prefix with a quantised tail would be silently wrong, so a codec
+    change restarts from scratch).  Closure DATA is not hashable (no
+    checkpoint format's is): re-pointing an identical loader at
+    different bytes of the same geometry is the caller's contract, as
+    with any resume system."""
     from bolt_tpu.utils import code_token
     stages = "|".join(_stage_token(s) for s in source.stages)
-    members = "|".join("%s:%s" % (n, d) for n, d in specs) if specs else ""
-    if group is not None:
-        # a grouped fold's members: its op, its group count, and the
-        # label and value functions by bytecode like every callable
-        members = "/".join(code_token(x) if callable(x) else repr(x)
-                           for x in group)
-    if gram is not None:
-        # a Gram matrix's: its sample axes, precision, conjugation, sums
-        members = repr(gram)
-    return ("bolt-stream-ckpt-v2", str(terminal), str(ddof),
+    rfunc = terminal.rfunc
+    return ("bolt-stream-ckpt-v2", str(terminal.name), str(terminal.ddof),
             code_token(rfunc) if rfunc is not None else "",
             "x".join(str(s) for s in source.shape),
             int(source.split), str(source.dtype), int(source.slab),
             str(source.kind),
             code_token(source.produce) if source.produce is not None
-            else "", stages, members, str(codec or ""))
+            else "", stages, terminal.members, str(codec or ""))
 
 
 # ---------------------------------------------------------------------
@@ -2145,9 +2242,14 @@ class _Run:
     both consumers of the ingest pool alike."""
 
     __slots__ = ("depth", "nwork", "codec", "delta_ok", "wire_item",
-                 "mspec", "tenant", "lease", "nretry")
+                 "mspec", "pod", "tenant", "lease", "nretry", "ingest",
+                 "compute", "_ready")
 
     def __init__(self, source):
+        # seconds of the regions the obs spans cover: the pool's
+        # ``stream.ingest``, the consumer's ``stream.compute`` + ``.sync``
+        self.ingest = self.compute = 0.0
+        self._ready = False
         self.depth = prefetch_depth()
         self.nwork = pool_size(source)
         # the source's own codec= wins over the scope; integer/bool
@@ -2173,6 +2275,7 @@ class _Run:
                 raise ValueError(err)       # per-process sidecars cannot
                 #                             feed a shard_map slab program
             self.mspec = _multihost.local_slab_spec(source)
+        self.pod = self.mspec is not None
         # the tenant tag rides into the pool threads, so their transfers
         # land in the submitter's counters, and under an ACTIVE serving
         # arbiter the run leases its slab bytes from the process-wide
@@ -2184,6 +2287,30 @@ class _Run:
         self.lease = (arb.lease(self.tenant or "default")
                       if arb is not None else None)
         self.nretry = retry_limit()
+
+    def enter(self):
+        """The consumer's loop begins.  The supervisor must not reform
+        the pod UP under a live collective schedule — this counter is
+        what its quiesce drain waits on (parallel.supervisor)."""
+        if self.pod:
+            _podwatch.pod_enter()
+
+    def ready(self):
+        """Before a dispatch; on a pod the FIRST call is the readiness
+        rendezvous (ISSUE 12): confirm every peer is alive over the
+        heartbeat transport BEFORE a dispatch enters the runtime — a
+        peer that died raises the pointed PeerLostError within ~2x
+        BOLT_POD_TIMEOUT instead of ~30s in gloo's connect."""
+        if self.pod and not self._ready:
+            _podwatch.ready_rendezvous()
+            self._ready = True
+
+    def leave(self):
+        """The consumer's way out: every outstanding budget byte back."""
+        if self.pod:
+            _podwatch.pod_exit()
+        if self.lease is not None:
+            self.lease.close()
 
 
 class _IngestPool:
@@ -2260,6 +2387,15 @@ class _IngestPool:
             return got[1]
         finally:
             _obs.end(sp)
+
+    def form(self, buf):
+        """``(thin, shape)`` of a slab :meth:`next` handed over: whether
+        it went up as :func:`_dense_views` of it, and the shape of the
+        slab its program is built for."""
+        if self._dense and isinstance(buf, tuple):
+            return True, _dense_shape(buf)
+        return False, (buf[0].shape if isinstance(buf, tuple)
+                       else buf.shape)
 
     def give_back(self, slabs, nbytes):
         """Return ``slabs`` ring permits and ``nbytes`` lease bytes (the
@@ -2537,6 +2673,17 @@ def _pod_sync(x, pod, phase, slab=None):
         _podwatch.reraise(exc, phase=phase, slab=slab)
 
 
+@contextlib.contextmanager
+def _undonated_ok():
+    """Around a slab's dispatch: backends without donation (the CPU dev
+    mesh) warn that the donated slab was unusable, and a place call's is
+    never aliased (no output has its shape): noise once a geometry."""
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        yield
+
+
 def _retired(handle):
     """Whether the slab program that returned ``handle`` is done, asked
     without blocking (through the module-level name: the tests' patch
@@ -2544,60 +2691,123 @@ def _retired(handle):
     return handle.is_ready()
 
 
-def _multi_comps(specs):
-    """Canonical component tuple for a fused multi-stat spec list —
-    ONE 'moments' triple serves every mean/var/std member, 'min'/'max'
-    serve their members AND both halves of a ``ptp``."""
-    names = [name for name, _ in specs]
-    comps = []
-    if "sum" in names:
-        comps.append("sum")
-    if any(n in ("mean", "var", "std") for n in names):
-        comps.append("moments")
-    if "min" in names or "ptp" in names:
-        comps.append("min")
-    if "max" in names or "ptp" in names:
-        comps.append("max")
-    return tuple(comps)
+class _Window:
+    """The confirm window of one streamed run: the slab programs its
+    consumer has dispatched and not yet confirmed done, oldest first.
+    Confirming a call hands its slabs' ring permits and lease bytes back
+    to the pool; WHEN to block for one is the consumer's policy.
+
+    ``phase`` names the block to the pod watchdog; ``attrs`` are the
+    consumer's own attributes of a ``stream.sync`` span; ``failed(slab,
+    exc)`` builds the error a failed confirm raises (``None``: the
+    failure itself); ``settle(handle, slab)`` runs between the confirm
+    and the give-back (the spill leg persists the part it confirmed)."""
+
+    def __init__(self, run, pool, phase, attrs=None, failed=None,
+                 settle=None):
+        self._run = run
+        self._pool = pool
+        self._phase = phase
+        self._attrs = attrs or {}
+        self._failed = failed
+        self._settle = settle
+        self._calls = deque()   # (slabs covered, handle, lease bytes, slab)
+        self._lone = 0
+        self.unconfirmed = 0    # slabs dispatched and not confirmed
+        self.high_water = 0
+
+    def _grew(self, slabs):
+        self.unconfirmed += slabs
+        self.high_water = max(self.high_water, self.unconfirmed)
+
+    def lone(self):
+        """A slab dispatched whose handle the NEXT call consumes
+        (``execute``'s even slab, donated into its pair's program):
+        unconfirmed from now, covered by that call's push."""
+        self._lone += 1
+        self._grew(1)
+
+    def push(self, cover, handle, nbytes, slab=None):
+        """A call dispatched: ``handle`` is ready when it is done and
+        stands for ``cover`` slabs (the lone ones before it among them)
+        and ``nbytes`` lease bytes; ``slab`` names it on its span."""
+        self._calls.append((cover, handle, nbytes, slab))
+        self._grew(cover - self._lone)
+        self._lone = 0
+
+    def confirm_oldest(self):
+        """Block for the OLDEST unconfirmed call and hand its permits and
+        lease bytes back.  On a pod the block rides the watchdog: a call
+        whose collective a dead peer will never complete raises the
+        pointed PeerLostError instead of hanging this survivor.  The
+        entry leaves only once confirmed: what a failure leaves,
+        :meth:`release` gives back."""
+        cover, handle, nbytes, slab = self._calls[0]
+        named = {} if slab is None else {"slab": slab}
+        t0 = _clock()
+        ssp = _obs.begin("stream.sync", slabs=cover, **self._attrs, **named)
+        try:
+            _pod_sync(handle, self._run.pod, self._phase, slab=slab)
+        except _podwatch.PeerLostError:
+            raise
+        except Exception as exc:  # noqa: BLE001
+            if self._failed is None:
+                raise
+            raise self._failed(slab, exc) from exc
+        finally:
+            _obs.end(ssp)
+        if self._settle is not None:
+            self._settle(handle, slab)
+        self._calls.popleft()
+        del handle              # a spilled part goes before its permit
+        self.unconfirmed -= cover
+        self._run.compute += _clock() - t0
+        self._pool.give_back(cover, nbytes)
+
+    def retire(self, keep):
+        """Confirm the oldest calls, blocking, until at most ``keep``
+        stay unconfirmed, and WITHOUT blocking every head of the window
+        that is done already: its permit goes back when the device lets
+        go of the slab, not a slab later."""
+        while self._calls and (len(self._calls) > keep
+                               or _retired(self._calls[0][1])):
+            self.confirm_oldest()
+
+    def starved(self):
+        """The arbiter-backed starvation valve (``pool.next``'s
+        ``idle``): with the feeder possibly blocked on budget bytes,
+        confirm one call per empty poll so its bytes recycle — a budget
+        under the full ring then runs a shallower window instead of
+        deadlocking.  Opens ONLY under real arbiter contention (some
+        acquire is queued): a feeder merely slow on I/O must not collapse
+        the window into per-slab syncs.  Says whether it confirmed."""
+        if self._calls and self._run.lease.arbiter.waiting():
+            self.confirm_oldest()
+            return True
+        return False
+
+    def release(self):
+        """The run's way out, after the pool closed: every call still
+        unconfirmed (a run that ended early) gives back what it holds."""
+        while self._calls:
+            cover, _, nbytes, _ = self._calls.popleft()
+            self.unconfirmed -= cover
+            self._pool.give_back(cover, nbytes)
 
 
-def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
-            source=None, group=None, gram=None):
-    """Run a streamed reduction terminal over ``arr``'s source: the
-    parallel-ingest, async-dispatch pipeline described in the module
-    docstring.  Returns a value-shaped ``BoltArrayTPU`` (``split=0``).
-
-    ``terminal="multi"`` streams a fused multi-stat group
-    (bolt_tpu/tpu/multistat.py): ``specs`` is the ordered ``(name,
-    ddof)`` member list, the per-slab program emits one component tuple
-    per slab from a single read, and the return value is a LIST of
-    value-shaped arrays, one per member — each finalised from the
-    shared folded components exactly as its standalone streamed
-    terminal would be.  ``source`` overrides ``arr._stream`` for
-    callers resolving already-detached pending handles (``arr=None``
-    skips the strict gate — the handle was gated at creation).
-
-    ``terminal="group"`` streams ``ops.segment_reduce`` by a label
-    function (:func:`maybe_group`): ``group`` is ``(op, label, value,
-    nseg)``, a slab's partial the flat tuple of the folded leaves and
-    the int32 counts (:func:`_group_partial`), merged component by
-    component as the multi tuple is, and the return value the pair
-    ``(folded tree, counts)`` of bolt arrays keyed by group.
-
-    ``terminal="gram"`` streams a Gram matrix (:func:`maybe_gram`):
-    ``gram`` is ``(sample axes, precision, second_conj, sums)``, a slab's
-    partial ``(G,)`` or ``(G, s)`` (:func:`_gram_partial`), its
-    components added, and the return value that tuple of device
-    arrays."""
-    from bolt_tpu.tpu.array import BoltArrayTPU
+def execute(arr, terminal, source=None):
+    """Run the streamed reduction ``terminal`` (a :class:`_Terminal`)
+    over ``arr``'s source: the parallel-ingest, async-dispatch pipeline
+    described in the module docstring.  Returns what the terminal wraps
+    its result as (:meth:`_Terminal.wrap`: a value-shaped
+    ``BoltArrayTPU``, ``split=0``, for a statistic or a ``reduce``).
+    ``source`` overrides ``arr._stream`` for callers resolving
+    already-detached pending handles (``arr=None`` skips the strict gate
+    — the handle was gated at creation)."""
     if source is None:
         source = arr._stream
-    comps = (_multi_comps(specs) if terminal == "multi"
-             else _group_comps(source, group) if terminal == "group"
-             else ("sum",) * (1 + gram[3]) if terminal == "gram"
-             else None)
     if arr is not None:
-        _engine.strict_guard(arr, "stream.%s()" % terminal)
+        _engine.strict_guard(arr, "stream.%s()" % terminal.name)
     if has_swap(source):
         # every terminal door resolves swaps before entering here; a
         # swap stage reaching the slab pipeline means a door was missed
@@ -2607,21 +2817,17 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
             "(stream.resolve_swaps)")
     run = _Run(source)
     mesh, depth, nwork = source.mesh, run.depth, run.nwork
-    codec_obj, mspec, lease = run.codec, run.mspec, run.lease
+    codec_obj, pod, lease = run.codec, run.pod, run.lease
     # order statistics are bit-exactness-sensitive, so lossy codecs
     # refuse them
-    if codec_obj is not None and not codec_obj.lossless:
-        order = terminal in ("min", "max") or (
-            terminal in _TUPLED
-            and any(c in ("min", "max") for c in comps))
-        if order:
-            names = [n for n, _ in specs] if specs else [terminal]
-            raise ValueError(
-                "lossy codec %r refused for the order-statistic "
-                "terminal(s) %s: min/max/ptp are exact by contract and "
-                "a quantised extremum is never the answer the caller "
-                "meant.  Use the lossless 'delta-f32' codec, or stream "
-                "this terminal uncompressed" % (codec_obj.name, names))
+    if codec_obj is not None and not codec_obj.lossless \
+            and terminal.order_sensitive:
+        raise ValueError(
+            "lossy codec %r refused for the order-statistic "
+            "terminal(s) %s: min/max/ptp are exact by contract and "
+            "a quantised extremum is never the answer the caller "
+            "meant.  Use the lossless 'delta-f32' codec, or stream "
+            "this terminal uncompressed" % (codec_obj.name, terminal.names))
     # resumable checkpointing (ISSUE 9): a per-source checkpoint dir
     # (fromcallback/fromiter checkpoint=) wins over the thread's
     # resumable() scope.  A matching checkpoint from a killed run is
@@ -2644,8 +2850,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
     ck_remap = None
     if ck_dir is not None:
         from bolt_tpu import checkpoint as _ckptlib
-        if mspec is not None and \
-                _multihost.mesh_process_count(mesh) \
+        if pod and _multihost.mesh_process_count(mesh) \
                 != _multihost.process_count():
             # the checkpoint rendezvous (multihost.barrier) is a
             # collective over the WHOLE runtime; a mesh spanning only a
@@ -2662,15 +2867,13 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 % (_multihost.mesh_process_count(mesh),
                    _multihost.process_count()))
         ck_fp = _run_fingerprint(
-            source, terminal, ddof, rfunc, specs,
-            codec=codec_obj.name if codec_obj is not None else None,
-            group=group, gram=gram)
+            source, terminal,
+            codec=codec_obj.name if codec_obj is not None else None)
         # the MESH's multiprocess answer, not the runtime's: a
         # process-local mesh inside a multi-process runtime checkpoints
         # single-process (its peers are elsewhere; a barrier would hang)
         ck_info = {}
-        got_ck = _ckptlib.stream_load(ck_dir, ck_fp,
-                                      multiprocess=mspec is not None,
+        got_ck = _ckptlib.stream_load(ck_dir, ck_fp, multiprocess=pod,
                                       info=ck_info)
         if got_ck is not None:
             start_slab, resume_records, ck_state = got_ck
@@ -2694,41 +2897,33 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         blocks = functools.partial(_skip_retired, source, start_slab,
                                    resume_records)
     total_slabs = len(jobs) if jobs is not None else None
-    # each worker holds one slab in hand, `depth` more may wait uploaded
-    # or dispatched-unconfirmed
-    ring = depth + nwork
+    ring = fold_ring(source)
     # the consumer confirms (and hands permits back) once MORE than
-    # `depth` slabs are dispatched and unconfirmed, so a slot stays free
-    # for EVERY worker's hand (at `ring - 1` a pool of any size ran two
-    # workers, started together: PERF.md section 5, PR 35)
+    # this many slabs are dispatched and unconfirmed, so a slot stays
+    # free for EVERY worker's hand (at `ring - 1` a pool of any size ran
+    # two workers, started together: PERF.md section 5, PR 35)
     window = ring - nwork
-    run_sp = _obs.begin("stream.run", terminal=terminal, depth=depth,
+    run_sp = _obs.begin("stream.run", terminal=terminal.name, depth=depth,
                         uploaders=nwork, kind=source.kind,
                         **({"codec": codec_obj.name}
                            if codec_obj is not None else {}))
-    # thin records for ONE device go up dense, and the slab program
-    # re-seats them
-    dense = dense_route(source)
     pool = _IngestPool(run, source, ring, jobs=jobs, blocks=blocks,
-                       first=start_slab, parent=run_sp, dense=dense)
+                       first=start_slab, parent=run_sp,
+                       dense=dense_route(source))
+    win = _Window(run, pool, "slab-partial sync")
 
     from bolt_tpu.tpu.array import _place_operands
     keyed, side = stage_extras(source.stages)
     side = _place_operands(side, mesh)      # once a run, not once a slab
     t_start = _clock()
-    ingest = 0.0
-    compute = 0.0
     nslabs = 0
     nthin = 0                   # slabs that went up dense
     ngramk = 0                  # slabs a packed_gram program folded
     fold = None
     pend = None                 # even slab's partial awaiting its pair
     pend_bytes = 0              # that slab's arbiter bytes, still held
-    pending_sync = deque()      # (slabs covered, partial, bytes) not
-    #                             yet confirmed retired
-    dispatched = 0
-    confirmed = 0
-    inflight_hw = 0
+    pend_slabs = 0              # and ring permit (0 behind a partial a
+    #                             checkpoint restored: another run's slab)
     done_records = resume_records   # records covered by retired slabs
     if ck_state is not None:
         # restore the EXACT fold state the checkpoint captured: the
@@ -2739,58 +2934,27 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         lv, pend = ck_state
         sample = next((x for x in lv if x is not None), pend)
         if sample is not None:
-            fold = _make_fold(terminal, rfunc, comps, mesh, sample)
+            fold = terminal.fold(mesh, sample)
             fold.levels = list(lv)
 
-    def _confirm_oldest():
-        """Sync the OLDEST unconfirmed pair partial (normally long
-        retired, ~free) and release its ring permits + arbiter bytes.
-        On a pod the sync rides the watchdog: a partial whose
-        collective a dead peer will never complete raises the pointed
-        PeerLostError instead of hanging this survivor."""
-        nonlocal compute, confirmed
-        cov, ref, nb = pending_sync.popleft()
-        ssp = _obs.begin("stream.sync", slabs=cov)
-        t0 = _clock()
-        try:
-            _pod_sync(ref, mspec is not None, "slab-partial sync")
-        finally:
-            _obs.end(ssp)
-        compute += _clock() - t0
-        confirmed += cov
-        pool.give_back(cov, nb)
-
     def _starved():
-        """The arbiter-backed starvation valve (pool.next's ``idle``):
-        with the feeder possibly blocked on budget bytes, confirm one
-        retired window per empty poll so its bytes recycle — a budget
-        smaller than the full ring then runs a shallower pipeline
-        instead of deadlocking.  Opens ONLY under real arbiter
-        contention (some acquire is queued — this run's blocked feeder
-        always is one): a feeder merely slow on I/O must not collapse
-        the bounded in-flight window into per-slab syncs.  The lone
-        unpaired partial is drained too: once its slab program retires,
-        the donated slab input is recycled and only a value-shaped
-        partial lives, so holding its slab-sized bytes would starve the
-        feeder forever on a one-slab-at-a-time budget."""
+        """The window's valve (``pool.next``'s ``idle``), and behind it
+        the lone unpaired partial: once its program retires only a
+        value-shaped partial lives, so holding its slab-sized bytes would
+        starve the feeder forever on a one-slab-at-a-time budget."""
         nonlocal pend_bytes
-        if lease.arbiter.waiting() == 0:
-            return                  # nobody needs bytes: keep the window
-        if pending_sync:
-            _confirm_oldest()
-        elif pend is not None and pend_bytes:
-            _pod_sync(pend, mspec is not None, "unpaired-partial sync")
+        if not win.starved() and pend is not None and pend_bytes \
+                and lease.arbiter.waiting():
+            _pod_sync(pend, pod, "unpaired-partial sync")
             pool.give_back(0, pend_bytes)
             pend_bytes = 0
 
     def _fold_push(part):
-        # pair-partials fold as a PAIRWISE tree for every terminal —
-        # the moments merge included, so power-of-two slab counts keep
-        # the Chan denominators exact (level 0 is fused into the odd
-        # slab programs; this tree is level 1 and up)
+        # level 0 is fused into the odd slab programs; the pairwise
+        # tree of every terminal is level 1 and up
         nonlocal fold
         if fold is None:
-            fold = _make_fold(terminal, rfunc, comps, mesh, part)
+            fold = terminal.fold(mesh, part)
         fold.push(part)
 
     def _write_checkpoint(abort=False):
@@ -2808,20 +2972,17 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         when the abort watermark is rendezvous-consistent.  A partial
         hung on the dead peer raises PeerLostError out of the drain
         and the caller falls back to the last periodic checkpoint."""
-        while pending_sync:
-            _confirm_oldest()
+        win.retire(0)
         state = (list(fold.levels) if fold is not None else [], pend)
         csp = _obs.begin("stream.checkpoint",
                          slabs=start_slab + nslabs)
         t0 = _clock()
         try:
-            _pod_sync(state, mspec is not None, "checkpoint drain")
+            _pod_sync(state, pod, "checkpoint drain")
             nb = _ckptlib.stream_save(ck_dir, ck_fp, start_slab + nslabs,
                                       done_records, state,
-                                      multiprocess=mspec is not None,
-                                      rendezvous=not (abort
-                                                      and mspec
-                                                      is not None),
+                                      multiprocess=pod,
+                                      rendezvous=not (abort and pod),
                                       remap_from=ck_remap,
                                       codec=codec_obj.name
                                       if codec_obj is not None else None)
@@ -2831,13 +2992,24 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         finally:
             _obs.end(csp)
 
+    def _abort_checkpoint():
+        """The run is failing (uploader death, source error, peer loss,
+        a chaos-injected fault): persist the retired-slab watermark
+        FIRST, so the next run over this source resumes from here and
+        not from the last periodic checkpoint — best effort, never
+        masking the original exception.  On a POD the write skips the
+        rendezvous (peers may be dead) and lands only when the
+        watchdog-guarded drain proves every retired slab's collective
+        completed: the watermark is then rendezvous-consistent, and the
+        fold partials are replicated values any survivor resumes from."""
+        if ck_dir is not None and nslabs:
+            try:
+                _write_checkpoint(abort=True)
+            except Exception:       # noqa: BLE001 — the original
+                pass                # failure is the story
+
     pool.start()
-    if mspec is not None:
-        # the supervisor must not reform the pod UP under a live
-        # collective schedule — this counter is what its quiesce
-        # drain waits on (bolt_tpu.parallel.supervisor)
-        _podwatch.pod_enter()
-    ready_done = False
+    run.enter()
     try:
         try:
             while True:
@@ -2845,46 +3017,23 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                 else None)
                 if got is None:
                     break
-                if mspec is not None and not ready_done:
-                    # pre-collective readiness rendezvous (ISSUE 12):
-                    # confirm every peer is alive over the heartbeat
-                    # transport BEFORE the first dispatch enters the
-                    # runtime — a peer that died before dispatching
-                    # raises the pointed PeerLostError within ~2x
-                    # BOLT_POD_TIMEOUT instead of this survivor
-                    # blocking ~30s in gloo's connect
-                    _podwatch.ready_rendezvous()
-                    ready_done = True
+                run.ready()
                 slab_g, buf, slab_bytes, tsec, slab_hi = got
-                # slab_bytes: the PROCESS-LOCAL wire bytes the pool
-                # acquired for the slab, which give_back must mirror
-                ingest += tsec
+                run.ingest += tsec
                 t0 = _clock()
-                thin = dense and isinstance(buf, tuple)
-                if thin:
-                    wshape = _dense_shape(buf)
-                    nthin += 1
-                else:
-                    wshape = (buf[0].shape if isinstance(buf, tuple)
-                              else buf.shape)
+                thin, wshape = pool.form(buf)
+                nthin += thin
                 csp = _obs.begin("stream.compute",
                                  slab=slab_g,
                                  **({"codec": codec_obj.name}
                                     if codec_obj is not None else {}))
                 _chaos.hit("stream.dispatch")
-                if mspec is not None:
+                if pod:
                     # the pod collective seam: this dispatch enqueues a
                     # cross-host rendezvous on every process
                     _chaos.hit("multihost.collective")
                 try:
-                    with warnings.catch_warnings():
-                        # backends without donation (the CPU dev mesh)
-                        # warn that the donated slab buffer was unusable
-                        # — expected there, and pure noise once per slab
-                        # geometry
-                        warnings.filterwarnings(
-                            "ignore",
-                            message="Some donated buffers were not usable")
+                    with _undonated_ok():
                         try:
                             # with a codec armed the dispatch IS the
                             # fused on-device decode — surfaced on the
@@ -2897,9 +3046,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                               slab=slab_g)
                                    if codec_obj is not None else None)
                             try:
-                                # a keyed chain's program takes the
-                                # slab's first key, then the side
-                                # operands; any other chain's nothing
+                                # (the slab's first key,) + operands
                                 extra = side
                                 if keyed:
                                     extra = (np.int32(slab_hi
@@ -2908,11 +3055,9 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                 # level-0 fold with its pair fused in
                                 fused = pend is not None
                                 prog = _slab_program(
-                                    source, terminal, wshape, ddof,
-                                    rfunc, fused=fused, comps=comps,
-                                    sharded=mspec is not None,
-                                    codec_obj=codec_obj, group=group,
-                                    thin=thin, gram=gram)
+                                    source, terminal, wshape, fused=fused,
+                                    sharded=pod, codec_obj=codec_obj,
+                                    thin=thin)
                                 xsp = _obs.begin("stream.dispatch",
                                                  slab=slab_g)
                                 try:
@@ -2921,27 +3066,25 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                                             else prog(buf, *extra))
                                 finally:
                                     _obs.end(xsp)
-                                if gram is not None:
-                                    # known once the call has lowered it
-                                    ngramk += getattr(prog, "gram_kernel",
-                                                      False)
-                                if fused:
-                                    pairp = part
-                                else:
-                                    pend, pend_bytes = part, slab_bytes
-                                    pairp = None
+                                # known once the call has lowered it
+                                ngramk += getattr(prog, "gram_kernel",
+                                                  False)
                             finally:
                                 _obs.end(dsp)
-                            if pairp is not None:
+                            if fused:
                                 pend = None
-                                _fold_push(pairp)
-                                pending_sync.append(
-                                    (2, pairp, pend_bytes + slab_bytes))
-                                pend_bytes = 0
+                                _fold_push(part)
+                                win.push(pend_slabs + 1, part,
+                                         pend_bytes + slab_bytes)
+                                pend_bytes = pend_slabs = 0
+                            else:
+                                pend, pend_bytes, pend_slabs = \
+                                    part, slab_bytes, 1
+                                win.lone()
                         except _podwatch.PeerLostError:
                             raise
                         except Exception as exc:  # noqa: BLE001
-                            if mspec is None:
+                            if not pod:
                                 raise
                             # a dead peer fails the collective FAST on
                             # localhost TCP (gloo closes the socket) —
@@ -2958,24 +3101,18 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                     del buf, got           # the donated ring slot is free
                 finally:
                     _obs.end(csp)
-                compute += _clock() - t0
-                dispatched += 1
-                if dispatched - confirmed > inflight_hw:
-                    inflight_hw = dispatched - confirmed
-                # bounded in-flight window: NO per-slab sync — only once
-                # the window fills does the consumer block, and then on
-                # the OLDEST pair partial, dispatched ~window slabs ago
-                # and normally long retired (a ~free wait that releases
-                # its ring permits and arbiter bytes)
-                while dispatched - confirmed > window and pending_sync:
-                    _confirm_oldest()
+                run.compute += _clock() - t0
+                # only once the window fills does the consumer block, and
+                # then on the OLDEST pair partial, ~window slabs old
+                while win.unconfirmed > window:
+                    win.confirm_oldest()
                 # resumable(): persist the fold state every ck_every
                 # retired slabs (skipping the final slab of a known-size
                 # stream — the run is about to finish and clear anyway)
                 if ck_dir is not None and nslabs % ck_every == 0 \
                         and not (total_slabs is not None
                                  and nslabs >= total_slabs):
-                    if mspec is not None:
+                    if pod:
                         # the slab-boundary QUIESCE gate (ISSUE 12): a
                         # supervisor folding a rejoined process back in
                         # asks running pod streams to stop HERE — the
@@ -2988,7 +3125,7 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                         # standalone barrier per checkpoint
                         _podwatch.quiesce_pre(start_slab + nslabs)
                     _write_checkpoint()
-                    if mspec is not None:
+                    if pod:
                         _podwatch.quiesce_gate(start_slab + nslabs,
                                                fenced=True)
             if pend is not None:
@@ -2997,31 +3134,11 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
                 _fold_push(pend)
                 pend = None
         except BaseException:
-            # the run is failing (uploader death, source error, peer
-            # loss, a chaos-injected fault): persist the retired-slab
-            # watermark FIRST, so the next run over this source resumes
-            # from here instead of from the last periodic checkpoint —
-            # best effort, never masking the original exception.  On a
-            # POD the abort write skips the rendezvous (peers may be
-            # dead) and lands only when the watchdog-guarded drain
-            # proves every retired slab's collective completed — the
-            # abort watermark is then rendezvous-consistent by
-            # construction, and the fold partials are replicated
-            # global values any surviving process can resume from
-            # (stream_save(rendezvous=False); the PR 9 carve-out that
-            # skipped pods entirely is gone).
-            if ck_dir is not None and nslabs:
-                try:
-                    _write_checkpoint(abort=True)
-                except Exception:       # noqa: BLE001 — the original
-                    pass                # failure is the story (a drain
-                #                         hung on the dead peer falls
-                #                         back to the last periodic
-                #                         checkpoint)
+            _abort_checkpoint()
             raise
         finally:
             pool.close()
-            pending_sync.clear()
+            win.release()
 
         if fold is None:
             raise RuntimeError(
@@ -3031,99 +3148,44 @@ def execute(arr, terminal, ddof=None, rfunc=None, specs=None,
         fsp = _obs.begin("stream.fold", final=True)
         t0 = _clock()
         try:
-            if terminal in ("sum", "reduce", "gram"):
-                out = fold.result()
-            elif terminal == "multi":
-                out = _finalise_multi(fold.result(), comps, specs, mesh)
-            elif terminal == "group":
-                out = _finalise_group(fold.result(), group, source)
-            else:
-                n, mu, m2 = fold.result()
-                out = _finalise_program(terminal, mu.shape, mu.dtype,
-                                        ddof, mesh)(n, mu, m2)
+            out = terminal.finalise(fold.result(), source)
             # the ONE synchronisation point of the whole run (pod runs
             # sync through the watchdog: a tail collective hung on a
             # dead peer raises PeerLostError, never an infinite wait)
-            _pod_sync(out, mspec is not None, "final result sync")
+            _pod_sync(out, pod, "final result sync")
         except BaseException:
-            # same abort-watermark contract as the main loop: the fold
-            # state covers every retired slab, so a failure here still
-            # leaves the best possible resume point
-            if ck_dir is not None and nslabs:
-                try:
-                    _write_checkpoint(abort=True)
-                except Exception:       # noqa: BLE001
-                    pass
+            # the fold state covers every retired slab, so a failure
+            # here still leaves the best possible resume point
+            _abort_checkpoint()
             raise
         finally:
             _obs.end(fsp)
         if ck_dir is not None:
             # success: a finished run leaves NO stale checkpoint behind
-            _ckptlib.stream_clear(ck_dir, multiprocess=mspec is not None)
-        compute += _clock() - t0
+            _ckptlib.stream_clear(ck_dir, multiprocess=pod)
+        run.compute += _clock() - t0
         wall = _clock() - t_start
-        overlap = max(0.0, ingest + compute - wall)
-        _engine.record_stream(nslabs, ingest, compute, wall, overlap,
-                              depth, uploaders=max(pool.high_water, 1),
-                              inflight=max(inflight_hw, 1),
-                              keyed=nslabs if keyed else 0,
-                              group=nslabs if group is not None else 0,
-                              thin=nthin,
-                              gram=nslabs if gram is not None else 0,
-                              gram_kernel=ngramk)
+        overlap = max(0.0, run.ingest + run.compute - wall)
+        _engine.record_stream(nslabs, run.ingest, run.compute, wall,
+                              overlap, depth,
+                              uploaders=max(pool.high_water, 1),
+                              inflight=max(win.high_water, 1),
+                              keyed=nslabs if keyed else 0, thin=nthin,
+                              **terminal.tally(nslabs, ngramk))
         if result_state(source).pred is not None:
             # a filter that ended in this terminal: no buffer was built
             # for it, as for a resident deferred one
             _engine.record_filter_fused()
         if run_sp is not None:
-            run_sp.set(slabs=nslabs, ingest_s=round(ingest, 6),
-                       compute_s=round(compute, 6),
+            run_sp.set(slabs=nslabs, ingest_s=round(run.ingest, 6),
+                       compute_s=round(run.compute, 6),
                        overlap_s=round(overlap, 6),
                        concurrent_uploaders=max(pool.high_water, 1),
-                       inflight_high_water=max(inflight_hw, 1))
-        if terminal in ("multi", "gram"):
-            return list(out)              # one jax array per member spec
-        if terminal == "group":
-            wrap = lambda o: BoltArrayTPU(o, 1, mesh)      # noqa: E731
-            return jax.tree_util.tree_map(wrap, out[0]), wrap(out[1])
-        return BoltArrayTPU(out, 0, mesh)
+                       inflight_high_water=max(win.high_water, 1))
+        return terminal.wrap(out, mesh)
     finally:
-        if mspec is not None:
-            _podwatch.pod_exit()
-        if lease is not None:
-            lease.close()       # return every outstanding budget byte
+        run.leave()
         _obs.end(run_sp)
-
-
-def _finalise_multi(folded, comps, specs, mesh):
-    """Per-member outputs from the folded component tuple: each member
-    finalises from the SHARED components exactly as its standalone
-    streamed terminal would (``_finalise_program`` for the moment
-    family, identity for sum/min/max, the fused max−min subtraction for
-    ``ptp``)."""
-    by = dict(zip(comps, folded))
-
-    def _sub(a, b):
-        # the SAME cached max−min program the in-memory fused groups
-        # use (one "multi-stat-sub" key per geometry, both paths)
-        from bolt_tpu.tpu.multistat import _sub_program
-        return _sub_program(a.shape, a.dtype, mesh)(a, b)
-
-    outs = []
-    for name, ddof_m in specs:
-        if name == "sum":
-            outs.append(by["sum"])
-        elif name == "min":
-            outs.append(by["min"])
-        elif name == "max":
-            outs.append(by["max"])
-        elif name == "ptp":
-            outs.append(_sub(by["max"], by["min"]))
-        else:
-            n, mu, m2 = by["moments"]
-            outs.append(_finalise_program(name, mu.shape, mu.dtype,
-                                          ddof_m, mesh)(n, mu, m2))
-    return outs
 
 
 # ---------------------------------------------------------------------
@@ -3474,7 +3536,7 @@ def _resolve_one_swap(source, collect=False, project=False):
 
     # the codec is lossless or None (gated when the swap was recorded)
     run = _Run(base)
-    pod = run.mspec is not None
+    pod = run.pod
     if pod and not plan.resident:
         # pod spill is refused, not attempted: phase 1 spills each
         # bucket whole on the one process that owns its rows, but
@@ -3521,11 +3583,11 @@ def _resolve_one_swap(source, collect=False, project=False):
                         devices=plan.devices)
     # thin records for ONE device go up dense and the place program
     # re-seats them, as execute's slab programs do (the spill leg's
-    # program takes the slab as the loader hands it over)
-    dense = plan.resident and dense_route(base)
-    # a one-shot iterable cannot resume, so `done` is empty without jobs
+    # program takes the slab as the loader hands it over); a one-shot
+    # iterable cannot resume, so `done` is empty without jobs
     pool = _IngestPool(run, base, plan.ring, jobs=jobs,
-                       noun="shuffle slab", parent=run_sp, dense=dense)
+                       noun="shuffle slab", parent=run_sp,
+                       dense=plan.resident and dense_route(base))
 
     def _spill_part(part, g):
         """Extract and persist every LOCALLY-OWNED bucket of slab
@@ -3556,24 +3618,33 @@ def _resolve_one_swap(source, collect=False, project=False):
                     attempt += 1
         _ckptlib.spill_slab_done(spill_dir, fp, g)
 
+    def _unrepeatable(g, exc):
+        """A failure at a call's confirm is final: the slab the call was
+        handed is donated, and so is the array it wrote into, to the
+        calls behind it, so nothing is left to dispatch again."""
+        return RuntimeError(
+            "shuffle slab %d failed at the confirm of its call "
+            "(%s: %s); what the call was handed is donated (the "
+            "slab, and the array the calls dispatched behind it "
+            "write into), so it cannot be retried in place and the "
+            "run ends here" % (g, type(exc).__name__, exc))
+
     t_start = _clock()
     moved = 0
     placed = 0
     nthin = 0                   # slabs that went up dense
-    ingest = compute = 0.0
     out = cursor = None
-    # place calls dispatched and not yet confirmed, oldest first: (slab,
-    # handle, the slab's lease bytes).  The resident leg keeps up to
-    # `window` of them, what the ring holds beyond a slab in every
-    # worker's hand, as execute does: call g's `out` is donated into
-    # call g + 1, so what it is confirmed by is the cursor it returned,
-    # a fresh scalar ready when the call is done, and nothing else of
-    # the slab is held.  The spill leg reads its part on the host, one
-    # block a slab.
-    inflight = deque()
+    # the resident leg keeps up to `window` place calls unconfirmed,
+    # what the ring holds beyond a slab in every worker's hand: call g's
+    # `out` is donated into call g + 1, so what confirms it is the
+    # cursor it returned, a fresh scalar ready when the call is done.
+    # The spill leg reads its part on the host, one block a slab,
+    # between its confirm and the give-back.
+    win = _Window(run, pool, "shuffle re-bucket", attrs={"shuffle": True},
+                  failed=_unrepeatable,
+                  settle=None if plan.resident else _spill_part)
     window = max(1, plan.ring - run.nwork) if plan.resident else 1
     windowed = 0                # calls dispatched behind an unconfirmed one
-    inflight_hw = 1
     if plan.resident:
         # phase 2 in place: the swapped array exists ONCE, from here
         # on; every slab's program is handed it (donated) and hands it
@@ -3583,79 +3654,21 @@ def _resolve_one_swap(source, collect=False, project=False):
         out, cursor = _shuffle.alloc_program(plan, mesh)()
         unit = base.slab if base.kind == "callback" else 1
 
-    def _confirm_oldest():
-        """Block for the OLDEST unconfirmed call (the spill leg: and
-        persist its part), then hand its ring permit and lease bytes
-        back.  A failure here is final: the slab the call was handed is
-        donated, and so is the array it wrote into, to the calls behind
-        it, so nothing is left to dispatch again.  The entry leaves the
-        window only once it is confirmed: what a failure leaves there
-        the run's way out gives back."""
-        nonlocal compute
-        g, handle, nb = inflight[0]
-        t0 = _clock()
-        ssp = _obs.begin("stream.sync", slabs=1, shuffle=True, slab=g)
-        try:
-            _pod_sync(handle, pod, "shuffle re-bucket", slab=g)
-        except _podwatch.PeerLostError:
-            raise
-        except Exception as exc:  # noqa: BLE001
-            raise RuntimeError(
-                "shuffle slab %d failed at the confirm of its call "
-                "(%s: %s); what the call was handed is donated (the "
-                "slab, and the array the calls dispatched behind it "
-                "write into), so it cannot be retried in place and the "
-                "run ends here" % (g, type(exc).__name__, exc)) from exc
-        finally:
-            _obs.end(ssp)
-        if not plan.resident:
-            _spill_part(handle, g)
-        inflight.popleft()
-        del handle              # a spilled part goes before its permit
-        compute += _clock() - t0
-        pool.give_back(1, nb)
-
-    def _retire(keep):
-        """Confirm the oldest calls, blocking, until at most ``keep``
-        stay unconfirmed, and WITHOUT blocking every head of the window
-        that is done already: its permit goes back when the device lets
-        go of the slab, not a slab later."""
-        while inflight and (len(inflight) > keep
-                            or _retired(inflight[0][1])):
-            _confirm_oldest()
-
-    def _starved():
-        """``execute``'s valve (``pool.next``'s ``idle``): with the
-        feeder possibly blocked on budget bytes, confirm one call per
-        empty poll so its bytes recycle, and a budget smaller than the
-        full ring runs a shallower window instead of deadlocking."""
-        if inflight and run.lease.arbiter.waiting():
-            _confirm_oldest()
-
     pool.start()
-    if pod:
-        _podwatch.pod_enter()
-    ready_done = False
+    run.enter()
     try:
         while True:
-            _retire(window)
-            got = pool.next(idle=_starved if run.lease is not None
+            win.retire(window)
+            got = pool.next(idle=win.starved if run.lease is not None
                             else None)
             if got is None:
                 break
-            if pod and not ready_done:
-                _podwatch.ready_rendezvous()
-                ready_done = True
+            run.ready()
             g, buf, bnb, tsec, _ = got
-            ingest += tsec
+            run.ingest += tsec
             t0 = _clock()
-            thin = dense and isinstance(buf, tuple)
+            thin, wshape = pool.form(buf)
             nthin += thin
-            if thin:
-                wshape = _dense_shape(buf)
-            else:
-                wshape = (buf[0].shape if isinstance(buf, tuple)
-                          else buf.shape)
             csp = _obs.begin("stream.compute", slab=g, shuffle=True)
             attempt = 0
             prev = None
@@ -3676,15 +3689,7 @@ def _resolve_one_swap(source, collect=False, project=False):
                             prog = _shuffle.rebucket_program(
                                 plan, pre, mesh, run.codec, source.dtype,
                                 wshape, run.delta_ok)
-                        with warnings.catch_warnings():
-                            # the uploaded slab is donated but never
-                            # aliased (no output has its shape; CPU dev
-                            # meshes have no donation at all): the
-                            # per-slab "donated buffers were not
-                            # usable" warning is expected noise
-                            warnings.filterwarnings(
-                                "ignore", message="Some donated "
-                                "buffers were not usable")
+                        with _undonated_ok():
                             xsp = _obs.begin("stream.dispatch", slab=g)
                             try:
                                 if plan.resident:
@@ -3714,26 +3719,18 @@ def _resolve_one_swap(source, collect=False, project=False):
                 del buf, got
                 moved += wshape[0] * (plan.total_bytes // plan.in_shape[0])
                 placed += 1
-                windowed += bool(inflight)
-                inflight.append((g, handle, bnb))
+                windowed += bool(win.unconfirmed)
+                win.push(1, handle, bnb, slab=g)
                 del handle
-                inflight_hw = max(inflight_hw, len(inflight))
-                compute += _clock() - t0
-                _retire(window - 1)
+                run.compute += _clock() - t0
+                win.retire(window - 1)
             finally:
                 _obs.end(csp)
-        _retire(0)              # the drain, in slab order
+        win.retire(0)           # the drain, in slab order
     finally:
         pool.close()
-        for _, _, nb in inflight:
-            # a run that ended early: the window's permits and lease
-            # bytes go back on the way out
-            pool.give_back(1, nb)
-        inflight.clear()
-        if pod:
-            _podwatch.pod_exit()
-        if run.lease is not None:
-            run.lease.close()
+        win.release()
+        run.leave()
         wall = _clock() - t_start
         # what crossed devices, by the planner's model, for the bytes
         # this run moved (a resumed spill skips slabs)
@@ -3748,10 +3745,11 @@ def _resolve_one_swap(source, collect=False, project=False):
         _obs.end(run_sp)
     # phase 1 completed: one streamed run, under the counters every
     # streamed run reports (a spilled swap's phase 2 adds its own)
-    _engine.record_stream(placed, ingest, compute, wall,
-                          max(0.0, ingest + compute - wall), run.depth,
-                          uploaders=max(pool.high_water, 1),
-                          inflight=inflight_hw, windowed=windowed,
+    _engine.record_stream(placed, run.ingest, run.compute, wall,
+                          max(0.0, run.ingest + run.compute - wall),
+                          run.depth, uploaders=max(pool.high_water, 1),
+                          inflight=max(win.high_water, 1),
+                          windowed=windowed,
                           keyed=placed if keyed else 0, thin=nthin)
 
     if plan.resident:

@@ -65,15 +65,7 @@ multi-process shards encode locally so DCN/gloo bytes shrink too
 serving arbiter leases the COMPRESSED slab bytes (admission floors
 recompute via :meth:`Codec.ratio`), and ``analysis.check`` forecasts
 the bytes saved as the BLT016 diagnostic.
-
-Where Pallas is available, an opt-in fused decode-and-reduce kernel
-(``bolt_tpu.ops.kernels.fused_decode_sum``, armed by
-``BOLT_CODEC_KERNEL=1``) keeps the int8 decode in-register on the way
-into a streamed ``sum`` — parity-locked against the XLA decode path
-like every other kernel in that module.
 """
-
-import os
 
 import numpy as np
 
@@ -363,18 +355,3 @@ register(_CastCodec("f16", _np_f16))
 register(_Int8Codec())
 register(_DeltaF32Codec())
 register(_DictCodec())
-
-
-# ---------------------------------------------------------------------
-# the opt-in Pallas decode-and-reduce door (ops/kernels.py)
-# ---------------------------------------------------------------------
-
-def kernel_enabled():
-    """True when the fused Pallas decode-and-reduce kernel is armed
-    (``BOLT_CODEC_KERNEL=1``): a streamed int8 ``sum`` with no stages
-    then decodes in-register inside
-    ``bolt_tpu.ops.kernels.fused_decode_sum`` instead of the XLA
-    decode+reduce — parity-locked, geometry-gated (the kernel returns
-    None off-plan and the XLA path serves)."""
-    return os.environ.get("BOLT_CODEC_KERNEL", "0").lower() in ("1",
-                                                                "true")

@@ -46,7 +46,7 @@ int32 accumulator (accumulate-in-i32), integer additive terminals
 pair behind ptp) are always exact.
 
 Streamed groups fold a tuple accumulator through the PR 5 pipeline
-(``stream.execute(terminal="multi")``): one ingest pass feeds every
+(``stream.execute`` of a ``stream._Multi``): one ingest pass feeds every
 member, the shared ``(n, mu, M2)`` moments triple serves all of
 mean/var/std, and Chan denominators stay exact on power-of-two slab
 counts — streamed multi-stat matches materialised bit-exactly there.
@@ -149,7 +149,7 @@ class _StatGroup:
     * ``"fpending"`` — a deferred filter: mapped chain + predicate mask
       traced once, every member folds the same mask.
     * ``"stream"``  — a lazy out-of-core source: one ingest pass through
-      ``stream.execute(terminal="multi")`` feeds a tuple accumulator.
+      ``stream.execute`` of a ``stream._Multi`` feeds a tuple accumulator.
     """
 
     __slots__ = ("kind", "mesh", "split", "base", "funcs", "fpending",
@@ -414,16 +414,17 @@ class _StatGroup:
     def _resolve_stream(self):
         members = self.members
         if (len(members) == 1
-                and members[0].name in ("sum", "mean", "var", "std")):
+                and members[0].name in _streamlib._STAT_NAMES):
             # standalone resolution: the exact pre-fusion streamed
             # terminal (same slab/merge/finalise programs and keys)
             m = members[0]
-            out = _streamlib.execute(None, m.name, ddof=m.ddof,
-                                     source=self.source)
+            out = _streamlib.execute(
+                None, _streamlib.stat_terminal(m.name, m.ddof),
+                source=self.source)
             m.result = out.tojax()
             return
         specs = tuple((m.name, m.ddof) for m in members)
-        outs = _streamlib.execute(None, "multi", specs=specs,
+        outs = _streamlib.execute(None, _streamlib._Multi(specs),
                                   source=self.source)
         if len(members) > 1:
             _engine.record_fused_stats(len(members))

@@ -534,50 +534,6 @@ def test_codec_change_restarts_instead_of_resuming(mesh):
 
 
 # ---------------------------------------------------------------------
-# the opt-in Pallas decode-and-reduce kernel
-# ---------------------------------------------------------------------
-
-def test_fused_decode_sum_parity_locked():
-    from bolt_tpu.ops.kernels import fused_decode_sum
-    q = np.random.RandomState(8).randint(0, 256, size=(16, 8, 128),
-                                         dtype=np.uint8)
-    out = fused_decode_sum(jnp.asarray(q), 0.031, -2.25, interpret=True)
-    assert out is not None
-    ref = np.sum(q.astype(np.float32) * np.float32(0.031)
-                 + np.float32(-2.25), axis=0)
-    assert np.allclose(np.asarray(out), ref, rtol=1e-6, atol=1e-4)
-
-
-def test_fused_decode_sum_declines_off_plan():
-    from bolt_tpu.ops.kernels import fused_decode_sum
-    # unaligned minor dim / wrong dtype / rank-1: the XLA path serves
-    assert fused_decode_sum(jnp.zeros((16, 100), jnp.uint8),
-                            1.0, 0.0) is None
-    assert fused_decode_sum(jnp.zeros((16, 128), jnp.float32),
-                            1.0, 0.0) is None
-    assert fused_decode_sum(jnp.zeros((128,), jnp.uint8),
-                            1.0, 0.0) is None
-
-
-def test_kernel_path_parity_end_to_end(monkeypatch):
-    # ONE device: GSPMD cannot partition a Mosaic kernel, so the door
-    # stays shut on a multi-device mesh (the XLA decode serves there)
-    import jax
-    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("k",))
-    x = (np.random.RandomState(9).rand(32, 256) * 10).astype(np.float32)
-    off = np.asarray(_src(x, mesh, chunks=8,
-                          codec="int8").sum().toarray())
-    monkeypatch.setenv("BOLT_CODEC_KERNEL", "1")
-    assert codeclib.kernel_enabled()
-    on = np.asarray(_src(x, mesh, chunks=8,
-                         codec="int8").sum().toarray())
-    assert np.allclose(on, off, rtol=1e-5, atol=1e-3)
-    assert any(k[0].startswith("stream-slab") and k[-1] is True
-               and k[10] == mesh for k in engine._CACHE
-               if isinstance(k, tuple))          # the kernel program ran
-
-
-# ---------------------------------------------------------------------
 # analysis: BLT016
 # ---------------------------------------------------------------------
 

@@ -227,12 +227,6 @@ _MOSAIC_CASES = [
      [((64, 64, 128), _F32)]),
     ("welford-bf16", lambda x: kernels.fused_welford(x, interpret=False),
      [((64, 64, 128), jnp.bfloat16)]),
-    ("decode_sum-uint8", lambda q, a, z: kernels.fused_decode_sum(
-        q, a, z, interpret=False),
-     [((64, 64, 128), jnp.uint8), ((), _F32), ((), _F32)]),
-    ("decode_sum-int8", lambda q, a, z: kernels.fused_decode_sum(
-        q, a, z, interpret=False),
-     [((64, 64, 128), jnp.int8), ((), _F32), ((), _F32)]),
     ("lane_band", lambda x: kernels.lane_band_pallas(
         x, _BOX25, interpret=False), [((16, 64, 256), _F32)]),
     ("lane_band-one-tile", lambda x: kernels.lane_band_pallas(
@@ -1226,8 +1220,8 @@ _SF100 = (600037902, 7)
 
 
 def _streamed_lineitem(v5e_device, query):
-    """``(source, terminal, kwargs)`` of one query over the streamed table,
-    as the public calls hand them to the executor."""
+    """``(source, terminal)`` of one query over the streamed table, as the
+    public calls hand them to the executor."""
     from bolt_tpu import stream
     from bolt_tpu.tpu.array import BoltArrayTPU
     mesh = _series_mesh(v5e_device)
@@ -1240,11 +1234,9 @@ def _streamed_lineitem(v5e_device, query):
     if query == "q6":
         out = b.filter(_q6_pred).map(_q6_value)
         assert out.streaming                      # nothing was uploaded
-        return out._stream, "sum", {}
-    group = ("sum", _q1_group, _q1_terms, 6)
+        return out._stream, stream._Sum()
     source = b.filter(_q1_pred)._stream
-    return source, "group", {"group": group,
-                             "comps": stream._group_comps(source, group)}
+    return source, stream._Group(("sum", _q1_group, _q1_terms, 6), source)
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["first", "acc-fused"])
@@ -1257,7 +1249,7 @@ def test_a_thin_slab_is_reseated_and_folded_in_place_on_v5e(v5e_device,
     import warnings
     import jax
     from bolt_tpu import stream
-    source, terminal, kw = _streamed_lineitem(v5e_device, query)
+    source, terminal = _streamed_lineitem(v5e_device, query)
     mesh = source.mesh
     where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     groups, rest = divmod(rows, 128)
@@ -1267,16 +1259,16 @@ def test_a_thin_slab_is_reseated_and_folded_in_place_on_v5e(v5e_device,
     with jax.enable_x64(False), warnings.catch_warnings():
         warnings.filterwarnings("ignore",
                                 message="Some donated buffers were not")
-        first = stream._slab_program(source, terminal, (rows, 7), None, None,
-                                     thin=True, **kw).lower(slab)
+        first = stream._slab_program(source, terminal, (rows, 7),
+                                     thin=True).lower(slab)
         if fused:
             acc = jax.tree_util.tree_map(
                 lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype,
                                                sharding=where),
                 first.out_info)
             compiled = stream._slab_program(
-                source, terminal, (rows, 7), None, None, fused=True,
-                thin=True, **kw).lower(slab, acc).compile()
+                source, terminal, (rows, 7), fused=True,
+                thin=True).lower(slab, acc).compile()
         else:
             compiled = first.compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
@@ -2037,24 +2029,23 @@ def test_a_plane_is_one_packed_gram_over_a_bitcast_on_v5e(v5e_device,
     from bolt_tpu import engine, stream
     from bolt_tpu.ops import linalg
     src, mesh = _streamed_recording(v5e_device)
-    gram = (2, "highest", False, center)
-    kw = {"gram": gram, "comps": ("sum",) * (1 + center), "thin": True}
+    gram = stream._Gram((2, "highest", False, center))
     where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
     slab = (jax.ShapeDtypeStruct(_DENSE_PLANE, np.float32, sharding=where),)
     placed = engine.gram_kernel_lowerings()
     with jax.enable_x64(False), warnings.catch_warnings():
         warnings.filterwarnings("ignore",
                                 message="Some donated buffers were not")
-        first = stream._slab_program(src, "gram", _PLANE, None, None,
-                                     **kw).lower(slab)
+        first = stream._slab_program(src, gram, _PLANE,
+                                     thin=True).lower(slab)
         if fused:
             acc = jax.tree_util.tree_map(
                 lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype,
                                                sharding=where),
                 first.out_info)
             compiled = stream._slab_program(
-                src, "gram", _PLANE, None, None, fused=True,
-                **kw).lower(slab, acc).compile()
+                src, gram, _PLANE, fused=True,
+                thin=True).lower(slab, acc).compile()
         else:
             compiled = first.compile()
     # the lowering said so, which is what stream_gram_kernel_slabs counts

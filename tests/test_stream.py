@@ -1177,6 +1177,74 @@ def test_stream_inflight_window_bounds_and_records(mesh):
 # chunked-view terminals on MATERIALISED arrays (delegation parity)
 # ---------------------------------------------------------------------
 
+# ---------------------------------------------------------------------
+# a streamed terminal is a VALUE (ISSUE 57): it rides in the engine's
+# program keys, so two calls of one door must build equal terminals
+# ---------------------------------------------------------------------
+
+def _glabel(v):
+    return (v[0, 0] > 0).astype(np.int32)
+
+
+def _several(b):
+    return [o.toarray() for o in bolt.compute(b.sum(), b.var(), b.ptp())]
+
+
+# kind -> (a door call on a streamed array, what the terminal it hands
+# the executor must be)
+_TERMINAL_DOORS = {
+    "sum": (lambda b: b.sum().toarray(), stream._Sum()),
+    "mean": (lambda b: b.mean().toarray(), stream._Moments("mean")),
+    "var-ddof": (lambda b: b.var(ddof=1).toarray(),
+                 stream._Moments("var", 1)),
+    "std": (lambda b: b.std().toarray(), stream._Moments("std", 0)),
+    "min": (lambda b: b.min().toarray(), stream._Multi((("min", None),))),
+    "max": (lambda b: b.max().toarray(), stream._Multi((("max", None),))),
+    # what the array layer hands over for ``func`` is its own to say
+    "reduce": (lambda b: b.reduce(np.maximum).toarray(), None),
+    "multi": (_several, stream._Multi(
+        (("sum", None), ("var", 0), ("ptp", None)))),
+    "group": (lambda b: bolt.ops.segment_reduce(b, _glabel, 2, op="mean"),
+              None),
+    "gram": (lambda b: bolt.ops.cov(b), stream._Gram(
+        (1, "highest", True, True))),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(_TERMINAL_DOORS))
+def test_two_door_calls_build_one_terminal_and_one_program(
+        mesh, kind, monkeypatch):
+    door, want = _TERMINAL_DOORS[kind]
+    seen = []
+    execute = stream.execute
+
+    def spy(arr, terminal, source=None):
+        seen.append(terminal)
+        return execute(arr, terminal, source)
+    monkeypatch.setattr(stream, "execute", spy)
+    data = _intdata()
+    door(_source(data, mesh, 4).map(ADD1))
+    c0 = engine.counters()
+    door(_source(data, mesh, 4).map(ADD1))
+    c1 = engine.counters()
+    first, second = seen
+    assert first is not second and first == second
+    assert hash(first) == hash(second) and first.key == second.key
+    assert len({first, second}) == 1
+    if want is not None:
+        assert first == want and want.key == first.key
+    assert first.name == kind.split("-")[0] or type(first) is stream._Multi
+    # the second run found every program the first built
+    assert c1["misses"] - c0["misses"] == 0
+    assert c1["aot_compiles"] - c0["aot_compiles"] == 0
+    assert c1["stream_chunks"] - c0["stream_chunks"] == 4
+    with pytest.raises(AttributeError, match="is a value"):
+        first.ddof = 3
+    others = [t for k, (_, t) in _TERMINAL_DOORS.items()
+              if k != kind and t is not None]
+    assert first not in others
+
+
 def test_chunked_terminals_materialised(mesh):
     data = _intdata()
     cv = bolt.array(data, mesh).chunk(size=(3,), axis=(0,))

@@ -384,11 +384,29 @@ PARENT_TEXT = {
         "9a55e4616e9d80d5ffe970510f522edec61948da5e20e6b0aef1d487fd84c33d",
     "place":
         "bb196d1d6937276501d69cbef96bfea086cc58b3a6bc9632bc46481dac8ae006",
+    # the three tupled terminals over the same source on four devices,
+    # read at 06c96b3 before ISSUE 57 touched the file
+    "slab-multi":
+        "8b292d12dc2406203a230de3ef43f2d2570cb55eea32a50255ca20bc27e9fc54",
+    "slab-multi-fused":
+        "78e5cf4c2074214d8ba55f73f14f4d2736bafc9bf05c76449a4b9039beea0260",
+    "slab-group":
+        "877d745a636eee5a491ccd68fa1c7adb93edadd5bc15878a973826ce017b46a5",
+    "slab-group-fused":
+        "c0aa843fe9a894294a9c64404833459a7dc722409b46960ddd45bba50eafef0f",
+    "slab-gram":
+        "743326962e033467beae7e24253a0424b6728975ddb8ccbdc1906cdf0c18d9b2",
+    "slab-gram-fused":
+        "7dbd86b2771c752d0b526464c31cf60f633cefe3bc3a099fea2c39d4f713176a",
 }
 
 
 def plus_one(v):
     return v + 1
+
+
+def _glabel(r):
+    return (r[0, 0] > 1).astype(np.int32) + (r[1, 1] > 1).astype(np.int32)
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0",
@@ -403,11 +421,22 @@ def test_a_source_with_no_keyed_stage_lowers_as_it_did(mesh4):
     acc = jax.ShapeDtypeStruct((4, 6), np.float32)
     texts = {
         "slab-sum": stream._slab_program(
-            src, "sum", (8, 4, 6), None, None).lower(slab).as_text(),
+            src, stream._Sum(), (8, 4, 6)).lower(slab).as_text(),
         "slab-sum-fused": stream._slab_program(
-            src, "sum", (8, 4, 6), None, None, fused=True).lower(
+            src, stream._Sum(), (8, 4, 6), fused=True).lower(
                 slab, acc).as_text(),
     }
+    for name, terminal in (
+            ("slab-multi", stream._Multi((("sum", None), ("std", 1),
+                                          ("min", None), ("max", None)))),
+            ("slab-group", stream._Group(("sum", _glabel, None, 3), src)),
+            ("slab-gram", stream._Gram((1, "highest", False, True)))):
+        first = stream._slab_program(src, terminal, (8, 4, 6)).lower(slab)
+        part = jax.tree_util.tree_map(
+            lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype), first.out_info)
+        texts[name] = first.as_text()
+        texts[name + "-fused"] = stream._slab_program(
+            src, terminal, (8, 4, 6), fused=True).lower(slab, part).as_text()
     sw = callback(x, mesh4, 8).map(plus_one).swap((0,), (0,))._stream
     _, perm, new_split = sw.stages[1]
     plan = shuffle.plan_shuffle((48, 4, 6), np.float32, 1, perm, new_split,

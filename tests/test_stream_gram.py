@@ -224,10 +224,11 @@ def test_the_slab_partials_add_up_to_the_whole(mesh, layout, d, sums):
     shape, axes, chunks, nslabs = _layout(layout, d)
     x = _integers(shape)
     m = len(axes)
-    gram = (m, "highest", False, sums)
+    gram = stream._Gram((m, "highest", False, sums))
     flat = x.astype(np.float64).reshape(-1, d)
     whole = [flat.T @ flat] + [flat.sum(axis=0)] * sums
-    parts = [stream._gram_partial(gram, jnp.asarray(x[lo:lo + chunks]))
+    parts = [gram.partial(jnp.asarray(x[lo:lo + chunks]), (), None, (), 1,
+                          None, None)
              for lo in range(0, shape[0], chunks)]
     assert len(parts) == nslabs and all(len(p) == 1 + sums for p in parts)
     for comp, want in enumerate(whole):
@@ -434,8 +435,7 @@ def test_a_killed_gram_pass_resumes_from_its_checkpoint(mesh, tmp_path):
     assert asked.sum() < 1200 and not checkpoint.stream_pending(ck)
     one = stream.StreamSource.from_callback(lambda i: x[i], x.shape, 1,
                                             x.dtype, mesh, chunks=150)
-    prints = {stream._run_fingerprint(one, "gram", None, None, None,
-                                      gram=g)
+    prints = {stream._run_fingerprint(one, stream._Gram(g))
               for g in ((1, "highest", True, True),
                         (1, "highest", True, False),
                         (1, "highest", False, True),

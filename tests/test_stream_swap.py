@@ -786,6 +786,163 @@ def test_a_serving_budget_under_the_ring_runs_a_shallower_window(
     assert np.array_equal(got, np.transpose(data, (1, 0, 2)))
 
 
+# the window itself (ISSUE 57: one ``stream._Window`` for ``execute`` and
+# the resolver alike), on a pool, a lease and handles that only record
+
+class _Recorder:
+    """A pool that records what came back, a run whose lease's arbiter
+    has ``waiters`` acquires queued, and the order of everything."""
+
+    pod = False
+    compute = 0.0
+
+    def __init__(self, monkeypatch, waiters=0, fail_at=None):
+        self.log = []
+        self.lease = self.arbiter = self
+        self.waiting = lambda: waiters
+
+        def sync(handle, pod, phase, slab=None):
+            self.log.append(("sync", handle, phase, slab))
+            if handle == fail_at:
+                raise OSError("device gone")
+        monkeypatch.setattr(stream, "_pod_sync", sync)
+        # a handle is done when it says so: "done-*"
+        monkeypatch.setattr(stream, "_retired",
+                            lambda handle: handle.startswith("done"))
+
+    def give_back(self, slabs, nbytes):
+        self.log.append(("back", slabs, nbytes))
+
+    def window(self, **kw):
+        return stream._Window(self, self, "a phase", **kw)
+
+    def backs(self):
+        return [e[1:] for e in self.log if e[0] == "back"]
+
+    def syncs(self):
+        return [e[1] for e in self.log if e[0] == "sync"]
+
+
+def _confirms_in_dispatch_order(rec):
+    win = rec.window(attrs={"shuffle": True})
+    for g in range(3):
+        win.push(1, "call-%d" % g, 100 + g, slab=g)
+    assert (win.unconfirmed, win.high_water) == (3, 3)
+    obs.clear()
+    obs.enable()
+    try:
+        while win.unconfirmed:
+            win.confirm_oldest()
+        spans = [sp.attrs for sp in obs.spans() if sp.name == "stream.sync"]
+    finally:
+        obs.disable()
+        obs.clear()
+    assert rec.log == [e for g in range(3) for e in (
+        ("sync", "call-%d" % g, "a phase", g), ("back", 1, 100 + g))]
+    assert spans == [{"slabs": 1, "shuffle": True, "slab": g}
+                     for g in range(3)]
+    assert win.high_water == 3 and rec.compute >= 0.0
+
+
+def _retire_lets_a_done_head_go(rec):
+    win = rec.window()
+    for name in ("done-0", "done-1", "call-2", "done-3"):
+        win.push(1, name, 8)
+    win.retire(4)               # nothing over: only what is done, in order
+    assert rec.syncs() == ["done-0", "done-1"] and win.unconfirmed == 2
+
+
+def _retire_blocks_for_a_head_over_keep(rec):
+    win = rec.window()
+    for g in range(4):
+        win.push(1, "call-%d" % g, 8)
+    win.retire(1)
+    assert rec.syncs() == ["call-0", "call-1", "call-2"]
+    win.retire(1)               # at keep, head not done: nothing
+    assert win.unconfirmed == 1 and len(rec.syncs()) == 3
+    win.retire(0)
+    assert win.unconfirmed == 0 and rec.backs() == [(1, 8)] * 4
+
+
+def _starved_needs_a_waiter(rec):
+    win = rec.window()
+    assert win.starved() is False           # nothing to confirm
+    win.push(1, "call-0", 8)
+    win.push(1, "call-1", 8)
+    if rec.waiting():
+        assert win.starved() is True and rec.syncs() == ["call-0"]
+        assert win.unconfirmed == 1         # ONE call an empty poll
+    else:
+        assert win.starved() is False and rec.log == []
+        assert win.unconfirmed == 2         # a slow feeder keeps the window
+
+
+def _release_after_a_failure(rec):
+    win = rec.window(failed=lambda slab, exc: RuntimeError(
+        "slab %d: %s" % (slab, exc)))
+    for g in range(3):
+        win.push(1, "call-%d" % g, 10 * (g + 1), slab=g)
+    win.confirm_oldest()
+    with pytest.raises(RuntimeError, match="slab 1: device gone") as err:
+        win.confirm_oldest()
+    assert isinstance(err.value.__cause__, OSError)
+    # the failed head stays counted, and holds its permit and bytes
+    assert win.unconfirmed == 2 and rec.backs() == [(1, 10)]
+    win.release()
+    win.release()                           # once
+    assert rec.backs() == [(1, 10), (1, 20), (1, 30)]
+    assert win.unconfirmed == 0 and rec.syncs() == ["call-0", "call-1"]
+
+
+def _the_spill_hook_runs_between(rec):
+    win = rec.window(settle=lambda handle, slab: rec.log.append(
+        ("settle", handle, slab)))
+    win.push(1, "part-0", 64, slab=5)
+    win.confirm_oldest()
+    assert [e[0] for e in rec.log] == ["sync", "settle", "back"]
+    assert rec.log[1] == ("settle", "part-0", 5)
+
+
+def _a_cover_of_two_moves_it_by_two(rec):
+    win = rec.window()
+    win.push(2, "pair-0", 200)
+    assert win.unconfirmed == 2
+    # execute's even slab counts from its own dispatch, its pair's push
+    # covers both
+    win.lone()
+    assert (win.unconfirmed, win.high_water) == (3, 3)
+    win.push(2, "pair-1", 300)
+    assert (win.unconfirmed, win.high_water) == (4, 4)
+    win.confirm_oldest()
+    assert win.unconfirmed == 2 and rec.backs() == [(2, 200)]
+    win.push(1, "restored-pair", 50)        # a lone partial of another run
+    assert win.unconfirmed == 3
+    win.retire(0)
+    assert rec.backs() == [(2, 200), (2, 300), (1, 50)]
+    assert rec.syncs() == ["pair-0", "pair-1", "restored-pair"]
+    assert all(e[3] is None for e in rec.log if e[0] == "sync")
+
+
+_WINDOW_UNITS = {
+    "confirms-in-dispatch-order": (_confirms_in_dispatch_order, {}),
+    "retire-lets-a-done-head-go": (_retire_lets_a_done_head_go, {}),
+    "retire-blocks-for-a-head-over-keep":
+        (_retire_blocks_for_a_head_over_keep, {}),
+    "starved-with-no-waiter": (_starved_needs_a_waiter, {}),
+    "starved-with-a-waiter": (_starved_needs_a_waiter, {"waiters": 1}),
+    "release-after-a-failure":
+        (_release_after_a_failure, {"fail_at": "call-1"}),
+    "the-spill-hook-runs-between": (_the_spill_hook_runs_between, {}),
+    "a-cover-of-two-moves-it-by-two": (_a_cover_of_two_moves_it_by_two, {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_WINDOW_UNITS))
+def test_the_window_on_a_fake_pool_and_fake_handles(case, monkeypatch):
+    check, kw = _WINDOW_UNITS[case]
+    check(_Recorder(monkeypatch, **kw))
+
+
 def test_the_window_probe_runs_at_toy_size():
     """``scripts/swap_window_probe.py``, what PERF.md's window table was
     read from, end to end at ``benchmark/tests``' toy sizes: a JSON line

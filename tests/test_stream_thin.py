@@ -176,11 +176,50 @@ PARENT_TEXT = {
         "272fe560eb5da2d8c34ba3043691d44bc4e2eda5303febf41a487bda39763ab0",
     "slab-sum-fused":
         "0563f4b4c2fa7a0e650824a2c64e17fb98d92eca2d844f2c85a26a9cb57fab48",
+    # the three tupled terminals, read at 06c96b3 before ISSUE 57 touched
+    # the file: a fused multi-stat group over the same fat source, Q1's
+    # shape (a grouped mean behind a filter over thin rows of seven, dense)
+    # and a Gram matrix with its sums over planes of thin rows
+    "slab-multi":
+        "9abaab6000346c024364bbcda57c769dead02131ed06411e4a6fef4a76337e48",
+    "slab-multi-fused":
+        "fab9471c51de14e0a2d7e89dbeba463dc55974519904d9bd1334f4f4206d50e2",
+    "slab-group":
+        "0fc37ff9ee19d7a7a3e180fa90f6d9c015ff7e255b6be69a3d9785bfb09cb44f",
+    "slab-group-fused":
+        "b444661a8fcd2b5f79a07fcf168fc84012a6fffb0507f822b1410bb645316c37",
+    "slab-gram":
+        "6ab080d254ca99e79d95e39f694ebe4f41c01f53445334f52b8f04d210af19fc",
+    "slab-gram-fused":
+        "54a287cbf2cef99a08eacc61ce613151d5870c04101755cc3b34b7908e8ba7f9",
 }
 
 
 def plus_one(v):
     return v + 1
+
+
+def _keep(r):
+    return r[0] > 0
+
+
+def _label(r):
+    return (r[1] > 0).astype(np.int32) * 2 + (r[2] > 0).astype(np.int32)
+
+
+def _terms(r):
+    return (r[3], r[4] * r[5])
+
+
+def _both(name, src, terminal, wshape, slab, **kw):
+    """The lowered text of ``terminal``'s slab program over ``src`` and of
+    its acc-fused twin (the partial it takes is the first's result)."""
+    first = stream._slab_program(src, terminal, wshape, **kw).lower(slab)
+    acc = jax.tree_util.tree_map(
+        lambda o: jax.ShapeDtypeStruct(o.shape, o.dtype), first.out_info)
+    fused = stream._slab_program(src, terminal, wshape, fused=True,
+                                 **kw).lower(slab, acc)
+    return {name: first.as_text(), name + "-fused": fused.as_text()}
 
 
 @pytest.mark.skipif(jax.__version__ != "0.9.0",
@@ -197,11 +236,32 @@ def test_the_fat_slab_program_is_the_parents_to_the_letter(one):
     acc = jax.ShapeDtypeStruct((256, 128), np.float32)
     texts = {
         "slab-sum": stream._slab_program(
-            src, "sum", slab.shape, None, None).lower(slab).as_text(),
+            src, stream._Sum(), slab.shape).lower(slab).as_text(),
         "slab-sum-fused": stream._slab_program(
-            src, "sum", slab.shape, None, None, fused=True).lower(
+            src, stream._Sum(), slab.shape, fused=True).lower(
                 slab, acc).as_text(),
     }
+    specs = (("sum", None), ("mean", None), ("var", 1), ("ptp", None))
+    texts.update(_both("slab-multi", src, stream._Multi(specs), slab.shape,
+                       slab))
+    rows = 1000
+    tsrc = bolt.fromcallback(lambda idx: None, (4000, 7), one,
+                             dtype=np.float32, chunks=rows).filter(
+                                 _keep)._stream
+    assert stream.dense_route(tsrc)
+    dense = (jax.ShapeDtypeStruct((rows // 128, 128 * 7), np.float32),
+             jax.ShapeDtypeStruct((rows % 128, 7), np.float32))
+    texts.update(_both(
+        "slab-group", tsrc, stream._Group(("mean", _label, _terms, 4), tsrc),
+        (rows, 7), dense, thin=True))
+    psrc = bolt.fromcallback(lambda idx: None, (8, 256, 16), one,
+                             dtype=np.float32, chunks=2).map(
+                                 plus_one)._stream
+    assert stream.dense_route(psrc)
+    dense = (jax.ShapeDtypeStruct((2, 2, 128 * 16), np.float32),)
+    texts.update(_both(
+        "slab-gram", psrc, stream._Gram((2, "highest", False, True)),
+        (2, 256, 16), dense, thin=True))
     assert {k: hashlib.sha256(v.encode()).hexdigest()
             for k, v in texts.items()} == PARENT_TEXT
 
