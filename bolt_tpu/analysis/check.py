@@ -373,8 +373,9 @@ def working_set_bytes(obj):
     """Estimated PEAK device bytes ``obj``'s pipeline needs at once —
     the number admission control compares against the serving budget:
 
-    * streaming plan → slab bytes x (prefetch depth + uploader pool),
-      the donated-ring bound;
+    * streaming plan → slab bytes x ``stream.fold_ring`` (the window
+      of unconfirmed slab programs + the uploader pool), the
+      donated-ring bound;
     * pending stat group → the group's one-pass read (stream groups use
       the ring bound);
     * deferred chain / filter / concrete array → source bytes + result
@@ -1229,8 +1230,9 @@ def _check_stream(arr, target, stages, diags):
     nproc = _mh.mesh_process_count(mesh)
     pool = _stream.pool_size(src)
     note = ("out-of-core: ~%d slabs of %d records, prefetch depth %d, "
-            "uploader pool %d"
-            % (nslabs, src.slab, _stream.fold_ring(src) - pool, pool))
+            "a window of %d unconfirmed slabs, uploader pool %d"
+            % (nslabs, src.slab, _stream.prefetch_depth(),
+               _stream.fold_ring(src) - pool, pool))
     if nproc > 1:
         # the per-host plan (explain() shows it): each process produces
         # and uploads only its shard of every slab; the cross-host fold

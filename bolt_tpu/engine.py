@@ -161,6 +161,9 @@ _SCHEMA = {
     "stream_windowed_slabs": 0,   # place calls the swap / collect
                                   # resolver dispatched while an earlier
                                   # one was still unconfirmed
+    "stream_early_retired_slabs": 0,  # slabs whose permits execute's
+                                      # window handed back without
+                                      # blocking, before it was full
     # fault-tolerance accounting (ISSUE 9: resumable streams).  A retry
     # is one re-attempted slab ingest (stream.retries / the serve layer's
     # per-submit retries); a resume is one streamed run that restarted
@@ -1065,7 +1068,7 @@ def record_checkpoint(nbytes, seconds):
 
 def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                   uploaders=1, inflight=1, keyed=0, group=0, thin=0,
-                  gram=0, gram_kernel=0, windowed=0):
+                  gram=0, gram_kernel=0, windowed=0, early=0):
     """Tally one completed streamed run (bolt_tpu.stream executor); the
     keys apply atomically — a snapshot can never see a run's wall time
     without its overlap.  Called by the run's own thread as the run ends,
@@ -1082,7 +1085,10 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
     folded, ``gram_kernel`` of them by a program lowered with the
     ``packed_gram`` kernel; ``windowed``: those whose place call the swap
     / collect resolver dispatched while an earlier one was still
-    unconfirmed (its window at work: 0 at ``prefetch(1)``)."""
+    unconfirmed (its window at work: 0 at ``prefetch(1)``); ``early``:
+    those whose permits ``execute``'s window handed back before it was
+    full, because the head of the window was done when asked (0 at
+    ``prefetch(1)``, and wherever no program is done a window later)."""
     _COUNTERS.update(_maxima={"stream_prefetch_depth": int(depth),
                               "stream_upload_threads": int(uploaders),
                               "stream_inflight_high_water": int(inflight)},
@@ -1093,6 +1099,7 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
                      stream_gram_slabs=int(gram),
                      stream_gram_kernel_slabs=int(gram_kernel),
                      stream_windowed_slabs=int(windowed),
+                     stream_early_retired_slabs=int(early),
                      stream_ingest_seconds=ingest_s,
                      stream_compute_seconds=compute_s,
                      stream_wall_seconds=wall_s,

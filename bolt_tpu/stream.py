@@ -26,7 +26,9 @@ a recorded swap).  Every streamed run is the same four pieces::
   keep one produce+upload thread.  A **re-sequencer** (:class:`_Reseq`)
   hands slabs to the consumer strictly in slab order whatever order the
   uploads finish in, so every consumer is deterministic.  Slab buffers
-  form a **ring** bounded by ``prefetch depth + pool size``: a permit
+  form a **ring** bounded by a consumer's window and the pool's size
+  (:func:`fold_ring`, :func:`swap_ring`: the prefetch depth, a measured
+  step, a slab in every worker's hand): a permit
   (and, under ``bolt_tpu.serve``, the slab's wire bytes from the
   device-memory arbiter) is taken per slab in slab order and comes back
   when the consumer says the slab's program retired;
@@ -38,9 +40,10 @@ a recorded swap).  Every streamed run is the same four pieces::
   FOLDS a reduction terminal, a VALUE that says what a slab's partial is
   and how two merge (:class:`_Terminal`: a sum, a ``reduce``, moments,
   the tuples of a fused multi-stat group, of :func:`maybe_group` and of
-  :func:`maybe_gram`): it syncs only on window overflow
-  (:func:`fold_ring`; an already-retired old partial, ~free) and on the
-  final result, so compute and ingest overlap.  Each ring buffer is
+  :func:`maybe_gram`): it blocks only on window overflow, for a pair
+  partial dispatched two slab periods back and long done, hands a
+  permit back as soon as the head of its window is done, and syncs on
+  the final result, so compute and ingest overlap.  Each ring buffer is
   **donated** into its slab program, the **level-0 fold is fused into
   the slab program** (odd slabs run ``prog(buf, acc)``: half the fold
   dispatches), and pair-partials above level 0 combine as a pairwise
@@ -141,10 +144,10 @@ from bolt_tpu.utils import chain_retry_step, iter_record_blocks, prod
 
 # prefetch depth k: how many uploaded slabs may wait ahead of the
 # consumer beyond the uploader pool's own hands-on slabs (the ring is
-# bounded at depth + pool size).  2 = classic double buffering: one slab
-# in compute, one in flight.  Deeper rings only help when per-slab
-# ingest time is noisy (0.3 % on the chip: PERF.md section 5, PR 35);
-# they cost one slab of HBM each.
+# bounded at depth + a measured step + pool size: fold_ring, swap_ring).
+# 2 = classic double buffering: one slab in compute, one in flight.
+# Deeper rings only help when per-slab ingest time is noisy (0.3 % on
+# the chip: PERF.md section 5, PR 35); they cost one slab of HBM each.
 _DEPTH = 2
 
 # uploader pool size: concurrent ingest workers.  0 = auto, resolved per
@@ -182,6 +185,26 @@ _LINK_COPIES = 2
 # (134 MB there).  One step is the smallest that leaves no pass short.
 _SWAP_WINDOW_STEP = 1
 
+# slabs `execute` keeps dispatched and unconfirmed BEYOND the prefetch
+# depth (fold_ring grows by as many: the pool's ring, the window and
+# analysis.check's price move together).  The same cause as above: a
+# slab program is done about one slab period after its dispatch, so at
+# a window of 2 the consumer blocks on a pair it dispatched ONE slab ago
+# and at 3 the head is still only just finishing when it is asked.
+# MEASURED (PERF.md section 5, PR 58; scripts/stream_depth_probe.py and
+# scripts/swap_window_probe.py on a v5e host, seconds a pass at windows
+# 2 / 3 / 4 / 5 with a done head let go at once, three passes each, the
+# link's own 1.194): `scan_q1q6`'s Q1, whose program of thin records
+# retires latest, 1.58-1.63 / 1.249-1.263 / 1.201-1.204 / 1.199-1.200
+# (confirmed only by a block: 1.51-1.52 / 1.259-1.266 / 1.205-1.213 /
+# 1.235-1.247), its heads found done 124 / 44-60 / 208-236 / 248 of 251
+# slabs; Q6 1.194-1.198 at every window; `scan_pca`'s Gram pass 1.46 /
+# 1.230 / 1.231 / 1.234 (the link's 1.230); `stack4d-1chip.stream`
+# 1.524-1.526 throughout.  Two steps is the smallest that leaves no pass
+# more than 2 % short of the link (Q1 is 4.6 % short at one); a permit
+# is one slab of HBM (64 MiB there, 268 MB in `scan_pca`).
+_FOLD_WINDOW_STEP = 2
+
 # the prefetch()/uploaders() SCOPES are thread-local (like
 # engine.donation and bolt.precision): under the multi-tenant serving
 # layer (bolt_tpu.serve) concurrent streams run on different threads,
@@ -200,7 +223,7 @@ def _scope_stack(name):
 
 # default slab budget when the caller gives no explicit record count:
 # big enough to amortise per-dispatch overhead, small enough that
-# depth+1 slabs stay far below any device's HBM (64 and 128 MiB read
+# a ring of slabs stays far below any device's HBM (64 and 128 MiB read
 # alike on the chip: PERF.md section 5, PR 35)
 _SLAB_BYTES = 64 << 20
 
@@ -528,6 +551,16 @@ def place_budget(source):
     return budget
 
 
+def _ring(source, step):
+    """A window of the prefetch depth and ``step`` more, plus one slab
+    in the hand of every worker of the uploader pool; ``prefetch(1)``
+    takes no step: it stays one call at a time."""
+    depth = prefetch_depth()
+    if depth > 1:
+        depth += step
+    return depth + pool_size(source)
+
+
 def swap_ring(source):
     """Uploaded slabs a streamed-swap resolution over ``source`` keeps
     on the device: the resolver's window of dispatched, unconfirmed
@@ -537,18 +570,18 @@ def swap_ring(source):
     the output.  The window is the prefetch depth and
     ``_SWAP_WINDOW_STEP`` more; ``prefetch(1)`` stays one call at a
     time."""
-    depth = prefetch_depth()
-    if depth > 1:
-        depth += _SWAP_WINDOW_STEP
-    return depth + pool_size(source)
+    return _ring(source, _SWAP_WINDOW_STEP)
 
 
 def fold_ring(source):
     """Uploaded slabs a folded run (:func:`execute`) over ``source``
-    keeps on the device: its window of unconfirmed slab programs, the
-    prefetch depth, plus one in the hand of every pool worker — the ring
-    its permits bound, and what ``analysis.check`` prices."""
-    return prefetch_depth() + pool_size(source)
+    keeps on the device: its window of unconfirmed slab programs plus
+    one in the hand of every pool worker — the ring its permits bound,
+    and what ``analysis.check`` prices.  The window is the prefetch
+    depth and ``_FOLD_WINDOW_STEP`` more; ``prefetch(1)`` stays one
+    slab program at a time (a pair's: the even slab's partial is fused
+    into the odd slab's program)."""
+    return _ring(source, _FOLD_WINDOW_STEP)
 
 
 def pool_size(source):
@@ -2685,10 +2718,11 @@ def _undonated_ok():
 
 
 def _retired(handle):
-    """Whether the slab program that returned ``handle`` is done, asked
-    without blocking (through the module-level name: the tests' patch
-    point, where a CPU's programs are done as soon as dispatched)."""
-    return handle.is_ready()
+    """Whether the slab program that returned ``handle`` (an array, or
+    the tuple a tupled partial is) is done, asked without blocking
+    (through the module-level name: the tests' patch point, where a
+    CPU's programs are done as soon as dispatched)."""
+    return all(x.is_ready() for x in jax.tree_util.tree_leaves(handle))
 
 
 class _Window:
@@ -2715,6 +2749,7 @@ class _Window:
         self._lone = 0
         self.unconfirmed = 0    # slabs dispatched and not confirmed
         self.high_water = 0
+        self.early = 0          # slabs retire() confirmed before it had to
 
     def _grew(self, slabs):
         self.unconfirmed += slabs
@@ -2766,11 +2801,16 @@ class _Window:
 
     def retire(self, keep):
         """Confirm the oldest calls, blocking, until at most ``keep``
-        stay unconfirmed, and WITHOUT blocking every head of the window
-        that is done already: its permit goes back when the device lets
-        go of the slab, not a slab later."""
-        while self._calls and (len(self._calls) > keep
-                               or _retired(self._calls[0][1])):
+        SLABS stay unconfirmed (a place call covers one, a pair partial
+        two, and a lone slab counts before any call covers it), and
+        WITHOUT blocking every head of the window that is done already:
+        its permits go back when the device lets go of the slabs, not a
+        slab later (``early`` counts those slabs)."""
+        while self._calls:
+            if self.unconfirmed <= keep:
+                if not _retired(self._calls[0][1]):
+                    break
+                self.early += self._calls[0][0]
             self.confirm_oldest()
 
     def starved(self):
@@ -2898,10 +2938,10 @@ def execute(arr, terminal, source=None):
                                    resume_records)
     total_slabs = len(jobs) if jobs is not None else None
     ring = fold_ring(source)
-    # the consumer confirms (and hands permits back) once MORE than
-    # this many slabs are dispatched and unconfirmed, so a slot stays
-    # free for EVERY worker's hand (at `ring - 1` a pool of any size ran
-    # two workers, started together: PERF.md section 5, PR 35)
+    # the consumer BLOCKS for a confirm (and hands permits back) once
+    # MORE than this many slabs are dispatched and unconfirmed, so a slot
+    # stays free for EVERY worker's hand (at `ring - 1` a pool of any
+    # size ran two workers, started together: PERF.md section 5, PR 35)
     window = ring - nwork
     run_sp = _obs.begin("stream.run", terminal=terminal.name, depth=depth,
                         uploaders=nwork, kind=source.kind,
@@ -3103,9 +3143,10 @@ def execute(arr, terminal, source=None):
                     _obs.end(csp)
                 run.compute += _clock() - t0
                 # only once the window fills does the consumer block, and
-                # then on the OLDEST pair partial, ~window slabs old
-                while win.unconfirmed > window:
-                    win.confirm_oldest()
+                # then on the OLDEST pair partial, ~window slabs old; a
+                # head that is done goes at once (by its own partial: the
+                # slab was donated)
+                win.retire(window)
                 # resumable(): persist the fold state every ck_every
                 # retired slabs (skipping the final slab of a known-size
                 # stream — the run is about to finish and clear anyway)
@@ -3170,6 +3211,7 @@ def execute(arr, terminal, source=None):
                               overlap, depth,
                               uploaders=max(pool.high_water, 1),
                               inflight=max(win.high_water, 1),
+                              early=win.early,
                               keyed=nslabs if keyed else 0, thin=nthin,
                               **terminal.tally(nslabs, ngramk))
         if result_state(source).pred is not None:

@@ -296,7 +296,7 @@ def phase_streamed(mesh, sz, seed, out_dir):
         if peaks is None:
             return
         ndev = len(peaks)
-        ring = stream.prefetch_depth() + stream.pool_size(source()._stream)
+        ring = stream.fold_ring(source()._stream)
         slab_bytes = slab * int(np.prod(shape[1:])) * 4
         # ring slabs + as many again in flight + value-shaped partials;
         # a leak of one slab per slab would be 64 slabs

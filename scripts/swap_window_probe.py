@@ -3,22 +3,32 @@
 worth in a streamed cell, from ONE process on the chip.
 
     python3 scripts/swap_window_probe.py <cell> --seed N
-        [--windows 1 2 3 4] [--passes 3] [--rounds 2] [--tiny]
+        [--windows 1 2 3 4] [--passes 3] [--rounds 2]
+        [--confirms early blocking] [--tiny]
 
 The cell's operand is built once (the host tile), then its one request is
 repeated under bolt's tracer alone (``obs.enable()``, no profiler) at each
 process-wide prefetch depth W (``stream.set_prefetch_depth``) with
-``stream._SWAP_WINDOW_STEP`` set to 0, so that W IS the resolver's window,
-ring W + pool (``stream.swap_ring``; the shipped step makes a caller's
-depth 2 a window of 3); the rounds walk the windows up and then down.  A JSON line a (round, W): the requests'
-walls, GB/s streamed, the link's own account (``transfer_seconds``), the
-consumer's and the pool's waits as shares of the wall and per span, and the
-window's counters (``stream_windowed_slabs``, the high-water, a process
-maximum).  What PERF.md section 5's window table (PR 56) was read from;
-``--tiny`` rehearses at ``benchmark/tests``' toy sizes on any device.  Runs
-in no cell of the benchmark; a cell of one request a cycle (``toseries``,
-``toseries4``, ``register``, ``scan_pca``)."""
+``stream._SWAP_WINDOW_STEP`` and ``stream._FOLD_WINDOW_STEP`` set to 0, so
+that W IS the resolver's window and ``execute``'s, ring W + pool
+(``stream.swap_ring``, ``stream.fold_ring``; the shipped steps make a
+caller's depth 2 a window of 3); the rounds walk the windows up and then
+down.  ``--confirms blocking`` repeats each window with ``stream._retired``
+answering no, so that a confirm is made only once the window is over
+(``execute`` before PR 58, the resolver's early retirement off).  A JSON
+line a (round, W, confirm): the requests' walls, GB/s streamed, the link's
+own account (``transfer_seconds``), the consumer's and the pool's waits as
+shares of the wall and per span (a folded pass is its ``stream.run``, a
+placed one its ``stream.shuffle`` / ``stream.collect``), and the windows'
+counters (``stream_windowed_slabs``, ``stream_early_retired_slabs``, the
+high-water, a process maximum).  What PERF.md section 5's window tables
+were read from (the resolver's, PR 56; ``scan_pca``'s Gram pass and
+``stream`` in ``execute``'s, PR 58, beside ``stream_depth_probe.py``'s Q6
+and Q1); ``--tiny`` rehearses at ``benchmark/tests``' toy sizes on any
+device.  Runs in no cell of the benchmark; a cell of one request a cycle
+(``toseries``, ``toseries4``, ``register``, ``scan_pca``, ``stream``)."""
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -47,6 +57,8 @@ def main():
     ap.add_argument("--windows", type=int, nargs="+", default=[1, 2, 3, 4])
     ap.add_argument("--passes", type=int, default=3)
     ap.add_argument("--rounds", type=int, default=2)
+    ap.add_argument("--confirms", nargs="+", default=["early"],
+                    choices=["early", "blocking"])
     ap.add_argument("--tiny", action="store_true",
                     help="benchmark/tests' toy sizes, on whatever device")
     args = ap.parse_args()
@@ -58,7 +70,9 @@ def main():
     cell.open_device()
     cell.build()
     from bolt_tpu import engine, obs, stream
-    stream._SWAP_WINDOW_STEP = 0        # the depth below IS the window
+    stream._SWAP_WINDOW_STEP = 0        # the depth below IS the window,
+    stream._FOLD_WINDOW_STEP = 0        # the resolver's and execute's
+    is_done = stream._retired
     if args.tiny:
         stream._SLAB_BYTES = 16 * 16 * 32 * 4
     kinds = cell.traffic["requests"]
@@ -75,7 +89,9 @@ def main():
     request()
     for rnd in range(args.rounds):
         order = args.windows if rnd % 2 == 0 else args.windows[::-1]
-        for w in order:
+        for w, confirm in itertools.product(order, args.confirms):
+            stream._retired = (is_done if confirm == "early"
+                               else lambda handle: False)
             stream.set_prefetch_depth(w)
             request()                   # settle at this depth
             obs.clear()
@@ -88,6 +104,15 @@ def main():
                 walls.append(time.perf_counter() - t0)
             c1 = engine.counters()
             totals = obs.totals()
+            # the folded pass's own spans (the consumer's thread: under
+            # execute's stream.run), apart from a placed pass's
+            fold = {}
+            for path, (count, seconds, _, _) in obs.trace.path_totals().items():
+                if "stream.run" in path:
+                    at = fold.setdefault(path[-1].split("stream.")[-1],
+                                         [0, 0.0])
+                    at[0] += count
+                    at[1] = round(at[1] + seconds, 4)
             obs.disable()
             obs.clear()
             d = {key: c1[key] - c0[key] for key in c1
@@ -96,6 +121,7 @@ def main():
             up = d["transfer_bytes"]
             row = {
                 "cell": args.cell, "round": rnd, "window": w,
+                "confirm": confirm,
                 "walls_s": [round(x, 4) for x in walls],
                 "GBps": up / wall / 1e9,
                 "upload_GBps": up / max(d["transfer_seconds"], 1e-9) / 1e9,
@@ -103,6 +129,7 @@ def main():
                 / max(d["transfer_seconds"], 1e-9),
                 "slabs": d["stream_chunks"],
                 "windowed": d.get("stream_windowed_slabs"),
+                "early": d.get("stream_early_retired_slabs"),
                 "inflight_hw": c1["stream_inflight_high_water"],
                 "workers_busy": totals.get("stream.ingest", {}).get(
                     "seconds", 0.0) / wall,
@@ -112,6 +139,7 @@ def main():
                 "us_a_span": {n.split("stream.")[1]: round(
                     1e6 * totals[n]["seconds"] / totals[n]["count"], 1)
                     for n in PER_SLAB if n in totals},
+                "fold": fold,
                 "peak_GB": cell.memory_peak() / 1e9,
             }
             print(json.dumps(row), flush=True)

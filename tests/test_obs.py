@@ -338,6 +338,7 @@ _EXPECTED_ENGINE_KEYS = {
     "stream_project_slabs": False,
     "stream_alltoall_bytes": False, "stream_upload_parts": False,
     "stream_windowed_slabs": False,
+    "stream_early_retired_slabs": False,
 }
 
 

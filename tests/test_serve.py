@@ -404,7 +404,9 @@ def test_working_set_estimates(mesh):
     x = _x((64, 8, 4))
     src = bolt.fromcallback(lambda idx: x[idx], x.shape, mesh,
                             dtype=np.float32, chunks=16)
-    ring = _stream.prefetch_depth() + _stream.pool_size(src._stream)
+    ring = _stream.fold_ring(src._stream)
+    assert ring == (_stream.prefetch_depth() + _stream._FOLD_WINDOW_STEP
+                    + _stream.pool_size(src._stream))
     est = analysis.working_set_bytes(src.map(ADD1))
     assert est == 16 * 8 * 4 * 4 * ring
     b = bolt.array(x, mesh).map(ADD1)

@@ -1174,6 +1174,265 @@ def test_stream_inflight_window_bounds_and_records(mesh):
 
 
 # ---------------------------------------------------------------------
+# execute's confirm window (PR 58): the prefetch depth and a step deep,
+# and a head that is done goes without a block
+# ---------------------------------------------------------------------
+
+def _floatdata():
+    """float32 values no fold order rounds alike: a merged order that
+    moved would show in the last bits of a mean or a variance."""
+    rng = np.random.default_rng(58)
+    return (rng.standard_normal(SHAPE) * 1e3).astype(np.float32)
+
+
+@pytest.fixture
+def never_done(monkeypatch):
+    """No slab program reads done until the consumer BLOCKS for it: the
+    chip's order of events with a program that retires late (a CPU's
+    are done as soon as dispatched), and the executor before PR 58."""
+    monkeypatch.setattr(stream, "_retired", lambda handle: False)
+
+
+@pytest.fixture
+def always_done(monkeypatch):
+    monkeypatch.setattr(stream, "_retired", lambda handle: True)
+
+
+def _spy_record_stream(monkeypatch):
+    seen = []
+    record = engine.record_stream
+
+    def spy(*args, **kw):
+        seen.append(kw)
+        return record(*args, **kw)
+    monkeypatch.setattr(engine, "record_stream", spy)
+    return seen
+
+
+def _fold_window(depth):
+    return depth + stream._FOLD_WINDOW_STEP if depth > 1 else 1
+
+
+@pytest.mark.parametrize("depth", [1, 2, 3, 4])
+def test_executes_window_is_the_depth_and_the_step_and_the_ring_holds(
+        mesh, depth, never_done, monkeypatch):
+    """With every confirm a real block (no program done before it), the
+    consumer blocks only once MORE than the window is unconfirmed: what
+    it has dispatched and not confirmed reaches the window and the slab
+    just dispatched, never more, each block is for the oldest pair, and
+    the permits out never pass ``fold_ring``."""
+    sync = stream._pod_sync
+
+    def slow(x, pod, phase, slab=None):
+        time.sleep(0.003)
+        return sync(x, pod, phase, slab=slab)
+    monkeypatch.setattr(stream, "_pod_sync", slow)
+    seen = _spy_record_stream(monkeypatch)
+    data = _intdata()
+    obs.clear()
+    obs.enable()
+    try:
+        with stream.prefetch(depth), stream.uploaders(2):
+            src = _source(data, mesh, 1)
+            ring = stream.fold_ring(src._stream)
+            got = np.asarray(src.sum().toarray())
+        assert obs.active_count() == 0
+        spans = obs.spans()
+    finally:
+        obs.disable()
+        obs.clear()
+    assert np.array_equal(got, data.sum(axis=0))
+    window = ring - 2
+    assert window == _fold_window(depth)
+    mine, = seen
+    assert mine["inflight"] == window + 1 and mine["early"] == 0
+    calls = sorted((sp for sp in spans if sp.name == "stream.dispatch"),
+                   key=lambda sp: sp.t0)
+    blocks = sorted((sp for sp in spans if sp.name == "stream.sync"),
+                    key=lambda sp: sp.t0)
+    # pair partials, all but what the window still held at the end
+    assert len(calls) == N and all(b.attrs["slabs"] == 2 for b in blocks)
+    assert N - window <= 2 * len(blocks) <= N
+    for sp in calls:
+        before = sum(c.t0 < sp.t0 for c in calls) \
+            - sum(b.attrs["slabs"] for b in blocks if b.t1 <= sp.t0)
+        assert before <= window
+    # the first block waits until the window is over, and no longer
+    assert blocks[0].t0 >= calls[window].t1
+    assert depth > 1 or blocks[0].t1 <= calls[2].t0
+    if window + 1 < N:
+        assert blocks[0].t0 <= calls[window + 1].t0
+    ingests = [sp for sp in spans if sp.name == "stream.ingest"]
+    assert len(ingests) == N
+    for sp in ingests:
+        out = sum(i.t0 <= sp.t0 for i in ingests) \
+            - sum(b.attrs["slabs"] for b in blocks if b.t1 <= sp.t0)
+        assert out <= ring
+
+
+@pytest.mark.parametrize("done", ["never_done", "always_done"])
+def test_prefetch_1_is_one_pair_at_a_time_and_retires_nothing_early(
+        mesh, done, request, monkeypatch):
+    request.getfixturevalue(done)
+    seen = _spy_record_stream(monkeypatch)
+    data = _intdata()
+    c0 = engine.counters()
+    with stream.prefetch(1), stream.uploaders(2):
+        src = _source(data, mesh, 1)
+        assert stream.fold_ring(src._stream) == 1 + 2     # no step
+        got = np.asarray(src.sum().toarray())
+    c1 = engine.counters()
+    assert np.array_equal(got, data.sum(axis=0))
+    mine, = seen
+    # an even slab and the odd one its partial is fused into: over the
+    # window of 1, so every pair is confirmed by a block
+    assert mine["inflight"] == 2 and mine["early"] == 0
+    assert c1["stream_early_retired_slabs"] \
+        == c0["stream_early_retired_slabs"]
+
+
+_FOLDS = {
+    "sum": lambda b: b.map(ADD1).sum(),
+    "mean": lambda b: b.mean(),
+    "var": lambda b: b.var(),
+    "filtered-sum": lambda b: b.filter(POSSUM).sum(),
+    "reduce": lambda b: b.reduce(jnp.maximum),
+}
+
+
+@pytest.mark.parametrize("chunks", [1, 3])        # 16 slabs; 6, odd tail
+@pytest.mark.parametrize("fold", sorted(_FOLDS))
+def test_a_deeper_window_and_an_early_retirement_change_no_bit(
+        mesh, fold, chunks, monkeypatch):
+    """The same slabs in the same order through the same pairwise tree,
+    whenever a confirm is made: the parent's executor (no step, no head
+    let go), the window with nothing ever done, and with everything done
+    when asked give one answer, and ``early`` says which it was."""
+    data = _floatdata()
+    nslabs = -(-N // chunks)
+    seen = _spy_record_stream(monkeypatch)
+
+    def run():
+        with stream.uploaders(2):
+            return np.asarray(_FOLDS[fold](
+                _source(data, mesh, chunks)).toarray())
+
+    with monkeypatch.context() as m:
+        m.setattr(stream, "_FOLD_WINDOW_STEP", 0)
+        m.setattr(stream, "_retired", lambda handle: False)
+        parents = run()
+    monkeypatch.setattr(stream, "_retired", lambda handle: False)
+    blocked = run()
+    monkeypatch.setattr(stream, "_retired", lambda handle: True)
+    early = run()
+    for got in (blocked, early):
+        assert got.dtype == parents.dtype and np.array_equal(
+            got, parents, equal_nan=True)
+    assert [kw["early"] for kw in seen] \
+        == [0, 0, nslabs - nslabs % 2]            # every pair, at once
+    assert [kw["inflight"] for kw in seen] == [
+        3, min(stream._FOLD_WINDOW_STEP + 3, nslabs), 2]
+
+
+def test_the_forecast_prices_the_ring_the_pool_is_given(mesh, monkeypatch):
+    """``analysis.working_set_bytes`` of a streamed source, the pool's
+    permits and ``execute``'s window are one number's three readers."""
+    data = _intdata()
+    rings = []
+    init = stream._IngestPool.__init__
+
+    def spy(self, run, source, ring, **kw):
+        rings.append(ring)
+        return init(self, run, source, ring, **kw)
+    monkeypatch.setattr(stream._IngestPool, "__init__", spy)
+    slab_bytes = 2 * V0 * V1 * data.dtype.itemsize
+    for depth, nwork in ((1, 1), (2, 2), (2, 3), (4, 2)):
+        with stream.prefetch(depth), stream.uploaders(nwork):
+            src = _source(data, mesh, 2)
+            ring = stream.fold_ring(src._stream)
+            assert ring == _fold_window(depth) + nwork
+            assert analysis.working_set_bytes(src) == slab_bytes * ring
+            note = analysis.explain(src)
+            src.sum().toarray()
+        assert rings.pop() == ring and not rings
+        assert "prefetch depth %d, a window of %d unconfirmed slabs, " \
+            "uploader pool %d" % (depth, ring - nwork, nwork) in str(note)
+
+
+def test_a_resumed_run_gives_back_the_permits_it_holds_and_no_more(
+        mesh, tmp_path, always_done, monkeypatch):
+    """A run resumed behind an UNPAIRED partial (a checkpoint cut at an
+    odd slab count): the partial it restored stands for another run's
+    slab, so the pair it is fused into covers ONE permit of this run's,
+    and early or late every slab's permit comes back once."""
+    from bolt_tpu import _chaos as chaos
+    data = _intdata()
+    ck = str(tmp_path / "ck")
+
+    def source():
+        return bolt.fromcallback(lambda idx: data[idx], data.shape, mesh,
+                                 dtype=data.dtype, chunks=2, checkpoint=ck)
+    chaos.clear()
+    chaos.inject("stream.upload", nth=4)          # slabs 0-2 folded: odd
+    try:
+        with pytest.raises(chaos.ChaosError):
+            with stream.uploaders(1):
+                source().sum().cache()
+    finally:
+        chaos.clear()
+    backs = []
+    give_back = stream._IngestPool.give_back
+
+    def spy(self, slabs, nbytes):
+        backs.append(slabs)
+        return give_back(self, slabs, nbytes)
+    monkeypatch.setattr(stream._IngestPool, "give_back", spy)
+    seen = _spy_record_stream(monkeypatch)
+    c0 = engine.counters()
+    got = np.asarray(source().sum().toarray())
+    c1 = engine.counters()
+    assert np.array_equal(got, data.sum(axis=0))
+    assert c1["stream_resumes"] - c0["stream_resumes"] == 1
+    mine, = seen
+    left = N // 2 - 3                             # slabs 3-7
+    assert c1["stream_chunks"] - c0["stream_chunks"] == left
+    # the restored partial's pair first, one permit; then whole pairs
+    assert backs == [1, 2, 2] and sum(backs) == left == mine["early"]
+
+
+def test_the_depth_probe_runs_at_toy_size():
+    """``scripts/stream_depth_probe.py``, what PERF.md's table of
+    ``execute``'s windows was read from, end to end at
+    ``benchmark/tests``' toy sizes: a JSON line a pass, a window's
+    high-water one over it, and nothing retired early where no program
+    reads done."""
+    import json
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, os.path.join(root, "scripts",
+                                      "stream_depth_probe.py"),
+         "--tiny", "--windows", "2", "3", "--passes", "1", "--seed", "7"],
+        capture_output=True, text=True, timeout=300, cwd=root,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert done.returncode == 0, done.stderr[-2000:]
+    rows = [json.loads(line) for line in done.stdout.splitlines()
+            if line.startswith('{"window"')]
+    assert [(r["window"], r["confirm"], r["kind"]) for r in rows] == [
+        (w, c, k) for w in (2, 3) for c in ("blocking", "early")
+        for k in ("q6", "q1")]
+    for r in rows:
+        assert r["stream_chunks"] >= 6 and "stream.sync" in r["spans"]
+        if r["confirm"] == "blocking":
+            assert r["stream_early_retired_slabs"] == 0
+            assert r["inflight_hw"] == [r["window"] + 1]
+        else:
+            assert r["inflight_hw"][0] <= r["window"] + 1
+
+
+# ---------------------------------------------------------------------
 # chunked-view terminals on MATERIALISED arrays (delegation parity)
 # ---------------------------------------------------------------------
 
