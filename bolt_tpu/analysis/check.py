@@ -267,18 +267,23 @@ def _effective_codec(src):
         return None
 
 
+def _wire_itemsize(src):
+    """Bytes an element of ``src`` takes on the link and in the ring:
+    the stored dtype's, or the WIRE dtype's when a codec is armed."""
+    c = _effective_codec(src)
+    if c is not None:
+        try:
+            return c.wire_dtype(src.dtype).itemsize
+        except ValueError:
+            pass          # refused combination: the run never streams
+    return src.dtype.itemsize
+
+
 def _stream_slab_bytes(src):
     """One slab's DEVICE bytes — the WIRE representation when a codec
     is armed (the ring holds and the arbiter leases compressed slabs;
     the admission floor recomputes through the codec ratio)."""
-    itemsize = src.dtype.itemsize
-    c = _effective_codec(src)
-    if c is not None:
-        try:
-            itemsize = c.wire_dtype(src.dtype).itemsize
-        except ValueError:
-            pass          # refused combination: the run never streams
-    return int(src.slab * prod(src.shape[1:]) * itemsize)
+    return int(src.slab * prod(src.shape[1:]) * _wire_itemsize(src))
 
 
 def _stream_ring_bytes(src):
@@ -1233,6 +1238,8 @@ def _check_stream(arr, target, stages, diags):
             "a window of %d unconfirmed slabs, uploader pool %d"
             % (nslabs, src.slab, _stream.prefetch_depth(),
                _stream.fold_ring(src) - pool, pool))
+    note += ("; stored %d B an element, %d B on the wire"
+             % (src.dtype.itemsize, _wire_itemsize(src)))
     if nproc > 1:
         # the per-host plan (explain() shows it): each process produces
         # and uploads only its shard of every slab; the cross-host fold

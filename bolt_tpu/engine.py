@@ -133,6 +133,15 @@ _SCHEMA = {
                                   # pool put (stream._upload_slab_mh: one
                                   # a local device a slab), so over
                                   # stream_chunks it is the parts a slab
+    "transfer_elements": 0,       # elements those bytes were: bytes over
+                                  # it is the width of an element ON THE
+                                  # WIRE (4.0 for float32 data, 2.0 for a
+                                  # camera's uint16 words, a codec's wire
+                                  # dtype under a codec)
+    "stream_narrow_slabs": 0,     # uploaded slabs whose element on the
+                                  # wire was narrower than 32 bits (stored
+                                  # so, or encoded so by a codec): the
+                                  # device tiles them in packed sublanes
     # streaming-executor accounting (bolt_tpu.stream: the out-of-core
     # double-buffered pipeline).  overlap_seconds is ingest time hidden
     # behind device compute: max(0, ingest + compute - wall) per run;
@@ -297,10 +306,12 @@ _SCHEMA = {
                                   # (ops.segment_reduce by a label
                                   # function on a streamed source; 0
                                   # where it materialised)
-    "stream_thin_slabs": 0,       # slabs of thin records that went up as
-                                  # a dense view of their bytes and were
-                                  # re-seated by their slab program
-                                  # (stream.thin_records)
+    "stream_thin_slabs": 0,       # slabs that went up as a dense view of
+                                  # their bytes and were re-seated by
+                                  # their slab program: thin records
+                                  # (stream.thin_records) and elements
+                                  # narrower than 32 bits as their words
+                                  # (stream.narrow_words)
     # a Gram matrix folded slab by slab (ops.pca / ops.cov on a streamed
     # source: stream.maybe_gram) and pca's second pass
     "stream_gram_slabs": 0,       # slabs folded by the Gram terminal (0
@@ -986,12 +997,15 @@ def _link_fresh(start, end):
     return max(fresh, 0.0)
 
 
-def record_transfer(nbytes, seconds, parts=0):
+def record_transfer(nbytes, seconds, parts=0, elements=0):
     """Tally one counted host->device transfer that took ``seconds`` and
     ended now (bolt_tpu.stream.transfer is the only caller — lint rule
     BLT105 keeps it that way).  ``parts``: the per-device sub-blocks an
     uploaded SLAB was put as (``stream_upload_parts``; 0 for any other
-    transfer).  ``transfer_copy_seconds`` gets the copy's
+    transfer).  ``elements``: how many elements the bytes were
+    (``transfer_elements``); a slab of fewer than four bytes an element
+    is a narrow one (``stream_narrow_slabs``).
+    ``transfer_copy_seconds`` gets the copy's
     own seconds; ``transfer_seconds`` only the part of them during which
     no other counted copy was in flight, so it is the link's busy time:
     the same number wherever copies never overlap.  The link is the
@@ -1002,7 +1016,10 @@ def record_transfer(nbytes, seconds, parts=0):
     _COUNTERS.update(transfer_bytes=int(nbytes),
                      transfer_seconds=busy,
                      transfer_copy_seconds=seconds,
-                     stream_upload_parts=int(parts))
+                     stream_upload_parts=int(parts),
+                     transfer_elements=int(elements),
+                     stream_narrow_slabs=int(
+                         bool(parts) and nbytes < 4 * elements))
     _TRANSFER_HIST.observe(int(nbytes))
 
 
@@ -1081,7 +1098,8 @@ def record_stream(chunks, ingest_s, compute_s, wall_s, overlap_s, depth,
     (and the depth) keep process maxima.  ``keyed``: of ``chunks``, the
     slabs whose program took the slab's first key as an operand; ``group``:
     those a grouped terminal folded; ``thin``: those that went up dense
-    and were re-seated on the device; ``gram``: those the Gram terminal
+    (thin records, or a narrower element's words) and were re-seated on
+    the device; ``gram``: those the Gram terminal
     folded, ``gram_kernel`` of them by a program lowered with the
     ``packed_gram`` kernel; ``windowed``: those whose place call the swap
     / collect resolver dispatched while an earlier one was still

@@ -430,8 +430,9 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
     (PERF.md, PR 32).  Unsigned, because a signed index is first
     wrapped (``select(i < 0, i + n, i)``), which hides those bits.
 
-    ``thin``: ``data`` is the dense form of a slab of thin records
-    (``stream._dense_views``), given its shape ``raw_slab_shape`` by
+    ``thin``: ``data`` is the dense form of a slab (``stream._dense_views``:
+    thin records, or the words of elements narrower than 32 bits), given
+    its shape ``raw_slab_shape`` and its element ``raw_dtype`` by
     ``stream._reseat`` first, as a fold's slab program is handed it."""
     unit = int(unit)
     key = _program_key("stream-shuffle-place", plan, pre_stages, mesh,
@@ -452,7 +453,7 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
             first = cursor * jnp.uint32(unit)
             at = tuple(first if i == j0 else zero
                        for i in range(out.ndim))
-            block = body(_reseat(data) if thin else data,
+            block = body(_reseat(data, raw_dtype) if thin else data,
                          first.astype(jnp.int32) if keyed else None,
                          operands)
             out = jax.lax.dynamic_update_slice(out, block, at)

@@ -20,8 +20,10 @@ a recorded swap).  Every streamed run is the same four pieces::
   uploaded slabs.  For random-access ``fromcallback`` sources each of N
   workers produces AND uploads its own slab (per-device sub-blocks via
   ``parallel.sharding.device_placements``; a slab of THIN records for
-  one device as a dense view of its bytes, which its slab program
-  re-seats: :func:`thin_records`), so one CPU thread is never
+  one device as a dense view of its bytes, and a slab of elements
+  NARROWER than 32 bits as the 32-bit words it already is, which its
+  slab program re-seats: :func:`thin_records`, :func:`narrow_words`), so
+  one CPU thread is never
   the bottleneck feeding many chips; sequential ``fromiter`` sources
   keep one produce+upload thread.  A **re-sequencer** (:class:`_Reseq`)
   hands slabs to the consumer strictly in slab order whatever order the
@@ -541,13 +543,16 @@ def swap_budget(mesh=None):
 def place_budget(source):
     """:func:`swap_budget` as the resident leg over ``source`` can spend
     it: less the one thing its place program holds that the plan does not
-    count, the re-seat's copies of a slab of thin records
+    count, the re-seat's copies of a slab that went up dense
     (:func:`dense_route`; two: rows of 64 are re-seated through two
     copies of the slab, compiled for the v5e, rows of seven through
-    one)."""
+    one, and the words of narrower elements are unpacked inside the
+    update's fusion beside four bytes an element of temporaries: two
+    slabs of 16-bit frames, four of 8-bit ones)."""
     budget = swap_budget(source.mesh)
     if budget is not None and dense_route(source):
-        budget -= 2 * _raw_slab_bytes(source)
+        budget -= max(2, 4 // source.dtype.itemsize) \
+            * _raw_slab_bytes(source)
     return budget
 
 
@@ -632,12 +637,13 @@ def transfer(x, sharding=None, wait=False):
             else jax.device_put(x)
         if host:
             out.block_until_ready()
-            nbytes = getattr(x, "nbytes", None)
-            if nbytes is None:
-                nbytes = np.asarray(x).nbytes
-            _engine.record_transfer(int(nbytes), _clock() - t0)
+            if not hasattr(x, "nbytes"):
+                x = np.asarray(x)
+            nbytes = int(x.nbytes)
+            _engine.record_transfer(nbytes, _clock() - t0,
+                                    elements=int(x.size))
             if sp is not None:
-                sp.set(bytes=int(nbytes), wait=wait)
+                sp.set(bytes=nbytes, wait=wait, dtype=str(x.dtype))
     finally:
         _obs.end(sp)
     return out
@@ -669,23 +675,57 @@ def thin_records(shape, dtype):
     return len(shape) == 2 or int(shape[1]) % _LANES == 0
 
 
+def narrow_words(shape, dtype):
+    """Whether slabs of a source of ``shape``/``dtype`` go up as the
+    32-bit WORDS they already are on the host: integer elements of one or
+    two bytes (a camera's ``uint16`` words, ``int16``, ``uint8``) whose
+    rows, the last axis, are whole words.  The device tiles such an array
+    with PAIRS (or fours) of rows packed into one 32-bit sublane
+    (``u16[128,512,512]{2,1,0:T(8,128)(2,1)}``), which no row-major host
+    block is, and the runtime interleaves the loader's block on the HOST
+    on its way there: 9.5 GB/s with two copies in flight for ``(128, 512,
+    512)`` ``uint16`` (5.1 with one; ``uint8`` the same) where the same
+    bytes as a ``uint32`` view, or as float32, go up at 13.98
+    (``scripts/narrow_h2d_probe.py``: PERF.md section 6, PR 59).  The
+    words pad nothing and need no host pass; the slab or place program
+    unpacks them on the device (:func:`_reseat`).  The rule reads the
+    record alone: nothing a caller sets."""
+    dtype = np.dtype(dtype)
+    return (dtype.kind in "iu" and dtype.itemsize < 4 and len(shape) >= 2
+            and int(shape[-1]) * dtype.itemsize % 4 == 0)
+
+
 def dense_route(source):
     """Whether ``source``'s slabs go up as :func:`_dense_views` of them
-    and are re-seated by their slab or place program: thin records for
-    ONE device (a codec's wire form and a pod's shards keep what they
-    had)."""
-    return (thin_records(source.shape, source.dtype)
+    and are re-seated by their slab or place program: thin records, or
+    elements narrower than 32 bits in rows of whole words, for ONE device
+    (a codec's wire form and a pod's shards keep what they had)."""
+    return ((thin_records(source.shape, source.dtype)
+             or narrow_words(source.shape, source.dtype))
             and source.mesh.devices.size == 1
             and _multihost.mesh_process_count(source.mesh) <= 1
             and resolve_codec(source) is None)
 
 
+def _goes_dense(block):
+    """Whether THIS block of a :func:`dense_route` source can be viewed
+    dense: C-contiguous, and of thin rows at least one group of 128."""
+    if not block.flags.c_contiguous:
+        return False
+    return (block.dtype.itemsize < 4 or block.ndim == 3
+            or block.shape[0] >= _LANES)
+
+
 def _dense_views(block):
-    """A C-contiguous slab of thin records as zero-copy views that pad
-    nothing.  Rows ``(n, c)``: the whole groups of 128 rows as ``(n //
-    128, 128 * c)``, then the rows past them as they are (under 128 of
-    them: once a slab, a few KB).  Planes ``(k, N, c)``: every plane's
-    groups, ``(k, N // 128, 128 * c)``."""
+    """A C-contiguous slab that goes up dense as zero-copy views that pad
+    nothing.  Elements narrower than 32 bits (:func:`narrow_words`): the
+    block as ``uint32`` words, the last axis a half or a quarter as long.
+    Thin rows ``(n, c)``: the whole groups of 128 rows as ``(n // 128,
+    128 * c)``, then the rows past them as they are (under 128 of them:
+    once a slab, a few KB).  Planes ``(k, N, c)``: every plane's groups,
+    ``(k, N // 128, 128 * c)``."""
+    if block.dtype.itemsize < 4:
+        return [block.view(np.uint32)]
     if block.ndim == 3:
         k, n, c = block.shape
         return [block.reshape(k, n // _LANES, _LANES * c)]
@@ -697,10 +737,13 @@ def _dense_views(block):
     return views
 
 
-def _dense_shape(parts):
-    """The shape of the slab that :func:`_dense_views`' uploads ``parts``
-    are the bytes of."""
+def _dense_shape(parts, dtype=None):
+    """The shape of the slab of ``dtype`` (default: the parts' own) that
+    :func:`_dense_views`' uploads ``parts`` are the bytes of."""
     dense = parts[0]
+    if dtype is not None and dense.dtype != dtype:  # a narrower one's words
+        return dense.shape[:-1] + (
+            dense.shape[-1] * 4 // np.dtype(dtype).itemsize,)
     if dense.ndim == 3:
         return (dense.shape[0], dense.shape[1] * _LANES,
                 dense.shape[2] // _LANES)
@@ -708,14 +751,23 @@ def _dense_shape(parts):
             dense.shape[1] // _LANES)
 
 
-def _reseat(parts):
-    """The slab of :func:`_dense_views`' uploads, traced as the slab
-    program's first operation: ONE copy of a slab on the device (0.7 ms of
-    64 MiB of rows of seven, 2.5 ms of a 268 MB plane, beside 4.9 and 21
-    ms of link; a plain ``reshape`` to the slab's shape goes through the
-    row-major tiled form, the row padded to 128 lanes, and costs GBs of
-    temp: compiled for the v5e, ISSUEs 51 and 55)."""
+def _reseat(parts, dtype=None):
+    """The slab of ``dtype`` (default: the parts' own) of
+    :func:`_dense_views`' uploads, traced as the slab program's first
+    operation: ONE copy of a slab on the device
+    (0.7 ms of 64 MiB of rows of seven, 2.5 ms of a 268 MB plane, beside
+    4.9 and 21 ms of link; a plain ``reshape`` to the slab's shape goes
+    through the row-major tiled form, the row padded to 128 lanes, and
+    costs GBs of temp: compiled for the v5e, ISSUEs 51 and 55).  The
+    words of narrower elements are unpacked (``bitcast_convert_type``:
+    the low bits first, as the host holds them) and given their row back;
+    in front of a re-axis XLA transposes the WORDS and unpacks inside the
+    update's fusion (compiled for the v5e, ISSUE 59)."""
     dense = parts[0]
+    if dtype is not None and dense.dtype != dtype:
+        with jax.named_scope("narrow_unpack"):
+            return jax.lax.bitcast_convert_type(dense, dtype).reshape(
+                _dense_shape(parts, dtype))
     if dense.ndim == 3:
         k, groups, c = dense.shape[0], dense.shape[1], \
             dense.shape[2] // _LANES
@@ -786,9 +838,10 @@ def _upload_slab_mh(block, mesh, split, slab_shape, axis0_off, dense=False):
             out = _sh.assemble_from_parts(slab_shape, sharding, parts)
             nparts = len(parts)
         nbytes = int(block.nbytes)
-        _engine.record_transfer(nbytes, _clock() - t0, parts=nparts)
+        _engine.record_transfer(nbytes, _clock() - t0, parts=nparts,
+                                elements=int(block.size))
         if sp is not None:
-            sp.set(bytes=nbytes, parts=nparts)
+            sp.set(bytes=nbytes, parts=nparts, dtype=str(block.dtype))
     finally:
         _obs.end(sp)
     return out
@@ -1999,7 +2052,7 @@ def _slab_program(source, terminal, slab_shape, fused=False, sharded=False,
             key0 = extra[0] if keyed else None
             operands = iter(extra[1:] if keyed else extra)
             if codec_obj is None:
-                x = _reseat(data) if thin else data
+                x = _reseat(data, raw_dtype) if thin else data
             elif codec_obj.sidecar:
                 x = codec_obj.decode(data[0], data[1:], raw_dtype, delta_ok)
             else:
@@ -2364,14 +2417,14 @@ class _IngestPool:
     programs retire, and ALWAYS calls :meth:`close`.  ``noun`` names a
     slab in the retry errors; ``parent`` is the run's span the
     ``stream.ingest`` spans nest under (nesting does not cross
-    threads).  ``dense``: the consumer's slab programs take a slab of
-    thin records as :func:`_dense_views` of it (:func:`thin_records`)."""
+    threads).  ``dense``: the consumer's slab programs take a slab as
+    :func:`_dense_views` of it (:func:`dense_route`)."""
 
     def __init__(self, run, source, ring, jobs=None, blocks=None, first=0,
                  noun="slab", parent=None, dense=False):
         self._run = run
         self._source = source
-        self._dense = dense     # the consumer re-seats thin slabs
+        self._dense = dense     # the consumer re-seats dense slabs
         self._jobs = jobs
         self._blocks = blocks or source.slabs
         self._first = first
@@ -2426,7 +2479,7 @@ class _IngestPool:
         it went up as :func:`_dense_views` of it, and the shape of the
         slab its program is built for."""
         if self._dense and isinstance(buf, tuple):
-            return True, _dense_shape(buf)
+            return True, _dense_shape(buf, self._source.dtype)
         return False, (buf[0].shape if isinstance(buf, tuple)
                        else buf.shape)
 
@@ -2504,9 +2557,7 @@ class _IngestPool:
             payload = block
         else:
             payload, side = _encode_slab(run.codec, block, run.delta_ok)
-        if run.mspec is None and self._dense \
-                and (payload.ndim == 3 or payload.shape[0] >= _LANES) \
-                and payload.flags.c_contiguous:
+        if run.mspec is None and self._dense and _goes_dense(payload):
             buf = _upload_slab(payload, source.mesh, source.split, True)
         elif run.mspec is None:
             # through the module-level name: the tests' patch point
@@ -3427,7 +3478,8 @@ def _materialize_base(source):
             return block
         data = jax.make_array_from_callback(shape, sharding, produce)
         _engine.record_transfer(
-            prod(shape) * source.dtype.itemsize, _clock() - t0)
+            prod(shape) * source.dtype.itemsize, _clock() - t0,
+            elements=prod(shape))
         return BoltArrayTPU(data, source.split, source.mesh)
     host = np.empty(shape, source.dtype)
     for lo, hi, block in source.slabs():
@@ -3452,7 +3504,7 @@ def _materialize_base(source):
                 seen.add(box)
                 local += prod([b - a for a, b in box])
         _engine.record_transfer(local * source.dtype.itemsize,
-                                _clock() - t0)
+                                _clock() - t0, elements=local)
         return BoltArrayTPU(data, source.split, source.mesh)
     data = transfer(host, sharding)
     return BoltArrayTPU(data, source.split, source.mesh)
@@ -3623,10 +3675,10 @@ def _resolve_one_swap(source, collect=False, project=False):
                         out_block=plan.out_block,
                         alltoall_bytes=plan.alltoall_bytes,
                         devices=plan.devices)
-    # thin records for ONE device go up dense and the place program
-    # re-seats them, as execute's slab programs do (the spill leg's
-    # program takes the slab as the loader hands it over); a one-shot
-    # iterable cannot resume, so `done` is empty without jobs
+    # thin records and narrow elements for ONE device go up dense and
+    # the place program re-seats them, as execute's slab programs do (the
+    # spill leg's program takes the slab as the loader hands it over); a
+    # one-shot iterable cannot resume, so `done` is empty without jobs
     pool = _IngestPool(run, base, plan.ring, jobs=jobs,
                        noun="shuffle slab", parent=run_sp,
                        dense=plan.resident and dense_route(base))
@@ -3711,7 +3763,8 @@ def _resolve_one_swap(source, collect=False, project=False):
             t0 = _clock()
             thin, wshape = pool.form(buf)
             nthin += thin
-            csp = _obs.begin("stream.compute", slab=g, shuffle=True)
+            csp = _obs.begin("stream.compute", slab=g, shuffle=True,
+                             dtype=str(source.dtype))
             attempt = 0
             prev = None
             try:
@@ -3809,7 +3862,8 @@ def _resolve_one_swap(source, collect=False, project=False):
     # files — it streams through the SAME slab-program machinery as
     # any other source (execute/materialize/retries/arbiter/resume all
     # inherited), with the post-swap stages riding lazily
-    nslabs = plan.nslabs
+    # an iterator's slabs are the blocks it yielded, however many
+    nslabs = plan.nslabs if base.kind == "callback" else placed
     out_shape = plan.out_shape
     out_block = plan.out_block
     j0 = plan.j0

@@ -285,7 +285,8 @@ class ConstructTPU:
         t0 = _clock()
         data = jax.make_array_from_callback(shape, sharding, produce)
         from bolt_tpu import engine as _engine
-        _engine.record_transfer(data.nbytes, _clock() - t0)
+        _engine.record_transfer(data.nbytes, _clock() - t0,
+                                elements=data.size)
         return BoltArrayTPU(data, split, mesh)
 
     @staticmethod

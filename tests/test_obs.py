@@ -339,6 +339,7 @@ _EXPECTED_ENGINE_KEYS = {
     "stream_alltoall_bytes": False, "stream_upload_parts": False,
     "stream_windowed_slabs": False,
     "stream_early_retired_slabs": False,
+    "transfer_elements": False, "stream_narrow_slabs": False,
 }
 
 

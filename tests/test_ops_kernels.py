@@ -1343,6 +1343,81 @@ def test_place_program_aliases_its_output_on_v5e(v5e_device, frames):
     assert not double.resident
 
 
+# the same session as the camera wrote it (ISSUE 59): 20,480 frames of
+# 16-bit words in the bytes of the float32 one.  The slab, its transposed
+# block and the array keep the element through the compiler too (no widen-
+# transpose-narrow, no float32 of a slab), the update stays in place, and
+# the block of a 64 MiB slab leaves HBM's temporaries altogether.  Handed
+# the slab as its 32-bit WORDS (the dense route of one device: the link
+# carries those at its rate and interleaves the loader's block on the host
+# at two thirds of it), the compiler transposes the words, half the
+# elements, and unpacks inside the update's fusion beside four bytes an
+# element of temporaries, which ``stream.place_budget`` takes off the budget
+_NARROW_SESSION = (20480, 512, 512)
+
+
+@pytest.mark.parametrize("words", [False, True],
+                         ids=["as-loaded", "as-words"])
+@pytest.mark.parametrize("dtype,tile", [("uint16", "(2,1)"),
+                                        ("int16", "(2,1)"),
+                                        ("uint8", "(4,1)")])
+def test_place_program_keeps_a_narrow_element_on_v5e(v5e_device, dtype,
+                                                     tile, words):
+    import warnings
+    import jax
+    from bolt_tpu.parallel import shuffle
+    mesh = _series_mesh(v5e_device)
+    frames, item = 128, np.dtype(dtype).itemsize
+    plan = shuffle.plan_shuffle(_NARROW_SESSION, dtype, 1, (1, 2, 0), 2,
+                                mesh, frames, int(16.9e9), None, ring=5)
+    assert plan.out_shape == (512, 512, 20480) and plan.resident
+    slab_shape = (frames,) + _NARROW_SESSION[1:]
+    program = shuffle.place_program(plan, (), mesh, None, np.dtype(dtype),
+                                    slab_shape, True, frames, words)
+    where = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+    slab = jax.ShapeDtypeStruct(slab_shape, dtype, sharding=where)
+    if words:
+        slab = (jax.ShapeDtypeStruct((frames, 512, 128 * item), np.uint32,
+                                     sharding=where),)
+    with jax.enable_x64(False), warnings.catch_warnings():
+        warnings.filterwarnings("ignore",
+                                message="Some donated buffers were not")
+        compiled = program.lower(
+            jax.ShapeDtypeStruct(plan.out_shape, dtype, sharding=where),
+            slab,
+            jax.ShapeDtypeStruct((), np.uint32, sharding=where)).compile()
+    mem, text = compiled.memory_analysis(), compiled.as_text()
+    out_bytes = item * int(np.prod(_NARROW_SESSION))
+    slab_bytes = item * int(np.prod(slab_shape))
+    assert out_bytes == 10737418240 * item // 2
+    assert out_bytes <= mem.output_size_in_bytes < out_bytes + 4096
+    assert out_bytes <= mem.alias_size_in_bytes          # in place
+    assert mem.argument_size_in_bytes < out_bytes + slab_bytes + 4096
+    # the words' unpack holds four bytes an element beside the slab
+    spare = 4 // item if words else 1
+    assert mem.temp_size_in_bytes <= spare * slab_bytes
+    # what the plan counts (the stored bytes an element) covers what is
+    # held, with what place_budget takes off for a dense route
+    assert plan.resident_bytes == out_bytes + 6 * slab_bytes
+    assert plan.resident_bytes + words * spare * slab_bytes >= (
+        mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        + 4 * slab_bytes)
+    assert plan.resident_bytes + spare * slab_bytes < 12e9
+    # the element all the way: packed sublanes, no float32, the offset's
+    # low bits known, ONE copy of a slab's size (the transpose: of the
+    # elements as loaded, of the words where those went up)
+    assert not re.search(r"\bf(16|32|64)\[", text)        # nothing widens
+    assert "T(8,128)%s" % tile in text
+    assert '"zeroes":"%d"' % (frames - 1) in text
+    entry = _computations(text)["ENTRY"]
+    big = [ln for ln in entry if " copy(" in ln and "[128,512," in ln]
+    assert len(big) == 1
+    assert ("u32[128,512,%d]" % (128 * item) in big[0]) is words
+    if not words:
+        assert "[128,512,512]{2,1,0:T(8,128)}" not in text
+        assert sum(" fusion(" in ln for ln in entry) == 1
+
+
 # ---------------------------------------------------------------------
 # compile-only: the per-pixel series analysis that follows toseries
 # (configuration pixelseries512-1chip, PR 36): ops.normalize -> detrend
