@@ -467,7 +467,18 @@ def place_program(plan, pre_stages, mesh, codec_obj, raw_dtype,
 LANES = 128          # the minor-axis tile of a TPU array, in elements
 
 
-def lane_slab(slab, records, record_bytes, perm, ceiling):
+def tile_width(mesh, shape, split):
+    """:func:`lane_slab`'s ``width``: how many devices shard the record
+    axis of a slab of ``shape[1:]`` records — asked of a slab of one lane
+    tile a device of the mesh, which every device can take a part of, so
+    the answer does not hang on how many records the default slab
+    happened to hold and stands for every slab drawn at that width."""
+    ndev = int(mesh.devices.size) if mesh is not None else 1
+    return _axis0_device_width(
+        mesh, (LANES * ndev,) + tuple(shape[1:]), split)
+
+
+def lane_slab(slab, records, record_bytes, perm, ceiling, width):
     """Records a slab for a streamed swap whose caller chose none: where
     the old record axis lands MINOR (``perm`` ends in 0) a slab's block
     is ``slab`` elements wide on the lane axis, and one that is not a
@@ -476,10 +487,20 @@ def lane_slab(slab, records, record_bytes, perm, ceiling):
     unless that many records pass ``ceiling`` bytes (records so fat
     that a tile of them is no slab any more).  On the chip, 512 x 512
     float32 frames: 128 a slab re-axed 9 % more bytes a second than the
-    default 64, at half the device time (PERF.md, PR 32)."""
-    if perm[-1] != 0 or slab % LANES == 0:
+    default 64, at half the device time (PERF.md, PR 32).
+
+    ``width`` devices shard a slab's record axis (:func:`tile_width`):
+    the tiles and the ceiling are then a DEVICE's, because a device's
+    part is what one link carries in one copy and what one device
+    holds of the ring — on the four-chip host a slab of whole tiles a
+    device is a quarter of the place calls and a copy four times as
+    large a link, 128 MiB where the mesh-wide slab's was 32 (PERF.md,
+    PR 60).  The devices divide the slab; at ``width`` 1 this is the
+    one-device rule to the digit."""
+    tile = LANES * width
+    if perm[-1] != 0 or slab % tile == 0:
         return slab
-    whole = -(-slab // LANES) * LANES
-    if whole * record_bytes > ceiling:
+    whole = -(-slab // tile) * tile
+    if whole * record_bytes > ceiling * width:
         return slab
     return min(whole, max(int(records), 1))

@@ -1299,9 +1299,9 @@ def swap_stage(arr, perm, new_split):
     source (per-process bucket ownership needs random access).
 
     Where the caller gave no ``chunks`` and the record axis lands minor,
-    the slab is re-drawn to whole lane tiles
-    (``parallel.shuffle.lane_slab``: a rule, no knob)."""
-    from bolt_tpu.parallel.shuffle import lane_slab
+    the slab is re-drawn to whole lane tiles a device that shards it
+    (``parallel.shuffle.lane_slab``: a rule on the mesh, no knob)."""
+    from bolt_tpu.parallel.shuffle import lane_slab, tile_width
     from bolt_tpu.tpu.array import BoltArrayTPU
     src = arr._stream
     st = result_state(src)
@@ -1317,7 +1317,8 @@ def swap_stage(arr, perm, new_split):
     if src.auto_slab and src.kind == "callback" and not has_swap(src):
         slab = lane_slab(src.slab, src.shape[0],
                          prod(src.shape[1:]) * src.dtype.itemsize, perm,
-                         2 * _SLAB_BYTES)
+                         2 * _SLAB_BYTES,
+                         tile_width(src.mesh, src.shape, src.split))
     return BoltArrayTPU._streamed(
         src.with_stage(("swap", tuple(int(p) for p in perm),
                         int(new_split)), slab=slab))

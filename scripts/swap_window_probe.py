@@ -4,7 +4,7 @@ worth in a streamed cell, from ONE process on the chip.
 
     python3 scripts/swap_window_probe.py <cell> --seed N
         [--windows 1 2 3 4] [--passes 3] [--rounds 2]
-        [--confirms early blocking] [--tiny]
+        [--confirms early blocking] [--chunks 128 256 ...] [--tiny]
 
 The cell's operand is built once (the host tile), then its one request is
 repeated under bolt's tracer alone (``obs.enable()``, no profiler) at each
@@ -15,8 +15,13 @@ that W IS the resolver's window and ``execute``'s, ring W + pool
 caller's depth 2 a window of 3); the rounds walk the windows up and then
 down.  ``--confirms blocking`` repeats each window with ``stream._retired``
 answering no, so that a confirm is made only once the window is over
-(``execute`` before PR 58, the resolver's early retirement off).  A JSON
-line a (round, W, confirm): the requests' walls, GB/s streamed, the link's
+(``execute`` before PR 58, the resolver's early retirement off).
+``--chunks`` repeats each window with the cell's ``fromcallback`` given
+the caller's own ``chunks=`` (records a slab; 0 is the cell's own call,
+which gives none), every round starting one setting further along; a
+setting that fails (a slab the device has no room for) is a line with
+its ``error`` and the probe goes on.  A JSON line a (round, W, confirm,
+chunks): the requests' walls, GB/s streamed, the link's
 own account (``transfer_seconds``), the consumer's and the pool's waits as
 shares of the wall and per span (a folded pass is its ``stream.run``, a
 placed one its ``stream.shuffle`` / ``stream.collect``), and the windows'
@@ -24,10 +29,13 @@ counters (``stream_windowed_slabs``, ``stream_early_retired_slabs``, the
 high-water, a process maximum).  What PERF.md section 5's window tables
 were read from (the resolver's, PR 56; ``scan_pca``'s Gram pass and
 ``stream`` in ``execute``'s, PR 58, beside ``stream_depth_probe.py``'s Q6
-and Q1); ``--tiny`` rehearses at ``benchmark/tests``' toy sizes on any
-device.  Runs in no cell of the benchmark; a cell of one request a cycle
-(``toseries``, ``toseries4``, ``register``, ``scan_pca``, ``stream``)."""
+and Q1) and its table of ``toseries4``'s slabs (``--windows 3 --chunks
+128 256 512 1024``, PR 60); ``--tiny`` rehearses at ``benchmark/tests``'
+toy sizes on any device.  Runs in no cell of the benchmark; a cell of one
+request a cycle (``toseries``, ``toseries4``, ``register``, ``scan_pca``,
+``stream``)."""
 import argparse
+import functools
 import itertools
 import json
 import os
@@ -59,6 +67,8 @@ def main():
     ap.add_argument("--rounds", type=int, default=2)
     ap.add_argument("--confirms", nargs="+", default=["early"],
                     choices=["early", "blocking"])
+    ap.add_argument("--chunks", type=int, nargs="+", default=[0],
+                    help="records a slab as the caller's chunks= (0: none)")
     ap.add_argument("--tiny", action="store_true",
                     help="benchmark/tests' toy sizes, on whatever device")
     args = ap.parse_args()
@@ -81,6 +91,9 @@ def main():
     fetch = man.module("fetches", kinds[k]["fetch"]).take
     operand = cell.operand
 
+    import bolt_tpu as bolt
+    from_callback = bolt.fromcallback
+
     def request():
         out = fetch(call(operand.operand()))
         del out
@@ -89,19 +102,35 @@ def main():
     request()
     for rnd in range(args.rounds):
         order = args.windows if rnd % 2 == 0 else args.windows[::-1]
-        for w, confirm in itertools.product(order, args.confirms):
+        at = rnd % len(args.chunks)
+        slabs = args.chunks[at:] + args.chunks[:at]
+        for w, confirm, chunks in itertools.product(order, args.confirms,
+                                                    slabs):
             stream._retired = (is_done if confirm == "early"
                                else lambda handle: False)
             stream.set_prefetch_depth(w)
-            request()                   # settle at this depth
+            # the operand's own call, with the caller's chunks= beside it
+            bolt.fromcallback = from_callback if not chunks else \
+                functools.partial(from_callback, chunks=chunks)
             obs.clear()
-            obs.enable()
-            c0 = engine.counters()
-            walls = []
-            for _ in range(args.passes):
-                t0 = time.perf_counter()
-                request()
-                walls.append(time.perf_counter() - t0)
+            try:
+                request()               # settle at this depth and slab
+                obs.enable()
+                c0 = engine.counters()
+                walls = []
+                for _ in range(args.passes):
+                    t0 = time.perf_counter()
+                    request()
+                    walls.append(time.perf_counter() - t0)
+            except Exception as e:      # noqa: BLE001 (reported, not hidden)
+                obs.disable()
+                obs.clear()
+                print(json.dumps({
+                    "cell": args.cell, "round": rnd, "window": w,
+                    "confirm": confirm, "chunks": chunks,
+                    "error": "%s: %s" % (type(e).__name__, str(e)[:400]),
+                    "peak_GB": cell.memory_peak() / 1e9}), flush=True)
+                continue
             c1 = engine.counters()
             totals = obs.totals()
             # the folded pass's own spans (the consumer's thread: under
@@ -121,7 +150,7 @@ def main():
             up = d["transfer_bytes"]
             row = {
                 "cell": args.cell, "round": rnd, "window": w,
-                "confirm": confirm,
+                "confirm": confirm, "chunks": chunks,
                 "walls_s": [round(x, 4) for x in walls],
                 "GBps": up / wall / 1e9,
                 "upload_GBps": up / max(d["transfer_seconds"], 1e-9) / 1e9,

@@ -1788,8 +1788,16 @@ def _blocked_estimate(funcs, block):
 # updated in place, at an offset whose low bits the compiler knows
 # ---------------------------------------------------------------------
 
+@pytest.mark.parametrize("slab,block", [
+    # a caller's chunks=128 (the default before ISSUE 60): a chip's quarter
+    # of the slab, the frames whole sublane tiles, the 512 of y on the lanes
+    (128, r"f32\[4,128,512,32\]\{2,3,1,0:T\(8,128\)[^}]*\}"),
+    # the default since ISSUE 60, a lane tile of frames a chip: the frames
+    # themselves on the lanes, whole tiles of them
+    (512, r"f32\[4,128,512,128\]\{3,2,1,0:T\(8,128\)[^}]*\}"),
+], ids=["128-frames", "512-frames-the-default"])
 def test_place_program_on_four_chips_is_one_all_to_all_and_in_place(
-        v5e_device):
+        v5e_device, slab, block):
     import re
     import jax
     from jax.experimental import topologies
@@ -1799,9 +1807,15 @@ def test_place_program_on_four_chips_is_one_all_to_all_and_in_place(
     topo = topologies.get_topology_desc(topology_name="v5e:2x2",
                                         platform="tpu")
     mesh = jax.sharding.Mesh(np.asarray(topo.devices[:4]), ("k",))
-    frames, slab, frame = 40960, 128, (512, 512)
+    from bolt_tpu import stream
+    frames, frame = 40960, (512, 512)
+    if slab == 512:                     # what the rule draws on this mesh
+        assert slab == shuffle.lane_slab(
+            stream._SLAB_BYTES // (512 * 512 * 4), frames, 512 * 512 * 4,
+            (1, 2, 0), 2 * stream._SLAB_BYTES,
+            shuffle.tile_width(mesh, (frames,) + frame, 1))
     plan = shuffle.plan_shuffle((frames,) + frame, np.float32, 1, (1, 2, 0),
-                                2, mesh, slab, None, None, ring=6)
+                                2, mesh, slab, None, None, ring=7)
     assert not plan.sharded and plan.devices == 4 and plan.resident
     assert plan.alltoall_bytes == frames * 512 * 512 * 4 * 3 // 4
     slab_shape = (slab,) + frame
@@ -1824,10 +1838,9 @@ def test_place_program_on_four_chips_is_one_all_to_all_and_in_place(
                        r"collective-permute|reduce-scatter)(?:-start)?\(",
                        text)
     assert found == ["all-to-all"], found
-    # the exchange is of a chip's quarter of the slab, the frames whole
-    # sublane tiles and the 512 of y on the lanes
-    assert re.search(r"f32\[4,128,512,32\]\{2,3,1,0:T\(8,128\)[^}]*\} "
-                     r"all-to-all\(", text), "the exchanged block changed"
+    # the exchange is of a chip's quarter of the slab
+    assert re.search(block + r" all-to-all\(", text), \
+        "the exchanged block changed"
     stats = compiled.memory_analysis()
     per_chip = frames * 512 * 512 * 4 // 4
     assert stats.alias_size_in_bytes == per_chip      # updated in place

@@ -265,31 +265,130 @@ def test_iterator_blocks_land_at_their_own_offsets(mesh):
     assert np.array_equal(got, np.transpose(data, (1, 2, 0)))
 
 
-@pytest.mark.parametrize("case,default,chunks,kv,want_slab", [
-    ("record-axis-minor", 64, None, ((0,), (0, 1)), 128),
-    ("already-whole-tiles", 256, None, ((0,), (0, 1)), 256),
-    ("rounds-up-not-down", 200, None, ((0,), (0, 1)), 256),
-    ("record-axis-in-the-middle", 64, None, ((0,), (0,)), 64),
-    ("the-caller-chose", 64, 64, ((0,), (0, 1)), 64),
-    ("records-too-fat-for-a-tile", 20, None, ((0,), (0, 1)), 20),
-])
+def _slab_mesh(kind):
+    """The meshes the default slab is drawn on: one device, the four of a
+    one-process host as one axis (``tests/test_stream_swap4.py``'s), and
+    the same four as 2 x 2."""
+    devs = np.array(jax.devices()[:4])
+    if kind == "2x2":
+        return jax.sharding.Mesh(devs.reshape(2, 2), ("a", "b"))
+    return jax.sharding.Mesh(devs[:kind], ("k",))
+
+
+# (case, mesh, frames, key axes, default slab, chunks, swap, the slab): a
+# frame is 64 float32 (8 x 8, or 2 x 4 x 8 with the 2 a second key axis)
+_DEFAULT_SLABS = [
+    # one device: the rule as it was before it knew of a mesh (ISSUE 32)
+    ("record-axis-minor", 1, 300, 1, 64, None, ((0,), (0, 1)), 128),
+    ("already-whole-tiles", 1, 300, 1, 256, None, ((0,), (0, 1)), 256),
+    ("rounds-up-not-down", 1, 300, 1, 200, None, ((0,), (0, 1)), 256),
+    ("record-axis-in-the-middle", 1, 300, 1, 64, None, ((0,), (0,)), 64),
+    ("the-caller-chose", 1, 300, 1, 64, 64, ((0,), (0, 1)), 64),
+    ("records-too-fat-for-a-tile", 1, 300, 1, 20, None, ((0,), (0, 1)), 20),
+    # four devices shard a slab's records: tiles and ceiling a DEVICE
+    ("whole-tiles-a-device", 4, 1100, 1, 64, None, ((0,), (0, 1)), 512),
+    ("a-tile-a-mesh-is-no-tile-a-device", 4, 1100, 1, 128, None,
+     ((0,), (0, 1)), 512),
+    ("already-whole-tiles-a-device", 4, 1100, 1, 512, None,
+     ((0,), (0, 1)), 512),
+    ("rounds-up-not-down-a-device", 4, 1100, 1, 520, None,
+     ((0,), (0, 1)), 1024),
+    ("records-too-fat-for-a-tile-a-device", 4, 1100, 1, 20, None,
+     ((0,), (0, 1)), 20),
+    ("one-record-under-the-ceiling-a-device", 4, 1100, 1, 63, None,
+     ((0,), (0, 1)), 63),
+    ("a-recording-shorter-than-a-tile-a-device", 4, 300, 1, 64, None,
+     ((0,), (0, 1)), 300),
+    ("record-axis-in-the-middle-on-four", 4, 1100, 1, 64, None,
+     ((0,), (0,)), 64),
+    ("the-caller-chose-on-four", 4, 1100, 1, 64, 64, ((0,), (0, 1)), 64),
+    # 2 x 2 with two key axes: one mesh axis a key axis, so TWO devices
+    # shard the records of a slab and the other two hold the same
+    ("half-the-devices-shard-the-records", "2x2", 600, 2, 64, None,
+     ((0,), (0, 1)), 256),
+    # ... and with one key axis that axis takes the whole mesh
+    ("one-key-axis-takes-both-mesh-axes", "2x2", 1100, 1, 64, None,
+     ((0,), (0, 1)), 512),
+]
+
+
+@pytest.mark.parametrize(
+    "case,devices,frames,split,default,chunks,kv,want_slab",
+    _DEFAULT_SLABS, ids=[c[0] for c in _DEFAULT_SLABS])
 def test_default_slab_is_whole_lane_tiles_where_records_land_minor(
-        mesh, monkeypatch, case, default, chunks, kv, want_slab):
+        monkeypatch, case, devices, frames, split, default, chunks, kv,
+        want_slab):
     """A rule, no knob: with no ``chunks`` given, a swap whose record axis
-    lands minor re-draws the slab to whole lane tiles (128 records), unless
-    a tile of records is over twice the default slab's bytes."""
-    shape = (300, 8, 8)
+    lands minor re-draws the slab to whole lane tiles (128 records) for
+    every device that shards a slab's records (ISSUE 60), unless a tile of
+    records is over twice the default slab's bytes, a device."""
+    mesh = _slab_mesh(devices)
+    shape = (frames, 8, 8) if split == 1 else (frames, 2, 4, 8)
     data = (np.arange(np.prod(shape)) % 977).astype(np.float32).reshape(
         shape)
     monkeypatch.setattr(stream, "_SLAB_BYTES", default * 8 * 8 * 4)
     src = bolt.fromcallback(lambda idx: data[idx], shape, mesh,
-                            dtype=np.float32, chunks=chunks)
+                            axis=tuple(range(split)), dtype=np.float32,
+                            chunks=chunks)
     assert src._stream.slab == (chunks or default)
     s = src.swap(*kv)
     assert s._stream.slab == want_slab, case
     assert src._stream.slab == (chunks or default)   # the source keeps its
-    perm = (1, 2, 0) if kv[1] == (0, 1) else (1, 0, 2)
+    # ``swap``'s permutation as ``_do_swap`` builds it
+    kaxes, vaxes = kv
+    perm = tuple([k for k in range(split) if k not in kaxes]
+                 + [split + v for v in vaxes] + list(kaxes)
+                 + [split + v for v in range(len(shape) - split)
+                    if v not in vaxes])
     assert np.array_equal(np.asarray(s._data), np.transpose(data, perm))
+
+
+@pytest.mark.parametrize("kind,split,want", [
+    (None, 1, 1), (1, 1, 1), (4, 1, 4), (4, 2, 4), ("2x2", 1, 4),
+    ("2x2", 2, 2), (3, 1, 3),
+], ids=["no-mesh", "one-device", "four", "four-two-key-axes",
+        "2x2-one-key-axis", "2x2-two-key-axes", "three"])
+def test_tile_width_is_the_devices_that_shard_a_slab_of_tiles(kind, split,
+                                                              want):
+    """``lane_slab``'s width is read off the mesh on a slab every device
+    can take a tile of, so it does not depend on how many records the
+    default slab happened to hold; a slab drawn at that width is sharded
+    by exactly as many."""
+    from bolt_tpu.parallel import sharding as sh
+    from bolt_tpu.parallel import shuffle
+    mesh = None if kind is None else (
+        jax.sharding.Mesh(np.array(jax.devices()[:3]), ("k",))
+        if kind == 3 else _slab_mesh(kind))
+    shape = (1000, 8, 8) if split == 1 else (1000, 2, 4, 8)
+    width = shuffle.tile_width(mesh, shape, split)
+    assert width == want
+    rec = int(np.prod(shape[1:])) * 4
+    slab = shuffle.lane_slab(64, 10 ** 6, rec, (1, 2, 0), 128 * rec, width)
+    assert slab == shuffle.LANES * want
+    assert shuffle._axis0_device_width(mesh, (slab,) + shape[1:],
+                                       split) == want
+    if mesh is not None:
+        _, placed = sh.device_placements(mesh, (slab,) + shape[1:], split)
+        rows = {idx[0].indices(slab)[:2] for _, idx in placed}
+        assert len(rows) == want
+        assert all((hi - lo) == shuffle.LANES for lo, hi in rows)
+
+
+@pytest.mark.parametrize("slab,want", [
+    (64, 128), (128, 128), (200, 256), (256, 256), (20, 20), (63, 63),
+    (1, 1), (129, 256)])
+def test_lane_slab_at_width_one_is_the_rule_of_one_device(slab, want):
+    """``width`` 1 is the function as PR 32 wrote it, before it knew of a
+    mesh: these are its answers."""
+    from bolt_tpu.parallel import shuffle
+    rec = 256
+    assert shuffle.lane_slab(slab, 10 ** 6, rec, (1, 2, 0), 2 * slab * rec,
+                             1) == want
+    # short recordings cap it, a record axis elsewhere leaves it
+    assert shuffle.lane_slab(slab, 100, rec, (1, 2, 0), 2 * slab * rec,
+                             1) == (want if want == slab else 100)
+    assert shuffle.lane_slab(slab, 10 ** 6, rec, (1, 0, 2),
+                             2 * slab * rec, 4) == slab
 
 
 # ---------------------------------------------------------------------

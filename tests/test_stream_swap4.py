@@ -119,6 +119,57 @@ def test_streamed_swap_on_four_devices_is_numpys_transpose(mesh4, geometry,
     assert c1["spill_bytes"] == c0["spill_bytes"]
 
 
+# the caller who gives no ``chunks`` (ISSUE 60): the default slab of 64
+# frames is drawn a lane tile a DEVICE, 512 frames over the four; frames
+# and the sub-blocks each slab is put as (a short last slab that four do
+# not divide goes up whole to each, as any such slab does)
+_DEFAULT_SLAB = [
+    ("the-slab-divides-the-recording", 1024, [512, 512]),
+    ("a-short-last-slab", 1100, [512, 512, 76]),
+    ("a-short-last-slab-four-do-not-divide", 1101, [512, 512, 77]),
+    ("a-recording-of-one-short-slab", 300, [300]),
+]
+
+
+@pytest.fixture
+def default_64_frames(monkeypatch):
+    monkeypatch.setattr(stream, "_SLAB_BYTES", 64 * V0 * V1 * 4)
+
+
+@pytest.mark.parametrize("geometry", _DEFAULT_SLAB,
+                         ids=[g[0] for g in _DEFAULT_SLAB])
+def test_default_slab_on_four_devices_is_numpys_transpose(
+        mesh4, default_64_frames, geometry):
+    _, n, slabs = geometry
+    data = _data(n)
+    src = _source(data, mesh4, None)
+    assert src._stream.slab == 64
+    arr = src.swap((0,), (0, 1))
+    assert arr._stream.slab == slabs[0]
+    c0 = engine.counters()
+    out, spans = _traced(lambda: arr._data)
+    c1 = engine.counters()
+    want = np.transpose(data, (1, 2, 0))
+    assert out.dtype == want.dtype and np.array_equal(np.asarray(out), want)
+    assert {s.data.shape[0] for s in out.addressable_shards} == {V0 // 4}
+    run, = [sp for sp in spans if sp.name == "stream.shuffle"]
+    ups = [sp for sp in spans if sp.name == "stream.transfer"]
+    assert run.attrs["resident"] and run.attrs["slabs"] == len(slabs)
+    assert run.attrs["devices"] == 4
+    assert sorted(sp.attrs["bytes"] for sp in ups) \
+        == sorted(k * V0 * V1 * 4 for k in slabs)
+    assert [sp.attrs["parts"] for sp in ups] == [4] * len(slabs)
+    assert c1["stream_chunks"] - c0["stream_chunks"] == len(slabs)
+    assert c1["shuffle_bytes"] - c0["shuffle_bytes"] == data.nbytes
+    crossed = c1["stream_alltoall_bytes"] - c0["stream_alltoall_bytes"]
+    assert crossed == run.attrs["alltoall_bytes"] == data.nbytes * 3 // 4
+    assert c1["spill_bytes"] == c0["spill_bytes"]
+    # the uniform slab and the short one are a place program each, and a
+    # second pass compiles nothing
+    np.asarray(_source(data, mesh4, None).swap((0,), (0, 1))._data)
+    assert engine.counters()["aot_compiles"] == c1["aot_compiles"]
+
+
 def test_nothing_crosses_where_the_record_axis_stays_in_front(mesh4):
     """A swap among the value axes alone: every record keeps its device,
     and the model says so."""
@@ -200,16 +251,23 @@ def test_four_workers_finishing_out_of_order_are_re_sequenced(mesh4,
     assert 2 <= engine.counters()["stream_upload_threads"] <= 4
 
 
-def test_the_forecast_is_the_runs_plan(mesh4):
+@pytest.mark.parametrize("n,chunks,slab,nslabs", [
+    (26, 8, 8, 4), (1100, None, 512, 3), (1024, None, 512, 2)],
+    ids=["the-callers-chunks", "the-default-slab-a-tile-a-device",
+         "the-default-slab-divides"])
+def test_the_forecast_is_the_runs_plan(mesh4, default_64_frames, n, chunks,
+                                       slab, nslabs):
     """BLT017 says, before anything runs, what the run then does: the
     same plan (resident, how much over how many devices, how much across
-    them), word for word."""
-    data = _data(26)
-    arr = _source(data, mesh4, 8).swap((0,), (0, 1))
+    them), word for word; at the default slab too, which is drawn when the
+    swap is recorded and carried by the source the forecast reads."""
+    data = _data(n)
+    arr = _source(data, mesh4, chunks).swap((0,), (0, 1))
     rep = analysis.check(arr)
     d, = [x for x in rep.diagnostics if x.code == "BLT017"]
     assert d.severity == "info"
     src = arr._stream
+    assert src.slab == slab
     plan = shuffle.plan_shuffle(
         data.shape, data.dtype, 1, (1, 2, 0), 2, mesh4, src.slab,
         stream.swap_budget(mesh4), None, ring=stream.swap_ring(src))
@@ -221,13 +279,17 @@ def test_the_forecast_is_the_runs_plan(mesh4):
     assert "one all-to-all per slab across its 4 devices" in d.hint
     assert plan.ring == stream.prefetch_depth() \
         + stream._SWAP_WINDOW_STEP + 4
+    # the ring and the place program's temp are slabs of the slab drawn
+    assert plan.slab_bytes == slab * V0 * V1 * 4
+    assert plan.resident_bytes == data.nbytes \
+        + (plan.ring + 1) * plan.slab_bytes
     c0 = engine.counters()
     out, spans = _traced(lambda: arr._data)
     c1 = engine.counters()
     run, = [sp for sp in spans if sp.name == "stream.shuffle"]
     assert run.attrs["alltoall_bytes"] == plan.alltoall_bytes
     assert run.attrs["ring"] == plan.ring
-    assert run.attrs["slabs"] == plan.nslabs == 4
+    assert run.attrs["slabs"] == plan.nslabs == nslabs
     assert run.attrs["out_block"] == plan.out_block
     assert c1["stream_alltoall_bytes"] - c0["stream_alltoall_bytes"] \
         == plan.alltoall_bytes
