@@ -188,6 +188,13 @@ _SCHEMA = {
     "fused_stat_groups": 0,       # multi-terminal fused dispatches
     "fused_stat_terminals": 0,    # terminals served by those dispatches
                                   # (terminals - groups = dispatches saved)
+    "one_pass_moment_launches": 0,  # var/std terminals of real floating
+                                  # data DISPATCHED in the one-pass form
+                                  # (tpu/moments.py): one a standalone
+                                  # program, one a var/std member of a
+                                  # fused or batched dispatch (counts the
+                                  # FORM: over sharded key axes alone its
+                                  # pilot is the mean, a second read)
     "getitems_fused": 0,          # deferred getitem windows traced inside
                                   # a consumer's program (no slice program
                                   # or launch of their own)
@@ -796,6 +803,13 @@ def record_fused_stats(n_terminals):
     the timeline carries it as the ``array.multi_stat`` span."""
     _COUNTERS.update(fused_stat_groups=1,
                      fused_stat_terminals=int(n_terminals))
+
+
+def record_one_pass_moments(n=1):
+    """``n`` ``var``/``std`` terminals of real floating data were
+    dispatched in the one-pass shifted-moment form
+    (``tpu/moments.py``): the program reads what it reduces once."""
+    _COUNTERS.add("one_pass_moment_launches", n)
 
 
 def record_getitems_fused(n):

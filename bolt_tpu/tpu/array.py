@@ -59,6 +59,7 @@ from bolt_tpu.parallel import swapmerge as _swapmerge
 from bolt_tpu.parallel.sharding import key_sharding, key_spec, spec_names
 from bolt_tpu.tpu import blocks as _blocks
 from bolt_tpu.tpu import fold as _fold
+from bolt_tpu.tpu import moments as _onepass
 from bolt_tpu.utils import (argpack, check_value_shape as _check_value_shape,
                             inshape, isreshapeable, istransposeable, prod,
                             tupleize, with_operands)
@@ -2060,7 +2061,9 @@ class BoltArrayTPU(BoltArray):
         # as a PendingStat handle — validation/strict/donation stay
         # eager here, only the dispatch moves to the first read, and
         # handles sharing this source fuse into ONE single-pass program
-        # (bolt.compute / a.stats(...)).  NotImplemented falls through
+        # (bolt.compute / a.stats(...); single-pass with a var/std of
+        # real floating data in it too: tpu/moments.py's shifted
+        # moments, where jnp.var read twice).  NotImplemented falls through
         # to the eager paths (consumed sources, zero-size extrema,
         # geometries the fused machinery does not serve).
         from bolt_tpu.tpu import multistat as _ms
@@ -2098,15 +2101,10 @@ class BoltArrayTPU(BoltArray):
         base, funcs = self._chain_parts()
 
         def build():
-            op = {"mean": jnp.mean, "var": jnp.var, "std": jnp.std,
-                  "sum": jnp.sum, "max": jnp.max, "min": jnp.min,
-                  "prod": jnp.prod, "all": jnp.all, "any": jnp.any,
-                  "ptp": jnp.ptp}[name]
-            kwargs = {} if ddof is None else {"ddof": ddof}
-
             def stat(data):
                 mapped = _chain_apply(funcs, split, data)
-                out = op(mapped, axis=axes, keepdims=keepdims, **kwargs)
+                out = _ms._stat_expr(mapped, name, axes, keepdims, ddof,
+                                     None, mesh, split)
                 return _constrain(out, mesh, new_split)
             return jax.jit(stat, donate_argnums=(0,) if donate else ())
 
@@ -2115,6 +2113,8 @@ class BoltArrayTPU(BoltArray):
         with _obs.span("array.stat", op=name, donate=donate,
                        **_span_funcs(funcs)):
             out = self._wrap(fn(_check_live(base)), new_split)
+        if name in ("var", "std") and _onepass.one_pass(self.dtype):
+            _engine.record_one_pass_moments()
         if donate:
             self._consume_donated("%s()" % name)
         return out
@@ -2144,8 +2144,10 @@ class BoltArrayTPU(BoltArray):
 
         ``mean``/``var``/``std`` divide by the masked COUNT (computed in
         the same pass); var uses the one-pass moment form
-        ``(Σx² − (Σx)²/n)/(n−ddof)`` — single HBM read, documented as
-        slightly less cancellation-robust than the two-pass eager form."""
+        ``(Σx² − (Σx)²/n)/(n−ddof)`` — single HBM read, UNSHIFTED, so
+        less cancellation-robust than the unfiltered terminal's form
+        (``tpu/moments.py``: also one read, about a pilot mean): its
+        error scales with ``var + mean²``."""
         vshape = tuple(self._fpending.out.shape)
         ndim = 1 + len(vshape)
         if axis is None:

@@ -352,7 +352,8 @@ def dispatch(batch, buckets, record=True):
         nsplits = (split if keepdims else 0,)
     elif kind == "stat":
         def expr(d):
-            return _ms._chain_stat_exprs(d, funcs, split, slots, None)
+            return _ms._chain_stat_exprs(d, funcs, split, slots, None,
+                                         mesh)
         nsplits = tuple(_ms._new_split(split, s[1], s[2]) for s in slots)
     else:
         from bolt_tpu.tpu.array import _chain_apply
@@ -397,6 +398,8 @@ def dispatch(batch, buckets, record=True):
                             mx.shape, mx.dtype, mesh)(mx, mn)
                     else:
                         m.result = lane[index[_ms._slot(m)[0]]]
+                if rfunc is None:
+                    _ms._record_one_pass(g)
                 g.dispatched = True
                 g.claimed = False
                 ev = g.claim_event

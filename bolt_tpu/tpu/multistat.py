@@ -13,6 +13,17 @@ ONE tuple-output program::
     s, v, lo, hi = bolt.compute(a.sum(), a.var(), a.min(), a.max())
     # map/filter stages applied once, four partials from ONE HBM pass
 
+ONE pass holds for a group with a ``var``/``std`` member of real
+floating data since PR 61: they are traced as shifted moments about a
+pilot mean known before the pass (``tpu/moments.py``; error scales with
+``var + (mean - pilot)**2``), sibling reductions of the other members.
+Before it ``jnp.var``'s centred second moment made such a group TWO
+reads, which it still is for complex, integer and boolean data (they
+keep ``jnp.var``) and, on a mesh of several devices, where ONLY the
+sharded key axes are reduced (the pilot takes those whole).  A filtered group's ``var`` is one pass in the
+UNSHIFTED form (``array._masked_stat_expr``: error scales with ``var +
+mean**2``); a streamed group's is per-slab moments merged by Chan.
+
 Laziness is read-transparent: everything observable at call time stays
 at call time (axis validation, the ``analysis.strict`` gate, the
 donation decision — a sole-owned chain base is consumed by its FIRST
@@ -66,6 +77,7 @@ from bolt_tpu import _precision
 from bolt_tpu import stream as _streamlib
 from bolt_tpu.obs import trace as _obs
 from bolt_tpu.tpu import fold as _fold
+from bolt_tpu.tpu import moments as _onepass
 from bolt_tpu.utils import inshape, prod, tupleize
 
 
@@ -94,7 +106,7 @@ _STREAM_LAZY = ("sum", "mean", "var", "std", "min", "max", "ptp")
 _ADDITIVE = ("sum", "prod", "mean", "var", "std")
 _INT_ADDITIVE = ("sum", "prod")
 
-_OPS = {"mean": jnp.mean, "var": jnp.var, "std": jnp.std,
+_OPS = {"mean": jnp.mean, "var": _onepass.var, "std": _onepass.std,
         "sum": jnp.sum, "max": jnp.max, "min": jnp.min,
         "prod": jnp.prod, "all": jnp.all, "any": jnp.any,
         "ptp": jnp.ptp}
@@ -303,15 +315,14 @@ class _StatGroup:
             # same engine key, same traced expressions
             m = members[0]
             # geometry only in the cached closure (see _resolve_reduce)
-            op = _OPS[m.name]
+            name, ddof = m.name, m.ddof
             axes, keepdims, new_split = m.axes, m.keepdims, m.new_split
-            kwargs = {} if m.ddof is None else {"ddof": m.ddof}
 
             def build():
                 def stat(data):
                     mapped = _chain_apply(funcs, split, data)
-                    out = op(mapped, axis=axes, keepdims=keepdims,
-                             **kwargs)
+                    out = _stat_expr(mapped, name, axes, keepdims, ddof,
+                                     None, mesh, split)
                     return _constrain(out, mesh, new_split)
                 return jax.jit(stat,
                                donate_argnums=(0,) if donate else ())
@@ -322,6 +333,7 @@ class _StatGroup:
             with _obs.span("array.stat", op=m.name, donate=donate,
                            **_span_funcs(funcs)):
                 m.result = fn(_check_live(base))
+            _record_one_pass(self)
             return
 
         # the fused multi-terminal program: one read, one slot per
@@ -334,7 +346,8 @@ class _StatGroup:
 
         def build():
             def stat(data):
-                outs = _chain_stat_exprs(data, funcs, split, slots, mode)
+                outs = _chain_stat_exprs(data, funcs, split, slots, mode,
+                                         mesh)
                 return tuple(_constrain(o, mesh, nsplit[s])
                              for o, s in zip(outs, slots))
             return jax.jit(stat, donate_argnums=(0,) if donate else ())
@@ -348,6 +361,7 @@ class _StatGroup:
             outs = fn(_check_live(base))
         if len(members) > 1:
             _engine.record_fused_stats(len(members))
+        _record_one_pass(self)
         index = {s: i for i, s in enumerate(slots)}
         for m in members:
             if m.name == "ptp":
@@ -432,12 +446,22 @@ class _StatGroup:
             m.result = out
 
 
+def _record_one_pass(group):
+    """Count a dispatched chain group's ``var``/``std`` members that
+    were traced in the one-pass form (``tpu/moments.py``: real floating
+    values; the ``accumulate=`` float modes cast such values and keep
+    the form)."""
+    n = sum(m.name in ("var", "std") for m in group.members)
+    if n and _onepass.one_pass(group.in_aval.dtype):
+        _engine.record_one_pass_moments(n)
+
+
 def _new_split(split, axes, keepdims):
     nkeys = sum(1 for a in axes if a < split)
     return split if keepdims else split - nkeys
 
 
-def _chain_stat_exprs(data, funcs, split, slots, mode):
+def _chain_stat_exprs(data, funcs, split, slots, mode, mesh):
     """The UNCONSTRAINED per-slot reduction expressions over one chain
     input — the shared body of the fused multi-stat program above AND
     the serve layer's batched (vmapped) program
@@ -446,20 +470,25 @@ def _chain_stat_exprs(data, funcs, split, slots, mode):
     caller applies the per-slot sharding constraint."""
     from bolt_tpu.tpu.array import _chain_apply
     mapped = _chain_apply(funcs, split, data)
-    return tuple(_stat_expr(mapped, name, axes, keepdims, ddof, mode)
+    return tuple(_stat_expr(mapped, name, axes, keepdims, ddof, mode, mesh,
+                            split)
                  for (name, axes, keepdims, ddof) in slots)
 
 
-def _stat_expr(mapped, name, axes, keepdims, ddof, mode):
-    """The per-terminal reduction expression of the fused program —
-    with ``mode=None`` exactly the standalone terminal's expression
-    (bit-identity of fused vs standalone is parity-locked in
+def _stat_expr(mapped, name, axes, keepdims, ddof, mode, mesh, split):
+    """The reduction expression of ONE chain terminal, standalone
+    (``mode=None``), fused or a batched lane: one traced arithmetic, so
+    bit-identity of fused vs standalone holds (parity-locked in
     tests/test_multistat.py); ``mode`` casts the ADDITIVE terminals'
     values ("bf16" accumulates in f32 — the accumulate-in-f32 contract;
     "f32" is exact for f32 pipelines) and leaves order statistics
-    untouched."""
+    untouched.  On a ``mesh`` of several devices the key axes (below
+    ``split``) are sharded, and a ``var``/``std`` is told to take them
+    whole in its pilot (``tpu/moments.py`` says why)."""
     op = _OPS[name]
     kwargs = {} if ddof is None else {"ddof": ddof}
+    if name in ("var", "std") and mesh is not None and mesh.size > 1:
+        kwargs["whole"] = tuple(a for a in axes if a < split)
     if mode == "int8":
         # the integer twin of bf16: int8 values, int32 accumulator (the
         # accumulate-in-i32 contract) — integer additive terminals of

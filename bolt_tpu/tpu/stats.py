@@ -11,6 +11,11 @@ fetch.
 This module is the framework's canonical example of the explicit-collective
 (``shard_map``) style; the everyday ``mean()/var()/std()`` methods use plain
 ``jnp`` reductions and let GSPMD insert the same collectives automatically.
+Their ``var``/``std`` of real floating data read HBM ONCE: one pass of
+moments shifted by a pilot mean (``tpu/moments.py``, error scaling with
+``var + (mean - pilot)**2``), one round of ``psum``s.  The moments HERE are
+centred on the finished mean (``m2``, what the Chan combine needs): one pass
+where the ``fused_welford`` kernel serves the geometry, two on the jnp path.
 """
 
 import numpy as np
@@ -30,7 +35,9 @@ def _shard_moments(x, axes):
     shard_map body).  When the reduced axes are the leading contiguous
     ones — the ``stats()`` default — and the shard geometry tiles cleanly,
     the single-HBM-pass pallas kernel computes them (XLA cannot fuse the
-    mean with the centred second moment, so it reads HBM twice).
+    mean with the second moment CENTRED on it, so the jnp path below reads
+    HBM twice; ``var()/std()`` escape that by centring on a pilot instead,
+    ``tpu/moments.py``, which gives no ``m2`` about the true mean).
     Everything else takes the jnp path — identical semantics,
     allclose-level numerics.  A geometry the plan admits and Mosaic
     refuses is a bug to see: the compile error propagates."""

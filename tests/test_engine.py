@@ -457,3 +457,53 @@ def test_tenant_scope_mirrors_engine_counters(mesh):
     t2 = engine.tenant_counters("unit-tenant")
     bolt.ones((8, 4), mesh).sum().toarray()
     assert engine.tenant_counters("unit-tenant") == t2
+
+
+# ---------------------------------------------------------------------
+# var/std of real floating data dispatched in the one-pass form
+# (ISSUE 61, bolt_tpu/tpu/moments.py)
+# ---------------------------------------------------------------------
+
+def _ask_var(b):
+    b.var().toarray()
+
+
+def _ask_std_of_a_window(b):
+    b[2:10].std(axis=(0, 1, 2)).toarray()
+
+
+def _ask_a_group(b):
+    m = b.map(lambda v: v + 1)
+    bolt.compute(m.var(), m.std(ddof=1), m.sum(), m.max())
+
+
+def _ask_mean_and_sum(b):
+    bolt.compute(b.mean(), b.sum())
+
+
+def _ask_var_behind_a_filter(b):
+    # the filter-folded terminal keeps its own (unshifted) one-pass fold
+    b.filter(lambda v: v.sum() > 0).var().toarray()
+
+
+@pytest.mark.parametrize("ask,dtype,launches", [
+    (_ask_var, np.float32, 1),
+    (_ask_var, np.float64, 1),
+    (_ask_std_of_a_window, np.float32, 1),
+    (_ask_a_group, np.float32, 2),
+    (_ask_var, np.int32, 0),
+    (_ask_var, np.complex64, 0),
+    (_ask_mean_and_sum, np.float32, 0),
+    (_ask_var_behind_a_filter, np.float32, 0),
+], ids=["var-f32", "var-f64", "window-std", "group-of-two", "var-int32",
+        "var-complex64", "no-moment", "behind-a-filter"])
+def test_one_pass_moment_launches_counts_dispatched_terminals(
+        mesh, ask, dtype, launches):
+    x = (np.random.RandomState(5).randn(16, 6, 4) * 8).astype(dtype)
+    b = bolt.array(x, mesh)
+    assert "one_pass_moment_launches" in engine._SCHEMA
+    c0 = engine.counters()["one_pass_moment_launches"]
+    ask(b)
+    assert engine.counters()["one_pass_moment_launches"] - c0 == launches
+    ask(b)                                # a cached program counts again
+    assert engine.counters()["one_pass_moment_launches"] - c0 == 2 * launches

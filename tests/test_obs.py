@@ -312,6 +312,7 @@ _EXPECTED_ENGINE_KEYS = {
     "stream_retries": False, "stream_resumes": False,
     "checkpoint_bytes": False, "checkpoint_seconds": True,
     "fused_stat_groups": False, "fused_stat_terminals": False,
+    "one_pass_moment_launches": False,
     "getitems_fused": False, "resplit_views": False,
     "gram_kernel_programs": False, "gram_sums_programs": False,
     "fold_kernel_programs": False,

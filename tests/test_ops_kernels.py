@@ -313,10 +313,18 @@ def test_kernel_compiles_for_v5e(v5e_device, name, fn, avals):
 # ---------------------------------------------------------------------
 
 _STACK = (3200, 200, 64, 64)          # the benchmark's resident stack
+
+
+def _library_std(x, axis):
+    # the expression the library's own statistic tables trace (ISSUE 61)
+    from bolt_tpu.tpu.multistat import _OPS
+    return _OPS["std"](x, axis=axis)
+
+
 _WINDOW_CASES = [
     # name, (starts, sizes) of the window, statistic, its axes
     ("slice_mean", ((97,), (16,)), jnp.mean, (0, 1, 2, 3)),
-    ("slice_std", ((41,), (16,)), jnp.std, (0, 1, 2, 3)),
+    ("slice_std", ((41,), (16,)), _library_std, (0, 1, 2, 3)),
     ("slice_max", ((13,), (16,)), jnp.max, (0,)),
     ("roi_trace", ((0, 11, 5, 3), (3200, 8, 8, 8)), jnp.mean, (1, 2, 3)),
 ]
@@ -354,6 +362,97 @@ def test_window_statistic_is_one_fusion_on_v5e(v5e_device, name, window,
     assert not outside, outside
     assert not re.search(r"= \S+ slice\(", entry), name
     assert compiled.memory_analysis().temp_size_in_bytes < win_bytes
+
+
+# ---------------------------------------------------------------------
+# compile-only: var()/std() of real floating data read what they reduce
+# ONCE (ISSUE 61).  ``jnp.std`` is a mean and then the squared deviations
+# from it, two reductions XLA cannot fuse: 838.9 MB for a 16-record window
+# of the stack (whose key axis is on the lanes, so 16 records cost a
+# 128-lane column, 419.4 MB a read) and 20.97 GB for the whole stack.  The
+# library's expression (tpu/moments.py) is a pilot over a small corner and
+# ONE multi-output fusion that takes both shifted moments from one read
+# ---------------------------------------------------------------------
+
+_COLUMN = 4 * 128 * 200 * 64 * 64     # a 128-lane column of the stack
+_ONE_PASS_CASES = [
+    # name, the window in front (or none), its axes, bytes of ONE read
+    ("window_std", ((41,), (16,)), (0, 1, 2, 3), _COLUMN),
+    ("stack_std", None, (0, 1, 2, 3), 4 * int(np.prod(_STACK))),
+    ("stack_std_a_record", None, (1, 2, 3), 4 * int(np.prod(_STACK))),
+]
+
+
+@pytest.mark.parametrize("name,window,axes,one_read", _ONE_PASS_CASES,
+                         ids=[c[0] for c in _ONE_PASS_CASES])
+def test_std_reads_what_it_reduces_once_on_v5e(v5e_device, name, window,
+                                               axes, one_read):
+    import re
+    import jax
+    from bolt_tpu.tpu.array import _Window, _chain_apply
+    funcs = () if window is None else (_Window(*window, (), 1),)
+    win_bytes = (one_read if window is None else
+                 4 * int(np.prod(funcs[0].out_shape(_STACK))))
+
+    def compiled(op):
+        def stat(data):
+            return op(_chain_apply(funcs, 1, data), axis=axes)
+        where = jax.sharding.SingleDeviceSharding(v5e_device)
+        with jax.enable_x64(False):
+            return jax.jit(stat).lower(jax.ShapeDtypeStruct(
+                _STACK, _F32, sharding=where)).compile()
+
+    ours, jnps = compiled(_library_std), compiled(jnp.std)
+    # the two-pass form reads twice (what the ledger's two operations,
+    # slice_reduce_fusion and multiply_reduce_fusion, were) ...
+    assert jnps.cost_analysis()["bytes accessed"] >= 1.95 * one_read
+    # ... the library's once, pilot included
+    assert ours.cost_analysis()["bytes accessed"] <= 1.05 * one_read
+    text = ours.as_text()
+    entry = text[text.index("ENTRY"):]
+    shaped = re.escape("f32[%s]" % ",".join(map(str, _STACK)))
+    param = re.search(r"(%\S+) = " + shaped + r"\S* parameter\(0\)",
+                      entry).group(1)
+    readers = [ln for ln in entry.splitlines()
+               if re.search(r" fusion\(%s[,)]" % re.escape(param), ln)]
+    # two fusions take the base: the pilot's, with one result, and ONE
+    # with the two sums as its results (no second reader at size)
+    both = [ln for ln in readers
+            if re.search(r"= \(f32\[[^ ]*, f32\[[^ ]*\) fusion\(", ln)]
+    assert len(readers) == 2 and len(both) == 1, readers
+    assert not re.search(r"= \S+ (slice|copy)\(%s" % re.escape(param), entry)
+    assert ours.memory_analysis().temp_size_in_bytes < min(win_bytes, 1 << 20)
+
+
+def test_std_on_four_chips_takes_the_sharded_axis_whole(v5e_device):
+    # a pilot cut out of the SHARDED key axis is a static window GSPMD
+    # re-shards by moving whole shards (1.39 GB of temporaries and 9.57
+    # GB read a chip for the 14.42 GB stack, where jnp.std reads 7.55):
+    # told the mesh, the expression corners the value axes alone and the
+    # program is one local pass and small all-reduces
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from bolt_tpu.tpu.multistat import _stat_expr
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("k",))
+    stack = (4400,) + _STACK[1:]
+    shard = 4 * int(np.prod(stack)) // 4
+
+    def stat(data):
+        return _stat_expr(data, "std", (0, 1, 2, 3), False, None, None,
+                          mesh, 1)
+
+    with jax.enable_x64(False):
+        compiled = jax.jit(stat, out_shardings=NamedSharding(
+            mesh, P())).lower(jax.ShapeDtypeStruct(
+                stack, _F32, sharding=NamedSharding(mesh, P("k")))).compile()
+    text = compiled.as_text()
+    assert "collective-permute" not in text and "all-gather" not in text
+    assert "all-reduce" in text
+    assert compiled.cost_analysis()["bytes accessed"] <= 1.05 * shard
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 # ---------------------------------------------------------------------

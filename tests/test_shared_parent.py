@@ -546,7 +546,10 @@ def single_consumer_program(mesh, cell):
 # tuning chain's text is PR 49's, under the engine key it has: that PR
 # spelt ``detrend``'s fit element-wise and took the mean's pass out of a
 # ``fourier`` behind it, the chain's last two maps (PR 44's text, with
-# the FFT out of ``fourier``: 3ca6f753dc6fd6aa / b0cd407b4582c347...)
+# the FFT out of ``fourier``: 3ca6f753dc6fd6aa / b0cd407b4582c347...).
+# The followups window's text is PR 61's, under the engine key it had:
+# ``std`` of real floating data is traced as one pass of shifted moments
+# (``tpu/moments.py``; ``jnp.std``'s two passes read 5dc44a0267fb0ce6...)
 PARENT_PROGRAMS = {
     "three-maps": {
         "4e42b4d2a6a0b9d7":
@@ -561,7 +564,7 @@ PARENT_PROGRAMS = {
         "d1dead2a598c914203bae4a98f76e3c8fe78287c1ca09f91d92753d53b040028"},
     "followups-window": {
         "5d663718c29e772c":
-        "5dc44a0267fb0ce6eb5d6a79e34847caf43873870a09d077a01f839a435d98b9"},
+        "c22c92aa7bb7cb3e770849ec5d40aefc8541c809d00c2cad7c2029fde63b3838"},
 }
 
 
